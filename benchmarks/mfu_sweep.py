@@ -35,10 +35,7 @@ def main():
     from ray_tpu.models import get_config
     from ray_tpu.train.step import OptimizerConfig, lm_loss_chunked_fn
 
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "")
-    peak = next((v for k, v in bench.PEAK_FLOPS.items() if k in kind),
-                197e12)
+    peak = bench.peak_flops(jax.devices()[0])
 
     # _bench_one re-imports lm_loss_chunked_fn at call time, so patching
     # the module attribute injects our chunk size

@@ -1,10 +1,10 @@
 """Multi-chip sharded-training headline legs (docs/train_sharded.md).
 
-Runs in its OWN process: the simulated multi-device mesh needs
+Its own program, run by hand: the simulated multi-device mesh needs
 ``JAX_PLATFORMS=cpu`` + ``XLA_FLAGS=--xla_force_host_platform_device_
-count=N`` pinned before the first backend touch, which bench.py (whose
-backend is already live) cannot do — so bench.py launches this module as
-a subprocess and folds its one JSON line into the headline output.
+count=N`` pinned before the first backend touch.  Its rows are counts and
+host timings on forced host devices — never device metrics — so
+``bench.py`` (the chip's JSON line) does not carry them.
 
 Two legs:
 
@@ -327,10 +327,11 @@ def main(argv=None) -> int:
     ap.add_argument("--pp-steps", type=int, default=3, dest="pp_steps")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--peak", type=float, default=197e12,
-                    help="per-device peak FLOPs for the MFU columns "
-                         "(bench.py's v5e default: simulated-CPU MFU is "
-                         "a consistency check, not a hardware claim)")
+    ap.add_argument("--peak", type=float, default=0.0,
+                    help="per-device peak FLOPs for the ledger's MFU "
+                         "column; 0 (default) = unknown, no MFU: forced "
+                         "host devices have no peak to be measured "
+                         "against")
     ap.add_argument("--legs", default="both",
                     choices=("both", "elastic", "pipeline"))
     args = ap.parse_args(argv)

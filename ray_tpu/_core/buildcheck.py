@@ -6,7 +6,8 @@ toolchain — but after any csrc/ edit a committed binary silently goes
 stale and runtime behavior diverges from source.  `make -C csrc` writes
 a stamp (`.src_sha256`, the hash of every csrc source) next to the
 binaries; ensure_fresh() recomputes that hash and, on mismatch, rebuilds
-before the binary is spawned/loaded (or warns when no toolchain exists).
+before the binary is spawned/loaded.  A rebuild that is needed and fails
+is an error: a binary that does not match its sources is never run.
 
 Importable standalone (no package imports): the Makefile invokes
 `python3 buildcheck.py --write-stamp` after a successful build.
@@ -54,18 +55,16 @@ def ensure_fresh(logger=None) -> None:
     """Verify the committed binaries match csrc/ sources; rebuild if not.
 
     Cheap (hashes ~15 small files) and runs at most once per process.
-    A failed rebuild degrades to a loud warning rather than an error:
-    the stale binary is still runnable, just possibly divergent.
+    Raises RuntimeError when the binaries are stale and the rebuild
+    fails (toolchain missing, compile error).
     """
     global _checked
     with _lock:
         if _checked:
             return
-        _checked = True
         want = source_hash()
-        if want is None:
-            return
-        if _stamp_matches(want):
+        if want is None or _stamp_matches(want):
+            _checked = True
             return
         # Stale. Serialize the rebuild across PROCESSES too (several
         # raylets on one machine may spawn workers concurrently; two
@@ -77,24 +76,31 @@ def ensure_fresh(logger=None) -> None:
                 fcntl.flock(lockf, fcntl.LOCK_EX)
                 # another process may have finished the rebuild while we
                 # waited for the lock
-                if _stamp_matches(want):
-                    return
-                subprocess.run(["make", "-C", _csrc_dir()], check=True,
-                               capture_output=True, timeout=600)
-                write_stamp()
+                if not _stamp_matches(want):
+                    if logger is not None:
+                        logger.info("ray_tpu/_core binaries are stale "
+                                    "relative to csrc/; rebuilding")
+                    subprocess.run(["make", "-C", _csrc_dir()], check=True,
+                                   capture_output=True, timeout=600)
+                    write_stamp()
         except Exception as exc:  # toolchain missing / compile error
-            msg = ("ray_tpu/_core binaries are stale relative to csrc/ "
-                   f"sources and rebuild failed ({exc}); runtime behavior "
-                   "may diverge from source — run `make -C csrc`")
-            if logger is not None:
-                logger.warning(msg)
-            else:
-                import warnings
-                warnings.warn(msg)
+            detail = getattr(exc, "stderr", b"") or b""
+            raise RuntimeError(
+                "ray_tpu/_core binaries are stale relative to csrc/ "
+                f"sources and the rebuild failed ({exc}); run "
+                "`make -C csrc`\n"
+                + detail.decode(errors="replace")[-2000:]) from exc
+        _checked = True
+
+
+_ARTEFACTS = ("libshmstore.so", "libscheduler.so", "pycodec_tool",
+              "cpp_worker", "cpp_driver_demo")
 
 
 def _stamp_matches(want: str) -> bool:
-    if not os.path.exists(_STAMP):
+    """Stamp equals the source hash AND every artefact is present."""
+    if not all(os.path.exists(os.path.join(_CORE_DIR, a))
+               for a in _ARTEFACTS + (".src_sha256",)):
         return False
     with open(_STAMP) as f:
         return f.read().strip() == want

@@ -5,12 +5,13 @@ ClusterResourceScheduler (/root/reference/src/ray/raylet/scheduling/
 cluster_resource_scheduler.h:45).  Resources cross the ABI as fixed-point
 milli-units packed into "name=milli;..." strings; if the .so isn't built, a
 pure-Python ClusterScheduler with identical semantics takes over (same
-tests run against both).
+tests run against both) — logged once at warning level, never silent.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
 import os
 import threading
 from typing import Dict, Optional
@@ -26,11 +27,11 @@ def _pack(resources: Dict[str, float]) -> bytes:
 
 
 def _load_lib():
-    if not os.path.exists(_LIB_PATH):
-        return None
+    from ray_tpu._core import buildcheck
     try:
+        buildcheck.ensure_fresh()
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except (OSError, RuntimeError):
         return None
     lib.sched_create.restype = ctypes.c_void_p
     lib.sched_create.argtypes = [ctypes.c_double, ctypes.c_int]
@@ -167,10 +168,20 @@ class PyClusterScheduler:
                        for n in self._nodes.values())
 
 
+@functools.cache
+def _warn_fallback() -> None:
+    """Once per process: the fallback is never silent."""
+    from ray_tpu._private.logging_utils import get_logger
+    get_logger("scheduler").warning(
+        "native scheduler %s did not load; using the pure-Python "
+        "ClusterScheduler (run `make -C csrc`)", _LIB_PATH)
+
+
 def make_scheduler(spill_threshold: float = 0.5, top_k: int = 1):
     """Native scheduler when the .so is built, Python fallback otherwise."""
     if _lib is not None:
         return NativeClusterScheduler(spill_threshold, top_k)
+    _warn_fallback()
     return PyClusterScheduler(spill_threshold, top_k)
 
 

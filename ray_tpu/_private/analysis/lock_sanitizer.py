@@ -168,15 +168,31 @@ def _record_edges(new_site: str, acquire_site: str) -> None:
         raise LockOrderError("\n".join(lines))
 
 
+# Set while THIS thread is inside the sanitizer's own bookkeeping.  An
+# allocation there can run the garbage collector, a collected
+# ObjectRef's __del__ takes a sanitized lock, and the hooks would
+# re-enter and block forever on _held_guard / _graph_lock, which this
+# same thread already holds (seen as a 20-minute hang of a chaos test
+# in tier-1).  Such a nested acquire/release pair lives entirely inside
+# the bookkeeping, so it is simply not recorded.
+_bookkeeping = threading.local()
+
+
 def _on_acquired(site: str, lock: object, first: bool) -> None:
     if not first:
         return  # RLock recursion: already on the stack
-    acq = _caller_site(3)
-    _record_edges(site, acq)
-    tid = threading.get_ident()
-    lock._held_tid = tid
-    with _held_guard:
-        _held_by_tid.setdefault(tid, []).append((site, acq, lock))
+    if getattr(_bookkeeping, "active", False):
+        return
+    _bookkeeping.active = True
+    try:
+        acq = _caller_site(3)
+        _record_edges(site, acq)
+        tid = threading.get_ident()
+        lock._held_tid = tid
+        with _held_guard:
+            _held_by_tid.setdefault(tid, []).append((site, acq, lock))
+    finally:
+        _bookkeeping.active = False
 
 
 def _on_released(lock: object) -> None:
@@ -184,8 +200,16 @@ def _on_released(lock: object) -> None:
     # which, for a plain Lock handed across threads, may not be the
     # releasing thread
     tid = getattr(lock, "_held_tid", None)
-    if tid is None:
+    if tid is None or getattr(_bookkeeping, "active", False):
         return
+    _bookkeeping.active = True
+    try:
+        _drop_held(tid, lock)
+    finally:
+        _bookkeeping.active = False
+
+
+def _drop_held(tid: int, lock: object) -> None:
     with _held_guard:
         held = _held_by_tid.get(tid)
         if held is None:

@@ -193,8 +193,41 @@ class Attention(nn.Module):
                 v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
             from ray_tpu.ops.ulysses import ulysses_attention
             return ulysses_attention(q, k, v, mesh=self.mesh, causal=True)
-        from ray_tpu.ops.attention import attention
+        from ray_tpu.ops.attention import attention, resolve_impl
+        impl = resolve_impl(impl)
+        if impl in ("flash", "splash") and self.mesh is not None \
+                and self.mesh.size > 1:
+            return self._kernel_attend_sharded(q, k, v, impl)
         return attention(q, k, v, causal=True, impl=impl)
+
+    def _kernel_attend_sharded(self, q, k, v, impl: str):
+        """A Pallas (Mosaic) kernel is one opaque custom call: GSPMD
+        cannot partition it, and lowering it with sharded operands on a
+        multi-chip TPU mesh is refused outright.  So the kernel runs per
+        shard under shard_map on the activations' own layout — batch
+        over the data axes, heads over ``tensor`` — which needs no
+        communication: attention is independent per (batch, head)."""
+        import functools
+
+        from jax import shard_map
+
+        from ray_tpu.ops.attention import attention
+        from ray_tpu.parallel.sharding import logical_spec
+        cfg, mesh = self.cfg, self.mesh
+        if mesh.shape.get("context", 1) > 1:
+            raise ValueError(
+                f"attention_impl={impl!r} needs the whole sequence on "
+                "each shard; with context parallelism use 'ring' or "
+                "'ulysses'")
+        if cfg.n_kv_heads != cfg.n_heads:
+            # equal head counts, so q/k/v share one head sharding
+            k = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
+            v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
+        spec = logical_spec(("batch", None, "heads", None), mesh,
+                            self.rules)
+        fn = functools.partial(attention, causal=True, impl=impl)
+        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
     def _decode_attend(self, q, k, v, positions):
         """Write K/V into the cache at per-row positions and attend under

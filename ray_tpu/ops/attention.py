@@ -23,11 +23,20 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except (RuntimeError, IndexError):
-        return False
+def backend_platform() -> str:
+    """Platform of the default backend — the ONE question every kernel
+    dispatch in ops/ asks.  A backend that cannot start (chip held by
+    another process, libtpu already loaded) raises here: it must never
+    be read as "not a TPU", or an explicit ``impl="flash"`` would
+    quietly become the Pallas interpreter or the XLA path."""
+    return jax.devices()[0].platform
+
+
+def resolve_impl(impl: str) -> str:
+    """``"auto"`` -> flash on TPU backends, xla elsewhere."""
+    if impl == "auto":
+        return "flash" if backend_platform() == "tpu" else "xla"
+    return impl
 
 
 def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -88,8 +97,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               impl: str = "auto",
               mask: Optional[jax.Array] = None) -> jax.Array:
     """Public fused attention entry point (see module docstring)."""
-    if impl == "auto":
-        impl = "flash" if _on_tpu() else "xla"
+    impl = resolve_impl(impl)
     if impl in ("flash", "splash") and mask is not None:
         impl = "xla"       # the Pallas kernels have no padding-mask path
     if impl in ("flash", "splash"):

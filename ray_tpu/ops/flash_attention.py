@@ -6,7 +6,7 @@ bounds.  The reference framework has no attention kernel at all (its compute
 lives in torch user code — SURVEY.md §2.6); this is the framework-native hot
 op that Train/Serve model families build on.
 
-On non-TPU backends the same kernels run under ``interpret=True`` so unit
+On the CPU backend the same kernels run under ``interpret=True`` so unit
 tests exercise the identical code path (SURVEY.md §4 device-simulation
 strategy).
 """
@@ -21,18 +21,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pltpu.TPUCompilerParams -> CompilerParams rename shim
-from ray_tpu._private.jax_compat import tpu_compiler_params as \
-    _CompilerParams
+from ray_tpu.ops.attention import backend_platform
+
+_CompilerParams = pltpu.CompilerParams
 
 NEG_INF = -1e30
 
 
 def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except (RuntimeError, IndexError):
-        return True
+    """Interpret mode only where the platform really is the CPU (unit
+    tests).  Any other platform gets the compiled kernel, and a backend
+    that fails to start raises instead of silently interpreting."""
+    return backend_platform() == "cpu"
 
 
 def _pick_block(seq: int, target: int) -> int:

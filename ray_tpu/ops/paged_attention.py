@@ -39,13 +39,12 @@ Two implementations:
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import xla_attention
+from ray_tpu.ops.attention import backend_platform, xla_attention
 
 
 def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
@@ -155,7 +154,7 @@ def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, heads, hd2), lambda r, *_: (r, 0, 0),
                          memory_space=pltpu.VMEM),         # q2
-            pl.BlockSpec(memory_space=pltpu.ANY),          # kv_pages (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),             # kv_pages (HBM)
         ],
         out_specs=pl.BlockSpec((1, heads, hd2), lambda r, *_: (r, 0, 0),
                                memory_space=pltpu.VMEM),
@@ -184,32 +183,40 @@ def paged_attention_tpu(q, kv_pages, block_tables, lengths, *,
     return out2[..., hd:]       # V half holds the attention output
 
 
-@functools.cache
-def _default_impl() -> str:
-    try:
-        return ("tpu" if jax.devices()[0].platform == "tpu" else "xla")
-    except (RuntimeError, IndexError):
-        return "xla"
+def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
+    """Which implementation ``paged_attention`` runs for a pool whose
+    minor dim (``2*head_dim``) is ``kv_minor``: ``"tpu"`` or ``"xla"``.
+
+    Only ``"auto"`` may settle for the XLA gather — off the TPU, or when
+    the page is not lane-aligned (Mosaic DMA slices need a minor dim
+    that is a multiple of 128, so test-size heads cannot use the
+    kernel).  An explicit ``"tpu"`` with such a shape raises.
+    ``RAY_TPU_PAGED_ATTENTION_IMPL=xla|tpu`` is read as an explicit
+    request — the on-chip engine-machinery tests force ``xla`` so they
+    can demand BIT-exact equality with lone dense generation (the
+    Pallas kernel's page-wise online softmax is numerically equivalent
+    but not bitwise, so greedy decode can tie-flip vs the dense
+    oracle)."""
+    import os
+    if impl == "auto":
+        impl = os.environ.get("RAY_TPU_PAGED_ATTENTION_IMPL", "auto")
+    if impl == "auto":
+        aligned = kv_minor % 128 == 0
+        return "tpu" if aligned and backend_platform() == "tpu" else "xla"
+    if impl == "tpu" and kv_minor % 128:
+        raise ValueError(
+            f"paged_attention impl='tpu' needs 2*head_dim % 128 == 0 "
+            f"(got {kv_minor}); use impl='auto' or 'xla' for this shape")
+    if impl not in ("tpu", "xla"):
+        raise ValueError(f"unknown paged attention impl: {impl!r}")
+    return impl
 
 
 def paged_attention(q, kv_pages, block_tables, lengths, *,
                     sm_scale: Optional[float] = None,
                     impl: str = "auto") -> jax.Array:
-    """Backend-dispatched paged decode attention (see module docstring).
-
-    ``RAY_TPU_PAGED_ATTENTION_IMPL=xla|tpu`` overrides the dispatch —
-    the on-chip engine-machinery tests force ``xla`` so they can demand
-    BIT-exact equality with lone dense generation (the Pallas kernel's
-    page-wise online softmax is numerically equivalent but not bitwise,
-    so greedy decode can tie-flip vs the dense oracle)."""
-    import os
-    if impl == "auto":
-        impl = os.environ.get("RAY_TPU_PAGED_ATTENTION_IMPL", "auto")
-    if impl == "auto":
-        impl = _default_impl()
-        if kv_pages.shape[-1] % 128:
-            # Mosaic DMA slices must be lane-aligned: 2*head_dim below
-            # 128 (test-size models) can't use the kernel
-            impl = "xla"
+    """Backend-dispatched paged decode attention (see module docstring
+    and :func:`resolve_paged_impl`)."""
+    impl = resolve_paged_impl(kv_pages.shape[-1], impl)
     fn = paged_attention_tpu if impl == "tpu" else paged_attention_xla
     return fn(q, kv_pages, block_tables, lengths, sm_scale=sm_scale)

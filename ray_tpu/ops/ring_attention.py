@@ -22,11 +22,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
+from jax.lax import axis_size as _axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-# renamed-API shims (shard_map promotion, lax.axis_size)
-from ray_tpu._private.jax_compat import axis_size as _axis_size
-from ray_tpu._private.jax_compat import shard_map as _shard_map
 
 NEG_INF = -1e30
 

@@ -19,11 +19,9 @@ import functools
 from typing import Optional
 
 import jax
+from jax import shard_map as _shard_map
+from jax.lax import axis_size as _axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-# renamed-API shims (shard_map promotion, lax.axis_size)
-from ray_tpu._private.jax_compat import axis_size as _axis_size
-from ray_tpu._private.jax_compat import shard_map as _shard_map
 
 
 def ulysses_attention_local(q: jax.Array, k: jax.Array, v: jax.Array, *,

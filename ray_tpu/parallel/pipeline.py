@@ -23,10 +23,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# shard_map promotion shim (_shard_map vs jax.experimental.shard_map)
-from ray_tpu._private.jax_compat import shard_map as _shard_map
 
 
 def _stage_perm(n: int):

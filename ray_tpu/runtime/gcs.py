@@ -1029,18 +1029,34 @@ class GcsServer:
                     pruned += 1
         return pruned
 
+    def _expired_nodes(self, now: float, pause: float, period: float,
+                       threshold: int) -> List[str]:
+        """Alive nodes whose last heartbeat is older than ``threshold``
+        periods (caller holds ``_lock``).  ``pause`` is how late the
+        health loop itself woke: a monitor that was paused cannot
+        conclude that others died.  When the whole host stalls — on a
+        TPU host libtpu's start freezes every process for seconds,
+        measured 3-6 s on the v5e VM in PR 21 — or the GCS is starved,
+        heartbeats could not be received meanwhile, so every node is
+        credited the pause before its age is judged."""
+        if pause > period:
+            for node in self._nodes.values():
+                node["last_heartbeat"] += pause
+        return [nid for nid, node in self._nodes.items()
+                if node["alive"]
+                and now - node["last_heartbeat"] > period * threshold]
+
     def _health_loop(self) -> None:
         period = CONFIG.heartbeat_period_ms / 1000.0
         threshold = CONFIG.health_check_failure_threshold
         ticks = 0
+        last_tick = time.monotonic()
         while not self._stopped.wait(period):
             now = time.monotonic()
-            dead = []
+            pause = now - last_tick - period
+            last_tick = now
             with self._lock:
-                for nid, node in self._nodes.items():
-                    if node["alive"] and \
-                            now - node["last_heartbeat"] > period * threshold:
-                        dead.append(nid)
+                dead = self._expired_nodes(now, pause, period, threshold)
                 have_pending = any(
                     a["state"] in (PENDING_CREATION, RESTARTING)
                     and not a.get("dispatched")
@@ -1766,6 +1782,10 @@ class GcsServer:
                 else sorted(order, key=lambda nid: -min(
                     avail[nid].get(r, 0) for r in bundle) if bundle else 0)
             for nid in candidates:
+                if strategy == "STRICT_SPREAD" and nid in placement:
+                    # one bundle per node: a roomy node (a many-core
+                    # head) must not draw two and fail the whole plan
+                    continue
                 if all(avail[nid].get(r, 0) >= v for r, v in bundle.items()):
                     placed = nid
                     break

@@ -28,11 +28,10 @@ _DEFAULT_FREELIST = 32768
 
 
 def _load_lib() -> ctypes.CDLL:
-    if not os.path.exists(_LIB_PATH):
-        import subprocess
-        csrc = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(__file__))), "csrc")
-        subprocess.run(["make", "-C", csrc], check=True, capture_output=True)
+    # never load a library that does not match csrc/: rebuilds when
+    # stale or missing, raises when that fails
+    from ray_tpu._core import buildcheck
+    buildcheck.ensure_fresh()
     lib = ctypes.CDLL(_LIB_PATH)
     lib.store_segment_size.restype = ctypes.c_uint64
     lib.store_segment_size.argtypes = [ctypes.c_uint64, ctypes.c_uint32,

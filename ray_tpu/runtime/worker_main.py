@@ -1293,6 +1293,10 @@ def main():
     parser.add_argument("--session-dir", required=True)
     parser.add_argument("--node-id", required=True)
     args = parser.parse_args()
+    # both spawn paths (exec, and the zygote's _become_worker) come
+    # through here before user code can compile anything
+    from ray_tpu._private.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     setup_component_logging("worker", args.session_dir)
     from ray_tpu._private.logging_utils import enable_stack_dumps
     enable_stack_dumps(args.session_dir)

@@ -76,9 +76,14 @@ def wrap_worker_command(container: dict, cmd: List[str], *,
            "-v", f"{session_dir}:{session_dir}",
            "-v", f"{store_dir}:{store_dir}",
            "--network=host", "--pid=host", "--ipc=host"]
+    cache_dir = env.get("JAX_COMPILATION_CACHE_DIR")
+    if cache_dir:
+        # the compile cache is placed from outside
+        # (_private/compile_cache.py): same directory inside the image
+        out += ["-v", f"{cache_dir}:{cache_dir}"]
     forward = ["PYTHONPATH", "RAY_TPU_SYSTEM_CONFIG",
                "RAY_TPU_RUNTIME_ENV", "RAY_TPU_INLINE_OBJECT_MAX_BYTES",
-               "JAX_PLATFORMS", "XLA_FLAGS"]
+               "JAX_PLATFORMS", "XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR"]
     # user env_vars from the runtime_env descriptor ride along too —
     # the raylet merged them into `env`, and the descriptor JSON names
     # which keys are the user's (the reference forwards the entire host

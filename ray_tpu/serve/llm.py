@@ -33,7 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import runtime_metrics as rtm
+from ray_tpu._private.compile_cache import (process_facts,
+                                             start_compile_clock)
 from ray_tpu._private.config import CONFIG
+from ray_tpu._private.logging_utils import get_logger
 from ray_tpu.serve.deployment import deployment
 from ray_tpu.util.tracing import tracing_helper as trh
 
@@ -64,6 +67,8 @@ _M_HANDOFF_SAVED = rtm.counter(
     "ray_tpu_serve_handoff_saved_bytes",
     "cross-host KV handoff bytes NOT shipped thanks to the int8 wire "
     "codec (raw - encoded, serve_handoff_quantize)")
+
+logger = get_logger("serve")
 
 # one int8 wire-codec block size for both handoff endpoints: encode and
 # decode must derive identical segmentation (quant.py wire layout)
@@ -166,6 +171,13 @@ class LLMServer:
         if role not in ("colocated", "prefill", "decode"):
             raise ValueError(f"unknown LLMServer role {role!r}")
         self.role = role
+        self._compile_clock = start_compile_clock()
+        # which device this replica serves on is decided by its lease,
+        # not by this class: a replica with no TPU lease is pinned to the
+        # CPU backend by the raylet (build_app num_tpus).  Say it once,
+        # loudly, so a gpt-scale engine on the host CPU is never silent.
+        logger.warning("LLMServer %r (%s) serving on %s", preset, role,
+                       process_facts(self._compile_clock)["device"])
         self.import_retry_s = import_retry_s
         del _upstream   # deploy-ordering anchor only (build_app)
         if role != "colocated":
@@ -433,6 +445,18 @@ class LLMServer:
         out["role"] = self.role
         return out
 
+    def device_info(self) -> Dict[str, Any]:
+        """What this replica really runs on, from the process that holds
+        the device: platform/kind/count, which paged-decode kernel the
+        engine's pool resolves to, and the compile clock so far."""
+        from ray_tpu.ops.paged_attention import resolve_paged_impl
+        return {
+            **process_facts(self._compile_clock),
+            "paged": bool(self.engine.paged),
+            "paged_impl": (resolve_paged_impl(2 * self.engine.cfg.head_dim)
+                           if self.engine.paged else None),
+        }
+
     def advertised_prefixes(self) -> Optional[Dict[str, Any]]:
         """Resident prompt-prefix digests for the replica metrics path
         (docs/serve_frontdoor.md): the controller republishes these on
@@ -485,8 +509,9 @@ def build_app(preset: str = "tiny", *, num_replicas: int = 1,
     ``num_tpus``: chips each replica leases.  MUST be > 0 to serve on
     TPU — a replica with no TPU lease is pinned to the CPU backend by
     the raylet (worker_main must not grab libtpu from under a training
-    job; raylet._tpu_env), and a gpt-scale engine on one CPU core
-    serves ~100x slower.  CI tests on CPU-only clusters keep 0.
+    job; raylet._tpu_env).  Every replica logs the platform it serves
+    on at start, at warning level, so a CPU-pinned gpt-scale engine is
+    visible.  CI tests on CPU-only clusters keep 0.
 
     ``autoscaling_config``: queue-depth replica autoscaling (min/max
     replicas, target_num_ongoing_requests_per_replica, up/downscale

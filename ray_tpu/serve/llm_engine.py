@@ -8,9 +8,10 @@ fixed slot grid, with requests admitted into free KV-cache slots and
 evicted the step they finish — no compile-shape churn, no head-of-line
 blocking behind a long generation.
 
-Design (shaped by one hard constraint: on a remote-chip transport every
-device->host fetch costs a full round-trip that outweighs a decode step
-~12x, so the engine does exactly ONE fetch per scheduling quantum):
+Design (shaped by one hard constraint: a device->host fetch is a host
+synchronization point — it drains the dispatch queue and costs a fixed
+host-dispatch latency that a single decode step does not amortize — so
+the engine does exactly ONE fetch per scheduling quantum):
 
   - The KV cache is one global [num_slots+1, max_seq, ...] buffer per
     layer (gpt.py ``_decode_attend`` slot mode: per-row write positions
@@ -80,9 +81,9 @@ from ray_tpu.serve.frontdoor.prefix import page_digests
 
 # admission waves are padded to the next of these sizes (bounded jit
 # specializations per prompt bucket); the top size bounds how many
-# prompts one prefill dispatch carries — on a remote-chip transport the
-# per-dispatch round-trip dwarfs the prefill compute, so saturation
-# bursts (prefill-ahead admitting a whole queue) want wide waves
+# prompts one prefill dispatch carries — each dispatch pays a fixed
+# host launch latency, so saturation bursts (prefill-ahead admitting a
+# whole queue) want wide waves
 _WAVE_SIZES = (1, 2, 4, 8, 16, 32)
 
 
@@ -1067,8 +1068,8 @@ class LLMEngine:
         """One batched prefill + one batched cache insert for admits
         sharing a prompt-length bucket.  Returns the DEVICE array of
         their first tokens — nothing is fetched here, and everything
-        rides ONE packed upload (each host->device transfer is a
-        round-trip on a remote-chip transport)."""
+        rides ONE packed upload (each host->device transfer is its own
+        dispatch with a fixed host-side latency)."""
         # packed layout per row: [prompt(bucket) | s_real | slot | temp*1e6]
         packed = np.zeros((wave, bucket + 3), np.int32)
         packed[:, bucket] = 1
@@ -1646,9 +1647,9 @@ class LLMEngine:
 
     def _process_prefill_waves(self, waves: list) -> list:
         """Fetch this iteration's prefill first-tokens with ONE combined
-        device->host transfer (each fetch is a full round-trip on a
-        remote-chip transport; a saturation burst dispatches many waves
-        per iteration) and complete/queue each request.  Returns the
+        device->host transfer (each fetch is a host sync with a fixed
+        latency; a saturation burst dispatches many waves per
+        iteration) and complete/queue each request.  Returns the
         export-flagged requests (first token now known) for
         _process_exports."""
         if not waves:

@@ -76,7 +76,7 @@ def make_grad_apply_step(model, mesh, optimizer=None, rules=None,
         return TrainState.create(apply_fn=model.apply,
                                  params=variables["params"], tx=tx)
 
-    from ray_tpu._private.jax_compat import NamedSharding, PartitionSpec
+    from jax.sharding import NamedSharding, PartitionSpec
     state_shardings, batch_sharding = trace_state_shardings(
         build_state, example_batch, mesh, rules, batch_axes=("batch", None))
     param_shardings = state_shardings.params
@@ -291,19 +291,68 @@ def _synth_batch(cfg, vocab: int, rank: int, step: int):
         jnp.int32)}
 
 
+def build_step(cfg: ShardedRunConfig, mesh, example_batch):
+    """``(init_fn, grad_fn, apply_fn)`` of the run's model and optimizer
+    compiled for ``mesh`` — the gang loop's step, also what a reference
+    run on another mesh (chip_smoke.py's one-device comparison) builds
+    so both sides share model, optimizer and schedule."""
+    from ray_tpu.models import GPT, get_config
+    from ray_tpu.train.step import OptimizerConfig
+
+    model = GPT(get_config(cfg.model, **cfg.model_overrides), mesh=mesh)
+    opt = OptimizerConfig(learning_rate=cfg.learning_rate,
+                          warmup_steps=1, decay_steps=max(10, cfg.steps),
+                          optimizer=cfg.optimizer)
+    return make_grad_apply_step(model, mesh, opt,
+                                example_batch=example_batch)[:3]
+
+
+def _run_summary(grad_fn, state, batch, mesh, losses, step_s,
+                 compile_clock) -> Dict[str, Any]:
+    """What this worker ran on, from the worker itself: device facts,
+    whether the lowered step holds the Pallas kernel (``tpu_custom_call``
+    — absent under the interpreter and under xla attention), how the
+    parameters are laid over the mesh's devices, and per-device memory."""
+    import jax
+
+    from ray_tpu._private.compile_cache import process_facts
+    leaves = jax.tree_util.tree_leaves(state.params)
+    mem = [(d.memory_stats() or {}).get("bytes_in_use")
+           for d in mesh.devices.flat]
+    return {
+        **process_facts(compile_clock),
+        "mesh": {k: int(v) for k, v in mesh.shape.items()},
+        "pallas_custom_call":
+            "tpu_custom_call" in grad_fn.lower(state, batch).as_text(),
+        "losses": list(losses),
+        "step_s": [round(t, 4) for t in step_s],
+        "n_params": len(leaves),
+        "min_devices_per_param": min(
+            len({s.device.id for s in x.addressable_shards})
+            for x in leaves),
+        "partitioned_params": sum(
+            len({str(s.index) for s in x.addressable_shards}) > 1
+            for x in leaves),
+        "bytes_in_use": mem,
+    }
+
+
 def sharded_train_loop(config: Dict[str, Any]):
     """The per-worker gang loop (module-level: workers import it)."""
+    import time
+
     import jax
     import numpy as np
 
     from ray_tpu._private import step_stats
     from ray_tpu.air import session
     from ray_tpu.air.checkpoint import Checkpoint
-    from ray_tpu.models import GPT, get_config
+    from ray_tpu.models import get_config
     from ray_tpu.train.jax_trainer import sync_gradients
     from ray_tpu.train.sharded import layout
-    from ray_tpu.train.step import OptimizerConfig
 
+    from ray_tpu._private.compile_cache import start_compile_clock
+    compile_clock = start_compile_clock()
     cfg: ShardedRunConfig = config["run"]
     rank = session.get_world_rank()
     world = session.get_world_size()
@@ -313,7 +362,6 @@ def sharded_train_loop(config: Dict[str, Any]):
                        if jax.process_count() == 1 else None)
     mesh = plan.build_mesh()
     model_cfg = get_config(cfg.model, **cfg.model_overrides)
-    model = GPT(model_cfg, mesh=mesh)
     n_params = model_cfg.num_params()
     flops_per_token = (6 * n_params
                        + 12 * model_cfg.n_layers * model_cfg.d_model
@@ -324,11 +372,7 @@ def sharded_train_loop(config: Dict[str, Any]):
         tokens_per_step=cfg.batch_per_worker * cfg.seq_len)
 
     batch = _synth_batch(cfg, model_cfg.vocab_size, rank, 0)
-    opt = OptimizerConfig(learning_rate=cfg.learning_rate,
-                          warmup_steps=1, decay_steps=max(10, cfg.steps),
-                          optimizer=cfg.optimizer)
-    init_fn, grad_fn, apply_fn, _, _ = make_grad_apply_step(
-        model, mesh, opt, example_batch=batch)
+    init_fn, grad_fn, apply_fn = build_step(cfg, mesh, batch)
     # same init seed on every DP rank: replicas must start identical,
     # divergence is what sync_gradients prevents
     state = init_fn(jax.random.PRNGKey(cfg.seed), batch)
@@ -348,6 +392,9 @@ def sharded_train_loop(config: Dict[str, Any]):
 
     clock = step_stats.step_clock()
     loss = float("nan")
+    summary: Optional[Dict[str, Any]] = None
+    losses: List[float] = []
+    step_s: List[float] = []
     keep_alive: List[Any] = []
     chain: List[int] = list(
         (ckpt.to_dict().get("chain") if ckpt is not None else None) or [])
@@ -358,6 +405,7 @@ def sharded_train_loop(config: Dict[str, Any]):
         if cfg.kv_breadcrumbs:
             _gcs().kv_put(f"shardsteps/{tag}/{rank}/{step}/{os.getpid()}",
                           b"1")
+        t_step = time.perf_counter()
         clock.begin()
         with clock.phase("device_compute"):
             grads, metrics = grad_fn(
@@ -377,12 +425,18 @@ def sharded_train_loop(config: Dict[str, Any]):
         with clock.phase("optimizer"):
             state = apply_fn(state, grads)
         if cfg.step_sleep_s:
-            import time
             time.sleep(cfg.step_sleep_s)
         loss = float(metrics["loss"])
         clock.end()
+        losses.append(loss)
+        step_s.append(time.perf_counter() - t_step)
         out = {"step": step, "loss": loss, "rank": rank,
                "ici_registered": registered}
+        if step == cfg.steps - 1:
+            # the run's facts ride the last report, which is what
+            # Result.metrics keeps: which device really ran the steps
+            summary = out["summary"] = _run_summary(
+                grad_fn, state, batch, mesh, losses, step_s, compile_clock)
         report_ckpt = None
         if (step + 1) % cfg.checkpoint_interval == 0 \
                 or step == cfg.steps - 1:
@@ -396,14 +450,46 @@ def sharded_train_loop(config: Dict[str, Any]):
                     tag=tag, step=step, world=world, chain=chain))
         session.report(out, checkpoint=report_ckpt)
     return {"final_loss": loss, "steps": cfg.steps,
-            "ici_registered": registered}
+            "ici_registered": registered, "summary": summary}
+
+
+def tpu_lease_per_worker(num_workers: int) -> Optional[Dict[str, float]]:
+    """What each gang worker must lease on this cluster so its steps run
+    on the chips: ``{"TPU": <chips of one host>}``, or None when no
+    alive node advertises a TPU (CPU clusters: tests, chipless hosts).
+
+    A worker with no TPU lease is pinned to the CPU backend by the
+    raylet, so on a TPU cluster "no lease" would silently train on the
+    host.  One worker owns ALL chips of its host (libtpu: one process
+    per host), hence more workers than TPU hosts cannot be placed and is
+    an error here, not a hang in the placement group."""
+    import ray_tpu
+
+    hosts = [n["resources"].get("TPU", 0) for n in ray_tpu.nodes()
+             if n["alive"] and n["resources"].get("TPU", 0) > 0]
+    if not hosts:
+        return None
+    if num_workers > len(hosts):
+        raise ValueError(
+            f"ShardedTrainer: num_workers={num_workers} but the cluster "
+            f"has {len(hosts)} TPU host(s).  A TPU worker holds every "
+            "chip of its host until it exits, so several TPU worker "
+            "processes on one host are unsupported "
+            "(docs/train_sharded.md, chip ownership): use one worker "
+            "per host and shard over its chips with ShardingConfig, or "
+            "pass resources_per_worker= to train on the CPU")
+    return {"TPU": float(min(hosts))}
 
 
 class ShardedTrainer:
     """Driver-side front end: a DataParallelTrainer running
     :func:`sharded_train_loop` under a JaxConfig, with the planner's
     config threaded through.  ``fit()`` returns the underlying trainer's
-    Result (gang recovery included)."""
+    Result (gang recovery included).
+
+    ``resources_per_worker=None`` (the default) leases the host's chips
+    for each worker when the cluster has any
+    (:func:`tpu_lease_per_worker`), decided at ``fit()``."""
 
     def __init__(self, run: ShardedRunConfig, *,
                  run_config=None, jax_config=None,
@@ -415,16 +501,19 @@ class ShardedTrainer:
         from ray_tpu.train.jax_trainer import JaxConfig
 
         self.run = run
-        scaling = ScalingConfig(num_workers=run.num_workers,
-                                resources_per_worker=resources_per_worker)
+        self._scaling = ScalingConfig(
+            num_workers=run.num_workers,
+            resources_per_worker=resources_per_worker)
         self._trainer = DataParallelTrainer(
             sharded_train_loop,
             train_loop_config={"run": run, "tag": tag},
-            backend_config=jax_config or JaxConfig(init_distributed=False,
-                                                   platform="cpu"),
-            scaling_config=scaling,
+            backend_config=jax_config or JaxConfig(init_distributed=False),
+            scaling_config=self._scaling,
             run_config=run_config,
             resume_from_checkpoint=resume_from_checkpoint)
 
     def fit(self):
+        if self._scaling.resources_per_worker is None:
+            self._scaling.resources_per_worker = tpu_lease_per_worker(
+                self.run.num_workers)
         return self._trainer.fit()

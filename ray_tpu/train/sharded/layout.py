@@ -33,7 +33,7 @@ import math
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from ray_tpu._private.jax_compat import PartitionSpec
+from jax.sharding import PartitionSpec
 from ray_tpu.parallel.mesh import AXIS_ORDER
 from ray_tpu.parallel.sharding import LOGICAL_RULES, MeshAxes, ShardingRules
 
@@ -177,7 +177,7 @@ class LayoutPlan:
         non-trivial axis (data crosses DCN, fsdp/tensor stay on ICI)."""
         import jax
         import numpy as np
-        from ray_tpu._private.jax_compat import Mesh
+        from jax.sharding import Mesh
         from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
         shape = self.mesh_shape
@@ -308,7 +308,7 @@ def get_mesh(mesh_shape: Optional[Dict[str, int]] = None):
 def _build_named_mesh(shape: Dict[str, int], devices):
     from jax.experimental import mesh_utils
 
-    from ray_tpu._private.jax_compat import Mesh
+    from jax.sharding import Mesh
     names, sizes = list(shape), tuple(shape.values())
     try:
         dev_array = mesh_utils.create_device_mesh(

@@ -27,13 +27,6 @@ class TrainWorker:
     def __init__(self, world_rank: int, world_size: int,
                  local_rank: int = 0, local_world_size: int = 1,
                  node_rank: int = 0):
-        # the deployment image's sitecustomize may force a TPU platform
-        # programmatically; re-assert the caller's JAX_PLATFORMS choice so
-        # CPU-simulated meshes (tests, dry runs) see their virtual devices
-        plat = os.environ.get("JAX_PLATFORMS")
-        if plat:
-            import jax
-            jax.config.update("jax_platforms", plat)
         self.world_rank = world_rank
         self.world_size = world_size
         self.local_rank = local_rank
@@ -71,9 +64,9 @@ class TrainWorker:
         TPU-native replacement for the reference's torch.distributed TCP
         rendezvous (train/torch/config.py:29). Returns local device count."""
         import jax
-        # re-pin the platform: set_env may have changed JAX_PLATFORMS
-        # after __init__ ran (plugin discovery overrides the plain env
-        # var, so the pin must go through jax.config)
+        # set_env may have changed JAX_PLATFORMS after jax was imported
+        # in this process; jax reads the variable only at import, so the
+        # choice goes through jax.config (no backend exists yet)
         plat = os.environ.get("JAX_PLATFORMS", "")
         if plat:
             jax.config.update("jax_platforms", plat)

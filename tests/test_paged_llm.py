@@ -178,9 +178,16 @@ def test_prefill_ahead_ttft_decoupled_from_slot_wait(tiny_parts):
             with lock:
                 results[rid] = r
 
-        threads = [threading.Thread(target=go, args=(0, 40))]
+        # request 0 holds the only slot for 72 tokens (5 of the 8 pool
+        # pages; the three queued requests need one each).  Wait for its
+        # first token, not for a fixed sleep: on a fast box 40 tokens were
+        # done within the old 0.3 s and nobody waited for the slot at all
+        threads = [threading.Thread(target=go, args=(0, 72))]
         threads[0].start()
-        time.sleep(0.3)        # let request 0 occupy the only slot
+        deadline = time.monotonic() + 120
+        while not firsts_seen and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert firsts_seen, "request 0 never produced a token"
         for rid in range(1, 4):
             th = threading.Thread(target=go, args=(rid, 8))
             th.start()

@@ -494,6 +494,51 @@ def test_lock_sanitizer_cross_thread_release_leaves_no_phantom():
         ls.reset()
 
 
+def test_lock_sanitizer_survives_gc_reentry_in_its_own_bookkeeping():
+    """An allocation inside the sanitizer's bookkeeping can run the
+    garbage collector, and a collected object's __del__ may take a
+    sanitized lock ON THE SAME THREAD (ObjectRef.__del__ does).  The
+    hooks must not re-enter their non-reentrant guards: that was a
+    self-deadlock that hung a chaos test for the rest of a tier-1 run."""
+    import threading
+
+    from ray_tpu._private.analysis import lock_sanitizer as ls
+    ls.reset()
+    inner = ls._DebugLock("siteFinalizer.py:1")
+    outer = ls._DebugLock("siteOuter.py:2")
+    real_snapshot = ls._held_snapshot
+    finished = threading.Event()
+
+    def snapshot_with_finalizer(tid=None):
+        # what the collector does mid-bookkeeping: a finalizer takes and
+        # drops a sanitized lock while the guard is held
+        with ls._held_guard:
+            with inner:
+                pass
+        return real_snapshot(tid)
+
+    def run():
+        with outer:
+            ls._held_snapshot = snapshot_with_finalizer
+            try:
+                with ls._DebugLock("siteNext.py:3"):
+                    pass
+            finally:
+                ls._held_snapshot = real_snapshot
+        finished.set()
+
+    try:
+        t = threading.Thread(target=run, daemon=True)
+        t.start()
+        assert finished.wait(10), "sanitizer deadlocked on its own guard"
+        assert ("siteOuter.py:2", "siteNext.py:3") in ls.edges()
+        assert not any("siteFinalizer" in a or "siteFinalizer" in b
+                       for a, b in ls.edges())
+    finally:
+        ls._held_snapshot = real_snapshot
+        ls.reset()
+
+
 def test_lock_sanitizer_install_gates_on_env_and_module(tmp_path,
                                                         monkeypatch):
     """install() wraps only locks created by instrumented files while

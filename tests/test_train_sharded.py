@@ -31,7 +31,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu._private.jax_compat import PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 from ray_tpu.train.sharded import layout
 from ray_tpu.train.sharded.layout import (ShardingConfig, dryrun_plans,
                                           plan)
