@@ -82,14 +82,20 @@ def start_compile_clock() -> dict:
 def process_facts(since: dict) -> dict:
     """What a chip-holding process reports about itself (the train
     loop's summary, ``LLMServer.device_info``): its pid, the device as
-    JAX sees it HERE, and what it compiled since ``since``
+    JAX sees it HERE, the most device memory any local device has held
+    (``peak_bytes_in_use``; None where the backend keeps no such
+    count), and what it compiled since ``since``
     (:func:`start_compile_clock`)."""
     import jax
     devs = jax.devices()
     now = _compile_totals()
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
     return {"pid": os.getpid(),
             "device": {"platform": devs[0].platform,
                        "kind": devs[0].device_kind, "count": len(devs)},
+            "peak_bytes_in_use": max(
+                (p for p in peaks if p is not None), default=None),
             "compile_s": round(now["compile_s"] - since["compile_s"], 2),
             "cache_hits": now["cache_hits"] - since["cache_hits"],
             "cache_misses": now["cache_misses"] - since["cache_misses"]}

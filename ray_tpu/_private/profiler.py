@@ -30,6 +30,23 @@ from typing import Any, Dict, Optional, Tuple
 LEAF_LINES_KEY = "__leaf_lines__"
 
 
+def span(name: str, step_num: Optional[int] = None):
+    """A host span on the ``jax.profiler`` trace's own clock: written
+    into the same ``.xplane.pb`` as the device's operations, so an idle
+    gap of the device can be put down to the program phase over it.  A
+    context manager, inert unless a profiler session is on (well under a
+    microsecond then).  ``set_metadata(**scalars)`` on the entered span
+    attaches a few small arguments, formatted only while tracing.  With
+    ``step_num`` the span is a step marker (``StepTraceAnnotation``).
+    The one place the repo opens a profiler span: ``StepClock``
+    (``train.<phase>``, ``train_step``) and the LLM engine's loop
+    (``engine.<phase>``)."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    if step_num is not None:
+        return StepTraceAnnotation(name, step_num=step_num)
+    return TraceAnnotation(name)
+
+
 def split_leaf_detail(counts: Dict[str, Any]
                       ) -> Tuple[Dict[str, int], Dict[str, Dict[str, int]]]:
     """Split a sample_folded() result into (stack counts, leaf line
@@ -104,7 +121,14 @@ def profile_capture(duration_s: float, *, device: bool = False,
             if out_dir is None:   # only once a trace will actually start
                 import tempfile
                 out_dir = tempfile.mkdtemp(prefix="ray-tpu-devtrace-")
-            jax.profiler.start_trace(out_dir)
+            # the host's view is the folded stacks sampled below; the
+            # trace is for the device and the program's own spans
+            # (span() above), so its per-call python tracer stays off:
+            # it slows every thread of a serving process and makes the
+            # capture several times larger and slower to write
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(out_dir, profiler_options=options)
             started = True
     except Exception as e:
         err = f"device trace failed to start: {e!r}"

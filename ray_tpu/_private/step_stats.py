@@ -61,11 +61,16 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu._private.config import CONFIG
 from ray_tpu._private import runtime_metrics as rtm
+from ray_tpu._private.profiler import span
 
 # canonical phase order: timeline sub-slices stack in this order inside a
 # step, and the goodput ledger reports totals keyed by these names
 PHASES = ("data_wait", "host_dispatch", "device_compute",
-          "grad_allreduce", "optimizer", "checkpoint")
+          "grad_allreduce", "optimizer", "checkpoint",
+          # sharded_train_loop's cut (docs/train_sharded.md): it never
+          # fences, so it records dispatches and the one wait as such
+          "batch", "grad_dispatch", "grad_sync", "apply_dispatch",
+          "loss_fetch", "report")
 
 # ms-scale steps need sub-ms resolution at the low end (a healthy
 # data_wait is tens of microseconds) while checkpoint phases reach tens
@@ -355,17 +360,20 @@ def _events_buffer():
 
 # ------------------------------------------------------------ step clock
 class _PhaseCtx:
-    __slots__ = ("_clock", "_name", "_t0")
+    __slots__ = ("_clock", "_name", "_t0", "_span")
 
     def __init__(self, clock: "StepClock", name: str):
         self._clock = clock
         self._name = name
+        self._span = span("train." + name)
 
     def __enter__(self):
         self._t0 = rtm.now()
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._span.__exit__(*exc)
         self._clock.record_phase(self._name,
                                  (rtm.now() - self._t0) * 1000.0)
         return False
@@ -382,13 +390,19 @@ class StepClock:
     caller fencing device work (``jax.block_until_ready`` inside the
     ``device_compute`` phase — the bench.py discipline); an unfenced
     dispatch attributes device time to whichever phase next blocks on
-    the device queue."""
+    the device queue.
+
+    Each phase is also a span ``train.<name>``, and each step a step
+    marker ``train_step``, on the ``jax.profiler`` trace's clock
+    (``_private/profiler.py span``: inert unless a trace is on), so a
+    device trace of the loop shows which phase the host was in."""
 
     def __init__(self, run: _RunContext):
         self._run = run
         self._open = False
         self._t_begin = 0.0
         self._phases: Dict[str, float] = {}
+        self._step_span = None
 
     # -- step lifecycle ----------------------------------------------------
     def begin(self) -> "StepClock":
@@ -397,6 +411,8 @@ class StepClock:
         self._open = True
         self._t_begin = rtm.now()
         self._phases = {}
+        self._step_span = span("train_step", step_num=self._run.step_no)
+        self._step_span.__enter__()
         return self
 
     def phase(self, name: str) -> _PhaseCtx:
@@ -416,6 +432,7 @@ class StepClock:
         if not self._open:
             return None
         self._open = False
+        self._step_span.__exit__(None, None, None)
         step_ms = (rtm.now() - self._t_begin) * 1000.0
         run = self._run
         step = run.step_no

@@ -65,6 +65,26 @@ class TransformerConfig:
         norms = 2 * self.d_model
         return emb + self.n_layers * (attn + mlp + norms) + self.d_model
 
+    def train_flops_per_token(self, seq_len: int) -> float:
+        """Operations a training step REQUIRES per token — the count
+        model-FLOPs utilization is taken over (the goodput ledger's
+        ``mfu``; ``chipbench/lib/flops.py`` counts the same way from the
+        published sizes, and a test pins the two together).  Counted:
+        every matrix multiplication of the blocks and the output head,
+        forward and backward (6 per parameter per token; an MoE block
+        runs ``moe_top_k`` experts a token), and causal attention once
+        (``QK^T`` and ``PV``: the masked half is not work the algorithm
+        needs).  Not counted: the embedding gather, recomputation under
+        remat, norms, rotary and softmax elementwise work."""
+        attn = self.d_model * self.head_dim * (
+            self.n_heads * 2 + self.n_kv_heads * 2)
+        mlp = 3 * self.d_model * self.d_ff * (
+            self.moe_top_k if self.moe_experts else 1)
+        head = self.vocab_size * self.d_model
+        attention = 6 * seq_len * self.n_heads * self.head_dim \
+            * self.n_layers
+        return 6.0 * (self.n_layers * (attn + mlp) + head) + attention
+
 
 PRESETS = {
     # test-size

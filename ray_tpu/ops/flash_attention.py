@@ -116,6 +116,7 @@ def _fwd(q3, k3, v3, causal: bool, sm_scale: float,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
     return o, lse
 
@@ -232,6 +233,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse, delta)
 
     kspec = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))
@@ -246,6 +248,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 

@@ -170,6 +170,7 @@ def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, heads, hd2), q2.dtype),
+        name="paged_attention_decode",
     )(block_tables, lengths, q2, kv_pages)
 
 

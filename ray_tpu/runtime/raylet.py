@@ -897,7 +897,11 @@ class Raylet:
             fwd = {"duration": duration}
             if "device" in p:   # gang/device capture passes through
                 fwd["device"] = bool(p.get("device"))
-            return h.conn.call("profile", fwd, timeout=duration + 30)
+            # stopping a device trace and writing it out has taken 30 s
+            # for a 6 s window of a busy serve replica (PERF.md, PR 24)
+            return h.conn.call(
+                "profile", fwd,
+                timeout=duration + (90 if fwd.get("device") else 30))
         from ray_tpu._private.profiler import sample_folded
         return sample_folded(duration)
 

@@ -542,7 +542,10 @@ def cmd_debug(args) -> None:
 def cmd_profile(args) -> None:
     """Flame-sample a live cluster process (reference `ray stack`/py-spy
     reporter path): GCS by default, a raylet with --node, one of its
-    workers with --worker.  `--group <name>` gang-fans-out instead:
+    workers with --worker (with --device that worker, e.g. a serve
+    replica, also captures a jax.profiler trace of the window: the
+    engine's spans and named programs on the device's clock).
+    `--group <name>` gang-fans-out instead:
     every rank of the named training run captures the SAME time window
     (folded host stacks always; a jax.profiler device trace with
     --device, TPU only — on a CPU-only box each rank reports the
@@ -558,8 +561,10 @@ def cmd_profile(args) -> None:
 
     if args.worker and not args.node:
         sys.exit("--worker requires --node (the worker's raylet)")
-    if args.device and not args.group:
-        sys.exit("--device requires --group (gang device capture)")
+    if args.device and not (args.group or args.worker):
+        sys.exit("--device needs the process that holds the chip: "
+                 "--group (a training gang) or --node with --worker "
+                 "(e.g. a serve replica)")
     addr = _resolve_address(args)
     host, port = addr.rsplit(":", 1)
     gcs = GcsClient((host, int(port)))
@@ -574,13 +579,25 @@ def cmd_profile(args) -> None:
             if node is None:
                 sys.exit(f"no alive node matching {args.node!r}")
             conn = rpc.connect(tuple(node["address"]), timeout=5.0)
+            req = {"duration": args.duration, "worker_id": args.worker}
+            if args.device:
+                req["device"] = True
             try:
-                counts = conn.call("profile",
-                                   {"duration": args.duration,
-                                    "worker_id": args.worker},
-                                   timeout=args.duration + 40)
+                counts = conn.call(
+                    "profile", req,
+                    timeout=args.duration + (100 if args.device else 40))
             finally:
                 conn.close()
+            if args.device:
+                # the capture dict: host stacks plus a jax.profiler
+                # trace of the same window, which holds the program's
+                # own spans (engine.*, train.*) and named programs
+                if counts.get("device_trace"):
+                    print(f"device trace at {counts['device_trace']} "
+                          "(on the worker's host)")
+                else:
+                    print(counts.get("device_error"), file=sys.stderr)
+                counts = counts["folded"]
         else:
             counts = gcs.call("profile", {"duration": args.duration},
                               timeout=args.duration + 40)
@@ -993,9 +1010,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "same window on EVERY rank and merge into one "
                          "Perfetto trace keyed by rank")
     sp.add_argument("--device", action="store_true",
-                    help="(--group) also capture a jax.profiler device "
-                         "trace per rank (TPU only; CPU-only boxes "
-                         "report the caveat and ship host stacks)")
+                    help="(--group, or --node with --worker) also "
+                         "capture a jax.profiler device trace in each "
+                         "process (TPU only; CPU-only boxes report the "
+                         "caveat and ship host stacks)")
     sp.add_argument("--duration", type=float, default=2.0)
     sp.add_argument("-o", "--output",
                     help="write folded stacks (.folded) or the merged "

@@ -257,9 +257,20 @@ class LLMServer:
             "tokens": result.tokens,
             "finish_reason": result.finish_reason,
             "prompt_len": result.prompt_len,
-            "time_to_first_token_s": result.time_to_first_token_s,
-            "latency_s": result.latency_s,
+            **self._timing(result),
         }
+
+    @staticmethod
+    def _timing(result) -> Dict[str, float]:
+        """The engine's own clock on one request, as the reply carries
+        it: ``queue_wait_s + prefill_s == time_to_first_token_s``;
+        ``slot_wait_s`` is the paged engine's wait for a decode slot
+        after the first token (docs/observability.md)."""
+        return {"time_to_first_token_s": result.time_to_first_token_s,
+                "latency_s": result.latency_s,
+                "queue_wait_s": result.queue_wait_s,
+                "prefill_s": result.prefill_s,
+                "slot_wait_s": result.slot_wait_s}
 
     # ------------------------------------------ disaggregated pool methods
 
@@ -434,8 +445,7 @@ class LLMServer:
                     "finish_reason": item.finish_reason,
                     "num_tokens": len(item.tokens),
                     "prompt_len": item.prompt_len,
-                    "time_to_first_token_s": item.time_to_first_token_s,
-                    "latency_s": item.latency_s,
+                    **self._timing(item),
                 }
             else:
                 yield {"token": int(item)}

@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
@@ -75,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private.profiler import span
 from ray_tpu.models.configs import TransformerConfig
 from ray_tpu.models.gpt import GPT
 from ray_tpu.serve.frontdoor.prefix import page_digests
@@ -94,6 +96,15 @@ class GenerationResult:
     prompt_len: int
     time_to_first_token_s: float
     latency_s: float
+    # where the time to the first token went, and what came after it:
+    # submit -> admitted (popped from the queue with its pages / into a
+    # wave) -> first token known -> installed in a decode slot.
+    # queue_wait_s + prefill_s == time_to_first_token_s; slot_wait_s is
+    # paged mode's wait of a prefilled request for a slot (inside the
+    # gap between its first and second token), 0 in dense mode
+    queue_wait_s: float = 0.0
+    prefill_s: float = 0.0
+    slot_wait_s: float = 0.0
 
 
 # re-exported here for engine-local users; defined in ray_tpu.exceptions
@@ -153,6 +164,7 @@ class _Request:
     deliver: Callable[[bool, Any], None]
     on_token: Optional[Callable[[int], None]]
     submitted_at: float = dataclasses.field(default_factory=time.monotonic)
+    admitted_at: Optional[float] = None   # left the queue (loop thread)
     delivered: bool = False
     export: bool = False                  # deliver a PrefillHandoff
     # chained page-boundary digests of the prompt (frontdoor/prefix.py),
@@ -173,7 +185,8 @@ class _Import:
 
 class _Slot:
     __slots__ = ("request", "pos", "out", "last_token", "first_token_at",
-                 "pages", "prompt_len", "borrowed", "prefix_entry")
+                 "installed_at", "pages", "prompt_len", "borrowed",
+                 "prefix_entry")
 
     def __init__(self, request: _Request, prompt_len: int, first_token: int,
                  pages: Optional[List[int]] = None,
@@ -183,6 +196,7 @@ class _Slot:
         self.out = [first_token]
         self.last_token = first_token
         self.first_token_at = time.monotonic()
+        self.installed_at: Optional[float] = None   # took a decode slot
         self.pages = pages or []         # paged mode: physical pages owned
         self.prompt_len = prompt_len
         # prefix-cache hit bookkeeping: the first ``borrowed`` entries of
@@ -217,10 +231,13 @@ class _Prefilled:
 
 
 class EngineStats:
-    """Occupancy / throughput counters, read by benchmarks and /stats."""
+    """Occupancy / throughput counters and the loop thread's time
+    accounts, read by benchmarks and /stats.  All cumulative: the
+    difference of two snapshots is the window's."""
 
     def __init__(self):
         self.steps = 0                   # decode steps executed (N-wide)
+        self.quanta = 0                  # decode blocks fetched
         self.step_tokens = 0             # tokens delivered from steps
         self.tokens_generated = 0        # + prefill first tokens
         self.prefills = 0
@@ -232,6 +249,18 @@ class EngineStats:
         self.prefix_misses = 0           # cache enabled but no usable match
         self.prefix_tokens_saved = 0     # prompt tokens NOT re-prefilled
         self.prefix_evictions = 0        # retained runs evicted (LRU/space)
+        self.prefill_waves = 0           # prefill programs dispatched
+        self.prefill_prompt_tokens = 0   # real prompt tokens in them
+        self.prefill_padded_tokens = 0   # wave x bucket: what they computed
+        # seconds of the loop thread, advanced at each phase's end
+        # (_Phase): loop_s is its whole life, the rest are parts of it.
+        # 1 - fetch_wait_s / (loop_s - idle_wait_s) is the share of its
+        # working time the host was NOT waiting for the device
+        self.loop_s = 0.0
+        self.idle_wait_s = 0.0           # blocked: nothing to do
+        self.fetch_wait_s = 0.0          # blocked: device -> host fetches
+        self.deliver_s = 0.0             # per-token bookkeeping, callbacks
+        self._loop_mark: Optional[float] = None
 
     def occupancy(self, num_slots: int) -> float:
         """Fraction of step-slots that produced a delivered token (junk
@@ -253,7 +282,47 @@ class EngineStats:
             "prefix_misses": self.prefix_misses,
             "prefix_tokens_saved": self.prefix_tokens_saved,
             "prefix_evictions": self.prefix_evictions,
+            "quanta": self.quanta,
+            "prefill_waves": self.prefill_waves,
+            "prefill_prompt_tokens": self.prefill_prompt_tokens,
+            "prefill_padded_tokens": self.prefill_padded_tokens,
+            "loop_s": self.loop_s,
+            "idle_wait_s": self.idle_wait_s,
+            "fetch_wait_s": self.fetch_wait_s,
+            "deliver_s": self.deliver_s,
         }
+
+
+class _Phase:
+    """One leaf phase of the engine loop: a span ``engine.<name>`` on the
+    profiler's clock (``_private/profiler.py span``, inert unless a
+    trace is on) and, where ``account`` names one of EngineStats' time
+    accounts, its wall time added there.  Phases do not nest, so an
+    idle gap of the device has one phase over it.  Entering returns the
+    span: ``set_metadata`` takes what is known only at the end."""
+
+    __slots__ = ("_stats", "_account", "_span", "_t0")
+
+    def __init__(self, stats: EngineStats, name: str,
+                 account: Optional[str] = None):
+        self._stats = stats
+        self._account = account
+        self._span = span("engine." + name)
+
+    def __enter__(self):
+        self._t0 = time.monotonic()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        st, now = self._stats, time.monotonic()
+        if st._loop_mark is not None:
+            st.loop_s += now - st._loop_mark
+            st._loop_mark = now
+        if self._account is not None:
+            setattr(st, self._account,
+                    getattr(st, self._account) + now - self._t0)
+        return False
 
 
 class LLMEngine:
@@ -376,8 +445,7 @@ class LLMEngine:
                 (leaf.shape[0] if leaf.ndim == 5 else 1)
                 for leaf in jax.tree.leaves(self._cache)
                 if self._is_pool_leaf(leaf))
-            self._block_jit = jax.jit(self._block_fn_paged,
-                                      donate_argnums=(1, 2))
+            block_fn = self._block_fn_paged
             # prompt-prefix page cache (docs/serve_frontdoor.md):
             # retained full prompt pages stay OUT of _free_pages, keyed
             # by their chained token digests; hits borrow them read-only
@@ -405,8 +473,15 @@ class LLMEngine:
             self.prefix_cache_pages = 0
             self._no_admit = (jnp.asarray(no_meta),
                               jnp.zeros((num_slots,), jnp.int32))
-            self._block_jit = jax.jit(self._block_fn,
-                                      donate_argnums=(1, 2))
+            block_fn = self._block_fn
+
+        # every jitted function of the engine is named engine_<what>:
+        # a profiler trace shows its program as jit_<name> on the
+        # device's ``XLA Modules`` line (docs/observability.md)
+        def engine_decode_block(*args):
+            return block_fn(*args)
+        self._block_jit = jax.jit(engine_decode_block,
+                                  donate_argnums=(1, 2))
 
     # ------------------------------------------------------------ jit fns
 
@@ -435,7 +510,7 @@ class LLMEngine:
     def _get_prefill(self, bucket: int, wave: int):
         fn = self._prefill_jit.get((bucket, wave))
         if fn is None:
-            def prefill(params, packed, rng):
+            def engine_prefill(params, packed, rng):
                 # packed [wave, bucket+3]: right-padded prompt tokens,
                 # then s_real, slot, temp*1e6 (single upload).  Per-row
                 # last REAL logit selected by s_real; first tokens
@@ -454,13 +529,14 @@ class LLMEngine:
                     logits, (s_reals - 1)[:, None, None], axis=1)[:, 0]
                 first = self._sample_fn(rng, last, temps)
                 return first, mut["cache"], slots
-            fn = self._prefill_jit[(bucket, wave)] = jax.jit(prefill)
+            fn = self._prefill_jit[(bucket, wave)] = jax.jit(
+                engine_prefill)
         return fn
 
     def _get_insert(self, bucket: int, wave: int):
         fn = self._insert_jit.get((bucket, wave))
         if fn is None:
-            def insert(cache, pre, slots):
+            def engine_insert(cache, pre, slots):
                 # scatter each prefilled row's first `bucket` positions
                 # into its slot; padded rows carry slot == num_slots
                 # (the scratch row)
@@ -484,7 +560,7 @@ class LLMEngine:
                     return g
                 return jax.tree.map(leaf, cache, pre)
             fn = self._insert_jit[(bucket, wave)] = jax.jit(
-                insert, donate_argnums=(0,))
+                engine_insert, donate_argnums=(0,))
         return fn
 
     def _block_fn(self, params, cache, state, admit_meta, a_firsts):
@@ -528,7 +604,7 @@ class LLMEngine:
         Donates the pool cache (it chains through every engine call)."""
         fn = self._prefill_jit.get((bucket, wave))
         if fn is None:
-            def prefill(params, cache, packed, tables, rng):
+            def engine_prefill(params, cache, packed, tables, rng):
                 # packed [wave, bucket+2]: prompt tokens | s_real | temp*1e6
                 tokens = packed[:, :bucket]
                 s_reals = packed[:, bucket]
@@ -543,7 +619,7 @@ class LLMEngine:
                 first = self._sample_fn(rng, last, temps)
                 return first, mut["cache"]
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
-                prefill, donate_argnums=(1,))
+                engine_prefill, donate_argnums=(1,))
         return fn
 
     def _get_prefill_suffix(self, bucket: int, wave: int):
@@ -555,7 +631,8 @@ class LLMEngine:
         the borrow count, offsets are page-aligned by construction)."""
         fn = self._suffix_jit.get((bucket, wave))
         if fn is None:
-            def prefill(params, cache, packed, tables, offs, rng):
+            def engine_prefill_suffix(params, cache, packed, tables, offs,
+                                      rng):
                 # packed [wave, bucket+2]: suffix tokens|s_real|temp*1e6
                 tokens = packed[:, :bucket]
                 s_reals = packed[:, bucket]
@@ -571,7 +648,7 @@ class LLMEngine:
                 first = self._sample_fn(rng, last, temps)
                 return first, mut["cache"]
             fn = self._suffix_jit[(bucket, wave)] = jax.jit(
-                prefill, donate_argnums=(1,))
+                engine_prefill_suffix, donate_argnums=(1,))
         return fn
 
     def _is_pool_leaf(self, leaf) -> bool:
@@ -601,7 +678,7 @@ class LLMEngine:
         order, before any later dispatch can recycle the pages."""
         fn = self._export_jit.get((bucket, wave))
         if fn is None:
-            def gather(cache, idx):
+            def engine_kv_export(cache, idx):
                 flat = idx.reshape(-1)                  # [wave*bucket]
                 parts = []
                 for leaf in jax.tree.leaves(cache):
@@ -619,7 +696,8 @@ class LLMEngine:
                                       + tuple(leaf.shape[-3:]))
                     parts.append(g)
                 return jnp.concatenate(parts, axis=1)
-            fn = self._export_jit[(bucket, wave)] = jax.jit(gather)
+            fn = self._export_jit[(bucket, wave)] = jax.jit(
+                engine_kv_export)
         return fn
 
     def _get_import(self, bucket: int, wave: int):
@@ -629,7 +707,7 @@ class LLMEngine:
         the cache like every other engine cache transform."""
         fn = self._import_jit.get((bucket, wave))
         if fn is None:
-            def scatter(cache, kv, idx):
+            def engine_kv_import(cache, kv, idx):
                 # kv [wave, ltot, bucket, kvh, ps, 2hd]; idx [wave, bucket]
                 flat = idx.reshape(-1)
                 leaves, treedef = jax.tree_util.tree_flatten(cache)
@@ -654,7 +732,7 @@ class LLMEngine:
                         off += 1
                 return jax.tree_util.tree_unflatten(treedef, out)
             fn = self._import_jit[(bucket, wave)] = jax.jit(
-                scatter, donate_argnums=(0,))
+                engine_kv_import, donate_argnums=(0,))
         return fn
 
     def _block_fn_paged(self, params, cache, state, admit_meta,
@@ -929,8 +1007,8 @@ class LLMEngine:
                         f"{self.kv_pool_pages - 1}")
                 self._imports.append(imp)
                 if self._thread is None or not self._thread.is_alive():
-                    self._thread = threading.Thread(target=self._loop,
-                                                    daemon=True)
+                    self._thread = threading.Thread(
+                        target=self._loop, daemon=True, name="llm-engine")
                     self._thread.start()
                 self._lock.notify()
 
@@ -1034,10 +1112,13 @@ class LLMEngine:
                 raise RuntimeError("engine closed")
             self._pending.append(req)
             if self._thread is None or not self._thread.is_alive():
-                self._thread = threading.Thread(target=self._loop,
-                                                daemon=True)
+                self._thread = threading.Thread(
+                    target=self._loop, daemon=True, name="llm-engine")
                 self._thread.start()
             self._lock.notify()
+
+    def _phase(self, name: str, account: Optional[str] = None) -> _Phase:
+        return _Phase(self.stats, name, account)
 
     def _bucket(self, n: int) -> int:
         b = self._min_bucket
@@ -1083,12 +1164,22 @@ class LLMEngine:
             self.params, jnp.asarray(packed), self._next_key())
         self._cache = self._get_insert(bucket, wave)(
             self._cache, pre_cache, slots)
-        self.stats.prefills += len(group)
+        self._count_prefill_wave(
+            len(group), sum(len(req.prompt) for req, _ in group),
+            wave * bucket)
         return firsts[:len(group)]
+
+    def _count_prefill_wave(self, requests: int, prompt_tokens: int,
+                            padded_tokens: int) -> None:
+        self.stats.prefills += requests
+        self.stats.prefill_waves += 1
+        self.stats.prefill_prompt_tokens += prompt_tokens
+        self.stats.prefill_padded_tokens += padded_tokens
 
     def _finish_admit(self, req: _Request, slot: int, first: int):
         self.stats.tokens_generated += 1
         sl = _Slot(req, len(req.prompt), first)
+        sl.installed_at = sl.first_token_at   # dense: admitted to a slot
         self._slots[slot] = sl
         if req.on_token is not None:
             self._safe_on_token(req, first)
@@ -1129,11 +1220,18 @@ class LLMEngine:
     def _deliver_result(self, sl: _Slot, reason: str) -> None:
         req = sl.request
         now = time.monotonic()
+        admitted_at = req.admitted_at or req.submitted_at
+        queue_wait_s = admitted_at - req.submitted_at
+        prefill_s = sl.first_token_at - admitted_at
         result = GenerationResult(
             tokens=sl.out, finish_reason=reason,
             prompt_len=sl.pos - len(sl.out) + 1,
-            time_to_first_token_s=sl.first_token_at - req.submitted_at,
-            latency_s=now - req.submitted_at)
+            # the sum, so that the two parts add up to it exactly
+            time_to_first_token_s=queue_wait_s + prefill_s,
+            latency_s=now - req.submitted_at,
+            queue_wait_s=queue_wait_s, prefill_s=prefill_s,
+            slot_wait_s=(0.0 if sl.installed_at is None
+                         else sl.installed_at - sl.first_token_at))
         self.stats.requests_completed += 1
         self._safe_deliver(req, True, result)
 
@@ -1157,6 +1255,7 @@ class LLMEngine:
         return True
 
     def _loop(self):
+        self.stats._loop_mark = time.monotonic()
         if self.paged:
             return self._loop_paged()
         # Software-pipelined: quantum k+1 is DISPATCHED before quantum
@@ -1170,7 +1269,8 @@ class LLMEngine:
                 while (not self._closed and not self._pending
                        and all(s is None for s in self._slots)
                        and inflight is None):
-                    self._lock.wait()
+                    with self._phase("wait_work", "idle_wait_s"):
+                        self._lock.wait()
                 if self._closed:
                     victims = ([s.request for s in self._slots
                                 if s is not None]
@@ -1184,9 +1284,14 @@ class LLMEngine:
                             RuntimeError("engine closed"))
                     return
                 admits = []
-                while self._pending and self._free:
-                    admits.append((self._pending.popleft(),
-                                   self._free.pop()))
+                with self._phase("admit") as sp:
+                    now = time.monotonic()
+                    while self._pending and self._free:
+                        req = self._pending.popleft()
+                        req.admitted_at = now
+                        admits.append((req, self._free.pop()))
+                    sp.set_metadata(admitted=len(admits),
+                                    pending=len(self._pending))
             try:
                 nxt = self._dispatch_quantum(admits, inflight)
                 if inflight is not None:
@@ -1220,10 +1325,12 @@ class LLMEngine:
         decoding on device but not yet placed in _slots."""
         admitted = []                      # (req, slot) in firsts order
         firsts_parts = []
-        for bucket, chunk, wave in self._wave_chunks(admits):
-            firsts_parts.append(
-                self._dispatch_admission_wave(chunk, bucket, wave))
-            admitted.extend(chunk)
+        if admits:
+            with self._prefill_phase():
+                for bucket, chunk, wave in self._wave_chunks(admits):
+                    firsts_parts.append(
+                        self._dispatch_admission_wave(chunk, bucket, wave))
+                    admitted.extend(chunk)
 
         rows = [(i, s.request) for i, s in enumerate(self._slots)
                 if s is not None]
@@ -1232,6 +1339,27 @@ class LLMEngine:
         rows += [(slot, req) for req, slot in admitted]
         if not rows:
             return None
+        with self._phase("dispatch_block") as sp:
+            sp.set_metadata(installs=len(admitted), active=len(rows))
+            return self._dispatch_block(admitted, firsts_parts, rows)
+
+    @contextlib.contextmanager
+    def _prefill_phase(self):
+        """The ``dispatch_prefill`` phase, with what it dispatched (the
+        prefill counters' growth inside it) as the span's arguments."""
+        st = self.stats
+        waves, prompt, padded = (st.prefill_waves, st.prefill_prompt_tokens,
+                                 st.prefill_padded_tokens)
+        with self._phase("dispatch_prefill") as sp:
+            yield
+            sp.set_metadata(
+                waves=st.prefill_waves - waves,
+                prompt_tokens=st.prefill_prompt_tokens - prompt,
+                padded_tokens=st.prefill_padded_tokens - padded)
+
+    def _dispatch_block(self, admitted: list, firsts_parts: list,
+                        rows: list):
+        """The dense block dispatch of :meth:`_dispatch_quantum`."""
         # decode state (tokens/positions/temps/rng) is device-chained;
         # the host uploads one packed admit array, cached when empty
         n_admit = len(admitted)
@@ -1255,30 +1383,44 @@ class LLMEngine:
 
     def _process_quantum(self, quantum):
         combined, admitted, rows = quantum
-        host = np.asarray(combined)        # the ONE fetch this quantum
+        with self._phase("fetch_block", "fetch_wait_s"):
+            host = np.asarray(combined)    # the ONE fetch this quantum
         K = self.block_size
         block = host[:self._rows * K].reshape(self._rows, K)
-        self.stats.steps += K
-
         # --- admissions complete (their first tokens are now known) ---
-        for (req, slot), first in zip(admitted, host[self._rows * K:]):
-            self._finish_admit(req, slot, int(first))
-        # --- block processing: truncate junk past each row's finish ---
-        for i, req in rows:
-            sl = self._slots[i]
-            if sl is None or sl.request is not req:
-                continue      # evicted earlier (or reused): junk row
-            for k in range(K):
-                tok = int(block[i, k])
-                sl.out.append(tok)
-                sl.last_token = tok
-                sl.pos += 1
-                self.stats.step_tokens += 1
-                self.stats.tokens_generated += 1
-                if sl.request.on_token is not None:
-                    self._safe_on_token(sl.request, tok)
-                if self._maybe_finish(i):
-                    break     # rest of the row is junk past eos
+        if admitted:
+            with self._phase("deliver_prefill", "deliver_s") as sp:
+                sp.set_metadata(requests=len(admitted))
+                for (req, slot), first in zip(admitted,
+                                              host[self._rows * K:]):
+                    self._finish_admit(req, slot, int(first))
+        self._deliver_block(block, rows)
+
+    def _deliver_block(self, block, rows: list) -> None:
+        """Hand one fetched decode block's tokens to their requests,
+        truncating junk past each row's finish (both loops)."""
+        with self._phase("deliver_block", "deliver_s") as sp:
+            st = self.stats
+            st.steps += self.block_size
+            st.quanta += 1
+            tokens0, done0 = st.step_tokens, st.requests_completed
+            for i, req in rows:
+                sl = self._slots[i]
+                if sl is None or sl.request is not req:
+                    continue      # evicted earlier (or reused): junk row
+                for k in range(self.block_size):
+                    tok = int(block[i, k])
+                    sl.out.append(tok)
+                    sl.last_token = tok
+                    sl.pos += 1
+                    st.step_tokens += 1
+                    st.tokens_generated += 1
+                    if sl.request.on_token is not None:
+                        self._safe_on_token(sl.request, tok)
+                    if self._maybe_finish(i):
+                        break     # rest of the row is junk past eos
+            sp.set_metadata(tokens=st.step_tokens - tokens0,
+                            finished=st.requests_completed - done0)
 
     # ------------------------------------------------- prompt-prefix cache
     #
@@ -1467,7 +1609,8 @@ class LLMEngine:
                        and not self._imports and not self._ready
                        and all(s is None for s in self._slots)
                        and inflight is None):
-                    self._lock.wait()
+                    with self._phase("wait_work", "idle_wait_s"):
+                        self._lock.wait()
                 if self._closed:
                     victims = (
                         [s.request for s in self._slots if s is not None]
@@ -1481,57 +1624,67 @@ class LLMEngine:
                         self._safe_deliver(
                             req, False, RuntimeError("engine closed"))
                     return
-                # imports first (a decode-pool engine's whole intake is
-                # handoffs), FIFO like pending prefills: the head waits
-                # for pages, nothing bypasses it (no starvation), and
-                # pages always free as resident streams complete — the
-                # queue-full rejection happens synchronously at submit
-                import_todo = []
-                while self._imports:
-                    short = self._imports[0].need - len(self._free_pages)
-                    if short > 0:
-                        # idle retained prefixes must not wedge the
-                        # FIFO head: the cache yields before admission
-                        self._prefix_reclaim(short)
-                    if self._imports[0].need > len(self._free_pages):
-                        break
-                    imp = self._imports.popleft()
-                    pages = [self._free_pages.pop()
-                             for _ in range(imp.need)]
-                    import_todo.append((imp, pages))
-                todo = []
-                hits = []
-                oversized = []
-                while self._pending:
-                    need = self._pages_needed(self._pending[0])
-                    if need > self.kv_pool_pages - 1:
-                        # can never fit: fail it rather than spin forever
-                        oversized.append(self._pending.popleft())
-                        continue
-                    hit = self._prefix_lookup(self._pending[0])
-                    fresh = need - (hit[1] if hit else 0)
-                    if fresh > len(self._free_pages):
-                        self._prefix_reclaim(
-                            fresh - len(self._free_pages))
-                    if fresh > len(self._free_pages):
+                with self._phase("admit") as sp:
+                    now = time.monotonic()
+                    # imports first (a decode-pool engine's whole intake
+                    # is handoffs), FIFO like pending prefills: the head
+                    # waits for pages, nothing bypasses it (no
+                    # starvation), and pages always free as resident
+                    # streams complete — the queue-full rejection happens
+                    # synchronously at submit
+                    import_todo = []
+                    while self._imports:
+                        short = (self._imports[0].need
+                                 - len(self._free_pages))
+                        if short > 0:
+                            # idle retained prefixes must not wedge the
+                            # FIFO head: the cache yields before admission
+                            self._prefix_reclaim(short)
+                        if self._imports[0].need > len(self._free_pages):
+                            break
+                        imp = self._imports.popleft()
+                        imp.request.admitted_at = now
+                        pages = [self._free_pages.pop()
+                                 for _ in range(imp.need)]
+                        import_todo.append((imp, pages))
+                    todo = []
+                    hits = []
+                    oversized = []
+                    while self._pending:
+                        need = self._pages_needed(self._pending[0])
+                        if need > self.kv_pool_pages - 1:
+                            # can never fit: fail it, do not spin forever
+                            oversized.append(self._pending.popleft())
+                            continue
+                        hit = self._prefix_lookup(self._pending[0])
+                        fresh = need - (hit[1] if hit else 0)
+                        if fresh > len(self._free_pages):
+                            self._prefix_reclaim(
+                                fresh - len(self._free_pages))
+                        if fresh > len(self._free_pages):
+                            if hit is not None:
+                                with self._prefix_lock:
+                                    hit[0].refs -= 1
+                            break          # FIFO: no bypass, no starvation
+                        req = self._pending.popleft()
+                        req.admitted_at = now
+                        pages = [self._free_pages.pop()
+                                 for _ in range(fresh)]
                         if hit is not None:
-                            with self._prefix_lock:
-                                hit[0].refs -= 1
-                        break          # FIFO: no bypass, no starvation
-                    req = self._pending.popleft()
-                    pages = [self._free_pages.pop()
-                             for _ in range(fresh)]
-                    if hit is not None:
-                        entry, cover = hit
-                        self.stats.prefix_hits += 1
-                        self.stats.prefix_tokens_saved += \
-                            cover * self.page_size
-                        hits.append((req, entry.pages[:cover] + pages,
-                                     cover, entry))
-                    else:
-                        if self.prefix_cache_pages and req.digests:
-                            self.stats.prefix_misses += 1
-                        todo.append((req, pages))
+                            entry, cover = hit
+                            self.stats.prefix_hits += 1
+                            self.stats.prefix_tokens_saved += \
+                                cover * self.page_size
+                            hits.append((req, entry.pages[:cover] + pages,
+                                         cover, entry))
+                        else:
+                            if self.prefix_cache_pages and req.digests:
+                                self.stats.prefix_misses += 1
+                            todo.append((req, pages))
+                    sp.set_metadata(
+                        admitted=len(todo) + len(hits) + len(import_todo),
+                        pending=len(self._pending),
+                        free_pages=len(self._free_pages))
             for req in oversized:
                 self._safe_deliver(req, False, ValueError(
                     f"request needs {self._pages_needed(req)} KV pages; "
@@ -1541,19 +1694,31 @@ class LLMEngine:
                 # request can land in a free slot this same iteration,
                 # and the block step is dispatched after the scatter so
                 # stream order covers its page writes
-                self._dispatch_import_waves(import_todo)
+                if import_todo:
+                    with self._phase("dispatch_import") as sp:
+                        sp.set_metadata(requests=len(import_todo))
+                        self._dispatch_import_waves(import_todo)
                 with self._lock:
                     installs = []
                     while self._free and self._ready:
                         installs.append((self._ready.popleft(),
                                          self._free.pop()))
-                new_prefills = (self._dispatch_prefill_waves(todo)
-                                + self._dispatch_suffix_waves(hits))
-                nxt = self._dispatch_block_paged(installs)
+                new_prefills = []
+                if todo or hits:
+                    with self._prefill_phase():
+                        new_prefills = (self._dispatch_prefill_waves(todo)
+                                        + self._dispatch_suffix_waves(hits))
+                with self._phase("dispatch_block") as sp:
+                    nxt = self._dispatch_block_paged(installs)
+                    sp.set_metadata(installs=len(installs),
+                                    active=len(nxt[1]) if nxt else 0)
                 if inflight is not None:
                     self._process_block_paged(inflight)
-                self._process_exports(
-                    self._process_prefill_waves(new_prefills))
+                exports = self._process_prefill_waves(new_prefills)
+                if exports:
+                    with self._phase("export") as sp:
+                        sp.set_metadata(requests=len(exports))
+                        self._process_exports(exports)
                 inflight = nxt
             except Exception as e:   # engine-fatal (OOM, compile error)
                 with self._lock:
@@ -1601,7 +1766,9 @@ class LLMEngine:
                 bucket, wave)(self.params, self._cache,
                               jnp.asarray(packed),
                               jnp.asarray(tables), self._next_key())
-            self.stats.prefills += len(chunk)
+            self._count_prefill_wave(
+                len(chunk), sum(len(req.prompt) for req, _ in chunk),
+                wave * bucket)
             out.append((firsts, metas))
         return out
 
@@ -1641,7 +1808,9 @@ class LLMEngine:
                                   jnp.asarray(packed),
                                   jnp.asarray(tables),
                                   jnp.asarray(offs), self._next_key())
-                self.stats.prefills += len(chunk)
+                self._count_prefill_wave(
+                    len(chunk), int(packed[:len(chunk), bucket].sum()),
+                    wave * bucket)
                 out.append((firsts, metas))
         return out
 
@@ -1654,17 +1823,20 @@ class LLMEngine:
         _process_exports."""
         if not waves:
             return []
-        if len(waves) == 1:
-            host = np.asarray(waves[0][0])
-        else:
-            host = np.asarray(jnp.concatenate([f for f, _ in waves]))
+        with self._phase("fetch_prefill", "fetch_wait_s"):
+            if len(waves) == 1:
+                host = np.asarray(waves[0][0])
+            else:
+                host = np.asarray(jnp.concatenate([f for f, _ in waves]))
         off = 0
         exports = []
-        for firsts, metas in waves:
-            n = firsts.shape[0]
-            exports.extend(self._complete_prefills(metas,
-                                                   host[off:off + n]))
-            off += n
+        with self._phase("deliver_prefill", "deliver_s") as sp:
+            for firsts, metas in waves:
+                n = firsts.shape[0]
+                exports.extend(self._complete_prefills(metas,
+                                                       host[off:off + n]))
+                off += n
+            sp.set_metadata(requests=sum(len(m) for _, m in waves))
         return exports
 
     def _complete_prefills(self, metas, host) -> list:
@@ -1813,8 +1985,10 @@ class LLMEngine:
         lasts = np.zeros((A,), np.int32)
         tables = np.zeros((A, self.max_pages), np.int32)
         n = 0
+        now = time.monotonic()
         for pf, slot in installs:
             sl = pf.slot_state
+            sl.installed_at = now
             self._slots[slot] = sl
             self._stale_slots.discard(slot)   # reuse doubles as redirect
             meta[0, n] = slot
@@ -1840,22 +2014,7 @@ class LLMEngine:
 
     def _process_block_paged(self, quantum) -> None:
         combined, rows = quantum
-        host = np.asarray(combined)        # the ONE fetch this quantum
-        K = self.block_size
-        block = host.reshape(self._rows, K)
-        self.stats.steps += K
-        for i, req in rows:
-            sl = self._slots[i]
-            if sl is None or sl.request is not req:
-                continue      # evicted earlier (or reused): junk row
-            for k in range(K):
-                tok = int(block[i, k])
-                sl.out.append(tok)
-                sl.last_token = tok
-                sl.pos += 1
-                self.stats.step_tokens += 1
-                self.stats.tokens_generated += 1
-                if sl.request.on_token is not None:
-                    self._safe_on_token(sl.request, tok)
-                if self._maybe_finish(i):
-                    break     # rest of the row is junk past eos
+        with self._phase("fetch_block", "fetch_wait_s"):
+            host = np.asarray(combined)    # the ONE fetch this quantum
+        self._deliver_block(host.reshape(self._rows, self.block_size),
+                            rows)

@@ -71,31 +71,33 @@ def make_grad_apply_step(model, mesh, optimizer=None, rules=None,
     if z_loss is None:
         z_loss = getattr(getattr(model, "cfg", None), "z_loss", 0.0)
 
-    def build_state(rng, batch) -> TrainState:
+    # the three functions' names are the programs' names in a profiler
+    # trace (``jit_train_grad`` on the device's ``XLA Modules`` line)
+    def train_init(rng, batch) -> TrainState:
         variables = model.init(rng, batch["tokens"][:, :-1])
         return TrainState.create(apply_fn=model.apply,
                                  params=variables["params"], tx=tx)
 
     from jax.sharding import NamedSharding, PartitionSpec
     state_shardings, batch_sharding = trace_state_shardings(
-        build_state, example_batch, mesh, rules, batch_axes=("batch", None))
+        train_init, example_batch, mesh, rules, batch_axes=("batch", None))
     param_shardings = state_shardings.params
     repl = NamedSharding(mesh, PartitionSpec())
 
-    def grad(state, batch):
+    def train_grad(state, batch):
         (loss, metrics), grads = jax.value_and_grad(
             lambda p: loss_fn(state.apply_fn, p, batch, z_loss),
             has_aux=True)(state.params)
         return grads, dict(metrics)
 
-    def apply(state, grads):
+    def train_apply(state, grads):
         return state.apply_gradients(grads=grads)
 
-    init_fn = jax.jit(build_state, out_shardings=state_shardings)
-    grad_fn = jax.jit(grad,
+    init_fn = jax.jit(train_init, out_shardings=state_shardings)
+    grad_fn = jax.jit(train_grad,
                       in_shardings=(state_shardings, batch_sharding),
                       out_shardings=(param_shardings, repl))
-    apply_fn = jax.jit(apply,
+    apply_fn = jax.jit(train_apply,
                        in_shardings=(state_shardings, param_shardings),
                        out_shardings=state_shardings,
                        donate_argnums=(0,))
@@ -362,12 +364,8 @@ def sharded_train_loop(config: Dict[str, Any]):
                        if jax.process_count() == 1 else None)
     mesh = plan.build_mesh()
     model_cfg = get_config(cfg.model, **cfg.model_overrides)
-    n_params = model_cfg.num_params()
-    flops_per_token = (6 * n_params
-                       + 12 * model_cfg.n_layers * model_cfg.d_model
-                       * cfg.seq_len)
     step_stats.set_model_info(
-        flops_per_token=flops_per_token,
+        flops_per_token=model_cfg.train_flops_per_token(cfg.seq_len),
         peak_flops=cfg.peak_flops or None,
         tokens_per_step=cfg.batch_per_worker * cfg.seq_len)
 
@@ -406,49 +404,61 @@ def sharded_train_loop(config: Dict[str, Any]):
             _gcs().kv_put(f"shardsteps/{tag}/{rank}/{step}/{os.getpid()}",
                           b"1")
         t_step = time.perf_counter()
+        # Nothing here fences the device: the phases are what the HOST
+        # does, leaves that do not overlap.  The one wait for the device
+        # is ``loss_fetch`` (in a gang also ``grad_sync``, which copies
+        # the gradients to the host); device time per program is in the
+        # profiler's trace (programs ``train_grad`` / ``train_apply``).
         clock.begin()
-        with clock.phase("device_compute"):
-            grads, metrics = grad_fn(
-                state, _synth_batch(cfg, model_cfg.vocab_size, rank, step))
+        with clock.phase("batch"):
+            step_batch = _synth_batch(cfg, model_cfg.vocab_size, rank, step)
+        with clock.phase("grad_dispatch"):
+            grads, metrics = grad_fn(state, step_batch)
+        with clock.phase("grad_sync"):
+            # async: issue the bucketed ring, then prepare the next
+            # batch while it runs (the overlap PendingSync.wait, which
+            # records its blocked part as ``grad_allreduce``, fences)
+            synced = sync_gradients(grads, quantize=cfg.quantize,
+                                    async_op=cfg.async_grad_sync)
         if cfg.async_grad_sync:
-            # issue the bucketed ring while the host prepares the next
-            # batch (the overlap the PendingSync fence accounts for)
-            pending = sync_gradients(grads, quantize=cfg.quantize,
-                                     async_op=True)
-            with clock.phase("host_dispatch"):
+            with clock.phase("batch"):
                 next_batch = _synth_batch(cfg, model_cfg.vocab_size, rank,
                                           step + 1)
                 del next_batch  # prefetch: generation cost is the point
-            grads = pending.wait()
-        else:
-            grads = sync_gradients(grads, quantize=cfg.quantize)
-        with clock.phase("optimizer"):
-            state = apply_fn(state, grads)
+            with clock.phase("grad_sync"):
+                synced = synced.wait()
+        with clock.phase("apply_dispatch"):
+            state = apply_fn(state, synced)
         if cfg.step_sleep_s:
             time.sleep(cfg.step_sleep_s)
-        loss = float(metrics["loss"])
-        clock.end()
+        with clock.phase("loss_fetch"):
+            loss = float(metrics["loss"])
         losses.append(loss)
         step_s.append(time.perf_counter() - t_step)
         out = {"step": step, "loss": loss, "rank": rank,
                "ici_registered": registered}
-        if step == cfg.steps - 1:
-            # the run's facts ride the last report, which is what
-            # Result.metrics keeps: which device really ran the steps
-            summary = out["summary"] = _run_summary(
-                grad_fn, state, batch, mesh, losses, step_s, compile_clock)
         report_ckpt = None
         if (step + 1) % cfg.checkpoint_interval == 0 \
                 or step == cfg.steps - 1:
-            save_sharded_checkpoint(state, tag=tag, step=step, rank=rank,
-                                    world=world, keep_alive=keep_alive)
+            with clock.phase("checkpoint"):
+                save_sharded_checkpoint(state, tag=tag, step=step,
+                                        rank=rank, world=world,
+                                        keep_alive=keep_alive)
             chain.insert(0, step)
             del chain[keep:]
             del keep_alive[:-keep]
             if rank == 0:
                 report_ckpt = Checkpoint.from_dict(make_checkpoint_meta(
                     tag=tag, step=step, world=world, chain=chain))
-        session.report(out, checkpoint=report_ckpt)
+        with clock.phase("report"):
+            if step == cfg.steps - 1:
+                # the run's facts ride the last report, which is what
+                # Result.metrics keeps: which device really ran the steps
+                summary = out["summary"] = _run_summary(
+                    grad_fn, state, batch, mesh, losses, step_s,
+                    compile_clock)
+            session.report(out, checkpoint=report_ckpt)
+        clock.end()
     return {"final_loss": loss, "steps": cfg.steps,
             "ici_registered": registered, "summary": summary}
 

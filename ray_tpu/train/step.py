@@ -239,7 +239,10 @@ def trace_state_shardings(build_state, example_batch, mesh: Mesh,
 def _born_sharded(build_state, step, example_batch, mesh: Mesh,
                   rules: ShardingRules, batch_axes=("batch",)):
     """Shared construction: trace the state abstractly, read logical
-    PartitionSpecs, jit init (born sharded) and step (donated state)."""
+    PartitionSpecs, jit init (born sharded) and step (donated state).
+    The two functions' own names (``train_init``, ``train_step``) are
+    the programs' names in a profiler trace (``jit_<name>`` on the
+    device's ``XLA Modules`` line)."""
     state_shardings, batch_sharding = trace_state_shardings(
         build_state, example_batch, mesh, rules, batch_axes)
     repl = NamedSharding(mesh, PartitionSpec())
@@ -273,7 +276,7 @@ def make_sharded_train(model: nn.Module,
     if z_loss is None:
         z_loss = getattr(getattr(model, "cfg", None), "z_loss", 0.0)
 
-    def build_state(rng, batch) -> TrainState:
+    def train_init(rng, batch) -> TrainState:
         if init_inputs is not None:
             variables = model.init(rng, *init_inputs(batch))
         else:
@@ -281,7 +284,8 @@ def make_sharded_train(model: nn.Module,
         return TrainState.create(apply_fn=model.apply,
                                  params=variables["params"], tx=tx)
 
-    def step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
+    def train_step(state: TrainState, batch
+                   ) -> Tuple[TrainState, Dict[str, Any]]:
         grad_fn = jax.value_and_grad(
             lambda p: loss_fn(state.apply_fn, p, batch, z_loss), has_aux=True)
         (loss, metrics), grads = grad_fn(state.params)
@@ -290,7 +294,7 @@ def make_sharded_train(model: nn.Module,
         metrics["grad_norm"] = optax.global_norm(grads)
         return new_state, metrics
 
-    return _born_sharded(build_state, step, example_batch, mesh, rules,
+    return _born_sharded(train_init, train_step, example_batch, mesh, rules,
                          batch_axes=("batch", None))
 
 
@@ -326,13 +330,13 @@ def make_vision_train(model: nn.Module,
     if example_batch is None:
         raise ValueError("example_batch is required to trace shapes")
 
-    def build_state(rng, batch) -> TrainStateBN:
+    def train_init(rng, batch) -> TrainStateBN:
         variables = model.init(rng, batch["image"])
         return TrainStateBN.create(
             apply_fn=model.apply, params=variables["params"], tx=tx,
             batch_stats=variables.get("batch_stats", {}))
 
-    def step(state: TrainStateBN, batch):
+    def train_step(state: TrainStateBN, batch):
         def lf(p):
             logits, mutated = state.apply_fn(
                 {"params": p, "batch_stats": state.batch_stats},
@@ -350,5 +354,5 @@ def make_vision_train(model: nn.Module,
 
     # batch leaves have mixed rank (image rank-4, label rank-1): shard dim 0
     # only, trailing dims stay unsharded implicitly
-    return _born_sharded(build_state, step, example_batch, mesh, rules,
+    return _born_sharded(train_init, train_step, example_batch, mesh, rules,
                          batch_axes=("batch",))

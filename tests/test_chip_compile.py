@@ -135,6 +135,23 @@ def test_gpt_small_train_step_one_chip(topo, on_tpu):
     apply_fn.lower(state, grads).compile()
 
 
+def test_train_grad_names_its_program_and_flash_kernels(topo, on_tpu):
+    """What a profiler trace finds the step by (ISSUE 24): the program
+    is ``train_grad`` and each of the three Pallas calls carries its own
+    name — a reader matches ``flash_bwd_dkv``, not a result type."""
+    from ray_tpu.train.sharded.layout import ShardingConfig
+
+    _, grad_fn, apply_fn, state, tokens = _train_step_programs(
+        topo.devices[:1], ShardingConfig(), batch=16,
+        overrides={"attention_impl": "flash"})
+    text = grad_fn.lower(state, tokens).as_text()
+    assert "@jit_train_grad" in text
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f'kernel_name = "{name}"' in text
+    grads = jax.eval_shape(grad_fn, state, tokens)[0]
+    assert "@jit_train_apply" in apply_fn.lower(state, grads).as_text()
+
+
 def test_train_step_2x2_mesh_has_collectives(topo, on_tpu):
     """fsdp=2 x tp=2 over the four described chips, default (auto ->
     flash) attention: the Pallas kernel must ride shard_map (GSPMD cannot
@@ -180,4 +197,8 @@ def test_engine_prefill_and_paged_decode_programs(topo, one_chip, on_tpu):
         *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
                  one_chip))
     assert "tpu_custom_call" in block.as_text()
+    # the names a profiler trace shows them by (ISSUE 24)
+    assert 'kernel_name = "paged_attention_decode"' in block.as_text()
+    assert "@jit_engine_decode_block" in block.as_text()
+    assert "@jit_engine_prefill" in prefill.as_text()
     block.compile()
