@@ -9,7 +9,9 @@ TPU-first design notes:
   - attention dispatches to the Pallas flash kernel, plain XLA einsum, or
     ring attention over the mesh's ``context`` axis for long sequences.
   - decode uses a KV cache held in the flax ``cache`` collection
-    (``decode`` is a module attribute, so it stays static under remat/scan).
+    (``decode`` is a module attribute, so it stays static under remat/scan);
+    the paged KV pool is one stacked leaf there, carried through the layer
+    scan and updated in place (never a scanned variable).
 """
 
 from __future__ import annotations
@@ -76,7 +78,7 @@ class MLP(nn.Module):
 def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
                  call_args, *, remat: Optional[bool] = None,
                  cache: bool = False, name: str = "blocks",
-                 n_layers: Optional[int] = None):
+                 n_layers: Optional[int] = None, carry=None):
     """Apply ``n_layers`` (default cfg.n_layers) blocks under the repo's
     standard stacking: remat per cfg.remat (HBM<->FLOPs), one
     ``lax.scan``'d block when cfg.scan_layers (O(1) compile time in
@@ -86,6 +88,14 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
     cfg.remat_layers splits the stack at the CALLER (two stack_layers
     calls, one rematted, one plain) — partial remat for configs with
     HBM headroom between "recompute everything" and "store everything".
+
+    ``carry`` is state that rides the stack beside ``x`` as LOOP-CARRIED
+    state, never as a scanned (sliced-in, stacked-out) variable: the
+    paged KV pool ``[n_layers, pages, ...]``, which every block updates
+    in place at its own layer index.  Blocks are then invoked
+    ``mdl(x, *call_args, carry, layer)`` and return ``(x, carry)``;
+    ``layer`` is a scanned ``arange`` under scan, a Python int unrolled.
+    Returns ``(x, carry)`` when a carry is given.
     """
     if n_layers is None:
         n_layers = cfg.n_layers
@@ -118,18 +128,32 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
         variable_axes = {"params": 0, "intermediates": 0}
         if cache:
             variable_axes["cache"] = 0
-        x, _ = nn.scan(
-            lambda mdl, carry, _: (mdl(carry, *call_args), None),
+        if carry is None:
+            init, layers = x, None
+
+            def body(mdl, x, _):
+                return mdl(x, *call_args), None
+        else:
+            init = (x, carry)
+            layers = jnp.arange(n_layers, dtype=jnp.int32)
+
+            def body(mdl, x_carry, layer):
+                return mdl(x_carry[0], *call_args, x_carry[1], layer), None
+        out, _ = nn.scan(
+            body,
             variable_axes=variable_axes,
             split_rngs={"params": True},
             length=n_layers,
             metadata_params={nn.PARTITION_NAME: None},
-        )(block_cls(cfg, **ctor_kwargs, name=name), x, None)
-    else:
-        for i in range(n_layers):
-            x = block_cls(cfg, **ctor_kwargs,
-                          name=f"{name[:-1]}_{i}")(x, *call_args)
-    return x
+        )(block_cls(cfg, **ctor_kwargs, name=name), init, layers)
+        return out
+    for i in range(n_layers):
+        block = block_cls(cfg, **ctor_kwargs, name=f"{name[:-1]}_{i}")
+        if carry is None:
+            x = block(x, *call_args)
+        else:
+            x, carry = block(x, *call_args, carry, i)
+    return x if carry is None else (x, carry)
 
 
 class Attention(nn.Module):
@@ -137,11 +161,6 @@ class Attention(nn.Module):
     mesh: Optional[Mesh] = None
     rules: ShardingRules = LOGICAL_RULES
     decode: bool = False
-    # paged decode (serve/llm_engine.py paged mode): KV lives in a shared
-    # page pool instead of dense per-row [max_seq] strips.  paged_pages=0
-    # keeps the dense layout.  See ops/paged_attention.py.
-    paged_pages: int = 0
-    page_size: int = 64
     # prefix-cache suffix prefill (serve/llm_engine.py): T > 1 windows
     # may start at nonzero positions over pages already holding a cached
     # prompt prefix, so attention must read back through the pool
@@ -149,7 +168,14 @@ class Attention(nn.Module):
     prefix_attend: bool = False
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions=None, block_tables=None):
+    def __call__(self, x, cos, sin, positions=None, block_tables=None,
+                 pool=None, layer=None):
+        """``pool`` (paged decode, serve/llm_engine.py paged mode): the
+        model's ONE stacked KV page pool ``[layers, pages, kv_heads,
+        page_size, 2*head_dim]`` (GPT declares it; see
+        ops/paged_attention.py) and this block's ``layer`` index into
+        it.  Returns ``(out, pool)`` then: the pool is passed through,
+        updated in place, never sliced."""
         cfg = self.cfg
         h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         q = _dense((h, hd), ("embed", "heads", "head_dim"), "wq",
@@ -161,16 +187,17 @@ class Attention(nn.Module):
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
 
-        if self.decode and self.paged_pages:
-            out = self._decode_attend_paged(q, k, v, positions,
-                                            block_tables)
+        if pool is not None:
+            out, pool = self._decode_attend_paged(q, k, v, positions,
+                                                  block_tables, pool, layer)
         elif self.decode:
             out = self._decode_attend(q, k, v, positions)
         else:
             out = self._train_attend(q, k, v)
         out = out.reshape(*out.shape[:2], h * hd)
-        return _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
-                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
+        out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
+        return out if pool is None else (out, pool)
 
     def _train_attend(self, q, k, v):
         cfg = self.cfg
@@ -273,61 +300,59 @@ class Attention(nn.Module):
         mask = k_idx[None, None, None, :] <= positions[:, None, :, None]
         return xla_attention(q, ck.value, cv.value, causal=False, mask=mask)
 
-    def _decode_attend_paged(self, q, k, v, positions, block_tables):
-        """Paged-pool decode: scatter this call's K/V into the rows' pages,
-        then attend over only the occupied pages (ops/paged_attention.py).
+    def _decode_attend_paged(self, q, k, v, positions, block_tables,
+                             pool, layer):
+        """Paged-pool decode: write this call's K/V into the rows' pages
+        of layer ``layer``, then attend over only the occupied pages
+        (ops/paged_attention.py).  Returns ``(out, pool)``.
+
+        ``pool`` is the whole stacked pool and stays whole: the write is
+        ``rows x T x kv_heads x 2*head_dim`` values at ``[layer, page, :,
+        offset]`` (in place, the buffer being loop-carried and donated),
+        the reads name ``[layer, page]``.  Nothing here may slice a
+        layer out of it — that is a copy of the layer's pool a layer.
 
         ``positions`` [B, T] as in ``_decode_attend``; ``block_tables``
         [B, max_pages] maps each row's logical page (position // page_size)
         to a physical page in the shared pool.  Prompt prefill is the
-        T > 1 case: the window is causal over itself (a prompt attends
-        only to its own prefix), so no pool read is needed — the scatter
-        below is the whole cache interaction, and right-pad garbage past
+        T > 1 case (windows start on a page boundary: write_kv_pages):
+        the window is causal over itself (a prompt attends only to its
+        own prefix), so no pool read is needed — the write below is the
+        whole cache interaction, and right-pad garbage past
         a real prompt is overwritten by decode writes before any length
         mask makes it visible (same invariant as dense slot mode).
         """
         cfg = self.cfg
-        ps = self.page_size
-        # one fused pool, K in [..., :hd], V in [..., hd:]; layout
-        # dictated by TPU tiling (ops/paged_attention.py layout note)
-        pool = (self.paged_pages, cfg.n_kv_heads, ps, 2 * cfg.head_dim)
-        ckv = self.variable("cache", "kv_pages", jnp.zeros, pool,
-                            cfg.dtype)
         if self.is_initializing():
-            return xla_attention(q, k, v, causal=True)
+            return xla_attention(q, k, v, causal=True), pool
         if positions is None or block_tables is None:
             raise ValueError("paged decode requires positions and "
                              "block_tables")
-        pages = jnp.take_along_axis(block_tables, positions // ps, axis=1)
-        offs = positions % ps
-        kv = jnp.concatenate([k, v], axis=-1).astype(cfg.dtype)
-        # advanced indices at dims 0 and 2 -> value layout [B, T, kvh, 2hd]
-        ckv.value = ckv.value.at[pages, :, offs].set(kv)
-        if q.shape[1] > 1:
-            if not self.prefix_attend:
-                return xla_attention(q, k, v, causal=True)
-            # suffix prefill: the window's keys are NOT the whole story —
-            # leading block-table entries hold a cached prompt prefix, so
-            # gather the row's full logical span back out of the pool and
-            # mask by absolute position (key j visible iff j <= query p).
-            # Unallocated table entries point at scratch page 0, whose
-            # garbage sits past every real query position.  Offset-0
-            # windows reduce to the causal case (their own keys were just
-            # scattered), so this path is correct for any offset.
-            b = q.shape[0]
-            gathered = ckv.value[block_tables]   # [B, mp, kvh, ps, 2hd]
-            kvfull = jnp.moveaxis(gathered, 3, 2).reshape(
-                b, -1, cfg.n_kv_heads, 2 * cfg.head_dim)
-            k_idx = jnp.arange(kvfull.shape[1])
-            mask = k_idx[None, None, None, :] <= \
-                positions[:, None, :, None]
-            return xla_attention(q, kvfull[..., :cfg.head_dim],
-                                 kvfull[..., cfg.head_dim:],
-                                 causal=False, mask=mask)
-        from ray_tpu.ops.paged_attention import paged_attention
-        out = paged_attention(q[:, 0], ckv.value, block_tables,
-                              positions[:, 0] + 1)
-        return out[:, None]
+        from ray_tpu.ops.paged_attention import (gather_kv_pages,
+                                                 paged_attention,
+                                                 write_kv_pages)
+        pool = write_kv_pages(pool, jnp.concatenate([k, v], axis=-1),
+                              block_tables, positions, layer=layer)
+        if q.shape[1] == 1:
+            out = paged_attention(q[:, 0], pool, block_tables,
+                                  positions[:, 0] + 1, layer=layer)
+            return out[:, None], pool
+        if not self.prefix_attend:
+            return xla_attention(q, k, v, causal=True), pool
+        # suffix prefill: the window's keys are NOT the whole story —
+        # leading block-table entries hold a cached prompt prefix, so
+        # gather the row's full logical span back out of the pool and
+        # mask by absolute position (key j visible iff j <= query p).
+        # Unallocated table entries point at scratch page 0, whose
+        # garbage sits past every real query position.  Offset-0
+        # windows reduce to the causal case (their own keys were just
+        # scattered), so this path is correct for any offset.
+        kvfull = gather_kv_pages(pool, block_tables, layer=layer)
+        k_idx = jnp.arange(kvfull.shape[1])
+        mask = k_idx[None, None, None, :] <= positions[:, None, :, None]
+        return xla_attention(q, kvfull[..., :cfg.head_dim],
+                             kvfull[..., cfg.head_dim:],
+                             causal=False, mask=mask), pool
 
 
 class Block(nn.Module):
@@ -335,18 +360,20 @@ class Block(nn.Module):
     mesh: Optional[Mesh] = None
     rules: ShardingRules = LOGICAL_RULES
     decode: bool = False
-    paged_pages: int = 0
-    page_size: int = 64
     prefix_attend: bool = False
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions=None, block_tables=None):
+    def __call__(self, x, cos, sin, positions=None, block_tables=None,
+                 pool=None, layer=None):
+        """With a paged KV ``pool`` (see Attention) returns ``(x, pool)``:
+        the shape ``stack_layers`` carries it through the stack in."""
         cfg = self.cfg
         y = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
         y = Attention(cfg, self.mesh, self.rules, self.decode,
-                      self.paged_pages, self.page_size,
                       self.prefix_attend, name="attn")(
-            y, cos, sin, positions, block_tables)
+            y, cos, sin, positions, block_tables, pool, layer)
+        if pool is not None:
+            y, pool = y
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = x + y
         y = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
@@ -364,7 +391,7 @@ class Block(nn.Module):
         if self.mesh is not None and not self.decode:
             x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
                               self.rules)
-        return x
+        return x if pool is None else (x, pool)
 
 
 class GPT(nn.Module):
@@ -418,11 +445,24 @@ class GPT(nn.Module):
                    else max(0, min(cfg.remat_layers, cfg.n_layers)))
         block_kwargs = dict(mesh=self.mesh, rules=self.rules,
                             decode=self.decode,
-                            paged_pages=self.paged_pages,
-                            page_size=self.page_size,
                             prefix_attend=self.prefix_attend)
         call_args = (cos, sin, positions, block_tables)
-        if do_remat and 0 < n_remat < cfg.n_layers:
+        if self.decode and self.paged_pages:
+            # the paged KV pool: ONE stacked leaf for the whole model,
+            # K in [..., :hd], V in [..., hd:] (layout dictated by TPU
+            # tiling, ops/paged_attention.py layout note).  It rides the
+            # layer stack as loop-carried state with a layer index, so
+            # each block writes its rows in place and no layer's pool is
+            # ever sliced out, relaid or written back.
+            ckv = self.variable(
+                "cache", "kv_pages", jnp.zeros,
+                (cfg.n_layers, self.paged_pages, cfg.n_kv_heads,
+                 self.page_size, 2 * cfg.head_dim), cfg.dtype)
+            x, pool = stack_layers(Block, cfg, block_kwargs, x, call_args,
+                                   remat=False, carry=ckv.value)
+            if not self.is_initializing():
+                ckv.value = pool
+        elif do_remat and 0 < n_remat < cfg.n_layers:
             # partial remat: the first n_remat layers recompute in the
             # backward pass, the tail stores activations (uses the HBM
             # headroom "policy" selection can't reach)

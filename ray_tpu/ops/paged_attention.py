@@ -11,16 +11,30 @@ linearly in ``max_seq_len``, and slot count was capped by
 
 Paged layout instead pools KV in fixed-size pages shared by all slots:
 
-  kv_pages:     [num_pages, kv_heads, page_size, 2*head_dim]  (per layer,
-                K in [..., :head_dim], V in [..., head_dim:])
+  kv_pages:     [layers, num_pages, kv_heads, page_size, 2*head_dim]
+                (ONE stacked pool for the whole model; K in
+                [..., :head_dim], V in [..., head_dim:])
   block_tables: [rows, max_pages_per_seq] int32  (logical -> physical)
 
-A sequence at position ``p`` occupies ``ceil((p+1)/page_size)`` pages.
-The layout is dictated by TPU tiling: Mosaic DMAs slice memrefs in
-(8, 128) tiles, so the page's minor dim must be a multiple of 128 —
-``2*head_dim`` is exactly that for the common head_dims (64, 128, 256),
-and fusing K and V makes a page one DMA instead of two.  kv_heads sits
-outside (page_size, 2*head_dim) so per-head views are tile-aligned.
+A sequence at position ``p`` occupies ``ceil((p+1)/page_size)`` pages,
+the same page ids in every layer.  The layout is dictated by TPU
+tiling: Mosaic DMAs slice memrefs in (8, 128) tiles, so the page's
+minor dim must be a multiple of 128 — ``2*head_dim`` is exactly that
+for the common head_dims (64, 128, 256), and fusing K and V makes a
+page one DMA instead of two.  kv_heads sits outside (page_size,
+2*head_dim) so per-head views are tile-aligned.
+
+ADDRESSING.  The pool is addressed, never sliced: a reader names
+``[layer, page]`` (the kernel's DMA source, the oracle's gather index),
+the writer ``[layer, page, :, offset]`` (``write_kv_pages`` below,
+called by models/gpt.py ``_decode_attend_paged``), on the whole stacked
+array.  ``pool[layer]`` as a value is a copy of one layer's pool (108 MB
+at SmolLM2-360M's default pool) — under the layer scan one such copy,
+its relayout and its write-back a layer, which was 85% of the serving
+cell's device time (PERF.md, PR 25).  So the pool rides the model's
+layer scan and the engine's step scan as loop-carried, donated state,
+and everything here takes it whole plus a ``layer`` index (an int, or a
+traced scalar under the layer scan).
 
 Two implementations:
 
@@ -39,6 +53,7 @@ Two implementations:
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -47,24 +62,89 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import backend_platform, xla_attention
 
 
+def write_kv_pages(pool: jax.Array, kv: jax.Array,
+                   block_tables: jax.Array, positions: jax.Array, *,
+                   layer=0) -> jax.Array:
+    """Write a call's fused K/V into layer ``layer`` of the pool, in
+    place when the pool is loop-carried and donated; returns the pool.
+
+    pool:      [layers, num_pages, kv_heads, page_size, 2*head_dim]
+    kv:        [rows, T, kv_heads, 2*head_dim]
+    positions: [rows, T] absolute positions, contiguous along T; a T > 1
+               window must start on a multiple of ``gcd(T, page_size)``
+               (the engine's windows start on page boundaries)
+
+    Both forms move ``rows * T * kv_heads * 2*head_dim`` values whatever
+    the pool's size, and both keep XLA on the pool's row-major layout
+    (the Pallas kernel's operand layout).  The obvious
+    ``pool.at[layer, pages, :, offs].set(kv)`` does not: its scatter
+    prefers kv_heads minor to page_size and XLA relays the WHOLE pool
+    out to that layout and back, a step.  Times on a v5e, 32 layers of
+    SmolLM2-360M's pool (PERF.md, PR 25):
+
+      - T == 1 (decode): ONE row scatter on the pool viewed as
+        ``[layers*pages*kv_heads*page_size, 2*head_dim]`` (a bitcast):
+        0.46 ms a step for 33 rows, against 3.2 ms for a
+        dynamic_update_slice a row.
+      - T > 1 (prefill): a loop of one dynamic_update_slice per chunk of
+        ``gcd(T, page_size)`` positions, ``[kv_heads, chunk,
+        2*head_dim]`` each: 8.1 ms for 4 x 2048 tokens, against 95 ms
+        for the row scatter.
+    """
+    _, n_pages, kvh, ps, d = pool.shape
+    t = positions.shape[1]
+    kv = kv.astype(pool.dtype)
+    if t == 1:
+        assert pool.size // d < 2 ** 31, "flat row index overflows int32"
+        pages = jnp.take_along_axis(block_tables, positions // ps, axis=1)
+        at = ((((layer * n_pages + pages) * kvh + jnp.arange(kvh)) * ps)
+              + positions % ps)                                # [rows, kvh]
+        pool = pool.reshape(-1, d).at[at.reshape(-1)].set(
+            kv.reshape(-1, d)).reshape(pool.shape)
+    else:
+        c = math.gcd(t, ps)
+        starts = positions[:, ::c]                             # [rows, T/c]
+        pages = jnp.take_along_axis(block_tables, starts // ps,
+                                    axis=1).reshape(-1)
+        offs = (starts % ps).reshape(-1)
+        chunks = jnp.moveaxis(kv.reshape(-1, c, kvh, d), 1, 2)
+
+        def write_chunk(i, pool):
+            chunk = jax.lax.dynamic_index_in_dim(chunks, i, keepdims=False)
+            return jax.lax.dynamic_update_slice(
+                pool, chunk[None, None], (layer, pages[i], 0, offs[i], 0))
+
+        pool = jax.lax.fori_loop(0, chunks.shape[0], write_chunk, pool)
+    return pool
+
+
+def gather_kv_pages(kv_pages: jax.Array, block_tables: jax.Array, *,
+                    layer=0) -> jax.Array:
+    """Each row's whole table span of layer ``layer``, position-major:
+    ``[rows, max_pages*page_size, kv_heads, 2*head_dim]``.  One gather
+    indexed (layer, page) on the stacked pool."""
+    kvh, d = kv_pages.shape[2], kv_pages.shape[4]
+    # [rows, mp, kvh, ps, 2hd] -> [rows, mp*ps, kvh, 2hd]
+    return jnp.moveaxis(kv_pages[layer, block_tables], 2, 3).reshape(
+        block_tables.shape[0], -1, kvh, d)
+
+
 def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
                         block_tables: jax.Array, lengths: jax.Array, *,
+                        layer=0,
                         sm_scale: Optional[float] = None) -> jax.Array:
     """Gather-based paged decode attention (one query token per row).
 
     q:            [rows, heads, head_dim]
-    kv_pages:     [num_pages, kv_heads, page_size, 2*head_dim]
+    kv_pages:     [layers, num_pages, kv_heads, page_size, 2*head_dim]
     block_tables: [rows, max_pages] physical page ids, position-ordered
     lengths:      [rows] number of valid positions (current pos + 1)
+    layer:        which layer's pages to read (int or traced scalar)
     returns       [rows, heads, head_dim]
     """
-    rows, _, hd = q.shape
-    _, kvh, ps, _ = kv_pages.shape
-    # [rows, mp, kvh, ps, 2hd] -> [rows, mp*ps, kvh, 2hd] position-major
-    kv = jnp.moveaxis(kv_pages[block_tables], 2, 3
-                      ).reshape(rows, -1, kvh, 2 * hd)
-    span = kv.shape[1]
-    mask = jnp.arange(span)[None, :] < lengths[:, None]
+    hd = q.shape[-1]
+    kv = gather_kv_pages(kv_pages, block_tables, layer=layer)
+    mask = jnp.arange(kv.shape[1])[None, :] < lengths[:, None]
     out = xla_attention(q[:, None], kv[..., :hd], kv[..., hd:],
                         causal=False, mask=mask, sm_scale=sm_scale)
     return out[:, 0]
@@ -72,8 +152,12 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
 
 def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
                 block_tables: jax.Array, lengths: jax.Array,
-                sm_scale: float) -> jax.Array:
+                layer: jax.Array, sm_scale: float) -> jax.Array:
     """Pallas TPU decode kernel: per-row loop over occupied pages only.
+
+    ``kv_pages`` is the whole stacked pool, left in HBM; ``layer`` [1]
+    rides as a scalar-prefetch operand beside the tables, and a page's
+    DMA source is ``kv_pages[layer, page]``.
 
     ``q2`` is the query padded to [rows, heads, 2*head_dim] (zeros in
     the V half) so every buffer's minor dim is lane-aligned; the zero
@@ -88,10 +172,10 @@ def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
     from jax.experimental.pallas import tpu as pltpu
 
     rows, heads, hd2 = q2.shape
-    num_pages, kvh, ps, _ = kv_pages.shape
+    _, _, kvh, ps, _ = kv_pages.shape
     g = heads // kvh
 
-    def kernel(tables_ref, len_ref, q_ref, kv_ref, out_ref,
+    def kernel(tables_ref, len_ref, layer_ref, q_ref, kv_ref, out_ref,
                kvbuf, acc_ref, m_ref, l_ref, sems):
         r = pl.program_id(0)
         length = len_ref[r]
@@ -99,7 +183,7 @@ def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
 
         def get_dma(slot, i):
             return pltpu.make_async_copy(
-                kv_ref.at[tables_ref[r, i]], kvbuf.at[slot],
+                kv_ref.at[layer_ref[0], tables_ref[r, i]], kvbuf.at[slot],
                 sems.at[slot])
 
         @pl.when(n_pg > 0)
@@ -149,12 +233,12 @@ def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
         out_ref[0] = (acc_ref[:] / norm).astype(out_ref.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # block_tables, lengths
+        num_scalar_prefetch=3,          # block_tables, lengths, layer
         grid=(rows,),
         in_specs=[
             pl.BlockSpec((1, heads, hd2), lambda r, *_: (r, 0, 0),
                          memory_space=pltpu.VMEM),         # q2
-            pl.BlockSpec(memory_space=pl.ANY),             # kv_pages (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),   # stacked kv_pages (HBM)
         ],
         out_specs=pl.BlockSpec((1, heads, hd2), lambda r, *_: (r, 0, 0),
                                memory_space=pltpu.VMEM),
@@ -171,16 +255,17 @@ def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, heads, hd2), q2.dtype),
         name="paged_attention_decode",
-    )(block_tables, lengths, q2, kv_pages)
+    )(block_tables, lengths, layer, q2, kv_pages)
 
 
-def paged_attention_tpu(q, kv_pages, block_tables, lengths, *,
+def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
                         sm_scale: Optional[float] = None) -> jax.Array:
     hd = q.shape[-1]
     scale = sm_scale if sm_scale is not None else hd ** -0.5
     q2 = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
     out2 = _tpu_kernel(q2, kv_pages, block_tables,
-                       lengths.astype(jnp.int32), scale)
+                       lengths.astype(jnp.int32),
+                       jnp.asarray(layer, jnp.int32).reshape(1), scale)
     return out2[..., hd:]       # V half holds the attention output
 
 
@@ -213,11 +298,13 @@ def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
     return impl
 
 
-def paged_attention(q, kv_pages, block_tables, lengths, *,
+def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
                     sm_scale: Optional[float] = None,
                     impl: str = "auto") -> jax.Array:
-    """Backend-dispatched paged decode attention (see module docstring
-    and :func:`resolve_paged_impl`)."""
+    """Backend-dispatched paged decode attention over layer ``layer`` of
+    the stacked pool (see module docstring and
+    :func:`resolve_paged_impl`)."""
     impl = resolve_paged_impl(kv_pages.shape[-1], impl)
     fn = paged_attention_tpu if impl == "tpu" else paged_attention_xla
-    return fn(q, kv_pages, block_tables, lengths, sm_scale=sm_scale)
+    return fn(q, kv_pages, block_tables, lengths, layer=layer,
+              sm_scale=sm_scale)

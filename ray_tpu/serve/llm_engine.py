@@ -32,7 +32,9 @@ the engine does exactly ONE fetch per scheduling quantum):
     the admission first-tokens come back in a single combined fetch.
   - No eos logic on device: rows that finish mid-block keep generating
     junk the host truncates; a freed slot keeps stepping junk until
-    it is reused (the grid is fixed — those steps are free).
+    it is reused (the grid is fixed — those steps are free in dense
+    mode; paged mode holds such a row at position 0, see
+    ``_block_fn_paged``).
   - Per-request temperature rides as an [N] array (greedy rows select
     argmax under the same jit); top_k/top_p are engine-static.
 
@@ -56,6 +58,18 @@ cache rows with a shared page pool + per-row block tables
     into the block step's device state.  Time-to-first-token is bounded
     by prefill throughput and pool capacity, not by slot turnover —
     the saturation-TTFT fix the dense engine could not express.
+  - Addressing: the pool is ONE stacked cache leaf ``kv_pages``
+    [layers, pool_pages, kv_heads, page_size, 2*head_dim], declared by
+    the model (models/gpt.py GPT) and chained, donated, through every
+    engine program.  It rides the model's layer scan and the block's
+    step scan as loop-carried state; a layer writes its rows at
+    ``[layer, page, :, offset]`` and the kernel reads ``[layer, page]``
+    (ops/paged_attention.py ``write_kv_pages`` / the DMA source).
+    Nobody slices a layer out of it: a decode step or a prefill wave
+    moves the rows it writes and the pages it reads, whatever
+    ``kv_pool_pages`` is (tests/test_chip_compile.py holds that in the
+    compiled programs).  Export / import / the prefix cache name whole
+    pages ``[:, page]`` across all layers.
   - Safety: a freed slot keeps stepping junk until its redirect row
     (table -> scratch page 0) rides the next block dispatch; pages are
     recycled only through dispatches ordered after the last junk write
@@ -433,17 +447,16 @@ class LLMEngine:
             # its handoff-latency histogram without the engine growing
             # a telemetry dependency
             self.on_import_admit: Optional[Callable[[float], None]] = None
-            # KV pool leaf identity + handoff shape: pool leaves carry
-            # trailing [pool_pages, kv_heads, page_size, 2*head_dim]
-            # (ops/paged_attention.py layout); _ltot counts total
-            # per-layer pools across the cache tree (the scan axis of a
-            # scanned leaf contributes its length) — the leading axis of
-            # PrefillHandoff.kv, which both handoff ends must agree on.
+            # KV pool leaf identity + handoff shape: pool leaves are
+            # [layers, pool_pages, kv_heads, page_size, 2*head_dim]
+            # (ops/paged_attention.py layout; the model declares one);
+            # _ltot counts the per-layer pools across the cache tree —
+            # the leading axis of PrefillHandoff.kv, which both handoff
+            # ends must agree on.
             self._pool_tail = (cfg.n_kv_heads, page_size,
                                2 * cfg.head_dim)
             self._ltot = sum(
-                (leaf.shape[0] if leaf.ndim == 5 else 1)
-                for leaf in jax.tree.leaves(self._cache)
+                leaf.shape[0] for leaf in jax.tree.leaves(self._cache)
                 if self._is_pool_leaf(leaf))
             block_fn = self._block_fn_paged
             # prompt-prefix page cache (docs/serve_frontdoor.md):
@@ -652,13 +665,13 @@ class LLMEngine:
         return fn
 
     def _is_pool_leaf(self, leaf) -> bool:
-        """A cache leaf holding the shared KV page pool: trailing
-        [pool_pages, kv_heads, page_size, 2*head_dim] with optionally a
-        leading scan-layer axis.  Other cache leaves (per-layer scalar
-        indices) are handoff-irrelevant."""
-        return (leaf.ndim in (4, 5)
-                and leaf.shape[-4] == self.kv_pool_pages
-                and tuple(leaf.shape[-3:]) == self._pool_tail)
+        """A cache leaf holding the shared KV page pool: [layers,
+        pool_pages, kv_heads, page_size, 2*head_dim], stacked over the
+        layers whether or not the model scans them.  Any other cache
+        leaf is handoff-irrelevant."""
+        return (leaf.ndim == 5
+                and leaf.shape[1] == self.kv_pool_pages
+                and tuple(leaf.shape[2:]) == self._pool_tail)
 
     def _page_bucket(self, n: int) -> int:
         """Power-of-two page-count bucket: bounds the gather/scatter jit
@@ -684,17 +697,9 @@ class LLMEngine:
                 for leaf in jax.tree.leaves(cache):
                     if not self._is_pool_leaf(leaf):
                         continue
-                    ax = leaf.ndim - 4
-                    g = jnp.take(leaf, flat, axis=ax)
-                    if leaf.ndim == 5:
-                        lc = leaf.shape[0]
-                        g = g.reshape((lc, wave, bucket)
-                                      + tuple(leaf.shape[-3:]))
-                        g = jnp.moveaxis(g, 1, 0)
-                    else:
-                        g = g.reshape((wave, 1, bucket)
-                                      + tuple(leaf.shape[-3:]))
-                    parts.append(g)
+                    g = jnp.take(leaf, flat, axis=1).reshape(
+                        (leaf.shape[0], wave, bucket) + self._pool_tail)
+                    parts.append(jnp.moveaxis(g, 1, 0))
                 return jnp.concatenate(parts, axis=1)
             fn = self._export_jit[(bucket, wave)] = jax.jit(
                 engine_kv_export)
@@ -717,19 +722,13 @@ class LLMEngine:
                     if not self._is_pool_leaf(leaf):
                         out.append(leaf)
                         continue
-                    tail = tuple(leaf.shape[-3:])
-                    if leaf.ndim == 5:
-                        lc = leaf.shape[0]
-                        src = jnp.moveaxis(kv[:, off:off + lc], 1, 0)
-                        src = src.reshape((lc, wave * bucket) + tail)
-                        out.append(leaf.at[:, flat].set(
-                            src.astype(leaf.dtype)))
-                        off += lc
-                    else:
-                        src = kv[:, off].reshape((wave * bucket,) + tail)
-                        out.append(leaf.at[flat].set(
-                            src.astype(leaf.dtype)))
-                        off += 1
+                    lc = leaf.shape[0]
+                    src = jnp.moveaxis(kv[:, off:off + lc], 1, 0)
+                    src = src.reshape((lc, wave * bucket)
+                                      + self._pool_tail)
+                    out.append(leaf.at[:, flat].set(
+                        src.astype(leaf.dtype)))
+                    off += lc
                 return jax.tree_util.tree_unflatten(treedef, out)
             fn = self._import_jit[(bucket, wave)] = jax.jit(
                 engine_kv_import, donate_argnums=(0,))
@@ -751,6 +750,14 @@ class LLMEngine:
         tables = tables.at[a_slots].set(admit_tables)
         rng, sub = jax.random.split(rng)
         keys = jax.random.split(sub, self.block_size)
+        # a row whose table starts at scratch page 0 holds no request
+        # (never installed, or redirected after eviction).  It keeps
+        # stepping junk, but AT POSITION 0: the kernel reads a row's
+        # ceil((position+1)/page_size) pages every layer, so an idle row
+        # left to walk to max_seq_len reads that many scratch pages a
+        # step — 28 idle rows did twice the work of the whole model
+        # (PERF.md, PR 25)
+        live = tables[:, 0] != 0
 
         def one(carry, key):
             tokens, positions, cache = carry
@@ -759,8 +766,9 @@ class LLMEngine:
                 positions[:, None], block_tables=tables,
                 mutable=["cache"])
             nxt = self._sample_fn(key, logits[:, -1], temps)
-            positions = jnp.minimum(positions + 1,
-                                    self.cfg.max_seq_len - 1)
+            positions = jnp.where(
+                live, jnp.minimum(positions + 1,
+                                  self.cfg.max_seq_len - 1), 0)
             return (nxt, positions, mut["cache"]), nxt
 
         (tokens, positions, cache), block = jax.lax.scan(

@@ -82,19 +82,24 @@ def test_flash_fwd_bwd_gpt_small_widths(topo, one_chip):
 
 
 def test_paged_decode_gpt_small_widths(topo, one_chip):
+    """The kernel takes the whole stacked pool and a layer index (a
+    traced scalar, as under the model's layer scan)."""
     from ray_tpu.ops.paged_attention import paged_attention
 
-    rows, heads, hd, ps, pages = 32, 12, 64, 64, 129
+    rows, heads, hd, ps, pages, layers = 32, 12, 64, 64, 129, 3
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     lowered = jax.jit(
-        lambda q, kv, bt, ln: paged_attention(q, kv, bt, ln, impl="tpu")
+        lambda q, kv, bt, ln, layer: paged_attention(
+            q, kv, bt, ln, layer=layer, impl="tpu")
     ).lower(sds((rows, heads, hd), jnp.bfloat16),
-            sds((pages, heads, ps, 2 * hd), jnp.bfloat16),
-            sds((rows, 4), jnp.int32), sds((rows,), jnp.int32))
+            sds((layers, pages, heads, ps, 2 * hd), jnp.bfloat16),
+            sds((rows, 4), jnp.int32), sds((rows,), jnp.int32),
+            sds((), jnp.int32))
     assert "tpu_custom_call" in lowered.as_text()
+    assert 'kernel_name = "paged_attention_decode"' in lowered.as_text()
     lowered.compile()
 
 
@@ -202,3 +207,132 @@ def test_engine_prefill_and_paged_decode_programs(topo, one_chip, on_tpu):
     assert "@jit_engine_decode_block" in block.as_text()
     assert "@jit_engine_prefill" in prefill.as_text()
     block.compile()
+
+
+# ---- the stacked KV pool is addressed in place (ISSUE 25) ----
+
+_IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
+             "dynamic-update-slice", "fusion:scatter",
+             "fusion:dynamic-update-slice"}
+
+
+def _pool_result_producers(hlo: str, sizes):
+    """Opcodes of the optimised HLO's instructions whose (array) result
+    holds one of ``sizes`` bf16 values, whatever its rank — the pool,
+    one layer of it, or a reshaped view.  A fusion is named by its
+    root: ``fusion:dynamic-update-slice`` is an update in place,
+    ``fusion:copy`` or ``fusion:dynamic-slice`` moved the pool."""
+    import collections
+    import re
+
+    inst = re.compile(
+        r"^\s*(ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* ([\w\-]+)\((.*)$")
+    roots, found = {}, []
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            comp = head.group(2)
+            continue
+        m = inst.match(line)
+        if not m:
+            continue
+        is_root, name, dims, op, rest = m.groups()
+        if is_root:
+            roots[comp] = op
+        if np.prod([int(d) for d in dims.split(",")]) in sizes:
+            calls = re.search(r"calls=%?([\w.\-]+)", rest)
+            found.append((op, calls.group(1) if calls else None))
+    return collections.Counter(
+        f"fusion:{roots.get(c, '?')}" if op == "fusion" else op
+        for op, c in found)
+
+
+def _smollm_engine(pages, monkeypatch):
+    """SmolLM2-360M's head shapes (15 heads, 5 KV heads of 64), 4 scanned
+    layers, and a pool that dwarfs weights and activations — described,
+    not allocated: parameters and cache are shapes."""
+    from ray_tpu.models.configs import get_config
+    from ray_tpu.models.gpt import GPT
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg = get_config("gpt-small", n_layers=4, d_model=960, n_heads=15,
+                     n_kv_heads=5, d_ff=2560, vocab_size=49152,
+                     tie_embeddings=True, dtype=jnp.bfloat16)
+    assert cfg.scan_layers
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, cfg.dtype),
+        jax.eval_shape(lambda: GPT(cfg, decode=True).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))["params"]))
+    # (the package re-exports the function ``generate`` under this name)
+    generate = importlib.import_module("ray_tpu.models.generate")
+    with monkeypatch.context() as patch:
+        init = generate.init_decode_cache
+        patch.setattr(
+            generate, "init_decode_cache",
+            lambda model, batch: jax.eval_shape(lambda: init(model, batch)))
+        return LLMEngine(cfg, params, num_slots=32, max_seq_len=2560,
+                         paged=True, page_size=64, kv_pool_pages=pages)
+
+
+def _compiled_engine_programs(pages, one_chip, monkeypatch):
+    eng = _smollm_engine(pages, monkeypatch)
+    (pool,) = [x for x in jax.tree.leaves(eng._cache)
+               if eng._is_pool_leaf(x)]
+    assert pool.shape == (4, pages, 5, 64, 128)
+    bucket, wave = 64, 2
+    block = eng._block_jit.lower(
+        *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                 one_chip))
+    assert 'kernel_name = "paged_attention_decode"' in block.as_text()
+    prefill = eng._get_prefill_paged(bucket, wave).lower(
+        *_shapes((eng.params, eng._cache,
+                  jnp.zeros((wave, bucket + 2), jnp.int32),
+                  jnp.zeros((wave, eng.max_pages), jnp.int32),
+                  jax.random.PRNGKey(0)), one_chip))
+    return {"engine_decode_block": block.compile(),
+            "engine_prefill": prefill.compile()}
+
+
+def _bytes_accessed(compiled):
+    try:
+        cost = compiled.cost_analysis()
+    except Exception:  # noqa: BLE001 — not every compiled object has one
+        return None
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0] if cost else None
+    return (cost or {}).get("bytes accessed")
+
+
+def test_engine_programs_address_the_pool_in_place(topo, one_chip, on_tpu,
+                                                   monkeypatch):
+    """ISSUE 25's guard.  In the compiled decode block and prefill the
+    stacked KV pool is never sliced, relaid out, copied or written back:
+    temporaries stay under ONE layer's pool, nothing but parameters and
+    in-place updates produces a pool-sized result, and a pool four times
+    as large changes neither temporaries nor bytes accessed.  (The tree
+    before ISSUE 25: decode block temporaries 17x one layer's pool,
+    ``copy`` and ``dynamic-slice`` fusions of the pool in both.)"""
+    pages = 2048
+    layer_elems = pages * 5 * 64 * 128
+    small = _compiled_engine_programs(pages, one_chip, monkeypatch)
+    large = _compiled_engine_programs(4 * pages, one_chip, monkeypatch)
+    for name, compiled in small.items():
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 2 * layer_elems, (
+            f"{name}: {temp / 1e6:.1f} MB of temporaries, one layer's "
+            f"pool is {2 * layer_elems / 1e6:.1f} MB")
+        made = _pool_result_producers(compiled.as_text(),
+                                      (layer_elems, 4 * layer_elems))
+        assert set(made) <= _IN_PLACE, (
+            f"{name}: pool-sized results from {dict(made)}")
+        assert any("scatter" in op or "update-slice" in op for op in made)
+        temp4 = large[name].memory_analysis().temp_size_in_bytes
+        assert abs(temp4 - temp) < 0.1 * temp, (
+            f"{name}: temporaries {temp / 1e6:.1f} MB at {pages} pages, "
+            f"{temp4 / 1e6:.1f} MB at {4 * pages}")
+        moved, moved4 = (_bytes_accessed(c) for c in (compiled, large[name]))
+        if moved and moved4:
+            assert abs(moved4 - moved) < 0.1 * moved, (
+                f"{name}: bytes accessed {moved / 1e6:.1f} MB at {pages} "
+                f"pages, {moved4 / 1e6:.1f} MB at {4 * pages}")

@@ -15,13 +15,13 @@ import time
 import pytest
 
 
-def _tiny():
+def _tiny(scan_layers=True):
     import jax
     import jax.numpy as jnp
     from ray_tpu.models.configs import get_config
     from ray_tpu.models.gpt import GPT
 
-    cfg = get_config("tiny")
+    cfg = get_config("tiny", scan_layers=scan_layers)
     model = GPT(cfg, decode=True)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 1), jnp.int32))["params"]
@@ -31,6 +31,14 @@ def _tiny():
 @pytest.fixture(scope="module")
 def tiny_parts():
     return _tiny()
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["scanned", "unrolled"])
+def tiny_parts_either(request):
+    """The tiny model with its layers scanned and unrolled: the KV pool
+    is one stacked leaf in both, the layer index traced or static."""
+    return _tiny(scan_layers=request.param)
 
 
 def _lone_expect(cfg, params, prompts, n=8):
@@ -60,20 +68,24 @@ def _submit_all(eng, prompts, n=8, timeout=240):
     return results
 
 
-def test_paged_model_matches_dense_at_mixed_offsets(tiny_parts):
+def test_paged_model_matches_dense_at_mixed_offsets(tiny_parts_either):
     """Model-level: paged prefill + per-page decode reproduces the dense
     decode path exactly with rows at different offsets and disjoint
     (deliberately shuffled) physical pages."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
     from ray_tpu.models.generate import init_decode_cache
     from ray_tpu.models.gpt import GPT
 
-    cfg, params = tiny_parts
+    cfg, params = tiny_parts_either
     ps = 16
     max_pages = cfg.max_seq_len // ps
     paged = GPT(cfg, decode=True, paged_pages=32, page_size=ps)
     cache = init_decode_cache(paged, 1)
+    # one stacked pool leaf, whether the layers are scanned or not
+    assert [x.shape for x in jax.tree.leaves(cache)] == [
+        (cfg.n_layers, 32, cfg.n_kv_heads, ps, 2 * cfg.head_dim)]
 
     prompts = [[1, 2, 3], [7, 8, 9, 10, 11]]
     expect = _lone_expect(cfg, params, prompts)
@@ -231,5 +243,198 @@ def test_paged_eos_streaming_and_oversized(tiny_parts):
         # engine still serves afterwards
         r2 = eng.submit([3, 4, 5], max_new_tokens=4, temperature=0.0)
         assert r2.tokens == probe.tokens
+    finally:
+        eng.close()
+
+
+# ---- the stacked pool, addressed by (layer, page) (ISSUE 25) ----
+
+
+def _random_pool(rs, layers, pages, kvh, ps, hd, dtype="float32"):
+    import jax.numpy as jnp
+    return jnp.asarray(rs.randn(layers, pages, kvh, ps, 2 * hd), dtype)
+
+
+def _dense_decode_attention(q, pool_layer, tables, lengths):
+    """Per-row dense softmax attention over the row's own pages, in
+    numpy float64: the oracle of the oracle."""
+    import numpy as np
+    pool_layer = np.asarray(pool_layer, np.float64)
+    q = np.asarray(q, np.float64)
+    rows, heads, hd = q.shape
+    kvh, ps = pool_layer.shape[1], pool_layer.shape[2]
+    out = np.zeros((rows, heads, hd))
+    for r in range(rows):
+        kv = np.concatenate([pool_layer[p] for p in np.asarray(tables[r])],
+                            axis=1)[:, :int(lengths[r])]    # [kvh, n, 2hd]
+        for h in range(heads):
+            k, v = kv[h // (heads // kvh), :, :hd], kv[h // (heads // kvh),
+                                                       :, hd:]
+            s = k @ q[r, h] * hd ** -0.5
+            w = np.exp(s - s.max())
+            out[r, h] = (w / w.sum()) @ v
+    return out
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_paged_attention_xla_reads_its_layer(head_dim, layer):
+    """The oracle on the stacked pool, layer given as a traced scalar,
+    against dense attention over that layer's pages alone, at
+    2*head_dim 128 and 256."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops.paged_attention import paged_attention_xla
+
+    rs = np.random.RandomState(head_dim + layer)
+    pool = _random_pool(rs, 5, 13, 2, 16, head_dim)
+    q = jnp.asarray(rs.randn(3, 4, head_dim), jnp.float32)
+    tables = jnp.asarray(rs.permutation(12)[:9].reshape(3, 3) + 1,
+                         jnp.int32)
+    lengths = jnp.asarray([1, 17, 48], jnp.int32)
+    got = jax.jit(lambda l: paged_attention_xla(
+        q, pool, tables, lengths, layer=l))(jnp.int32(layer))
+    want = _dense_decode_attention(q, pool[layer], tables, lengths)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_pallas_decode_kernel_matches_oracle_in_tpu_interpreter(
+        monkeypatch, head_dim, layer):
+    """The Pallas kernel itself, run on the CPU by the TPU interpreter
+    (it simulates the DMAs and semaphores): it must fetch
+    ``kv_pages[layer, page]`` — a wrong layer or page is a wrong answer
+    — and agree with the gather oracle to bf16 precision."""
+    import functools
+
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ray_tpu.ops.paged_attention import (paged_attention_tpu,
+                                             paged_attention_xla)
+
+    monkeypatch.setattr(pl, "pallas_call", functools.partial(
+        pl.pallas_call, interpret=pltpu.InterpretParams()))
+    rs = np.random.RandomState(7 * head_dim + layer)
+    pool = _random_pool(rs, 3, 13, 2, 16, head_dim, "bfloat16")
+    q = jnp.asarray(rs.randn(3, 4, head_dim), jnp.bfloat16)
+    tables = jnp.asarray(rs.permutation(12).reshape(3, 4) + 1, jnp.int32)
+    lengths = jnp.asarray([5, 33, 64], jnp.int32)
+    got = paged_attention_tpu(q, pool, tables, lengths,
+                              layer=jnp.int32(layer))
+    want = paged_attention_xla(q, pool, tables, lengths, layer=layer)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    other = paged_attention_xla(q, pool, tables, lengths,
+                                layer=(layer + 1) % 3)
+    assert np.abs(np.asarray(got, np.float32)
+                  - np.asarray(other, np.float32)).max() > 0.1
+
+
+@pytest.mark.parametrize("window", [1, 8, 16, 48],
+                         ids=["decode", "part-page", "page", "3-pages"])
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+def test_write_kv_pages_touches_only_its_rows(layer, window):
+    """Both forms of the write (decode's row scatter, prefill's chunk
+    loop) against plain numpy indexing: the named (layer, page, offset)
+    rows change, nothing else does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops.paged_attention import write_kv_pages
+
+    ps, kvh, hd = 16, 2, 8
+    rs = np.random.RandomState(window)
+    pool = _random_pool(rs, 3, 9, kvh, ps, hd)
+    tables = np.asarray([[3, 1, 7, 5], [2, 8, 4, 6]], np.int32)
+    # decode rows sit mid-page at different offsets; prefill windows
+    # start on page boundaries (the engine's contract)
+    start = np.asarray([21, 40] if window == 1 else [16, 0])
+    positions = start[:, None] + np.arange(window)[None]
+    kv = rs.randn(2, window, kvh, 2 * hd).astype(np.float32)
+    got = jax.jit(lambda p, l: write_kv_pages(
+        p, jnp.asarray(kv), jnp.asarray(tables), jnp.asarray(positions),
+        layer=l))(pool, jnp.int32(layer))
+    want = np.array(pool)
+    for r in range(2):
+        for t in range(window):
+            pos = positions[r, t]
+            want[layer, tables[r, pos // ps], :, pos % ps] = kv[r, t]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_handoff_round_trip_equals_lone_generation(tiny_parts_either):
+    """export_prefill on one engine, import_prefill on another: the
+    pages ship as [layers, npages, ...] out of one stacked pool into
+    another, and decode continues exactly as lone generation."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts_either
+    prompts = [[1, 2, 3], [7, 8, 9, 10, 11], [5] * 19]
+    expect = _lone_expect(cfg, params, prompts)
+    kw = dict(num_slots=2, block_size=4, paged=True, page_size=16,
+              kv_pool_pages=1 + 8)
+    pre, dec = LLMEngine(cfg, params, **kw), LLMEngine(cfg, params, **kw)
+    try:
+        for prompt, want in zip(prompts, expect):
+            h = pre.export_prefill(prompt, max_new_tokens=8,
+                                   temperature=0.0)
+            assert h.kv.shape == (cfg.n_layers, -(-len(prompt) // 16),
+                                  cfg.n_kv_heads, 16, 2 * cfg.head_dim)
+            assert dec.import_prefill(h).tokens == want
+    finally:
+        pre.close()
+        dec.close()
+
+
+def test_prefix_suffix_prefill_equals_full_prefill(tiny_parts_either):
+    """A prompt whose first pages are cached prefills only its suffix,
+    attending back through the pool at (layer, page): the tokens are
+    those of a full prefill."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts_either
+    shared = list(range(3, 3 + 32))               # two full pages
+    prompts = [shared + [40, 41], shared + [50, 51, 52, 53, 54]]
+    expect = _lone_expect(cfg, params, prompts)
+    eng = LLMEngine(cfg, params, num_slots=2, block_size=4, paged=True,
+                    page_size=16, kv_pool_pages=1 + 16,
+                    prefix_cache_pages=8)
+    try:
+        for prompt, want in zip(prompts, expect):
+            assert eng.submit(prompt, max_new_tokens=8,
+                              temperature=0.0).tokens == want
+        assert eng.stats.prefix_hits >= 1
+        assert eng.stats.prefix_tokens_saved >= 32
+    finally:
+        eng.close()
+
+
+def test_idle_rows_stay_at_position_zero(tiny_parts):
+    """The decode kernel reads ceil((position+1)/page_size) pages a row a
+    layer, so a row that holds no request (table -> scratch page 0) must
+    not walk towards max_seq_len while it steps junk; a live row
+    advances by the block."""
+    import numpy as np
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts
+    eng = LLMEngine(cfg, params, num_slots=3, block_size=4, paged=True,
+                    page_size=16, kv_pool_pages=1 + 8)
+    try:
+        meta = np.asarray(eng._no_admit[0]).copy()
+        tables = np.zeros((3, eng.max_pages), np.int32)
+        meta[:, 0] = (1, 5, 0)              # slot 1, position 5, greedy
+        tables[0, 0] = 3                    # its first page: live
+        for _ in range(2):
+            _, eng._state, eng._cache = eng._block_jit(
+                eng.params, eng._cache, eng._state, meta,
+                np.zeros((3,), np.int32), tables)
+            meta = np.asarray(eng._no_admit[0])
+            tables = np.zeros((3, eng.max_pages), np.int32)
+        assert np.asarray(eng._state[1]).tolist() == [0, 5 + 2 * 4, 0, 0]
     finally:
         eng.close()
