@@ -44,26 +44,68 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01
+    # None -> d_model // n_heads; a model whose heads * head_dim differs
+    # from d_model states it (wq/wk/wv map d_model -> heads * head_dim,
+    # wo maps it back)
+    head_dim: Optional[int] = None
+    moe_d_ff: Optional[int] = None         # an expert's width; None -> d_ff
+    moe_act: str = "silu"                  # silu (SwiGLU) | relu (ReGLU)
+    # dropless: every (token, chosen expert) pair is computed whatever
+    # the imbalance (ops/moe.py DroplessMoE), no capacity, no aux loss
+    moe_dropless: bool = False
+    # the router reads the block's normalised ATTENTION input, not the
+    # expert layer's own input
+    moe_router_pre_attn: bool = False
+    # Per-layer attention kinds, looked up by layer index (layer l uses
+    # entry l; the layouts may be longer than n_layers: a model cut in
+    # depth keeps its published lists).  rope_layout[l] == 0 -> the layer
+    # does not rotate (NoPE); window_layout[l] == 1 -> key j is visible
+    # to query i only while j > i - sliding_window.  None -> every layer
+    # rotates / windows iff sliding_window is set.
+    sliding_window: Optional[int] = None
+    rope_layout: Optional[tuple] = None
+    window_layout: Optional[tuple] = None
 
     def __post_init__(self):
         if self.n_kv_heads is None:
             self.n_kv_heads = self.n_heads
         if self.d_ff is None:
             self.d_ff = 4 * self.d_model
-        assert self.d_model % self.n_heads == 0
+        if self.head_dim is None:
+            assert self.d_model % self.n_heads == 0
+            self.head_dim = self.d_model // self.n_heads
+        if self.moe_d_ff is None:
+            self.moe_d_ff = self.d_ff
         assert self.n_heads % self.n_kv_heads == 0
+        assert self.moe_act in ("silu", "relu")
+        for layout in (self.rope_layout, self.window_layout):
+            assert layout is None or len(layout) >= self.n_layers
+        if self.rope_layout is not None:
+            self.rope_layout = tuple(int(v) for v in self.rope_layout)
+        if self.window_layout is not None:
+            assert self.sliding_window
+            self.window_layout = tuple(int(v) for v in self.window_layout)
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def layers_differ(self) -> bool:
+        """Some layer's attention is of another kind than the others':
+        the layer stack then hands every block its layer index."""
+        return self.rope_layout is not None or self.window_layout is not None
+
+    def _attn_params(self) -> int:
+        return self.d_model * self.head_dim * (
+            self.n_heads * 2 + self.n_kv_heads * 2)
 
     def num_params(self) -> int:
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        attn = self.d_model * self.head_dim * (
-            self.n_heads * 2 + self.n_kv_heads * 2)
-        mlp = 3 * self.d_model * self.d_ff
+        if self.moe_experts:
+            mlp = self.moe_experts * 3 * self.d_model * self.moe_d_ff \
+                + self.d_model * self.moe_experts            # + router
+        else:
+            mlp = 3 * self.d_model * self.d_ff
         norms = 2 * self.d_model
-        return emb + self.n_layers * (attn + mlp + norms) + self.d_model
+        return emb + self.n_layers * (self._attn_params() + mlp + norms) \
+            + self.d_model
 
     def train_flops_per_token(self, seq_len: int) -> float:
         """Operations a training step REQUIRES per token — the count
@@ -75,11 +117,14 @@ class TransformerConfig:
         runs ``moe_top_k`` experts a token), and causal attention once
         (``QK^T`` and ``PV``: the masked half is not work the algorithm
         needs).  Not counted: the embedding gather, recomputation under
-        remat, norms, rotary and softmax elementwise work."""
-        attn = self.d_model * self.head_dim * (
-            self.n_heads * 2 + self.n_kv_heads * 2)
-        mlp = 3 * self.d_model * self.d_ff * (
-            self.moe_top_k if self.moe_experts else 1)
+        remat, norms, rotary and softmax elementwise work.  A window
+        is not taken off the attention term: it is an upper bound there."""
+        attn = self._attn_params()
+        if self.moe_experts:
+            mlp = 3 * self.d_model * self.moe_d_ff * self.moe_top_k \
+                + self.d_model * self.moe_experts            # + router
+        else:
+            mlp = 3 * self.d_model * self.d_ff
         head = self.vocab_size * self.d_model
         attention = 6 * seq_len * self.n_heads * self.head_dim \
             * self.n_layers
@@ -127,9 +172,36 @@ PRESETS = {
                                    n_layers=32, n_heads=32, n_kv_heads=8,
                                    d_ff=14336, max_seq_len=8192,
                                    rope_theta=500000.0),
+    # SmallThinker-21BA3B-Instruct (PowerInfer) as published: 52 expert
+    # layers of 64 ReGLU experts (width 768), top-6, dropless, routed
+    # from the attention's input; heads * head_dim = 3584 != d_model; a
+    # 4-layer period of one global layer without positions and three
+    # rotating layers with a window of 4096
+    "smallthinker-21b-a3b": TransformerConfig(
+        vocab_size=151936, d_model=2560, n_layers=52, n_heads=28,
+        n_kv_heads=4, head_dim=128, d_ff=768, max_seq_len=16384,
+        rope_theta=1.5e6, norm_eps=1e-6, moe_experts=64, moe_top_k=6,
+        moe_d_ff=768, moe_act="relu", moe_dropless=True,
+        moe_router_pre_attn=True, sliding_window=4096,
+        rope_layout=(0, 1, 1, 1) * 13, window_layout=(0, 1, 1, 1) * 13),
+    # the same block at test size (tests/test_layer_kinds.py)
+    "tiny-smallthinker": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=6, n_kv_heads=2,
+        head_dim=16, d_ff=32, max_seq_len=128, dtype=jnp.float32,
+        remat=False, moe_experts=8, moe_top_k=3, moe_d_ff=32,
+        moe_act="relu", moe_dropless=True, moe_router_pre_attn=True,
+        sliding_window=8, rope_layout=(0, 1, 1, 1) * 2,
+        window_layout=(0, 1, 1, 1) * 2),
 }
 
 
 def get_config(name: str, **overrides) -> TransformerConfig:
     base = PRESETS[name]
-    return dataclasses.replace(base, **overrides) if overrides else base
+    if not overrides:
+        return base
+    for derived, of in (("head_dim", base.d_model // base.n_heads),
+                        ("moe_d_ff", base.d_ff)):
+        # a size the preset left to its default follows the overrides
+        if derived not in overrides and getattr(base, derived) == of:
+            overrides[derived] = None
+    return dataclasses.replace(base, **overrides)

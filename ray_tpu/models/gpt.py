@@ -39,6 +39,24 @@ def _dense(features, logical_axes, name=None, use_bias=False,
             nn.initializers.lecun_normal(), logical_axes))
 
 
+# "no window" as a window: larger than any context, small enough that
+# ``position - NO_WINDOW`` stays inside int32
+NO_WINDOW = 1 << 30
+
+
+def window_mask(q_pos, k_pos, window=None):
+    """Boolean ``[B, 1, Q, K]``: key ``j`` is visible to the query at
+    absolute position ``p`` iff ``j <= p`` and, under a ``window`` (a
+    scalar, traced or not), ``j > p - window``.  ``q_pos`` [B, Q],
+    ``k_pos`` [K]."""
+    q_pos = q_pos[:, None, :, None]
+    k_pos = k_pos[None, None, None, :]
+    mask = k_pos <= q_pos
+    if window is not None:
+        mask = mask & (k_pos > q_pos - window)
+    return mask
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-6
 
@@ -78,7 +96,8 @@ class MLP(nn.Module):
 def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
                  call_args, *, remat: Optional[bool] = None,
                  cache: bool = False, name: str = "blocks",
-                 n_layers: Optional[int] = None, carry=None):
+                 n_layers: Optional[int] = None, carry=None,
+                 first_layer: int = 0):
     """Apply ``n_layers`` (default cfg.n_layers) blocks under the repo's
     standard stacking: remat per cfg.remat (HBM<->FLOPs), one
     ``lax.scan``'d block when cfg.scan_layers (O(1) compile time in
@@ -96,6 +115,16 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
     ``mdl(x, *call_args, carry, layer)`` and return ``(x, carry)``;
     ``layer`` is a scanned ``arange`` under scan, a Python int unrolled.
     Returns ``(x, carry)`` when a carry is given.
+
+    Where the layers differ in kind (``cfg.layers_differ``: per-layer
+    rotation and window layouts) ONE block is still scanned and every
+    block is handed its layer index (``first_layer`` + its place in this
+    stack), with or without a carry: the kinds differ only in two
+    scalars a layer (rotate or not, window or none), which the block
+    looks up by that index, so the parameters stay one stacked tree, the
+    compile time stays O(1) in depth, and a model cut in depth keeps
+    its published layouts.  Scanning one period instead would need a
+    second, nested parameter tree for the same arithmetic.
     """
     if n_layers is None:
         n_layers = cfg.n_layers
@@ -128,14 +157,20 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
         variable_axes = {"params": 0, "intermediates": 0}
         if cache:
             variable_axes["cache"] = 0
-        if carry is None:
+        layers = jnp.arange(first_layer, first_layer + n_layers,
+                            dtype=jnp.int32)
+        if carry is None and not cfg.layers_differ:
             init, layers = x, None
 
             def body(mdl, x, _):
                 return mdl(x, *call_args), None
+        elif carry is None:
+            init = x
+
+            def body(mdl, x, layer):
+                return mdl(x, *call_args, None, layer), None
         else:
             init = (x, carry)
-            layers = jnp.arange(n_layers, dtype=jnp.int32)
 
             def body(mdl, x_carry, layer):
                 return mdl(x_carry[0], *call_args, x_carry[1], layer), None
@@ -149,10 +184,12 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
         return out
     for i in range(n_layers):
         block = block_cls(cfg, **ctor_kwargs, name=f"{name[:-1]}_{i}")
-        if carry is None:
-            x = block(x, *call_args)
+        if carry is not None:
+            x, carry = block(x, *call_args, carry, first_layer + i)
+        elif cfg.layers_differ:
+            x = block(x, *call_args, None, first_layer + i)
         else:
-            x, carry = block(x, *call_args, carry, i)
+            x = block(x, *call_args)
     return x if carry is None else (x, carry)
 
 
@@ -184,23 +221,69 @@ class Attention(nn.Module):
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
         v = _dense((kvh, hd), ("embed", "kv", "head_dim"), "wv",
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        rotate, window = self._layer_kind(layer)
+        if rotate is None:
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+        else:       # this layer's entry of rope_layout: 0 -> no positions
+            q = jnp.where(rotate, apply_rope(q, cos, sin, positions), q)
+            k = jnp.where(rotate, apply_rope(k, cos, sin, positions), k)
 
         if pool is not None:
-            out, pool = self._decode_attend_paged(q, k, v, positions,
-                                                  block_tables, pool, layer)
+            out, pool = self._decode_attend_paged(
+                q, k, v, positions, block_tables, pool, layer, window)
         elif self.decode:
-            out = self._decode_attend(q, k, v, positions)
+            out = self._decode_attend(q, k, v, positions, window)
         else:
-            out = self._train_attend(q, k, v)
+            out = self._train_attend(q, k, v, window)
         out = out.reshape(*out.shape[:2], h * hd)
         out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
         return out if pool is None else (out, pool)
 
-    def _train_attend(self, q, k, v):
+    def _layer_kind(self, layer):
+        """``(rotate, window)`` of layer ``layer`` (an int, or the traced
+        index of the layer scan): ``rotate`` is None where every layer
+        rotates, else this layer's entry of ``cfg.rope_layout`` as a
+        bool; ``window`` is None where the model has no window, else
+        this layer's (``NO_WINDOW`` for a global layer of a model whose
+        other layers have one).  A config without layouts never reads
+        ``layer``."""
         cfg = self.cfg
+        rotate = window = None
+        if cfg.rope_layout is not None:
+            rotate = jnp.asarray(cfg.rope_layout, jnp.bool_)[layer]
+        if cfg.window_layout is not None:
+            window = jnp.where(
+                jnp.asarray(cfg.window_layout, jnp.bool_)[layer],
+                jnp.int32(cfg.sliding_window), jnp.int32(NO_WINDOW))
+        elif cfg.sliding_window:
+            window = jnp.int32(cfg.sliding_window)
+        return rotate, window
+
+    def _window_over(self, window, span: int):
+        """``window`` where a key span of ``span`` positions (static) can
+        reach past it, else None: where the window cannot bite, the
+        program lowers as a model without a window does."""
+        w = self.cfg.sliding_window
+        return window if w and span > w else None
+
+    @staticmethod
+    def _window_attend(q, k, v, window):
+        """Causal attention of a span over itself under a window; no
+        kernel here takes one (ROADMAP B2): masked einsum."""
+        pos = jnp.arange(q.shape[1])
+        return xla_attention(q, k, v, causal=False,
+                             mask=window_mask(pos[None, :], pos, window))
+
+    def _train_attend(self, q, k, v, window=None):
+        cfg = self.cfg
+        window = self._window_over(window, q.shape[1])
+        if window is not None:
+            if cfg.attention_impl in ("ring", "ulysses"):
+                raise ValueError("a sliding window over a sequence longer "
+                                 "than it needs attention_impl xla/auto")
+            return self._window_attend(q, k, v, window)
         impl = cfg.attention_impl
         if impl in ("ring", "ulysses"):
             if self.mesh is None:
@@ -256,7 +339,7 @@ class Attention(nn.Module):
         return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
-    def _decode_attend(self, q, k, v, positions):
+    def _decode_attend(self, q, k, v, positions, window=None):
         """Write K/V into the cache at per-row positions and attend under
         a position mask.
 
@@ -296,12 +379,13 @@ class Attention(nn.Module):
         idx.value = jnp.max(positions) + 1
         # key j is visible to the query at absolute position p iff j <= p
         # (equivalent to the old q_offset causal mask when rows align)
-        k_idx = jnp.arange(cfg.max_seq_len)
-        mask = k_idx[None, None, None, :] <= positions[:, None, :, None]
+        # and, under a window, j > p - window
+        mask = window_mask(positions, jnp.arange(cfg.max_seq_len),
+                           self._window_over(window, cfg.max_seq_len))
         return xla_attention(q, ck.value, cv.value, causal=False, mask=mask)
 
     def _decode_attend_paged(self, q, k, v, positions, block_tables,
-                             pool, layer):
+                             pool, layer, window=None):
         """Paged-pool decode: write this call's K/V into the rows' pages
         of layer ``layer``, then attend over only the occupied pages
         (ops/paged_attention.py).  Returns ``(out, pool)``.
@@ -321,6 +405,10 @@ class Attention(nn.Module):
         whole cache interaction, and right-pad garbage past
         a real prompt is overwritten by decode writes before any length
         mask makes it visible (same invariant as dense slot mode).
+
+        ``window`` (this layer's, traced; None without one): decode
+        reads only the pages that hold the last ``window`` positions,
+        and a T > 1 span longer than the window is masked by it.
         """
         cfg = self.cfg
         if self.is_initializing():
@@ -334,10 +422,15 @@ class Attention(nn.Module):
         pool = write_kv_pages(pool, jnp.concatenate([k, v], axis=-1),
                               block_tables, positions, layer=layer)
         if q.shape[1] == 1:
-            out = paged_attention(q[:, 0], pool, block_tables,
-                                  positions[:, 0] + 1, layer=layer)
+            out = paged_attention(
+                q[:, 0], pool, block_tables, positions[:, 0] + 1,
+                layer=layer, window=self._window_over(
+                    window, block_tables.shape[1] * pool.shape[3]))
             return out[:, None], pool
         if not self.prefix_attend:
+            window = self._window_over(window, q.shape[1])
+            if window is not None:
+                return self._window_attend(q, k, v, window), pool
             return xla_attention(q, k, v, causal=True), pool
         # suffix prefill: the window's keys are NOT the whole story —
         # leading block-table entries hold a cached prompt prefix, so
@@ -348,8 +441,8 @@ class Attention(nn.Module):
         # windows reduce to the causal case (their own keys were just
         # scattered), so this path is correct for any offset.
         kvfull = gather_kv_pages(pool, block_tables, layer=layer)
-        k_idx = jnp.arange(kvfull.shape[1])
-        mask = k_idx[None, None, None, :] <= positions[:, None, :, None]
+        mask = window_mask(positions, jnp.arange(kvfull.shape[1]),
+                           self._window_over(window, kvfull.shape[1]))
         return xla_attention(q, kvfull[..., :cfg.head_dim],
                              kvfull[..., cfg.head_dim:],
                              causal=False, mask=mask), pool
@@ -369,6 +462,15 @@ class Block(nn.Module):
         the shape ``stack_layers`` carries it through the stack in."""
         cfg = self.cfg
         y = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
+        moe = router_logits = None
+        if cfg.moe_experts > 0 and cfg.moe_dropless:
+            from ray_tpu.ops.moe import DroplessMoE
+            moe = DroplessMoE(cfg.d_model, cfg.moe_experts, cfg.moe_d_ff,
+                              top_k=cfg.moe_top_k, act=cfg.moe_act,
+                              dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                              name="moe")
+            if cfg.moe_router_pre_attn:
+                router_logits = moe.router_logits(y)
         y = Attention(cfg, self.mesh, self.rules, self.decode,
                       self.prefix_attend, name="attn")(
             y, cos, sin, positions, block_tables, pool, layer)
@@ -377,9 +479,11 @@ class Block(nn.Module):
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = x + y
         y = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
-        if cfg.moe_experts > 0:
+        if moe is not None:
+            y = moe(y, router_logits)
+        elif cfg.moe_experts > 0:
             from ray_tpu.ops.moe import MoEMLP
-            y = MoEMLP(cfg.moe_experts, cfg.d_ff, top_k=cfg.moe_top_k,
+            y = MoEMLP(cfg.moe_experts, cfg.moe_d_ff, top_k=cfg.moe_top_k,
                        capacity_factor=cfg.moe_capacity_factor,
                        aux_loss_coef=cfg.moe_aux_coef,
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -392,6 +496,22 @@ class Block(nn.Module):
             x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
                               self.rules)
         return x if pool is None else (x, pool)
+
+
+def output_logits(cfg: TransformerConfig, params, hidden) -> jax.Array:
+    """float32 logits of post-final-norm hidden states ``[..., d_model]``
+    (what ``GPT.__call__(return_hidden=True)`` returns) under ``params``
+    (GPT's tree): the head ``GPT.__call__`` applies, for a caller that
+    needs it on a few positions only (serve/llm_engine.py: one row a
+    prompt, not ``[wave, bucket, vocab]``)."""
+    p = nn.meta.unbox(params)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("...d,vd->...v", hidden,
+                            p["embed"].astype(cfg.dtype))
+    else:
+        logits = jnp.einsum("...d,dv->...v", hidden,
+                            p["lm_head"]["kernel"].astype(cfg.dtype))
+    return logits.astype(jnp.float32)
 
 
 class GPT(nn.Module):
@@ -472,7 +592,8 @@ class GPT(nn.Module):
             x = stack_layers(Block, cfg, block_kwargs, x,
                              call_args, remat=False,
                              cache=True, name="blocks_tail",
-                             n_layers=cfg.n_layers - n_remat)
+                             n_layers=cfg.n_layers - n_remat,
+                             first_layer=n_remat)
         else:
             x = stack_layers(Block, cfg, block_kwargs, x,
                              call_args, remat=do_remat,

@@ -116,3 +116,122 @@ class MoEMLP(nn.Module):
         aux = self.aux_loss_coef * e * jnp.sum(f * p)
         self.sow("intermediates", "moe_aux_loss", aux)
         return y.astype(x.dtype)
+
+
+# Dropless experts ---------------------------------------------------------
+
+# pairs (tokens x top_k) up to which every expert is computed for every
+# token and the gates pick; above it the pairs are sorted by expert and
+# run as one grouped product a matrix.  The decode shape (33 rows, 198
+# pairs) lies below: there the layer is bound by the experts' bytes, and
+# XLA's TPU lowering of ``ragged_dot`` at so few rows is itself an
+# all-experts product, over the 198 pair rows where this one has 33; it
+# becomes the grouped kernel from some hundreds of rows (PERF.md, PR 26)
+DENSE_PAIRS_MAX = 512
+
+
+def route_top_k(logits: jax.Array, k: int):
+    """``(gates [N, k] float32, experts [N, k] int32)`` of float32 router
+    logits ``[N, E]``: the ``k`` largest, softmax over those (softmax over
+    all, top-k, renormalised, gives the same numbers)."""
+    top, idx = jax.lax.top_k(logits.astype(jnp.float32), k)
+    return jax.nn.softmax(top, axis=-1), idx
+
+
+def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
+                     act: str = "silu") -> jax.Array:
+    """``sum_j gates[t, j] * down_e(act(gate_e x_t) * up_e x_t)`` with
+    ``e = experts[t, j]``, for EVERY pair ``(t, j)``: no capacity, nothing
+    dropped whatever the imbalance.
+
+    x [N, d]; gates, experts [N, k]; w_gate, w_up [E, d, f]; w_down
+    [E, f, d] (already in the compute dtype).  Two formulations of the
+    same sum, chosen by the static number of pairs (``DENSE_PAIRS_MAX``):
+    all experts times a gate matrix, or a sort by expert and three
+    ``jax.lax.ragged_dot`` (on the TPU a grouped-matmul kernel that reads
+    each touched expert once and computes only the pairs' rows).
+    """
+    n, d = x.shape
+    e = w_gate.shape[0]
+    k = experts.shape[1]
+    fn = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    if n * k <= DENSE_PAIRS_MAX:
+        gate_h = jnp.einsum("nd,edf->enf", x, w_gate)
+        up_h = jnp.einsum("nd,edf->enf", x, w_up)
+        # combine[n, e]: the token's gate for expert e, 0 if not chosen
+        combine = jnp.zeros((n, e), jnp.float32).at[
+            jnp.arange(n)[:, None], experts].add(gates)
+        h = (fn(gate_h) * up_h).astype(jnp.float32) * combine.T[:, :, None]
+        return jnp.einsum("enf,efd->nd", h.astype(x.dtype), w_down)
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)              # pairs by expert
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    xs = jnp.take(x, order // k, axis=0)                # [N*k, d]
+    gate_h = jax.lax.ragged_dot(xs, w_gate, sizes)
+    up_h = jax.lax.ragged_dot(xs, w_up, sizes)
+    out = jax.lax.ragged_dot(fn(gate_h) * up_h, w_down, sizes)
+    out = out * jnp.take(gates.reshape(-1), order)[:, None].astype(
+        out.dtype)
+    back = jnp.argsort(order)                           # pair -> row
+    return jnp.take(out, back, axis=0).reshape(n, k, d).sum(axis=1)
+
+
+class DroplessMoE(nn.Module):
+    """Top-k expert layer that computes every (token, chosen expert)
+    pair.  The router is a method of its own so that a block can route
+    from another tensor than the experts read (``router_logits(h)`` with
+    the attention's input, then ``__call__(m, logits)``); called with no
+    logits it routes from its own input.  Sows ``expert_idx`` (``[B, S,
+    k]`` int32) into ``intermediates`` for whoever counts the load."""
+
+    d_model: int
+    n_experts: int
+    d_ff: int
+    top_k: int = 2
+    act: str = "silu"
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    def setup(self):
+        e, d, f = self.n_experts, self.d_model, self.d_ff
+        self.router = nn.DenseGeneral(
+            e, axis=-1, use_bias=False, dtype=jnp.float32,
+            param_dtype=self.param_dtype,
+            # the TPU multiplies float32 in bf16 passes unless told not to
+            precision=jax.lax.Precision.HIGHEST,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("embed", None)))
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        self.w_gate = self.param(
+            "w_gate", nn.with_logical_partitioning(
+                init, ("expert", "expert_in", "expert_mlp")),
+            (e, d, f), self.param_dtype)
+        self.w_up = self.param(
+            "w_up", nn.with_logical_partitioning(
+                init, ("expert", "expert_in", "expert_mlp")),
+            (e, d, f), self.param_dtype)
+        self.w_down = self.param(
+            "w_down", nn.with_logical_partitioning(
+                init, ("expert", "expert_mlp", "expert_in")),
+            (e, f, d), self.param_dtype)
+
+    def router_logits(self, h: jax.Array) -> jax.Array:
+        """float32 logits ``[B, S, E]`` (float32 input, weights and
+        accumulation: a near-tie of the k-th expert decides a whole
+        expert's contribution)."""
+        return self.router(h.astype(jnp.float32))
+
+    def __call__(self, x: jax.Array, logits=None) -> jax.Array:
+        b, s, d = x.shape
+        if logits is None:
+            logits = self.router_logits(x)
+        gates, experts = route_top_k(logits.reshape(b * s, -1), self.top_k)
+        self.sow("intermediates", "expert_idx",
+                 experts.reshape(b, s, self.top_k))
+        dt = self.dtype
+        y = dropless_experts(
+            x.reshape(b * s, d).astype(dt), gates, experts,
+            self.w_gate.astype(dt), self.w_up.astype(dt),
+            self.w_down.astype(dt), act=self.act)
+        return y.reshape(b, s, d).astype(x.dtype)

@@ -92,7 +92,7 @@ import numpy as np
 
 from ray_tpu._private.profiler import span
 from ray_tpu.models.configs import TransformerConfig
-from ray_tpu.models.gpt import GPT
+from ray_tpu.models.gpt import GPT, output_logits
 from ray_tpu.serve.frontdoor.prefix import page_digests
 
 # admission waves are padded to the next of these sizes (bounded jit
@@ -266,6 +266,17 @@ class EngineStats:
         self.prefill_waves = 0           # prefill programs dispatched
         self.prefill_prompt_tokens = 0   # real prompt tokens in them
         self.prefill_padded_tokens = 0   # wave x bucket: what they computed
+        # dropless expert layers (ops/moe.py), counted on the device over
+        # the rows that hold a request and fetched with each block's
+        # tokens: a layer step is one expert layer in one decode step
+        self.moe_layer_steps = 0
+        self.moe_experts_touched = 0     # experts with >= 1 pair, summed
+        # pages the window layers' decode reads took (window_pages_read)
+        # and left out because they lie wholly behind the window
+        # (window_pages_skipped), over delivered tokens; read + skipped
+        # is what the lengths alone would have read
+        self.window_pages_read = 0
+        self.window_pages_skipped = 0
         # seconds of the loop thread, advanced at each phase's end
         # (_Phase): loop_s is its whole life, the rest are parts of it.
         # 1 - fetch_wait_s / (loop_s - idle_wait_s) is the share of its
@@ -300,6 +311,10 @@ class EngineStats:
             "prefill_waves": self.prefill_waves,
             "prefill_prompt_tokens": self.prefill_prompt_tokens,
             "prefill_padded_tokens": self.prefill_padded_tokens,
+            "moe_layer_steps": self.moe_layer_steps,
+            "moe_experts_touched": self.moe_experts_touched,
+            "window_pages_read": self.window_pages_read,
+            "window_pages_skipped": self.window_pages_skipped,
             "loop_s": self.loop_s,
             "idle_wait_s": self.idle_wait_s,
             "fetch_wait_s": self.fetch_wait_s,
@@ -396,6 +411,16 @@ class LLMEngine:
         else:
             self.model = GPT(cfg, decode=True)
         self.stats = EngineStats()
+        # the paged block program also returns the dropless expert
+        # layers' load (EngineStats.moe_*); a model without them
+        # compiles the program it always did
+        self._counts_expert_load = bool(
+            paged and cfg.moe_experts and cfg.moe_dropless)
+        # layers whose decode reads stop at the window
+        self._window_layers = (
+            0 if not (paged and cfg.sliding_window) else cfg.n_layers
+            if cfg.window_layout is None
+            else sum(cfg.window_layout[:cfg.n_layers]))
 
         self._rng = jax.random.PRNGKey(seed)
         self._lock = threading.Condition()
@@ -520,6 +545,20 @@ class LLMEngine:
         return sample_logits(rng, logits, temperature=temps,
                              top_k=self.top_k, top_p=self.top_p)
 
+    def _last_logits(self, model, params, cache, tokens, positions,
+                     s_reals, **kwargs):
+        """``(logits [wave, vocab] of each row's last REAL position, the
+        updated cache)``.  The head runs on those rows alone: float32
+        logits of every position are ``wave x bucket x vocab`` (1.2 GB
+        for one 2048-token prompt at a 152k vocabulary), of which one
+        row a prompt is read."""
+        hidden, mut = model.apply(
+            {"params": params, "cache": cache}, tokens, positions,
+            return_hidden=True, mutable=["cache"], **kwargs)
+        last = jnp.take_along_axis(
+            hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0]
+        return output_logits(self.cfg, params, last), mut["cache"]
+
     def _get_prefill(self, bucket: int, wave: int):
         fn = self._prefill_jit.get((bucket, wave))
         if fn is None:
@@ -535,13 +574,10 @@ class LLMEngine:
                 b, s = tokens.shape
                 positions = jnp.broadcast_to(jnp.arange(s), (b, s))
                 cache = self._init_cache(b)
-                logits, mut = self.model.apply(
-                    {"params": params, "cache": cache}, tokens, positions,
-                    mutable=["cache"])
-                last = jnp.take_along_axis(
-                    logits, (s_reals - 1)[:, None, None], axis=1)[:, 0]
+                last, cache = self._last_logits(
+                    self.model, params, cache, tokens, positions, s_reals)
                 first = self._sample_fn(rng, last, temps)
-                return first, mut["cache"], slots
+                return first, cache, slots
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
                 engine_prefill)
         return fn
@@ -624,13 +660,11 @@ class LLMEngine:
                 temps = packed[:, bucket + 1].astype(jnp.float32) / 1e6
                 b, s = tokens.shape
                 positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-                logits, mut = self.model.apply(
-                    {"params": params, "cache": cache}, tokens, positions,
-                    block_tables=tables, mutable=["cache"])
-                last = jnp.take_along_axis(
-                    logits, (s_reals - 1)[:, None, None], axis=1)[:, 0]
+                last, cache = self._last_logits(
+                    self.model, params, cache, tokens, positions, s_reals,
+                    block_tables=tables)
                 first = self._sample_fn(rng, last, temps)
-                return first, mut["cache"]
+                return first, cache
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
                 engine_prefill, donate_argnums=(1,))
         return fn
@@ -653,13 +687,11 @@ class LLMEngine:
                 b, s = tokens.shape
                 positions = offs[:, None] + jnp.broadcast_to(
                     jnp.arange(s), (b, s))
-                logits, mut = self.model_prefix.apply(
-                    {"params": params, "cache": cache}, tokens, positions,
-                    block_tables=tables, mutable=["cache"])
-                last = jnp.take_along_axis(
-                    logits, (s_reals - 1)[:, None, None], axis=1)[:, 0]
+                last, cache = self._last_logits(
+                    self.model_prefix, params, cache, tokens, positions,
+                    s_reals, block_tables=tables)
                 first = self._sample_fn(rng, last, temps)
-                return first, mut["cache"]
+                return first, cache
             fn = self._suffix_jit[(bucket, wave)] = jax.jit(
                 engine_prefill_suffix, donate_argnums=(1,))
         return fn
@@ -758,23 +790,52 @@ class LLMEngine:
         # step — 28 idle rows did twice the work of the whole model
         # (PERF.md, PR 25)
         live = tables[:, 0] != 0
+        load = self._counts_expert_load
 
         def one(carry, key):
             tokens, positions, cache = carry
             logits, mut = self.model.apply(
                 {"params": params, "cache": cache}, tokens[:, None],
                 positions[:, None], block_tables=tables,
-                mutable=["cache"])
+                mutable=["cache", "intermediates"] if load else ["cache"])
             nxt = self._sample_fn(key, logits[:, -1], temps)
             positions = jnp.where(
                 live, jnp.minimum(positions + 1,
                                   self.cfg.max_seq_len - 1), 0)
-            return (nxt, positions, mut["cache"]), nxt
+            out = (nxt, self._expert_load(mut["intermediates"], live)
+                   ) if load else nxt
+            return (nxt, positions, mut["cache"]), out
 
         (tokens, positions, cache), block = jax.lax.scan(
             one, (tokens, positions, cache), keys)
-        return (block.T.reshape(-1),
-                (tokens, positions, temps, tables, rng), cache)
+        if load:
+            # two numbers ride the block's ONE fetch
+            block, (steps, touched) = block
+            combined = jnp.concatenate([
+                block.T.reshape(-1), jnp.stack(
+                    [steps.sum(), touched.sum()])])
+        else:
+            combined = block.T.reshape(-1)
+        return combined, (tokens, positions, temps, tables, rng), cache
+
+    def _expert_load(self, intermediates, live):
+        """One decode step's expert load over the rows that hold a
+        request, from the ``expert_idx`` every dropless expert layer
+        sows (``[.., rows, 1, k]``, stacked by the layer scan): int32
+        ``(layer steps with a live row, experts touched summed over
+        them)``.  On the v5e 2.3 us of a decode step of 11.4 ms
+        (PERF.md, PR 26)."""
+        e = self.cfg.moe_experts
+        idx = jnp.concatenate([
+            leaf.reshape(-1, self._rows, leaf.shape[-1])
+            for path, leaf in jax.tree_util.tree_leaves_with_path(
+                intermediates)
+            if "expert_idx" in jax.tree_util.keystr(path)])   # [L, rows, k]
+        counts = (jax.nn.one_hot(idx, e, dtype=jnp.int32)
+                  * live[None, :, None, None].astype(jnp.int32)
+                  ).sum(axis=(1, 2))                          # [L, E]
+        return ((counts.sum(axis=-1) > 0).sum().astype(jnp.int32),
+                (counts > 0).sum().astype(jnp.int32))
 
     # ------------------------------------------------------------- public
 
@@ -1416,6 +1477,7 @@ class LLMEngine:
                 sl = self._slots[i]
                 if sl is None or sl.request is not req:
                     continue      # evicted earlier (or reused): junk row
+                pos0 = sl.pos
                 for k in range(self.block_size):
                     tok = int(block[i, k])
                     sl.out.append(tok)
@@ -1427,8 +1489,31 @@ class LLMEngine:
                         self._safe_on_token(sl.request, tok)
                     if self._maybe_finish(i):
                         break     # rest of the row is junk past eos
+                if self._window_layers:
+                    self._count_window_pages(pos0 + 1, sl.pos)
             sp.set_metadata(tokens=st.step_tokens - tokens0,
                             finished=st.requests_completed - done0)
+
+    def _count_window_pages(self, first: int, last: int) -> None:
+        """``EngineStats.window_pages_*`` for one row's delivered steps,
+        which read ``first .. last`` positions: the page arithmetic of
+        ``ops/paged_attention.py _tpu_kernel`` (a step over ``n``
+        positions loops pages ``max(0, n - window) // page_size`` to
+        ``ceil(n / page_size)``) summed in closed form, once a row a
+        block.  Host arithmetic on positions the loop already holds: it
+        says what the window leaves unread, not that the kernel did so
+        (the benchmark's on-chip kernel check and timing do)."""
+        ps, w = self.page_size, self.cfg.sliding_window
+
+        def floors(n):            # sum of k // ps for k = 0 .. n
+            q, r = divmod(n, ps)
+            return ps * q * (q - 1) // 2 + q * (r + 1) if n > 0 else 0
+
+        by_length = floors(last + ps - 1) - floors(first + ps - 2)
+        skipped = floors(last - w) - floors(max(first, w) - w - 1)
+        st = self.stats
+        st.window_pages_skipped += skipped * self._window_layers
+        st.window_pages_read += (by_length - skipped) * self._window_layers
 
     # ------------------------------------------------- prompt-prefix cache
     #
@@ -2024,5 +2109,10 @@ class LLMEngine:
         combined, rows = quantum
         with self._phase("fetch_block", "fetch_wait_s"):
             host = np.asarray(combined)    # the ONE fetch this quantum
+        if self._counts_expert_load:
+            st = self.stats
+            host, (steps, touched) = host[:-2], host[-2:]
+            st.moe_layer_steps += int(steps)
+            st.moe_experts_touched += int(touched)
         self._deliver_block(host.reshape(self._rows, self.block_size),
                             rows)
