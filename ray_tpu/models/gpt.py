@@ -457,9 +457,11 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
-                 pool=None, layer=None):
+                 moe_stacked=None, pool=None, layer=None):
         """With a paged KV ``pool`` (see Attention) returns ``(x, pool)``:
-        the shape ``stack_layers`` carries it through the stack in."""
+        the shape ``stack_layers`` carries it through the stack in.
+        ``moe_stacked``: the layer stack's whole dropless expert leaves
+        (GPT hands them down in decode; see ``DroplessMoE.__call__``)."""
         cfg = self.cfg
         y = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
         moe = router_logits = None
@@ -480,7 +482,10 @@ class Block(nn.Module):
         x = x + y
         y = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
         if moe is not None:
-            y = moe(y, router_logits)
+            # a row whose table starts at the scratch page holds no
+            # request (serve/llm_engine.py): its experts are not read
+            live = None if block_tables is None else block_tables[:, 0] != 0
+            y = moe(y, router_logits, live, moe_stacked, layer)
         elif cfg.moe_experts > 0:
             from ray_tpu.ops.moe import MoEMLP
             y = MoEMLP(cfg.moe_experts, cfg.moe_d_ff, top_k=cfg.moe_top_k,
@@ -527,6 +532,20 @@ class GPT(nn.Module):
     page_size: int = 64
     prefix_attend: bool = False            # suffix prefill over cached pages
 
+    def _moe_stacked(self):
+        """The scanned layer stack's dropless expert leaves ``[L, E, ...]``
+        whole, for a decode-mode model (no gradient is taken through the
+        kernel that reads them): they ride ``call_args`` into the scan
+        body beside the layer index, as the KV pool rides the carry, so
+        that no layer's experts are sliced out.  None where there is no
+        such stack (no dropless experts, unrolled layers, initialising)."""
+        cfg = self.cfg
+        if not (self.decode and cfg.moe_dropless and cfg.moe_experts
+                and cfg.scan_layers) or self.is_initializing():
+            return None
+        moe = nn.meta.unbox(self.variables["params"]["blocks"]["moe"])
+        return moe["w_gate"], moe["w_up"], moe["w_down"]
+
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False,
                  block_tables=None):
@@ -566,7 +585,8 @@ class GPT(nn.Module):
         block_kwargs = dict(mesh=self.mesh, rules=self.rules,
                             decode=self.decode,
                             prefix_attend=self.prefix_attend)
-        call_args = (cos, sin, positions, block_tables)
+        call_args = (cos, sin, positions, block_tables,
+                     self._moe_stacked())
         if self.decode and self.paged_pages:
             # the paged KV pool: ONE stacked leaf for the whole model,
             # K in [..., :hd], V in [..., hd:] (layout dictated by TPU

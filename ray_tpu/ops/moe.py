@@ -21,6 +21,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention import backend_platform
+
 
 class MoEMLP(nn.Module):
     """Drop-in SwiGLU MLP with ``n_experts`` experts and top-k routing.
@@ -120,14 +122,21 @@ class MoEMLP(nn.Module):
 
 # Dropless experts ---------------------------------------------------------
 
-# pairs (tokens x top_k) up to which every expert is computed for every
-# token and the gates pick; above it the pairs are sorted by expert and
+# pairs (tokens x top_k) up to which each touched expert is read once for
+# all rows and the gates pick; above it the pairs are sorted by expert and
 # run as one grouped product a matrix.  The decode shape (33 rows, 198
 # pairs) lies below: there the layer is bound by the experts' bytes, and
-# XLA's TPU lowering of ``ragged_dot`` at so few rows is itself an
-# all-experts product, over the 198 pair rows where this one has 33; it
-# becomes the grouped kernel from some hundreds of rows (PERF.md, PR 26)
+# XLA's TPU lowering of ``ragged_dot`` at so few rows is an all-experts
+# product over the 198 pair rows; it becomes the grouped kernel from some
+# hundreds of rows (PERF.md, PR 26).  Below it the TPU runs the
+# ``moe_experts_decode`` kernel, which reads only the experts that live
+# rows chose (PERF.md, PR 27); elsewhere every expert is multiplied
 DENSE_PAIRS_MAX = 512
+
+# the kernel's double-buffered blocks of the three matrices may take this
+# much VMEM before the expert width is tiled (a v5e core has 128 MiB;
+# SmallThinker's expert is 11.8 MB, 23.6 MB double-buffered)
+_KERNEL_WEIGHTS_VMEM = 40 << 20
 
 
 def route_top_k(logits: jax.Array, k: int):
@@ -138,29 +147,165 @@ def route_top_k(logits: jax.Array, k: int):
     return jax.nn.softmax(top, axis=-1), idx
 
 
+def touched_experts(experts: jax.Array, live, n_experts: int):
+    """``(ids [min(E, N * k)] int32, count [] int32)``: the experts that
+    at least one row of ``live`` (``[N]`` bool; None: every row) chose in
+    ``experts [N, k]``, ascending, padded by repeating the last one.
+    ``count`` is what ``LLMEngine._expert_load`` counts for the layer."""
+    n, k = experts.shape
+    chosen = experts[..., None] == jnp.arange(n_experts)       # [N, k, E]
+    if live is not None:
+        chosen = chosen & live[:, None, None]
+    hit = chosen.any(axis=(0, 1))                               # [E]
+    count = hit.sum().astype(jnp.int32)
+    # slot s holds the s-th touched id: a compare and a sum, no sort
+    slot = jnp.arange(min(n_experts, n * k))[:, None]
+    place = hit & (jnp.cumsum(hit) - 1 == slot)                 # [S, E]
+    ids = (place * jnp.arange(n_experts)).sum(axis=1).astype(jnp.int32)
+    return jnp.where(slot[:, 0] < count, ids, ids.max()), count
+
+
+def expert_kernel_applies(pairs: int, d: int, f: int) -> bool:
+    """Whether ``pairs`` (rows x top_k) on STACKED experts of widths
+    ``d``, ``f`` run the Pallas kernel: the small-pair formulation, on
+    the TPU, at lane-aligned widths (a choice by shape and platform, as
+    ``paged_attention`` makes it)."""
+    return (pairs <= DENSE_PAIRS_MAX and d % 128 == 0 and f % 128 == 0
+            and backend_platform() == "tpu")
+
+
+def _width_tile(d: int, f: int, itemsize: int) -> int:
+    """The widest slice of an expert's ``f`` (a multiple of 128 that
+    divides it) whose double-buffered blocks fit the VMEM budget."""
+    tiles = [t for t in range(f, 0, -128) if f % t == 0]
+    fits = [t for t in tiles
+            if 2 * 3 * d * t * itemsize <= _KERNEL_WEIGHTS_VMEM]
+    return (fits or tiles[-1:])[0]
+
+
+def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
+                    fn) -> jax.Array:
+    """Pallas TPU kernel of the small-pair formulation: grid over the
+    slots of ``ids`` (and slices of the expert width), the block of each
+    matrix picked by ``(layer, ids[slot])`` out of the STACKED weights
+    ``[L, E, d, f]`` / ``[L, E, f, d]``, which stay whole in HBM.  Slots
+    past ``count`` repeat the block before them, so nothing is fetched
+    for them, and their arithmetic is skipped.  Rounding as the
+    all-experts formulation: bf16 operands, float32 accumulation, the
+    activation product rounded, scaled by the float32 gate, rounded for
+    the down product, summed over experts in float32, rounded once.
+
+    x [N, d] (N a multiple of 16); combine [N, E] float32; ids [S],
+    count [1], layer [1] int32 (scalar-prefetch operands)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = x.shape
+    e, f = w_gate.shape[1], w_gate.shape[3]
+    slots = ids.shape[0]
+    tf = _width_tile(d, f, x.dtype.itemsize)
+    nf = f // tf
+
+    def kernel(ids_ref, count_ref, layer_ref, x_ref, c_ref, wg_ref, wu_ref,
+               wd_ref, o_ref, acc_ref):
+        s, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when((s == 0) & (j == 0))
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(s < count_ref[0])
+        def _():
+            xv = x_ref[...]
+            gate_h = jnp.dot(xv, wg_ref[...],
+                             preferred_element_type=jnp.float32)
+            up_h = jnp.dot(xv, wu_ref[...],
+                           preferred_element_type=jnp.float32)
+            # this expert's column of the gates, picked by a lane mask
+            lane = jax.lax.broadcasted_iota(jnp.int32, (n, e), 1)
+            gate = jnp.sum(jnp.where(lane == ids_ref[s], c_ref[...], 0.0),
+                           axis=1, keepdims=True)               # [N, 1]
+            h = (fn(gate_h.astype(xv.dtype).astype(jnp.float32))
+                 * up_h.astype(xv.dtype).astype(jnp.float32))
+            h = h.astype(xv.dtype).astype(jnp.float32) * gate
+            acc_ref[...] += jnp.dot(h.astype(xv.dtype), wd_ref[...],
+                                    preferred_element_type=jnp.float32)
+
+        @pl.when((s == slots - 1) & (j == nf - 1))
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    def weights(down: bool):
+        def index(s, j, ids, count, layer):
+            # a slot past the count names the block fetched last
+            j = jnp.where(s < count[0], j, nf - 1)
+            return (layer[0], ids[s]) + ((j, 0) if down else (0, j))
+        return pl.BlockSpec((None, None) + ((tf, d) if down else (d, tf)),
+                            index)
+
+    whole = lambda *_: (0, 0)                                   # noqa: E731
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                  # ids, count, layer
+            grid=(slots, nf),
+            in_specs=[pl.BlockSpec((n, d), whole),
+                      pl.BlockSpec((n, e), whole),
+                      weights(False), weights(False), weights(True)],
+            out_specs=pl.BlockSpec((n, d), whole),
+            scratch_shapes=[pltpu.VMEM((n, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_KERNEL_WEIGHTS_VMEM + (16 << 20)),
+        name="moe_experts_decode",
+    )(ids, count, layer, x, combine, w_gate, w_up, w_down)
+
+
 def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
-                     act: str = "silu") -> jax.Array:
+                     act: str = "silu", live=None, layer=None) -> jax.Array:
     """``sum_j gates[t, j] * down_e(act(gate_e x_t) * up_e x_t)`` with
     ``e = experts[t, j]``, for EVERY pair ``(t, j)``: no capacity, nothing
     dropped whatever the imbalance.
 
     x [N, d]; gates, experts [N, k]; w_gate, w_up [E, d, f]; w_down
-    [E, f, d] (already in the compute dtype).  Two formulations of the
-    same sum, chosen by the static number of pairs (``DENSE_PAIRS_MAX``):
-    all experts times a gate matrix, or a sort by expert and three
-    ``jax.lax.ragged_dot`` (on the TPU a grouped-matmul kernel that reads
-    each touched expert once and computes only the pairs' rows).
-    """
+    [E, f, d] (already in the compute dtype), or with ``layer`` (an int32
+    scalar, traced or not) a layer stack's whole leaves ``[L, E, ...]``,
+    of which layer ``layer`` is read.  Two formulations of the same sum,
+    chosen by the static number of pairs (``DENSE_PAIRS_MAX``).  Up to
+    it, the gates pick among experts that are each read once for all
+    rows (``live [N]`` bool: only those rows count and the others come
+    out zero; None: every row): of stacked weights, on the TPU at
+    lane-aligned widths, the ``moe_experts_decode`` kernel reads the
+    ``touched_experts`` in place; otherwise, and as the kernel's oracle,
+    all experts are multiplied (a kernel fed a scan's slice of the
+    stack would have XLA copy the layer's experts out first, and no
+    gradient is defined through it: it is the decode path).  Above it,
+    a sort by expert and three ``jax.lax.ragged_dot`` (on the TPU a
+    grouped-matmul kernel that reads each touched expert once and
+    computes only the pairs' rows; ``live`` is not looked at)."""
     n, d = x.shape
-    e = w_gate.shape[0]
+    e, f = w_gate.shape[-3], w_gate.shape[-1]
     k = experts.shape[1]
     fn = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    kernel = layer is not None and expert_kernel_applies(n * k, d, f)
+    if layer is not None and not kernel:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
     if n * k <= DENSE_PAIRS_MAX:
-        gate_h = jnp.einsum("nd,edf->enf", x, w_gate)
-        up_h = jnp.einsum("nd,edf->enf", x, w_up)
+        if live is not None:
+            gates = jnp.where(live[:, None], gates, 0.0)
         # combine[n, e]: the token's gate for expert e, 0 if not chosen
         combine = jnp.zeros((n, e), jnp.float32).at[
             jnp.arange(n)[:, None], experts].add(gates)
+        if kernel:
+            ids, count = touched_experts(experts, live, e)
+            pad = (0, -n % 16), (0, 0)             # whole bf16 sublane tiles
+            return _experts_kernel(
+                jnp.pad(x, pad), jnp.pad(combine, pad), ids,
+                count.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
+                w_gate, w_up, w_down, fn)[:n]
+        gate_h = jnp.einsum("nd,edf->enf", x, w_gate)
+        up_h = jnp.einsum("nd,edf->enf", x, w_up)
         h = (fn(gate_h) * up_h).astype(jnp.float32) * combine.T[:, :, None]
         return jnp.einsum("enf,efd->nd", h.astype(x.dtype), w_down)
     flat = experts.reshape(-1)
@@ -222,7 +367,14 @@ class DroplessMoE(nn.Module):
         expert's contribution)."""
         return self.router(h.astype(jnp.float32))
 
-    def __call__(self, x: jax.Array, logits=None) -> jax.Array:
+    def __call__(self, x: jax.Array, logits=None, live=None, stacked=None,
+                 layer=None) -> jax.Array:
+        """``live [B]`` bool: the rows that hold a request (None: all).
+        ``stacked``: the whole ``(w_gate, w_up, w_down)`` leaves ``[L, E,
+        ...]`` of the layer stack this layer is scanned in, and ``layer``
+        its index there.  The decode kernel reads them in place; handed
+        this layer's slice of the scan, a custom call would make XLA
+        copy the layer's experts out first (PERF.md, PR 26)."""
         b, s, d = x.shape
         if logits is None:
             logits = self.router_logits(x)
@@ -230,8 +382,17 @@ class DroplessMoE(nn.Module):
         self.sow("intermediates", "expert_idx",
                  experts.reshape(b, s, self.top_k))
         dt = self.dtype
+        weights = self.w_gate, self.w_up, self.w_down
+        if (stacked is not None and layer is not None
+                and stacked[0].dtype == dt
+                and expert_kernel_applies(b * s * self.top_k, d, self.d_ff)):
+            weights = stacked
+        else:           # this layer's own slice, as the scan hands it over
+            layer = None
+        if live is not None:
+            live = jnp.repeat(live, s)
         y = dropless_experts(
             x.reshape(b * s, d).astype(dt), gates, experts,
-            self.w_gate.astype(dt), self.w_up.astype(dt),
-            self.w_down.astype(dt), act=self.act)
+            *(w.astype(dt) for w in weights), act=self.act, live=live,
+            layer=layer)
         return y.reshape(b, s, d).astype(x.dtype)

@@ -54,7 +54,7 @@ def on_tpu(monkeypatch):
     """Make kernel dispatch answer "tpu" (compiled Pallas, not the
     interpreter): the compile target is the described chip, while
     ``jax.devices()`` here is the CPU."""
-    for name in ("attention", "flash_attention", "paged_attention"):
+    for name in ("attention", "flash_attention", "paged_attention", "moe"):
         mod = importlib.import_module(f"ray_tpu.ops.{name}")
         monkeypatch.setattr(mod, "backend_platform", lambda: "tpu")
 
@@ -248,17 +248,12 @@ def _pool_result_producers(hlo: str, sizes):
         for op, c in found)
 
 
-def _smollm_engine(pages, monkeypatch):
-    """SmolLM2-360M's head shapes (15 heads, 5 KV heads of 64), 4 scanned
-    layers, and a pool that dwarfs weights and activations — described,
-    not allocated: parameters and cache are shapes."""
-    from ray_tpu.models.configs import get_config
+def _described_engine(cfg, monkeypatch, **kw):
+    """A paged ``LLMEngine`` of ``cfg`` that is described, not allocated:
+    parameters and cache are shapes."""
     from ray_tpu.models.gpt import GPT
     from ray_tpu.serve.llm_engine import LLMEngine
 
-    cfg = get_config("gpt-small", n_layers=4, d_model=960, n_heads=15,
-                     n_kv_heads=5, d_ff=2560, vocab_size=49152,
-                     tie_embeddings=True, dtype=jnp.bfloat16)
     assert cfg.scan_layers
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, cfg.dtype),
@@ -271,8 +266,20 @@ def _smollm_engine(pages, monkeypatch):
         patch.setattr(
             generate, "init_decode_cache",
             lambda model, batch: jax.eval_shape(lambda: init(model, batch)))
-        return LLMEngine(cfg, params, num_slots=32, max_seq_len=2560,
-                         paged=True, page_size=64, kv_pool_pages=pages)
+        return LLMEngine(cfg, params, num_slots=32, paged=True,
+                         page_size=64, **kw)
+
+
+def _smollm_engine(pages, monkeypatch):
+    """SmolLM2-360M's head shapes (15 heads, 5 KV heads of 64), 4 scanned
+    layers, and a pool that dwarfs weights and activations."""
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("gpt-small", n_layers=4, d_model=960, n_heads=15,
+                     n_kv_heads=5, d_ff=2560, vocab_size=49152,
+                     tie_embeddings=True, dtype=jnp.bfloat16)
+    return _described_engine(cfg, monkeypatch, max_seq_len=2560,
+                             kv_pool_pages=pages)
 
 
 def _compiled_engine_programs(pages, one_chip, monkeypatch):
@@ -336,3 +343,40 @@ def test_engine_programs_address_the_pool_in_place(topo, one_chip, on_tpu,
             assert abs(moved4 - moved) < 0.1 * moved, (
                 f"{name}: bytes accessed {moved / 1e6:.1f} MB at {pages} "
                 f"pages, {moved4 / 1e6:.1f} MB at {4 * pages}")
+
+
+# ---- decode reads the stacked expert weights in place (ISSUE 27) ----
+
+def test_decode_block_reads_the_stacked_experts_in_place(topo, one_chip,
+                                                         on_tpu, monkeypatch):
+    """``engine_decode_block`` at SmallThinker's widths (2 of its layers,
+    the serve-reason cell's slots, pages and context) holds the
+    ``moe_experts_decode`` kernel over the STACKED expert leaves, and
+    nothing in the compiled program produces a layer's experts (a custom
+    call fed the scan's slice would: 0.755 GB copied a layer), with
+    temporaries far under one layer's expert weights."""
+    import re
+
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("smallthinker-21b-a3b", n_layers=2, max_seq_len=6144,
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    eng = _described_engine(cfg, monkeypatch, max_seq_len=6144)
+    block = eng._block_jit.lower(
+        *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                 one_chip))
+    assert 'kernel_name = "moe_experts_decode"' in block.as_text()
+    assert 'kernel_name = "paged_attention_decode"' in block.as_text()
+    compiled = block.compile()
+    hlo = compiled.as_text()
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*%?moe_experts_decode\S* = ", line)]
+    assert call.count("bf16[2,64,2560,768]") == 2       # w_gate, w_up
+    assert call.count("bf16[2,64,768,2560]") == 1       # w_down
+    one_matrix = 64 * 2560 * 768
+    made = _pool_result_producers(hlo, (one_matrix,))
+    assert not made, f"a layer's experts are produced by {dict(made)}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.05 * 2 * one_matrix, (
+        f"{temp / 1e6:.1f} MB of temporaries; one matrix of a layer's "
+        f"experts is {2 * one_matrix / 1e6:.1f} MB")

@@ -178,6 +178,15 @@ def test_the_engine_s_greedy_tokens_are_the_reference_s(parts):
     assert skipped > 0
 
 
+def _interpret_pallas(monkeypatch):
+    """Run every ``pallas_call`` under jax's TPU interpreter."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(pl, "pallas_call", functools.partial(
+        pl.pallas_call, interpret=pltpu.InterpretParams()))
+
+
 @pytest.mark.parametrize("window", [16, 40, 1 << 30],
                          ids=["page", "part-pages", "none"])
 def test_pallas_decode_kernel_honours_the_window_in_tpu_interpreter(
@@ -186,13 +195,10 @@ def test_pallas_decode_kernel_honours_the_window_in_tpu_interpreter(
     the same window, and far from the answer without one."""
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
     from ray_tpu.ops.paged_attention import (paged_attention_tpu,
                                              paged_attention_xla)
 
-    monkeypatch.setattr(pl, "pallas_call", functools.partial(
-        pl.pallas_call, interpret=pltpu.InterpretParams()))
+    _interpret_pallas(monkeypatch)
     rs = np.random.RandomState(window % 97)
     pool = jnp.asarray(rs.randn(3, 13, 2, 16, 128), jnp.bfloat16)
     q = jnp.asarray(rs.randn(4, 4, 64), jnp.bfloat16)
@@ -259,6 +265,175 @@ def test_nothing_is_dropped_under_total_imbalance(parts, monkeypatch,
                       published)
     np.testing.assert_allclose(got[0], want, atol=3e-5, rtol=1e-5)
     assert np.abs(np.asarray(want)).max() > 0.1
+
+
+# ---- the touched-experts decode kernel (ISSUE 27) ----
+
+def _interpreted_expert_kernel(monkeypatch):
+    """Make ``ops/moe.py`` take its TPU branch, with the kernel under
+    jax's TPU interpreter (the paged kernel keeps its XLA branch).
+    Returns the list that collects the shape of the expert weights each
+    kernel is built over."""
+    from ray_tpu.ops import moe
+
+    built, kernel = [], moe._experts_kernel
+
+    def spy(x, combine, ids, count, layer, w_gate, *rest):
+        built.append(w_gate.shape)
+        return kernel(x, combine, ids, count, layer, w_gate, *rest)
+    monkeypatch.setattr(moe, "_experts_kernel", spy)
+    monkeypatch.setattr(moe, "backend_platform", lambda: "tpu")
+    _interpret_pallas(monkeypatch)
+    return built
+
+
+def _routing(case, rows, e, k, rs):
+    """``(experts [rows, k], live [rows])`` of a named routing."""
+    import numpy as np
+    experts = np.stack([rs.permutation(e)[:k] for _ in range(rows)])
+    live = np.ones(rows, bool)
+    if case == "one-live-row":
+        live[:] = False
+        live[3] = True
+    elif case == "total-imbalance":
+        experts[:] = [1, 4, 6]
+    elif case == "all-touched":
+        experts[:4] = np.arange(4 * k).reshape(4, k) % e
+    elif case == "dead-rows-elsewhere":     # live rows on 0-3, dead on 4-7
+        live[1::2] = False
+        experts[live] = np.stack([rs.permutation(4)[:k] for _ in
+                                  range(live.sum())])
+        experts[~live] = 4 + np.stack([rs.permutation(4)[:k] for _ in
+                                       range((~live).sum())])
+    return experts, live
+
+
+@pytest.mark.parametrize("layers,vmem", [(3, None), (1, None), (3, 1)],
+                         ids=["stacked", "one-layer", "width-tiled"])
+@pytest.mark.parametrize("case", ["one-live-row", "total-imbalance",
+                                  "all-touched", "dead-rows-elsewhere",
+                                  "no-mask"])
+def test_expert_kernel_reads_what_live_rows_chose(monkeypatch, case,
+                                                  layers, vmem):
+    """The ``moe_experts_decode`` kernel (TPU interpreter, lane-aligned
+    small widths, bf16) against the all-experts formulation; the id list
+    it walks holds exactly the experts the live rows chose, which is what
+    ``LLMEngine._expert_load`` counts; dead rows come out zero.
+    ``width-tiled``: an expert too wide for the kernel's VMEM budget is
+    walked in slices of 128 of its width (a second grid axis)."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops import moe
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    e, d, f, rows, k = 8, 128, 256, 9, 3
+    rs = np.random.RandomState(len(case))
+    experts, live = _routing(case, rows, e, k, rs)
+    mask = None if case == "no-mask" else jnp.asarray(live)
+    x = jnp.asarray(rs.randn(rows, d), jnp.bfloat16)
+    gates = jnp.asarray(rs.dirichlet(np.ones(k), rows), jnp.float32)
+    w = [jnp.asarray(rs.randn(layers, e, a, b) / a ** 0.5, jnp.bfloat16)
+         for a, b in ((d, f), (d, f), (f, d))]
+    layer = jnp.int32(layers - 1)
+    args = (x, gates, jnp.asarray(experts, jnp.int32), *w)
+    run = jax.jit(lambda *a: moe.dropless_experts(
+        *a, act="relu", live=mask, layer=layer))
+    want = np.asarray(run(*args), np.float32)        # every expert read
+    built = _interpreted_expert_kernel(monkeypatch)
+    if vmem:
+        monkeypatch.setattr(moe, "_KERNEL_WEIGHTS_VMEM", vmem)
+        assert moe._width_tile(d, f, 2) == 128
+    run = jax.jit(lambda *a: moe.dropless_experts(
+        *a, act="relu", live=mask, layer=layer))
+    got = np.asarray(run(*args), np.float32)
+    assert built == [(layers, e, d, f)]
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    assert np.abs(want[live]).max() > 0.3
+    if mask is not None:
+        assert (got[~live] == 0).all() and (want[~live] == 0).all()
+
+    ids, count = moe.touched_experts(jnp.asarray(experts), mask, e)
+    chosen = sorted(set(experts[live].reshape(-1)))
+    assert ids.shape == (min(e, rows * k),)
+    assert list(np.asarray(ids)[:int(count)]) == chosen
+    assert (np.asarray(ids)[int(count):] == chosen[-1]).all()
+    assert {"one-live-row": k, "total-imbalance": 3, "all-touched": e,
+            "dead-rows-elsewhere": 4}.get(case, len(chosen)) == len(chosen)
+    steps, touched = LLMEngine._expert_load(
+        types.SimpleNamespace(cfg=types.SimpleNamespace(moe_experts=e),
+                              _rows=rows),
+        {"expert_idx": jnp.asarray(experts)[None, :, None]},
+        jnp.asarray(live))
+    assert (int(steps), int(touched)) == (1, int(count))
+
+
+def test_no_live_row_reads_no_expert(monkeypatch):
+    """An engine with nothing in flight: the kernel's id list is empty,
+    every slot's arithmetic is skipped and the layer adds zero."""
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops import moe
+
+    _interpreted_expert_kernel(monkeypatch)
+    rs = np.random.RandomState(0)
+    w = [jnp.asarray(rs.randn(1, 4, 128, 128), jnp.bfloat16)
+         for _ in range(3)]
+    experts = jnp.asarray(rs.randint(0, 4, (5, 2)), jnp.int32)
+    dead = jnp.zeros((5,), bool)
+    _, count = moe.touched_experts(experts, dead, 4)
+    assert int(count) == 0
+    out = moe.dropless_experts(
+        jnp.asarray(rs.randn(5, 128), jnp.bfloat16),
+        jnp.full((5, 2), 0.5), experts, *w, live=dead, layer=0)
+    assert (np.asarray(out, np.float32) == 0).all()
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scanned", "unrolled"])
+def test_decode_hands_the_kernel_the_stacked_experts(monkeypatch, scan):
+    """Through the engine's own model at lane-aligned widths, rows 0 and
+    2 holding a request and row 1 on the scratch page: the decode
+    step with the kernel gives the live rows the logits the all-experts
+    formulation gives them.  Scanned layers hand the kernel the STACKED
+    expert leaves (its operands hold the layer dimension); unrolled
+    layers have no stack and keep the all-experts product."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.models import GPT, get_config
+
+    cfg = get_config(PRESET, n_layers=4, d_model=128, moe_d_ff=128,
+                     scan_layers=scan)
+    params = GPT(cfg).init(jax.random.PRNGKey(2),
+                           jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = _engine(cfg, params)
+    try:
+        tables = jnp.zeros((eng._rows, eng.max_pages), jnp.int32)
+        tables = tables.at[0, :2].set(jnp.asarray([3, 1]))
+        tables = tables.at[2, :2].set(jnp.asarray([2, 5]))
+        args = ({"params": eng.params, "cache": eng._cache},
+                jnp.asarray([[7], [0], [9]]),
+                jnp.asarray([[5], [0], [4]]))
+
+        def step(variables, tokens, positions):
+            return eng.model.apply(variables, tokens, positions,
+                                   block_tables=tables,
+                                   mutable=["cache"])[0]
+        want = jax.jit(step)(*args)
+        built = _interpreted_expert_kernel(monkeypatch)
+        # (another function object: jit's cache is keyed by it)
+        got = jax.jit(lambda *a: step(*a))(*args)
+    finally:
+        eng.close()
+    # [L, E, d, f] whole, not a layer's [E, d, f] (the scan may trace
+    # its body more than once)
+    assert set(built) == ({(4, 8, 128, 128)} if scan else set())
+    live = np.asarray([0, 2])
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=1e-5)
+    assert np.abs(np.asarray(want)[live]).max() > 0.1
 
 
 # sha256 of the lowered (StableHLO) text of ``GPT(cfg).apply`` on a
