@@ -1,10 +1,23 @@
 """The one general traffic generator: a mix file of parameters in, a
 schedule of requests out.
 
-Steadiness rule: the schedule (when each request is due, how long its
-prompt and its answer are) is drawn once from the mix's own ``draw_seed``
-and is the same for every ``--seed``; ``--seed`` draws the token ids (and,
-in the runner, the weights).  Permuting the same lengths and gaps by the
+Steadiness rule: whatever a run's cost follows is part of the work, so
+the mix fixes it; ``--seed`` draws only what the cost does not follow.
+The schedule (when each request is due, how long its prompt and its
+answer are) is drawn once from the mix's own ``draw_seed`` and is the
+same for every ``--seed``.  In a mix of kind ``serve`` (a dense model: a
+step costs the same whatever its weights and tokens are) ``--seed`` draws
+the token ids here and the weights in the runner.  In a mix of kind
+``serve_arch`` whose model routes to experts, a decode step costs what
+the live rows' routing touches and random routers are skewed, so the
+weights are part of the work too, and so are the token ids, since greedy
+answers on random weights fall into cycles and a row in a cycle keeps
+touching the same few experts: the mix names both (``weights_seed``,
+``contents_seed``; read in ``runners/serve_arch.py``) and ``--seed``
+draws what is left, in serve-reason nothing: a run differs from the next
+by the machine alone.  (Nine ``--seed``s spread its mean TPOT by 6.2%
+where one repeated to 0.04%: my chip runs, PR 27; with the weights alone
+fixed ten still spread it by 4.3%: PR 29; PERF.md.)  Permuting the same lengths and gaps by the
 seed was tried and taken out: with some seventy requests in a window the
 engine's 32-step quanta make the 95th percentiles depend on which requests
 share a quantum, and six orders of the same work spread TTFT p95 by 9.5%
@@ -23,6 +36,9 @@ Serve mix parameters (``chipbench/traffic/<mix>.json``)::
                          "min": a, "max": b} or
                         {"dist": "uniform", "min": a, "max": b}
     draw_seed           seed of the fixed set
+    weights_seed, contents_seed
+                        ``serve_arch`` mixes only, read by that runner
+                        (the generator takes the seed it is given)
 
 The number of requests is ``round(rate_per_s * seconds)``; the gaps are
 scaled so that the last request is due just inside the window.

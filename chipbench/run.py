@@ -7,7 +7,9 @@ traffic mix by name, picks the runner by the mix's ``kind``
 (``chipbench/runners/<kind>.py``), and reads each metric the cell reports
 with the metric's own file (``chipbench/metrics/<name>.py``).  Progress
 goes to earlier lines; the last line of standard output is the one JSON
-object the contract asks for.  This process never starts a JAX backend:
+object the contract asks for; its last key, ``compared``, holds each
+number that decided ``correct`` beside its limit, and the same are the
+last lines of standard error.  This process never starts a JAX backend:
 the worker or the replica must have the chip.  Any platform but ``tpu``
 ends in a non-zero exit and no result line (``CHIPBENCH_REHEARSAL=1``
 lets a CPU rehearsal print a line marked ``"rehearsal": true`` and still
@@ -42,6 +44,16 @@ def say(what: str, **facts) -> None:
 def metric_names(bench: dict, cell: str, kind: str) -> list:
     return [m for m in bench[kind]
             if "workloads" not in m or cell in m["workloads"]]
+
+
+def compared(record: dict) -> dict:
+    """What decided ``correct``, each number beside its limit ``[lowest,
+    highest]``: every check as 1 or 0, and the numbers a runner compared
+    with a reference where its record names them (``compared``)."""
+    out = {name: {"value": int(bool(ok)), "limit": [1, None]}
+           for name, ok in record["checks"].items()}
+    out.update(record.get("compared") or {})
+    return out
 
 
 def main() -> int:
@@ -115,6 +127,7 @@ def main() -> int:
             (record.get("traced") or {}).get("window_s") or 0.0,
             red.get("span_s") or 0.0) or None
         line["breakdown"] = trace.breakdown(red)
+    line["compared"] = compared(record)            # last, and on stderr
     if cluster.driver_touched_backend():
         print("chipbench: the driver process initialised a JAX backend",
               file=sys.stderr)
@@ -129,6 +142,9 @@ def main() -> int:
     if not line["correct"]:
         say("failed_checks", checks=record["checks"])
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr)
     return 0
 
 

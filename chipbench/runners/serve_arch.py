@@ -19,6 +19,14 @@ reference, not a runner.  The record is ``runners/serve.py``'s (``kind:
 Mix parameters beyond ``runners/serve.py``'s (``warm_waves`` is not
 read)::
 
+    weights_seed     the seed the server makes its weights from, for
+                     every ``--seed``: where a step costs what the live
+                     rows' routing touches, the routers' skew is part of
+                     the work and the mix fixes it, as it fixes the
+                     schedule (``lib/traffic.py``'s steadiness rule)
+    contents_seed    the same for the prompts' token ids.  Without
+                     either key that input is drawn from ``--seed``, as
+                     in ``runners/serve.py``
     warm_horizon_s   the prefill programs warmed are those a REHEARSAL of
                      the schedule finds: requests of one prompt bucket
                      due within this many seconds of each other may share
@@ -90,6 +98,25 @@ def _refuse_unknown(preset: str, overrides: dict) -> None:
             f", TransformerConfig lacks {unknown}")
 
 
+def server_args(config: dict, mix: dict, seed31: int) -> dict:
+    """What ``LLMServer`` is built with.  The weights come from the mix's
+    ``weights_seed`` where it names one (module docstring) and from the
+    run's ``--seed`` where it does not."""
+    adapter = importlib.import_module(config["program"]["adapter"])
+    overrides = adapter.model_overrides(config,
+                                        mix.get("config_overrides", {}))
+    return dict(mix["server"], paged=True,
+                seed=mix.get("weights_seed", seed31),
+                config_overrides=overrides)
+
+
+def cell_schedule(mix: dict, seed: int, seconds: float, vocab: int) -> list:
+    """The generator's schedule, its token ids drawn from the mix's
+    ``contents_seed`` where it names one and from ``--seed`` where not."""
+    return traffic.serve_schedule(mix, mix.get("contents_seed", seed),
+                                  seconds, vocab)
+
+
 def deploy(cell, config, mix, seed31, allow_cpu, say, pairs=None):
     """As ``runners/serve.py deploy``, with the overrides and the replica
     class of a configuration that names its adapter and reference.
@@ -100,12 +127,8 @@ def deploy(cell, config, mix, seed31, allow_cpu, say, pairs=None):
 
     from chipbench.lib.replica_arch import ArchBenchLLMServer
 
-    adapter = importlib.import_module(config["program"]["adapter"])
-    overrides = adapter.model_overrides(config,
-                                        mix.get("config_overrides", {}))
-    _refuse_unknown(config["program"]["preset"], overrides)
-    server = dict(mix["server"], paged=True, seed=seed31,
-                  config_overrides=overrides)
+    server = server_args(config, mix, seed31)
+    _refuse_unknown(config["program"]["preset"], server["config_overrides"])
     if pairs is None:
         spec = mix["prompt_len"]
         pairs = [(b, w) for b in _buckets(spec["min"], spec["max"])
@@ -127,7 +150,7 @@ def deploy(cell, config, mix, seed31, allow_cpu, say, pairs=None):
         warm = ray_tpu.get(handle.bench_warm.remote(
             pairs, mix.get("warm_concat", {})), timeout=1100)
         say("replica", device=info["device"], paged_impl=info["paged_impl"],
-            warm=warm, pairs=pairs)
+            weights_seed=server["seed"], warm=warm, pairs=pairs)
         short = mix["prompt_len"]["min"]
         for n in range(mix["warm_requests"]):
             items = [ray_tpu.get(ref, timeout=300) for ref in
@@ -169,7 +192,7 @@ def run(ctx) -> dict:
     cell, config, mix = ctx["cell"], ctx["config"], ctx["mix"]
     say, seconds = ctx["say"], ctx["seconds"]
     vocab = config["vocab_size"]
-    schedule = traffic.serve_schedule(mix, ctx["seed"], seconds, vocab)
+    schedule = cell_schedule(mix, ctx["seed"], seconds, vocab)
     say("schedule", **traffic.describe(schedule, seconds))
     ref_spec = mix["reference"]
     wants_long = any(
@@ -230,6 +253,10 @@ def run(ctx) -> dict:
                  for k in facts0["compiles"]}
     finished = [r for r in recs if "done" in r]
     failed = [r for r in recs if "error" in r]
+    # each number the reference check compares, beside its limit
+    compared = {f"{m['which']}.{key}": {"value": m[key],
+                                        "limit": limits[key]}
+                for m in ref for key in limits if key in m}
     # the checks mean what runners/serve.py's mean; the reference's
     # limits are the mix's (PERF.md says what each was set from)
     checks = {
@@ -250,8 +277,8 @@ def run(ctx) -> dict:
             not wants_long or all(
                 key in m for m in ref if m["which"] == "long"
                 for key in limits)),
-        "reference_numbers": all(inside(m[key], *limits[key]) for m in ref
-                                 for key in limits if key in m),
+        "reference_numbers": all(inside(c["value"], *c["limit"])
+                                 for c in compared.values()),
     }
     say("serve_done", requests=len(recs), finished=len(finished),
         failed=len(failed), errors=[r["error"] for r in failed][:3],
@@ -268,7 +295,7 @@ def run(ctx) -> dict:
                                r["token_t"][0], r["token_t"][-1])]
         + [len(r["token_t"])] for r in finished if r["token_t"]])
     return {
-        "kind": "serve", "checks": checks,
+        "kind": "serve", "checks": checks, "compared": compared,
         "attempted": sum("sent" in r for r in recs),
         "failed": len(failed),
         "device": {"platform": info["device"]["platform"],

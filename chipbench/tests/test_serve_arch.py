@@ -206,6 +206,37 @@ def test_rehearsal_of_the_schedule_finds_the_waves():
                                     (64, 1), (64, 2), (128, 1)]
 
 
+def test_the_mix_names_the_weights_the_server_makes():
+    """``weights_seed`` reaches ``LLMServer`` whatever ``--seed`` is;
+    a mix without it draws the weights from ``--seed`` as before."""
+    from chipbench.runners import serve_arch
+    with open(os.path.join(HERE, "..", "traffic", "serve-reason.json")) as f:
+        mix = json.load(f)
+    cfg = _real_config()
+    a, b = (serve_arch.server_args(cfg, mix, seed31) for seed31 in (7, 11))
+    assert a == b and a["seed"] == mix["weights_seed"]
+    assert a["seed"] == 2700000015 % (2**31 - 1)      # PERF.md, PR 29
+    assert a["paged"] and a["num_slots"] == 32
+    assert a["config_overrides"]["n_layers"] == 8
+    bare = {k: v for k, v in mix.items() if k != "weights_seed"}
+    assert [serve_arch.server_args(cfg, bare, s)["seed"]
+            for s in (7, 11)] == [7, 11]
+    # and the token ids of the run those weights were chosen by
+    assert mix["contents_seed"] == 2700000015
+    a, b = (serve_arch.cell_schedule(mix, s, 45, cfg["vocab_size"])
+            for s in (7, 2900000011))
+    assert a == b and len(a) == 27
+
+
+def test_the_mix_may_name_the_token_ids_too():
+    from chipbench.runners.serve_arch import cell_schedule
+    a, b = (cell_schedule(TOY_MIX, seed, 3.0, 256) for seed in (3, 4))
+    assert all(x["prompt"] != y["prompt"] for x, y in zip(a, b))
+    fixed = dict(TOY_MIX, contents_seed=5)
+    a, b = (cell_schedule(fixed, seed, 3.0, 256) for seed in (3, 4))
+    assert a == b == cell_schedule(TOY_MIX, 5, 3.0, 256)
+
+
 def test_expert_bytes_by_hand():
     from chipbench.lib import moe_bytes
     cfg = _real_config()
@@ -235,8 +266,18 @@ def test_window_pages_skipped_share_reader():
 
 
 def test_moe_readers_on_a_hand_made_record():
-    from chipbench.metrics import moe_roofline_share, moe_time_share
+    from chipbench.metrics import (moe_experts_touched_mean,
+                                   moe_roofline_share, moe_time_share)
+    # the counters over the WINDOW: 16.5 experts touched a layer step
+    whole = _record(
+        stats0={"moe_layer_steps": 64, "moe_experts_touched": 400},
+        stats1={"moe_layer_steps": 76_064, "moe_experts_touched": 1_254_400})
+    assert moe_experts_touched_mean.read(whole) == 16.5
+    assert moe_experts_touched_mean.read(_record(
+        stats0={"moe_layer_steps": 64, "moe_experts_touched": 400},
+        stats1={"moe_layer_steps": 64, "moe_experts_touched": 400})) is None
     run = _record(stats0={}, stats1={})
+    assert moe_experts_touched_mean.read(run) is None         # the parent
     # the counters over the TRACED interval, as the runner snapshots them
     run["traced"] = {
         "stats0": {"moe_layer_steps": 500, "moe_experts_touched": 9_000},
@@ -301,6 +342,14 @@ def test_runner_end_to_end_on_the_cpu():
     assert {m["which"] for m in done["reference"]} == {"short", "long"}
     long = next(m for m in done["reference"] if m["which"] == "long")
     assert set(TOY_MIX["reference"]["limits"]) <= set(long)
+    # each number compared goes beside its limit into the result's line
+    assert record["compared"]["long.hidden_rel_err"] == {
+        "value": long["hidden_rel_err"], "limit": [None, 1e-4]}
+    from chipbench.run import compared
+    line = compared(record)
+    assert line["no_failed_request"] == {"value": 1, "limit": [1, None]}
+    assert line["platform_tpu"]["value"] == 0
+    assert line["short.router_rel_err"]["limit"] == [None, 1e-5]
     assert long["hidden_rel_err"] < 1e-5 and long["past"] > 0
     assert done["stats1"]["moe_layer_steps"] > 0
     assert serve_tpot_mean_ms.read(record) > 0

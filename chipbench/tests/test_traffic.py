@@ -34,6 +34,31 @@ def test_every_seed_offers_the_same_schedule_with_other_tokens():
     assert shape(other) != shape(a)
 
 
+# serve-reason's 45 s as PR 26 drew them (draw_seed 26, 0.60/s): due
+# time, prompt length, answer length.  A PR that steadies the cell may
+# fix what --seed draws, not what is offered (PR 29).
+SERVE_REASON_45 = [
+    (0.0, 784, 718), (0.638205, 849, 3586), (2.708552, 1109, 595),
+    (9.693358, 174, 964), (11.414547, 808, 2875), (12.683676, 930, 2592),
+    (12.783534, 1365, 1317), (13.385113, 635, 697), (19.291528, 227, 1615),
+    (19.407591, 1008, 1147), (19.517789, 64, 4096), (21.347411, 345, 1367),
+    (22.076164, 120, 3590), (23.886348, 1276, 1450), (24.41056, 161, 1057),
+    (24.902576, 125, 2121), (25.47789, 417, 2153), (27.960939, 436, 3614),
+    (30.308138, 375, 910), (30.364702, 901, 2770), (32.81254, 269, 827),
+    (35.662227, 457, 1535), (36.913094, 256, 2093), (37.110229, 865, 1890),
+    (37.136814, 156, 1068), (44.790021, 341, 1759), (44.997838, 802, 493)]
+
+
+def test_serve_reason_offers_what_it_offered_for_every_seed():
+    mix = _mix("serve-reason")
+    assert mix["rate_per_s"] == 0.6 and mix["draw_seed"] == 26
+    for seed in (1, 2900000017, mix["contents_seed"]):
+        s = traffic.serve_schedule(mix, seed, 45, 151936)
+        assert [(round(r["due_s"], 6), len(r["prompt"]),
+                 r["max_new_tokens"]) for r in s] == SERVE_REASON_45
+    assert (849, 3586) in [row[1:] for row in SERVE_REASON_45]
+
+
 def test_schedule_is_what_the_mix_says():
     mix = _mix("serve-chat")
     s = traffic.serve_schedule(mix, 7, 45, 49152)
