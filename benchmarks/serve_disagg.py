@@ -253,7 +253,7 @@ def run_arm(mode, connections=1000, new_tokens=16, slots=16,
                  system_config={"actor_creation_timeout_s": 900.0})
     try:
         serve.start()
-        common = dict(preset="tiny", paged=True, page_size=PAGE_SIZE,
+        common = dict(preset="tiny", page_size=PAGE_SIZE,
                       max_seq_len=MAX_SEQ, max_prompt_len=LONG_LEN + 8,
                       block_size=block_size,
                       max_concurrent_queries=2 * connections,

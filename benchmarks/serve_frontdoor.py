@@ -269,7 +269,7 @@ def run_frontdoor(connections=1000, new_tokens=32, duration_s=60.0,
         serve.start(serve.HTTPOptions(port=port))
         serve.run(serve.llm.build_app(
             preset="tiny", disaggregated=True, prefill_replicas=1,
-            num_replicas=1, num_slots=2 * slots, paged=True,
+            num_replicas=1, num_slots=2 * slots,
             page_size=PAGE_SIZE, max_seq_len=MAX_SEQ,
             max_prompt_len=LONG_LEN + 8, block_size=8,
             max_concurrent_queries=2 * connections,

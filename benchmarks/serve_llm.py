@@ -37,8 +37,8 @@ except ImportError:          # run as a script from benchmarks/
 
 
 def build_engine(preset: str, slots: int, seed: int = 0,
-                 max_seq_len=None, block_size=16, paged=False,
-                 page_size=64, kv_pool_pages=None):
+                 max_seq_len=None, block_size=16, page_size=64,
+                 kv_pool_pages=None):
     import jax
     import jax.numpy as jnp
 
@@ -52,30 +52,30 @@ def build_engine(preset: str, slots: int, seed: int = 0,
                         jnp.zeros((1, 1), jnp.int32))["params"]
     return LLMEngine(cfg, params, num_slots=slots,
                      max_seq_len=max_seq_len,
-                     block_size=block_size, paged=paged,
-                     page_size=page_size,
+                     block_size=block_size, page_size=page_size,
                      kv_pool_pages=kv_pool_pages), cfg
 
 
+def _pool_pages(slots, requests, prompt_len, new_tokens, page_size):
+    """A pool in which every request can prefill ahead of slot turnover
+    (the TTFT path), with slack."""
+    per_req = -(-(prompt_len + new_tokens) // page_size)
+    return 1 + (requests + slots) * per_req
+
+
 def bench_engine(preset="gpt-small", slots=8, requests=64, prompt_len=64,
-                 new_tokens=64, stagger_s=0.0, paged=False, page_size=64):
+                 new_tokens=64, stagger_s=0.0, page_size=64):
     """Drive the engine directly (no serve actor hop): the chip-side
     ceiling for one replica."""
-    # KV allocation sized to the workload (prompt + generation + slack):
-    # dense decode reads the whole cache row every step.  Paged mode
-    # pools pages instead; size the pool so every request can prefill
-    # ahead (the TTFT path) with slack.
-    pool = None
-    if paged:
-        per_req = -(-(prompt_len + new_tokens) // page_size)
-        pool = 1 + (requests + slots) * per_req
     eng, cfg = build_engine(preset, slots,
                             max_seq_len=2 * (prompt_len + new_tokens),
-                            paged=paged, page_size=page_size,
-                            kv_pool_pages=pool)
+                            page_size=page_size,
+                            kv_pool_pages=_pool_pages(
+                                slots, requests, prompt_len, new_tokens,
+                                page_size))
     try:
         return _drive_engine(eng, cfg, preset, slots, requests, prompt_len,
-                             new_tokens, stagger_s, paged)
+                             new_tokens, stagger_s)
     finally:
         # a mid-bench failure must not leak the loop thread + device
         # buffers into the next suite scenario
@@ -83,15 +83,14 @@ def bench_engine(preset="gpt-small", slots=8, requests=64, prompt_len=64,
 
 
 def _drive_engine(eng, cfg, preset, slots, requests, prompt_len,
-                  new_tokens, stagger_s, paged):
+                  new_tokens, stagger_s):
     import asyncio
 
     vocab = cfg.vocab_size
 
     # compile every jit path at the bench shapes before timing, incl.
-    # the saturation-burst decomposition in paged mode
-    eng.warmup(prompt_lens=[prompt_len],
-               burst=requests if paged else 0)
+    # the saturation-burst decomposition
+    eng.warmup(prompt_lens=[prompt_len], burst=requests)
     eng.submit([7] * prompt_len, max_new_tokens=4, temperature=0.8)
 
     # single-threaded async submission: all requests enqueue at t~0 from
@@ -120,7 +119,7 @@ def _drive_engine(eng, cfg, preset, slots, requests, prompt_len,
     t50, t99 = _percentiles(ttfts)
     return {
         "metric": "serve_llm_engine",
-        "kv": "paged" if paged else "dense",
+        "kv": "paged",
         "preset": preset,
         "num_slots": slots,
         "requests": requests,
@@ -138,7 +137,7 @@ def _drive_engine(eng, cfg, preset, slots, requests, prompt_len,
 
 
 def bench_serve(preset="gpt-small", slots=8, requests=64, prompt_len=64,
-                new_tokens=64, paged=False, page_size=64):
+                new_tokens=64, page_size=64):
     """Same load through a Serve replica handle: measures what a client
     of the deployment sees (adds router + actor-call overhead).
 
@@ -149,10 +148,7 @@ def bench_serve(preset="gpt-small", slots=8, requests=64, prompt_len=64,
     import ray_tpu
     from ray_tpu import serve
 
-    pool = None
-    if paged:
-        per_req = -(-(prompt_len + new_tokens) // page_size)
-        pool = 1 + (requests + slots) * per_req
+    pool = _pool_pages(slots, requests, prompt_len, new_tokens, page_size)
     # replica __init__ compiles every engine specialization (warmup):
     # give actor creation room beyond the 60 s default.  num_tpus=1 on
     # both the cluster and the deployment: a replica without a TPU
@@ -166,10 +162,10 @@ def bench_serve(preset="gpt-small", slots=8, requests=64, prompt_len=64,
         app = serve.llm.build_app(preset=preset, num_slots=slots,
                                   max_concurrent_queries=2 * requests,
                                   max_seq_len=2 * (prompt_len + new_tokens),
-                                  num_tpus=1, paged=paged,
-                                  page_size=page_size, kv_pool_pages=pool,
+                                  num_tpus=1, page_size=page_size,
+                                  kv_pool_pages=pool,
                                   warmup_prompt_lens=[prompt_len],
-                                  warmup_burst=requests if paged else 0)
+                                  warmup_burst=requests)
         handle = serve.run(app, name="llm-bench")
         # warm the replica's jit paths
         ray_tpu.get(handle.remote({"prompt": [7] * prompt_len,
@@ -197,7 +193,7 @@ def bench_serve(preset="gpt-small", slots=8, requests=64, prompt_len=64,
         t50, t99 = _percentiles(ttfts)
         return {
             "metric": "serve_llm_handle",
-            "kv": "paged" if paged else "dense",
+            "kv": "paged",
             "preset": preset,
             "num_slots": slots,
             "requests": requests,
@@ -221,18 +217,14 @@ def bench_serve(preset="gpt-small", slots=8, requests=64, prompt_len=64,
 
 def run_suite(slots: int, requests: int):
     """The MICROBENCH serve_llm matrix (one JSON line each):
-      - gpt-small dense engine     (continuity with rounds 3-4)
-      - gpt-small paged engine     (paged KV + prefill-ahead TTFT)
-      - gpt-small paged handle     (client view through Serve)
-      - gpt-large dense engine     (1B: the dense baseline)
-      - gpt-large paged engine     (1B: the north-star scale row)
+      - gpt-small engine     (the pool + prefill-ahead TTFT)
+      - gpt-small handle     (client view through Serve)
+      - gpt-large engine     (1B: the north-star scale row)
     """
     scenarios = [
-        ("gpt-small", False, True),
-        ("gpt-small", True, True),
-        ("gpt-small", True, False),          # handle row
-        ("gpt-large", False, True),
-        ("gpt-large", True, True),
+        ("gpt-small", True),
+        ("gpt-small", False),                # handle row
+        ("gpt-large", True),
     ]
     # One process per chip: an engine row holds the chip in the process
     # that built the engine, the handle row needs it for the replica's
@@ -241,18 +233,17 @@ def run_suite(slots: int, requests: int):
     # released) before the next starts.
     import subprocess
     failed = 0
-    for preset, paged, engine_only in scenarios:
+    for preset, engine_only in scenarios:
         cmd = [sys.executable, os.path.abspath(__file__),
                "--preset", preset, "--slots", str(slots),
                "--requests", str(requests)]
-        cmd += ["--paged"] if paged else []
         cmd += ["--engine-only"] if engine_only else []
         proc = subprocess.run(cmd, stdout=subprocess.PIPE, text=True)
         sys.stdout.write(proc.stdout)
         sys.stdout.flush()
         if proc.returncode != 0:        # one scenario must not kill the rest
             failed += 1
-            print(f"[serve_llm] {preset} paged={paged} "
+            print(f"[serve_llm] {preset} "
                   f"engine_only={engine_only} FAILED: exit "
                   f"{proc.returncode}", file=sys.stderr, flush=True)
     if "jax" in sys.modules:
@@ -272,7 +263,6 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=64)
     ap.add_argument("--engine-only", action="store_true")
-    ap.add_argument("--paged", action="store_true")
     ap.add_argument("--page-size", type=int, default=64)
     ap.add_argument("--suite", action="store_true",
                     help="emit the full MICROBENCH scenario matrix")
@@ -286,7 +276,7 @@ def main():
     bench = bench_engine if args.engine_only else bench_serve
     row = bench(args.preset, args.slots, args.requests,
                 args.prompt_len, args.new_tokens,
-                paged=args.paged, page_size=args.page_size)
+                page_size=args.page_size)
     print(json.dumps(row))
 
 

@@ -188,7 +188,7 @@ def phase_serve(ray_tpu, seed: int) -> dict:
 
     with phase_limit("serve", 500) as ph:
         app = serve.llm.build_app(
-            SERVE_PRESET, num_tpus=1, paged=True, page_size=64, num_slots=8,
+            SERVE_PRESET, num_tpus=1, page_size=64, num_slots=8,
             max_seq_len=2 * (PROMPT_LEN + NEW_TOKENS), seed=seed,
             max_concurrent_queries=32, warmup_prompt_lens=[PROMPT_LEN])
         handle = serve.run(app, name="chip-smoke")

@@ -141,7 +141,7 @@ class LLMServer:
     decodes), ``"prefill"`` (serves ``prefill()`` handoff exports only)
     or ``"decode"`` (admits handoffs via ``decode()``, never prefills).
     The split pools of a ``disaggregated=True`` app (docs/
-    serve_disagg.md); both split roles force ``paged=True``.
+    serve_disagg.md).
     """
 
     def __init__(self, preset: str = "tiny", *, num_slots: int = 8,
@@ -151,7 +151,7 @@ class LLMServer:
                  block_size: int = 32, max_seq_len: Optional[int] = None,
                  warmup_prompt_lens: Optional[list] = None,
                  warmup_burst: int = 0,
-                 paged: bool = False, page_size: int = 64,
+                 paged: bool = True, page_size: int = 64,
                  kv_pool_pages: Optional[int] = None,
                  role: str = "colocated",
                  # deliberately SHORTER than DisaggHandle's
@@ -170,6 +170,12 @@ class LLMServer:
 
         if role not in ("colocated", "prefill", "decode"):
             raise ValueError(f"unknown LLMServer role {role!r}")
+        if not paged:
+            # one legal value, kept while chipbench/ passes it
+            # (ROADMAP C12); refused before any weights are made
+            raise ValueError(
+                "paged=False: the dense engine was removed; LLMServer "
+                "always serves from the paged KV pool")
         self.role = role
         self._compile_clock = start_compile_clock()
         # which device this replica serves on is decided by its lease,
@@ -180,8 +186,6 @@ class LLMServer:
                        process_facts(self._compile_clock)["device"])
         self.import_retry_s = import_retry_s
         del _upstream   # deploy-ordering anchor only (build_app)
-        if role != "colocated":
-            paged = True      # handoff is defined on the paged pool
         cfg = get_config(preset, **(config_overrides or {}))
         params = self._load_params(cfg, checkpoint, seed)
         if prefix_cache_pages is None:
@@ -190,7 +194,7 @@ class LLMServer:
                                 max_prompt_len=max_prompt_len,
                                 top_k=top_k, top_p=top_p, seed=seed,
                                 block_size=block_size,
-                                max_seq_len=max_seq_len, paged=paged,
+                                max_seq_len=max_seq_len,
                                 page_size=page_size,
                                 kv_pool_pages=kv_pool_pages,
                                 import_queue_max=import_queue_max,
@@ -209,7 +213,7 @@ class LLMServer:
                 lambda ms: _M_HANDOFF_MS.observe("import_admit", ms))
         if warmup_prompt_lens:
             # pay all compiles at replica start, none at request time
-            # (warmup_burst additionally compiles the paged engine's
+            # (warmup_burst additionally compiles the engine's
             # saturation-burst fetch shapes — see LLMEngine.warmup)
             self.engine.warmup(prompt_lens=warmup_prompt_lens,
                                burst=warmup_burst)
@@ -264,7 +268,7 @@ class LLMServer:
     def _timing(result) -> Dict[str, float]:
         """The engine's own clock on one request, as the reply carries
         it: ``queue_wait_s + prefill_s == time_to_first_token_s``;
-        ``slot_wait_s`` is the paged engine's wait for a decode slot
+        ``slot_wait_s`` is a prefilled request's wait for a decode slot
         after the first token (docs/observability.md)."""
         return {"time_to_first_token_s": result.time_to_first_token_s,
                 "latency_s": result.latency_s,
@@ -462,9 +466,7 @@ class LLMServer:
         from ray_tpu.ops.paged_attention import resolve_paged_impl
         return {
             **process_facts(self._compile_clock),
-            "paged": bool(self.engine.paged),
-            "paged_impl": (resolve_paged_impl(2 * self.engine.cfg.head_dim)
-                           if self.engine.paged else None),
+            "paged_impl": resolve_paged_impl(2 * self.engine.cfg.head_dim),
         }
 
     def advertised_prefixes(self) -> Optional[Dict[str, Any]]:
@@ -473,7 +475,7 @@ class LLMServer:
         get_targets so handles prefix-affinity-route the prefill hop.
         None (advertise nothing) when the engine's prefix cache is
         off."""
-        if not getattr(self.engine, "prefix_cache_pages", 0):
+        if not self.engine.prefix_cache_pages:
             return None
         return {"page_size": self.engine.page_size,
                 "digests": self.engine.prefix_digests()}
@@ -548,9 +550,9 @@ def build_app(preset: str = "tiny", *, num_replicas: int = 1,
     actor_opts = {"num_tpus": num_tpus} if num_tpus else None
     pkw = dict(server_kwargs)
     pkw.update(prefill_server_kwargs or {})
-    pkw.update(role="prefill", paged=True)
+    pkw.update(role="prefill")
     dkw = dict(server_kwargs)
-    dkw.update(role="decode", paged=True)
+    dkw.update(role="decode")
     prefill_dep = deployment(
         LLMServer, name=f"llm-{preset}-prefill",
         num_replicas=prefill_replicas,

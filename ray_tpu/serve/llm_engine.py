@@ -4,76 +4,64 @@ The reference serves LLMs by scaling replicas and batching whole requests
 (`python/ray/serve/batching.py`); its Serve LLM benchmark surface is
 llama-3-8b qps/p50/p99 (BASELINE.md north-star row).  On TPU the win is
 *iteration-level* scheduling (Orca-style): one jitted decode step over a
-fixed slot grid, with requests admitted into free KV-cache slots and
+fixed slot grid, with requests installed into free decode slots and
 evicted the step they finish — no compile-shape churn, no head-of-line
 blocking behind a long generation.
 
 Design (shaped by one hard constraint: a device->host fetch is a host
 synchronization point — it drains the dispatch queue and costs a fixed
 host-dispatch latency that a single decode step does not amortize — so
-the engine does exactly ONE fetch per scheduling quantum):
+the engine does exactly ONE fetch per decode block and one per
+iteration's prefills):
 
-  - The KV cache is one global [num_slots+1, max_seq, ...] buffer per
-    layer (gpt.py ``_decode_attend`` slot mode: per-row write positions
-    + a position mask, so every row sits at a different offset).  Row
-    ``num_slots`` is a scratch slot that absorbs padded admission
-    writes; it is never scheduled.
-  - Prefill runs per admission WAVE: prompts sharing a power-of-two
-    length bucket run as one batched forward (one compile per
-    (bucket, wave-size) pair), each first token is sampled inside the
-    same jit, and the prompt K/V blocks are scattered into their slots
-    in one call.  Right-pad garbage beyond a real prompt length is
-    always overwritten by a decode write before the position mask makes
-    it visible, so padding needs no extra masking.
-  - One jitted ``block step`` advances ALL slots ``block_size`` tokens
-    via lax.scan: [N] tokens in, [N, K] tokens out, donated cache.
-    Newly admitted slots get their first token scattered in on-device
-    (the host never sees it before dispatch), and the block output and
-    the admission first-tokens come back in a single combined fetch.
+  - The pool.  K/V lives in a shared page pool addressed through
+    per-row block tables (ops/paged_attention.py): ONE stacked cache
+    leaf ``kv_pages`` [layers, pool_pages, kv_heads, page_size,
+    2*head_dim], declared by the model (models/gpt.py GPT) and chained,
+    donated, through every engine program.  It rides the model's layer
+    scan and the block's step scan as loop-carried state; a layer
+    writes its rows at ``[layer, page, :, offset]`` and the kernel reads
+    ``[layer, page]`` (``write_kv_pages`` / the DMA source).  Nobody
+    slices a layer out of it: a decode step or a prefill wave moves the
+    rows it writes and the pages it reads, whatever ``kv_pool_pages``
+    is (tests/test_chip_compile.py holds that in the compiled
+    programs).  Decode attention reads only the pages a row occupies
+    (the Pallas kernel's fori_loop bound is the row's page count), so a
+    long ``max_seq_len`` costs no bandwidth per step and KV capacity is
+    pooled, not reserved per slot.  Page 0 is scratch: a zeroed table
+    points there and it absorbs every padded write.  Export / import /
+    the prefix cache name whole pages ``[:, page]`` across all layers.
+  - Slotless prefill.  Prefill runs per admission WAVE: prompts
+    sharing a power-of-two length bucket run as one batched forward
+    (one compile per (bucket, wave-size) pair) that writes their K/V
+    straight into freshly allocated pages and samples each first token
+    inside the same jit — *before* any decode slot frees
+    (prefill-ahead).  Right-pad garbage beyond a real prompt length is
+    always overwritten by a decode write before a row's length makes it
+    visible, so padding needs no extra masking.
+  - The ready queue.  A prefilled request waits holding its first
+    token; a freeing slot "installs" it by uploading its (token,
+    position, table) row into the block step's device state.
+    Time-to-first-token is bounded by prefill throughput and pool
+    capacity, not by slot turnover.
+  - The block step.  One jitted program advances ALL slots
+    ``block_size`` tokens via lax.scan: [N] tokens in, [N, K] tokens
+    out, donated pool; tokens, positions, temperatures, tables and the
+    rng stay on the device between blocks.  Installs upload their
+    current last token (host-known since their prefill), so the block's
+    tokens come back in a single fetch.
   - No eos logic on device: rows that finish mid-block keep generating
-    junk the host truncates; a freed slot keeps stepping junk until
-    it is reused (the grid is fixed — those steps are free in dense
-    mode; paged mode holds such a row at position 0, see
-    ``_block_fn_paged``).
+    junk the host truncates.  A freed slot keeps stepping junk until
+    its redirect row (table -> scratch page 0, position 0; see
+    ``_block_fn``) rides the next block dispatch; pages are recycled
+    only through dispatches ordered after the last junk write (device
+    stream order), so reuse can never corrupt a live request.
   - Per-request temperature rides as an [N] array (greedy rows select
     argmax under the same jit); top_k/top_p are engine-static.
 
 The host loop owns admission/eviction and runs on a plain thread;
 ``submit`` is loop-aware like serve's ``_BatchQueue.submit`` (awaitable
 from an async replica, blocking from a plain thread).
-
-PAGED MODE (``paged=True``) replaces the dense per-slot ``[max_seq]``
-cache rows with a shared page pool + per-row block tables
-(ops/paged_attention.py):
-
-  - HBM: decode attention reads only the pages a row occupies (the
-    Pallas kernel's fori_loop bound is the row's page count), so long
-    ``max_seq_len`` stops costing bandwidth per step, and KV capacity
-    is pooled instead of reserved per slot.
-  - TTFT: prefill becomes SLOTLESS — a queued request's prompt K/V is
-    written straight into freshly allocated pages and its first token
-    sampled *before* any decode slot frees (prefill-ahead).  Requests
-    then wait in a ready queue holding their first token; a freeing
-    slot "installs" one by uploading its (token, position, table) row
-    into the block step's device state.  Time-to-first-token is bounded
-    by prefill throughput and pool capacity, not by slot turnover —
-    the saturation-TTFT fix the dense engine could not express.
-  - Addressing: the pool is ONE stacked cache leaf ``kv_pages``
-    [layers, pool_pages, kv_heads, page_size, 2*head_dim], declared by
-    the model (models/gpt.py GPT) and chained, donated, through every
-    engine program.  It rides the model's layer scan and the block's
-    step scan as loop-carried state; a layer writes its rows at
-    ``[layer, page, :, offset]`` and the kernel reads ``[layer, page]``
-    (ops/paged_attention.py ``write_kv_pages`` / the DMA source).
-    Nobody slices a layer out of it: a decode step or a prefill wave
-    moves the rows it writes and the pages it reads, whatever
-    ``kv_pool_pages`` is (tests/test_chip_compile.py holds that in the
-    compiled programs).  Export / import / the prefix cache name whole
-    pages ``[:, page]`` across all layers.
-  - Safety: a freed slot keeps stepping junk until its redirect row
-    (table -> scratch page 0) rides the next block dispatch; pages are
-    recycled only through dispatches ordered after the last junk write
-    (device stream order), so reuse can never corrupt a live request.
 """
 
 from __future__ import annotations
@@ -114,8 +102,8 @@ class GenerationResult:
     # submit -> admitted (popped from the queue with its pages / into a
     # wave) -> first token known -> installed in a decode slot.
     # queue_wait_s + prefill_s == time_to_first_token_s; slot_wait_s is
-    # paged mode's wait of a prefilled request for a slot (inside the
-    # gap between its first and second token), 0 in dense mode
+    # a prefilled request's wait for a slot (inside the gap between its
+    # first and second token)
     queue_wait_s: float = 0.0
     prefill_s: float = 0.0
     slot_wait_s: float = 0.0
@@ -211,7 +199,7 @@ class _Slot:
         self.last_token = first_token
         self.first_token_at = time.monotonic()
         self.installed_at: Optional[float] = None   # took a decode slot
-        self.pages = pages or []         # paged mode: physical pages owned
+        self.pages = pages or []         # physical pool pages owned
         self.prompt_len = prompt_len
         # prefix-cache hit bookkeeping: the first ``borrowed`` entries of
         # ``pages`` are SHARED read-only prefix pages owned by
@@ -234,8 +222,8 @@ class _PrefixEntry:
 
 
 class _Prefilled:
-    """Paged mode: a request whose prompt K/V already sits in pool pages
-    and whose first token is known, waiting for a decode slot."""
+    """A request whose prompt K/V already sits in pool pages and whose
+    first token is known, waiting for a decode slot."""
 
     __slots__ = ("slot_state", "table")
 
@@ -362,16 +350,20 @@ class LLMEngine:
                  top_k: int = 0, top_p: float = 1.0, seed: int = 0,
                  min_prefill_bucket: int = 16, block_size: int = 32,
                  max_seq_len: Optional[int] = None,
-                 paged: bool = False, page_size: int = 64,
+                 paged: bool = True, page_size: int = 64,
                  kv_pool_pages: Optional[int] = None,
                  import_queue_max: Optional[int] = None,
                  prefix_cache_pages: int = 0):
+        if not paged:
+            # the keyword outlives the dense engine (removed in PR 30)
+            # only until chipbench/ stops passing it (ROADMAP C12)
+            raise ValueError(
+                "paged=False: the dense engine was removed; LLMEngine "
+                "always serves from the paged KV pool")
         # Inference engine owns its own copies of the knobs a server
         # tunes independently of training:
-        #  - max_seq_len: the KV allocation AND the per-step attention
-        #    read span.  Decode attends over the whole cache row every
-        #    step, so serving 128-token chats with a 8192-long cache
-        #    reads 64x more HBM than needed — size it to the workload.
+        #  - max_seq_len: the longest sequence a row may reach, hence
+        #    the width of a block table and the default pool's size.
         #  - dtype: params are cast to the activation dtype once here;
         #    serving never needs f32 master weights, and keeping them
         #    would re-cast (and re-read) the full parameter set every
@@ -388,37 +380,26 @@ class LLMEngine:
         self.max_prompt_len = max_prompt_len or cfg.max_seq_len // 2
         self._min_bucket = min_prefill_bucket
         self.block_size = block_size
-        self.paged = paged
-        if paged:
-            self.page_size = page_size
-            self.max_pages = -(-cfg.max_seq_len // page_size)
-            # page 0 is the scratch page (zeroed tables point there).
-            # Default pool: HBM PARITY with the dense cache — the dense
-            # engine allocates (num_slots + 1) full-length rows (the +1
-            # is the scratch row), i.e. (num_slots + 1) * max_pages
-            # page-equivalents, so flipping paged=True on a deployment
-            # that fit in dense mode can never OOM it.  The old default
-            # (4 * num_slots * max_pages) allocated ~4x the dense
-            # cache's HBM for prefill-ahead headroom; deployments that
-            # want the ready queue to prefill well ahead of slot
-            # turnover should pass kv_pool_pages explicitly (e.g.
-            # benchmarks/serve_llm.py sizes it per request load).
-            self.kv_pool_pages = (kv_pool_pages if kv_pool_pages
-                                  else 1 + (num_slots + 1) * self.max_pages)
-            self.model = GPT(cfg, decode=True,
-                             paged_pages=self.kv_pool_pages,
-                             page_size=page_size)
-        else:
-            self.model = GPT(cfg, decode=True)
+        self.page_size = page_size
+        self.max_pages = -(-cfg.max_seq_len // page_size)
+        # page 0 is the scratch page (zeroed tables point there).  The
+        # default pool holds every slot at full length plus one
+        # full-length scratch row; a deployment that wants the ready
+        # queue to prefill well ahead of slot turnover passes
+        # kv_pool_pages (benchmarks/serve_llm.py sizes it per load).
+        self.kv_pool_pages = (kv_pool_pages if kv_pool_pages
+                              else 1 + (num_slots + 1) * self.max_pages)
+        self.model = GPT(cfg, decode=True, paged_pages=self.kv_pool_pages,
+                         page_size=page_size)
         self.stats = EngineStats()
-        # the paged block program also returns the dropless expert
-        # layers' load (EngineStats.moe_*); a model without them
-        # compiles the program it always did
+        # the block program also returns the dropless expert layers'
+        # load (EngineStats.moe_*); a model without them compiles the
+        # program it always did
         self._counts_expert_load = bool(
-            paged and cfg.moe_experts and cfg.moe_dropless)
+            cfg.moe_experts and cfg.moe_dropless)
         # layers whose decode reads stop at the window
         self._window_layers = (
-            0 if not (paged and cfg.sliding_window) else cfg.n_layers
+            0 if not cfg.sliding_window else cfg.n_layers
             if cfg.window_layout is None
             else sum(cfg.window_layout[:cfg.n_layers]))
 
@@ -430,94 +411,83 @@ class LLMEngine:
         self._closed = False
         self._thread: Optional[threading.Thread] = None
 
-        # +1 scratch row absorbing padded admission writes
+        # +1 scratch row: the target of padded install rows
         self._rows = num_slots + 1
         self._cache = self._init_cache(self._rows)
-        # decode state lives ON DEVICE between quanta (tokens, positions,
-        # temps, rng): the host uploads only the small admit arrays, and
-        # only when something was admitted
+        # decode state lives ON DEVICE between blocks (tokens, positions,
+        # temps, tables, rng): the host uploads only the small install
+        # arrays, and only when something was installed or redirected
         self._state = self._init_state(seed)
-        # packed admit metadata [3, num_slots]: slots row, positions row,
-        # temps*1e6 row — one upload per quantum, cached when empty
+        # packed install metadata [3, num_slots]: slots row, positions
+        # row, temps*1e6 row — one upload per block, cached when empty
         no_meta = np.zeros((3, num_slots), np.int32)
         no_meta[0, :] = num_slots                           # -> scratch
+        self._no_admit = (jnp.asarray(no_meta),
+                          jnp.zeros((num_slots,), jnp.int32),
+                          jnp.zeros((num_slots, self.max_pages), jnp.int32))
         self._prefill_jit: dict = {}      # (bucket, wave) -> jitted fn
-        self._insert_jit: dict = {}       # (bucket, wave) -> jitted fn
-        if paged:
-            self._no_admit = (jnp.asarray(no_meta),
-                              jnp.zeros((num_slots,), jnp.int32),
-                              jnp.zeros((num_slots, self.max_pages),
-                                        jnp.int32))
-            self._free_pages: List[int] = list(
-                range(1, self.kv_pool_pages))[::-1]
-            self._ready: collections.deque = collections.deque()
-            self._stale_slots: set = set()   # evicted, redirect pending
-            self._imports: collections.deque = collections.deque()
-            # admitted-handoff wait-queue bound: beyond it
-            # import_prefill rejects SYNCHRONOUSLY (KVPoolFullError) so
-            # the caller can route elsewhere.  None (default) queues
-            # without bound — a queued import costs one deque entry
-            # plus its handoff bytes, and FIFO page allocation cannot
-            # wedge (pages free as resident streams complete, exactly
-            # the pending-prefill contract).  Routers that would
-            # otherwise poll a full pool are the reason rejection is
-            # a cap, not the default: at saturation, thousands of
-            # re-queue round-trips/s cost more decode throughput than
-            # the waiting ever could.
-            self.import_queue_max = import_queue_max
-            self._export_jit: dict = {}      # (page bucket, wave) -> fn
-            self._import_jit: dict = {}      # (page bucket, wave) -> fn
-            # optional observer called with the host-side remap wall
-            # (ms) per admitted import wave — the serving layer feeds
-            # its handoff-latency histogram without the engine growing
-            # a telemetry dependency
-            self.on_import_admit: Optional[Callable[[float], None]] = None
-            # KV pool leaf identity + handoff shape: pool leaves are
-            # [layers, pool_pages, kv_heads, page_size, 2*head_dim]
-            # (ops/paged_attention.py layout; the model declares one);
-            # _ltot counts the per-layer pools across the cache tree —
-            # the leading axis of PrefillHandoff.kv, which both handoff
-            # ends must agree on.
-            self._pool_tail = (cfg.n_kv_heads, page_size,
-                               2 * cfg.head_dim)
-            self._ltot = sum(
-                leaf.shape[0] for leaf in jax.tree.leaves(self._cache)
-                if self._is_pool_leaf(leaf))
-            block_fn = self._block_fn_paged
-            # prompt-prefix page cache (docs/serve_frontdoor.md):
-            # retained full prompt pages stay OUT of _free_pages, keyed
-            # by their chained token digests; hits borrow them read-only
-            # and prefill only the suffix.  The budget never exceeds the
-            # pool minus one working page.
-            self.prefix_cache_pages = max(
-                0, min(int(prefix_cache_pages), self.kv_pool_pages - 2))
-            self._prefix_lock = threading.Lock()
-            self._prefix_index: dict = {}    # digest -> (_PrefixEntry, n)
-            # deepest-digest -> entry, insertion-ordered for LRU
-            self._prefix_entries: collections.OrderedDict = \
-                collections.OrderedDict()
-            self._prefix_pages_used = 0
-            self._prefix_seq = 0
-            if self.prefix_cache_pages:
-                # same params/cache structure, different (static)
-                # attention path: T>1 windows at nonzero offsets attend
-                # back through the pool over borrowed prefix pages
-                self.model_prefix = GPT(cfg, decode=True,
-                                        paged_pages=self.kv_pool_pages,
-                                        page_size=page_size,
-                                        prefix_attend=True)
-            self._suffix_jit: dict = {}      # (bucket, wave) -> jitted fn
-        else:
-            self.prefix_cache_pages = 0
-            self._no_admit = (jnp.asarray(no_meta),
-                              jnp.zeros((num_slots,), jnp.int32))
-            block_fn = self._block_fn
+        self._suffix_jit: dict = {}       # (bucket, wave) -> jitted fn
+        self._export_jit: dict = {}       # (page bucket, wave) -> fn
+        self._import_jit: dict = {}       # (page bucket, wave) -> fn
+        self._free_pages: List[int] = list(
+            range(1, self.kv_pool_pages))[::-1]
+        self._ready: collections.deque = collections.deque()
+        self._stale_slots: set = set()    # evicted, redirect pending
+        self._imports: collections.deque = collections.deque()
+        # admitted-handoff wait-queue bound: beyond it import_prefill
+        # rejects SYNCHRONOUSLY (KVPoolFullError) so the caller can
+        # route elsewhere.  None (default) queues without bound — a
+        # queued import costs one deque entry plus its handoff bytes,
+        # and FIFO page allocation cannot wedge (pages free as resident
+        # streams complete, exactly the pending-prefill contract).
+        # Routers that would otherwise poll a full pool are the reason
+        # rejection is a cap, not the default: at saturation, thousands
+        # of re-queue round-trips/s cost more decode throughput than
+        # the waiting ever could.
+        self.import_queue_max = import_queue_max
+        # optional observer called with the host-side remap wall (ms)
+        # per admitted import wave — the serving layer feeds its
+        # handoff-latency histogram without the engine growing a
+        # telemetry dependency
+        self.on_import_admit: Optional[Callable[[float], None]] = None
+        # KV pool leaf identity + handoff shape: pool leaves are
+        # [layers, pool_pages, kv_heads, page_size, 2*head_dim]
+        # (ops/paged_attention.py layout; the model declares one);
+        # _ltot counts the per-layer pools across the cache tree — the
+        # leading axis of PrefillHandoff.kv, which both handoff ends
+        # must agree on.
+        self._pool_tail = (cfg.n_kv_heads, page_size, 2 * cfg.head_dim)
+        self._ltot = sum(
+            leaf.shape[0] for leaf in jax.tree.leaves(self._cache)
+            if self._is_pool_leaf(leaf))
+        # prompt-prefix page cache (docs/serve_frontdoor.md): retained
+        # full prompt pages stay OUT of _free_pages, keyed by their
+        # chained token digests; hits borrow them read-only and prefill
+        # only the suffix.  The budget never exceeds the pool minus one
+        # working page.
+        self.prefix_cache_pages = max(
+            0, min(int(prefix_cache_pages), self.kv_pool_pages - 2))
+        self._prefix_lock = threading.Lock()
+        self._prefix_index: dict = {}     # digest -> (_PrefixEntry, n)
+        # deepest-digest -> entry, insertion-ordered for LRU
+        self._prefix_entries: collections.OrderedDict = \
+            collections.OrderedDict()
+        self._prefix_pages_used = 0
+        self._prefix_seq = 0
+        if self.prefix_cache_pages:
+            # same params/cache structure, different (static) attention
+            # path: T>1 windows at nonzero offsets attend back through
+            # the pool over borrowed prefix pages
+            self.model_prefix = GPT(cfg, decode=True,
+                                    paged_pages=self.kv_pool_pages,
+                                    page_size=page_size,
+                                    prefix_attend=True)
 
         # every jitted function of the engine is named engine_<what>:
         # a profiler trace shows its program as jit_<name> on the
         # device's ``XLA Modules`` line (docs/observability.md)
         def engine_decode_block(*args):
-            return block_fn(*args)
+            return self._block_fn(*args)
         self._block_jit = jax.jit(engine_decode_block,
                                   donate_argnums=(1, 2))
 
@@ -528,15 +498,12 @@ class LLMEngine:
         return init_decode_cache(self.model, batch)
 
     def _init_state(self, seed: int):
-        state = (jnp.zeros((self._rows,), jnp.int32),     # tokens
-                 jnp.zeros((self._rows,), jnp.int32),     # positions
-                 jnp.zeros((self._rows,), jnp.float32),   # temps
-                 jax.random.PRNGKey(seed))                # device rng
-        if self.paged:
-            # + per-row block tables (zeros -> every page is scratch)
-            state = state[:3] + (jnp.zeros(
-                (self._rows, self.max_pages), jnp.int32),) + state[3:]
-        return state
+        return (jnp.zeros((self._rows,), jnp.int32),      # tokens
+                jnp.zeros((self._rows,), jnp.int32),      # positions
+                jnp.zeros((self._rows,), jnp.float32),    # temps
+                # per-row block tables (zeros -> every page is scratch)
+                jnp.zeros((self._rows, self.max_pages), jnp.int32),
+                jax.random.PRNGKey(seed))                 # device rng
 
     def _sample_fn(self, rng, logits, temps):
         """[B, V] logits + per-row temperature -> [B] token ids
@@ -546,7 +513,7 @@ class LLMEngine:
                              top_k=self.top_k, top_p=self.top_p)
 
     def _last_logits(self, model, params, cache, tokens, positions,
-                     s_reals, **kwargs):
+                     s_reals, tables):
         """``(logits [wave, vocab] of each row's last REAL position, the
         updated cache)``.  The head runs on those rows alone: float32
         logits of every position are ``wave x bucket x vocab`` (1.2 GB
@@ -554,97 +521,10 @@ class LLMEngine:
         row a prompt is read."""
         hidden, mut = model.apply(
             {"params": params, "cache": cache}, tokens, positions,
-            return_hidden=True, mutable=["cache"], **kwargs)
+            return_hidden=True, mutable=["cache"], block_tables=tables)
         last = jnp.take_along_axis(
             hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0]
         return output_logits(self.cfg, params, last), mut["cache"]
-
-    def _get_prefill(self, bucket: int, wave: int):
-        fn = self._prefill_jit.get((bucket, wave))
-        if fn is None:
-            def engine_prefill(params, packed, rng):
-                # packed [wave, bucket+3]: right-padded prompt tokens,
-                # then s_real, slot, temp*1e6 (single upload).  Per-row
-                # last REAL logit selected by s_real; first tokens
-                # sampled here so admission needs no host round-trip.
-                tokens = packed[:, :bucket]
-                s_reals = packed[:, bucket]
-                slots = packed[:, bucket + 1]
-                temps = packed[:, bucket + 2].astype(jnp.float32) / 1e6
-                b, s = tokens.shape
-                positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-                cache = self._init_cache(b)
-                last, cache = self._last_logits(
-                    self.model, params, cache, tokens, positions, s_reals)
-                first = self._sample_fn(rng, last, temps)
-                return first, cache, slots
-            fn = self._prefill_jit[(bucket, wave)] = jax.jit(
-                engine_prefill)
-        return fn
-
-    def _get_insert(self, bucket: int, wave: int):
-        fn = self._insert_jit.get((bucket, wave))
-        if fn is None:
-            def engine_insert(cache, pre, slots):
-                # scatter each prefilled row's first `bucket` positions
-                # into its slot; padded rows carry slot == num_slots
-                # (the scratch row)
-                def leaf(g, p):
-                    # K/V leaves are [..., batch, seq, kv_heads, head_dim]
-                    # (a leading layer axis under scan_layers): the batch
-                    # axis sits at ndim-4 BY LAYOUT, never inferred from
-                    # shapes — wave can equal the global row count.
-                    # Lower-rank leaves (per-layer scalar "index") are
-                    # engine-unused in slot mode: skip.
-                    if g.ndim < 4:
-                        return g
-                    ax = g.ndim - 4
-                    for r in range(wave):
-                        row = jax.lax.slice_in_dim(p, r, r + 1, axis=ax)
-                        row = jax.lax.slice_in_dim(row, 0, bucket,
-                                                   axis=ax + 1)
-                        start = [jnp.int32(0)] * g.ndim
-                        start[ax] = slots[r]
-                        g = jax.lax.dynamic_update_slice(g, row, start)
-                    return g
-                return jax.tree.map(leaf, cache, pre)
-            fn = self._insert_jit[(bucket, wave)] = jax.jit(
-                engine_insert, donate_argnums=(0,))
-        return fn
-
-    def _block_fn(self, params, cache, state, admit_meta, a_firsts):
-        """lax.scan of block_size decode steps: one dispatch, ONE
-        combined [rows*K + num_slots] fetch of (token block, admission
-        first tokens), and all decode state chained on device.  Newly
-        admitted rows' tokens/positions/temps are scattered in here;
-        admit_meta is one packed [3, num_slots] i32 upload (slots,
-        positions, temps*1e6), padded so every quantum reuses one
-        compiled program (pad slots point at the scratch row)."""
-        tokens, positions, temps, rng = state
-        a_slots = admit_meta[0]
-        tokens = tokens.at[a_slots].set(a_firsts)
-        positions = positions.at[a_slots].set(admit_meta[1])
-        temps = temps.at[a_slots].set(
-            admit_meta[2].astype(jnp.float32) / 1e6)
-        rng, sub = jax.random.split(rng)
-        keys = jax.random.split(sub, self.block_size)
-
-        def one(carry, key):
-            tokens, positions, cache = carry
-            logits, mut = self.model.apply(
-                {"params": params, "cache": cache}, tokens[:, None],
-                positions[:, None], mutable=["cache"])
-            nxt = self._sample_fn(key, logits[:, -1], temps)
-            positions = jnp.minimum(positions + 1,
-                                    self.cfg.max_seq_len - 1)
-            return (nxt, positions, mut["cache"]), nxt
-
-        (tokens, positions, cache), block = jax.lax.scan(
-            one, (tokens, positions, cache), keys)
-        combined = jnp.concatenate([block.T.reshape(-1), a_firsts])
-        return combined, (tokens, positions, temps, rng), cache
-
-    # ------------------------------------------------ paged-mode jit fns
 
     def _get_prefill_paged(self, bucket: int, wave: int):
         """Slotless prefill: prompts write straight into pool pages via
@@ -662,7 +542,7 @@ class LLMEngine:
                 positions = jnp.broadcast_to(jnp.arange(s), (b, s))
                 last, cache = self._last_logits(
                     self.model, params, cache, tokens, positions, s_reals,
-                    block_tables=tables)
+                    tables)
                 first = self._sample_fn(rng, last, temps)
                 return first, cache
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
@@ -689,7 +569,7 @@ class LLMEngine:
                     jnp.arange(s), (b, s))
                 last, cache = self._last_logits(
                     self.model_prefix, params, cache, tokens, positions,
-                    s_reals, block_tables=tables)
+                    s_reals, tables)
                 first = self._sample_fn(rng, last, temps)
                 return first, cache
             fn = self._suffix_jit[(bucket, wave)] = jax.jit(
@@ -766,13 +646,19 @@ class LLMEngine:
                 engine_kv_import, donate_argnums=(0,))
         return fn
 
-    def _block_fn_paged(self, params, cache, state, admit_meta,
-                        admit_lasts, admit_tables):
-        """Paged block step.  Differences from _block_fn: per-row block
-        tables ride the device state; installs upload their CURRENT last
-        token (known to the host since the request's prefill quantum) so
-        nothing extra is fetched; redirect rows (evicted slots) are just
-        installs of (token 0, position 0, zero table -> scratch page)."""
+    def _block_fn(self, params, cache, state, admit_meta, admit_lasts,
+                  admit_tables):
+        """lax.scan of block_size decode steps: one dispatch, ONE fetch
+        of the [rows * K] token block, and all decode state (per-row
+        block tables included) chained on device.  Installed rows'
+        tokens/positions/temps/tables are scattered in here; admit_meta
+        is one packed [3, num_slots] i32 upload (slots, positions,
+        temps*1e6), padded so every block reuses one compiled program
+        (pad slots point at the scratch row).  Installs upload their
+        CURRENT last token (known to the host since the request's
+        prefill) so nothing extra is fetched; redirect rows (evicted
+        slots) are just installs of (token 0, position 0, zero table ->
+        scratch page)."""
         tokens, positions, temps, tables, rng = state
         a_slots = admit_meta[0]
         tokens = tokens.at[a_slots].set(admit_lasts)
@@ -845,41 +731,26 @@ class LLMEngine:
         no request pays compile latency.  Serve replicas call this at
         init; benchmarks call it before timing.
 
-        ``burst`` (paged mode): additionally push that many 1-token
-        dummy requests through the live loop at once, compiling the
-        saturation-burst paths the per-function loops can't reach (the
-        combined multi-wave fetch concat; its shape depends on the burst
+        ``burst``: additionally push that many 1-token dummy requests
+        through the live loop at once, compiling the saturation-burst
+        paths the per-function loops can't reach (the combined
+        multi-wave fetch concat; its shape depends on the burst
         decomposition)."""
         buckets = sorted({self._bucket(n) for n in prompt_lens})
         rng = jax.random.PRNGKey(0)
-        # dense admission is bounded by free slots, so waves beyond
-        # num_slots are dead shapes — don't pay their compiles (paged
-        # prefill is slotless: any wave size can occur)
-        sizes = [w for w in _WAVE_SIZES
-                 if self.paged or w == 1 or w // 2 < self.num_slots]
         for bucket in buckets:
-            for wave in sizes:
-                if self.paged:
-                    packed = np.zeros((wave, bucket + 2), np.int32)
-                    packed[:, bucket] = 1
-                    tables = jnp.zeros((wave, self.max_pages), jnp.int32)
-                    _, self._cache = self._get_prefill_paged(
-                        bucket, wave)(self.params, self._cache,
-                                      jnp.asarray(packed), tables, rng)
-                    continue
-                packed = np.zeros((wave, bucket + 3), np.int32)
+            # prefill is slotless: any wave size can occur
+            for wave in _WAVE_SIZES:
+                packed = np.zeros((wave, bucket + 2), np.int32)
                 packed[:, bucket] = 1
-                packed[:, bucket + 1] = self.num_slots      # scratch
-                firsts, pre, slots = self._get_prefill(bucket, wave)(
-                    self.params, jnp.asarray(packed), rng)
-                self._cache = self._get_insert(bucket, wave)(
-                    self._cache, pre, slots)
+                tables = jnp.zeros((wave, self.max_pages), jnp.int32)
+                _, self._cache = self._get_prefill_paged(bucket, wave)(
+                    self.params, self._cache, jnp.asarray(packed), tables,
+                    rng)
         combined, self._state, self._cache = self._block_jit(
             self.params, self._cache, self._state, *self._no_admit)
         np.asarray(combined)   # force completion (and the compile)
-        if burst and self.paged:
-            import asyncio
-
+        if burst:
             plen = max(prompt_lens)
 
             async def _burst():
@@ -925,7 +796,7 @@ class LLMEngine:
             raise ValueError(f"prompt len {len(prompt)} > max_prompt_len "
                              f"{self.max_prompt_len}")
         digests = (page_digests(prompt, self.page_size)
-                   if self.paged and self.prefix_cache_pages else None)
+                   if self.prefix_cache_pages else None)
         return self._submit_request(
             lambda deliver: _Request(list(prompt), max_new_tokens,
                                      temperature, eos_id, deliver,
@@ -947,7 +818,6 @@ class LLMEngine:
         onto the calling event loop; the engine's completion delivery
         is loop-ordered after every bridged token, so the final result
         always follows the tokens it summarizes."""
-        import asyncio
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
 
@@ -979,15 +849,13 @@ class LLMEngine:
     def export_prefill(self, prompt: List[int], *,
                        max_new_tokens: int = 32, temperature: float = 0.0,
                        eos_id: Optional[int] = None):
-        """Prefill-only submit: run slotless paged prefill, sample the
+        """Prefill-only submit: run slotless prefill, sample the
         first token, then GATHER the request's pool pages into one
         contiguous host buffer and free them — the request never takes
         a decode slot here.  Resolves to a PrefillHandoff that
         ``import_prefill`` on another engine admits straight into
         decode.  Loop-aware like ``submit`` (awaitable inside an event
         loop, blocking from a plain thread)."""
-        if not self.paged:
-            raise RuntimeError("export_prefill requires paged=True")
         if len(prompt) == 0:
             raise ValueError("empty prompt")
         if len(prompt) > self.max_prompt_len:
@@ -1018,8 +886,6 @@ class LLMEngine:
         KVPoolFullError is raised SYNCHRONOUSLY only when
         ``import_queue_max`` is set and the wait queue is full — the
         signal for the router to re-queue against another replica."""
-        if not self.paged:
-            raise RuntimeError("import_prefill requires paged=True")
         h = handoff
         if h.finish_reason is not None:
             raise ValueError("handoff already finished at its first "
@@ -1154,16 +1020,13 @@ class LLMEngine:
         with self._lock:
             return {
                 "pending": len(self._pending),
-                "imports": len(self._imports) if self.paged else 0,
-                "ready": len(self._ready) if self.paged else 0,
+                "imports": len(self._imports),
+                "ready": len(self._ready),
                 "busy_slots": self.num_slots - len(self._free),
-                "free_pages": (len(self._free_pages) if self.paged
-                               else 0),
-                "pool_pages": self.kv_pool_pages if self.paged else 0,
-                "prefix_pages_cached": (self._prefix_pages_used
-                                        if self.paged else 0),
-                "prefix_entries": (len(self._prefix_entries)
-                                   if self.paged else 0),
+                "free_pages": len(self._free_pages),
+                "pool_pages": self.kv_pool_pages,
+                "prefix_pages_cached": self._prefix_pages_used,
+                "prefix_entries": len(self._prefix_entries),
             }
 
     def close(self):
@@ -1173,7 +1036,7 @@ class LLMEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
 
-    # -------------------------------------------------------- engine loop
+    # ------------------------------------------------------- loop helpers
 
     def _enqueue(self, req: _Request):
         with self._lock:
@@ -1197,8 +1060,8 @@ class LLMEngine:
 
     def _wave_chunks(self, items: list):
         """Group (req, payload) pairs by prompt-length bucket and yield
-        (bucket, chunk, wave_size) batches — the one admission-batching
-        policy both the dense and the paged prefill paths follow."""
+        (bucket, chunk, wave_size) batches — the admission-batching
+        policy of the prefill path."""
         by_bucket: dict = {}
         for item in items:
             by_bucket.setdefault(self._bucket(len(item[0].prompt)),
@@ -1213,47 +1076,12 @@ class LLMEngine:
         self._rng, key = jax.random.split(self._rng)
         return key
 
-    def _dispatch_admission_wave(self, group: list, bucket: int,
-                                 wave: int):
-        """One batched prefill + one batched cache insert for admits
-        sharing a prompt-length bucket.  Returns the DEVICE array of
-        their first tokens — nothing is fetched here, and everything
-        rides ONE packed upload (each host->device transfer is its own
-        dispatch with a fixed host-side latency)."""
-        # packed layout per row: [prompt(bucket) | s_real | slot | temp*1e6]
-        packed = np.zeros((wave, bucket + 3), np.int32)
-        packed[:, bucket] = 1
-        packed[:, bucket + 1] = self.num_slots        # pad rows: scratch
-        for r, (req, slot) in enumerate(group):
-            packed[r, :len(req.prompt)] = req.prompt
-            packed[r, bucket] = len(req.prompt)
-            packed[r, bucket + 1] = slot
-            packed[r, bucket + 2] = int(req.temperature * 1e6)
-        firsts, pre_cache, slots = self._get_prefill(bucket, wave)(
-            self.params, jnp.asarray(packed), self._next_key())
-        self._cache = self._get_insert(bucket, wave)(
-            self._cache, pre_cache, slots)
-        self._count_prefill_wave(
-            len(group), sum(len(req.prompt) for req, _ in group),
-            wave * bucket)
-        return firsts[:len(group)]
-
     def _count_prefill_wave(self, requests: int, prompt_tokens: int,
                             padded_tokens: int) -> None:
         self.stats.prefills += requests
         self.stats.prefill_waves += 1
         self.stats.prefill_prompt_tokens += prompt_tokens
         self.stats.prefill_padded_tokens += padded_tokens
-
-    def _finish_admit(self, req: _Request, slot: int, first: int):
-        self.stats.tokens_generated += 1
-        sl = _Slot(req, len(req.prompt), first)
-        sl.installed_at = sl.first_token_at   # dense: admitted to a slot
-        self._slots[slot] = sl
-        if req.on_token is not None:
-            self._safe_on_token(req, first)
-        # a 1-token request (or instant eos) finishes without stepping
-        self._maybe_finish(slot)
 
     def _safe_on_token(self, req: _Request, token: int):
         try:
@@ -1311,106 +1139,16 @@ class LLMEngine:
             return False
         self._slots[i] = None
         self._free.append(i)
-        if self.paged:
-            # the freed slot junk-steps its old table until its redirect
-            # row rides a block dispatch; pages recycle only through
-            # later dispatches, so immediate free is stream-safe (see
-            # module docstring).  Junk writes only ever advance PAST the
-            # prompt span, so leading pages retained by the prefix cache
-            # are never touched by the straggling steps.
-            self._stale_slots.add(i)
-            self._prefix_release(sl)
+        # the freed slot junk-steps its old table until its redirect
+        # row rides a block dispatch; pages recycle only through later
+        # dispatches, so immediate free is stream-safe (see module
+        # docstring).  Junk writes only ever advance PAST the prompt
+        # span, so leading pages retained by the prefix cache are never
+        # touched by the straggling steps.
+        self._stale_slots.add(i)
+        self._prefix_release(sl)
         self._deliver_result(sl, reason)
         return True
-
-    def _loop(self):
-        self.stats._loop_mark = time.monotonic()
-        if self.paged:
-            return self._loop_paged()
-        # Software-pipelined: quantum k+1 is DISPATCHED before quantum
-        # k's results are fetched and processed, so the device never
-        # idles on the host's fetch round-trip or bookkeeping.  The
-        # price is a one-block admission/eviction lag, which the
-        # request-identity checks in _process_quantum make safe.
-        inflight = None
-        while True:
-            with self._lock:
-                while (not self._closed and not self._pending
-                       and all(s is None for s in self._slots)
-                       and inflight is None):
-                    with self._phase("wait_work", "idle_wait_s"):
-                        self._lock.wait()
-                if self._closed:
-                    victims = ([s.request for s in self._slots
-                                if s is not None]
-                               + ([r for r, _ in inflight[1]]
-                                  if inflight else [])
-                               + list(self._pending))
-                    self._pending.clear()
-                    for req in victims:
-                        self._safe_deliver(
-                            req, False,
-                            RuntimeError("engine closed"))
-                    return
-                admits = []
-                with self._phase("admit") as sp:
-                    now = time.monotonic()
-                    while self._pending and self._free:
-                        req = self._pending.popleft()
-                        req.admitted_at = now
-                        admits.append((req, self._free.pop()))
-                    sp.set_metadata(admitted=len(admits),
-                                    pending=len(self._pending))
-            try:
-                nxt = self._dispatch_quantum(admits, inflight)
-                if inflight is not None:
-                    self._process_quantum(inflight)
-                inflight = nxt
-            except Exception as e:   # engine-fatal (OOM, compile error)
-                with self._lock:
-                    victims = ([s.request for s in self._slots
-                                if s is not None]
-                               + [a[0] for a in admits]
-                               + ([r for r, _ in inflight[1]]
-                                  if inflight else [])
-                               + list(self._pending))
-                    self._pending.clear()
-                    self._slots = [None] * self.num_slots
-                    self._free = list(range(self.num_slots))[::-1]
-                inflight = None
-                # the block/insert calls donate the cache and device
-                # state: after a failed call the old buffers may be
-                # deleted — rebuild before continuing
-                self._cache = self._init_cache(self._rows)
-                self._state = self._init_state(0)
-                for req in victims:
-                    self._safe_deliver(req, False, e)
-
-    def _dispatch_quantum(self, admits: list, inflight):
-        """Prefill + enqueue one decode block; returns (combined_device,
-        admitted, rows) or None when there is nothing to run.  ``rows``
-        snapshots (slot_index, request) pairs whose tokens this block
-        carries — including the PREVIOUS quantum's admissions, which are
-        decoding on device but not yet placed in _slots."""
-        admitted = []                      # (req, slot) in firsts order
-        firsts_parts = []
-        if admits:
-            with self._prefill_phase():
-                for bucket, chunk, wave in self._wave_chunks(admits):
-                    firsts_parts.append(
-                        self._dispatch_admission_wave(chunk, bucket, wave))
-                    admitted.extend(chunk)
-
-        rows = [(i, s.request) for i, s in enumerate(self._slots)
-                if s is not None]
-        if inflight is not None:
-            rows += [(slot, req) for req, slot in inflight[1]]
-        rows += [(slot, req) for req, slot in admitted]
-        if not rows:
-            return None
-        with self._phase("dispatch_block") as sp:
-            sp.set_metadata(installs=len(admitted), active=len(rows))
-            return self._dispatch_block(admitted, firsts_parts, rows)
 
     @contextlib.contextmanager
     def _prefill_phase(self):
@@ -1426,48 +1164,9 @@ class LLMEngine:
                 prompt_tokens=st.prefill_prompt_tokens - prompt,
                 padded_tokens=st.prefill_padded_tokens - padded)
 
-    def _dispatch_block(self, admitted: list, firsts_parts: list,
-                        rows: list):
-        """The dense block dispatch of :meth:`_dispatch_quantum`."""
-        # decode state (tokens/positions/temps/rng) is device-chained;
-        # the host uploads one packed admit array, cached when empty
-        n_admit = len(admitted)
-        if n_admit:
-            A = self.num_slots
-            meta = np.zeros((3, A), np.int32)
-            meta[0, :] = self.num_slots
-            for r, (req, slot) in enumerate(admitted):
-                meta[0, r] = slot
-                meta[1, r] = len(req.prompt)
-                meta[2, r] = int(req.temperature * 1e6)
-            pad = jnp.zeros((A - n_admit,), jnp.int32)
-            admit_meta = jnp.asarray(meta)
-            admit_firsts = jnp.concatenate(firsts_parts + [pad])
-        else:
-            admit_meta, admit_firsts = self._no_admit
-        combined, self._state, self._cache = self._block_jit(
-            self.params, self._cache, self._state, admit_meta,
-            admit_firsts)
-        return (combined, admitted, rows)
-
-    def _process_quantum(self, quantum):
-        combined, admitted, rows = quantum
-        with self._phase("fetch_block", "fetch_wait_s"):
-            host = np.asarray(combined)    # the ONE fetch this quantum
-        K = self.block_size
-        block = host[:self._rows * K].reshape(self._rows, K)
-        # --- admissions complete (their first tokens are now known) ---
-        if admitted:
-            with self._phase("deliver_prefill", "deliver_s") as sp:
-                sp.set_metadata(requests=len(admitted))
-                for (req, slot), first in zip(admitted,
-                                              host[self._rows * K:]):
-                    self._finish_admit(req, slot, int(first))
-        self._deliver_block(block, rows)
-
     def _deliver_block(self, block, rows: list) -> None:
         """Hand one fetched decode block's tokens to their requests,
-        truncating junk past each row's finish (both loops)."""
+        truncating junk past each row's finish."""
         with self._phase("deliver_block", "deliver_s") as sp:
             st = self.stats
             st.steps += self.block_size
@@ -1528,7 +1227,7 @@ class LLMEngine:
         """Boundary digests of retained prefix runs, newest entries
         first — the replica's advertisement on the controller
         load-publish path (frontdoor/prefix.py contract)."""
-        if not (self.paged and self.prefix_cache_pages):
+        if not self.prefix_cache_pages:
             return []
         out: List[str] = []
         with self._prefix_lock:
@@ -1652,7 +1351,7 @@ class LLMEngine:
         return n_full
 
     def _prefix_release(self, sl: _Slot) -> None:
-        """Free a paged slot's pages with prefix accounting: borrowed
+        """Free a slot's pages with prefix accounting: borrowed
         prefix pages go back to their entry (refcount), owned pages are
         offered to retention first, the rest return to the pool."""
         kept = self._prefix_retain(sl)
@@ -1667,14 +1366,12 @@ class LLMEngine:
     def _prefix_reset(self) -> None:
         """Engine-fatal recovery: the pool was rebuilt, every retained
         page id is meaningless — drop the cache wholesale."""
-        if not self.paged:
-            return
         with self._prefix_lock:
             self._prefix_index.clear()
             self._prefix_entries.clear()
             self._prefix_pages_used = 0
 
-    # ---------------------------------------------------- paged engine loop
+    # -------------------------------------------------------- engine loop
 
     def _pages_needed(self, req: _Request) -> int:
         if req.export:
@@ -1686,15 +1383,20 @@ class LLMEngine:
                    self.cfg.max_seq_len)
         return -(-span // self.page_size)
 
-    def _loop_paged(self):
-        """Pipelined like _loop, with a slotless prefill stage ahead of
+    def _loop(self):
+        """Software-pipelined, with a slotless prefill stage ahead of
         the block: each iteration (1) prefills as many queued prompts as
         the pool allows, (2) installs ready requests into free slots and
         dispatches the next block, (3) processes the PREVIOUS block's
         fetch, (4) fetches this iteration's prefill first-tokens (the
-        device finished them before the just-dispatched block).  TTFT is
-        therefore one prefill round-trip, independent of slot turnover.
+        device finished them before the just-dispatched block).  Block
+        k+1 is dispatched before block k's tokens are fetched, so the
+        device never idles on the host's fetch round-trip or
+        bookkeeping; the price is a one-block install/eviction lag,
+        which the request-identity check in _deliver_block makes safe.
+        TTFT is one prefill round-trip, independent of slot turnover.
         """
+        self.stats._loop_mark = time.monotonic()
         inflight = None       # (combined_dev, rows)
         while True:
             with self._lock:
@@ -1802,11 +1504,11 @@ class LLMEngine:
                         new_prefills = (self._dispatch_prefill_waves(todo)
                                         + self._dispatch_suffix_waves(hits))
                 with self._phase("dispatch_block") as sp:
-                    nxt = self._dispatch_block_paged(installs)
+                    nxt = self._dispatch_block(installs)
                     sp.set_metadata(installs=len(installs),
                                     active=len(nxt[1]) if nxt else 0)
                 if inflight is not None:
-                    self._process_block_paged(inflight)
+                    self._process_block(inflight)
                 exports = self._process_prefill_waves(new_prefills)
                 if exports:
                     with self._phase("export") as sp:
@@ -2067,7 +1769,7 @@ class LLMEngine:
                     with self._lock:
                         self._ready.append(_Prefilled(sl, table))
 
-    def _dispatch_block_paged(self, installs: list):
+    def _dispatch_block(self, installs: list):
         """Install ready requests into free slots (their last token and
         position are host-known — nothing is fetched), attach redirect
         rows for stale slots, and dispatch one decode block.  Returns
@@ -2105,7 +1807,7 @@ class LLMEngine:
                 if s is not None]
         return (combined, rows)
 
-    def _process_block_paged(self, quantum) -> None:
+    def _process_block(self, quantum) -> None:
         combined, rows = quantum
         with self._phase("fetch_block", "fetch_wait_s"):
             host = np.asarray(combined)    # the ONE fetch this quantum

@@ -54,8 +54,12 @@ def on_tpu(monkeypatch):
     """Make kernel dispatch answer "tpu" (compiled Pallas, not the
     interpreter): the compile target is the described chip, while
     ``jax.devices()`` here is the CPU."""
-    for name in ("attention", "flash_attention", "paged_attention", "moe"):
-        mod = importlib.import_module(f"ray_tpu.ops.{name}")
+    # import them all before patching any: a module first imported here
+    # would bind attention's PATCHED function as its own, and the undo
+    # would put that back for every later test of the process
+    mods = [importlib.import_module(f"ray_tpu.ops.{name}") for name in
+            ("attention", "flash_attention", "paged_attention", "moe")]
+    for mod in mods:
         monkeypatch.setattr(mod, "backend_platform", lambda: "tpu")
 
 
@@ -190,7 +194,7 @@ def test_engine_prefill_and_paged_decode_programs(topo, one_chip, on_tpu):
     cfg = get_config("gpt-small", n_layers=2)
     params = GPT(cfg, decode=True).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))["params"]
-    eng = LLMEngine(cfg, params, num_slots=8, max_seq_len=256, paged=True,
+    eng = LLMEngine(cfg, params, num_slots=8, max_seq_len=256,
                     page_size=64)
     prefill = eng._get_prefill_paged(64, 8).lower(
         *_shapes((eng.params, eng._cache,
@@ -266,8 +270,7 @@ def _described_engine(cfg, monkeypatch, **kw):
         patch.setattr(
             generate, "init_decode_cache",
             lambda model, batch: jax.eval_shape(lambda: init(model, batch)))
-        return LLMEngine(cfg, params, num_slots=32, paged=True,
-                         page_size=64, **kw)
+        return LLMEngine(cfg, params, num_slots=32, page_size=64, **kw)
 
 
 def _smollm_engine(pages, monkeypatch):

@@ -99,7 +99,7 @@ def test_forward_pass_matches_the_reference(parts, monkeypatch, pairs_max):
 
 def _engine(cfg, params, **kw):
     from ray_tpu.serve.llm_engine import LLMEngine
-    return LLMEngine(cfg, params, num_slots=2, paged=True, page_size=4,
+    return LLMEngine(cfg, params, num_slots=2, page_size=4,
                      max_seq_len=64, max_prompt_len=32, block_size=4,
                      min_prefill_bucket=4, **kw)
 
@@ -144,7 +144,7 @@ def test_paged_prefill_and_decode_match_the_reference(parts):
 
 def test_the_engine_s_greedy_tokens_are_the_reference_s(parts):
     """(b) the loop itself: two requests prefilled and decoded past the
-    window by ``LLMEngine(paged=True)``; every token it returned is the
+    window by ``LLMEngine``; every token it returned is the
     reference's largest logit at its position, and the engine counted
     the expert load and the pages its window layers left unread."""
     from chipbench.lib import reference_smallthinker as ref

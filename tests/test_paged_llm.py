@@ -1,12 +1,13 @@
-"""Paged KV-cache serving: block tables, pool recycling, prefill-ahead.
+"""The serving engine's KV pool: block tables, pool recycling,
+prefill-ahead.
 
-VERDICT round-4 task #1: replace the dense per-slot ``[max_seq]`` KV rows
-with paged allocation (ops/paged_attention.py + llm_engine paged mode).
-The bar: slot decode matches lone generation at mixed offsets, pages
-recycle safely across requests, and queued requests get their first
-token from the slotless prefill stage (the TTFT knob) instead of
-waiting for slot turnover.  CPU-sized; real-chip numbers live in
-benchmarks/serve_llm.py --paged.
+``LLMEngine`` serves from a shared page pool (ops/paged_attention.py)
+and nothing else.  The bar: slot decode matches lone generation
+(``models/generate.py Generator``, the plain reference) at mixed
+offsets, pages recycle safely across requests, and queued requests get
+their first token from the slotless prefill stage (the TTFT knob)
+instead of waiting for slot turnover.  CPU-sized; the chip's numbers
+are the benchmark's (chipbench/) and benchmarks/serve_llm.py's.
 """
 
 import threading
@@ -122,24 +123,119 @@ def test_paged_model_matches_dense_at_mixed_offsets(tiny_parts_either):
     assert out == expect
 
 
-def test_paged_engine_matches_lone_generation(tiny_parts):
-    """Engine-level (the VERDICT bar): greedy decode through the paged
-    engine — slotless prefill, install, per-row tables — equals each
-    prompt generated alone, with more requests than decode slots."""
+@pytest.mark.parametrize("front", ["LLMEngine", "LLMServer"])
+def test_paged_false_is_refused(front):
+    """The dense engine is gone: the keyword has one legal value (it
+    stays while chipbench/ passes it, ROADMAP C12) and the other one
+    raises, before any weights are made."""
+    from ray_tpu.serve.llm import LLMServer
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    with pytest.raises(ValueError, match="dense engine was removed"):
+        if front == "LLMServer":
+            LLMServer("no-such-preset", paged=False)
+        else:
+            LLMEngine(None, None, paged=False)
+
+
+def test_a_server_nobody_configured_names_its_decode_kernel():
+    """``LLMServer("tiny")`` serves from the pool: its replica reports
+    which paged-decode implementation it resolved to, never ``None``."""
+    from ray_tpu.serve.llm import LLMServer
+
+    srv = LLMServer("tiny")
+    try:
+        info = srv.device_info()
+        assert info["paged_impl"] in ("tpu", "xla")
+        assert "paged" not in info
+        eng = srv.engine
+        assert eng.load_snapshot()["pool_pages"] == (
+            1 + (eng.num_slots + 1) * eng.max_pages)
+    finally:
+        srv.engine.close()
+
+
+# (engine arguments, prompts): the shapes that once had an engine test
+# each.  ``rows-wide-wave``: three prompts into num_slots=3, an
+# admission wave (4) as wide as the engine's row count (3 + scratch).
+# ``default-engine``: no argument at all, more requests than its slots.
+_EQUALITY_CASES = {
+    "more-requests-than-slots": (
+        dict(num_slots=2, block_size=4, page_size=16, kv_pool_pages=1 + 8),
+        [[1, 2, 3], [7, 8, 9, 10, 11], [50, 60], [5] * 9]),
+    "rows-wide-wave": (
+        dict(num_slots=3, block_size=4),
+        [[11, 12, 13], [21, 22], [31, 32, 33, 34]]),
+    "one-block-covers-the-answer": (
+        dict(num_slots=4),
+        [[1, 2, 3], [7, 8, 9, 10, 11], [50, 60]]),
+    "default-engine": (
+        dict(),
+        [[i + 1, i + 2, i + 3][:1 + i % 3] for i in range(11)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_EQUALITY_CASES))
+def test_engine_matches_lone_generation(tiny_parts, case):
+    """Engine-level (the VERDICT bar): greedy decode through the engine
+    — slotless prefill, install, per-row tables — equals each prompt
+    generated alone, whatever its neighbours in the batch; a pool that
+    nobody sized holds every slot at full length plus scratch."""
     from ray_tpu.serve.llm_engine import LLMEngine
 
     cfg, params = tiny_parts
-    prompts = [[1, 2, 3], [7, 8, 9, 10, 11], [50, 60], [5] * 9]
+    kw, prompts = _EQUALITY_CASES[case]
     expect = _lone_expect(cfg, params, prompts)
-    eng = LLMEngine(cfg, params, num_slots=2, block_size=4, paged=True,
-                    page_size=16, kv_pool_pages=1 + 8)
+    eng = LLMEngine(cfg, params, **kw)
     try:
+        assert eng.load_snapshot()["pool_pages"] == kw.get(
+            "kv_pool_pages", 1 + (eng.num_slots + 1) * eng.max_pages)
         results = _submit_all(eng, prompts)
         for i in range(len(prompts)):
             assert results[i] is not None
             assert results[i].tokens == expect[i], (
-                f"paged decode diverged for prompt {i}")
+                f"decode diverged for prompt {i}")
             assert results[i].prompt_len == len(prompts[i])
+    finally:
+        eng.close()
+
+
+def test_default_pool_holds_every_slot_at_full_length(tiny_parts):
+    """``num_slots`` requests that each run to ``max_seq_len`` all get
+    their pages at once on the default pool: every first token is out
+    before any request has finished (a request that had to wait for
+    pages gets them only when another finishes), and every page comes
+    back."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts
+    eng = LLMEngine(cfg, params, num_slots=3)
+    try:
+        done_at_first_token = {}
+
+        def submit(rid):
+            def on_token(_tok):
+                # the loop thread's own count, read on the loop thread
+                done_at_first_token.setdefault(
+                    rid, eng.stats.requests_completed)
+            return eng.submit([rid + 1, rid + 2], temperature=0.0,
+                              max_new_tokens=2 * cfg.max_seq_len,
+                              on_token=on_token)
+
+        results = [None] * 3
+        threads = [threading.Thread(
+            target=lambda i=i: results.__setitem__(i, submit(i)))
+            for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=240)
+        assert done_at_first_token == {0: 0, 1: 0, 2: 0}
+        for r in results:
+            assert r is not None and r.finish_reason == "length"
+            assert r.prompt_len + len(r.tokens) == cfg.max_seq_len
+        snap = eng.load_snapshot()
+        assert snap["free_pages"] == snap["pool_pages"] - 1
     finally:
         eng.close()
 
@@ -155,7 +251,7 @@ def test_page_recycling_stays_exact(tiny_parts):
     prompts = [[i + 1, i + 2, i + 3] for i in range(16)]
     expect = _lone_expect(cfg, params, prompts, n=6)
     # 4 usable pages, 1 page per request -> at most 4 in flight, 16 total
-    eng = LLMEngine(cfg, params, num_slots=2, block_size=4, paged=True,
+    eng = LLMEngine(cfg, params, num_slots=2, block_size=4,
                     page_size=16, kv_pool_pages=1 + 4)
     try:
         results = _submit_all(eng, prompts, n=6)
@@ -174,7 +270,7 @@ def test_prefill_ahead_ttft_decoupled_from_slot_wait(tiny_parts):
     from ray_tpu.serve.llm_engine import LLMEngine
 
     cfg, params = tiny_parts
-    eng = LLMEngine(cfg, params, num_slots=1, block_size=4, paged=True,
+    eng = LLMEngine(cfg, params, num_slots=1, block_size=4,
                     page_size=16, kv_pool_pages=1 + 8)
     try:
         eng.warmup(prompt_lens=[3])
@@ -219,29 +315,46 @@ def test_prefill_ahead_ttft_decoupled_from_slot_wait(tiny_parts):
         eng.close()
 
 
-def test_paged_eos_streaming_and_oversized(tiny_parts):
-    """eos stops a paged row; on_token streams in order; a request that
-    can never fit the pool fails alone without wedging the loop."""
+@pytest.mark.parametrize("kw", [
+    dict(num_slots=2),
+    dict(num_slots=2, max_prompt_len=16),
+    dict(num_slots=2, block_size=4, page_size=16, kv_pool_pages=1 + 6,
+         max_prompt_len=60),
+], ids=["default-pool", "short-prompts", "small-pool"])
+def test_eos_streaming_and_refusals(tiny_parts, kw):
+    """on_token fires once per generated token, in order; eos stops a
+    row; an answer stops at max_seq_len; a prompt over max_prompt_len,
+    or a request that can never fit the pool, fails alone without
+    wedging the loop."""
     from ray_tpu.serve.llm_engine import LLMEngine
 
     cfg, params = tiny_parts
-    eng = LLMEngine(cfg, params, num_slots=2, block_size=4, paged=True,
-                    page_size=16, kv_pool_pages=1 + 6, max_prompt_len=60)
+    eng = LLMEngine(cfg, params, **kw)
     try:
         seen = []
-        probe = eng.submit([3, 4, 5], max_new_tokens=4, temperature=0.0,
+        probe = eng.submit([3, 4, 5], max_new_tokens=5, temperature=0.0,
                            on_token=seen.append)
-        assert seen == probe.tokens
+        assert seen == probe.tokens and len(seen) == 5
+        # the first greedily generated token as a fake eos: the request
+        # must stop right there
         eos = probe.tokens[0]
         r = eng.submit([3, 4, 5], max_new_tokens=64, temperature=0.0,
                        eos_id=eos)
         assert r.finish_reason == "eos"
         assert r.tokens == [eos]
-        # needs ceil(min(60+128, max_seq 128)/16) = 8 pages > pool's 6
-        with pytest.raises(ValueError):
-            eng.submit([9] * 60, max_new_tokens=128)
+        with pytest.raises(ValueError, match="max_prompt_len"):
+            eng.submit([9] * (eng.max_prompt_len + 1), max_new_tokens=4)
+        longest = dict(max_new_tokens=2 * cfg.max_seq_len)
+        if eng.max_pages > eng.kv_pool_pages - 1:
+            # needs ceil(max_seq 128 / 16) = 8 pages > the pool's 6
+            with pytest.raises(ValueError, match="KV pages"):
+                eng.submit([9] * 60, **longest)
+        else:
+            r = eng.submit([3, 4, 5], **longest)       # > max_seq_len cap
+            assert r.finish_reason == "length"
+            assert r.prompt_len + len(r.tokens) == cfg.max_seq_len
         # engine still serves afterwards
-        r2 = eng.submit([3, 4, 5], max_new_tokens=4, temperature=0.0)
+        r2 = eng.submit([3, 4, 5], max_new_tokens=5, temperature=0.0)
         assert r2.tokens == probe.tokens
     finally:
         eng.close()
@@ -366,28 +479,37 @@ def test_write_kv_pages_touches_only_its_rows(layer, window):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-def test_handoff_round_trip_equals_lone_generation(tiny_parts_either):
-    """export_prefill on one engine, import_prefill on another: the
-    pages ship as [layers, npages, ...] out of one stacked pool into
-    another, and decode continues exactly as lone generation."""
+def _handoff_round_trip(cfg, params, **kw):
     from ray_tpu.serve.llm_engine import LLMEngine
 
-    cfg, params = tiny_parts_either
     prompts = [[1, 2, 3], [7, 8, 9, 10, 11], [5] * 19]
     expect = _lone_expect(cfg, params, prompts)
-    kw = dict(num_slots=2, block_size=4, paged=True, page_size=16,
-              kv_pool_pages=1 + 8)
     pre, dec = LLMEngine(cfg, params, **kw), LLMEngine(cfg, params, **kw)
+    ps = pre.page_size
     try:
         for prompt, want in zip(prompts, expect):
             h = pre.export_prefill(prompt, max_new_tokens=8,
                                    temperature=0.0)
-            assert h.kv.shape == (cfg.n_layers, -(-len(prompt) // 16),
-                                  cfg.n_kv_heads, 16, 2 * cfg.head_dim)
+            assert h.kv.shape == (cfg.n_layers, -(-len(prompt) // ps),
+                                  cfg.n_kv_heads, ps, 2 * cfg.head_dim)
             assert dec.import_prefill(h).tokens == want
     finally:
         pre.close()
         dec.close()
+
+
+def test_handoff_round_trip_equals_lone_generation(tiny_parts_either):
+    """export_prefill on one engine, import_prefill on another: the
+    pages ship as [layers, npages, ...] out of one stacked pool into
+    another, and decode continues exactly as lone generation."""
+    _handoff_round_trip(*tiny_parts_either, num_slots=2, block_size=4,
+                        page_size=16, kv_pool_pages=1 + 8)
+
+
+def test_handoff_between_default_engines(tiny_parts):
+    """Any two engines of one model can hand off: nothing has to be
+    asked for."""
+    _handoff_round_trip(*tiny_parts)
 
 
 def test_prefix_suffix_prefill_equals_full_prefill(tiny_parts_either):
@@ -400,7 +522,7 @@ def test_prefix_suffix_prefill_equals_full_prefill(tiny_parts_either):
     shared = list(range(3, 3 + 32))               # two full pages
     prompts = [shared + [40, 41], shared + [50, 51, 52, 53, 54]]
     expect = _lone_expect(cfg, params, prompts)
-    eng = LLMEngine(cfg, params, num_slots=2, block_size=4, paged=True,
+    eng = LLMEngine(cfg, params, num_slots=2, block_size=4,
                     page_size=16, kv_pool_pages=1 + 16,
                     prefix_cache_pages=8)
     try:
@@ -422,7 +544,7 @@ def test_idle_rows_stay_at_position_zero(tiny_parts):
     from ray_tpu.serve.llm_engine import LLMEngine
 
     cfg, params = tiny_parts
-    eng = LLMEngine(cfg, params, num_slots=3, block_size=4, paged=True,
+    eng = LLMEngine(cfg, params, num_slots=3, block_size=4,
                     page_size=16, kv_pool_pages=1 + 8)
     try:
         meta = np.asarray(eng._no_admit[0]).copy()

@@ -33,46 +33,6 @@ def tiny_engine_parts():
     return _tiny()
 
 
-def test_slot_decode_matches_lone_generate(tiny_engine_parts):
-    """Greedy decode through the slot engine == Generator.generate of the
-    same prompt alone: the per-row position mask must make batch
-    neighbors invisible."""
-    import jax.numpy as jnp
-    from ray_tpu.models.generate import Generator
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    cfg, params = tiny_engine_parts
-    prompts = [[1, 2, 3], [7, 8, 9, 10, 11], [50, 60]]
-    lone = Generator(cfg, params)
-    expect = [
-        [int(t) for t in lone.generate(jnp.asarray([p], jnp.int32),
-                                       max_new_tokens=8,
-                                       temperature=0.0)[0]]
-        for p in prompts
-    ]
-
-    eng = LLMEngine(cfg, params, num_slots=4)
-    try:
-        results = [None] * len(prompts)
-        threads = []
-        for i, p in enumerate(prompts):
-            def go(i=i, p=p):
-                results[i] = eng.submit(p, max_new_tokens=8,
-                                        temperature=0.0)
-            t = threading.Thread(target=go)
-            t.start()
-            threads.append(t)
-        for t in threads:
-            t.join(timeout=120)
-        for i in range(len(prompts)):
-            assert results[i] is not None
-            assert results[i].tokens == expect[i], (
-                f"slot decode diverged for prompt {i}")
-            assert results[i].prompt_len == len(prompts[i])
-    finally:
-        eng.close()
-
-
 def test_interleaved_admission_and_slot_reuse(tiny_engine_parts):
     """More requests than slots, submitted in two waves mid-decode: all
     complete, slots are reused, and occupancy shows real batching."""
@@ -120,84 +80,6 @@ def test_interleaved_admission_and_slot_reuse(tiny_engine_parts):
         # (Junk steps past eos / block tails count against occupancy, and
         # these generations are shorter than one block.)
         assert st["batch_occupancy"] > 0.25
-    finally:
-        eng.close()
-
-
-def test_admission_wave_equals_cache_rows(tiny_engine_parts):
-    """Regression: with num_slots=3 a 4-wide admission wave has the same
-    leading shape as the 4-row global cache (3 slots + scratch) — the
-    insert must still write the prompt K/V (axis by layout, not by shape
-    mismatch), or every request decodes against a zeroed prompt."""
-    import jax.numpy as jnp
-    from ray_tpu.models.generate import Generator
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    cfg, params = tiny_engine_parts
-    prompts = [[11, 12, 13], [21, 22], [31, 32, 33, 34]]
-    lone = Generator(cfg, params)
-    expect = [
-        [int(t) for t in lone.generate(jnp.asarray([p], jnp.int32),
-                                       max_new_tokens=6,
-                                       temperature=0.0)[0]]
-        for p in prompts
-    ]
-    eng = LLMEngine(cfg, params, num_slots=3, block_size=4)
-    try:
-        results = [None] * 3
-        threads = []
-        for i, p in enumerate(prompts):
-            def go(i=i, p=p):
-                results[i] = eng.submit(p, max_new_tokens=6,
-                                        temperature=0.0)
-            t = threading.Thread(target=go)
-            t.start()
-            threads.append(t)
-        for t in threads:
-            t.join(timeout=120)
-        for i in range(3):
-            assert results[i] is not None
-            assert results[i].tokens == expect[i]
-    finally:
-        eng.close()
-
-
-def test_engine_eos_and_errors(tiny_engine_parts):
-    """eos stops a row without touching its neighbors; an over-long
-    prompt fails just that request."""
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    cfg, params = tiny_engine_parts
-    eng = LLMEngine(cfg, params, num_slots=2, max_prompt_len=16)
-    try:
-        with pytest.raises(ValueError):
-            eng.submit(list(range(17)), max_new_tokens=4)
-        r = eng.submit([3, 4, 5], max_new_tokens=200)  # > max_seq_len cap
-        assert r.finish_reason == "length"
-        assert len(r.tokens) <= cfg.max_seq_len
-        # pick the first greedily generated token as a fake eos: the
-        # request must stop right there
-        probe = eng.submit([3, 4, 5], max_new_tokens=4, temperature=0.0)
-        eos = probe.tokens[0]
-        r2 = eng.submit([3, 4, 5], max_new_tokens=64, temperature=0.0,
-                        eos_id=eos)
-        assert r2.finish_reason == "eos"
-        assert r2.tokens == [eos]
-    finally:
-        eng.close()
-
-
-def test_streaming_on_token(tiny_engine_parts):
-    """on_token fires once per generated token, in order."""
-    from ray_tpu.serve.llm_engine import LLMEngine
-
-    cfg, params = tiny_engine_parts
-    eng = LLMEngine(cfg, params, num_slots=2)
-    try:
-        seen = []
-        r = eng.submit([9, 9, 9], max_new_tokens=5, temperature=0.0,
-                       on_token=seen.append)
-        assert seen == r.tokens
     finally:
         eng.close()
 

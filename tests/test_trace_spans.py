@@ -39,10 +39,10 @@ def _host_spans(trace_dir, prefixes=("engine.", "train")):
     return out
 
 
-@pytest.fixture(scope="module", params=["paged", "dense"])
-def server(request):
+@pytest.fixture(scope="module")
+def server():
     from ray_tpu.serve.llm import LLMServer
-    srv = LLMServer("tiny", paged=request.param == "paged", **ENGINE_KW)
+    srv = LLMServer("tiny", **ENGINE_KW)
     yield srv
     srv.engine.close()
 
@@ -82,7 +82,7 @@ def test_reply_splits_time_to_first_token(server, entry, n_tokens):
     assert q + p == reply["time_to_first_token_s"]
     assert q >= 0 and p > 0 and w >= 0
     assert reply["time_to_first_token_s"] <= reply["latency_s"]
-    if n_tokens == 1 or not server.engine.paged:
+    if n_tokens == 1:
         assert w == 0.0
     else:
         # installed after its first token was known, before it finished
@@ -147,8 +147,7 @@ def test_engine_spans_on_the_trace_clock(server, tmp_path):
     assert count["engine.dispatch_block"] >= quanta
     assert count["engine.admit"] >= count["engine.dispatch_block"]
     assert count["engine.wait_work"] >= 1       # between the two requests
-    if eng.paged:
-        assert count["engine.fetch_prefill"] == 2
+    assert count["engine.fetch_prefill"] == 2
     assert set(count) <= {"engine." + n for n in (
         "wait_work", "admit", "dispatch_import", "dispatch_prefill",
         "dispatch_block", "fetch_block", "deliver_block", "fetch_prefill",
