@@ -206,13 +206,15 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
-                 pool=None, layer=None):
+                 pool=None, layer=None, live=None):
         """``pool`` (paged decode, serve/llm_engine.py paged mode): the
         model's ONE stacked KV page pool ``[layers, pages, kv_heads,
         page_size, 2*head_dim]`` (GPT declares it; see
         ops/paged_attention.py) and this block's ``layer`` index into
         it.  Returns ``(out, pool)`` then: the pool is passed through,
-        updated in place, never sliced."""
+        updated in place, never sliced.  ``live`` [rows] bool (``Block``
+        makes it): the rows that hold a request, the only ones a decode
+        step reads pages for."""
         cfg = self.cfg
         h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         q = _dense((h, hd), ("embed", "heads", "head_dim"), "wq",
@@ -231,7 +233,8 @@ class Attention(nn.Module):
 
         if pool is not None:
             out, pool = self._decode_attend_paged(
-                q, k, v, positions, block_tables, pool, layer, window)
+                q, k, v, positions, block_tables, pool, layer, window,
+                live)
         elif self.decode:
             out = self._decode_attend(q, k, v, positions, window)
         else:
@@ -385,7 +388,7 @@ class Attention(nn.Module):
         return xla_attention(q, ck.value, cv.value, causal=False, mask=mask)
 
     def _decode_attend_paged(self, q, k, v, positions, block_tables,
-                             pool, layer, window=None):
+                             pool, layer, window=None, live=None):
         """Paged-pool decode: write this call's K/V into the rows' pages
         of layer ``layer``, then attend over only the occupied pages
         (ops/paged_attention.py).  Returns ``(out, pool)``.
@@ -409,6 +412,9 @@ class Attention(nn.Module):
         ``window`` (this layer's, traced; None without one): decode
         reads only the pages that hold the last ``window`` positions,
         and a T > 1 span longer than the window is masked by it.
+        ``live`` [rows] bool (None: every row): decode reads no page of
+        a row it leaves out, whose output is zeros; its K/V is still
+        written (to the scratch page its table names).
         """
         cfg = self.cfg
         if self.is_initializing():
@@ -424,7 +430,7 @@ class Attention(nn.Module):
         if q.shape[1] == 1:
             out = paged_attention(
                 q[:, 0], pool, block_tables, positions[:, 0] + 1,
-                layer=layer, window=self._window_over(
+                layer=layer, live=live, window=self._window_over(
                     window, block_tables.shape[1] * pool.shape[3]))
             return out[:, None], pool
         if not self.prefix_attend:
@@ -463,6 +469,10 @@ class Block(nn.Module):
         ``moe_stacked``: the layer stack's whole dropless expert leaves
         (GPT hands them down in decode; see ``DroplessMoE.__call__``)."""
         cfg = self.cfg
+        # a row whose table starts at the scratch page holds no request
+        # (serve/llm_engine.py): neither the decode attention kernel nor
+        # the expert kernel reads anything for it
+        live = None if block_tables is None else block_tables[:, 0] != 0
         y = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
         moe = router_logits = None
         if cfg.moe_experts > 0 and cfg.moe_dropless:
@@ -475,16 +485,13 @@ class Block(nn.Module):
                 router_logits = moe.router_logits(y)
         y = Attention(cfg, self.mesh, self.rules, self.decode,
                       self.prefix_attend, name="attn")(
-            y, cos, sin, positions, block_tables, pool, layer)
+            y, cos, sin, positions, block_tables, pool, layer, live)
         if pool is not None:
             y, pool = y
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = x + y
         y = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
         if moe is not None:
-            # a row whose table starts at the scratch page holds no
-            # request (serve/llm_engine.py): its experts are not read
-            live = None if block_tables is None else block_tables[:, 0] != 0
             y = moe(y, router_logits, live, moe_stacked, layer)
         elif cfg.moe_experts > 0:
             from ray_tpu.ops.moe import MoEMLP

@@ -42,13 +42,16 @@ Two implementations:
     dense attention.  Runs on every backend (the CPU test oracle and
     fallback).  It reads the whole (static) table span, so its HBM win
     comes from sizing ``max_pages_per_seq`` to the workload.
-  - ``paged_attention_tpu`` — Pallas kernel: grid over rows, per-row
-    ``fori_loop`` DMAs ONLY the row's occupied pages HBM->VMEM
-    (double-buffered) with flash-style online softmax.  HBM traffic per
-    decode step scales with actual context length — the property the
-    dense row layout can't have.
+  - ``paged_attention_tpu`` — Pallas kernel: one invocation walks the
+    flat list of (live row, occupied page) work, DMAing ONLY those
+    pages HBM->VMEM through a pipeline that stays full across rows,
+    bf16 pages straight to the MXU, flash-style online softmax in
+    float32.  A row that holds no request issues nothing.  HBM traffic
+    and time per decode step scale with the context the live rows
+    actually hold — the property the dense row layout can't have.
 
-``paged_attention`` dispatches by backend.
+``paged_attention`` dispatches by backend.  Both take ``live`` [rows]
+(optional): rows it leaves out are not read and return zeros.
 """
 
 from __future__ import annotations
@@ -131,7 +134,7 @@ def gather_kv_pages(kv_pages: jax.Array, block_tables: jax.Array, *,
 
 def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
                         block_tables: jax.Array, lengths: jax.Array, *,
-                        layer=0, window=None,
+                        layer=0, window=None, live=None,
                         sm_scale: Optional[float] = None) -> jax.Array:
     """Gather-based paged decode attention (one query token per row).
 
@@ -142,6 +145,8 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
     layer:        which layer's pages to read (int or traced scalar)
     window:       None, or a scalar (int or traced): only the last
                   ``window`` of the ``lengths`` positions are visible
+    live:         None (every row is read), or [rows] bool: a row it
+                  leaves out comes back as zeros
     returns       [rows, heads, head_dim]
     """
     hd = q.shape[-1]
@@ -151,151 +156,240 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
     if window is not None:
         mask = mask & (pos >= lengths[:, None] - window)
     out = xla_attention(q[:, None], kv[..., :hd], kv[..., hd:],
-                        causal=False, mask=mask, sm_scale=sm_scale)
-    return out[:, 0]
+                        causal=False, mask=mask, sm_scale=sm_scale)[:, 0]
+    if live is not None:
+        out = jnp.where(live[:, None, None], out, jnp.zeros_like(out))
+    return out
 
 
-def _tpu_kernel(q2: jax.Array, kv_pages: jax.Array,
+# a work item of the kernel is a chunk of one row's pages: this many
+# positions (on a v5e 64 a chunk ran at 250 GB/s, 256 at 500, 512 at
+# 720, 1024 at 740: PERF.md, PR 32) unless that is more than
+# _CHUNK_BYTES of pages (gptj-6b's page is 1 MiB: 8 of them twice over
+# are all of VMEM); and the buffers of that size, the one computed on
+# and the ones in flight (a third buffer bought 4% at most)
+_CHUNK_TOKENS = 512
+_CHUNK_BYTES = 2 << 20
+_PIPELINE_DEPTH = 2
+
+
+def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                 block_tables: jax.Array, lengths: jax.Array,
                 layer: jax.Array, sm_scale: float,
-                window: Optional[jax.Array] = None) -> jax.Array:
-    """Pallas TPU decode kernel: per-row loop over occupied pages only.
+                window: Optional[jax.Array] = None,
+                live: Optional[jax.Array] = None) -> jax.Array:
+    """Pallas TPU decode kernel: ONE invocation walks the flat list of
+    (live row, chunk of occupied pages) work with a page pipeline that
+    never drains between rows.
 
-    ``kv_pages`` is the whole stacked pool, left in HBM; ``layer`` [1]
-    rides as a scalar-prefetch operand beside the tables, and a page's
-    DMA source is ``kv_pages[layer, page]``.
+    ``kv_pages`` is the whole stacked pool, left in HBM; a page's DMA
+    source is ``kv_pages[layer, page]``.  ``q`` [rows, heads, qw] and
+    the output [rows, heads, qw] sit whole in VMEM (33 x 28 x 128 bf16
+    is 236 KB).  Tables, lengths, ``layer`` [1] and, where given,
+    ``window`` [1] and ``live`` [rows] ride as scalar-prefetch operands.
 
-    ``q2`` is the query padded to [rows, heads, 2*head_dim] (zeros in
-    the V half) so every buffer's minor dim is lane-aligned; the zero
-    half makes q2 . kv_page contract to K-only scores, and p . kv_page
-    leaves the real output in the V half of the accumulator — no
-    sub-tile slicing anywhere in the kernel.  The row's page count
-    (ceil(length/page_size)) is a traced ``fori_loop`` bound, so pages
-    past the row's context are never DMA'd.  In-kernel math stays 2-D
-    per kv head (Mosaic rejects batched dot_generals).
+    The kernel first compacts, in SMEM, the rows it has to read: those
+    ``live`` names (all of them without the operand) whose page range
+    ``max(0, length - window) // page_size .. ceil(length / page_size)``
+    is not empty.  Every other row issues no DMA and no vector work;
+    its output is zeros.  A row's range is cut into chunks of ``cp``
+    pages (``_CHUNK_TOKENS`` positions; one online-softmax step each:
+    a step's latency is a chain of two MXU round trips and two lane
+    reductions whatever it covers, so a page a step is bound by that
+    chain, not by bytes).  A fetch cursor runs ``_PIPELINE_DEPTH - 1``
+    chunks ahead of the compute cursor over the flat list, so while a
+    row's last chunk is in the MXU the next row's first pages are
+    already in flight.  Pages past a row's context and pages wholly
+    behind the window are never named; a chunk's buffer past the row's
+    last page keeps what an earlier chunk left there (zeros at first),
+    under the position mask.
 
-    ``window`` [1] (optional) is one more scalar-prefetch operand: the
-    page loop then starts at ``max(0, length - window) // page_size``
-    and positions before ``length - window`` are masked, so a window
-    layer at 6000 tokens reads 65 pages and not 94.  Without it the
-    kernel is built as it was (no operand, no extra mask).
+    A page goes to the MXU in the pool's dtype (float32 accumulation);
+    the scale, the softmax statistics and the accumulator are float32,
+    and ``p`` is cast to the pool's dtype for ``p @ v`` — what
+    ``ops/attention.py xla_attention`` computes.  In-kernel math stays
+    2-D per kv head (Mosaic rejects batched dot_generals); a head's
+    statistics are loop-carried values, not scratch.
+
+    ``qw`` decides how a page is split.  ``qw == head_dim`` (head_dim a
+    multiple of 128: the halves are whole lane tiles): scores contract
+    against the page's K half, ``p`` against its V half, as views.
+    ``qw == 2*head_dim`` (the caller zero-padded the query): scores
+    contract against the whole page (the zero half makes them K-only),
+    ``p @ page`` leaves the output in the V half, and the caller slices
+    it out — no sub-tile slicing in the kernel.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rows, heads, hd2 = q2.shape
-    _, _, kvh, ps, _ = kv_pages.shape
+    rows, heads, qw = q.shape
+    _, _, kvh, ps, hd2 = kv_pages.shape
     g = heads // kvh
-
-    windowed = window is not None
+    depth = _PIPELINE_DEPTH
+    page_bytes = kvh * ps * hd2 * kv_pages.dtype.itemsize
+    cp = max(1, min(_CHUNK_TOKENS // ps, _CHUNK_BYTES // page_bytes))
+    windowed, masked = window is not None, live is not None
+    n_prefetch = 3 + windowed + masked
+    # the page's K and V parts as the two products see them
+    k_cols, v_cols = ((slice(0, qw), slice(qw, hd2)) if qw != hd2
+                      else (slice(None), slice(None)))
 
     def kernel(*refs):
         tables_ref, len_ref, layer_ref = refs[:3]
-        (q_ref, kv_ref, out_ref, kvbuf, acc_ref, m_ref, l_ref,
-         sems) = refs[3 + windowed:]
-        r = pl.program_id(0)
-        length = len_ref[r]
-        n_pg = pl.cdiv(length, ps)
-        if windowed:
-            first_pos = jnp.maximum(length - refs[3][0], 0)
-            pg0 = first_pos // ps
-        else:
-            first_pos, pg0 = None, 0
+        window_ref = refs[3] if windowed else None
+        live_ref = refs[3 + windowed] if masked else None
+        (q_ref, kv_ref, out_ref, kvbuf, row_out, ids, firsts, ends,
+         sems) = refs[n_prefetch:]
 
-        def get_dma(slot, i):
-            return pltpu.make_async_copy(
-                kv_ref.at[layer_ref[0], tables_ref[r, i]], kvbuf.at[slot],
-                sems.at[slot])
+        # branch-free and unrolled (0.3-0.7 us a call less than a loop
+        # of conditional stores): every row is written at the running
+        # count, and only a kept row moves the count past its entry
+        n = jnp.int32(0)
+        for r in range(rows):
+            length = len_ref[r]
+            end = pl.cdiv(length, ps)
+            first = (jnp.maximum(length - window_ref[0], 0) // ps
+                     if windowed else 0)
+            keep = end > first
+            if masked:
+                keep = keep & (live_ref[r] != 0)
+            ids[n], firsts[n], ends[n] = r, first, end
+            n = n + keep.astype(jnp.int32)
+        out_ref[...] = jnp.zeros_like(out_ref)
+        kvbuf[...] = jnp.zeros_like(kvbuf)
 
-        @pl.when(n_pg > pg0)
-        def _():
-            get_dma(pg0 % 2 if windowed else 0, pg0).start()
+        def chunk_dmas(slot, at, end, row=None):
+            """Start the DMA of every page of the chunk that begins at
+            page ``at`` of table row ``row`` into ``slot``; without
+            ``row``, wait for them (a wait names no source)."""
+            for k in range(cp):
+                @pl.when(at + k < end)
+                def _():
+                    dma = pltpu.make_async_copy(
+                        kv_ref.at[layer_ref[0], 0 if row is None
+                                  else tables_ref[row, at + k]],
+                        kvbuf.at[slot, k], sems.at[slot])
+                    dma.wait() if row is None else dma.start()
 
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        qv = q_ref[0].astype(jnp.float32) * sm_scale      # [heads, 2hd]
+        def fetch(cursor, slot):
+            """Start the DMAs of the chunk at ``cursor`` = (index into
+            the compacted rows, first page of the chunk) into ``slot``,
+            if there is one; returns the next chunk's cursor."""
+            j, at = cursor
 
-        def body(i, _):
-            slot = i % 2
-
-            @pl.when(i + 1 < n_pg)
+            @pl.when(j < n)
             def _():
-                get_dma((i + 1) % 2, i + 1).start()
+                chunk_dmas(slot, at, ends[j], ids[j])
 
-            get_dma(slot, i).wait()
-            pos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
-            valid = pos < length
-            if windowed:
-                valid = valid & (pos >= first_pos)
-            for h in range(kvh):                 # static per-head 2-D ops
-                lo, hi = h * g, (h + 1) * g
-                kv_h = kvbuf[slot, h].astype(jnp.float32)   # [ps, 2hd]
-                # zero V-half of q2 -> K-only scores
-                s = jax.lax.dot_general(
-                    qv[lo:hi], kv_h, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)     # [g, ps]
-                s = jnp.where(valid, s, -1e30)
-                m_prev = m_ref[lo:hi]                       # [g, 1]
-                m_new = jnp.maximum(
-                    m_prev, jnp.max(s, axis=1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m_prev - m_new)
-                l_ref[lo:hi] = (l_ref[lo:hi] * alpha
-                                + jnp.sum(p, axis=1, keepdims=True))
-                # [g, 2hd]: K-half is junk, V-half is the real p @ V
-                pv = jax.lax.dot_general(
-                    p, kv_h, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                acc_ref[lo:hi] = acc_ref[lo:hi] * alpha + pv
-                m_ref[lo:hi] = m_new
-            return 0
+            more = at + cp < ends[jnp.minimum(j, rows - 1)]
+            return (jnp.where(more, j, j + 1),
+                    jnp.where(more, at + cp,
+                              firsts[jnp.minimum(j + 1, rows - 1)]))
 
-        jax.lax.fori_loop(pg0, n_pg, body, 0)
-        norm = jnp.maximum(l_ref[:], 1e-30)               # [heads, 1]
-        out_ref[0] = (acc_ref[:] / norm).astype(out_ref.dtype)
+        cursor = (jnp.int32(0), firsts[0])
+        for slot in range(depth - 1):
+            cursor = fetch(cursor, slot)
+
+        def row(j, carry):
+            step, cursor = carry
+            r, first, end = ids[j], firsts[j], ends[j]
+            length = len_ref[r]
+            first_pos = (jnp.maximum(length - window_ref[0], 0)
+                         if windowed else None)
+            qf = q_ref[r].astype(jnp.float32)              # [heads, qw]
+            qs = [qf[h * g:(h + 1) * g].astype(kvbuf.dtype)
+                  for h in range(kvh)]
+            stats = tuple((jnp.full((g, 1), -1e30, jnp.float32),
+                           jnp.zeros((g, 1), jnp.float32),
+                           jnp.zeros((g, qw), jnp.float32))
+                          for _ in range(kvh))
+
+            def chunk(t, carry):
+                step, cursor, stats = carry
+                cursor = fetch(cursor, (step + depth - 1) % depth)
+                slot, at = step % depth, first + t * cp
+                chunk_dmas(slot, at, end)
+                pos = at * ps + jax.lax.broadcasted_iota(
+                    jnp.int32, (g, cp * ps), 1)
+                valid = pos < length
+                if windowed:
+                    valid = valid & (pos >= first_pos)
+                new = []
+                for h in range(kvh):             # static per-head 2-D ops
+                    m_prev, l_prev, acc = stats[h]
+                    kv = kvbuf[slot, :, h].reshape(cp * ps, hd2)
+                    s = jax.lax.dot_general(
+                        qs[h], kv[:, k_cols], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+                    s = jnp.where(valid, s, -1e30)        # [g, cp*ps]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    alpha = jnp.exp(m_prev - m_new)
+                    pv = jax.lax.dot_general(
+                        p.astype(kvbuf.dtype), kv[:, v_cols],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # [g, qw]
+                    new.append((m_new,
+                                l_prev * alpha
+                                + jnp.sum(p, axis=1, keepdims=True),
+                                acc * alpha + pv))
+                return step + 1, cursor, tuple(new)
+
+            step, cursor, stats = jax.lax.fori_loop(
+                0, pl.cdiv(end - first, cp), chunk, (step, cursor, stats))
+            for h, (_, l, acc) in enumerate(stats):
+                row_out[h * g:(h + 1) * g] = acc / jnp.maximum(l, 1e-30)
+            out_ref[r] = row_out[...].astype(out_ref.dtype)
+            return step, cursor
+
+        jax.lax.fori_loop(0, n, row, (jnp.int32(0), cursor))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # block_tables, lengths, layer (, window)
-        num_scalar_prefetch=3 + windowed,
-        grid=(rows,),
+        # block_tables, lengths, layer (, window) (, live)
+        num_scalar_prefetch=n_prefetch,
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((1, heads, hd2), lambda r, *_: (r, 0, 0),
-                         memory_space=pltpu.VMEM),         # q2
+            pl.BlockSpec(memory_space=pltpu.VMEM),        # q, whole
             pl.BlockSpec(memory_space=pl.ANY),   # stacked kv_pages (HBM)
         ],
-        out_specs=pl.BlockSpec((1, heads, hd2), lambda r, *_: (r, 0, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, kvh, ps, hd2), kv_pages.dtype),  # double-buffer
-            pltpu.VMEM((heads, hd2), jnp.float32),          # acc
-            pltpu.VMEM((heads, 1), jnp.float32),            # running max
-            pltpu.VMEM((heads, 1), jnp.float32),            # running sum
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((depth, cp, kvh, ps, hd2), kv_pages.dtype),
+            pltpu.VMEM((heads, qw), jnp.float32),   # one row's output
+            pltpu.SMEM((rows,), jnp.int32),         # rows to read
+            pltpu.SMEM((rows,), jnp.int32),         # their first page
+            pltpu.SMEM((rows,), jnp.int32),         # the page they end at
+            pltpu.SemaphoreType.DMA((depth,)),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, heads, hd2), q2.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, heads, qw), q.dtype),
         name="paged_attention_decode",
     )(block_tables, lengths, layer, *([window] if windowed else []),
-      q2, kv_pages)
+      *([live] if masked else []), q, kv_pages)
 
 
 def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
-                        window=None,
+                        window=None, live=None,
                         sm_scale: Optional[float] = None) -> jax.Array:
     hd = q.shape[-1]
     scale = sm_scale if sm_scale is not None else hd ** -0.5
-    q2 = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+    # a half of the page is whole lane tiles, or the query is padded
+    # with zeros to the page's width (see _tpu_kernel)
+    split = hd % 128 == 0
+    if not split:
+        q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
     if window is not None:
         window = jnp.asarray(window, jnp.int32).reshape(1)
-    out2 = _tpu_kernel(q2, kv_pages, block_tables,
-                       lengths.astype(jnp.int32),
-                       jnp.asarray(layer, jnp.int32).reshape(1), scale,
-                       window)
-    return out2[..., hd:]       # V half holds the attention output
+    if live is not None:
+        live = live.astype(jnp.int32)
+    out = _tpu_kernel(q, kv_pages, block_tables, lengths.astype(jnp.int32),
+                      jnp.asarray(layer, jnp.int32).reshape(1), scale,
+                      window, live)
+    return out if split else out[..., hd:]
 
 
 def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
@@ -328,13 +422,21 @@ def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
 
 
 def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
-                    window=None, sm_scale: Optional[float] = None,
+                    window=None, live=None,
+                    sm_scale: Optional[float] = None,
                     impl: str = "auto") -> jax.Array:
     """Backend-dispatched paged decode attention over layer ``layer`` of
     the stacked pool (see module docstring and
     :func:`resolve_paged_impl`); under a ``window`` (scalar, traced or
-    not) only the last ``window`` positions of each row."""
+    not) only the last ``window`` positions of each row.
+
+    ``live`` [rows] bool says which rows hold a request: the others are
+    not read and come back as zeros, from either implementation.  The
+    serving engine's rows are live where ``block_tables[:, 0] != 0``
+    (page 0 is scratch; models/gpt.py ``Block`` derives the mask once
+    for this kernel and the expert kernel).  ``None``: every row is
+    read."""
     impl = resolve_paged_impl(kv_pages.shape[-1], impl)
     fn = paged_attention_tpu if impl == "tpu" else paged_attention_xla
     return fn(q, kv_pages, block_tables, lengths, layer=layer,
-              window=window, sm_scale=sm_scale)
+              window=window, live=live, sm_scale=sm_scale)

@@ -259,10 +259,13 @@ class EngineStats:
         # tokens: a layer step is one expert layer in one decode step
         self.moe_layer_steps = 0
         self.moe_experts_touched = 0     # experts with >= 1 pair, summed
-        # pages the window layers' decode reads took (window_pages_read)
-        # and left out because they lie wholly behind the window
-        # (window_pages_skipped), over delivered tokens; read + skipped
-        # is what the lengths alone would have read
+        # pages the decode kernel read over delivered tokens, every
+        # layer (decode_pages_read: times a page's bytes in one layer it
+        # is the kernel's HBM traffic); of them the window layers' part
+        # (window_pages_read), and what those layers left out because it
+        # lies wholly behind the window (window_pages_skipped): read +
+        # skipped is what the lengths alone would have read there
+        self.decode_pages_read = 0
         self.window_pages_read = 0
         self.window_pages_skipped = 0
         # seconds of the loop thread, advanced at each phase's end
@@ -301,6 +304,7 @@ class EngineStats:
             "prefill_padded_tokens": self.prefill_padded_tokens,
             "moe_layer_steps": self.moe_layer_steps,
             "moe_experts_touched": self.moe_experts_touched,
+            "decode_pages_read": self.decode_pages_read,
             "window_pages_read": self.window_pages_read,
             "window_pages_skipped": self.window_pages_skipped,
             "loop_s": self.loop_s,
@@ -669,12 +673,14 @@ class LLMEngine:
         rng, sub = jax.random.split(rng)
         keys = jax.random.split(sub, self.block_size)
         # a row whose table starts at scratch page 0 holds no request
-        # (never installed, or redirected after eviction).  It keeps
-        # stepping junk, but AT POSITION 0: the kernel reads a row's
-        # ceil((position+1)/page_size) pages every layer, so an idle row
-        # left to walk to max_seq_len reads that many scratch pages a
-        # step — 28 idle rows did twice the work of the whole model
-        # (PERF.md, PR 25)
+        # (never installed, or redirected after eviction).  The model's
+        # Block derives the same mask from the tables and its decode
+        # kernels read nothing for such a row (PERF.md, PR 27, PR 32).
+        # It keeps stepping junk, but AT POSITION 0, so its K/V write
+        # stays on the scratch page's first row and a reader without
+        # the mask reads one page of it, not ceil((position+1) /
+        # page_size): 28 idle rows left to walk to max_seq_len did twice
+        # the work of the whole model (PERF.md, PR 25)
         live = tables[:, 0] != 0
         load = self._counts_expert_load
 
@@ -1188,20 +1194,21 @@ class LLMEngine:
                         self._safe_on_token(sl.request, tok)
                     if self._maybe_finish(i):
                         break     # rest of the row is junk past eos
-                if self._window_layers:
-                    self._count_window_pages(pos0 + 1, sl.pos)
+                self._count_decode_pages(pos0 + 1, sl.pos)
             sp.set_metadata(tokens=st.step_tokens - tokens0,
                             finished=st.requests_completed - done0)
 
-    def _count_window_pages(self, first: int, last: int) -> None:
-        """``EngineStats.window_pages_*`` for one row's delivered steps,
-        which read ``first .. last`` positions: the page arithmetic of
-        ``ops/paged_attention.py _tpu_kernel`` (a step over ``n``
-        positions loops pages ``max(0, n - window) // page_size`` to
-        ``ceil(n / page_size)``) summed in closed form, once a row a
-        block.  Host arithmetic on positions the loop already holds: it
-        says what the window leaves unread, not that the kernel did so
-        (the benchmark's on-chip kernel check and timing do)."""
+    def _count_decode_pages(self, first: int, last: int) -> None:
+        """``EngineStats.decode_pages_read`` and ``window_pages_*`` for
+        one row's delivered steps, which read ``first .. last``
+        positions: the page arithmetic of ``ops/paged_attention.py
+        _tpu_kernel`` (a step over ``n`` positions reads pages ``max(0,
+        n - window) // page_size`` to ``ceil(n / page_size)``, from page
+        0 in a layer without a window) summed in closed form, once a row
+        a block.  Host arithmetic on positions the loop already holds:
+        it says what the kernel's loop bounds name, not that the kernel
+        kept to them (the benchmark's on-chip kernel check and timing,
+        and the poisoned-page test, do)."""
         ps, w = self.page_size, self.cfg.sliding_window
 
         def floors(n):            # sum of k // ps for k = 0 .. n
@@ -1209,10 +1216,14 @@ class LLMEngine:
             return ps * q * (q - 1) // 2 + q * (r + 1) if n > 0 else 0
 
         by_length = floors(last + ps - 1) - floors(first + ps - 2)
-        skipped = floors(last - w) - floors(max(first, w) - w - 1)
+        windowed = self._window_layers
+        skipped = (floors(last - w) - floors(max(first, w) - w - 1)
+                   if windowed else 0)
         st = self.stats
-        st.window_pages_skipped += skipped * self._window_layers
-        st.window_pages_read += (by_length - skipped) * self._window_layers
+        st.window_pages_skipped += skipped * windowed
+        st.window_pages_read += (by_length - skipped) * windowed
+        st.decode_pages_read += (by_length * self.cfg.n_layers
+                                 - skipped * windowed)
 
     # ------------------------------------------------- prompt-prefix cache
     #

@@ -85,25 +85,46 @@ def test_flash_fwd_bwd_gpt_small_widths(topo, one_chip):
     lowered.compile()
 
 
-def test_paged_decode_gpt_small_widths(topo, one_chip):
+# (rows, heads, kv heads, head_dim, table width, window operand, live
+# operand): gpt-small as before, then the two serving cells' decode
+# shapes (SmolLM2-360M: padded query, no window; SmallThinker: the page
+# split into its halves, window and live operands), then the preset
+# with the largest page (1 MiB: a chunk of 512 positions twice over
+# would not fit VMEM, so the kernel cuts its chunk by bytes)
+_PAGED_SHAPES = {
+    "gpt-small": (32, 12, 12, 64, 4, False, False),
+    "smollm2-360m": (33, 15, 5, 64, 40, False, True),
+    "smallthinker-21b-a3b": (33, 28, 4, 128, 96, True, True),
+    "gptj-6b": (33, 16, 16, 256, 32, False, True),
+}
+
+
+@pytest.mark.parametrize("shape", list(_PAGED_SHAPES))
+def test_paged_decode_gpt_small_widths(topo, one_chip, shape):
     """The kernel takes the whole stacked pool and a layer index (a
-    traced scalar, as under the model's layer scan)."""
+    traced scalar, as under the model's layer scan); one call is one
+    custom call ``paged_attention_decode`` whatever operands it has."""
     from ray_tpu.ops.paged_attention import paged_attention
 
-    rows, heads, hd, ps, pages, layers = 32, 12, 64, 64, 129, 3
+    rows, heads, kvh, hd, mp, windowed, masked = _PAGED_SHAPES[shape]
+    ps, pages, layers = 64, 129, 3
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     lowered = jax.jit(
-        lambda q, kv, bt, ln, layer: paged_attention(
-            q, kv, bt, ln, layer=layer, impl="tpu")
+        lambda q, kv, bt, ln, layer, window, live: paged_attention(
+            q, kv, bt, ln, layer=layer, impl="tpu",
+            window=window if windowed else None,
+            live=live if masked else None)
     ).lower(sds((rows, heads, hd), jnp.bfloat16),
-            sds((layers, pages, heads, ps, 2 * hd), jnp.bfloat16),
-            sds((rows, 4), jnp.int32), sds((rows,), jnp.int32),
-            sds((), jnp.int32))
-    assert "tpu_custom_call" in lowered.as_text()
-    assert 'kernel_name = "paged_attention_decode"' in lowered.as_text()
+            sds((layers, pages, kvh, ps, 2 * hd), jnp.bfloat16),
+            sds((rows, mp), jnp.int32), sds((rows,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32),
+            sds((rows,), jnp.bool_))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
     lowered.compile()
 
 
@@ -294,7 +315,9 @@ def _compiled_engine_programs(pages, one_chip, monkeypatch):
     block = eng._block_jit.lower(
         *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
                  one_chip))
-    assert 'kernel_name = "paged_attention_decode"' in block.as_text()
+    # once a layer: the scanned layer's body holds the one call
+    assert block.as_text().count(
+        'kernel_name = "paged_attention_decode"') == 1
     prefill = eng._get_prefill_paged(bucket, wave).lower(
         *_shapes((eng.params, eng._cache,
                   jnp.zeros((wave, bucket + 2), jnp.int32),
@@ -369,7 +392,8 @@ def test_decode_block_reads_the_stacked_experts_in_place(topo, one_chip,
         *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
                  one_chip))
     assert 'kernel_name = "moe_experts_decode"' in block.as_text()
-    assert 'kernel_name = "paged_attention_decode"' in block.as_text()
+    assert block.as_text().count(
+        'kernel_name = "paged_attention_decode"') == 1
     compiled = block.compile()
     hlo = compiled.as_text()
     (call,) = [line for line in hlo.splitlines()

@@ -412,25 +412,41 @@ def test_paged_attention_xla_reads_its_layer(head_dim, layer):
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
 
+_PS = 16          # page size of the kernel cases below
+
+
+@pytest.fixture
+def tpu_interpreter(monkeypatch):
+    """Run ``pallas_call`` in the TPU interpreter (DMAs, semaphores and
+    SMEM simulated on the CPU; memory nobody wrote reads as NaN), with
+    the kernel's chunk cut to two pages so that a row of a few pages
+    already spans several chunks."""
+    import functools
+    import importlib
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(pl, "pallas_call", functools.partial(
+        pl.pallas_call, interpret=pltpu.InterpretParams()))
+    monkeypatch.setattr(
+        importlib.import_module("ray_tpu.ops.paged_attention"),
+        "_CHUNK_TOKENS", 2 * _PS)
+
+
 @pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
 @pytest.mark.parametrize("head_dim", [64, 128])
 def test_pallas_decode_kernel_matches_oracle_in_tpu_interpreter(
-        monkeypatch, head_dim, layer):
+        tpu_interpreter, head_dim, layer):
     """The Pallas kernel itself, run on the CPU by the TPU interpreter
     (it simulates the DMAs and semaphores): it must fetch
     ``kv_pages[layer, page]`` — a wrong layer or page is a wrong answer
     — and agree with the gather oracle to bf16 precision."""
-    import functools
-
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
     from ray_tpu.ops.paged_attention import (paged_attention_tpu,
                                              paged_attention_xla)
 
-    monkeypatch.setattr(pl, "pallas_call", functools.partial(
-        pl.pallas_call, interpret=pltpu.InterpretParams()))
     rs = np.random.RandomState(7 * head_dim + layer)
     pool = _random_pool(rs, 3, 13, 2, 16, head_dim, "bfloat16")
     q = jnp.asarray(rs.randn(3, 4, head_dim), jnp.bfloat16)
@@ -445,6 +461,114 @@ def test_pallas_decode_kernel_matches_oracle_in_tpu_interpreter(
                                 layer=(layer + 1) % 3)
     assert np.abs(np.asarray(got, np.float32)
                   - np.asarray(other, np.float32)).max() > 0.1
+
+
+# ---- the kernel follows the bytes live rows read (ISSUE 32) ----
+
+
+def _kernel_case(rs, head_dim, lengths, max_pages, kvh=2, group=3):
+    """A bf16 pool whose page 0 is scratch, a query, and shuffled
+    disjoint tables for rows of ``lengths``."""
+    import jax.numpy as jnp
+
+    rows = len(lengths)
+    pool = _random_pool(rs, 2, 1 + rows * max_pages, kvh, _PS, head_dim,
+                        "bfloat16")
+    q = jnp.asarray(rs.randn(rows, kvh * group, head_dim), jnp.bfloat16)
+    tables = (rs.permutation(rows * max_pages).reshape(rows, max_pages)
+              + 1).astype("int32")
+    return q, pool, tables, jnp.asarray(lengths, jnp.int32)
+
+
+def _layer_1(fn, q, pool, tables, lengths, window, live):
+    import jax.numpy as jnp
+    import numpy as np
+
+    return np.asarray(fn(
+        q, pool, jnp.asarray(tables), lengths, layer=1, window=window,
+        live=None if live is None else jnp.asarray(live)), np.float32)
+
+
+def _assert_kernel_is_oracle(*case):
+    import numpy as np
+    from ray_tpu.ops.paged_attention import (paged_attention_tpu,
+                                             paged_attention_xla)
+
+    live = case[-1]
+    got = _layer_1(paged_attention_tpu, *case)
+    want = _layer_1(paged_attention_xla, *case)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-2)
+    if live is not None:
+        dead = ~np.asarray(live)
+        assert (got[dead] == 0).all() and (want[dead] == 0).all()
+    return got
+
+
+# lengths on the page edges, and a one-page row handing the pipeline
+# over to a many-page row and back
+_KERNEL_LENGTHS = [1, _PS, _PS + 1, 7 * _PS - 3, 5, 4 * _PS]
+_LIVE_PATTERNS = {
+    "all-live": None,
+    "dead-first": [0, 1, 1, 1, 1, 1],
+    "dead-middle": [1, 1, 0, 0, 1, 1],
+    "dead-last": [1, 1, 1, 1, 1, 0],
+    "one-live": [0, 0, 0, 1, 0, 0],
+    "none-live": [0, 0, 0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("pattern", list(_LIVE_PATTERNS))
+@pytest.mark.parametrize("window", [None, 3 * _PS + 5, 1 << 20],
+                         ids=["no-window", "window-inside", "window-past"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_pallas_decode_kernel_reads_live_rows_only(
+        tpu_interpreter, head_dim, window, pattern):
+    """Kernel against ``paged_attention_xla`` in the TPU interpreter:
+    the padded-query form (head_dim 64) and the split-page form (128),
+    with no window operand, a window that cuts the long rows and one
+    that cuts nothing, under every shape of ``live``; a dead row's
+    output is exactly zero on both sides."""
+    import numpy as np
+
+    live = _LIVE_PATTERNS[pattern]
+    live = None if live is None else np.asarray(live, bool)
+    rs = np.random.RandomState(head_dim + len(pattern))
+    q, pool, tables, lengths = _kernel_case(rs, head_dim, _KERNEL_LENGTHS, 7)
+    if live is not None:
+        tables[~live] = 0                 # as the engine leaves them
+    _assert_kernel_is_oracle(q, pool, tables, lengths, window, live)
+
+
+@pytest.mark.parametrize("window", [None, 3 * _PS + 5],
+                         ids=["no-window", "window"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_pallas_decode_kernel_never_loads_what_it_must_not_read(
+        tpu_interpreter, head_dim, window):
+    """NaN in the scratch page, in every page of the dead rows' tables,
+    in the pages past each live row's length and in the pages wholly
+    behind the window: a page that is loaded and only masked turns
+    ``0 * NaN`` into NaN in ``p @ v``, so a finite output equal to the
+    clean pool's says those pages were never DMA'd."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    live = np.asarray([1, 0, 1, 1, 0, 1], bool)
+    rs = np.random.RandomState(head_dim)
+    q, pool, tables, lengths = _kernel_case(rs, head_dim, _KERNEL_LENGTHS, 7)
+    clean = _assert_kernel_is_oracle(q, pool, tables, lengths, window, live)
+    poison = [0]
+    for r, n in enumerate(_KERNEL_LENGTHS):
+        first = 0 if window is None else max(0, n - window) // _PS
+        last = -(-n // _PS)
+        poison += (list(tables[r]) if not live[r] else
+                   list(tables[r, :first]) + list(tables[r, last:]))
+    assert len(poison) > 7
+    pool = pool.at[:, jnp.asarray(poison)].set(jnp.nan)
+    from ray_tpu.ops.paged_attention import paged_attention_tpu
+    np.testing.assert_array_equal(
+        _layer_1(paged_attention_tpu, q, pool, tables, lengths, window,
+                 live), clean)
 
 
 @pytest.mark.parametrize("window", [1, 8, 16, 48],
@@ -535,28 +659,120 @@ def test_prefix_suffix_prefill_equals_full_prefill(tiny_parts_either):
         eng.close()
 
 
-def test_idle_rows_stay_at_position_zero(tiny_parts):
+def _step_blocks(eng, live_slots, blocks=2):
+    """Drive ``_block_jit`` by hand: install ``live_slots`` = {slot:
+    first page} at position 5 (greedy), the other rows idle on scratch
+    page 0, and run ``blocks`` blocks.  Returns each live slot's tokens
+    and the final positions."""
+    import numpy as np
+
+    rows = eng.num_slots + 1
+    meta = np.asarray(eng._no_admit[0]).copy()
+    tables = np.zeros((meta.shape[1], eng.max_pages), np.int32)
+    for i, (slot, page) in enumerate(live_slots.items()):
+        meta[:, i] = (slot, 5, 0)
+        tables[i, 0] = page
+    out = {slot: [] for slot in live_slots}
+    for _ in range(blocks):
+        block, eng._state, eng._cache = eng._block_jit(
+            eng.params, eng._cache, eng._state, meta,
+            np.zeros((meta.shape[1],), np.int32), tables)
+        block = np.asarray(block)[:rows * eng.block_size].reshape(
+            rows, eng.block_size)
+        for slot in live_slots:
+            out[slot] += block[slot].tolist()
+        meta = np.asarray(eng._no_admit[0])
+        tables = np.zeros_like(tables)
+    return out, np.asarray(eng._state[1]).tolist()
+
+
+@pytest.mark.parametrize("live_slots", [
+    {1: 3}, {0: 3}, {2: 3}, {1: 3, 0: 5}, {2: 3, 1: 5}, {0: 3, 1: 5, 2: 7}],
+    ids=["slot1-alone", "slot0-alone", "slot2-alone", "dead-last",
+         "dead-first", "none-dead"])
+def test_idle_rows_stay_at_position_zero(tiny_parts, monkeypatch,
+                                         live_slots):
     """The decode kernel reads ceil((position+1)/page_size) pages a row a
     layer, so a row that holds no request (table -> scratch page 0) must
     not walk towards max_seq_len while it steps junk; a live row
-    advances by the block."""
+    advances by the block.  ``Block`` hands the decode attention the
+    rows that hold a request (table not on the scratch page), and the
+    tokens of a live row are the same whichever slots around it are
+    dead."""
+    import importlib
+
     import numpy as np
     from ray_tpu.serve.llm_engine import LLMEngine
 
+    paged = importlib.import_module("ray_tpu.ops.paged_attention")
+    masks = []
+
+    def spy(q, *a, live=None, **kw):
+        masks.append(None if live is None else live.shape)
+        return real(q, *a, live=live, **kw)
+
+    real = paged.paged_attention
+    monkeypatch.setattr(paged, "paged_attention", spy)
     cfg, params = tiny_parts
-    eng = LLMEngine(cfg, params, num_slots=3, block_size=4,
-                    page_size=16, kv_pool_pages=1 + 8)
+
+    def run(slots):
+        eng = LLMEngine(cfg, params, num_slots=3, block_size=4,
+                        page_size=16, kv_pool_pages=1 + 8)
+        try:
+            return _step_blocks(eng, slots)
+        finally:
+            eng.close()
+
+    tokens, positions = run(live_slots)
+    assert masks and all(m == (4,) for m in masks)     # 3 slots + scratch
+    assert positions == [5 + 2 * 4 if s in live_slots else 0
+                         for s in range(3)] + [0]
+    # the row on page 3, alone in slot 1 with every other row dead
+    alone, _ = run({1: 3})
+    (first,) = [s for s, page in live_slots.items() if page == 3]
+    assert tokens[first] == alone[1]
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny-smallthinker"])
+def test_decode_pages_read_counts_what_the_kernel_reads(preset):
+    """``EngineStats.decode_pages_read`` over a short run is the sum,
+    over delivered decode steps and layers, of the pages the kernel's
+    loop bounds name: ``ceil((pos + 1) / page)`` from page 0 in a layer
+    without a window, from ``max(0, pos + 1 - window) // page`` in one
+    with; ``window_pages_read`` is the window layers' part of it."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models.configs import get_config
+    from ray_tpu.models.gpt import GPT
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg = get_config(preset)
+    params = GPT(cfg, decode=True).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))["params"]
+    ps, w = 4, cfg.sliding_window
+    window_layers = sum(cfg.window_layout) if cfg.window_layout else 0
+    eng = LLMEngine(cfg, params, num_slots=2, page_size=ps, block_size=4,
+                    max_seq_len=64, max_prompt_len=32,
+                    min_prefill_bucket=4)
+    requests = ((13, 20), (6, 9), (3, 2))        # prompt, new tokens
     try:
-        meta = np.asarray(eng._no_admit[0]).copy()
-        tables = np.zeros((3, eng.max_pages), np.int32)
-        meta[:, 0] = (1, 5, 0)              # slot 1, position 5, greedy
-        tables[0, 0] = 3                    # its first page: live
-        for _ in range(2):
-            _, eng._state, eng._cache = eng._block_jit(
-                eng.params, eng._cache, eng._state, meta,
-                np.zeros((3,), np.int32), tables)
-            meta = np.asarray(eng._no_admit[0])
-            tables = np.zeros((3, eng.max_pages), np.int32)
-        assert np.asarray(eng._state[1]).tolist() == [0, 5 + 2 * 4, 0, 0]
+        for plen, new in requests:
+            out = eng.submit(list(range(1, plen + 1)), max_new_tokens=new,
+                             temperature=0.0)
+            assert len(out.tokens) == new
+        st = eng.stats.snapshot(2)
     finally:
         eng.close()
+    plain = windowed = 0
+    for plen, new in requests:
+        # the first token is the prefill's; the step that makes token k
+        # sits at position plen + k - 1
+        for pos in range(plen, plen + new - 1):
+            pages = -(-(pos + 1) // ps)
+            plain += pages * (cfg.n_layers - window_layers)
+            if window_layers:
+                windowed += window_layers * (
+                    pages - max(0, pos + 1 - w) // ps)
+    assert plain > 0 and bool(windowed) == bool(window_layers)
+    assert st["decode_pages_read"] == plain + windowed
+    assert st["window_pages_read"] == windowed
