@@ -22,7 +22,7 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None       # None -> = n_heads (MHA)
     d_ff: Optional[int] = None             # None -> 4 * d_model (8/3 for swiglu)
     max_seq_len: int = 2048
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None: no layer rotates
     norm_eps: float = 1e-6
     dtype: jnp.dtype = jnp.bfloat16        # activation dtype
     param_dtype: jnp.dtype = jnp.float32
@@ -65,6 +65,29 @@ class TransformerConfig:
     sliding_window: Optional[int] = None
     rope_layout: Optional[tuple] = None
     window_layout: Optional[tuple] = None
+    # Per-layer BLOCK classes ("full_attention" | "linear_attention";
+    # layer l is entry l, the tuple may be longer than n_layers).  Where
+    # it names more than one class the layer stack scans one PERIOD of
+    # it (``period``; models/gpt.py Period) and n_layers is whole
+    # periods.  A linear_attention layer is a Gated DeltaNet mixer
+    # (ops/gated_delta.py): linear_key_heads x linear_key_head_dim
+    # queries and keys, linear_value_heads x linear_value_head_dim
+    # values and gates, a causal depthwise convolution of
+    # linear_conv_kernel taps in front; linear_allow_neg_eigval: the
+    # write strength ranges over (0, 2), not (0, 1).  It holds no KV
+    # pages but a fixed-size state a request (serve/llm_engine.py).
+    layer_types: Optional[tuple] = None
+    linear_key_heads: Optional[int] = None
+    linear_value_heads: Optional[int] = None
+    linear_key_head_dim: Optional[int] = None
+    linear_value_head_dim: Optional[int] = None
+    linear_conv_kernel: int = 4
+    linear_allow_neg_eigval: bool = False
+    # RMSNorm over the whole q and k projections before the heads split
+    qk_norm: bool = False
+    # a full_attention block normalises each sub-layer's OUTPUT (x +
+    # Norm(f(x)), the OLMo 2/3 block), not its input
+    post_norm: bool = False
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -85,6 +108,33 @@ class TransformerConfig:
         if self.window_layout is not None:
             assert self.sliding_window
             self.window_layout = tuple(int(v) for v in self.window_layout)
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            assert len(self.layer_types) >= self.n_layers
+            assert set(self.layer_types) <= {"full_attention",
+                                             "linear_attention"}
+            assert not self.layers_differ and not self.moe_experts
+            if self.period:
+                assert self.n_layers % len(self.period) == 0, (
+                    "n_layers must be whole periods of layer_types")
+
+    @property
+    def period(self) -> Optional[tuple]:
+        """The shortest run of block classes that ``layer_types``
+        repeats over the first ``n_layers``, where it names more than one
+        class (the layer stack then scans that run); else None."""
+        kinds = (self.layer_types or ())[:self.n_layers]
+        if len(set(kinds)) < 2:
+            return None
+        return next(kinds[:p] for p in range(1, len(kinds) + 1)
+                    if all(k == kinds[i % p] for i, k in enumerate(kinds)))
+
+    def layers_of(self, kind: str) -> int:
+        """How many of the ``n_layers`` are of block class ``kind``
+        (every layer is full_attention without ``layer_types``)."""
+        if self.layer_types is None:
+            return self.n_layers if kind == "full_attention" else 0
+        return self.layer_types[:self.n_layers].count(kind)
 
     @property
     def layers_differ(self) -> bool:
@@ -93,8 +143,29 @@ class TransformerConfig:
         return self.rope_layout is not None or self.window_layout is not None
 
     def _attn_params(self) -> int:
+        qk = ((self.n_heads + self.n_kv_heads) * self.head_dim
+              if self.qk_norm else 0)
         return self.d_model * self.head_dim * (
-            self.n_heads * 2 + self.n_kv_heads * 2)
+            self.n_heads * 2 + self.n_kv_heads * 2) + qk
+
+    def _linear_attn_params(self) -> int:
+        """A Gated DeltaNet mixer: q, k, v, gate and output
+        projections, the two per-head gates' projections, the
+        convolution's taps, ``A_log`` and ``dt_bias`` a value head, the
+        output norm's one weight of ``linear_value_head_dim``."""
+        qk = self.linear_key_heads * self.linear_key_head_dim
+        vv = self.linear_value_heads * self.linear_value_head_dim
+        return (self.d_model * (2 * qk + 2 * vv) + vv * self.d_model
+                + 2 * self.d_model * self.linear_value_heads
+                + self.linear_conv_kernel * (2 * qk + vv)
+                + 2 * self.linear_value_heads + self.linear_value_head_dim)
+
+    def layer_params(self, kind: str = "full_attention") -> int:
+        """One dense block of class ``kind``: mixer, SwiGLU, two
+        norms."""
+        mixer = (self._attn_params() if kind == "full_attention"
+                 else self._linear_attn_params())
+        return mixer + 3 * self.d_model * self.d_ff + 2 * self.d_model
 
     def num_params(self) -> int:
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
@@ -104,8 +175,10 @@ class TransformerConfig:
         else:
             mlp = 3 * self.d_model * self.d_ff
         norms = 2 * self.d_model
-        return emb + self.n_layers * (self._attn_params() + mlp + norms) \
-            + self.d_model
+        linear = self.layers_of("linear_attention")
+        mixers = (self.n_layers - linear) * self._attn_params() + (
+            linear and linear * self._linear_attn_params())
+        return emb + mixers + self.n_layers * (mlp + norms) + self.d_model
 
     def train_flops_per_token(self, seq_len: int) -> float:
         """Operations a training step REQUIRES per token — the count
@@ -192,6 +265,29 @@ PRESETS = {
         moe_act="relu", moe_dropless=True, moe_router_pre_attn=True,
         sliding_window=8, rope_layout=(0, 1, 1, 1) * 2,
         window_layout=(0, 1, 1, 1) * 2),
+    # Olmo-Hybrid-7B (allenai) as published: a 4-layer period of three
+    # Gated DeltaNet layers (30 heads, keys of 96, values of 192, a
+    # convolution of 4 taps, write strength in (0, 2)) and one
+    # full-attention layer (30 heads of 128, QK-norm, post-norm, no
+    # rotation anywhere)
+    "olmo-hybrid-7b": TransformerConfig(
+        vocab_size=100352, d_model=3840, n_layers=32, n_heads=30,
+        n_kv_heads=30, head_dim=128, d_ff=11008, max_seq_len=65536,
+        rope_theta=None, norm_eps=1e-6, qk_norm=True, post_norm=True,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 8,
+        linear_key_heads=30, linear_value_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192,
+        linear_conv_kernel=4, linear_allow_neg_eigval=True),
+    # the same blocks at test size: two periods
+    # (tests/test_olmo_hybrid.py)
+    "tiny-olmo-hybrid": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=4,
+        head_dim=16, d_ff=128, max_seq_len=256, dtype=jnp.float32,
+        remat=False, rope_theta=None, qk_norm=True, post_norm=True,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2,
+        linear_key_heads=4, linear_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=32, linear_conv_kernel=4,
+        linear_allow_neg_eigval=True),
 }
 
 

@@ -193,6 +193,29 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
     return x if carry is None else (x, carry)
 
 
+# float32 scores ``[rows, heads, T, T]`` of a prefill wave beyond this
+# many bytes are computed a group of rows at a time (8 prompts of 2048
+# tokens at 30 heads are 4 GB of them, beside 12 GB of weights and pool)
+_PREFILL_SCORE_BYTES = 1 << 30
+
+
+def _prefill_attend(q, k, v):
+    """Causal attention of each row of a prefill wave over itself.
+    ``xla_attention`` as it is where the wave's scores fit
+    ``_PREFILL_SCORE_BYTES``; else the same over the largest groups of
+    rows that do, one after the other (``lax.map``)."""
+    b, t, h, _ = q.shape
+    group = max(1, min(b, _PREFILL_SCORE_BYTES // (4 * h * t * t)))
+    while b % group:
+        group -= 1
+    if group == b:
+        return xla_attention(q, k, v, causal=True)
+    split = lambda a: a.reshape(b // group, group, *a.shape[1:])  # noqa: E731
+    out = jax.lax.map(lambda qkv: xla_attention(*qkv, causal=True),
+                      (split(q), split(k), split(v)))
+    return out.reshape(b, *out.shape[2:])
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     mesh: Optional[Mesh] = None
@@ -223,8 +246,15 @@ class Attention(nn.Module):
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
         v = _dense((kvh, hd), ("embed", "kv", "head_dim"), "wv",
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
+        if cfg.qk_norm:       # over the whole projection, heads unsplit
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(
+                q.reshape(*q.shape[:2], h * hd)).reshape(q.shape)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(
+                k.reshape(*k.shape[:2], kvh * hd)).reshape(k.shape)
         rotate, window = self._layer_kind(layer)
-        if rotate is None:
+        if cfg.rope_theta is None:
+            pass                            # no layer rotates
+        elif rotate is None:
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
         else:       # this layer's entry of rope_layout: 0 -> no positions
@@ -437,7 +467,7 @@ class Attention(nn.Module):
             window = self._window_over(window, q.shape[1])
             if window is not None:
                 return self._window_attend(q, k, v, window), pool
-            return xla_attention(q, k, v, causal=True), pool
+            return _prefill_attend(q, k, v), pool
         # suffix prefill: the window's keys are NOT the whole story —
         # leading block-table entries hold a cached prompt prefix, so
         # gather the row's full logical span back out of the pool and
@@ -473,7 +503,10 @@ class Block(nn.Module):
         # (serve/llm_engine.py): neither the decode attention kernel nor
         # the expert kernel reads anything for it
         live = None if block_tables is None else block_tables[:, 0] != 0
-        y = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
+        # cfg.post_norm: each sub-layer reads x and its OUTPUT is normed
+        attn_norm = RMSNorm(cfg.norm_eps, name="attn_norm")
+        mlp_norm = RMSNorm(cfg.norm_eps, name="mlp_norm")
+        y = x if cfg.post_norm else attn_norm(x)
         moe = router_logits = None
         if cfg.moe_experts > 0 and cfg.moe_dropless:
             from ray_tpu.ops.moe import DroplessMoE
@@ -488,9 +521,11 @@ class Block(nn.Module):
             y, cos, sin, positions, block_tables, pool, layer, live)
         if pool is not None:
             y, pool = y
+        if cfg.post_norm:
+            y = attn_norm(y)
         y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
         x = x + y
-        y = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+        y = x if cfg.post_norm else mlp_norm(x)
         if moe is not None:
             y = moe(y, router_logits, live, moe_stacked, layer)
         elif cfg.moe_experts > 0:
@@ -502,12 +537,215 @@ class Block(nn.Module):
                        name="moe")(y)
         else:
             y = MLP(cfg, name="mlp")(y)
+        if cfg.post_norm:
+            y = mlp_norm(y)
         y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
         x = x + y
         if self.mesh is not None and not self.decode:
             x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
                               self.rules)
         return x if pool is None else (x, pool)
+
+
+def _a_log_init(key, shape, dtype):
+    """log of a decay rate uniform in [1, 16) (the family's own
+    initialisation, as ``_dt_bias_init``): with it a head forgets over
+    tens to thousands of tokens, as a trained one does."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype):
+    """Inverse softplus of a step log-uniform in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, jnp.log(1e-3),
+                                    jnp.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class LinearAttention(nn.Module):
+    """Gated DeltaNet mixer (ops/gated_delta.py): projections, a causal
+    depthwise convolution with SiLU over ``[q; k; v]``, unit-length
+    ``q`` and ``k`` a head, the gated delta rule, a per-head RMSNorm of
+    the output gated by ``SiLU(z)``, the output projection.
+
+    What it remembers of a sequence is ``rec = (state, conv)``: the
+    model's two stacked leaves ``[linear layers, entries, dk, heads *
+    dv]`` float32 and ``[linear layers, entries, .., 128]`` (the
+    convolution's last ``taps - 1`` inputs, flat in rows of 128 lanes: a
+    minor pair ``[3, channels]`` would be tiled out to 16 rows, and one
+    of ``[entries, 3 * channels]`` could not be merged with the layer
+    axis without a copy), addressed ``[layer, entry]`` and passed
+    through whole, as the KV pool is.  Without ``rec`` a call is
+    a whole sequence from an empty state (training, a plain forward).
+    With it: ``T > 1`` is a prompt from an empty state whose final
+    state and tail are written to the rows' ``entries`` (``lengths``:
+    each row's real length; right-pad past it is not absorbed), ``T ==
+    1`` one decode step on the rows' entries, dead rows untouched."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, lengths=None, entries=None, rec=None, layer=None,
+                 live=None):
+        from ray_tpu.ops import gated_delta as gd
+        cfg = self.cfg
+        hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        taps = cfg.linear_conv_kernel
+        b, t, _ = x.shape
+        f32 = jnp.float32
+
+        def proj(features, name, axes=("embed", "heads", "head_dim")):
+            return _dense(features, axes[:1 + len(features)], name,
+                          dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
+        u = jnp.concatenate([
+            proj((hk, dk), "wq").reshape(b, t, hk * dk),
+            proj((hk, dk), "wk").reshape(b, t, hk * dk),
+            proj((hv, dv), "wv").reshape(b, t, hv * dv)], axis=-1)
+        z = proj((hv, dv), "wg")
+        a, bw = proj((hv,), "wa"), proj((hv,), "wb")
+        vec = lambda init: nn.with_logical_partitioning(    # noqa: E731
+            init, ("norm",))
+        conv_w = self.param(
+            "conv", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), (None, "norm")),
+            (taps, u.shape[-1]), cfg.param_dtype).astype(f32)
+        a_log = self.param("A_log", vec(_a_log_init), (hv,), f32)
+        dt_bias = self.param("dt_bias", vec(_dt_bias_init), (hv,), f32)
+        o_scale = self.param("o_norm", vec(nn.initializers.ones_init()),
+                             (dv,), f32)
+
+        decode_step = rec is not None and t == 1 \
+            and not self.is_initializing()
+        if decode_step:
+            state, conv = rec
+            flat, at = gd.flat_rows(conv, layer, entries)
+            tail = flat[at].reshape(b, taps - 1, -1)
+            window = jnp.concatenate([tail, u.astype(conv.dtype)], 1)
+            if live is not None:      # a dead row keeps its tail
+                tail = jnp.where(live[:, None, None], window[:, 1:], tail)
+            else:
+                tail = window[:, 1:]
+            rec = (state, flat.at[at].set(
+                tail.reshape((b,) + conv.shape[2:])).reshape(conv.shape))
+        else:       # zeros before the sequence's start
+            window = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+        # the convolution as the sum of its taps' shifted products
+        c = sum(window[:, j:j + t].astype(f32) * conv_w[j]
+                for j in range(taps))
+        c = nn.silu(c).astype(cfg.dtype)
+        q, k, v = jnp.split(c, [hk * dk, 2 * hk * dk], axis=-1)
+        q, k = (y.reshape(b, t, hk, dk).astype(f32) for y in (q, k))
+        unit = lambda y: y * jax.lax.rsqrt(                 # noqa: E731
+            jnp.sum(y * y, -1, keepdims=True) + 1e-6)
+        q = (unit(q) * dk ** -0.5).astype(cfg.dtype)
+        k = unit(k).astype(cfg.dtype)
+        if hv != hk:                  # value heads share a key head
+            q, k = (jnp.repeat(y, hv // hk, axis=2) for y in (q, k))
+        v = v.reshape(b, t, hv, dv)
+        beta = jax.nn.sigmoid(bw.astype(f32)) * (
+            2.0 if cfg.linear_allow_neg_eigval else 1.0)
+        g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(f32) + dt_bias)
+
+        if decode_step:
+            o, state = gd.gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                     beta[:, 0], rec[0], entries, live,
+                                     layer=layer)
+            o, rec = o[:, None], (state, rec[1])
+        else:
+            o, final = gd.gated_delta_chunked(q, k, v, g, beta, lengths)
+            if rec is not None and not self.is_initializing():
+                state, conv = rec
+                if lengths is None:
+                    lengths = jnp.full((b,), t, jnp.int32)
+                # the last taps - 1 REAL inputs (zeros before the start),
+                # picked by a 0/1 product: exact, and no gather
+                pick = (jnp.arange(window.shape[1])[None, None, :]
+                        == lengths[:, None, None]
+                        + jnp.arange(taps - 1)[None, :, None])
+                tail = jnp.einsum("bjt,btc->bjc", pick.astype(window.dtype),
+                                  window)
+                rec = (gd.write_rows(state, gd.pack_state(final), layer,
+                                     entries),
+                       gd.write_rows(conv, tail.reshape(
+                           (b,) + conv.shape[2:]), layer, entries))
+        # per-head RMSNorm (one weight of dv), gated
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.norm_eps) * o_scale
+        o = (o * nn.silu(z.astype(f32))).astype(cfg.dtype)
+        out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
+            o.reshape(b, t, hv * dv))
+        return out if rec is None else (out, rec)
+
+
+class LinearBlock(nn.Module):
+    """A pre-norm block around ``LinearAttention``: ``h = x +
+    GDN(Norm(x))``, ``out = h + MLP(Norm(h))``."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Mesh] = None
+    rules: ShardingRules = LOGICAL_RULES
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, block_tables=None, lengths=None, entries=None,
+                 rec=None, layer=None):
+        cfg = self.cfg
+        live = None if block_tables is None else block_tables[:, 0] != 0
+        y = LinearAttention(cfg, name="attn")(
+            RMSNorm(cfg.norm_eps, name="attn_norm")(x), lengths, entries,
+            rec, layer, live)
+        if rec is not None:
+            y, rec = y
+        x = x + jax.ad_checkpoint.checkpoint_name(y, "attn_out")
+        y = MLP(cfg, name="mlp")(RMSNorm(cfg.norm_eps, name="mlp_norm")(x))
+        x = x + jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
+        if self.mesh is not None and not self.decode:
+            x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
+                              self.rules)
+        return x if rec is None else (x, rec)
+
+
+class Period(nn.Module):
+    """One period of ``cfg.layer_types``: what ``stack_layers`` scans
+    where the layers are of more than one block CLASS (their parameter
+    trees differ, so one stacked ``Block`` cannot hold them).  The
+    parameters are stacked over periods, one subtree ``layer_<j>`` a
+    position in the period.  The carry is ``(pool, rec)``: the KV pool
+    stacked over the full-attention layers ONLY and the recurrent
+    leaves stacked over the linear ones; ``period`` (the scanned index)
+    times the layers of a class a period, plus the position's rank in
+    its class, is the layer's index into its leaf."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Mesh] = None
+    rules: ShardingRules = LOGICAL_RULES
+    decode: bool = False
+    prefix_attend: bool = False
+
+    @nn.compact
+    def __call__(self, x, cos, sin, positions=None, block_tables=None,
+                 lengths=None, entries=None, carry=None, period=None):
+        kinds = self.cfg.period
+        pool, rec = carry if carry is not None else (None, None)
+        seen = {"full_attention": 0, "linear_attention": 0}
+        for j, kind in enumerate(kinds):
+            layer = None if carry is None else (
+                period * kinds.count(kind) + seen[kind])
+            seen[kind] += 1
+            if kind == "full_attention":
+                x = Block(self.cfg, self.mesh, self.rules, self.decode,
+                          self.prefix_attend, name=f"layer_{j}")(
+                    x, cos, sin, positions, block_tables, None, pool, layer)
+                if pool is not None:
+                    x, pool = x
+            else:
+                x = LinearBlock(self.cfg, self.mesh, self.rules,
+                                self.decode, name=f"layer_{j}")(
+                    x, block_tables, lengths, entries, rec, layer)
+                if rec is not None:
+                    x, rec = x
+        return x if carry is None else (x, (pool, rec))
 
 
 def output_logits(cfg: TransformerConfig, params, hidden) -> jax.Array:
@@ -538,6 +776,9 @@ class GPT(nn.Module):
     paged_pages: int = 0                   # >0: paged KV decode (see Attention)
     page_size: int = 64
     prefix_attend: bool = False            # suffix prefill over cached pages
+    # entries of the recurrent leaves (see LinearAttention) of a paged
+    # model with linear_attention layers; entry 0 is scratch
+    state_entries: int = 0
 
     def _moe_stacked(self):
         """The scanned layer stack's dropless expert leaves ``[L, E, ...]``
@@ -553,9 +794,66 @@ class GPT(nn.Module):
         moe = nn.meta.unbox(self.variables["params"]["blocks"]["moe"])
         return moe["w_gate"], moe["w_up"], moe["w_down"]
 
+    def _stack_periods(self, x, block_kwargs, call_args, lengths, entries,
+                       remat):
+        """The layer stack of a model whose ``layer_types`` name more
+        than one block class: ``Period`` is what is scanned (4 layers a
+        step at Olmo-Hybrid's 3 + 1), and a paged decode model carries
+        ``(pool, (state, conv))`` through it: the KV pool has the
+        full-attention layers only, the recurrent leaves the others."""
+        cfg = self.cfg
+        if cfg.remat_layers is not None:
+            raise ValueError("remat_layers counts layers of one class; "
+                             "a model that scans periods takes remat "
+                             "on or off")
+        n_periods = cfg.n_layers // len(cfg.period)
+        if not (self.decode and self.paged_pages):
+            if self.decode and not self.is_initializing():
+                raise ValueError(
+                    "a linear_attention layer has no dense-cache decode: "
+                    "its state lives in the paged engine's entries "
+                    "(serve/llm_engine.py); Generator cannot run it")
+            return stack_layers(Period, cfg, block_kwargs, x,
+                                call_args + (lengths, None), remat=remat,
+                                cache=True, n_layers=n_periods)
+        n_full = cfg.layers_of("full_attention")
+        n_lin = cfg.n_layers - n_full
+        # the convolution's last inputs, every channel of [q; k; v]
+        tail = (cfg.linear_conv_kernel - 1) * (
+            2 * cfg.linear_key_heads * cfg.linear_key_head_dim
+            + cfg.linear_value_heads * cfg.linear_value_head_dim)
+        ckv = self.variable(
+            "cache", "kv_pages", jnp.zeros,
+            (n_full, self.paged_pages, cfg.n_kv_heads, self.page_size,
+             2 * cfg.head_dim), cfg.dtype)
+        cst = self.variable(
+            "cache", "gdn_state", jnp.zeros,
+            (n_lin, self.state_entries, cfg.linear_key_head_dim,
+             cfg.linear_value_heads * cfg.linear_value_head_dim),
+            jnp.float32)
+        ccv = self.variable(
+            "cache", "gdn_conv", jnp.zeros,
+            (n_lin, self.state_entries) + (
+                (tail // 128, 128) if tail % 128 == 0 else (1, tail)),
+            cfg.dtype)
+        if entries is None:
+            entries = jnp.arange(x.shape[0], dtype=jnp.int32)
+        x, (pool, (state, conv)) = stack_layers(
+            Period, cfg, block_kwargs, x, call_args + (lengths, entries),
+            remat=False, n_layers=n_periods,
+            carry=(ckv.value, (cst.value, ccv.value)))
+        if not self.is_initializing():
+            ckv.value, cst.value, ccv.value = pool, state, conv
+        return x
+
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False,
-                 block_tables=None):
+                 block_tables=None, lengths=None, state_rows=None):
+        """``lengths`` [B] and ``state_rows`` [B] matter to a model with
+        linear_attention layers only: each row's REAL length in a ``T >
+        1`` call (right-pad that attention never sees would be absorbed
+        by a recurrence; None: every position is real) and each row's
+        entry of the recurrent leaves (None: row i uses entry i)."""
         cfg = self.cfg
         embed = self.param(
             "embed",
@@ -576,9 +874,11 @@ class GPT(nn.Module):
         if self.mesh is not None and not self.decode:
             x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
                               self.rules)
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                    cfg.rope_theta)
-        if self.mesh is not None and not self.decode:
+        cos = sin = None
+        if cfg.rope_theta is not None:
+            cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+                                        cfg.rope_theta)
+        if cos is not None and self.mesh is not None and not self.decode:
             # the rope tables are tiny closure constants: pin them
             # replicated so the partitioner never invents a sharding for
             # them (they otherwise surface as involuntarily
@@ -594,7 +894,10 @@ class GPT(nn.Module):
                             prefix_attend=self.prefix_attend)
         call_args = (cos, sin, positions, block_tables,
                      self._moe_stacked())
-        if self.decode and self.paged_pages:
+        if cfg.period:
+            x = self._stack_periods(x, block_kwargs, call_args[:4], lengths,
+                                    state_rows, do_remat)
+        elif self.decode and self.paged_pages:
             # the paged KV pool: ONE stacked leaf for the whole model,
             # K in [..., :hd], V in [..., hd:] (layout dictated by TPU
             # tiling, ops/paged_attention.py layout note).  It rides the

@@ -44,6 +44,25 @@ iteration's prefills):
     position, table) row into the block step's device state.
     Time-to-first-token is bounded by prefill throughput and pool
     capacity, not by slot turnover.
+  - State that is not pages.  A model with linear_attention layers
+    (models/gpt.py LinearAttention) keeps, beside its KV pages in the
+    full-attention layers, a FIXED-SIZE recurrent state a request: two
+    more stacked cache leaves ``gdn_state`` [linear layers, entries,
+    dk, heads*dv] float32 and ``gdn_conv`` [linear layers, entries,
+    (taps-1)*channels/128, 128], chained and donated with the pool.  A request
+    holds one ENTRY of them from admission to finish, allocated and
+    freed with its pages (``_free_states`` beside ``_free_pages``;
+    admission waits for either); entry 0 is scratch, as page 0 is.  The
+    prefill program is told each prompt's REAL length (a recurrence
+    would absorb the right-pad attention never sees) and writes the
+    prompt's final state to the request's entry, starting from zeros:
+    that write is what clears an entry for reuse.  A decode row
+    addresses its entry through an ``entries`` array that rides the
+    block step's device state and the install upload, as its table
+    does; a redirected row points at scratch.  There are ``num_slots +
+    1 + _STATE_AHEAD`` entries: every slot, scratch, and that many
+    requests prefilled ahead of a slot.  The prefix cache and the
+    prefill handoff carry pages only and are refused for such a model.
   - The block step.  One jitted program advances ALL slots
     ``block_size`` tokens via lax.scan: [N] tokens in, [N, K] tokens
     out, donated pool; tokens, positions, temperatures, tables and the
@@ -89,6 +108,13 @@ from ray_tpu.serve.frontdoor.prefix import page_digests
 # host launch latency, so saturation bursts (prefill-ahead admitting a
 # whole queue) want wide waves
 _WAVE_SIZES = (1, 2, 4, 8, 16, 32)
+
+# state entries beyond one a slot and scratch (module docstring): how
+# many requests of a model with recurrent layers may be prefilled and
+# waiting for a slot.  An entry is megabytes (20.5 MB at Olmo-Hybrid-7B's
+# sizes over 9 layers), so this is small; a request that finds none free
+# waits in the queue as it waits for pages
+_STATE_AHEAD = 8
 
 
 @dataclasses.dataclass
@@ -172,6 +198,9 @@ class _Request:
     # chained page-boundary digests of the prompt (frontdoor/prefix.py),
     # computed at submit when the prefix cache is enabled
     digests: Optional[List[str]] = None
+    # its entry of the recurrent state leaves, from admission to finish
+    # (0: none; a model without recurrent layers never takes one)
+    entry: int = 0
 
 
 @dataclasses.dataclass
@@ -268,6 +297,13 @@ class EngineStats:
         self.decode_pages_read = 0
         self.window_pages_read = 0
         self.window_pages_skipped = 0
+        # recurrent (gated delta) layers: a layer step is one such layer
+        # in one decode step; gdn_state_rows sums over them the rows
+        # whose token was delivered, each of which had its state entry
+        # read and written once by that layer step.  Host arithmetic,
+        # once a row a block, as the page counts are
+        self.gdn_layer_steps = 0
+        self.gdn_state_rows = 0
         # seconds of the loop thread, advanced at each phase's end
         # (_Phase): loop_s is its whole life, the rest are parts of it.
         # 1 - fetch_wait_s / (loop_s - idle_wait_s) is the share of its
@@ -307,6 +343,8 @@ class EngineStats:
             "decode_pages_read": self.decode_pages_read,
             "window_pages_read": self.window_pages_read,
             "window_pages_skipped": self.window_pages_skipped,
+            "gdn_layer_steps": self.gdn_layer_steps,
+            "gdn_state_rows": self.gdn_state_rows,
             "loop_s": self.loop_s,
             "idle_wait_s": self.idle_wait_s,
             "fetch_wait_s": self.fetch_wait_s,
@@ -393,8 +431,21 @@ class LLMEngine:
         # kv_pool_pages (benchmarks/serve_llm.py sizes it per load).
         self.kv_pool_pages = (kv_pool_pages if kv_pool_pages
                               else 1 + (num_slots + 1) * self.max_pages)
+        # layers that hold KV pages, and layers that hold a recurrent
+        # state entry instead (module docstring)
+        self._pool_layers = cfg.layers_of("full_attention")
+        self._state_layers = cfg.n_layers - self._pool_layers
+        self.state_entries = (num_slots + 1 + _STATE_AHEAD
+                              if self._state_layers else 0)
+        if self._state_layers and prefix_cache_pages:
+            raise ValueError(
+                "prefix_cache_pages > 0 on a model with linear_attention "
+                "layers: a cached page run would need a snapshot of the "
+                "recurrent state at its page boundary to resume from, "
+                "which the prefix cache does not keep")
         self.model = GPT(cfg, decode=True, paged_pages=self.kv_pool_pages,
-                         page_size=page_size)
+                         page_size=page_size,
+                         state_entries=self.state_entries)
         self.stats = EngineStats()
         # the block program also returns the dropless expert layers'
         # load (EngineStats.moe_*); a model without them compiles the
@@ -406,6 +457,8 @@ class LLMEngine:
             0 if not cfg.sliding_window else cfg.n_layers
             if cfg.window_layout is None
             else sum(cfg.window_layout[:cfg.n_layers]))
+        self._free_states: List[int] = list(
+            range(1, self.state_entries))[::-1]
 
         self._rng = jax.random.PRNGKey(seed)
         self._lock = threading.Condition()
@@ -417,6 +470,8 @@ class LLMEngine:
 
         # +1 scratch row: the target of padded install rows
         self._rows = num_slots + 1
+        # install metadata rows: slots, positions, temps (, entries)
+        self._meta_rows = 4 if self._state_layers else 3
         self._cache = self._init_cache(self._rows)
         # decode state lives ON DEVICE between blocks (tokens, positions,
         # temps, tables, rng): the host uploads only the small install
@@ -424,7 +479,7 @@ class LLMEngine:
         self._state = self._init_state(seed)
         # packed install metadata [3, num_slots]: slots row, positions
         # row, temps*1e6 row — one upload per block, cached when empty
-        no_meta = np.zeros((3, num_slots), np.int32)
+        no_meta = np.zeros((self._meta_rows, num_slots), np.int32)
         no_meta[0, :] = num_slots                           # -> scratch
         self._no_admit = (jnp.asarray(no_meta),
                           jnp.zeros((num_slots,), jnp.int32),
@@ -502,12 +557,15 @@ class LLMEngine:
         return init_decode_cache(self.model, batch)
 
     def _init_state(self, seed: int):
-        return (jnp.zeros((self._rows,), jnp.int32),      # tokens
-                jnp.zeros((self._rows,), jnp.int32),      # positions
-                jnp.zeros((self._rows,), jnp.float32),    # temps
-                # per-row block tables (zeros -> every page is scratch)
-                jnp.zeros((self._rows, self.max_pages), jnp.int32),
-                jax.random.PRNGKey(seed))                 # device rng
+        state = (jnp.zeros((self._rows,), jnp.int32),     # tokens
+                 jnp.zeros((self._rows,), jnp.int32),     # positions
+                 jnp.zeros((self._rows,), jnp.float32),   # temps
+                 # per-row block tables (zeros -> every page is scratch)
+                 jnp.zeros((self._rows, self.max_pages), jnp.int32),
+                 jax.random.PRNGKey(seed))                # device rng
+        if self._state_layers:      # per-row state entries (0: scratch)
+            state += (jnp.zeros((self._rows,), jnp.int32),)
+        return state
 
     def _sample_fn(self, rng, logits, temps):
         """[B, V] logits + per-row temperature -> [B] token ids
@@ -517,15 +575,20 @@ class LLMEngine:
                              top_k=self.top_k, top_p=self.top_p)
 
     def _last_logits(self, model, params, cache, tokens, positions,
-                     s_reals, tables):
+                     s_reals, tables, entries=None):
         """``(logits [wave, vocab] of each row's last REAL position, the
         updated cache)``.  The head runs on those rows alone: float32
         logits of every position are ``wave x bucket x vocab`` (1.2 GB
         for one 2048-token prompt at a 152k vocabulary), of which one
-        row a prompt is read."""
+        row a prompt is read.  ``entries`` [wave] (a model with
+        recurrent layers): where each prompt's final state is written;
+        such a model is also told the real lengths."""
+        recurrent = {} if entries is None else {
+            "lengths": s_reals, "state_rows": entries}
         hidden, mut = model.apply(
             {"params": params, "cache": cache}, tokens, positions,
-            return_hidden=True, mutable=["cache"], block_tables=tables)
+            return_hidden=True, mutable=["cache"], block_tables=tables,
+            **recurrent)
         last = jnp.take_along_axis(
             hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0]
         return output_logits(self.cfg, params, last), mut["cache"]
@@ -539,6 +602,7 @@ class LLMEngine:
         if fn is None:
             def engine_prefill(params, cache, packed, tables, rng):
                 # packed [wave, bucket+2]: prompt tokens | s_real | temp*1e6
+                # (| state entry, a model with recurrent layers)
                 tokens = packed[:, :bucket]
                 s_reals = packed[:, bucket]
                 temps = packed[:, bucket + 1].astype(jnp.float32) / 1e6
@@ -546,7 +610,8 @@ class LLMEngine:
                 positions = jnp.broadcast_to(jnp.arange(s), (b, s))
                 last, cache = self._last_logits(
                     self.model, params, cache, tokens, positions, s_reals,
-                    tables)
+                    tables, packed[:, bucket + 2] if self._state_layers
+                    else None)
                 first = self._sample_fn(rng, last, temps)
                 return first, cache
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
@@ -663,8 +728,12 @@ class LLMEngine:
         prefill) so nothing extra is fetched; redirect rows (evicted
         slots) are just installs of (token 0, position 0, zero table ->
         scratch page)."""
-        tokens, positions, temps, tables, rng = state
+        tokens, positions, temps, tables, rng, *entries = state
         a_slots = admit_meta[0]
+        recurrent = {}
+        if entries:           # a redirect row's entry is scratch (0)
+            entries = (entries[0].at[a_slots].set(admit_meta[3]),)
+            recurrent["state_rows"] = entries[0]
         tokens = tokens.at[a_slots].set(admit_lasts)
         positions = positions.at[a_slots].set(admit_meta[1])
         temps = temps.at[a_slots].set(
@@ -689,7 +758,8 @@ class LLMEngine:
             logits, mut = self.model.apply(
                 {"params": params, "cache": cache}, tokens[:, None],
                 positions[:, None], block_tables=tables,
-                mutable=["cache", "intermediates"] if load else ["cache"])
+                mutable=["cache", "intermediates"] if load else ["cache"],
+                **recurrent)
             nxt = self._sample_fn(key, logits[:, -1], temps)
             positions = jnp.where(
                 live, jnp.minimum(positions + 1,
@@ -708,7 +778,8 @@ class LLMEngine:
                     [steps.sum(), touched.sum()])])
         else:
             combined = block.T.reshape(-1)
-        return combined, (tokens, positions, temps, tables, rng), cache
+        return combined, (tokens, positions, temps, tables, rng,
+                          *entries), cache
 
     def _expert_load(self, intermediates, live):
         """One decode step's expert load over the rows that hold a
@@ -747,7 +818,8 @@ class LLMEngine:
         for bucket in buckets:
             # prefill is slotless: any wave size can occur
             for wave in _WAVE_SIZES:
-                packed = np.zeros((wave, bucket + 2), np.int32)
+                packed = np.zeros((wave, self.packed_width(bucket)),
+                                  np.int32)
                 packed[:, bucket] = 1
                 tables = jnp.zeros((wave, self.max_pages), jnp.int32)
                 _, self._cache = self._get_prefill_paged(bucket, wave)(
@@ -787,6 +859,19 @@ class LLMEngine:
                 t.join()
                 if "err" in out:
                     raise out["err"]
+
+    def packed_width(self, bucket: int) -> int:
+        """Columns of a prefill program's ``packed`` operand at
+        ``bucket`` (see ``_get_prefill_paged``)."""
+        return bucket + (3 if self._state_layers else 2)
+
+    def _refuse_handoff(self) -> None:
+        if self._state_layers:
+            raise ValueError(
+                "prefill handoff on a model with linear_attention "
+                "layers: a PrefillHandoff carries KV pages only, not the "
+                "request's recurrent state and convolution tail, so the "
+                "importer would decode from an empty state")
 
     def submit(self, prompt: List[int], *, max_new_tokens: int = 32,
                temperature: float = 0.0, eos_id: Optional[int] = None,
@@ -862,6 +947,7 @@ class LLMEngine:
         ``import_prefill`` on another engine admits straight into
         decode.  Loop-aware like ``submit`` (awaitable inside an event
         loop, blocking from a plain thread)."""
+        self._refuse_handoff()
         if len(prompt) == 0:
             raise ValueError("empty prompt")
         if len(prompt) > self.max_prompt_len:
@@ -893,6 +979,7 @@ class LLMEngine:
         ``import_queue_max`` is set and the wait queue is full — the
         signal for the router to re-queue against another replica."""
         h = handoff
+        self._refuse_handoff()
         if h.finish_reason is not None:
             raise ValueError("handoff already finished at its first "
                              "token; nothing to decode")
@@ -1031,6 +1118,9 @@ class LLMEngine:
                 "busy_slots": self.num_slots - len(self._free),
                 "free_pages": len(self._free_pages),
                 "pool_pages": self.kv_pool_pages,
+                "state_entries_in_use": max(
+                    0, self.state_entries - 1 - len(self._free_states)),
+                "state_entries": self.state_entries,
                 "prefix_pages_cached": self._prefix_pages_used,
                 "prefix_entries": len(self._prefix_entries),
             }
@@ -1195,6 +1285,8 @@ class LLMEngine:
                     if self._maybe_finish(i):
                         break     # rest of the row is junk past eos
                 self._count_decode_pages(pos0 + 1, sl.pos)
+                st.gdn_state_rows += (sl.pos - pos0) * self._state_layers
+            st.gdn_layer_steps += self.block_size * self._state_layers
             sp.set_metadata(tokens=st.step_tokens - tokens0,
                             finished=st.requests_completed - done0)
 
@@ -1222,7 +1314,7 @@ class LLMEngine:
         st = self.stats
         st.window_pages_skipped += skipped * windowed
         st.window_pages_read += (by_length - skipped) * windowed
-        st.decode_pages_read += (by_length * self.cfg.n_layers
+        st.decode_pages_read += (by_length * self._pool_layers
                                  - skipped * windowed)
 
     # ------------------------------------------------- prompt-prefix cache
@@ -1367,6 +1459,9 @@ class LLMEngine:
         offered to retention first, the rest return to the pool."""
         kept = self._prefix_retain(sl)
         self._free_pages.extend(sl.pages[max(kept, sl.borrowed):])
+        if sl.request.entry:
+            self._free_states.append(sl.request.entry)
+            sl.request.entry = 0
         if sl.prefix_entry is not None:
             with self._prefix_lock:
                 sl.prefix_entry.refs -= 1
@@ -1467,13 +1562,17 @@ class LLMEngine:
                         if fresh > len(self._free_pages):
                             self._prefix_reclaim(
                                 fresh - len(self._free_pages))
-                        if fresh > len(self._free_pages):
+                        if fresh > len(self._free_pages) or (
+                                self._state_layers
+                                and not self._free_states):
                             if hit is not None:
                                 with self._prefix_lock:
                                     hit[0].refs -= 1
                             break          # FIFO: no bypass, no starvation
                         req = self._pending.popleft()
                         req.admitted_at = now
+                        if self._state_layers:
+                            req.entry = self._free_states.pop()
                         pages = [self._free_pages.pop()
                                  for _ in range(fresh)]
                         if hit is not None:
@@ -1490,7 +1589,9 @@ class LLMEngine:
                     sp.set_metadata(
                         admitted=len(todo) + len(hits) + len(import_todo),
                         pending=len(self._pending),
-                        free_pages=len(self._free_pages))
+                        free_pages=len(self._free_pages),
+                        **({"free_states": len(self._free_states)}
+                           if self._state_layers else {}))
             for req in oversized:
                 self._safe_deliver(req, False, ValueError(
                     f"request needs {self._pages_needed(req)} KV pages; "
@@ -1544,6 +1645,8 @@ class LLMEngine:
                     self._free = list(range(self.num_slots))[::-1]
                     self._free_pages = list(
                         range(1, self.kv_pool_pages))[::-1]
+                    self._free_states = list(
+                        range(1, self.state_entries))[::-1]
                     self._stale_slots.clear()
                 self._prefix_reset()
                 inflight = None
@@ -1558,7 +1661,7 @@ class LLMEngine:
         first tokens are fetched later in the iteration."""
         out = []
         for bucket, chunk, wave in self._wave_chunks(todo):
-            packed = np.zeros((wave, bucket + 2), np.int32)
+            packed = np.zeros((wave, self.packed_width(bucket)), np.int32)
             packed[:, bucket] = 1
             tables = np.zeros((wave, self.max_pages), np.int32)
             metas = []
@@ -1566,6 +1669,8 @@ class LLMEngine:
                 packed[r, :len(req.prompt)] = req.prompt
                 packed[r, bucket] = len(req.prompt)
                 packed[r, bucket + 1] = int(req.temperature * 1e6)
+                if self._state_layers:
+                    packed[r, bucket + 2] = req.entry
                 tables[r, :len(pages)] = pages
                 metas.append((req, pages, tables[r].copy(), 0, None))
             firsts, self._cache = self._get_prefill_paged(
@@ -1786,7 +1891,7 @@ class LLMEngine:
         rows for stale slots, and dispatch one decode block.  Returns
         (combined_device, rows) or None when no slot is active."""
         A = self.num_slots
-        meta = np.zeros((3, A), np.int32)
+        meta = np.zeros((self._meta_rows, A), np.int32)
         meta[0, :] = A                                  # pad -> scratch
         lasts = np.zeros((A,), np.int32)
         tables = np.zeros((A, self.max_pages), np.int32)
@@ -1800,6 +1905,8 @@ class LLMEngine:
             meta[0, n] = slot
             meta[1, n] = sl.pos
             meta[2, n] = int(sl.request.temperature * 1e6)
+            if self._state_layers:
+                meta[3, n] = sl.request.entry
             lasts[n] = sl.last_token
             tables[n] = pf.table
             n += 1

@@ -49,18 +49,24 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _answer_tpu(patch):
+    """Every kernel module's platform question answers "tpu"."""
+    # import them all before patching any: a module first imported here
+    # would bind attention's PATCHED function as its own, and the undo
+    # would put that back for every later test of the process
+    mods = [importlib.import_module(f"ray_tpu.ops.{name}") for name in
+            ("attention", "flash_attention", "paged_attention", "moe",
+             "gated_delta")]
+    for mod in mods:
+        patch.setattr(mod, "backend_platform", lambda: "tpu")
+
+
 @pytest.fixture
 def on_tpu(monkeypatch):
     """Make kernel dispatch answer "tpu" (compiled Pallas, not the
     interpreter): the compile target is the described chip, while
     ``jax.devices()`` here is the CPU."""
-    # import them all before patching any: a module first imported here
-    # would bind attention's PATCHED function as its own, and the undo
-    # would put that back for every later test of the process
-    mods = [importlib.import_module(f"ray_tpu.ops.{name}") for name in
-            ("attention", "flash_attention", "paged_attention", "moe")]
-    for mod in mods:
-        monkeypatch.setattr(mod, "backend_platform", lambda: "tpu")
+    _answer_tpu(monkeypatch)
 
 
 def _shapes(tree, sharding):
@@ -241,9 +247,9 @@ _IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
              "fusion:dynamic-update-slice"}
 
 
-def _pool_result_producers(hlo: str, sizes):
+def _pool_result_producers(hlo: str, sizes, dtype: str = "bf16"):
     """Opcodes of the optimised HLO's instructions whose (array) result
-    holds one of ``sizes`` bf16 values, whatever its rank — the pool,
+    holds one of ``sizes`` values of ``dtype``, whatever its rank — the pool,
     one layer of it, or a reshaped view.  A fusion is named by its
     root: ``fusion:dynamic-update-slice`` is an update in place,
     ``fusion:copy`` or ``fusion:dynamic-slice`` moved the pool."""
@@ -251,7 +257,7 @@ def _pool_result_producers(hlo: str, sizes):
     import re
 
     inst = re.compile(
-        r"^\s*(ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* ([\w\-]+)\((.*)$")
+        rf"^\s*(ROOT )?%?([\w.\-]+) = {dtype}\[([\d,]+)\]\S* ([\w\-]+)\((.*)$")
     roots, found = {}, []
     comp = None
     for line in hlo.splitlines():
@@ -291,7 +297,8 @@ def _described_engine(cfg, monkeypatch, **kw):
         patch.setattr(
             generate, "init_decode_cache",
             lambda model, batch: jax.eval_shape(lambda: init(model, batch)))
-        return LLMEngine(cfg, params, num_slots=32, page_size=64, **kw)
+        return LLMEngine(cfg, params, **{"num_slots": 32, "page_size": 64,
+                                         **kw})
 
 
 def _smollm_engine(pages, monkeypatch):
@@ -407,3 +414,96 @@ def test_decode_block_reads_the_stacked_experts_in_place(topo, one_chip,
     assert temp < 0.05 * 2 * one_matrix, (
         f"{temp / 1e6:.1f} MB of temporaries; one matrix of a layer's "
         f"experts is {2 * one_matrix / 1e6:.1f} MB")
+
+
+# ---- a model whose state is pages AND recurrent entries (ISSUE 33) ----
+
+@pytest.fixture(scope="module")
+def olmo_hybrid_programs(topo, one_chip):
+    """``engine_decode_block`` and a prefill wave twice the largest
+    the serve-assist cell warms (8 x 2048 here; 4 x 2048 and 16 x 1024
+    there, all past 1 GiB of scores: ``_prefill_attend`` goes by groups
+    of rows), of Olmo-Hybrid-7B at the cell's
+    cut and server: 12 layers, 64 slots, 1408 pages, 73 state entries.
+    Compiled once for the tests below (some 40 s)."""
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("olmo-hybrid-7b", n_layers=12, max_seq_len=4096,
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    patch = pytest.MonkeyPatch()
+    _answer_tpu(patch)
+    try:
+        eng = _described_engine(cfg, patch, num_slots=64, max_seq_len=4096,
+                                max_prompt_len=2048, kv_pool_pages=1408)
+        block = eng._block_jit.lower(
+            *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                     one_chip))
+        bucket, wave = 2048, 8
+        prefill = eng._get_prefill_paged(bucket, wave).lower(
+            *_shapes((eng.params, eng._cache,
+                      jnp.zeros((wave, eng.packed_width(bucket)), jnp.int32),
+                      jnp.zeros((wave, eng.max_pages), jnp.int32),
+                      jax.random.PRNGKey(0)), one_chip))
+        return {"eng": eng, "block_text": block.as_text(),
+                "prefill_text": prefill.as_text(),
+                "engine_decode_block": block.compile(),
+                "engine_prefill": prefill.compile()}
+    finally:
+        patch.undo()
+
+
+def test_olmo_hybrid_engine_programs_compile_with_their_kernels(
+        olmo_hybrid_programs):
+    """Both programs compile for the described v5e; the decode block
+    holds ``gdn_decode`` once a linear layer of the scanned period and
+    the paged kernel once, under the names a trace shows."""
+    p = olmo_hybrid_programs
+    shapes = {k: v.shape for k, v in p["eng"]._cache.items()}
+    assert shapes == {"kv_pages": (3, 1408, 30, 64, 256),
+                      "gdn_state": (9, 73, 96, 5760),
+                      "gdn_conv": (9, 73, 270, 128)}
+    text = p["block_text"]
+    assert text.count('kernel_name = "gdn_decode"') == 3
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
+    assert "@jit_engine_decode_block" in text
+    assert "@jit_engine_prefill" in p["prefill_text"]
+    # the chunked prefill form is plain XLA: no kernel of its own yet
+    assert "gdn_" not in p["prefill_text"].replace("gdn_state", "").replace(
+        "gdn_conv", "")
+
+
+@pytest.mark.parametrize("name", ["engine_decode_block", "engine_prefill"])
+def test_olmo_hybrid_programs_address_pool_and_state_in_place(
+        olmo_hybrid_programs, name):
+    """Nothing but parameters and in-place updates produces a result of
+    the size of the KV pool, of the recurrent state leaf or of the
+    convolution-tail leaf, whole or one layer of it: neither is sliced,
+    relaid out, copied or written back (a ``[.., 3, channels]`` tail
+    leaf was: its minor pair is tiled to 16 rows, and merging the layer
+    and entry axes of ``[.., entries, 3 * channels]`` is a copy)."""
+    compiled = olmo_hybrid_programs[name]
+    hlo = compiled.as_text()
+    pool = 1408 * 30 * 64 * 256
+    state, tail = 73 * 96 * 5760, 73 * 270 * 128
+    for what, sizes, dtype in (("pool", (pool, 3 * pool), "bf16"),
+                               ("state", (state, 9 * state), "f32"),
+                               ("tail", (tail, 9 * tail), "bf16")):
+        made = _pool_result_producers(hlo, sizes, dtype)
+        assert set(made) <= _IN_PLACE, (
+            f"{name}: {what}-sized results from {dict(made)}")
+
+
+def test_olmo_hybrid_cell_fits_the_chip(olmo_hybrid_programs):
+    """12.2 GB resident (weights 6.54, pool 4.15, state entries 1.50),
+    and a prefill's temporaries (4.1 GB at 8 x 2048, twice the cell's
+    largest wave)
+    beside it under the 16.9 GB a v5e gives a process (it ran there:
+    PERF.md, PR 33); the decode block's temporaries are far under the
+    state entries of one layer."""
+    p = olmo_hybrid_programs
+    block = p["engine_decode_block"].memory_analysis()
+    prefill = p["engine_prefill"].memory_analysis()
+    assert 12.1e9 < block.argument_size_in_bytes < 12.3e9
+    assert block.temp_size_in_bytes < 0.3e9
+    assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
+            + block.temp_size_in_bytes) < 16.6e9
