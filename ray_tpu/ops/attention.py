@@ -4,8 +4,8 @@
   - ``"xla"``    — einsum attention; runs everywhere, materializes [Sq, Sk].
   - ``"flash"``  — Pallas TPU flash kernel (ray_tpu/ops/flash_attention.py);
                    O(S) memory, fused online softmax on the MXU.
-  - ``"splash"`` — JAX's public tuned TPU kernel (comparison impl; the
-    in-tree flash kernel measured faster at head_dim 64).
+  - ``"splash"`` — JAX's public tuned TPU kernel (comparison impl; not
+    timed by any benchmark cell).
   - ``"auto"``   — flash on TPU backends, xla elsewhere.
 
 Layout convention throughout the framework: ``q``: [batch, q_len, heads,
@@ -109,8 +109,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             from ray_tpu.ops.flash_attention import flash_attention
             return flash_attention(q, k, v, causal=causal,
                                    sm_scale=sm_scale)
-        # JAX's tuned public TPU kernel, kept as a comparison impl (the
-        # in-tree flash kernel measured faster at head_dim 64 — bench.py)
+        # JAX's tuned public TPU kernel, kept as a comparison impl
         from ray_tpu.ops.splash import splash_attention
         return splash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     if impl == "xla":

@@ -1,10 +1,51 @@
 """Flash attention as a Pallas TPU kernel (forward + backward).
 
-Blockwise online-softmax attention: O(S) memory, [block_q, block_k] tiles on
-the MXU, fp32 accumulators in VMEM, causal block skipping via dynamic loop
-bounds.  The reference framework has no attention kernel at all (its compute
-lives in torch user code — SURVEY.md §2.6); this is the framework-native hot
-op that Train/Serve model families build on.
+Blockwise online-softmax attention in three kernels, ``flash_fwd``,
+``flash_bwd_dq`` and ``flash_bwd_dkv``, that pay for the triangle a
+causal call needs.  Two levels of blocks:
+
+  - The GRID walks spans of the two sequences (up to 1024 positions
+    each): the reduction axis is innermost and ``"arbitrary"``, operands
+    arrive as ``(1, span, head_dim)`` blocks, running statistics and
+    accumulators live in VMEM scratch from the reduction's first span to
+    its last.  No operand is mapped whole, so VMEM holds a few spans
+    whatever the sequence length.  A span pair wholly above the diagonal
+    does no work (``pl.when``) and its index map names the diagonal's
+    span again, so no DMA is issued for it.
+  - INSIDE a span pair the kernel walks ``block_q x block_k`` tiles in a
+    loop that is unrolled when the kernel is traced: on a diagonal span
+    pair the tiles the mask covers whole are left out by plain
+    arithmetic, the ``iota``/compare/select mask is built only on the
+    tiles the diagonal crosses, and a span pair below the diagonal
+    builds none.  (One pair a grid step was tried first: a step's fixed
+    cost and its statistics outweigh a 256 x 256 tile's work; PERF.md
+    section 6, PR 34.)
+
+Every tile is computed TRANSPOSED, ``k @ q^T``: keys on sublanes,
+queries on lanes.  The softmax statistics, lse and delta are then
+lane-dense ``[1, block_q]`` rows, reductions over keys run down
+sublanes, and none of the second products needs a score tile
+transposed: the forward accumulates ``o^T += v^T @ p^T`` and dq
+``dq^T += k^T @ ds^T`` (``v^T`` / ``k^T`` made once a span, the
+``[head_dim, span]`` accumulator turned once at the end), dkv
+``dv += p^T @ dO`` and ``dk += ds^T @ q`` as they stand.  lse and delta
+travel as ``[batch*heads, 1, q_len]``: a trailing axis of 1 pads to 128
+lanes in HBM as in VMEM.
+
+Operands reach the MXU in the dtype they arrive in (bf16 stays bf16),
+products accumulate in float32; the probabilities and ``ds`` are cast to
+the operands' dtype before the second products.  ``m``, ``l``, lse,
+delta, the scores and the accumulators are float32.  The softmax scale
+is folded into the tile's resident operand (q; k in ``flash_bwd_dkv``)
+where that is exact — ``head_dim ** -0.5`` a power of two — and applied
+to the float32 scores otherwise.
+
+Spans and tiles come from ``plan_blocks``, a function of the sequence
+lengths (the probe that chose its targets read the same ones fastest at
+both head sizes and operand dtypes), which also returns the share of
+tile pairs a causal call visits.  A sequence that no
+lane-aligned block divides (ViT's 197) runs as one tile, the whole
+sequence.
 
 On the CPU backend the same kernels run under ``interpret=True`` so unit
 tests exercise the identical code path (SURVEY.md §4 device-simulation
@@ -14,7 +55,9 @@ strategy).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import itertools
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +69,10 @@ from ray_tpu.ops.attention import backend_platform
 _CompilerParams = pltpu.CompilerParams
 
 NEG_INF = -1e30
+LANES = 128
+
+# contract the last dim of both operands: a @ b^T, native on the MXU
+_NT = (((1,), (1,)), ((), ()))
 
 
 def _interpret() -> bool:
@@ -43,213 +90,404 @@ def _pick_block(seq: int, target: int) -> int:
 
 
 # --------------------------------------------------------------------------- #
+# The plan: spans, score tiles and the pairs a call visits                    #
+# --------------------------------------------------------------------------- #
+
+class Tiling(NamedTuple):
+    """One kernel's blocks.  The grid walks SPANS of the two sequences;
+    inside a span pair the kernel walks (q tile, kv tile) pairs of
+    ``block_q x block_k`` scores.  ``visited`` of ``total`` such pairs do
+    work: all of them, or under ``causal`` those the mask does not cover
+    whole."""
+    span_q: int
+    span_k: int
+    block_q: int
+    block_k: int
+    visited: int
+    total: int
+
+
+class FlashPlan(NamedTuple):
+    fwd: Tiling
+    dq: Tiling
+    dkv: Tiling
+
+
+def _aligned_block(seq: int, target: int, step: int = LANES) -> int:
+    """The largest multiple of ``step`` and of 128 up to ``target`` that
+    divides ``seq``; the whole sequence where there is none."""
+    step = math.lcm(step, LANES)
+    for b in range(min(target, seq) // step * step, 0, -step):
+        if seq % b == 0:
+            return b
+    return seq
+
+
+def _tiling(q_len: int, kv_len: int, block_q: int, block_k: int,
+            causal: bool) -> Tiling:
+    nq, nk = q_len // block_q, kv_len // block_k
+    if causal:
+        # one span for both sequences, so that a span pair is wholly
+        # above, on or wholly below the diagonal; a tile pair has an
+        # unmasked element iff its first column is not past its last row
+        span_q = span_k = _aligned_block(q_len, SPAN,
+                                         math.lcm(block_q, block_k))
+        visited = sum(min(nk, ((a + 1) * block_q - 1) // block_k + 1)
+                      for a in range(nq))
+    else:
+        span_q = _aligned_block(q_len, SPAN, block_q)
+        span_k = _aligned_block(kv_len, SPAN, block_k)
+        visited = nq * nk
+    return Tiling(span_q, span_k, block_q, block_k, visited, nq * nk)
+
+
+def plan_blocks(q_len: int, kv_len: int, causal: bool,
+                block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> FlashPlan:
+    """Spans and score tiles of the three kernels from what the call can
+    observe, and the share of tile pairs it visits.
+
+    The tiles are the largest lane-aligned divisors of the sequences up
+    to the targets below, the spans likewise up to ``SPAN``.  A probe of
+    the kernels alone on a v5e (PERF.md section 6, PR 34) read the same
+    targets fastest at ``head_dim`` 64 and 128, bf16 and float32,
+    sequences of 1,024 to 4,096, causal and not, so the lengths are all
+    the plan reads.  ``block_q`` / ``block_k`` given explicitly (tests)
+    are the score tile of all three kernels, cut to a divisor of the
+    sequence as before.
+    """
+    if block_q is not None or block_k is not None:
+        t = _tiling(q_len, kv_len, _pick_block(q_len, block_q or q_len),
+                    _pick_block(kv_len, block_k or kv_len), causal)
+        return FlashPlan(t, t, t)
+    return FlashPlan(*(
+        _tiling(q_len, kv_len, _aligned_block(q_len, tq),
+                _aligned_block(kv_len, tk), causal)
+        for tq, tk in _TARGETS))
+
+
+# a span's operands are whole in VMEM
+SPAN = 1024
+# (block_q, block_k) targets of fwd, dq, dkv
+_TARGETS = ((512, 512), (512, 512), (128, 128))
+
+
+# --------------------------------------------------------------------------- #
+# Shared pieces of the three bodies                                           #
+# --------------------------------------------------------------------------- #
+
+def _scale_is_exact(sm_scale: float) -> bool:
+    """A power of two scales any float operand without rounding."""
+    return sm_scale > 0 and math.frexp(sm_scale)[0] == 0.5
+
+
+def _tile_pairs(span_q: int, span_k: int, block_q: int, block_k: int,
+                diagonal: bool):
+    """The (q tile, kv tile, masked) of a span pair that do work, by plain
+    arithmetic when the kernel is traced: every pair of a span pair below
+    the diagonal; on a DIAGONAL span pair (same rows as columns) not the
+    tiles the causal mask covers whole, and ``masked`` on those the
+    diagonal crosses."""
+    return [(a, b, diagonal and b * block_k + block_k - 1 > a * block_q)
+            for a in range(span_q // block_q)
+            for b in range(span_k // block_k)
+            if not diagonal or b * block_k <= a * block_q + block_q - 1]
+
+
+def _strips(pairs, axis: int, sizes, join: bool):
+    """``_tile_pairs`` grouped by their q tile (``axis`` 0) or kv tile
+    (1): [(outer slice, [(inner slice, masked)])].  ``join`` makes one
+    strip of adjacent inner tiles of a kind, so an outer tile meets at
+    most one strip the diagonal crosses and one it does not."""
+    size, inner = sizes[axis], sizes[1 - axis]
+    out = []
+    for i, group in itertools.groupby(
+            sorted(pairs, key=lambda pair: pair[axis]),
+            key=lambda pair: pair[axis]):
+        strips = []
+        for _, run in itertools.groupby(
+                group, key=lambda pair: pair[2] if join else pair):
+            run = list(run)
+            strips.append((slice(run[0][1 - axis] * inner,
+                                 (run[-1][1 - axis] + 1) * inner),
+                           run[0][2]))
+        out.append((slice(i * size, (i + 1) * size), strips))
+    return out
+
+
+def _on_spans(causal: bool, spans: int, qi, ki, body):
+    """Run ``body(diagonal)`` for span pair (qi, ki) of a grid of ``spans``
+    a sequence: not at all above the diagonal, and a pair below it does
+    not build a mask (nor is traced where one span holds the sequence:
+    the kernels' text is what a process start pays for)."""
+    if not causal:
+        body(False)
+        return
+    pl.when(ki == qi)(lambda: body(True))
+    if spans > 1:
+        pl.when(ki < qi)(lambda: body(False))
+
+
+def _scores_t(k, q, rows: slice, cols: slice, masked: bool,
+              sm_scale: Optional[float]):
+    """The transposed score tile ``k @ q^T`` [keys, queries] in float32;
+    ``sm_scale`` None where an operand came scaled.  ``masked``: the tile
+    of a diagonal span pair whose first query is ``rows.start`` and first
+    key ``cols.start`` loses what lies above the diagonal."""
+    st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+    if sm_scale is not None:
+        st = st * sm_scale
+    if masked:
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                 - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0))
+        st = jnp.where(ahead >= cols.start - rows.start, st, NEG_INF)
+    return st
+
+
+def _last_span(causal: bool, qi):
+    """The last kv span a q span needs, under grid (bh, q span, kv span)."""
+    return qi if causal else pl.num_programs(2) - 1
+
+
+# --------------------------------------------------------------------------- #
 # Forward                                                                     #
 # --------------------------------------------------------------------------- #
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                sm_scale: float, causal: bool, block_k: int):
-    block_q = q_ref.shape[1]
-    kv_len = k_ref.shape[1]
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # [bq, d]
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                sm_scale: float, causal: bool, spans: int,
+                block_q: int, block_k: int):
+    span_q, span_k = q_ref.shape[1], k_ref.shape[1]
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    fold = _scale_is_exact(sm_scale)
 
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, q_ref.shape[2]), jnp.float32)
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    if causal:
-        # blocks strictly above the diagonal contribute nothing
-        num_kb = jnp.minimum(
-            (qi * block_q + block_q + block_k - 1) // block_k,
-            kv_len // block_k)
-    else:
-        num_kb = kv_len // block_k
+    def body(diagonal: bool):
+        pairs = _tile_pairs(span_q, span_k, block_q, block_k, diagonal)
+        vt = v_ref[0].T                                     # [d, span_k]
+        for rows, strips in _strips(pairs, 0, (block_q, block_k), True):
+            q = q_ref[0, rows, :]
+            if fold:
+                q = q * sm_scale
+            st = [_scores_t(k_ref[0, cols, :], q, rows, cols, masked,
+                            None if fold else sm_scale)
+                  for cols, masked in strips]
+            # ONE softmax update a q tile for all it sees of the span
+            m = m_ref[:, rows]
+            m_new = functools.reduce(
+                jnp.maximum, [jnp.max(x, axis=0, keepdims=True) for x in st],
+                m)
+            alpha = jnp.exp(m - m_new)
+            pt = [jnp.exp(x - m_new) for x in st]
+            l_ref[:, rows] = l_ref[:, rows] * alpha + sum(
+                jnp.sum(x, axis=0, keepdims=True) for x in pt)
+            acc_ref[:, rows] = acc_ref[:, rows] * alpha + sum(
+                jax.lax.dot(vt[:, cols], x.astype(vt.dtype),
+                            preferred_element_type=jnp.float32)
+                for x, (cols, _) in zip(pt, strips))
+            m_ref[:, rows] = m_new
 
-    def body(kb, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+    _on_spans(causal, spans, qi, ki, body)
 
-    m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l_safe))[:, None]
+    @pl.when(ki == _last_span(causal, qi))
+    def _():
+        l = l_ref[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / l_safe).T.astype(o_ref.dtype)
+        lse_ref[0] = m_ref[...] + jnp.log(l_safe)
 
 
-def _fwd(q3, k3, v3, causal: bool, sm_scale: float,
-         block_q: int, block_k: int, interpret: bool):
+def _specs(t: Tiling, d: int, causal: bool):
+    """Block specs under grid (bh, q span, kv span): q-side operands,
+    kv-side operands, [1, span_q] rows.  A kv span above the diagonal
+    names the diagonal's span again, so nothing is fetched for it."""
+    kv_span = (lambda i, j: jnp.minimum(j, i)) if causal else (lambda i, j: j)
+    return (pl.BlockSpec((1, t.span_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, t.span_k, d),
+                         lambda b, i, j: (b, kv_span(i, j), 0)),
+            pl.BlockSpec((1, 1, t.span_q), lambda b, i, j: (b, 0, i)))
+
+
+_SEMANTICS = _CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _fwd(q3, k3, v3, causal: bool, sm_scale: float, t: Tiling,
+         interpret: bool):
+    """-> o [bh, q_len, d], lse [bh, 1, q_len] float32."""
     bh, q_len, d = q3.shape
     kv_len = k3.shape[1]
-    grid = (bh, q_len // block_q)
-    o, lse = pl.pallas_call(
+    qspec, kspec, row = _specs(t, d, causal)
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, kv_len, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, kv_len, d), lambda i, j: (i, 0, 0)),
+                          spans=q_len // t.span_q,
+                          block_q=t.block_q, block_k=t.block_k),
+        grid=(bh, q_len // t.span_q, kv_len // t.span_k),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, row],
+        out_shape=[jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, q_len), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((1, t.span_q), jnp.float32),    # m
+            pltpu.VMEM((1, t.span_q), jnp.float32),    # l
+            pltpu.VMEM((d, t.span_q), jnp.float32),    # o^T
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, q_len, 1), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_SEMANTICS,
         interpret=interpret,
         name="flash_fwd",
     )(q3, k3, v3)
-    return o, lse
 
 
 # --------------------------------------------------------------------------- #
 # Backward                                                                    #
 # --------------------------------------------------------------------------- #
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   sm_scale: float, causal: bool, block_k: int):
-    block_q = q_ref.shape[1]
-    kv_len = k_ref.shape[1]
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, 0]
-    delta = delta_ref[0][:, 0]
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   acc_ref, *, sm_scale: float, causal: bool, spans: int,
+                   block_q: int, block_k: int):
+    span_q, span_k = q_ref.shape[1], k_ref.shape[1]
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    fold = _scale_is_exact(sm_scale)
 
-    if causal:
-        num_kb = jnp.minimum(
-            (qi * block_q + block_q + block_k - 1) // block_k,
-            kv_len // block_k)
-    else:
-        num_kb = kv_len // block_k
+    @pl.when(ki == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        return dq + jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+    def body(diagonal: bool):
+        pairs = _tile_pairs(span_q, span_k, block_q, block_k, diagonal)
+        kt = k_ref[0].T                                     # [d, span_k]
+        for rows, strips in _strips(pairs, 0, (block_q, block_k), True):
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            if fold:
+                q = q * sm_scale
+            lse, delta = lse_ref[0, :, rows], delta_ref[0, :, rows]
+            dqt = acc_ref[:, rows]
+            for cols, masked in strips:
+                st = _scores_t(k_ref[0, cols, :], q, rows, cols, masked,
+                               None if fold else sm_scale)
+                pt = jnp.exp(st - lse)
+                dpt = jax.lax.dot_general(v_ref[0, cols, :], do, _NT,
+                                          preferred_element_type=jnp.float32)
+                dst = pt * (dpt - delta)
+                dqt = dqt + jax.lax.dot(kt[:, cols], dst.astype(kt.dtype),
+                                        preferred_element_type=jnp.float32)
+            acc_ref[:, rows] = dqt
 
-    dq = jax.lax.fori_loop(
-        0, num_kb, body, jnp.zeros((block_q, q_ref.shape[2]), jnp.float32))
-    # q was pre-scaled; k inside the loop is unscaled, so dq is exact.
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    _on_spans(causal, spans, qi, ki, body)
+
+    @pl.when(ki == _last_span(causal, qi))
+    def _():
+        # ds was left unscaled and k is as it came: dq = scale * ds @ k
+        dq_ref[0] = (acc_ref[...] * sm_scale).T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *,
-                    sm_scale: float, causal: bool, block_q: int):
-    block_k = k_ref.shape[1]
-    q_len = q_ref.shape[1]
-    ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+                    dk_ref, dv_ref, dk_acc, dv_acc, *,
+                    sm_scale: float, causal: bool, spans: int,
+                    block_q: int, block_k: int):
+    """Grid (bh, kv span, q span): the kv side is resident."""
+    span_k, span_q = k_ref.shape[1], q_ref.shape[1]
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    fold = _scale_is_exact(sm_scale)
 
-    num_qb = q_len // block_q
-    start_qb = (ki * block_k) // block_q if causal else 0
+    @pl.when(qi == (ki if causal else 0))
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32) * sm_scale
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            rows = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])  # [bq, bk]
-        dv_new = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        # dk = sm_scale * ds^T @ q; q here is pre-scaled, so this is exact.
-        dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+    def body(diagonal: bool):
+        pairs = _tile_pairs(span_q, span_k, block_q, block_k, diagonal)
+        for cols, tiles in _strips(pairs, 1, (block_q, block_k), False):
+            k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+            if fold:
+                k = k * sm_scale
+            dk, dv = dk_acc[cols, :], dv_acc[cols, :]
+            for rows, masked in tiles:
+                q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+                st = _scores_t(k, q, rows, cols, masked,
+                               None if fold else sm_scale)
+                pt = jnp.exp(st - lse_ref[0, :, rows])
+                dv = dv + jax.lax.dot(pt.astype(do.dtype), do,
+                                      preferred_element_type=jnp.float32)
+                dpt = jax.lax.dot_general(v, do, _NT,
+                                          preferred_element_type=jnp.float32)
+                dst = pt * (dpt - delta_ref[0, :, rows])
+                dk = dk + jax.lax.dot(dst.astype(q.dtype), q,
+                                      preferred_element_type=jnp.float32)
+            dk_acc[cols, :], dv_acc[cols, :] = dk, dv
 
-    d = k_ref.shape[2]
-    dk, dv = jax.lax.fori_loop(
-        start_qb, num_qb, body,
-        (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d), jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    _on_spans(causal, spans, qi, ki, body)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _():
+        # q is as it came: dk = scale * ds^T @ q
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
-         block_q: int, block_k: int, interpret: bool):
+def _bwd_dq(q3, k3, v3, do3, lse, delta, causal: bool, sm_scale: float,
+            t: Tiling, interpret: bool):
     bh, q_len, d = q3.shape
     kv_len = k3.shape[1]
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-
-    qspec = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0))
-    full_q = pl.BlockSpec((1, q_len, d), lambda i, j: (i, 0, 0))
-    full_kv = pl.BlockSpec((1, kv_len, d), lambda i, j: (i, 0, 0))
-    vec_q = pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
-    full_vec_q = pl.BlockSpec((1, q_len, 1), lambda i, j: (i, 0, 0))
-
-    dq = pl.pallas_call(
+    qspec, kspec, row = _specs(t, d, causal)
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=block_k),
-        grid=(bh, q_len // block_q),
-        in_specs=[qspec, full_kv, full_kv, qspec, vec_q, vec_q],
+                          spans=q_len // t.span_q,
+                          block_q=t.block_q, block_k=t.block_k),
+        grid=(bh, q_len // t.span_q, kv_len // t.span_k),
+        in_specs=[qspec, kspec, kspec, qspec, row, row],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((d, t.span_q), jnp.float32)],   # dq^T
+        compiler_params=_SEMANTICS,
         interpret=interpret,
         name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse, delta)
 
-    kspec = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0))
-    dk, dv = pl.pallas_call(
+
+def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal: bool, sm_scale: float,
+             t: Tiling, interpret: bool):
+    bh, q_len, d = q3.shape
+    kv_len = k3.shape[1]
+    # under grid (bh, kv span, q span) a pair above the diagonal names
+    # the diagonal's q span, so nothing is fetched for it
+    q_span = (lambda i, j: jnp.maximum(i, j)) if causal else (lambda i, j: i)
+    qspec = pl.BlockSpec((1, t.span_q, d),
+                         lambda b, j, i: (b, q_span(i, j), 0))
+    row = pl.BlockSpec((1, 1, t.span_q),
+                       lambda b, j, i: (b, 0, q_span(i, j)))
+    kspec = pl.BlockSpec((1, t.span_k, d), lambda b, j, i: (b, j, 0))
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q),
-        grid=(bh, kv_len // block_k),
-        in_specs=[full_q, kspec, kspec, full_q, full_vec_q, full_vec_q],
+                          spans=q_len // t.span_q,
+                          block_q=t.block_q, block_k=t.block_k),
+        grid=(bh, kv_len // t.span_k, q_len // t.span_q),
+        in_specs=[qspec, kspec, kspec, qspec, row, row],
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((bh, kv_len, d), k3.dtype),
                    jax.ShapeDtypeStruct((bh, kv_len, d), v3.dtype)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[
+            pltpu.VMEM((t.span_k, d), jnp.float32),    # dk
+            pltpu.VMEM((t.span_k, d), jnp.float32),    # dv
+        ],
+        compiler_params=_SEMANTICS,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta)
+
+
+def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
+         plan: FlashPlan, interpret: bool):
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1)[:, None, :]                    # as lse
+    dq = _bwd_dq(q3, k3, v3, do3, lse, delta, causal, sm_scale, plan.dq,
+                 interpret)
+    dk, dv = _bwd_dkv(q3, k3, v3, do3, lse, delta, causal, sm_scale,
+                      plan.dkv, interpret)
     return dq, dk, dv
 
 
@@ -257,21 +495,20 @@ def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
 # custom-vjp wrapper                                                          #
 # --------------------------------------------------------------------------- #
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, causal, sm_scale, block_q, block_k, interpret):
-    o, _ = _fwd(q3, k3, v3, causal, sm_scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q3, k3, v3, causal, sm_scale, plan, interpret):
+    o, _ = _fwd(q3, k3, v3, causal, sm_scale, plan.fwd, interpret)
     return o
 
 
-def _flash_fwd(q3, k3, v3, causal, sm_scale, block_q, block_k, interpret):
-    o, lse = _fwd(q3, k3, v3, causal, sm_scale, block_q, block_k, interpret)
+def _flash_fwd(q3, k3, v3, causal, sm_scale, plan, interpret):
+    o, lse = _fwd(q3, k3, v3, causal, sm_scale, plan.fwd, interpret)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, do3):
+def _flash_bwd(causal, sm_scale, plan, interpret, res, do3):
     q3, k3, v3, o3, lse = res
-    return _bwd(q3, k3, v3, o3, lse, do3, causal, sm_scale,
-                block_q, block_k, interpret)
+    return _bwd(q3, k3, v3, o3, lse, do3, causal, sm_scale, plan, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -279,10 +516,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: Optional[float] = None,
-                    block_q: int = 1024, block_k: int = 1024,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Flash attention on [B, S, H, D] / [B, Sk, H, D] inputs (heads equal;
-    GQA expansion happens in ops.attention)."""
+    GQA expansion happens in ops.attention).  ``block_q`` / ``block_k``
+    override ``plan_blocks`` (tests); compiled for the chip they must be
+    multiples of 128 or the whole sequence."""
     b, q_len, h, d = q.shape
     kv_len = k.shape[1]
     if causal and q_len != kv_len:
@@ -291,13 +531,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             f"{q_len} vs {kv_len}); use ops.attention with q_offset for "
             "decode-style queries")
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    bq = _pick_block(q_len, block_q)
-    bk = _pick_block(kv_len, block_k)
+    plan = plan_blocks(q_len, kv_len, causal, block_q, block_k)
     if interpret is None:
         interpret = _interpret()
 
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
-    o3 = _flash(to3(q), to3(k), to3(v), causal, scale, bq, bk, bool(interpret))
+    o3 = _flash(to3(q), to3(k), to3(v), causal, float(scale), plan,
+                bool(interpret))
     return o3.reshape(b, h, q_len, d).transpose(0, 2, 1, 3)

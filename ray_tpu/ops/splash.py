@@ -1,10 +1,10 @@
 """Splash-attention wrapper: JAX's production TPU attention kernel.
 
-The hand-rolled Pallas kernel (ops/flash_attention.py) reaches ~59%
-hardware utilization on 1B-scale shapes; ``jax.experimental.pallas.ops
-.tpu.splash_attention`` is the heavily tuned public kernel (fused
-causal-grid skipping, tuned block sizes per generation) exposed here as
-``attention(..., impl="splash")``.  Layout adapter only — inputs stay
+``jax.experimental.pallas.ops.tpu.splash_attention`` is the public
+kernel (causal-grid skipping, block sizes per generation) exposed here
+as ``attention(..., impl="splash")``, a comparison impl beside the
+in-tree kernel (ops/flash_attention.py).  No benchmark cell runs it and
+no record bears a speed for it.  Layout adapter only — inputs stay
 [B, S, H, D] like every other impl.
 """
 

@@ -91,6 +91,31 @@ def test_flash_fwd_bwd_gpt_small_widths(topo, one_chip):
     lowered.compile()
 
 
+# the training cell's call (SmolLM2-360M, 16 x 1024), then what the
+# whole-sequence operands of the old backward refused from sequence 2048
+# (RESOURCE_EXHAUSTED): 4 x 4096 at heads of 64, 2 x 2048 at heads of 128
+@pytest.mark.parametrize("shape", [(16, 1024, 15, 64), (4, 4096, 15, 64),
+                                   (2, 2048, 28, 128)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_flash_fwd_bwd_compiles_in_blocks(topo, one_chip, shape):
+    from ray_tpu.ops.flash_attention import flash_attention, plan_blocks
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv)
+    hlo = lowered.as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in hlo, name
+    # several blocks a sequence, so causal skipping has pairs to skip
+    for t in plan_blocks(shape[1], shape[1], True):
+        assert t.visited < t.total
+    lowered.compile()
+
+
 # (rows, heads, kv heads, head_dim, table width, window operand, live
 # operand): gpt-small as before, then the two serving cells' decode
 # shapes (SmolLM2-360M: padded query, no window; SmallThinker: the page

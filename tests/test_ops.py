@@ -32,6 +32,161 @@ def test_flash_matches_xla_fwd_bwd(causal):
         np.testing.assert_allclose(a, b, atol=2e-4)
 
 
+def _flash_and_xla_with_grads(q, k, v, do, causal, **blocks):
+    """(o, dq, dk, dv) of the flash kernels, and of float32 XLA attention
+    on the same values."""
+    def run(f, *a):
+        o, vjp = jax.vjp(f, *a)
+        return (o,) + tuple(vjp(do.astype(o.dtype)))
+    got = run(lambda *a: flash_attention(*a, causal=causal, **blocks),
+              q, k, v)
+    want = run(lambda *a: xla_attention(*a, causal=causal),
+               *(x.astype(jnp.float32) for x in (q, k, v)))
+    return got, want
+
+
+# float32 operands keep float32 products: the tolerances of
+# test_flash_matches_xla_fwd_bwd.  bf16 operands: a bf16 rounding is
+# 2**-9 relative, and a result passes through three of them (the
+# operands' own, the probabilities or ds ahead of the second product, the
+# output's cast), each of a value no larger than the result's largest:
+# 2**-6 of the reference's largest magnitude is four such roundings.
+_F32_TOL = dict(o=2e-5, grad=2e-4)
+# (dtype, sequence, head_dim, causal, block keywords, span): ``span``
+# None is the module's own (1024: one span holds these sequences, tiles
+# are skipped and masked inside it); 128 makes the GRID walk several
+# spans, so whole span pairs are skipped and their index maps clamped
+_FLASH_CASES = {
+    # several tiles, block_q != block_k, both orders, causal and not
+    "f32-q64-k128-causal": (jnp.float32, 256, 32, True, dict(block_q=64, block_k=128), None),
+    "f32-q128-k64-causal": (jnp.float32, 256, 32, True, dict(block_q=128, block_k=64), None),
+    "f32-q64-k128-full": (jnp.float32, 256, 32, False, dict(block_q=64, block_k=128), None),
+    "f32-q128-k64-full": (jnp.float32, 256, 32, False, dict(block_q=128, block_k=64), None),
+    "f32-q64-k128-causal-spans": (jnp.float32, 384, 32, True, dict(block_q=64, block_k=128), 128),
+    "f32-q128-k64-causal-spans": (jnp.float32, 384, 32, True, dict(block_q=128, block_k=64), 128),
+    "f32-q64-k128-full-spans": (jnp.float32, 384, 32, False, dict(block_q=64, block_k=128), 128),
+    # the cell's operand dtype at its head_dim and at 128, blocks as
+    # plan_blocks gives them
+    "bf16-d64-causal": (jnp.bfloat16, 1024, 64, True, {}, None),
+    "bf16-d128-causal": (jnp.bfloat16, 512, 128, True, {}, None),
+    "bf16-d64-full": (jnp.bfloat16, 512, 64, False, {}, None),
+    "bf16-d64-causal-spans": (jnp.bfloat16, 512, 64, True, {}, 256),
+    # no lane-aligned block divides these: one tile, the whole sequence
+    "f32-197-full": (jnp.float32, 197, 32, False, {}, None),
+    "f32-200-causal": (jnp.float32, 200, 32, True, {}, None),
+}
+
+
+@pytest.fixture
+def flash_span(monkeypatch):
+    """Set the kernels' span (the grid's block) for one test."""
+    import importlib
+    mod = importlib.import_module("ray_tpu.ops.flash_attention")
+    return lambda span: monkeypatch.setattr(mod, "SPAN", span)
+
+
+@pytest.mark.parametrize("case", list(_FLASH_CASES))
+def test_flash_blocks_match_xla_fwd_bwd(case, flash_span):
+    dtype, S, D, causal, blocks, span = _FLASH_CASES[case]
+    if span:
+        flash_span(span)
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v, do = [jax.random.normal(kk, (1, S, 2, D), jnp.float32)
+                   .astype(dtype) for kk in keys]
+    got, want = _flash_and_xla_with_grads(q, k, v, do, causal, **blocks)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype
+        if dtype == jnp.float32:
+            atol = _F32_TOL["o" if name == "o" else "grad"]
+        else:
+            atol = 2.0 ** -6 * float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, atol=atol,
+                                   rtol=0, err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("span", [1024, 128], ids=["tiles", "spans"])
+def test_flash_causal_skipped_blocks_are_not_read(span, flash_span):
+    """What lies above the diagonal's blocks must not reach the result,
+    not even multiplied by a zero probability (0 * NaN is NaN).  Forward
+    and dq skip keys past a q block's last row; dkv skips queries before
+    a kv block's first column.  ``tiles``: the skipping inside one span;
+    ``spans``: the grid's, whole span pairs."""
+    flash_span(span)
+    S, D, blk, cut = 512, 32, 128, 256
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, do = [jax.random.normal(kk, (1, S, 2, D), jnp.float32)
+                   for kk in keys]
+
+    def run(q, k, v, do):
+        o, vjp = jax.vjp(lambda *a: flash_attention(
+            *a, causal=True, block_q=blk, block_k=blk), q, k, v)
+        return (o,) + tuple(vjp(do))
+
+    o, dq, dk, dv = run(q, k, v, do)
+    # keys and values from `cut` on are NaN: query rows before it never
+    # needed them
+    nan_tail = lambda x: x.at[:, cut:].set(jnp.nan)
+    o_n, dq_n, _, _ = run(q, nan_tail(k), nan_tail(v), do)
+    np.testing.assert_array_equal(o_n[:, :cut], o[:, :cut])
+    np.testing.assert_array_equal(dq_n[:, :cut], dq[:, :cut])
+    # queries and their cotangents before `cut` are NaN: key rows from it
+    # on never needed them
+    nan_head = lambda x: x.at[:, :cut].set(jnp.nan)
+    _, _, dk_n, dv_n = run(nan_head(q), k, v, nan_head(do))
+    np.testing.assert_array_equal(dk_n[:, cut:], dk[:, cut:])
+    np.testing.assert_array_equal(dv_n[:, cut:], dv[:, cut:])
+
+
+def _pairs_by_brute_force(q_len, kv_len, block_q, block_k, causal):
+    mask = (np.tril(np.ones((q_len, kv_len), bool)) if causal
+            else np.ones((q_len, kv_len), bool))
+    blocks = mask.reshape(q_len // block_q, block_q,
+                          kv_len // block_k, block_k)
+    return int(blocks.any(axis=(1, 3)).sum()), blocks.shape[0] * blocks.shape[2]
+
+
+# (q_len, kv_len, causal): the training cell's call, then a long
+# sequence, one that 512 does not divide, a ViT's and an odd length (no
+# lane-aligned block at all), and an encoder's rectangular call
+_PLAN_SHAPES = [
+    (1024, 1024, True),
+    (4096, 4096, True),
+    (1536, 1536, True),
+    (197, 197, False),
+    (1500, 1500, True),
+    (384, 640, False),
+]
+
+
+@pytest.mark.parametrize("shape", _PLAN_SHAPES,
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+def test_flash_plan_blocks_and_visited_share(shape):
+    from ray_tpu.ops.flash_attention import plan_blocks
+    q_len, kv_len, causal = shape
+    plan = plan_blocks(q_len, kv_len, causal)
+    for t in plan:
+        for seq, blocks in ((q_len, (t.block_q, t.span_q)),
+                            (kv_len, (t.block_k, t.span_k))):
+            for b in blocks:
+                assert b == seq or (b % 128 == 0 and seq % b == 0), (seq, b)
+        assert t.span_q % t.block_q == 0 and t.span_k % t.block_k == 0
+        assert (t.visited, t.total) == _pairs_by_brute_force(
+            q_len, kv_len, t.block_q, t.block_k, causal)
+    if q_len % 128:
+        assert all(t.total == 1 for t in plan)      # the whole sequence
+
+
+@pytest.mark.parametrize("block,pairs", [(1024, (1, 1)), (512, (3, 4)),
+                                         (256, (10, 16)), (128, (36, 64))])
+def test_flash_visited_share_of_the_training_cell(block, pairs):
+    """The counter of how often causal skipping engages is static: the
+    pairs of a [1024, 1024] causal call by block size."""
+    from ray_tpu.ops.flash_attention import plan_blocks
+    t = plan_blocks(1024, 1024, True, block, block).fwd
+    assert (t.visited, t.total) == pairs
+    assert pairs == _pairs_by_brute_force(1024, 1024, block, block, True)
+
+
 def test_gqa_repeat_kv():
     key = jax.random.PRNGKey(1)
     B, S, H, KvH, D = 1, 64, 8, 2, 16
