@@ -88,6 +88,38 @@ class TransformerConfig:
     # a full_attention block normalises each sub-layer's OUTPUT (x +
     # Norm(f(x)), the OLMo 2/3 block), not its input
     post_norm: bool = False
+    # Latent attention (MLA; models/gpt.py LatentAttention), on where
+    # kv_lora_rank is set: a token's cache row in a layer is ONE vector
+    # [c (kv_lora_rank, normed) | k_rope (qk_rope_head_dim, rotated)]
+    # shared by every head; a head's query is [nope | rope] of
+    # qk_nope_head_dim + qk_rope_head_dim (= head_dim, the softmax
+    # scale's), its value v_head_dim.  rope_interleave: the rotation
+    # pairs dims (2j, 2j+1), not (j, j + half).
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_interleave: bool = False
+    # The dropless router's scoring (ops/moe.py route_top_k): "softmax"
+    # over the chosen logits, or "sigmoid": scores sigmoid(logits), the
+    # choice by score + a learned selection bias, the gates the chosen
+    # scores alone, renormalised, times moe_route_scale.
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    # shared experts: one SwiGLU of moe_shared_experts * moe_d_ff beside
+    # the routed sum, every token through it
+    moe_shared_experts: int = 0
+    # the first first_dense_layers layers have a dense SwiGLU of d_ff
+    # where the others have experts (a prefix stack ahead of the scanned
+    # one, models/gpt.py GPT)
+    first_dense_layers: int = 0
+    # expert parallelism as ONE chip sees it: the router scores all
+    # moe_experts, this program holds moe_experts_held of them starting
+    # at moe_held_first and computes the pairs that chose those; what the
+    # absent experts would add is some other chip's to compute.  None:
+    # every expert is held
+    moe_experts_held: Optional[int] = None
+    moe_held_first: int = 0
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -101,6 +133,16 @@ class TransformerConfig:
             self.moe_d_ff = self.d_ff
         assert self.n_heads % self.n_kv_heads == 0
         assert self.moe_act in ("silu", "relu")
+        assert self.moe_scoring in ("softmax", "sigmoid")
+        if self.kv_lora_rank:
+            assert self.head_dim == (self.qk_nope_head_dim
+                                     + self.qk_rope_head_dim)
+            assert self.v_head_dim and not self.qk_norm
+            assert not self.layers_differ and not self.sliding_window
+        if self.moe_experts_held is not None:
+            assert self.moe_dropless and 0 <= self.moe_held_first \
+                <= self.moe_experts - self.moe_experts_held
+        assert 0 <= self.first_dense_layers <= self.n_layers
         for layout in (self.rope_layout, self.window_layout):
             assert layout is None or len(layout) >= self.n_layers
         if self.rope_layout is not None:
@@ -114,6 +156,7 @@ class TransformerConfig:
             assert set(self.layer_types) <= {"full_attention",
                                              "linear_attention"}
             assert not self.layers_differ and not self.moe_experts
+            assert not self.kv_lora_rank
             if self.period:
                 assert self.n_layers % len(self.period) == 0, (
                     "n_layers must be whole periods of layer_types")
@@ -142,7 +185,40 @@ class TransformerConfig:
         the layer stack then hands every block its layer index."""
         return self.rope_layout is not None or self.window_layout is not None
 
+    @property
+    def rope_dim(self) -> int:
+        """How many dims of a head the rotation turns."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def cache_row_width(self) -> int:
+        """The paged pool's minor dimension, a token's row in a layer and
+        a KV head: ``[k | v]`` of ``2*head_dim``, or the latent row ``[c
+        | k_rope]`` padded with zeros to whole lane tiles of 128 (576 ->
+        640: ops/paged_attention.py layout note)."""
+        if not self.kv_lora_rank:
+            return 2 * self.head_dim
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """KV heads of the paged pool: one where the row is latent."""
+        return 1 if self.kv_lora_rank else self.n_kv_heads
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return (self.moe_experts if self.moe_experts_held is None
+                else self.moe_experts_held)
+
     def _attn_params(self) -> int:
+        if self.kv_lora_rank:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            return (self.d_model * self.n_heads * self.head_dim
+                    + self.d_model * (r + rope) + r
+                    + r * self.n_heads * (self.qk_nope_head_dim
+                                          + self.v_head_dim)
+                    + self.n_heads * self.v_head_dim * self.d_model)
         qk = ((self.n_heads + self.n_kv_heads) * self.head_dim
               if self.qk_norm else 0)
         return self.d_model * self.head_dim * (
@@ -168,17 +244,25 @@ class TransformerConfig:
         return mixer + 3 * self.d_model * self.d_ff + 2 * self.d_model
 
     def num_params(self) -> int:
+        """Parameters this program holds (``experts_here`` routed experts
+        a layer, not all the router scores)."""
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
+        dense = 3 * self.d_model * self.d_ff
         if self.moe_experts:
-            mlp = self.moe_experts * 3 * self.d_model * self.moe_d_ff \
+            mlp = (self.experts_here + self.moe_shared_experts) * 3 \
+                * self.d_model * self.moe_d_ff \
                 + self.d_model * self.moe_experts            # + router
+            if self.moe_scoring == "sigmoid":
+                mlp += self.moe_experts                      # + its bias
         else:
-            mlp = 3 * self.d_model * self.d_ff
+            mlp = dense
         norms = 2 * self.d_model
         linear = self.layers_of("linear_attention")
         mixers = (self.n_layers - linear) * self._attn_params() + (
             linear and linear * self._linear_attn_params())
-        return emb + mixers + self.n_layers * (mlp + norms) + self.d_model
+        first = self.first_dense_layers
+        return (emb + mixers + first * dense + (self.n_layers - first) * mlp
+                + self.n_layers * norms + self.d_model)
 
     def train_flops_per_token(self, seq_len: int) -> float:
         """Operations a training step REQUIRES per token — the count
@@ -193,15 +277,22 @@ class TransformerConfig:
         remat, norms, rotary and softmax elementwise work.  A window
         is not taken off the attention term: it is an upper bound there."""
         attn = self._attn_params()
+        dense = 3 * self.d_model * self.d_ff
         if self.moe_experts:
-            mlp = 3 * self.d_model * self.moe_d_ff * self.moe_top_k \
+            mlp = 3 * self.d_model * self.moe_d_ff * (
+                self.moe_top_k + self.moe_shared_experts) \
                 + self.d_model * self.moe_experts            # + router
         else:
-            mlp = 3 * self.d_model * self.d_ff
+            mlp = dense
+        first = self.first_dense_layers
         head = self.vocab_size * self.d_model
-        attention = 6 * seq_len * self.n_heads * self.head_dim \
+        # QK^T over head_dim and PV over the value's (v_head_dim where
+        # they differ), forward and backward, the causal half
+        attention = 3 * seq_len * self.n_heads * (
+            self.head_dim + (self.v_head_dim or self.head_dim)) \
             * self.n_layers
-        return 6.0 * (self.n_layers * (attn + mlp) + head) + attention
+        return 6.0 * (self.n_layers * attn + first * dense
+                      + (self.n_layers - first) * mlp + head) + attention
 
 
 PRESETS = {
@@ -288,6 +379,29 @@ PRESETS = {
         linear_key_heads=4, linear_value_heads=4, linear_key_head_dim=8,
         linear_value_head_dim=32, linear_conv_kernel=4,
         linear_allow_neg_eigval=True),
+    # Kanana-2-30B-A3B-Instruct (kakaocorp, model_type deepseek_v3) as
+    # published: latent attention (32 heads, latent 512 + 64 rotated,
+    # queries 128 + 64, values 128, no q_lora), layer 0 a dense SwiGLU
+    # of 6144, then 47 layers of 128 sigmoid-routed experts of 768
+    # (top-6 by score + bias, renormalised, x 2.448) and two shared
+    "kanana-2-30b-a3b": TransformerConfig(
+        vocab_size=128256, d_model=2048, n_layers=48, n_heads=32,
+        n_kv_heads=32, head_dim=192, d_ff=6144, max_seq_len=32768,
+        rope_theta=1e6, norm_eps=1e-6, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_interleave=True, moe_experts=128, moe_top_k=6, moe_d_ff=768,
+        moe_dropless=True, moe_scoring="sigmoid", moe_route_scale=2.448,
+        moe_shared_experts=2, first_dense_layers=1),
+    # the same blocks at test size (tests/test_latent_attention.py): the
+    # latent row is 32 + 8 wide, one dense layer then three of 8 experts
+    "tiny-kanana": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=4, n_heads=4, n_kv_heads=4,
+        head_dim=24, d_ff=128, max_seq_len=256, dtype=jnp.float32,
+        remat=False, rope_theta=1e4, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+        moe_experts=8, moe_top_k=3, moe_d_ff=32, moe_dropless=True,
+        moe_scoring="sigmoid", moe_route_scale=2.448,
+        moe_shared_experts=2, first_dense_layers=1),
 }
 
 

@@ -80,13 +80,15 @@ class MLP(nn.Module):
     left the repo."""
 
     cfg: TransformerConfig
+    d_ff: Optional[int] = None             # None -> cfg.d_ff
 
     @nn.compact
     def __call__(self, y):
         cfg = self.cfg
-        gate = _dense(cfg.d_ff, ("embed", "mlp"), "w_gate",
+        d_ff = self.d_ff or cfg.d_ff
+        gate = _dense(d_ff, ("embed", "mlp"), "w_gate",
                       dtype=cfg.dtype, param_dtype=cfg.param_dtype)(y)
-        up = _dense(cfg.d_ff, ("embed", "mlp"), "w_up",
+        up = _dense(d_ff, ("embed", "mlp"), "w_up",
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(y)
         return _dense(cfg.d_model, ("mlp", "embed"), "w_down",
                       dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
@@ -195,24 +197,58 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
 
 # float32 scores ``[rows, heads, T, T]`` of a prefill wave beyond this
 # many bytes are computed a group of rows at a time (8 prompts of 2048
-# tokens at 30 heads are 4 GB of them, beside 12 GB of weights and pool)
+# tokens at 30 heads are 4 GB of them, beside 12 GB of weights and pool),
+# and where ONE row's pass it (32 heads at 8192 tokens are 8.6 GB) a
+# block of its queries at a time
 _PREFILL_SCORE_BYTES = 1 << 30
 
 
-def _prefill_attend(q, k, v):
+def _prefill_attend(q, k, v, sm_scale=None):
     """Causal attention of each row of a prefill wave over itself.
     ``xla_attention`` as it is where the wave's scores fit
     ``_PREFILL_SCORE_BYTES``; else the same over the largest groups of
-    rows that do, one after the other (``lax.map``)."""
-    b, t, h, _ = q.shape
-    group = max(1, min(b, _PREFILL_SCORE_BYTES // (4 * h * t * t)))
+    rows that do, one after the other (``lax.map``); and where ONE row's
+    do not, no scores at all: on the TPU the flash kernel
+    (``ops/flash_attention.py``: the masked half skipped; values
+    narrower than the keys ride zero-padded to their width), elsewhere
+    blocks of that row's queries, each against the keys up to its causal
+    edge (unrolled: a block's key span is static).  Materialised, such a
+    row's softmax fusions ran at a twentieth of the chip's bandwidth
+    (PERF.md section 6, PR 37)."""
+    b, t, h, d = q.shape
+    row_bytes = 4 * h * t * t
+    if row_bytes > _PREFILL_SCORE_BYTES:
+        from ray_tpu.ops.attention import attention, resolve_impl
+        if resolve_impl("auto") == "flash" and t % 128 == 0:
+            wide = jnp.pad(v, [(0, 0)] * 3 + [(0, d - v.shape[-1])])
+            return attention(q, k, wide, causal=True, sm_scale=sm_scale,
+                             impl="flash")[..., :v.shape[-1]]
+        blocks = 2
+        while row_bytes // blocks > _PREFILL_SCORE_BYTES and t % (
+                2 * blocks) == 0:
+            blocks *= 2
+        bq = t // blocks
+        pos = jnp.arange(t)
+
+        def row(qkv):
+            q, k, v = (a[None] for a in qkv)
+            return jnp.concatenate([
+                xla_attention(
+                    q[:, lo:lo + bq], k[:, :lo + bq], v[:, :lo + bq],
+                    causal=False, sm_scale=sm_scale,
+                    mask=(pos[None, :lo + bq] <= pos[lo:lo + bq, None]
+                          )[None, None])
+                for lo in range(0, t, bq)], axis=1)[0]
+        return jax.lax.map(row, (q, k, v))
+    group = max(1, min(b, _PREFILL_SCORE_BYTES // row_bytes))
     while b % group:
         group -= 1
     if group == b:
-        return xla_attention(q, k, v, causal=True)
+        return xla_attention(q, k, v, causal=True, sm_scale=sm_scale)
     split = lambda a: a.reshape(b // group, group, *a.shape[1:])  # noqa: E731
-    out = jax.lax.map(lambda qkv: xla_attention(*qkv, causal=True),
-                      (split(q), split(k), split(v)))
+    out = jax.lax.map(
+        lambda qkv: xla_attention(*qkv, causal=True, sm_scale=sm_scale),
+        (split(q), split(k), split(v)))
     return out.reshape(b, *out.shape[2:])
 
 
@@ -484,7 +520,64 @@ class Attention(nn.Module):
                              causal=False, mask=mask), pool
 
 
-class Block(nn.Module):
+def _rope_interleaved(x, cos, sin, positions=None):
+    """Rotation of the pairs ``(2j, 2j+1)``: the even dims are moved in
+    front of the odd ones and the halves rotated (``apply_rope``).  The
+    result is a fixed permutation of the in-place rotation's, the same
+    for queries and keys, so every score is what it would be."""
+    return apply_rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1),
+                      cos, sin, positions)
+
+
+def _widen(a, width: int):
+    """``a`` padded with zeros to ``width`` along its last axis."""
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - a.shape[-1])])
+
+
+def absorbed_attention(cfg: TransformerConfig, q, wkv_b, pool, block_tables,
+                       lengths, *, layer=0, live=None):
+    """One query a row over the latent rows its pages hold, ABSORBED
+    (``LatentAttention``): ``q [B, heads, dn + dr]`` rotated already,
+    ``wkv_b [r, heads, dn + dv]``, ``pool`` the stacked latent pool ->
+    ``[B, heads, dv]``.  ``q_lat = q_nope Wuk^T``; the paged kernel takes
+    ``[q_lat | q_rope | 0]`` against whole rows and returns ``sum_j p_j
+    c_j``; ``Wuv`` maps that to the head's value."""
+    from ray_tpu.ops.paged_attention import paged_attention
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkv_b[..., :dn])
+    o_lat = paged_attention(
+        _widen(jnp.concatenate([q_lat, q[..., dn:]], -1), pool.shape[-1]),
+        pool, block_tables, lengths, layer=layer, live=live,
+        sm_scale=cfg.head_dim ** -0.5, v_width=r)
+    return jnp.einsum("bhr,rhd->bhd", o_lat, wkv_b[..., dn:])
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA, no ``q_lora``).
+    With ``y`` the block's normed input::
+
+        q   = y Wq            heads x [q_nope dn | q_rope dr]
+        ckv = y Wkva          [c' r | k_rope' dr]
+        c   = RMSNorm(c');  k_rope = rope(k_rope'), ONE for all heads
+        [k_nope dn | v dv] = c Wkvb   a head
+        s = (q_nope . k_nope + rope(q_rope) . k_rope) / sqrt(dn + dr)
+
+    What a token leaves in the cache is ``[c | k_rope]`` (``r + dr``
+    values, after the norm and the rotation), the same bytes for keys
+    and values; nothing per head is stored.  Three paths:
+
+    - plain (training, a whole forward, the dense-cache decode of
+      ``Generator``) and paged PREFILL over a wave's own keys: EXPANDED,
+      keys and values a head from ``c Wkvb``;
+    - paged DECODE: ABSORBED.  ``q_lat = q_nope Wuk^T`` (``Wkvb`` split a
+      head into ``Wuk [r, dn]`` and ``Wuv [r, dv]``), scores ``[q_lat |
+      q_rope] . row``, ``o_lat = sum_j p_j c_j`` (the row's first ``r``),
+      ``o = o_lat Wuv``: the kernel sees ``heads`` queries on ONE KV head
+      whose keys are the whole row and whose values are its first ``r``.
+
+    The pool's row is ``cfg.cache_row_width`` wide, zeros past ``r +
+    dr`` (ops/paged_attention.py layout note)."""
+
     cfg: TransformerConfig
     mesh: Optional[Mesh] = None
     rules: ShardingRules = LOGICAL_RULES
@@ -493,12 +586,129 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
+                 pool=None, layer=None, live=None):
+        cfg = self.cfg
+        h, r = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        q = _dense((h, dn + dr), ("embed", "heads", "head_dim"), "wq",
+                   dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
+        ckv = _dense(r + dr, ("embed", "head_dim"), "wkv_a",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
+        wkv_b = self.param(
+            "wkv_b", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2)),
+                ("head_dim", "heads", "head_dim")),
+            (r, h, dn + dv), cfg.param_dtype).astype(cfg.dtype)
+        rope = _rope_interleaved if cfg.rope_interleave else apply_rope
+        c = RMSNorm(cfg.norm_eps, name="kv_norm")(ckv[..., :r])
+        k_rope = rope(ckv[..., None, r:], cos, sin, positions)[:, :, 0]
+        q = jnp.concatenate(
+            [q[..., :dn], rope(q[..., dn:], cos, sin, positions)], -1)
+
+        if pool is not None:
+            out, pool = self._decode_attend_paged(
+                q, c, k_rope, wkv_b, positions, block_tables, pool, layer,
+                live)
+        elif self.decode:
+            out = self._attend_cached(q, c, k_rope, wkv_b, positions)
+        else:
+            out = xla_attention(q, *self._expand(c, k_rope, wkv_b),
+                                causal=True)
+        out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
+            out.reshape(*out.shape[:2], h * dv))
+        return out if pool is None else (out, pool)
+
+    def _expand(self, c, k_rope, wkv_b):
+        """``(k [B, T, heads, dn + dr], v [B, T, heads, dv])`` of latent
+        rows ``c [B, T, r]``, ``k_rope [B, T, dr]``."""
+        dn = self.cfg.qk_nope_head_dim
+        kv = jnp.einsum("btr,rhe->bthe", c, wkv_b)
+        k_rope = jnp.broadcast_to(k_rope[:, :, None, :],
+                                  kv.shape[:3] + k_rope.shape[-1:])
+        return jnp.concatenate([kv[..., :dn], k_rope], -1), kv[..., dn:]
+
+    def _attend_cached(self, q, c, k_rope, wkv_b, positions):
+        """``Attention._decode_attend`` for latent rows: a dense cache
+        ``[B, max_seq_len, r + dr]`` written at per-row positions, every
+        cached row expanded a call (the plain path of ``Generator``;
+        serving decodes absorbed from the paged pool)."""
+        cfg = self.cfg
+        b, r = q.shape[0], cfg.kv_lora_rank
+        cache = self.variable("cache", "latent", jnp.zeros,
+                              (b, cfg.max_seq_len, r + k_rope.shape[-1]),
+                              cfg.dtype)
+        idx = self.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
+        if self.is_initializing():
+            return xla_attention(q, *self._expand(c, k_rope, wkv_b),
+                                 causal=True)
+        if positions is None:
+            positions = idx.value + jnp.broadcast_to(
+                jnp.arange(q.shape[1]), (b, q.shape[1]))
+        row = jnp.concatenate([c, k_rope], -1).astype(cfg.dtype)
+        cache.value = jax.vmap(
+            lambda rows, new, p: jax.lax.dynamic_update_slice(
+                rows, new, (p, 0)))(cache.value, row, positions[:, 0])
+        idx.value = jnp.max(positions) + 1
+        k, v = self._expand(cache.value[..., :r], cache.value[..., r:],
+                            wkv_b)
+        return xla_attention(
+            q, k, v, causal=False,
+            mask=window_mask(positions, jnp.arange(cfg.max_seq_len)))
+
+    def _decode_attend_paged(self, q, c, k_rope, wkv_b, positions,
+                             block_tables, pool, layer, live):
+        """``Attention._decode_attend_paged`` for latent rows: write this
+        call's rows ``[c | k_rope | 0]`` into the rows' pages of layer
+        ``layer``; a prompt (``T > 1``) then attends EXPANDED over its own
+        keys (no pool read), a decode step ABSORBED over the occupied
+        pages.  Returns ``(out [B, T, heads, dv], pool)``."""
+        cfg = self.cfg
+        if self.is_initializing():
+            return xla_attention(q, *self._expand(c, k_rope, wkv_b),
+                                 causal=True), pool
+        if positions is None or block_tables is None:
+            raise ValueError("paged decode requires positions and "
+                             "block_tables")
+        if self.prefix_attend:
+            raise ValueError(
+                "suffix prefill over a latent pool: LatentAttention has "
+                "no path that gathers a cached prefix's latent rows and "
+                "expands them (kv_b) beside the window's own")
+        from ray_tpu.ops.paged_attention import write_kv_pages
+        row = jnp.concatenate([c, k_rope], -1)
+        pool = write_kv_pages(
+            pool, _widen(row, pool.shape[-1])[:, :, None], block_tables,
+            positions, layer=layer)
+        if q.shape[1] > 1:
+            return _prefill_attend(q, *self._expand(c, k_rope, wkv_b)), pool
+        out = absorbed_attention(cfg, q[:, 0], wkv_b, pool, block_tables,
+                                 positions[:, 0] + 1, layer=layer, live=live)
+        return out[:, None], pool
+
+
+class Block(nn.Module):
+    cfg: TransformerConfig
+    mesh: Optional[Mesh] = None
+    rules: ShardingRules = LOGICAL_RULES
+    decode: bool = False
+    prefix_attend: bool = False
+    # a layer of the dense prefix of a model whose other layers have
+    # experts (cfg.first_dense_layers): its feed-forward is SwiGLU(d_ff)
+    dense_ffn: bool = False
+
+    @nn.compact
+    def __call__(self, x, cos, sin, positions=None, block_tables=None,
                  moe_stacked=None, pool=None, layer=None):
         """With a paged KV ``pool`` (see Attention) returns ``(x, pool)``:
         the shape ``stack_layers`` carries it through the stack in.
         ``moe_stacked``: the layer stack's whole dropless expert leaves
-        (GPT hands them down in decode; see ``DroplessMoE.__call__``)."""
+        (GPT hands them down in decode; see ``DroplessMoE.__call__``).
+        ``layer`` counts every layer (the pool's index); the stacked
+        experts' index starts after the dense prefix."""
         cfg = self.cfg
+        experts = cfg.moe_experts > 0 and not self.dense_ffn
         # a row whose table starts at the scratch page holds no request
         # (serve/llm_engine.py): neither the decode attention kernel nor
         # the expert kernel reads anything for it
@@ -508,16 +718,20 @@ class Block(nn.Module):
         mlp_norm = RMSNorm(cfg.norm_eps, name="mlp_norm")
         y = x if cfg.post_norm else attn_norm(x)
         moe = router_logits = None
-        if cfg.moe_experts > 0 and cfg.moe_dropless:
+        if experts and cfg.moe_dropless:
             from ray_tpu.ops.moe import DroplessMoE
             moe = DroplessMoE(cfg.d_model, cfg.moe_experts, cfg.moe_d_ff,
                               top_k=cfg.moe_top_k, act=cfg.moe_act,
                               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                              name="moe")
+                              scoring=cfg.moe_scoring,
+                              route_scale=cfg.moe_route_scale,
+                              held=cfg.moe_experts_held,
+                              held_first=cfg.moe_held_first, name="moe")
             if cfg.moe_router_pre_attn:
                 router_logits = moe.router_logits(y)
-        y = Attention(cfg, self.mesh, self.rules, self.decode,
-                      self.prefix_attend, name="attn")(
+        attn_cls = LatentAttention if cfg.kv_lora_rank else Attention
+        y = attn_cls(cfg, self.mesh, self.rules, self.decode,
+                     self.prefix_attend, name="attn")(
             y, cos, sin, positions, block_tables, pool, layer, live)
         if pool is not None:
             y, pool = y
@@ -527,8 +741,15 @@ class Block(nn.Module):
         x = x + y
         y = x if cfg.post_norm else mlp_norm(x)
         if moe is not None:
-            y = moe(y, router_logits, live, moe_stacked, layer)
-        elif cfg.moe_experts > 0:
+            routed = moe(y, router_logits, live, moe_stacked,
+                         None if layer is None
+                         else layer - cfg.first_dense_layers)
+            if cfg.moe_shared_experts:        # every token, beside the sum
+                routed = routed + MLP(
+                    cfg, cfg.moe_shared_experts * cfg.moe_d_ff,
+                    name="shared_mlp")(y)
+            y = routed
+        elif experts:
             from ray_tpu.ops.moe import MoEMLP
             y = MoEMLP(cfg.moe_experts, cfg.moe_d_ff, top_k=cfg.moe_top_k,
                        capacity_factor=cfg.moe_capacity_factor,
@@ -794,6 +1015,29 @@ class GPT(nn.Module):
         moe = nn.meta.unbox(self.variables["params"]["blocks"]["moe"])
         return moe["w_gate"], moe["w_up"], moe["w_down"]
 
+    def _stack_blocks(self, x, block_kwargs, call_args, **stack):
+        """``stack_layers`` of ``Block`` over every layer.  Where the
+        first ``cfg.first_dense_layers`` have a dense feed-forward and
+        the others experts, two stacks, as the ``remat_layers`` split
+        is two: ``dense_blocks`` (a parameter tree of its own: the
+        kinds differ in leaves, not in two scalars), then ``blocks``,
+        the scanned expert stack, whose layer indices go on from the
+        prefix's; a ``carry`` (the pool) rides through both."""
+        cfg = self.cfg
+        first = cfg.first_dense_layers
+        if not first:
+            return stack_layers(Block, cfg, block_kwargs, x, call_args,
+                                **stack)
+        carry = stack.pop("carry", None)
+        x = stack_layers(Block, cfg, dict(block_kwargs, dense_ffn=True), x,
+                         call_args, name="dense_blocks", n_layers=first,
+                         carry=carry, **stack)
+        if carry is not None:
+            x, carry = x
+        return stack_layers(Block, cfg, block_kwargs, x, call_args,
+                            n_layers=cfg.n_layers - first,
+                            first_layer=first, carry=carry, **stack)
+
     def _stack_periods(self, x, block_kwargs, call_args, lengths, entries,
                        remat):
         """The layer stack of a model whose ``layer_types`` name more
@@ -876,7 +1120,7 @@ class GPT(nn.Module):
                               self.rules)
         cos = sin = None
         if cfg.rope_theta is not None:
-            cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+            cos, sin = rope_frequencies(cfg.rope_dim, cfg.max_seq_len,
                                         cfg.rope_theta)
         if cos is not None and self.mesh is not None and not self.decode:
             # the rope tables are tiny closure constants: pin them
@@ -898,21 +1142,27 @@ class GPT(nn.Module):
             x = self._stack_periods(x, block_kwargs, call_args[:4], lengths,
                                     state_rows, do_remat)
         elif self.decode and self.paged_pages:
-            # the paged KV pool: ONE stacked leaf for the whole model,
-            # K in [..., :hd], V in [..., hd:] (layout dictated by TPU
-            # tiling, ops/paged_attention.py layout note).  It rides the
-            # layer stack as loop-carried state with a layer index, so
-            # each block writes its rows in place and no layer's pool is
-            # ever sliced out, relaid or written back.
+            # the paged pool: ONE stacked leaf for the whole model, its
+            # row what the model's attention caches of a token (K in
+            # [..., :hd], V in [..., hd:]; or one latent row for all
+            # heads: cfg.cache_row_width, ops/paged_attention.py layout
+            # note).  It rides the layer stack as loop-carried state
+            # with a layer index, so each block writes its rows in place
+            # and no layer's pool is ever sliced out, relaid or written
+            # back.
             ckv = self.variable(
                 "cache", "kv_pages", jnp.zeros,
-                (cfg.n_layers, self.paged_pages, cfg.n_kv_heads,
-                 self.page_size, 2 * cfg.head_dim), cfg.dtype)
-            x, pool = stack_layers(Block, cfg, block_kwargs, x, call_args,
-                                   remat=False, carry=ckv.value)
+                (cfg.n_layers, self.paged_pages, cfg.cache_kv_heads,
+                 self.page_size, cfg.cache_row_width), cfg.dtype)
+            x, pool = self._stack_blocks(x, block_kwargs, call_args,
+                                         remat=False, carry=ckv.value)
             if not self.is_initializing():
                 ckv.value = pool
         elif do_remat and 0 < n_remat < cfg.n_layers:
+            if cfg.first_dense_layers:
+                raise ValueError("remat_layers splits ONE stack; a model "
+                                 "with a dense prefix takes remat on or "
+                                 "off")
             # partial remat: the first n_remat layers recompute in the
             # backward pass, the tail stores activations (uses the HBM
             # headroom "policy" selection can't reach)
@@ -925,9 +1175,8 @@ class GPT(nn.Module):
                              n_layers=cfg.n_layers - n_remat,
                              first_layer=n_remat)
         else:
-            x = stack_layers(Block, cfg, block_kwargs, x,
-                             call_args, remat=do_remat,
-                             cache=True)
+            x = self._stack_blocks(x, block_kwargs, call_args,
+                                   remat=do_remat, cache=True)
 
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if return_hidden:

@@ -17,6 +17,8 @@ step collects and adds it (ray_tpu/train/step.py lm_loss_fn).
 
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -133,18 +135,36 @@ class MoEMLP(nn.Module):
 # rows chose (PERF.md, PR 27); elsewhere every expert is multiplied
 DENSE_PAIRS_MAX = 512
 
+# pairs above which the grouped formulation runs a chunk of tokens at a
+# time: its sorted copies of the pairs' rows are ``[pairs, d]`` each (a
+# prefill wave of 4 x 8192 tokens at top-6 is 197k pairs, 805 MB a copy
+# at d 2048)
+RAGGED_PAIRS_MAX = 1 << 17
+
 # the kernel's double-buffered blocks of the three matrices may take this
 # much VMEM before the expert width is tiled (a v5e core has 128 MiB;
 # SmallThinker's expert is 11.8 MB, 23.6 MB double-buffered)
 _KERNEL_WEIGHTS_VMEM = 40 << 20
 
 
-def route_top_k(logits: jax.Array, k: int):
+def route_top_k(logits: jax.Array, k: int, *, scoring: str = "softmax",
+                bias=None, scale: float = 1.0):
     """``(gates [N, k] float32, experts [N, k] int32)`` of float32 router
-    logits ``[N, E]``: the ``k`` largest, softmax over those (softmax over
-    all, top-k, renormalised, gives the same numbers)."""
-    top, idx = jax.lax.top_k(logits.astype(jnp.float32), k)
-    return jax.nn.softmax(top, axis=-1), idx
+    logits ``[N, E]``.  ``"softmax"``: the ``k`` largest, softmax over
+    those (softmax over all, top-k, renormalised, gives the same
+    numbers).  ``"sigmoid"``: scores ``s = sigmoid(logits)``; the choice
+    is the ``k`` largest of ``s + bias`` (``bias [E]``, a selection bias
+    that never reaches the gates); the gates are the chosen ``s``,
+    renormalised to sum to one, times ``scale``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        top, idx = jax.lax.top_k(logits, k)
+        gates = jax.nn.softmax(top, axis=-1)
+        return (gates if scale == 1.0 else gates * scale), idx
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    return top / (top.sum(-1, keepdims=True) + 1e-20) * scale, idx
 
 
 def touched_experts(experts: jax.Array, live, n_experts: int):
@@ -153,6 +173,8 @@ def touched_experts(experts: jax.Array, live, n_experts: int):
     ``experts [N, k]``, ascending, padded by repeating the last one.
     ``count`` is what ``LLMEngine._expert_load`` counts for the layer."""
     n, k = experts.shape
+    # an id outside 0..E-1 (a pair whose expert is held elsewhere)
+    # matches nothing
     chosen = experts[..., None] == jnp.arange(n_experts)       # [N, k, E]
     if live is not None:
         chosen = chosen & live[:, None, None]
@@ -263,7 +285,8 @@ def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
 
 
 def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
-                     act: str = "silu", live=None, layer=None) -> jax.Array:
+                     act: str = "silu", live=None, layer=None,
+                     partial: bool = False) -> jax.Array:
     """``sum_j gates[t, j] * down_e(act(gate_e x_t) * up_e x_t)`` with
     ``e = experts[t, j]``, for EVERY pair ``(t, j)``: no capacity, nothing
     dropped whatever the imbalance.
@@ -283,11 +306,24 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     gradient is defined through it: it is the decode path).  Above it,
     a sort by expert and three ``jax.lax.ragged_dot`` (on the TPU a
     grouped-matmul kernel that reads each touched expert once and
-    computes only the pairs' rows; ``live`` is not looked at)."""
+    computes only the pairs' rows; ``live`` is not looked at; above
+    ``RAGGED_PAIRS_MAX`` pairs, a chunk of tokens after the other).
+
+    ``experts`` index the ``E`` experts of the weights GIVEN.  With
+    ``partial`` an id may be ``E``: a pair whose expert is not among
+    them (``DroplessMoE.held``: it is some other chip's), dropped before
+    anything is read or multiplied for it (a scatter drops an index out
+    of range; the sort puts such pairs behind every group)."""
     n, d = x.shape
     e, f = w_gate.shape[-3], w_gate.shape[-1]
     k = experts.shape[1]
     fn = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    if n * k > RAGGED_PAIRS_MAX and n % 2 == 0:
+        halves = lambda a: a.reshape(2, n // 2, *a.shape[1:])  # noqa: E731
+        return jax.lax.map(
+            lambda xs: dropless_experts(*xs, w_gate, w_up, w_down, act=act,
+                                        layer=layer, partial=partial),
+            (halves(x), halves(gates), halves(experts))).reshape(n, d)
     kernel = layer is not None and expert_kernel_applies(n * k, d, f)
     if layer is not None and not kernel:
         w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
@@ -317,6 +353,8 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     out = jax.lax.ragged_dot(fn(gate_h) * up_h, w_down, sizes)
     out = out * jnp.take(gates.reshape(-1), order)[:, None].astype(
         out.dtype)
+    if partial:          # rows behind the last group belong to no expert
+        out = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None], out, 0)
     back = jnp.argsort(order)                           # pair -> row
     return jnp.take(out, back, axis=0).reshape(n, k, d).sum(axis=1)
 
@@ -327,7 +365,15 @@ class DroplessMoE(nn.Module):
     from another tensor than the experts read (``router_logits(h)`` with
     the attention's input, then ``__call__(m, logits)``); called with no
     logits it routes from its own input.  Sows ``expert_idx`` (``[B, S,
-    k]`` int32) into ``intermediates`` for whoever counts the load."""
+    k]`` int32) into ``intermediates`` for whoever counts the load.
+
+    ``held`` (None: all): this layer holds the weights of ``held``
+    experts, ids ``held_first .. held_first + held - 1`` of the
+    ``n_experts`` the router scores, as one chip of an expert-parallel
+    group does.  It routes over all ``n_experts`` and computes the pairs
+    that chose an expert it holds; the others' terms are left out of its
+    sum.  ``expert_idx`` then counts in the held experts' own numbering,
+    ``held`` for a pair that went elsewhere."""
 
     d_model: int
     n_experts: int
@@ -336,16 +382,28 @@ class DroplessMoE(nn.Module):
     act: str = "silu"
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
+    scoring: str = "softmax"           # route_top_k
+    route_scale: float = 1.0
+    held: Optional[int] = None
+    held_first: int = 0
 
     def setup(self):
-        e, d, f = self.n_experts, self.d_model, self.d_ff
+        d, f = self.d_model, self.d_ff
+        e = self.n_experts if self.held is None else self.held
         self.router = nn.DenseGeneral(
-            e, axis=-1, use_bias=False, dtype=jnp.float32,
+            self.n_experts, axis=-1, use_bias=False, dtype=jnp.float32,
             param_dtype=self.param_dtype,
             # the TPU multiplies float32 in bf16 passes unless told not to
             precision=jax.lax.Precision.HIGHEST,
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", None)))
+        if self.scoring == "sigmoid":
+            # drawn away from zero, so that folding it into the gates is
+            # a different function (a checkpoint's is learned)
+            self.select_bias = self.param(
+                "e_score_correction_bias", nn.with_logical_partitioning(
+                    nn.initializers.normal(0.1), (None,)),
+                (self.n_experts,), jnp.float32)
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         self.w_gate = self.param(
@@ -378,7 +436,15 @@ class DroplessMoE(nn.Module):
         b, s, d = x.shape
         if logits is None:
             logits = self.router_logits(x)
-        gates, experts = route_top_k(logits.reshape(b * s, -1), self.top_k)
+        gates, experts = route_top_k(
+            logits.reshape(b * s, -1), self.top_k, scoring=self.scoring,
+            bias=(self.select_bias if self.scoring == "sigmoid" else None),
+            scale=self.route_scale)
+        if self.held is not None:
+            experts = experts - self.held_first
+            here = (experts >= 0) & (experts < self.held)
+            experts = jnp.where(here, experts, self.held)
+            gates = jnp.where(here, gates, 0.0)
         self.sow("intermediates", "expert_idx",
                  experts.reshape(b, s, self.top_k))
         dt = self.dtype
@@ -394,5 +460,5 @@ class DroplessMoE(nn.Module):
         y = dropless_experts(
             x.reshape(b * s, d).astype(dt), gates, experts,
             *(w.astype(dt) for w in weights), act=self.act, live=live,
-            layer=layer)
+            layer=layer, partial=self.held is not None)
         return y.reshape(b, s, d).astype(x.dtype)

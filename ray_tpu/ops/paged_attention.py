@@ -11,9 +11,11 @@ linearly in ``max_seq_len``, and slot count was capped by
 
 Paged layout instead pools KV in fixed-size pages shared by all slots:
 
-  kv_pages:     [layers, num_pages, kv_heads, page_size, 2*head_dim]
-                (ONE stacked pool for the whole model; K in
-                [..., :head_dim], V in [..., head_dim:])
+  kv_pages:     [layers, num_pages, kv_heads, page_size, row]
+                (ONE stacked pool for the whole model; ``row`` is what
+                the model's attention caches of a token in a KV head:
+                ``2*head_dim``, K in [..., :head_dim], V in [...,
+                head_dim:]; or a LATENT row, below)
   block_tables: [rows, max_pages_per_seq] int32  (logical -> physical)
 
 A sequence at position ``p`` occupies ``ceil((p+1)/page_size)`` pages,
@@ -21,8 +23,23 @@ the same page ids in every layer.  The layout is dictated by TPU
 tiling: Mosaic DMAs slice memrefs in (8, 128) tiles, so the page's
 minor dim must be a multiple of 128 — ``2*head_dim`` is exactly that
 for the common head_dims (64, 128, 256), and fusing K and V makes a
-page one DMA instead of two.  kv_heads sits outside (page_size,
-2*head_dim) so per-head views are tile-aligned.
+page one DMA instead of two.  kv_heads sits outside (page_size, row) so
+per-head views are tile-aligned.
+
+LATENT ROWS (models/gpt.py LatentAttention).  A latent-attention model
+caches ONE vector a token for all heads, ``[c | k_rope]`` (512 + 64 at
+the DeepSeek-V3 sizes): ``kv_heads`` is 1, a key is the whole row and
+the value its first ``v_width`` (``c``): the same bytes.  576 is 4.5
+lane tiles, so the row is PADDED with zeros to 640: one leaf, one DMA a
+page, every function here unchanged but for which columns the two
+products read (``v_width``); the zero columns add nothing to a score.
+The price is 11% more bytes a page than the model's 1,152 B a token (a
+share of the HBM roofline of 0.9 at best).  The other layout, two
+leaves ``[.., page, 512]`` and the 64 rotated dims packed two tokens a
+lane tile, reads no padding and pays a second DMA a page, a second
+pool leaf through every engine program and a second product a chunk;
+it was not built (PERF.md section 6, PR 37, has the measurement this
+one was kept on).
 
 ADDRESSING.  The pool is addressed, never sliced: a reader names
 ``[layer, page]`` (the kernel's DMA source, the oracle's gather index),
@@ -71,8 +88,8 @@ def write_kv_pages(pool: jax.Array, kv: jax.Array,
     """Write a call's fused K/V into layer ``layer`` of the pool, in
     place when the pool is loop-carried and donated; returns the pool.
 
-    pool:      [layers, num_pages, kv_heads, page_size, 2*head_dim]
-    kv:        [rows, T, kv_heads, 2*head_dim]
+    pool:      [layers, num_pages, kv_heads, page_size, row]
+    kv:        [rows, T, kv_heads, row]  (``[k | v]``, or a latent row)
     positions: [rows, T] absolute positions, contiguous along T; a T > 1
                window must start on a multiple of ``gcd(T, page_size)``
                (the engine's windows start on page boundaries)
@@ -135,11 +152,12 @@ def gather_kv_pages(kv_pages: jax.Array, block_tables: jax.Array, *,
 def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
                         block_tables: jax.Array, lengths: jax.Array, *,
                         layer=0, window=None, live=None,
-                        sm_scale: Optional[float] = None) -> jax.Array:
+                        sm_scale: Optional[float] = None,
+                        v_width: Optional[int] = None) -> jax.Array:
     """Gather-based paged decode attention (one query token per row).
 
-    q:            [rows, heads, head_dim]
-    kv_pages:     [layers, num_pages, kv_heads, page_size, 2*head_dim]
+    q:            [rows, heads, head_dim]  (latent: [rows, heads, row])
+    kv_pages:     [layers, num_pages, kv_heads, page_size, row]
     block_tables: [rows, max_pages] physical page ids, position-ordered
     lengths:      [rows] number of valid positions (current pos + 1)
     layer:        which layer's pages to read (int or traced scalar)
@@ -147,7 +165,10 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
                   ``window`` of the ``lengths`` positions are visible
     live:         None (every row is read), or [rows] bool: a row it
                   leaves out comes back as zeros
-    returns       [rows, heads, head_dim]
+    v_width:      None: the row is ``[k | v]`` halves.  An int: a latent
+                  row (module docstring): keys the whole row, values its
+                  first ``v_width`` columns
+    returns       [rows, heads, head_dim]  (latent: [rows, heads, v_width])
     """
     hd = q.shape[-1]
     kv = gather_kv_pages(kv_pages, block_tables, layer=layer)
@@ -155,7 +176,9 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
     mask = pos < lengths[:, None]
     if window is not None:
         mask = mask & (pos >= lengths[:, None] - window)
-    out = xla_attention(q[:, None], kv[..., :hd], kv[..., hd:],
+    k, v = ((kv[..., :hd], kv[..., hd:]) if v_width is None
+            else (kv, kv[..., :v_width]))
+    out = xla_attention(q[:, None], k, v,
                         causal=False, mask=mask, sm_scale=sm_scale)[:, 0]
     if live is not None:
         out = jnp.where(live[:, None, None], out, jnp.zeros_like(out))
@@ -169,6 +192,10 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
 # are all of VMEM); and the buffers of that size, the one computed on
 # and the ones in flight (a third buffer bought 4% at most)
 _CHUNK_TOKENS = 512
+# a latent page's chunk (one KV head, 32 query heads a product): on a
+# v5e 24 rows of 5,000 positions ran in 267 us at 512 a chunk and 246 at
+# 1024, of 9,000 in 459 and 404 (PERF.md, PR 37)
+_LATENT_CHUNK_TOKENS = 1024
 _CHUNK_BYTES = 2 << 20
 _PIPELINE_DEPTH = 2
 
@@ -177,7 +204,8 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                 block_tables: jax.Array, lengths: jax.Array,
                 layer: jax.Array, sm_scale: float,
                 window: Optional[jax.Array] = None,
-                live: Optional[jax.Array] = None) -> jax.Array:
+                live: Optional[jax.Array] = None,
+                v_width: Optional[int] = None) -> jax.Array:
     """Pallas TPU decode kernel: ONE invocation walks the flat list of
     (live row, chunk of occupied pages) work with a page pipeline that
     never drains between rows.
@@ -217,7 +245,11 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
     ``qw == 2*head_dim`` (the caller zero-padded the query): scores
     contract against the whole page (the zero half makes them K-only),
     ``p @ page`` leaves the output in the V half, and the caller slices
-    it out — no sub-tile slicing in the kernel.
+    it out — no sub-tile slicing in the kernel.  ``v_width`` (a latent
+    row, ``qw`` the row's width): scores against the whole page, ``p``
+    against its first ``v_width`` columns (whole lane tiles), the output
+    ``[rows, heads, v_width]``: ``kvh`` is 1 and all ``heads`` queries
+    of a row ride one product.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -227,12 +259,18 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
     g = heads // kvh
     depth = _PIPELINE_DEPTH
     page_bytes = kvh * ps * hd2 * kv_pages.dtype.itemsize
-    cp = max(1, min(_CHUNK_TOKENS // ps, _CHUNK_BYTES // page_bytes))
+    chunk_tokens = _CHUNK_TOKENS if v_width is None else _LATENT_CHUNK_TOKENS
+    cp = max(1, min(chunk_tokens // ps, _CHUNK_BYTES // page_bytes))
     windowed, masked = window is not None, live is not None
     n_prefetch = 3 + windowed + masked
     # the page's K and V parts as the two products see them
-    k_cols, v_cols = ((slice(0, qw), slice(qw, hd2)) if qw != hd2
-                      else (slice(None), slice(None)))
+    if v_width is not None:
+        k_cols, v_cols = slice(None), slice(0, v_width)
+    elif qw != hd2:
+        k_cols, v_cols = slice(0, qw), slice(qw, hd2)
+    else:
+        k_cols, v_cols = slice(None), slice(None)
+    vw = v_width or qw                  # the output's width
 
     def kernel(*refs):
         tables_ref, len_ref, layer_ref = refs[:3]
@@ -301,7 +339,7 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                   for h in range(kvh)]
             stats = tuple((jnp.full((g, 1), -1e30, jnp.float32),
                            jnp.zeros((g, 1), jnp.float32),
-                           jnp.zeros((g, qw), jnp.float32))
+                           jnp.zeros((g, vw), jnp.float32))
                           for _ in range(kvh))
 
             def chunk(t, carry):
@@ -329,7 +367,7 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                     pv = jax.lax.dot_general(
                         p.astype(kvbuf.dtype), kv[:, v_cols],
                         (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)  # [g, qw]
+                        preferred_element_type=jnp.float32)  # [g, vw]
                     new.append((m_new,
                                 l_prev * alpha
                                 + jnp.sum(p, axis=1, keepdims=True),
@@ -356,7 +394,7 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((depth, cp, kvh, ps, hd2), kv_pages.dtype),
-            pltpu.VMEM((heads, qw), jnp.float32),   # one row's output
+            pltpu.VMEM((heads, vw), jnp.float32),   # one row's output
             pltpu.SMEM((rows,), jnp.int32),         # rows to read
             pltpu.SMEM((rows,), jnp.int32),         # their first page
             pltpu.SMEM((rows,), jnp.int32),         # the page they end at
@@ -366,7 +404,7 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, heads, qw), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, heads, vw), q.dtype),
         name="paged_attention_decode",
     )(block_tables, lengths, layer, *([window] if windowed else []),
       *([live] if masked else []), q, kv_pages)
@@ -374,12 +412,14 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
 
 def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
                         window=None, live=None,
-                        sm_scale: Optional[float] = None) -> jax.Array:
+                        sm_scale: Optional[float] = None,
+                        v_width: Optional[int] = None) -> jax.Array:
     hd = q.shape[-1]
     scale = sm_scale if sm_scale is not None else hd ** -0.5
     # a half of the page is whole lane tiles, or the query is padded
-    # with zeros to the page's width (see _tpu_kernel)
-    split = hd % 128 == 0
+    # with zeros to the page's width (see _tpu_kernel); a latent row's
+    # query comes at the page's width
+    split = hd % 128 == 0 or v_width is not None
     if not split:
         q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
     if window is not None:
@@ -388,13 +428,16 @@ def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
         live = live.astype(jnp.int32)
     out = _tpu_kernel(q, kv_pages, block_tables, lengths.astype(jnp.int32),
                       jnp.asarray(layer, jnp.int32).reshape(1), scale,
-                      window, live)
+                      window, live, v_width)
     return out if split else out[..., hd:]
 
 
-def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
+def resolve_paged_impl(kv_minor: int, impl: str = "auto",
+                       v_width: Optional[int] = None) -> str:
     """Which implementation ``paged_attention`` runs for a pool whose
-    minor dim (``2*head_dim``) is ``kv_minor``: ``"tpu"`` or ``"xla"``.
+    minor dim (a token's row: ``2*head_dim``, or a latent row padded to
+    whole lane tiles, of which the values are the first ``v_width``) is
+    ``kv_minor``: ``"tpu"`` or ``"xla"``.
 
     Only ``"auto"`` may settle for the XLA gather — off the TPU, or when
     the page is not lane-aligned (Mosaic DMA slices need a minor dim
@@ -409,13 +452,14 @@ def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
     import os
     if impl == "auto":
         impl = os.environ.get("RAY_TPU_PAGED_ATTENTION_IMPL", "auto")
+    aligned = kv_minor % 128 == 0 and (v_width or 0) % 128 == 0
     if impl == "auto":
-        aligned = kv_minor % 128 == 0
         return "tpu" if aligned and backend_platform() == "tpu" else "xla"
-    if impl == "tpu" and kv_minor % 128:
+    if impl == "tpu" and not aligned:
         raise ValueError(
-            f"paged_attention impl='tpu' needs 2*head_dim % 128 == 0 "
-            f"(got {kv_minor}); use impl='auto' or 'xla' for this shape")
+            f"paged_attention impl='tpu' needs a pool row of whole lane "
+            f"tiles, 2*head_dim % 128 == 0 (got {kv_minor}, values "
+            f"{v_width}); use impl='auto' or 'xla' for this shape")
     if impl not in ("tpu", "xla"):
         raise ValueError(f"unknown paged attention impl: {impl!r}")
     return impl
@@ -424,6 +468,7 @@ def resolve_paged_impl(kv_minor: int, impl: str = "auto") -> str:
 def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
                     window=None, live=None,
                     sm_scale: Optional[float] = None,
+                    v_width: Optional[int] = None,
                     impl: str = "auto") -> jax.Array:
     """Backend-dispatched paged decode attention over layer ``layer`` of
     the stacked pool (see module docstring and
@@ -435,8 +480,9 @@ def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
     serving engine's rows are live where ``block_tables[:, 0] != 0``
     (page 0 is scratch; models/gpt.py ``Block`` derives the mask once
     for this kernel and the expert kernel).  ``None``: every row is
-    read."""
-    impl = resolve_paged_impl(kv_pages.shape[-1], impl)
+    read.  ``v_width``: the pool holds latent rows (module docstring);
+    the kernel then wants ``v_width`` in whole lane tiles too."""
+    impl = resolve_paged_impl(kv_pages.shape[-1], impl, v_width)
     fn = paged_attention_tpu if impl == "tpu" else paged_attention_xla
     return fn(q, kv_pages, block_tables, lengths, layer=layer,
-              window=window, live=live, sm_scale=sm_scale)
+              window=window, live=live, sm_scale=sm_scale, v_width=v_width)

@@ -163,6 +163,7 @@ class LLMServer:
                  import_retry_s: float = 5.0,
                  import_queue_max: Optional[int] = None,
                  prefix_cache_pages: Optional[int] = None,
+                 prefill_wave_tokens: Optional[int] = None,
                  _upstream: Any = None,
                  config_overrides: Optional[Dict[str, Any]] = None):
         from ray_tpu.models.configs import get_config
@@ -198,7 +199,8 @@ class LLMServer:
                                 page_size=page_size,
                                 kv_pool_pages=kv_pool_pages,
                                 import_queue_max=import_queue_max,
-                                prefix_cache_pages=prefix_cache_pages)
+                                prefix_cache_pages=prefix_cache_pages,
+                                prefill_wave_tokens=prefill_wave_tokens)
         # exported handoff objects are owned by THIS replica: freeing
         # the last owner-side ref frees the object, so each ref is
         # pinned for a TTL comfortably beyond any decode retry deadline
@@ -464,9 +466,11 @@ class LLMServer:
         the device: platform/kind/count, which paged-decode kernel the
         engine's pool resolves to, and the compile clock so far."""
         from ray_tpu.ops.paged_attention import resolve_paged_impl
+        cfg = self.engine.cfg
         return {
             **process_facts(self._compile_clock),
-            "paged_impl": resolve_paged_impl(2 * self.engine.cfg.head_dim),
+            "paged_impl": resolve_paged_impl(
+                cfg.cache_row_width, v_width=cfg.kv_lora_rank),
         }
 
     def advertised_prefixes(self) -> Optional[Dict[str, Any]]:

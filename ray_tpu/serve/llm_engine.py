@@ -16,9 +16,12 @@ iteration's prefills):
 
   - The pool.  K/V lives in a shared page pool addressed through
     per-row block tables (ops/paged_attention.py): ONE stacked cache
-    leaf ``kv_pages`` [layers, pool_pages, kv_heads, page_size,
-    2*head_dim], declared by the model (models/gpt.py GPT) and chained,
-    donated, through every engine program.  It rides the model's layer
+    leaf ``kv_pages`` [layers, pool_pages, kv_heads, page_size, row],
+    declared by the model (models/gpt.py GPT) and chained, donated,
+    through every engine program.  ``row`` is what the model's
+    attention caches of a token: ``2*head_dim`` (K|V), or ONE latent
+    row for all heads (``cfg.cache_row_width``, ``kv_heads`` 1); the
+    engine reads the shape off the leaf and is otherwise indifferent.  It rides the model's layer
     scan and the block's step scan as loop-carried state; a layer
     writes its rows at ``[layer, page, :, offset]`` and the kernel reads
     ``[layer, page]`` (``write_kv_pages`` / the DMA source).  Nobody
@@ -149,8 +152,8 @@ class PrefillHandoff:
 
     ``kv`` is the request's occupied pool pages gathered into ONE
     contiguous host array ``[n_pool_leaves, npages, kv_heads,
-    page_size, 2*head_dim]`` (K/V fused exactly as the pool stores
-    them, ops/paged_attention.py layout) — one ``jax.device_get``
+    page_size, row]`` (rows exactly as the pool stores them, K|V fused
+    or latent: ops/paged_attention.py layout) — one ``jax.device_get``
     round-trip on export, one ``device_put`` + page-table remap on
     import.  Only ``ceil(prompt_len / page_size)`` pages ship: the
     first generated token's K/V is written by the importer's first
@@ -304,6 +307,13 @@ class EngineStats:
         # once a row a block, as the page counts are
         self.gdn_layer_steps = 0
         self.gdn_state_rows = 0
+        # latent-attention layers (a latent pool): a layer step is one
+        # such layer in one decode step; mla_context_tokens sums over
+        # them the cached positions the absorbed kernel read for rows
+        # whose token was delivered (a step at position p reads p + 1
+        # rows).  Host arithmetic, once a row a block
+        self.mla_layer_steps = 0
+        self.mla_context_tokens = 0
         # seconds of the loop thread, advanced at each phase's end
         # (_Phase): loop_s is its whole life, the rest are parts of it.
         # 1 - fetch_wait_s / (loop_s - idle_wait_s) is the share of its
@@ -345,6 +355,8 @@ class EngineStats:
             "window_pages_skipped": self.window_pages_skipped,
             "gdn_layer_steps": self.gdn_layer_steps,
             "gdn_state_rows": self.gdn_state_rows,
+            "mla_layer_steps": self.mla_layer_steps,
+            "mla_context_tokens": self.mla_context_tokens,
             "loop_s": self.loop_s,
             "idle_wait_s": self.idle_wait_s,
             "fetch_wait_s": self.fetch_wait_s,
@@ -395,7 +407,8 @@ class LLMEngine:
                  paged: bool = True, page_size: int = 64,
                  kv_pool_pages: Optional[int] = None,
                  import_queue_max: Optional[int] = None,
-                 prefix_cache_pages: int = 0):
+                 prefix_cache_pages: int = 0,
+                 prefill_wave_tokens: Optional[int] = None):
         if not paged:
             # the keyword outlives the dense engine (removed in PR 30)
             # only until chipbench/ stops passing it (ROADMAP C12)
@@ -421,6 +434,13 @@ class LLMEngine:
         self.top_p = top_p
         self.max_prompt_len = max_prompt_len or cfg.max_seq_len // 2
         self._min_bucket = min_prefill_bucket
+        # the most tokens (wave x bucket) ONE prefill program may carry;
+        # more prompts of a bucket than that go in several waves, one
+        # after the other.  A wave's activations are its tokens' (32 x
+        # 8,192 tokens of queries and expanded keys alone are 13 GB at
+        # 32 heads of 192); None: a wave takes up to _WAVE_SIZES[-1]
+        # prompts whatever their bucket
+        self.prefill_wave_tokens = prefill_wave_tokens
         self.block_size = block_size
         self.page_size = page_size
         self.max_pages = -(-cfg.max_seq_len // page_size)
@@ -443,6 +463,15 @@ class LLMEngine:
                 "layers: a cached page run would need a snapshot of the "
                 "recurrent state at its page boundary to resume from, "
                 "which the prefix cache does not keep")
+        if cfg.kv_lora_rank and prefix_cache_pages:
+            raise ValueError(
+                "prefix_cache_pages > 0 on a latent-attention model: a "
+                "hit's suffix prefill would have to gather the cached "
+                "prefix's latent rows out of the pool and expand them "
+                "(kv_b) beside the window's own keys, a path "
+                "models/gpt.py LatentAttention does not have")
+        # layers whose pool row is latent: the absorbed decode kernel's
+        self._latent_layers = self._pool_layers if cfg.kv_lora_rank else 0
         self.model = GPT(cfg, decode=True, paged_pages=self.kv_pool_pages,
                          page_size=page_size,
                          state_entries=self.state_entries)
@@ -510,12 +539,13 @@ class LLMEngine:
         # telemetry dependency
         self.on_import_admit: Optional[Callable[[float], None]] = None
         # KV pool leaf identity + handoff shape: pool leaves are
-        # [layers, pool_pages, kv_heads, page_size, 2*head_dim]
-        # (ops/paged_attention.py layout; the model declares one);
-        # _ltot counts the per-layer pools across the cache tree — the
-        # leading axis of PrefillHandoff.kv, which both handoff ends
-        # must agree on.
-        self._pool_tail = (cfg.n_kv_heads, page_size, 2 * cfg.head_dim)
+        # [layers, pool_pages, kv_heads, page_size, row]
+        # (ops/paged_attention.py layout; the model declares one and
+        # its shape is read off it: a latent pool has one KV head and
+        # its own row width); _ltot counts the per-layer pools across
+        # the cache tree — the leading axis of PrefillHandoff.kv, which
+        # both handoff ends must agree on.
+        self._pool_tail = tuple(self._cache["kv_pages"].shape[2:])
         self._ltot = sum(
             leaf.shape[0] for leaf in jax.tree.leaves(self._cache)
             if self._is_pool_leaf(leaf))
@@ -647,7 +677,7 @@ class LLMEngine:
 
     def _is_pool_leaf(self, leaf) -> bool:
         """A cache leaf holding the shared KV page pool: [layers,
-        pool_pages, kv_heads, page_size, 2*head_dim], stacked over the
+        pool_pages, kv_heads, page_size, row], stacked over the
         layers whether or not the model scans them.  Any other cache
         leaf is handoff-irrelevant."""
         return (leaf.ndim == 5
@@ -788,7 +818,9 @@ class LLMEngine:
         ``(layer steps with a live row, experts touched summed over
         them)``.  On the v5e 2.3 us of a decode step of 11.4 ms
         (PERF.md, PR 26)."""
-        e = self.cfg.moe_experts
+        # experts held here: a pair that went to another chip's carries
+        # the id past them, which one_hot leaves out
+        e = self.cfg.experts_here
         idx = jnp.concatenate([
             leaf.reshape(-1, self._rows, leaf.shape[-1])
             for path, leaf in jax.tree_util.tree_leaves_with_path(
@@ -818,6 +850,8 @@ class LLMEngine:
         for bucket in buckets:
             # prefill is slotless: any wave size can occur
             for wave in _WAVE_SIZES:
+                if wave > self._widest_wave(bucket):
+                    break
                 packed = np.zeros((wave, self.packed_width(bucket)),
                                   np.int32)
                 packed[:, bucket] = 1
@@ -1154,6 +1188,14 @@ class LLMEngine:
             b *= 2
         return min(b, self.cfg.max_seq_len)
 
+    def _widest_wave(self, bucket: int) -> int:
+        """The largest wave size whose prompts of ``bucket`` tokens fit
+        ``prefill_wave_tokens`` (one prompt always does)."""
+        if self.prefill_wave_tokens is None:
+            return _WAVE_SIZES[-1]
+        return max(w for w in _WAVE_SIZES
+                   if w == 1 or w * bucket <= self.prefill_wave_tokens)
+
     def _wave_chunks(self, items: list):
         """Group (req, payload) pairs by prompt-length bucket and yield
         (bucket, chunk, wave_size) batches — the admission-batching
@@ -1163,8 +1205,9 @@ class LLMEngine:
             by_bucket.setdefault(self._bucket(len(item[0].prompt)),
                                  []).append(item)
         for bucket, group in by_bucket.items():
-            for start in range(0, len(group), _WAVE_SIZES[-1]):
-                chunk = group[start:start + _WAVE_SIZES[-1]]
+            widest = self._widest_wave(bucket)
+            for start in range(0, len(group), widest):
+                chunk = group[start:start + widest]
                 wave = next(w for w in _WAVE_SIZES if w >= len(chunk))
                 yield bucket, chunk, wave
 
@@ -1258,7 +1301,9 @@ class LLMEngine:
             sp.set_metadata(
                 waves=st.prefill_waves - waves,
                 prompt_tokens=st.prefill_prompt_tokens - prompt,
-                padded_tokens=st.prefill_padded_tokens - padded)
+                padded_tokens=st.prefill_padded_tokens - padded,
+                # what a wave writes to the pool a token a layer
+                pool_row=self._pool_tail[0] * self._pool_tail[2])
 
     def _deliver_block(self, block, rows: list) -> None:
         """Hand one fetched decode block's tokens to their requests,
@@ -1286,7 +1331,11 @@ class LLMEngine:
                         break     # rest of the row is junk past eos
                 self._count_decode_pages(pos0 + 1, sl.pos)
                 st.gdn_state_rows += (sl.pos - pos0) * self._state_layers
+                # steps at positions pos0 .. pos - 1 read pos0 + 1 .. pos
+                st.mla_context_tokens += self._latent_layers * (
+                    (sl.pos - pos0) * (sl.pos + pos0 + 1) // 2)
             st.gdn_layer_steps += self.block_size * self._state_layers
+            st.mla_layer_steps += self.block_size * self._latent_layers
             sp.set_metadata(tokens=st.step_tokens - tokens0,
                             finished=st.requests_completed - done0)
 
@@ -1696,8 +1745,9 @@ class LLMEngine:
             sfx = len(req.prompt) - cover * self.page_size
             by_bucket.setdefault(self._bucket(sfx), []).append(item)
         for bucket, group in by_bucket.items():
-            for start in range(0, len(group), _WAVE_SIZES[-1]):
-                chunk = group[start:start + _WAVE_SIZES[-1]]
+            widest = self._widest_wave(bucket)
+            for start in range(0, len(group), widest):
+                chunk = group[start:start + widest]
                 wave = next(w for w in _WAVE_SIZES if w >= len(chunk))
                 packed = np.zeros((wave, bucket + 2), np.int32)
                 packed[:, bucket] = 1

@@ -532,3 +532,129 @@ def test_olmo_hybrid_cell_fits_the_chip(olmo_hybrid_programs):
     assert block.temp_size_in_bytes < 0.3e9
     assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
             + block.temp_size_in_bytes) < 16.6e9
+
+
+# ---- a latent pool, a dense prefix, a share of the experts (ISSUE 37) ----
+
+def test_paged_decode_on_latent_pages(topo, one_chip):
+    """The kernel family's latent case at Kanana-2's decode shape: 32
+    query heads on ONE KV head, a page of rows padded from 576 to 640,
+    scores over the whole row, values its first 512; still one custom
+    call ``paged_attention_decode``."""
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    rows, heads, mp, ps, pages, layers = 33, 32, 160, 64, 129, 3
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(
+        lambda q, kv, bt, ln, layer, live: paged_attention(
+            q, kv, bt, ln, layer=layer, impl="tpu", live=live,
+            sm_scale=192 ** -0.5, v_width=512)
+    ).lower(sds((rows, heads, 640), jnp.bfloat16),
+            sds((layers, pages, 1, ps, 640), jnp.bfloat16),
+            sds((rows, mp), jnp.int32), sds((rows,), jnp.int32),
+            sds((), jnp.int32), sds((rows,), jnp.bool_))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
+    assert lowered.out_info.shape == (rows, heads, 512)
+    lowered.compile()
+
+
+@pytest.fixture(scope="module")
+def kanana_programs(topo, one_chip):
+    """``engine_decode_block`` and the two widest prefill waves the
+    serve-docqa cell warms (2 x 8192, a row's scores in query blocks,
+    and 4 x 4096) of Kanana-2-30B-A3B at the cell's cut and server: 16
+    layers (one dense, 15 of 16 held experts of 128), 32 slots, pages of
+    64, ``max_seq_len`` 10240, the default pool of 5,281 latent pages.
+    Compiled once for the tests below."""
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("kanana-2-30b-a3b", n_layers=16, moe_experts_held=16,
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    patch = pytest.MonkeyPatch()
+    _answer_tpu(patch)
+    try:
+        eng = _described_engine(cfg, patch, max_seq_len=10240,
+                                max_prompt_len=8192,
+                                prefill_wave_tokens=16384)
+        block = eng._block_jit.lower(
+            *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                     one_chip))
+        out = {"eng": eng, "block_text": block.as_text(),
+               "engine_decode_block": block.compile()}
+        for bucket, wave in ((8192, 2), (4096, 4)):
+            prefill = eng._get_prefill_paged(bucket, wave).lower(
+                *_shapes((eng.params, eng._cache,
+                          jnp.zeros((wave, eng.packed_width(bucket)),
+                                    jnp.int32),
+                          jnp.zeros((wave, eng.max_pages), jnp.int32),
+                          jax.random.PRNGKey(0)), one_chip))
+            out[f"prefill_text_{bucket}"] = prefill.as_text()
+            out[f"engine_prefill_{bucket}"] = prefill.compile()
+        return out
+    finally:
+        patch.undo()
+
+
+def test_kanana_engine_programs_compile_with_their_kernels(kanana_programs):
+    """The decode block holds the latent paged kernel once in the dense
+    prefix and once in the scanned expert stack, and ``moe_experts_decode``
+    over the stacked HELD experts ``[15, 16, ...]``; in the prefill
+    programs a row's scores pass 1 GiB at both buckets, so the expanded
+    attention is the flash kernel (twice: the dense prefix, the scanned
+    stack) and no ``[32, q, k]`` scores exist."""
+    p = kanana_programs
+    assert {k: v.shape for k, v in p["eng"]._cache.items()} == {
+        "kv_pages": (16, 5281, 1, 64, 640)}
+    text = p["block_text"]
+    assert text.count('kernel_name = "paged_attention_decode"') == 2
+    assert text.count('kernel_name = "moe_experts_decode"') == 1
+    assert "@jit_engine_decode_block" in text
+    hlo = p["engine_decode_block"].as_text()
+    import re
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*%?moe_experts_decode\S* = ", line)]
+    assert call.count("bf16[15,16,2048,768]") == 2      # w_gate, w_up
+    assert call.count("bf16[15,16,768,2048]") == 1      # w_down
+    for bucket in (8192, 4096):
+        assert "@jit_engine_prefill" in p[f"prefill_text_{bucket}"]
+        text = p[f"prefill_text_{bucket}"]
+        assert text.count('kernel_name = "flash_fwd"') == 2
+        assert not re.search(r"tensor<\d+x32x\d{3,}x\d{3,}xf32>", text)
+
+
+@pytest.mark.parametrize("name", ["engine_decode_block",
+                                  "engine_prefill_8192",
+                                  "engine_prefill_4096"])
+def test_kanana_programs_address_the_latent_pool_in_place(kanana_programs,
+                                                          name):
+    """Nothing but parameters and in-place updates produces a result of
+    the size of the latent pool, whole or one layer of it, nor (in the
+    decode block, whose kernel reads the stack in place) of a layer's
+    held experts."""
+    hlo = kanana_programs[name].as_text()
+    pool = 5281 * 64 * 640
+    made = _pool_result_producers(hlo, (pool, 16 * pool))
+    assert set(made) <= _IN_PLACE, f"{name}: pool-sized from {dict(made)}"
+    if name == "engine_decode_block":
+        made = _pool_result_producers(hlo, (16 * 2048 * 768,))
+        assert not made, f"{name}: a layer's experts from {dict(made)}"
+
+
+def test_kanana_cell_fits_the_chip(kanana_programs):
+    """11.4 GB resident (weights 4.53, latent pool 6.92 at rows of 640),
+    and the widest prefill wave's temporaries beside it under the 16.9 GB
+    a v5e gives a process."""
+    p = kanana_programs
+    block = p["engine_decode_block"].memory_analysis()
+    assert 11.3e9 < block.argument_size_in_bytes < 11.6e9
+    assert block.temp_size_in_bytes < 0.3e9
+    for bucket in (8192, 4096):
+        prefill = p[f"engine_prefill_{bucket}"].memory_analysis()
+        assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
+                + block.temp_size_in_bytes) < 16.0e9, (
+            bucket, prefill.temp_size_in_bytes / 1e9)

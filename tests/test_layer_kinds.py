@@ -363,7 +363,7 @@ def test_expert_kernel_reads_what_live_rows_chose(monkeypatch, case,
     assert {"one-live-row": k, "total-imbalance": 3, "all-touched": e,
             "dead-rows-elsewhere": 4}.get(case, len(chosen)) == len(chosen)
     steps, touched = LLMEngine._expert_load(
-        types.SimpleNamespace(cfg=types.SimpleNamespace(moe_experts=e),
+        types.SimpleNamespace(cfg=types.SimpleNamespace(experts_here=e),
                               _rows=rows),
         {"expert_idx": jnp.asarray(experts)[None, :, None]},
         jnp.asarray(live))
