@@ -42,11 +42,25 @@ iteration's prefills):
     (prefill-ahead).  Right-pad garbage beyond a real prompt length is
     always overwritten by a decode write before a row's length makes it
     visible, so padding needs no extra masking.
-  - The ready queue.  A prefilled request waits holding its first
-    token; a freeing slot "installs" it by uploading its (token,
-    position, table) row into the block step's device state.
-    Time-to-first-token is bounded by prefill throughput and pool
-    capacity, not by slot turnover.
+  - Install with the prefill.  A request admitted while a slot is
+    free takes it at once: the block dispatched right behind its
+    prefill wave installs it, its first token handed from the wave's
+    output to the block's install array ON THE DEVICE (one tiny
+    program a wave, ``engine_install_firsts``), everything else of the
+    install row (position, temperature, table, state entry) being
+    host-known from admission.  The host fetches the wave's first
+    tokens where it always did, delivers them, and learns then what it
+    installed; a first token that ends the request (eos) evicts the
+    row the way any last token does.  So the second token is one
+    decode step behind the first on the device, not a block behind
+    (``EngineStats.installs_with_prefill``).  Not installed this way:
+    an exported request, and one the host knows to end at its first
+    token (``max_new_tokens`` 1, a prompt at ``max_seq_len - 1``).
+  - The ready queue.  With no slot free, a prefilled request waits
+    holding its first token; a freeing slot "installs" it by uploading
+    its (token, position, table) row into the block step's device
+    state.  Time-to-first-token is bounded by prefill throughput and
+    pool capacity, not by slot turnover.
   - State that is not pages.  A model with linear_attention layers
     (models/gpt.py LinearAttention) keeps, beside its KV pages in the
     full-attention layers, a FIXED-SIZE recurrent state a request: two
@@ -69,9 +83,9 @@ iteration's prefills):
   - The block step.  One jitted program advances ALL slots
     ``block_size`` tokens via lax.scan: [N] tokens in, [N, K] tokens
     out, donated pool; tokens, positions, temperatures, tables and the
-    rng stay on the device between blocks.  Installs upload their
-    current last token (host-known since their prefill), so the block's
-    tokens come back in a single fetch.
+    rng stay on the device between blocks.  Installs from the ready
+    queue upload their current last token (host-known since their
+    prefill), so the block's tokens come back in a single fetch.
   - No eos logic on device: rows that finish mid-block keep generating
     junk the host truncates.  A freed slot keeps stepping junk until
     its redirect row (table -> scratch page 0, position 0; see
@@ -222,15 +236,17 @@ class _Slot:
                  "installed_at", "pages", "prompt_len", "borrowed",
                  "prefix_entry")
 
-    def __init__(self, request: _Request, prompt_len: int, first_token: int,
+    def __init__(self, request: _Request, prompt_len: int,
+                 first_token: Optional[int],
                  pages: Optional[List[int]] = None,
                  borrowed: int = 0, prefix_entry=None):
         self.request = request
         self.pos = prompt_len            # next write position
-        self.out = [first_token]
-        self.last_token = first_token
-        self.first_token_at = time.monotonic()
         self.installed_at: Optional[float] = None   # took a decode slot
+        # None: a prefill wave is computing it (``set_first`` when the
+        # host has fetched it)
+        if first_token is not None:
+            self.set_first(first_token)
         self.pages = pages or []         # physical pool pages owned
         self.prompt_len = prompt_len
         # prefix-cache hit bookkeeping: the first ``borrowed`` entries of
@@ -238,6 +254,11 @@ class _Slot:
         # ``prefix_entry`` — never freed here, refcount released instead
         self.borrowed = borrowed
         self.prefix_entry = prefix_entry
+
+    def set_first(self, token: int) -> None:
+        self.out = [token]
+        self.last_token = token
+        self.first_token_at = time.monotonic()
 
 
 class _PrefixEntry:
@@ -254,14 +275,23 @@ class _PrefixEntry:
 
 
 class _Prefilled:
-    """A request whose prompt K/V already sits in pool pages and whose
-    first token is known, waiting for a decode slot."""
+    """A request whose prompt K/V sits in pool pages, or will when the
+    prefill wave dispatched for it has run.  Its first token is known
+    to the host from the fetch of that wave on; until then it exists on
+    the device alone, and a request installed that early (``slot``)
+    takes it from there (``source``)."""
 
-    __slots__ = ("slot_state", "table")
+    __slots__ = ("slot_state", "table", "source", "slot")
 
     def __init__(self, slot_state: _Slot, table):
         self.slot_state = slot_state     # reused verbatim at install
         self.table = table               # np.int32 [max_pages]
+        # (the wave's ``firsts`` on the device, this request's row of
+        # it) while the host has not fetched the token
+        self.source: Optional[tuple] = None
+        # the decode slot it took in the block dispatched behind its
+        # prefill wave; None: it waits in ``_ready``
+        self.slot: Optional[int] = None
 
 
 class EngineStats:
@@ -275,6 +305,10 @@ class EngineStats:
         self.step_tokens = 0             # tokens delivered from steps
         self.tokens_generated = 0        # + prefill first tokens
         self.prefills = 0
+        # of them, requests stepped by the block dispatched right behind
+        # their prefill wave (a slot was free): their second token does
+        # not wait a block for the host to learn the first
+        self.installs_with_prefill = 0
         self.requests_completed = 0
         self.exports = 0                 # prefill handoffs shipped out
         self.imports = 0                 # prefill handoffs admitted
@@ -335,6 +369,7 @@ class EngineStats:
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
             "prefills": self.prefills,
+            "installs_with_prefill": self.installs_with_prefill,
             "requests_completed": self.requests_completed,
             "batch_occupancy": round(self.occupancy(num_slots), 4),
             "exports": self.exports,
@@ -580,6 +615,14 @@ class LLMEngine:
         self._block_jit = jax.jit(engine_decode_block,
                                   donate_argnums=(1, 2))
 
+        def engine_install_firsts(lasts, firsts, rows):
+            # the block's admit_lasts [num_slots] with the first tokens
+            # of ONE prefill wave put in, device to device: rows[n] is
+            # the wave's row that install n takes its token from, -1
+            # where it takes none.  One program a wave size
+            return jnp.where(rows < 0, lasts, firsts[jnp.maximum(rows, 0)])
+        self._install_firsts_jit = jax.jit(engine_install_firsts)
+
     # ------------------------------------------------------------ jit fns
 
     def _init_cache(self, batch):
@@ -753,11 +796,12 @@ class LLMEngine:
         tokens/positions/temps/tables are scattered in here; admit_meta
         is one packed [3, num_slots] i32 upload (slots, positions,
         temps*1e6), padded so every block reuses one compiled program
-        (pad slots point at the scratch row).  Installs upload their
-        CURRENT last token (known to the host since the request's
-        prefill) so nothing extra is fetched; redirect rows (evicted
-        slots) are just installs of (token 0, position 0, zero table ->
-        scratch page)."""
+        (pad slots point at the scratch row).  admit_lasts holds each
+        install's CURRENT last token: uploaded where the host knows it,
+        put there on the device where its prefill wave runs just ahead
+        of this block (_dispatch_block), so nothing extra is fetched;
+        redirect rows (evicted slots) are just installs of (token 0,
+        position 0, zero table -> scratch page)."""
         tokens, positions, temps, tables, rng, *entries = state
         a_slots = admit_meta[0]
         recurrent = {}
@@ -862,6 +906,12 @@ class LLMEngine:
         combined, self._state, self._cache = self._block_jit(
             self.params, self._cache, self._state, *self._no_admit)
         np.asarray(combined)   # force completion (and the compile)
+        # what hands a wave's first tokens to the block behind it
+        # (_dispatch_block): any wave size can occur at any bucket
+        none = jnp.full((self.num_slots,), -1, jnp.int32)
+        for wave in _WAVE_SIZES:
+            self._install_firsts_jit(
+                self._no_admit[1], jnp.zeros((wave,), jnp.int32), none)
         if burst:
             plen = max(prompt_lens)
 
@@ -1243,13 +1293,18 @@ class LLMEngine:
             pass
 
     @staticmethod
-    def _finish_reason(sl: _Slot, max_seq_len: int) -> Optional[str]:
+    def _length_reached(req: _Request, n_out: int, pos: int,
+                        max_seq_len: int) -> bool:
+        """``n_out`` tokens out and the next write at ``pos``: no more
+        are wanted, or fit."""
+        return n_out >= req.max_new_tokens or pos + 1 >= max_seq_len
+
+    @classmethod
+    def _finish_reason(cls, sl: _Slot, max_seq_len: int) -> Optional[str]:
         req = sl.request
         if req.eos_id is not None and sl.last_token == req.eos_id:
             return "eos"
-        if len(sl.out) >= req.max_new_tokens:
-            return "length"
-        if sl.pos + 1 >= max_seq_len:
+        if cls._length_reached(req, len(sl.out), sl.pos, max_seq_len):
             return "length"
         return None
 
@@ -1266,16 +1321,18 @@ class LLMEngine:
             time_to_first_token_s=queue_wait_s + prefill_s,
             latency_s=now - req.submitted_at,
             queue_wait_s=queue_wait_s, prefill_s=prefill_s,
+            # a request installed with its prefill was stepping before
+            # the host had its first token: it waited for no slot
             slot_wait_s=(0.0 if sl.installed_at is None
-                         else sl.installed_at - sl.first_token_at))
+                         else max(0.0, sl.installed_at
+                                  - sl.first_token_at)))
         self.stats.requests_completed += 1
         self._safe_deliver(req, True, result)
 
-    def _maybe_finish(self, i: int) -> bool:
+    def _evict(self, i: int, reason: str) -> None:
+        """Slot ``i``'s request is done: the slot and what it held go
+        back, its result goes out."""
         sl = self._slots[i]
-        reason = self._finish_reason(sl, self.cfg.max_seq_len)
-        if reason is None:
-            return False
         self._slots[i] = None
         self._free.append(i)
         # the freed slot junk-steps its old table until its redirect
@@ -1287,7 +1344,6 @@ class LLMEngine:
         self._stale_slots.add(i)
         self._prefix_release(sl)
         self._deliver_result(sl, reason)
-        return True
 
     @contextlib.contextmanager
     def _prefill_phase(self):
@@ -1312,12 +1368,14 @@ class LLMEngine:
             st = self.stats
             st.steps += self.block_size
             st.quanta += 1
+            st.gdn_layer_steps += self.block_size * self._state_layers
+            st.mla_layer_steps += self.block_size * self._latent_layers
             tokens0, done0 = st.step_tokens, st.requests_completed
             for i, req in rows:
                 sl = self._slots[i]
                 if sl is None or sl.request is not req:
                     continue      # evicted earlier (or reused): junk row
-                pos0 = sl.pos
+                pos0, reason = sl.pos, None
                 for k in range(self.block_size):
                     tok = int(block[i, k])
                     sl.out.append(tok)
@@ -1327,15 +1385,18 @@ class LLMEngine:
                     st.tokens_generated += 1
                     if sl.request.on_token is not None:
                         self._safe_on_token(sl.request, tok)
-                    if self._maybe_finish(i):
+                    reason = self._finish_reason(sl, self.cfg.max_seq_len)
+                    if reason is not None:
                         break     # rest of the row is junk past eos
                 self._count_decode_pages(pos0 + 1, sl.pos)
                 st.gdn_state_rows += (sl.pos - pos0) * self._state_layers
                 # steps at positions pos0 .. pos - 1 read pos0 + 1 .. pos
                 st.mla_context_tokens += self._latent_layers * (
                     (sl.pos - pos0) * (sl.pos + pos0 + 1) // 2)
-            st.gdn_layer_steps += self.block_size * self._state_layers
-            st.mla_layer_steps += self.block_size * self._latent_layers
+                if reason is not None:
+                    # counted first: whoever holds the result may read
+                    # the counters
+                    self._evict(i, reason)
             sp.set_metadata(tokens=st.step_tokens - tokens0,
                             finished=st.requests_completed - done0)
 
@@ -1541,14 +1602,21 @@ class LLMEngine:
     def _loop(self):
         """Software-pipelined, with a slotless prefill stage ahead of
         the block: each iteration (1) prefills as many queued prompts as
-        the pool allows, (2) installs ready requests into free slots and
-        dispatches the next block, (3) processes the PREVIOUS block's
-        fetch, (4) fetches this iteration's prefill first-tokens (the
-        device finished them before the just-dispatched block).  Block
-        k+1 is dispatched before block k's tokens are fetched, so the
-        device never idles on the host's fetch round-trip or
-        bookkeeping; the price is a one-block install/eviction lag,
-        which the request-identity check in _deliver_block makes safe.
+        the pool allows, (2) gives free slots to ready requests and then
+        to the requests just prefilled (their first token still on the
+        device) and dispatches the next block, (3) processes the
+        PREVIOUS block's fetch, (4) fetches this iteration's prefill
+        first-tokens (the device finished them before the
+        just-dispatched block).  Block k+1 is dispatched before block
+        k's tokens are fetched, so the device never idles on the host's
+        fetch round-trip or bookkeeping.  The price is a one-block
+        EVICTION lag: a row that finished in block k (or at its first
+        token, an install with the prefill) steps junk through block
+        k+1 and its slot is free for the block after, which the
+        request-identity check in _deliver_block makes safe.  There is
+        no install lag while a slot is free: a request is stepped by
+        the block behind its prefill.  Only a request that found every
+        slot taken waits in _ready, for the eviction that frees one.
         TTFT is one prefill round-trip, independent of slot turnover.
         """
         self.stats._loop_mark = time.monotonic()
@@ -1654,19 +1722,25 @@ class LLMEngine:
                     with self._phase("dispatch_import") as sp:
                         sp.set_metadata(requests=len(import_todo))
                         self._dispatch_import_waves(import_todo)
-                with self._lock:
-                    installs = []
-                    while self._free and self._ready:
-                        installs.append((self._ready.popleft(),
-                                         self._free.pop()))
                 new_prefills = []
                 if todo or hits:
                     with self._prefill_phase():
                         new_prefills = (self._dispatch_prefill_waves(todo)
                                         + self._dispatch_suffix_waves(hits))
+                with self._lock:
+                    installs = []
+                    while self._free and self._ready:
+                        installs.append((self._ready.popleft(),
+                                         self._free.pop()))
+                    # slots still free (so nothing is left in _ready):
+                    # the requests of the waves just dispatched take
+                    # them, FIFO, and are stepped by the block that
+                    # follows their prefill on the device
+                    early = self._install_with_prefill(new_prefills,
+                                                       installs)
                 with self._phase("dispatch_block") as sp:
                     nxt = self._dispatch_block(installs)
-                    sp.set_metadata(installs=len(installs),
+                    sp.set_metadata(installs=len(installs), early=early,
                                     active=len(nxt[1]) if nxt else 0)
                 if inflight is not None:
                     self._process_block(inflight)
@@ -1704,6 +1778,29 @@ class LLMEngine:
                 for req in victims:
                     self._safe_deliver(req, False, e)
 
+    def _install_with_prefill(self, waves: list, installs: list) -> int:
+        """Give free slots to requests whose prefill wave was dispatched
+        this iteration (engine lock held): appended to ``installs`` with
+        ``source`` naming where on the device their first token will be.
+        Not a request that is exported, nor one the host knows to end at
+        its first token whatever that is: neither decodes here.  Returns
+        how many."""
+        early = 0
+        for firsts, metas in waves:
+            for row, pf in enumerate(metas):
+                if not self._free:
+                    break
+                req = pf.slot_state.request
+                if req.export or self._length_reached(
+                        req, 1, len(req.prompt), self.cfg.max_seq_len):
+                    continue
+                pf.source = (firsts, row)
+                pf.slot = self._free.pop()
+                installs.append((pf, pf.slot))
+                early += 1
+        self.stats.installs_with_prefill += early
+        return early
+
     def _dispatch_prefill_waves(self, todo: list) -> list:
         """Batch queued prompts into (bucket, wave) prefill calls that
         write straight into their reserved pages.  Device dispatch only —
@@ -1721,7 +1818,9 @@ class LLMEngine:
                 if self._state_layers:
                     packed[r, bucket + 2] = req.entry
                 tables[r, :len(pages)] = pages
-                metas.append((req, pages, tables[r].copy(), 0, None))
+                metas.append(_Prefilled(
+                    _Slot(req, len(req.prompt), None, pages),
+                    tables[r].copy()))
             firsts, self._cache = self._get_prefill_paged(
                 bucket, wave)(self.params, self._cache,
                               jnp.asarray(packed),
@@ -1762,8 +1861,9 @@ class LLMEngine:
                     packed[r, bucket + 1] = int(req.temperature * 1e6)
                     tables[r, :len(pages)] = pages
                     offs[r] = c
-                    metas.append((req, pages, tables[r].copy(),
-                                  cover, entry))
+                    metas.append(_Prefilled(
+                        _Slot(req, len(req.prompt), None, pages, cover,
+                              entry), tables[r].copy()))
                 firsts, self._cache = self._get_prefill_suffix(
                     bucket, wave)(self.params, self._cache,
                                   jnp.asarray(packed),
@@ -1801,30 +1901,39 @@ class LLMEngine:
         return exports
 
     def _complete_prefills(self, metas, host) -> list:
-        """Requests finish here if one token was all they wanted,
-        otherwise they join the ready queue holding their first token.
-        Export-flagged requests are returned for the gather stage
-        instead of queueing for a local slot."""
+        """The host learns a wave's first tokens.  Requests finish here
+        if one token was all they wanted, otherwise they join the ready
+        queue holding it — unless the block behind the wave already
+        steps them (``_install_with_prefill``).  Export-flagged requests
+        are returned for the gather stage instead of queueing for a
+        local slot."""
         exports = []
-        for (req, pages, table, borrowed, entry), first in \
-                zip(metas, host):
+        for pf, first in zip(metas, host):
+            sl = pf.slot_state
+            req = sl.request
             self.stats.tokens_generated += 1
-            sl = _Slot(req, len(req.prompt), int(first), pages,
-                       borrowed, entry)
+            sl.set_first(int(first))
+            pf.source = None
             if req.export:
                 exports.append((req, sl))
                 continue
             if req.on_token is not None:
                 self._safe_on_token(req, int(first))
             reason = self._finish_reason(sl, self.cfg.max_seq_len)
-            if reason is not None:
+            if pf.slot is not None:
+                # stepping since the block behind its wave: a first
+                # token that ends it (eos) evicts it as any row's last
+                # token does, redirect and all
+                if reason is not None:
+                    self._evict(pf.slot, reason)
+            elif reason is not None:
                 # never installed -> nothing junk-steps these pages:
                 # free immediately, no redirect needed
                 self._prefix_release(sl)
                 self._deliver_result(sl, reason)
             else:
                 with self._lock:
-                    self._ready.append(_Prefilled(sl, table))
+                    self._ready.append(pf)
         return exports
 
     def _process_exports(self, exports: list) -> None:
@@ -1936,15 +2045,20 @@ class LLMEngine:
                         self._ready.append(_Prefilled(sl, table))
 
     def _dispatch_block(self, installs: list):
-        """Install ready requests into free slots (their last token and
-        position are host-known — nothing is fetched), attach redirect
-        rows for stale slots, and dispatch one decode block.  Returns
+        """Install requests into free slots, attach redirect rows for
+        stale slots, and dispatch one decode block.  A request's
+        position, temperature, table and state entry are host-known from
+        its admission on; its last token is too (nothing is fetched),
+        except where its prefill wave was dispatched this iteration
+        (``pf.source``): that token goes from the wave's output into the
+        block's ``admit_lasts`` on the device.  Returns
         (combined_device, rows) or None when no slot is active."""
         A = self.num_slots
         meta = np.zeros((self._meta_rows, A), np.int32)
         meta[0, :] = A                                  # pad -> scratch
         lasts = np.zeros((A,), np.int32)
         tables = np.zeros((A, self.max_pages), np.int32)
+        from_waves: dict = {}     # id(firsts) -> (firsts, rows [A])
         n = 0
         now = time.monotonic()
         for pf, slot in installs:
@@ -1957,7 +2071,13 @@ class LLMEngine:
             meta[2, n] = int(sl.request.temperature * 1e6)
             if self._state_layers:
                 meta[3, n] = sl.request.entry
-            lasts[n] = sl.last_token
+            if pf.source is None:
+                lasts[n] = sl.last_token
+            else:
+                firsts, row = pf.source
+                _, rows = from_waves.setdefault(
+                    id(firsts), (firsts, np.full((A,), -1, np.int32)))
+                rows[n] = row
             tables[n] = pf.table
             n += 1
         if all(s is None for s in self._slots):
@@ -1967,8 +2087,14 @@ class LLMEngine:
                 meta[0, n] = slot   # zero token/pos/table -> scratch page
                 n += 1
                 self._stale_slots.discard(slot)
-        admit = ((jnp.asarray(meta), jnp.asarray(lasts),
-                  jnp.asarray(tables)) if n else self._no_admit)
+        if n:
+            lasts = jnp.asarray(lasts)
+            for firsts, rows in from_waves.values():
+                lasts = self._install_firsts_jit(lasts, firsts,
+                                                 jnp.asarray(rows))
+            admit = (jnp.asarray(meta), lasts, jnp.asarray(tables))
+        else:
+            admit = self._no_admit
         combined, self._state, self._cache = self._block_jit(
             self.params, self._cache, self._state, *admit)
         rows = [(i, s.request) for i, s in enumerate(self._slots)

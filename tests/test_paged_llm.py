@@ -315,6 +315,310 @@ def test_prefill_ahead_ttft_decoupled_from_slot_wait(tiny_parts):
         eng.close()
 
 
+# ---- a prefilled request joins the block behind its wave (ISSUE 38) ----
+
+
+def _dispatch_log(eng):
+    """What the engine hands the device, in order: ``("prefill",)`` a
+    prefill wave (full or suffix), ``("block", positions)`` a decode
+    block with the positions of the rows it installs on a real table
+    (redirect and pad rows left out)."""
+    import numpy as np
+
+    log = []
+    block = eng._block_jit
+
+    def block_spy(params, cache, state, meta, lasts, tables):
+        meta, first_page = np.asarray(meta), np.asarray(tables)[:, 0]
+        log.append(("block", sorted(
+            int(pos) for slot, pos, page in zip(meta[0], meta[1], first_page)
+            if slot < eng.num_slots and page != 0)))
+        return block(params, cache, state, meta, lasts, tables)
+
+    eng._block_jit = block_spy
+    for name in ("_get_prefill_paged", "_get_prefill_suffix"):
+        def get_spy(bucket, wave, get=getattr(eng, name)):
+            fn = get(bucket, wave)
+
+            def run(*operands):
+                log.append(("prefill",))
+                return fn(*operands)
+            return run
+        setattr(eng, name, get_spy)
+    return log
+
+
+def _blocks_behind_waves(log):
+    """The installed positions of the block dispatched right behind
+    each prefill wave (``None`` where no block follows a wave)."""
+    return [log[i + 1][1] if i + 1 < len(log) and log[i + 1][0] == "block"
+            else None for i, entry in enumerate(log) if entry[0] == "prefill"]
+
+
+def _second_while_first_decodes(eng, first, second):
+    """Submit ``first`` (kwargs of ``submit``), and ``second`` the
+    moment ``first``'s first token is out.  Returns both results and
+    how many requests had completed when ``second``'s first token came
+    (0: ``first`` was still decoding)."""
+    out = {}
+    started = threading.Event()
+    done_before = []
+
+    def go(name, kw, on_first):
+        seen = []
+
+        def on_token(_tok):
+            if not seen:
+                seen.append(1)
+                on_first()
+        out[name] = eng.submit(on_token=on_token, temperature=0.0, **kw)
+
+    threads = [
+        threading.Thread(target=go, args=("first", first, started.set)),
+        threading.Thread(target=go, args=("second", second, lambda: (
+            done_before.append(eng.stats.requests_completed))))]
+    threads[0].start()
+    assert started.wait(timeout=120), "the first request made no token"
+    threads[1].start()
+    for t in threads:
+        t.join(timeout=240)
+    return out["first"], out["second"], done_before[0]
+
+
+def _all_came_back(eng):
+    snap = eng.load_snapshot()
+    assert snap["busy_slots"] == 0 and snap["ready"] == 0
+    assert snap["free_pages"] + snap["prefix_pages_cached"] == (
+        snap["pool_pages"] - 1)
+    assert snap["state_entries_in_use"] == 0
+
+
+def _hybrid_parts():
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models.configs import get_config
+    from ray_tpu.models.gpt import GPT
+
+    cfg = get_config("tiny-olmo-hybrid")
+    params = GPT(cfg).init(jax.random.PRNGKey(1),
+                           jnp.zeros((1, 8), jnp.int32))["params"]
+    return cfg, params, dict(num_slots=2, page_size=4, max_seq_len=64,
+                             max_prompt_len=32, block_size=4,
+                             min_prefill_bucket=8)
+
+
+@pytest.mark.parametrize("case", [
+    "joins-the-block-in-flight", "state-entry", "prefix-hit",
+    "no-free-slot", "eos-at-first-token", "eos-at-first-token-state-entry",
+    "one-token-is-all", "engine-fatal"])
+def test_prefilled_request_is_stepped_by_the_block_behind_its_wave(
+        tiny_parts, case):
+    """A request admitted while a slot is free is installed into the
+    block dispatched right behind its prefill wave, its first token
+    going from the wave to the block on the device
+    (``installs_with_prefill``), and decodes exactly as alone.  Each
+    case is one of the things the host learns only afterwards, or a
+    reason not to install."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    hybrid = case.endswith("state-entry")
+    if hybrid:
+        cfg, params, kw = _hybrid_parts()
+    else:
+        cfg, params = tiny_parts
+        kw = dict(num_slots=2, block_size=4, page_size=16,
+                  kv_pool_pages=1 + 8)
+    if case == "no-free-slot":
+        kw["num_slots"] = 1
+    if case == "prefix-hit":
+        kw.update(kv_pool_pages=1 + 16, prefix_cache_pages=8)
+    shared = list(range(3, 3 + 32))               # two full pages
+    long, short = ([4, 5, 6], [7, 8, 9, 10, 11]) if case != "prefix-hit" \
+        else (shared + [40, 41], shared + [50, 51, 52, 53, 54])
+    n_long, n_short = (40, 8) if not hybrid else (24, 8)
+
+    def alone(prompt, n):
+        if not hybrid:
+            return _lone_expect(cfg, params, [prompt], n=n)[0]
+        lone = LLMEngine(cfg, params, **kw)
+        try:
+            return lone.submit(prompt, max_new_tokens=n,
+                               temperature=0.0).tokens
+        finally:
+            lone.close()
+
+    eng = LLMEngine(cfg, params, **kw)
+    try:
+        log = _dispatch_log(eng)
+        st = eng.stats
+        if case in ("joins-the-block-in-flight", "state-entry",
+                    "prefix-hit", "no-free-slot"):
+            if case == "prefix-hit":
+                # the run both prompts share is cached by a request
+                # that came and went
+                eng.submit(shared + [60], max_new_tokens=2)
+                log.clear()
+            first, second, done_before = _second_while_first_decodes(
+                eng, dict(prompt=long, max_new_tokens=n_long),
+                dict(prompt=short, max_new_tokens=n_short))
+            assert done_before == (1 if case == "prefix-hit" else 0)
+            assert first.tokens == alone(long, n_long)
+            assert second.tokens == alone(short, n_short)
+            behind = _blocks_behind_waves(log)
+            assert first.slot_wait_s == 0.0
+            if case == "no-free-slot":
+                # the one slot is taken: prefilled ahead, it waits in
+                # _ready for the slot as it always did
+                assert behind == [[len(long)], []]
+                assert st.installs_with_prefill == 1 and st.prefills == 2
+                assert second.slot_wait_s > 0.0
+            else:
+                assert behind == [[len(long)], [len(short)]]
+                assert st.installs_with_prefill == st.prefills == (
+                    3 if case == "prefix-hit" else 2)
+                assert second.slot_wait_s == 0.0
+            if case == "prefix-hit":
+                assert st.prefix_hits == 2
+        elif case.startswith("eos-at-first-token"):
+            # test_page_recycling_stays_exact's form, every second
+            # request ending at its first token: it was installed and
+            # is stepping when the host learns that, and its slot, pages
+            # and entry go the way an evicted row's go
+            prompts = [[i + 1, i + 2, i + 3] for i in range(12)]
+            expect = [alone(p, 6) for p in prompts]
+            r = eng.submit(prompts[1], max_new_tokens=64, temperature=0.0,
+                           eos_id=expect[1][0])
+            assert (r.finish_reason, r.tokens) == ("eos", expect[1][:1])
+            assert _blocks_behind_waves(log) == [[3]]
+            assert st.installs_with_prefill == 1
+            _all_came_back(eng)
+            results = [None] * len(prompts)
+
+            def go(i):
+                results[i] = eng.submit(
+                    prompts[i], max_new_tokens=6, temperature=0.0,
+                    eos_id=expect[i][0] if i % 2 else None)
+            threads = [threading.Thread(target=go, args=(i,))
+                       for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=240)
+            for i, r in enumerate(results):
+                assert r is not None, f"request {i} hung"
+                assert r.tokens == (expect[i][:1] if i % 2 else expect[i]), (
+                    f"request {i} diverged")
+        elif case == "one-token-is-all":
+            # the host knows it ends at its first token: no slot, no
+            # block at all
+            want = alone(long, 1)
+            for _ in range(2):
+                r = eng.submit(long, max_new_tokens=1, temperature=0.0)
+                assert (r.finish_reason, r.tokens) == ("length", want)
+            r = eng.submit([9] * 3, temperature=0.0,
+                           max_new_tokens=2 * cfg.max_seq_len)
+            assert len(r.tokens) == cfg.max_seq_len - 3
+            edge = [9] * (cfg.max_seq_len // 2)
+            eng2 = LLMEngine(cfg, params, max_seq_len=len(edge) + 1,
+                             max_prompt_len=len(edge), **kw)
+            try:
+                log2 = _dispatch_log(eng2)
+                r = eng2.submit(edge, max_new_tokens=8, temperature=0.0)
+                assert r.finish_reason == "length" and len(r.tokens) == 1
+                assert log2 == [("prefill",)]
+            finally:
+                eng2.close()
+            assert _blocks_behind_waves(log)[:2] == [None, None]
+            assert (st.installs_with_prefill, st.prefills) == (1, 3)
+        elif case == "engine-fatal":
+            # installed, not yet fetched, and the block fails: the
+            # request fails once, everything is taken back
+            spied = eng._block_jit
+
+            def fail_once(*operands):
+                eng._block_jit = spied
+                raise RuntimeError("injected block failure")
+            eng._block_jit = fail_once
+            reached = []     # deliveries that found it undelivered
+            deliver = eng._safe_deliver
+            eng._safe_deliver = lambda req, ok, value: (
+                req.delivered or reached.append(ok),
+                deliver(req, ok, value))
+            with pytest.raises(RuntimeError, match="injected"):
+                eng.submit(long, max_new_tokens=8, temperature=0.0)
+            assert reached == [False]
+            assert _blocks_behind_waves(log) == [None]
+        _all_came_back(eng)
+        # and the engine serves on, exactly
+        assert eng.submit(short, max_new_tokens=6,
+                          temperature=0.0).tokens == alone(short, 6)
+        _all_came_back(eng)
+    finally:
+        eng.close()
+
+
+def test_install_path_compiles_nothing_after_warmup(tiny_parts):
+    """The programs that hand a wave's first tokens to the block behind
+    it are compiled by ``warmup(prompt_lens=())``, which every benchmark
+    replica calls after its own prefill warm-up: bursts that form every
+    wave size, each installed whole with its prefill, then compile
+    nothing at all (counted the way the benchmark's
+    ``no_compile_in_window`` counts: ``chipbench/lib/compile_watch``)."""
+    import asyncio
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from chipbench.lib import compile_watch
+    from ray_tpu.serve.llm_engine import _WAVE_SIZES, LLMEngine
+
+    cfg, params = tiny_parts
+    eng = LLMEngine(cfg, params, num_slots=_WAVE_SIZES[-1], block_size=4,
+                    page_size=16)
+    try:
+        bucket = eng._bucket(3)
+        for wave in _WAVE_SIZES:       # a replica's own prefill warm-up
+            packed = np.zeros((wave, eng.packed_width(bucket)), np.int32)
+            packed[:, bucket] = 1
+            _, eng._cache = eng._get_prefill_paged(bucket, wave)(
+                eng.params, eng._cache, jnp.asarray(packed),
+                jnp.zeros((wave, eng.max_pages), jnp.int32),
+                jax.random.PRNGKey(0))
+        before = compile_watch.snapshot()
+        eng.warmup(prompt_lens=())
+        warmed = compile_watch.names_since(before)
+        assert sum("engine_install_firsts" in name for name in warmed) == len(
+            _WAVE_SIZES), warmed
+
+        def burst(size):
+            async def run():
+                with eng._lock:        # one admission, hence one wave
+                    futs = [eng.submit([i + 1, i + 2, i + 3],
+                                       max_new_tokens=6, temperature=0.0)
+                            for i in range(size)]
+                return await asyncio.gather(*futs)
+            return asyncio.run(run())
+
+        burst(1)       # what any first request compiles (the key split)
+        expect = _lone_expect(cfg, params, [[1, 2, 3], [32, 33, 34]], n=6)
+        mark = compile_watch.snapshot()
+        st = eng.stats
+        waves0, early0 = st.prefill_waves, st.installs_with_prefill
+        for size in reversed(_WAVE_SIZES):
+            results = burst(size)
+            assert results[0].tokens == expect[0]
+            if size == _WAVE_SIZES[-1]:
+                assert results[-1].tokens == expect[1]
+        assert st.prefill_waves - waves0 == len(_WAVE_SIZES)
+        assert st.installs_with_prefill - early0 == sum(_WAVE_SIZES)
+        after = compile_watch.snapshot()
+        assert after["backend_compiles"] == mark["backend_compiles"], (
+            compile_watch.names_since(mark))
+        assert compile_watch.names_since(mark) == []
+    finally:
+        eng.close()
+
+
 @pytest.mark.parametrize("kw", [
     dict(num_slots=2),
     dict(num_slots=2, max_prompt_len=16),
