@@ -12,9 +12,26 @@ fit the chip at long prompts: PERF.md).  ``runners/serve.py`` deploys it
 with ``serve.deployment`` exactly as ``build_app`` deploys ``LLMServer``.
 """
 
+import collections
 import time
 
 from ray_tpu.serve.llm import LLMServer
+
+
+def count_prefill_calls(eng) -> collections.Counter:
+    """``{(bucket, wave): calls}``, counted from now on: the engine asks
+    ``_get_prefill_paged`` for the program of every prefill wave it
+    dispatches, so the pairs counted between two snapshots are the
+    prefill programs that interval RAN (which have to be among those the
+    cell warmed: ``prefill_pairs_used`` on the ``serve_done`` line)."""
+    calls = collections.Counter()
+    get = eng._get_prefill_paged
+
+    def counting(bucket: int, wave: int):
+        calls[bucket, wave] += 1
+        return get(bucket, wave)
+    eng._get_prefill_paged = counting
+    return calls
 
 
 class BenchLLMServer(LLMServer):
@@ -23,6 +40,7 @@ class BenchLLMServer(LLMServer):
         from chipbench.lib import compile_watch
         compile_watch.snapshot()             # listeners on before any jit
         super().__init__(*args, **kwargs)
+        self._prefill_calls = count_prefill_calls(self.engine)
 
     def bench_warm(self, pairs, concat_sizes) -> dict:
         """Compile (or load) the paged prefill program of each ``(bucket,
@@ -73,15 +91,18 @@ class BenchLLMServer(LLMServer):
                 "memory_peak_bytes": max(
                     (s.get("peak_bytes_in_use", 0) for s in stats),
                     default=0),
+                "prefill_calls": sorted(
+                    [b, w, n] for (b, w), n in self._prefill_calls.items()),
                 "load": self.engine.load_snapshot()}
 
     def bench_trace(self, action: str, trace_dir: str = "") -> float:
         """Returns the wall time at which tracing was on (start) or was
         still on (stop)."""
-        import jax
         if action == "start":
-            jax.profiler.start_trace(trace_dir)
+            from chipbench.lib import trace
+            trace.start_trace(trace_dir)
             return time.time()
+        import jax
         now = time.time()
         jax.profiler.stop_trace()
         return now

@@ -380,17 +380,26 @@ class HybridBenchLLMServer(BenchLLMServer):
         # each program is built ahead of its first call, LOADERS at a
         # time (the call then finds it: jit keeps what
         # ``lower().compile()`` made for the same operands).  Compiling
-        # the 25 takes 254 s so, 343 s one after the other; read back
-        # from the compile cache they take ~155 s either way, which is
-        # Python tracing them, ~4 s a program on the chip's host
-        # (PERF.md section 6, PR 33)
+        # 25 took 254 s so, 343 s one after the other; read back from
+        # the compile cache they take as long either way, which is
+        # Python tracing them: 6.4 s a program on the chip's host, 9 s
+        # the widest, 10 s on a slow host (PERF.md section 6, PRs 33
+        # and 40), so the mix warms no program its windows cannot form
         fns = [eng._get_prefill_paged(b, w) for b, w in pairs]
+
+        def build(fn, pair) -> float:
+            """Seconds this program took to trace, lower and compile or
+            load, LOADERS of them sharing one interpreter (for the log:
+            which programs a shorter warm-up list saves most on)."""
+            t = time.perf_counter()
+            fn.lower(*operands(*pair)).compile()
+            return round(time.perf_counter() - t, 2)
+
         with concurrent.futures.ThreadPoolExecutor(LOADERS) as pool:
             block = pool.submit(lambda: eng._block_jit.lower(
                 eng.params, eng._cache, eng._state, *eng._no_admit
             ).compile())
-            list(pool.map(lambda fn, pair: fn.lower(*operands(*pair)
-                                                    ).compile(), fns, pairs))
+            each = list(pool.map(build, fns, pairs))
             block.result()
         built = time.perf_counter() - t0
         for fn, pair in zip(fns, pairs):
@@ -407,27 +416,9 @@ class HybridBenchLLMServer(BenchLLMServer):
         with concurrent.futures.ThreadPoolExecutor(8) as pool:
             list(pool.map(join, combos))
         return {"seconds": time.perf_counter() - t0, "programs": len(pairs),
-                "joins": len(combos), "built_s": built,
+                "joins": len(combos), "built_s": built, "built_each_s": each,
                 "init_s": self._init_s,
                 "params_s": getattr(self, "_params_s", None)}
-
-    def bench_trace(self, action: str, trace_dir: str = "") -> float:
-        """``BenchLLMServer.bench_trace`` with the profiler's PYTHON call
-        tracer off (its device planes, ``TraceAnnotation`` spans and
-        runtime events stay: every reader of a trace reads those).  At
-        this cell's token rate (some 1,500 tokens a second, each a few
-        Python calls in three threads of the replica) the call tracer
-        made a block's delivery 547 ms against 436 ms of the block on
-        the device, and ``stop_trace`` then held the interpreter for
-        ~11 s converting its events: streams opened after it never
-        reached the client (PERF.md section 6, PR 33)."""
-        if action != "start":
-            return super().bench_trace(action)
-        import jax
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=options)
-        return time.time()
 
     def bench_reference(self, samples, config: dict) -> list:
         """Each sample's tokens (prompt, then what the engine streamed)

@@ -15,7 +15,8 @@ it takes from the trace:
   the host event (any line of a ``/host:`` plane) that overlaps it most.
 
 Nothing here knows a kernel's name: the per-layer metrics' own files
-hold the patterns they look for.
+hold the patterns they look for.  ``start_trace`` is how every runner's
+process that holds the chip turns the profiler on.
 """
 
 import glob
@@ -25,6 +26,22 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 _HLO = re.compile(r"^%?(?P<lhs>[^ ]+) = (?P<rest>.*)$", re.S)
 _OPCODE = re.compile(r"[\]})] ([a-z][\w-]*)\(")
+
+
+def start_trace(trace_dir: str) -> None:
+    """``jax.profiler.start_trace`` with the profiler's PYTHON call
+    tracer off: its device planes, ``TraceAnnotation`` spans and runtime
+    events stay, and every reader of a trace reads those.  The call
+    tracer records every Python call of every thread of the process: it
+    made a block's delivery 22.5 ms against 1.8 ms untraced in
+    ``serve-chat`` and 547 ms against a block of 436 ms in
+    ``serve-assist``, where ``stop_trace`` then held the interpreter for
+    ~11 s converting its events (PERF.md section 6, PRs 33 and 40), and
+    it filled ``breakdown.idle_gaps`` with other threads' frames."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
 
 
 def short_name(event_name: str) -> str:

@@ -132,6 +132,13 @@ def main() -> int:
         print("chipbench: the driver process initialised a JAX backend",
               file=sys.stderr)
         return 1
+    if not line["correct"]:
+        say("failed_checks", checks=record["checks"])
+    # the last line but one: where the run's seconds went (the driver
+    # stops a run at 360 s); the runner's phases where it names them
+    say("phases", **record.get("phases", {}),
+        setup_s=round(record["setup_s"], 2),
+        total_s=round(time.time() - started, 2))
     if device["platform"] != "tpu":
         if rehearsal:
             print(json.dumps({"rehearsal": True, "not_a_measurement":
@@ -139,8 +146,6 @@ def main() -> int:
         print(f"chipbench: ran on {device['platform']!r}, not on a TPU: "
               "no result", file=sys.stderr)
         return 1
-    if not line["correct"]:
-        say("failed_checks", checks=record["checks"])
     print(json.dumps(line), flush=True)
     for name, c in line["compared"].items():
         print(f"compared {name} {c['value']} limit {c['limit']}",

@@ -21,6 +21,14 @@ against the far side of that reading's limit and recorded in
 ``compared`` as ``control.<sample>.<name>``.  The two reference samples
 are picked among the requests whose prompt does NOT fill its prefill
 bucket: only those have the right-pad that ``padding_absorbed`` reads.
+And ``warm_pairs``: ``[[bucket, wave], ...]`` warmed BESIDE the pairs the
+rehearsal of ``warm_horizon_s`` names (``warmed_pairs``): the horizon says
+which pause of the machine the cell takes without a compile in its
+window, the list adds the wave size above the widest wave a window was
+SEEN to form where the rehearsal stops short of it
+(``traffic/serve-assist.pairs-seen.json``; every prefill program costs
+the set-up 6-10 s of Python tracing, cache or no cache: PERF.md
+section 6, PR 40).
 """
 
 import asyncio
@@ -38,6 +46,13 @@ def _padded(prompt_len: int) -> bool:
     """The engine's prefill buckets are powers of two: a prompt of any
     other length is right-padded."""
     return bool(prompt_len & (prompt_len - 1))
+
+
+def warmed_pairs(schedule: list, mix: dict) -> list:
+    """The prefill programs a run warms: the rehearsal's, and the mix's
+    own ``warm_pairs`` (module docstring)."""
+    return sorted({*rehearse(schedule, mix),
+                   *(tuple(p) for p in mix.get("warm_pairs", ()))})
 
 
 def deploy(cell, config, mix, seed31, allow_cpu, say, pairs=None):
@@ -76,9 +91,12 @@ def deploy(cell, config, mix, seed31, allow_cpu, say, pairs=None):
             pairs, mix.get("warm_concat", {})), timeout=1100)
         # where the set-up's seconds go: a run has to end well inside
         # the driver's limit (PERF.md section 6, PR 33)
+        took = info["phases"] = {"cluster_s": round(t1 - t0, 2),
+                                 "replica_s": round(t2 - t1, 2),
+                                 "warm_s": round(warm["seconds"], 2)}
         say("replica", device=info["device"], paged_impl=info["paged_impl"],
             gdn_impl=info.get("gdn_impl"), weights_seed=server["seed"],
-            cluster_s=round(t1 - t0, 2), replica_s=round(t2 - t1, 2),
+            cluster_s=took["cluster_s"], replica_s=took["replica_s"],
             warm=warm, pairs=pairs)
         short = mix["prompt_len"]["min"]
         for n in range(mix["warm_requests"]):
@@ -114,7 +132,7 @@ def run(ctx) -> dict:
 
     ray_tpu, handle, info = deploy(cell, config, mix, ctx["seed31"],
                                    ctx["allow_cpu"], say,
-                                   rehearse(schedule, mix))
+                                   warmed_pairs(schedule, mix))
     try:
         stats0 = ray_tpu.get(handle.stats.remote(), timeout=60)
         facts0 = ray_tpu.get(handle.bench_facts.remote(), timeout=60)
@@ -156,8 +174,11 @@ def run(ctx) -> dict:
              for s in samples], config), timeout=900) if samples else []
         for s, m in zip(samples, ref):
             m["which"] = s["which"]
-        say("after_window", drained_s=round(drained - first_wall, 2),
-            reference_s=round(time.time() - drained, 2))
+        phases = dict(info["phases"], window_s=seconds,
+                      drained_s=round(drained - first_wall, 2),
+                      reference_s=round(time.time() - drained, 2))
+        say("after_window", drained_s=phases["drained_s"],
+            reference_s=phases["reference_s"])
     finally:
         serve.shutdown()
         ray_tpu.shutdown()
@@ -168,6 +189,12 @@ def run(ctx) -> dict:
         hi is None or x <= hi)
     in_window = {k: facts1["compiles"][k] - facts0["compiles"][k]
                  for k in facts0["compiles"]}
+    # the prefill programs the window and its drain RAN, with their
+    # calls: each has to be among ``pairs`` of the ``replica`` line
+    before = {(b, w): n for b, w, n in facts0["prefill_calls"]}
+    pairs_used = [[b, w, n - before.get((b, w), 0)]
+                  for b, w, n in facts1["prefill_calls"]
+                  if n > before.get((b, w), 0)]
     finished = [r for r in recs if "done" in r]
     failed = [r for r in recs if "error" in r]
     # each number the reference check compares, beside its limit
@@ -210,7 +237,7 @@ def run(ctx) -> dict:
     }
     say("serve_done", requests=len(recs), finished=len(finished),
         failed=len(failed), errors=[r["error"] for r in failed][:3],
-        compile_in_window=in_window,
+        compile_in_window=in_window, prefill_pairs_used=pairs_used,
         longest_compile_s=facts1["longest_compile_s"],
         compiled_names=facts1["compiled_names"],
         client=serve_views.client_summary(recs),
@@ -230,7 +257,7 @@ def run(ctx) -> dict:
                    "kind": info["device"]["kind"],
                    "count": info["device"]["count"],
                    "memory_peak_bytes": facts1["memory_peak_bytes"]},
-        "first_measured_wall": first_wall,
+        "first_measured_wall": first_wall, "phases": phases,
         "chips": cell["chips"], "config": config, "mix": mix,
         "serve": {"requests": recs, "seconds": seconds,
                   "stats0": stats0, "stats1": stats1,

@@ -37,7 +37,7 @@ def bench_train_loop(config):
 
     import jax
 
-    from chipbench.lib import compile_watch, reference
+    from chipbench.lib import compile_watch, reference, trace
     from ray_tpu.air import session
     from ray_tpu.train.sharded import layout
     from ray_tpu.train.sharded.executor import (_synth_batch, build_step,
@@ -81,7 +81,7 @@ def bench_train_loop(config):
 
             def on_step(metrics):
                 if metrics["step"] == 0:
-                    jax.profiler.start_trace(plan["trace_dir"])
+                    trace.start_trace(plan["trace_dir"])
                     marks["t0"] = time.perf_counter()
                 elif metrics["step"] == last:
                     marks["t1"] = time.perf_counter()

@@ -456,8 +456,12 @@ def test_hybrid_readers_on_a_hand_made_record():
 def test_benchmark_json_lists_the_cell_where_it_reports():
     with open(os.path.join(HERE, "..", "..", "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "olmo-hybrid-7b"
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "olmo-hybrid-7b", "serve-assist", 1)
+    assert [c["file"] for c in bench["configs"]
+            if c["name"] == cell["config"]] == [
+        "chipbench/configs/olmo-hybrid-7b.json"]
     lists = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
              if CELL in m.get("workloads", ())}
     assert lists == {
@@ -471,18 +475,56 @@ def test_benchmark_json_lists_the_cell_where_it_reports():
     assert "decode_step_device_ms" not in lists
 
 
-def test_the_replica_traces_without_the_python_call_tracer(monkeypatch):
-    """``HybridBenchLLMServer.bench_trace('start')`` turns the profiler's
-    Python call tracer off and nothing else (PERF.md section 6, PR 33)."""
-    import jax
+def test_the_warm_up_covers_the_programs_the_window_was_seen_to_run():
+    """What a run of the committed mix warms (``rehearse`` of the
+    committed schedule under the committed ``warm_horizon_s``, and the
+    mix's ``warm_pairs``) names every prefill program that the cell's
+    windows were SEEN to run on the chip (``traffic/serve-assist.
+    pairs-seen.json``: the ``prefill_pairs_used`` of my chip runs, PR
+    40) and the next wave size up in each bucket that formed a wave of
+    two, so an edit of the mix cannot quietly drop a program the window
+    needs."""
+    from chipbench.runners.serve_arch import _WAVES, cell_schedule, rehearse
+    from chipbench.runners.serve_hybrid import warmed_pairs
+    mix, cfg = _real_mix(), _real_config()
+    with open(os.path.join(HERE, "..", "traffic",
+                           "serve-assist.pairs-seen.json")) as f:
+        seen = json.load(f)
+    assert seen["seconds"] == 45 and len(seen["runs"]) >= 3
+    schedule = cell_schedule(mix, 7, seen["seconds"], cfg["vocab_size"])
+    warmed = set(warmed_pairs(schedule, mix))
+    assert set(rehearse(schedule, mix)) <= warmed
+    used = {(b, w) for run in seen["runs"] for b, w, _ in run["pairs"]}
+    assert used and used <= warmed, sorted(used - warmed)
+    for b in {b for b, _ in used}:
+        widest = max(w for c, w in used if c == b)
+        # one wave size of margin in every bucket that formed a wave of
+        # two or more (64 and 2048 formed none: ISSUE 40's exception)
+        if widest > 1:
+            assert (b, _WAVES[_WAVES.index(widest) + 1]) in warmed, b
+    assert mix["warm_horizon_s"] >= 1.0        # two rounds of the loop
 
-    from chipbench.lib.replica_hybrid import HybridBenchLLMServer
+
+@pytest.mark.parametrize("replica", [
+    "chipbench.lib.replica.BenchLLMServer",
+    "chipbench.lib.replica_hybrid.HybridBenchLLMServer"])
+def test_the_replica_traces_without_the_python_call_tracer(monkeypatch,
+                                                           replica):
+    """``bench_trace('start')`` of every replica turns the profiler's
+    Python call tracer off and nothing else (PERF.md section 6, PRs 33
+    and 40): one ``lib/trace.py start_trace`` for them and the trainer's
+    worker."""
+    import importlib
+
+    import jax
+    module, name = replica.rsplit(".", 1)
+    cls = getattr(importlib.import_module(module), name)
     seen = {}
     monkeypatch.setattr(
         jax.profiler, "start_trace",
         lambda log_dir, profiler_options=None: seen.update(
             dir=log_dir, options=profiler_options))
-    at = HybridBenchLLMServer.bench_trace(object(), "start", "/some/dir")
+    at = cls.bench_trace(object(), "start", "/some/dir")
     assert at > 0 and seen["dir"] == "/some/dir"
     default = jax.profiler.ProfileOptions()
     assert seen["options"].python_tracer_level == 0
@@ -510,6 +552,16 @@ def test_runner_end_to_end_on_the_cpu():
         assert checks.pop(not_here) is False
     assert all(checks.values()), checks
     done = dict(lines)["serve_done"]
+    # the prefill programs the window ran were warmed, and every wave
+    # the engine counted is a call of one of them
+    warmed = {tuple(p) for p in dict(lines)["replica"]["pairs"]}
+    used = done["prefill_pairs_used"]
+    assert used and {(b, w) for b, w, _ in used} <= warmed
+    assert sum(n for _, _, n in used) == (
+        done["stats1"]["prefill_waves"] - done["stats0"]["prefill_waves"])
+    assert set(record["phases"]) == {
+        "cluster_s", "replica_s", "warm_s", "window_s", "drained_s",
+        "reference_s"}
     assert {m["which"] for m in done["reference"]} == {"short", "long"}
     long = next(m for m in done["reference"] if m["which"] == "long")
     assert set(TOY_MIX["reference"]["limits"]) <= set(long)
