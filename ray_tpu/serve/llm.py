@@ -271,12 +271,21 @@ class LLMServer:
         """The engine's own clock on one request, as the reply carries
         it: ``queue_wait_s + prefill_s == time_to_first_token_s``;
         ``slot_wait_s`` is a prefilled request's wait for a decode slot
-        after the first token (docs/observability.md)."""
+        after the first token; ``stepping_s + prefill_stall_s +
+        block_tail_s == latency_s - time_to_first_token_s`` for a
+        request of two tokens or more (docs/observability.md)."""
         return {"time_to_first_token_s": result.time_to_first_token_s,
                 "latency_s": result.latency_s,
                 "queue_wait_s": result.queue_wait_s,
                 "prefill_s": result.prefill_s,
-                "slot_wait_s": result.slot_wait_s}
+                "slot_wait_s": result.slot_wait_s,
+                **LLMServer._decode_timing(result)}
+
+    @staticmethod
+    def _decode_timing(result) -> Dict[str, float]:
+        return {"stepping_s": result.stepping_s,
+                "prefill_stall_s": result.prefill_stall_s,
+                "block_tail_s": result.block_tail_s}
 
     # ------------------------------------------ disaggregated pool methods
 
@@ -425,6 +434,10 @@ class LLMServer:
                     "prompt_len": handoff.prompt_len,
                     "handoff_pull_ms": round(pull_ms, 3),
                     "latency_s": item.latency_s,
+                    # this engine's clock starts at the import: its
+                    # ``time_to_first_token_s`` is the wait for pages
+                    "time_to_first_token_s": item.time_to_first_token_s,
+                    **self._decode_timing(item),
                 }
                 return
             yield {"token": int(item)}

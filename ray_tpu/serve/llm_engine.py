@@ -150,6 +150,21 @@ class GenerationResult:
     queue_wait_s: float = 0.0
     prefill_s: float = 0.0
     slot_wait_s: float = 0.0
+    # where the time after the first token went, on the same clock:
+    # stepping_s + prefill_stall_s + block_tail_s == latency_s -
+    # time_to_first_token_s for a request of two tokens or more (all
+    # three 0.0 for one that ended at its first).  prefill_stall_s: the
+    # device seconds of OTHER requests' prefill waves that ran ahead of
+    # decode blocks this one rode, after the block that first stepped
+    # it; block_tail_s: from the instant the device produced its last
+    # token (step k of its last block, placed in the block's own seconds
+    # by k / block_size) to this result's stamp: the junk steps behind
+    # it and the host's delivery up to this row; stepping_s: the rest
+    # (the decode blocks themselves, the first block's wait behind the
+    # request's own wave, what the host adds between blocks)
+    stepping_s: float = 0.0
+    prefill_stall_s: float = 0.0
+    block_tail_s: float = 0.0
 
 
 # re-exported here for engine-local users; defined in ray_tpu.exceptions
@@ -234,7 +249,7 @@ class _Import:
 class _Slot:
     __slots__ = ("request", "pos", "out", "last_token", "first_token_at",
                  "installed_at", "pages", "prompt_len", "borrowed",
-                 "prefix_entry")
+                 "prefix_entry", "stall_base", "last_step_at")
 
     def __init__(self, request: _Request, prompt_len: int,
                  first_token: Optional[int],
@@ -243,6 +258,11 @@ class _Slot:
         self.request = request
         self.pos = prompt_len            # next write position
         self.installed_at: Optional[float] = None   # took a decode slot
+        # LLMEngine._stall_s when the first block that stepped it was
+        # delivered (waves ahead of later blocks are other requests'),
+        # and the instant the device produced its last token
+        self.stall_base: Optional[float] = None
+        self.last_step_at: Optional[float] = None
         # None: a prefill wave is computing it (``set_first`` when the
         # host has fetched it)
         if first_token is not None:
@@ -292,6 +312,35 @@ class _Prefilled:
         # the decode slot it took in the block dispatched behind its
         # prefill wave; None: it waits in ``_ready``
         self.slot: Optional[int] = None
+
+
+class _Ahead:
+    """What was dispatched in front of one decode block (prefill waves,
+    imports' scatters), for the account of where that block's interval
+    went (``LLMEngine._account_block``).  ``start``: when the device
+    turned to it (the previous block's fetch came back, or the dispatch
+    where nothing was in flight).  ``watch``: the LAST wave's first
+    tokens on the device while the loop has not seen them ready (waves
+    run in order, so the last one's end is the end of all); ``seen``:
+    the last look that found them not ready.  ``wave_s``: their device
+    seconds once the loop has seen the end, None until then.  ``key``:
+    the one prefill program this is, where it is one and nothing else;
+    ``like``: the seconds such programs took when last seen alone, None
+    where one of them never was."""
+
+    __slots__ = ("waves", "key", "like", "watch", "start", "seen",
+                 "wave_s")
+
+    def __init__(self, scatters: int, prefills: list, wave_like: dict):
+        self.waves = scatters + len(prefills)
+        keys = [key for _, _, key in prefills]
+        self.key = keys[0] if self.waves == 1 and keys else None
+        self.like: Optional[float] = None
+        if keys and not scatters and all(k in wave_like for k in keys):
+            self.like = sum(wave_like[k] for k in keys)
+        self.watch = prefills[-1][0] if prefills else None
+        self.start = self.seen = 0.0
+        self.wave_s: Optional[float] = None
 
 
 class EngineStats:
@@ -357,6 +406,24 @@ class EngineStats:
         self.fetch_wait_s = 0.0          # blocked: device -> host fetches
         self.deliver_s = 0.0             # per-token bookkeeping, callbacks
         self._loop_mark: Optional[float] = None
+        # the time between first and last token, summed over finished
+        # requests of two tokens or more (GenerationResult has the
+        # parts): prefill_stall_row_s / decode_row_s is the share of
+        # decode time spent behind other requests' prompts
+        self.decode_row_s = 0.0
+        self.prefill_stall_row_s = 0.0
+        self.block_tail_row_s = 0.0
+        # (device seconds of prefill waves and import scatters the loop
+        # has accounted for, every wave once; since when waves have been
+        # running that it has not yet seen the end of; the last of them's
+        # first tokens on the device; what such waves took before): one
+        # tuple, swapped whole, so that a snapshot from another thread
+        # counts waves under way up to now, and never twice
+        self._wave: tuple = (0.0, None, None, 0.0)
+
+    @property
+    def prefill_wave_s(self) -> float:
+        return self._wave[0]
 
     def occupancy(self, num_slots: int) -> float:
         """Fraction of step-slots that produced a delivered token (junk
@@ -365,6 +432,14 @@ class EngineStats:
                 if self.steps else 0.0)
 
     def snapshot(self, num_slots: int) -> dict:
+        wave_s, since, watch, like = self._wave
+        if since is not None:
+            # still running: all of it up to now; ended at a moment the
+            # loop has not seen yet (it is blocked behind the block, or
+            # about to look): no more than such waves took before
+            under_way = max(0.0, time.monotonic() - since)
+            wave_s += (under_way if not watch.is_ready()
+                       else min(under_way, like))
         return {
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
@@ -396,6 +471,10 @@ class EngineStats:
             "idle_wait_s": self.idle_wait_s,
             "fetch_wait_s": self.fetch_wait_s,
             "deliver_s": self.deliver_s,
+            "decode_row_s": self.decode_row_s,
+            "prefill_stall_row_s": self.prefill_stall_row_s,
+            "block_tail_row_s": self.block_tail_row_s,
+            "prefill_wave_s": wave_s,
         }
 
 
@@ -556,6 +635,20 @@ class LLMEngine:
             range(1, self.kv_pool_pages))[::-1]
         self._ready: collections.deque = collections.deque()
         self._stale_slots: set = set()    # evicted, redirect pending
+        # the loop thread's account of each block's interval (fetch to
+        # fetch; ``_account_block``): when the last block's fetch came
+        # back, that block's own seconds (its interval less the waves
+        # ahead of it), the wave seconds of every block fetched so far
+        # (a slot's ``stall_base`` is a mark on it); and what it has
+        # measured to reckon by where it cannot see: the seconds each
+        # prefill program took when last it ran alone in an iteration,
+        # by (bucket, wave size, suffix), and the last block's own
+        # seconds that were measured and not reckoned
+        self._fetched_at = 0.0
+        self._block_s = 0.0
+        self._stall_s = 0.0
+        self._wave_like: dict = {}
+        self._block_like: Optional[float] = None
         self._imports: collections.deque = collections.deque()
         # admitted-handoff wait-queue bound: beyond it import_prefill
         # rejects SYNCHRONOUSLY (KVPoolFullError) so the caller can
@@ -1314,18 +1407,36 @@ class LLMEngine:
         admitted_at = req.admitted_at or req.submitted_at
         queue_wait_s = admitted_at - req.submitted_at
         prefill_s = sl.first_token_at - admitted_at
+        # the sum, so that the two parts add up to it exactly
+        ttft_s = queue_wait_s + prefill_s
+        latency_s = now - req.submitted_at
+        stepping_s = stall_s = tail_s = 0.0
+        if sl.last_step_at is not None:
+            # it ended in a block, so it has two tokens or more and
+            # ``_deliver_block`` left its marks: tail and stall as
+            # measured, each held inside what is left of the decode
+            # time, stepping the remainder
+            st = self.stats
+            decode_s = max(0.0, latency_s - ttft_s)
+            tail_s = min(max(0.0, now - sl.last_step_at), decode_s)
+            stall_s = min(max(0.0, self._stall_s - sl.stall_base),
+                          decode_s - tail_s)
+            stepping_s = max(0.0, decode_s - stall_s - tail_s)
+            st.decode_row_s += decode_s
+            st.prefill_stall_row_s += stall_s
+            st.block_tail_row_s += tail_s
         result = GenerationResult(
             tokens=sl.out, finish_reason=reason,
             prompt_len=sl.pos - len(sl.out) + 1,
-            # the sum, so that the two parts add up to it exactly
-            time_to_first_token_s=queue_wait_s + prefill_s,
-            latency_s=now - req.submitted_at,
+            time_to_first_token_s=ttft_s, latency_s=latency_s,
             queue_wait_s=queue_wait_s, prefill_s=prefill_s,
             # a request installed with its prefill was stepping before
             # the host had its first token: it waited for no slot
             slot_wait_s=(0.0 if sl.installed_at is None
                          else max(0.0, sl.installed_at
-                                  - sl.first_token_at)))
+                                  - sl.first_token_at)),
+            stepping_s=stepping_s, prefill_stall_s=stall_s,
+            block_tail_s=tail_s)
         self.stats.requests_completed += 1
         self._safe_deliver(req, True, result)
 
@@ -1361,9 +1472,11 @@ class LLMEngine:
                 # what a wave writes to the pool a token a layer
                 pool_row=self._pool_tail[0] * self._pool_tail[2])
 
-    def _deliver_block(self, block, rows: list) -> None:
+    def _deliver_block(self, block, rows: list, ahead: _Ahead) -> None:
         """Hand one fetched decode block's tokens to their requests,
-        truncating junk past each row's finish."""
+        truncating junk past each row's finish.  ``ahead`` is what the
+        device runs meanwhile, in front of the NEXT block: a look at it
+        after each row brackets the end of its waves."""
         with self._phase("deliver_block", "deliver_s") as sp:
             st = self.stats
             st.steps += self.block_size
@@ -1388,17 +1501,94 @@ class LLMEngine:
                     reason = self._finish_reason(sl, self.cfg.max_seq_len)
                     if reason is not None:
                         break     # rest of the row is junk past eos
+                if sl.stall_base is None:
+                    # its first block: the waves ahead of it (its own,
+                    # or those it waited behind for a slot) are not a
+                    # stall between its tokens
+                    sl.stall_base = self._stall_s
                 self._count_decode_pages(pos0 + 1, sl.pos)
                 st.gdn_state_rows += (sl.pos - pos0) * self._state_layers
                 # steps at positions pos0 .. pos - 1 read pos0 + 1 .. pos
                 st.mla_context_tokens += self._latent_layers * (
                     (sl.pos - pos0) * (sl.pos + pos0 + 1) // 2)
                 if reason is not None:
+                    # step k + 1 of the block produced its last token;
+                    # the steps behind it are junk
+                    sl.last_step_at = self._fetched_at - self._block_s * (
+                        self.block_size - 1 - k) / self.block_size
                     # counted first: whoever holds the result may read
                     # the counters
                     self._evict(i, reason)
-            sp.set_metadata(tokens=st.step_tokens - tokens0,
+                if ahead.watch is not None:
+                    self._look(ahead)
+            sp.set_metadata(block=st.quanta,
+                            tokens=st.step_tokens - tokens0,
                             finished=st.requests_completed - done0)
+
+    # ------------------------------------- where a block's interval went
+    #
+    # The device runs, back to back, what the loop dispatched: the waves
+    # of iteration i, block i, the waves of iteration i+1, block i+1.  A
+    # block's fetch comes back when the block is done, so the interval
+    # from one block's fetch to the next is the device's seconds for
+    # the waves ahead of the later block plus that block.  Where the
+    # waves end inside it the loop learns without touching the device's
+    # stream, by looking (``jax.Array.is_ready``) at the last wave's
+    # first tokens: after each row it delivers of the block before, and
+    # around the fetch of those tokens, which blocks until the wave is
+    # done where it is the only one.  Where it saw no end (several
+    # waves, whose tokens are joined BEHIND the block) it reckons: each
+    # wave as that program went when last it ran alone, or, where one
+    # never did (or imports were scattered), the interval's excess over
+    # the last block that was measured.
+
+    def _begin(self, ahead: _Ahead, at: float) -> None:
+        """The device turns to what is ahead of the next block."""
+        ahead.start = ahead.seen = at
+        if ahead.watch is not None:
+            # one wave's end is always seen, and at once; several
+            # waves' end may not be
+            like = float("inf") if ahead.key else ahead.like or 0.0
+            self.stats._wave = (self.stats._wave[0], at, ahead.watch, like)
+
+    def _look(self, ahead: _Ahead) -> None:
+        now = time.monotonic()
+        if ahead.watch.is_ready():
+            # done somewhere between the last look and this one
+            self._waves_done(ahead, (ahead.seen + now) / 2)
+        else:
+            ahead.seen = now
+
+    def _waves_done(self, ahead: _Ahead, at: float) -> None:
+        ahead.watch = None
+        ahead.wave_s = max(0.0, at - ahead.start)
+        self.stats._wave = (self.stats._wave[0] + ahead.wave_s, None, None,
+                            0.0)
+
+    def _account_block(self, ahead: _Ahead, done: float):
+        """A block's fetch came back at ``done``: split the interval
+        since the device turned to it into the waves ahead of it and its
+        own seconds.  Returns (interval, wave seconds)."""
+        interval = max(0.0, done - ahead.start)
+        measured = not ahead.waves or ahead.wave_s is not None
+        if not measured:
+            if ahead.like is not None:
+                reckoned = ahead.like
+            elif self._block_like is not None:
+                reckoned = interval - self._block_like
+            else:
+                reckoned = 0.0
+            self._waves_done(
+                ahead, ahead.start + min(max(0.0, reckoned), interval))
+        elif ahead.key:
+            self._wave_like[ahead.key] = ahead.wave_s
+        wave_s = min(ahead.wave_s or 0.0, interval)
+        self._fetched_at = done
+        self._block_s = interval - wave_s
+        self._stall_s += wave_s
+        if measured:
+            self._block_like = self._block_s
+        return interval, wave_s
 
     def _count_decode_pages(self, first: int, last: int) -> None:
         """``EngineStats.decode_pages_read`` and ``window_pages_*`` for
@@ -1714,14 +1904,16 @@ class LLMEngine:
                     f"request needs {self._pages_needed(req)} KV pages; "
                     f"pool holds {self.kv_pool_pages - 1}"))
             try:
+                dispatched_at = time.monotonic()
                 # scatter imports BEFORE taking installs: an imported
                 # request can land in a free slot this same iteration,
                 # and the block step is dispatched after the scatter so
                 # stream order covers its page writes
+                scatters = 0
                 if import_todo:
                     with self._phase("dispatch_import") as sp:
                         sp.set_metadata(requests=len(import_todo))
-                        self._dispatch_import_waves(import_todo)
+                        scatters = self._dispatch_import_waves(import_todo)
                 new_prefills = []
                 if todo or hits:
                     with self._prefill_phase():
@@ -1738,18 +1930,26 @@ class LLMEngine:
                     # follows their prefill on the device
                     early = self._install_with_prefill(new_prefills,
                                                        installs)
+                ahead = _Ahead(scatters, new_prefills, self._wave_like)
                 with self._phase("dispatch_block") as sp:
                     nxt = self._dispatch_block(installs)
                     sp.set_metadata(installs=len(installs), early=early,
                                     active=len(nxt[1]) if nxt else 0)
                 if inflight is not None:
-                    self._process_block(inflight)
-                exports = self._process_prefill_waves(new_prefills)
+                    self._process_block(inflight, ahead)
+                else:
+                    # nothing in flight: the device turns to this
+                    # iteration's work as it is dispatched
+                    self._begin(ahead, dispatched_at)
+                exports = self._process_prefill_waves(
+                    new_prefills, ahead, alone=nxt is None)
                 if exports:
                     with self._phase("export") as sp:
                         sp.set_metadata(requests=len(exports))
                         self._process_exports(exports)
-                inflight = nxt
+                # with what runs ahead of it, for the account of its
+                # interval when its fetch comes back
+                inflight = None if nxt is None else (*nxt, ahead)
             except Exception as e:   # engine-fatal (OOM, compile error)
                 with self._lock:
                     victims = (
@@ -1773,6 +1973,8 @@ class LLMEngine:
                     self._stale_slots.clear()
                 self._prefix_reset()
                 inflight = None
+                self._block_like = None
+                self.stats._wave = (self.stats._wave[0], None, None, 0.0)
                 self._cache = self._init_cache(self._rows)
                 self._state = self._init_state(0)
                 for req in victims:
@@ -1786,7 +1988,7 @@ class LLMEngine:
         its first token whatever that is: neither decodes here.  Returns
         how many."""
         early = 0
-        for firsts, metas in waves:
+        for firsts, metas, _ in waves:
             for row, pf in enumerate(metas):
                 if not self._free:
                     break
@@ -1804,7 +2006,8 @@ class LLMEngine:
     def _dispatch_prefill_waves(self, todo: list) -> list:
         """Batch queued prompts into (bucket, wave) prefill calls that
         write straight into their reserved pages.  Device dispatch only —
-        first tokens are fetched later in the iteration."""
+        first tokens are fetched later in the iteration.  Returns a
+        (firsts, metas, key) a wave, ``key`` naming its program."""
         out = []
         for bucket, chunk, wave in self._wave_chunks(todo):
             packed = np.zeros((wave, self.packed_width(bucket)), np.int32)
@@ -1828,7 +2031,7 @@ class LLMEngine:
             self._count_prefill_wave(
                 len(chunk), sum(len(req.prompt) for req, _ in chunk),
                 wave * bucket)
-            out.append((firsts, metas))
+            out.append((firsts, metas, (bucket, wave, False)))
         return out
 
     def _dispatch_suffix_waves(self, todo: list) -> list:
@@ -1836,7 +2039,7 @@ class LLMEngine:
         offset prefill — each row's leading table entries are borrowed
         read-only prefix pages, the window starts at the page-aligned
         cover and writes only fresh pages.  Output rides the same
-        (firsts, metas) shape as _dispatch_prefill_waves."""
+        (firsts, metas, key) shape as _dispatch_prefill_waves."""
         out = []
         by_bucket: dict = {}
         for item in todo:
@@ -1872,32 +2075,40 @@ class LLMEngine:
                 self._count_prefill_wave(
                     len(chunk), int(packed[:len(chunk), bucket].sum()),
                     wave * bucket)
-                out.append((firsts, metas))
+                out.append((firsts, metas, (bucket, wave, True)))
         return out
 
-    def _process_prefill_waves(self, waves: list) -> list:
+    def _process_prefill_waves(self, waves: list, ahead: _Ahead,
+                               alone: bool) -> list:
         """Fetch this iteration's prefill first-tokens with ONE combined
         device->host transfer (each fetch is a host sync with a fixed
         latency; a saturation burst dispatches many waves per
         iteration) and complete/queue each request.  Returns the
         export-flagged requests (first token now known) for
-        _process_exports."""
+        _process_exports.  ``alone``: no block was dispatched behind
+        the waves."""
         if not waves:
             return []
+        if ahead.watch is not None:
+            self._look(ahead)
         with self._phase("fetch_prefill", "fetch_wait_s"):
             if len(waves) == 1:
                 host = np.asarray(waves[0][0])
             else:
-                host = np.asarray(jnp.concatenate([f for f, _ in waves]))
+                host = np.asarray(jnp.concatenate([f for f, *_ in waves]))
+        if ahead.watch is not None and (len(waves) == 1 or alone):
+            # the fetch waited for the waves' end and for nothing else
+            # (several waves' tokens are joined BEHIND the block)
+            self._waves_done(ahead, time.monotonic())
         off = 0
         exports = []
         with self._phase("deliver_prefill", "deliver_s") as sp:
-            for firsts, metas in waves:
+            for firsts, metas, _ in waves:
                 n = firsts.shape[0]
                 exports.extend(self._complete_prefills(metas,
                                                        host[off:off + n]))
                 off += n
-            sp.set_metadata(requests=sum(len(m) for _, m in waves))
+            sp.set_metadata(requests=sum(len(m) for _, m, _ in waves))
         return exports
 
     def _complete_prefills(self, metas, host) -> list:
@@ -1994,14 +2205,14 @@ class LLMEngine:
                         temperature=req.temperature, eos_id=req.eos_id,
                         export_ms=ms))
 
-    def _dispatch_import_waves(self, todo: list) -> None:
+    def _dispatch_import_waves(self, todo: list) -> int:
         """Scatter admitted handoffs' prompt K/V into their freshly
         allocated pages — one packed upload + one jitted remap per
         (page-bucket, wave) group — and queue them ready-to-install
         with their first token already known.  No prefill compute, no
-        fetch: the decode-only admission path."""
-        if not todo:
-            return
+        fetch: the decode-only admission path.  Returns the scatter
+        programs dispatched."""
+        scatters = 0
         groups: dict = {}
         for imp, pages in todo:
             groups.setdefault(self._page_bucket(imp.handoff.npages),
@@ -2021,6 +2232,7 @@ class LLMEngine:
                     idx[r, :h.npages] = pages[:h.npages]
                 self._cache = self._get_import(bucket, wave)(
                     self._cache, jnp.asarray(kvbuf), jnp.asarray(idx))
+                scatters += 1
                 if self.on_import_admit is not None:
                     ms = (time.monotonic() - t0) * 1e3 / len(chunk)
                     for _ in chunk:
@@ -2043,6 +2255,7 @@ class LLMEngine:
                     table[:len(pages)] = pages
                     with self._lock:
                         self._ready.append(_Prefilled(sl, table))
+        return scatters
 
     def _dispatch_block(self, installs: list):
         """Install requests into free slots, attach redirect rows for
@@ -2101,14 +2314,25 @@ class LLMEngine:
                 if s is not None]
         return (combined, rows)
 
-    def _process_block(self, quantum) -> None:
-        combined, rows = quantum
-        with self._phase("fetch_block", "fetch_wait_s"):
+    def _process_block(self, quantum, nxt_ahead: _Ahead) -> None:
+        """Fetch and deliver one block (``quantum``: what
+        ``_dispatch_block`` returned, and what was dispatched ahead of
+        it); ``nxt_ahead`` is what was dispatched behind it, which the
+        device turns to now."""
+        combined, rows, ahead = quantum
+        with self._phase("fetch_block", "fetch_wait_s") as sp:
             host = np.asarray(combined)    # the ONE fetch this quantum
+            done = time.monotonic()
+            interval, wave_s = self._account_block(ahead, done)
+            self._begin(nxt_ahead, done)
+            sp.set_metadata(block=self.stats.quanta + 1, rows=len(rows),
+                            waves=ahead.waves,
+                            interval_ms=round(1e3 * interval, 3),
+                            wave_ms=round(1e3 * wave_s, 3))
         if self._counts_expert_load:
             st = self.stats
             host, (steps, touched) = host[:-2], host[-2:]
             st.moe_layer_steps += int(steps)
             st.moe_experts_touched += int(touched)
         self._deliver_block(host.reshape(self._rows, self.block_size),
-                            rows)
+                            rows, nxt_ahead)

@@ -89,6 +89,25 @@ def test_reply_splits_time_to_first_token(server, entry, n_tokens):
         assert w <= reply["latency_s"] - reply["time_to_first_token_s"]
 
 
+@pytest.mark.parametrize("entry", ["call", "stream"])
+@pytest.mark.parametrize("n_tokens", [1, 11])
+def test_reply_splits_time_after_first_token(server, entry, n_tokens):
+    """stepping + prefill stall + block tail IS the time after the first
+    token (ISSUE 41), on ``__call__``'s reply and ``.stream``'s summary;
+    a request that ends at its first token has none of the three."""
+    reply = asyncio.run(_reply(server, entry, n_tokens))
+    parts = [reply[k] for k in
+             ("stepping_s", "prefill_stall_s", "block_tail_s")]
+    assert all(p >= 0.0 for p in parts)
+    if n_tokens == 1:
+        assert parts == [0.0, 0.0, 0.0]
+    else:
+        assert sum(parts) == pytest.approx(
+            reply["latency_s"] - reply["time_to_first_token_s"], abs=1e-9)
+        assert parts[1] == 0.0          # nobody else's prompt came
+        assert parts[0] > 0.0 and parts[2] > 0.0
+
+
 def test_engine_time_accounts_and_prefill_counters(server):
     """The loop thread's accounts only grow, the parts never exceed the
     whole, padded prefill tokens are at least the real ones, and one
@@ -159,6 +178,20 @@ def test_engine_spans_on_the_trace_clock(server, tmp_path):
     delivered = sum(a["tokens"] for n, a in spans
                     if n == "engine.deliver_block")
     assert delivered == 9               # 10 asked, the first from prefill
+    # a block's fetch and its delivery carry one number, the quantum's,
+    # and the fetch says where the interval since the last fetch went
+    fetched = [a for n, a in spans if n == "engine.fetch_block"]
+    numbers = [a["block"] for a in fetched]
+    assert numbers == list(range(before["quanta"] + 1,
+                                 after["quanta"] + 1))
+    assert numbers == [a["block"] for n, a in spans
+                       if n == "engine.deliver_block"]
+    assert sum(a["waves"] for a in fetched) == 1    # the one that decoded
+    assert all(a["rows"] >= 1 and 0.0 <= a["wave_ms"] <= a["interval_ms"]
+               for a in fetched)
+    assert all((a["wave_ms"] > 0.0) == (a["waves"] > 0) for a in fetched)
+    assert 1e3 * (after["prefill_wave_s"] - before["prefill_wave_s"]) \
+        >= sum(a["wave_ms"] for a in fetched) - 1e-2
     # not this (the test's) thread: its own marker lies on another line
     jax.profiler.start_trace(str(tmp_path / "idle"))
     try:
