@@ -176,13 +176,22 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
 
             def body(mdl, x_carry, layer):
                 return mdl(x_carry[0], *call_args, x_carry[1], layer), None
+        # no broadcast variable and no broadcast output here, so flax
+        # need not trace the body a second time, under partial
+        # evaluation, to check them constant: that pass was half of
+        # every program's tracing, and walks a loop inside the body
+        # (``Block._chunked``) twice more.  ``init`` keeps it: the pass
+        # draws on the parameters' rng, so without it a seed would give
+        # other weights than it did
+        stacked = block_cls(cfg, **ctor_kwargs, name=name)
         out, _ = nn.scan(
             body,
             variable_axes=variable_axes,
             split_rngs={"params": True},
             length=n_layers,
             metadata_params={nn.PARTITION_NAME: None},
-        )(block_cls(cfg, **ctor_kwargs, name=name), init, layers)
+            check_constancy_invariants=stacked.is_initializing(),
+        )(stacked, init, layers)
         return out
     for i in range(n_layers):
         block = block_cls(cfg, **ctor_kwargs, name=f"{name[:-1]}_{i}")
@@ -203,7 +212,7 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
 _PREFILL_SCORE_BYTES = 1 << 30
 
 
-def _prefill_attend(q, k, v, sm_scale=None):
+def _prefill_attend(q, k, v, sm_scale=None, lengths=None):
     """Causal attention of each row of a prefill wave over itself.
     ``xla_attention`` as it is where the wave's scores fit
     ``_PREFILL_SCORE_BYTES``; else the same over the largest groups of
@@ -214,7 +223,13 @@ def _prefill_attend(q, k, v, sm_scale=None):
     blocks of that row's queries, each against the keys up to its causal
     edge (unrolled: a block's key span is static).  Materialised, such a
     row's softmax fusions ran at a twentieth of the chip's bandwidth
-    (PERF.md section 6, PR 37)."""
+    (PERF.md section 6, PR 37).
+
+    ``lengths`` [B] (None: every position is real): each row's real
+    length.  The flash kernel leaves out the query spans past it, whose
+    output is zeros; the other paths compute the right-pad too, finite
+    values that nobody reads.  A real position's output is the same
+    either way: under the causal mask it sees no key past itself."""
     b, t, h, d = q.shape
     row_bytes = 4 * h * t * t
     if row_bytes > _PREFILL_SCORE_BYTES:
@@ -222,7 +237,7 @@ def _prefill_attend(q, k, v, sm_scale=None):
         if resolve_impl("auto") == "flash" and t % 128 == 0:
             wide = jnp.pad(v, [(0, 0)] * 3 + [(0, d - v.shape[-1])])
             return attention(q, k, wide, causal=True, sm_scale=sm_scale,
-                             impl="flash")[..., :v.shape[-1]]
+                             impl="flash", q_lens=lengths)[..., :v.shape[-1]]
         blocks = 2
         while row_bytes // blocks > _PREFILL_SCORE_BYTES and t % (
                 2 * blocks) == 0:
@@ -265,7 +280,7 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
-                 pool=None, layer=None, live=None):
+                 pool=None, layer=None, live=None, lengths=None, part=None):
         """``pool`` (paged decode, serve/llm_engine.py paged mode): the
         model's ONE stacked KV page pool ``[layers, pages, kv_heads,
         page_size, 2*head_dim]`` (GPT declares it; see
@@ -273,7 +288,35 @@ class Attention(nn.Module):
         it.  Returns ``(out, pool)`` then: the pool is passed through,
         updated in place, never sliced.  ``live`` [rows] bool (``Block``
         makes it): the rows that hold a request, the only ones a decode
-        step reads pages for."""
+        step reads pages for.  ``lengths`` [rows] (a prompt wave): the
+        rows' real lengths (``_prefill_attend``).
+
+        ``part`` (``Block``'s chunked prefill, which runs the token-wise
+        work a chunk of positions at a time and the attention whole):
+        ``"project"`` maps ``x`` to the rotated ``(q, k, v)``,
+        ``"attend"`` takes that triple as ``x`` and returns ``(heads'
+        outputs [B, T, heads * head_dim], pool)``, ``"output"`` maps
+        those to the layer's output.  None is the three in a row."""
+        if part == "output":
+            return self._output(x)
+        qkv = x if part == "attend" else self._project(
+            x, cos, sin, positions, layer)
+        if part == "project":
+            return qkv
+        out, pool_out = self._attend(*qkv, positions, block_tables, pool,
+                                     layer, live, lengths)
+        if part == "attend":
+            return out, pool_out
+        out = self._output(out)
+        return out if pool is None else (out, pool_out)
+
+    def projected(self, b: int, t: int):
+        """The shapes of what ``part="project"`` makes of ``[b, t, d]``."""
+        cfg = self.cfg
+        return tuple(jax.ShapeDtypeStruct((b, t, h, cfg.head_dim), cfg.dtype)
+                     for h in (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads))
+
+    def _project(self, x, cos, sin, positions, layer):
         cfg = self.cfg
         h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         q = _dense((h, hd), ("embed", "heads", "head_dim"), "wq",
@@ -287,7 +330,7 @@ class Attention(nn.Module):
                 q.reshape(*q.shape[:2], h * hd)).reshape(q.shape)
             k = RMSNorm(cfg.norm_eps, name="k_norm")(
                 k.reshape(*k.shape[:2], kvh * hd)).reshape(k.shape)
-        rotate, window = self._layer_kind(layer)
+        rotate, _ = self._layer_kind(layer)
         if cfg.rope_theta is None:
             pass                            # no layer rotates
         elif rotate is None:
@@ -296,19 +339,25 @@ class Attention(nn.Module):
         else:       # this layer's entry of rope_layout: 0 -> no positions
             q = jnp.where(rotate, apply_rope(q, cos, sin, positions), q)
             k = jnp.where(rotate, apply_rope(k, cos, sin, positions), k)
+        return q, k, v
 
+    def _attend(self, q, k, v, positions, block_tables, pool, layer, live,
+                lengths):
+        _, window = self._layer_kind(layer)
         if pool is not None:
             out, pool = self._decode_attend_paged(
                 q, k, v, positions, block_tables, pool, layer, window,
-                live)
+                live, lengths)
         elif self.decode:
             out = self._decode_attend(q, k, v, positions, window)
         else:
             out = self._train_attend(q, k, v, window)
-        out = out.reshape(*out.shape[:2], h * hd)
-        out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
-                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
-        return out if pool is None else (out, pool)
+        return out.reshape(*out.shape[:2], -1), pool
+
+    def _output(self, out):
+        cfg = self.cfg
+        return _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
+                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
 
     def _layer_kind(self, layer):
         """``(rotate, window)`` of layer ``layer`` (an int, or the traced
@@ -454,7 +503,8 @@ class Attention(nn.Module):
         return xla_attention(q, ck.value, cv.value, causal=False, mask=mask)
 
     def _decode_attend_paged(self, q, k, v, positions, block_tables,
-                             pool, layer, window=None, live=None):
+                             pool, layer, window=None, live=None,
+                             lengths=None):
         """Paged-pool decode: write this call's K/V into the rows' pages
         of layer ``layer``, then attend over only the occupied pages
         (ops/paged_attention.py).  Returns ``(out, pool)``.
@@ -471,9 +521,13 @@ class Attention(nn.Module):
         T > 1 case (windows start on a page boundary: write_kv_pages):
         the window is causal over itself (a prompt attends only to its
         own prefix), so no pool read is needed — the write below is the
-        whole cache interaction, and right-pad garbage past
-        a real prompt is overwritten by decode writes before any length
-        mask makes it visible (same invariant as dense slot mode).
+        whole cache interaction.  What it writes past a real prompt's
+        end (``lengths``, a wave's real lengths) is the right-pad's
+        rows where they were computed and ZEROS where ``Block`` and the
+        flash kernel skipped them: finite either way, which is what the
+        decode kernel needs of a row it multiplies by a masked
+        probability of 0, and overwritten by decode writes before any
+        length makes it visible.
 
         ``window`` (this layer's, traced; None without one): decode
         reads only the pages that hold the last ``window`` positions,
@@ -503,7 +557,7 @@ class Attention(nn.Module):
             window = self._window_over(window, q.shape[1])
             if window is not None:
                 return self._window_attend(q, k, v, window), pool
-            return _prefill_attend(q, k, v), pool
+            return _prefill_attend(q, k, v, lengths=lengths), pool
         # suffix prefill: the window's keys are NOT the whole story —
         # leading block-table entries hold a cached prompt prefix, so
         # gather the row's full logical span back out of the pool and
@@ -586,11 +640,22 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
-                 pool=None, layer=None, live=None):
+                 pool=None, layer=None, live=None, lengths=None, part=None):
+        """``part`` as in ``Attention.__call__``; what ``"project"`` hands
+        ``"attend"`` is ``(q, k, v, row)``: the prompt's keys and values
+        a head EXPANDED already (token-wise work as the projections are)
+        and the latent row ``[c | k_rope]`` the pages take."""
         cfg = self.cfg
         h, r = cfg.n_heads, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
+        if part == "output":
+            return self._output(x)
+        if part == "attend":
+            q, k, v, row = x
+            pool = self._write_rows(row, positions, block_tables, pool, layer)
+            out = _prefill_attend(q, k, v, lengths=lengths)
+            return out.reshape(*out.shape[:2], h * dv), pool
         q = _dense((h, dn + dr), ("embed", "heads", "head_dim"), "wq",
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
         ckv = _dense(r + dr, ("embed", "head_dim"), "wkv_a",
@@ -605,20 +670,36 @@ class LatentAttention(nn.Module):
         k_rope = rope(ckv[..., None, r:], cos, sin, positions)[:, :, 0]
         q = jnp.concatenate(
             [q[..., :dn], rope(q[..., dn:], cos, sin, positions)], -1)
+        if part == "project":
+            return (q, *self._expand(c, k_rope, wkv_b),
+                    jnp.concatenate([c, k_rope], -1))
 
         if pool is not None:
             out, pool = self._decode_attend_paged(
                 q, c, k_rope, wkv_b, positions, block_tables, pool, layer,
-                live)
+                live, lengths)
         elif self.decode:
             out = self._attend_cached(q, c, k_rope, wkv_b, positions)
         else:
             out = xla_attention(q, *self._expand(c, k_rope, wkv_b),
                                 causal=True)
-        out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
-                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
-            out.reshape(*out.shape[:2], h * dv))
+        out = self._output(out.reshape(*out.shape[:2], h * dv))
         return out if pool is None else (out, pool)
+
+    def _output(self, out):
+        cfg = self.cfg
+        return _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
+                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
+
+    def projected(self, b: int, t: int):
+        """The shapes of what ``part="project"`` makes of ``[b, t, d]``."""
+        cfg = self.cfg
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        return tuple(jax.ShapeDtypeStruct((b, t) + tail, cfg.dtype)
+                     for tail in ((cfg.n_heads, dn + dr),
+                                  (cfg.n_heads, dn + dr),
+                                  (cfg.n_heads, cfg.v_head_dim),
+                                  (cfg.kv_lora_rank + dr,)))
 
     def _expand(self, c, k_rope, wkv_b):
         """``(k [B, T, heads, dn + dr], v [B, T, heads, dv])`` of latent
@@ -657,17 +738,9 @@ class LatentAttention(nn.Module):
             q, k, v, causal=False,
             mask=window_mask(positions, jnp.arange(cfg.max_seq_len)))
 
-    def _decode_attend_paged(self, q, c, k_rope, wkv_b, positions,
-                             block_tables, pool, layer, live):
-        """``Attention._decode_attend_paged`` for latent rows: write this
-        call's rows ``[c | k_rope | 0]`` into the rows' pages of layer
-        ``layer``; a prompt (``T > 1``) then attends EXPANDED over its own
-        keys (no pool read), a decode step ABSORBED over the occupied
-        pages.  Returns ``(out [B, T, heads, dv], pool)``."""
-        cfg = self.cfg
-        if self.is_initializing():
-            return xla_attention(q, *self._expand(c, k_rope, wkv_b),
-                                 causal=True), pool
+    def _write_rows(self, row, positions, block_tables, pool, layer):
+        """``pool`` with the latent rows ``row [B, T, r + dr]`` written,
+        ``[row | 0]``, to the rows' pages of layer ``layer``."""
         if positions is None or block_tables is None:
             raise ValueError("paged decode requires positions and "
                              "block_tables")
@@ -677,15 +750,72 @@ class LatentAttention(nn.Module):
                 "no path that gathers a cached prefix's latent rows and "
                 "expands them (kv_b) beside the window's own")
         from ray_tpu.ops.paged_attention import write_kv_pages
-        row = jnp.concatenate([c, k_rope], -1)
-        pool = write_kv_pages(
+        return write_kv_pages(
             pool, _widen(row, pool.shape[-1])[:, :, None], block_tables,
             positions, layer=layer)
+
+    def _decode_attend_paged(self, q, c, k_rope, wkv_b, positions,
+                             block_tables, pool, layer, live, lengths=None):
+        """``Attention._decode_attend_paged`` for latent rows: write this
+        call's rows ``[c | k_rope | 0]`` into the rows' pages of layer
+        ``layer``; a prompt (``T > 1``) then attends EXPANDED over its own
+        keys (no pool read), a decode step ABSORBED over the occupied
+        pages.  Returns ``(out [B, T, heads, dv], pool)``."""
+        cfg = self.cfg
+        if self.is_initializing():
+            return xla_attention(q, *self._expand(c, k_rope, wkv_b),
+                                 causal=True), pool
+        pool = self._write_rows(jnp.concatenate([c, k_rope], -1), positions,
+                                block_tables, pool, layer)
         if q.shape[1] > 1:
-            return _prefill_attend(q, *self._expand(c, k_rope, wkv_b)), pool
+            return _prefill_attend(q, *self._expand(c, k_rope, wkv_b),
+                                   lengths=lengths), pool
         out = absorbed_attention(cfg, q[:, 0], wkv_b, pool, block_tables,
                                  positions[:, 0] + 1, layer=layer, live=live)
         return out[:, None], pool
+
+
+# positions of a prompt wave that ``Block`` runs its token-wise work on
+# at a time (the flash kernel's span, ops/flash_attention.py SPAN): a
+# wave longer than one chunk computes as many as its longest real
+# prompt reaches
+PREFILL_CHUNK = 1024
+
+
+def _in_chunks(span: int) -> bool:
+    """A span of whole chunks, more than one."""
+    return span > PREFILL_CHUNK and span % PREFILL_CHUNK == 0
+
+
+def prefill_positions(span: int, longest: int) -> int:
+    """Of a prompt wave's ``span`` positions a row, those a model told
+    the rows' real lengths does its token-wise work on, ``longest``
+    being the longest of them: the chunks up to it where the span runs
+    in chunks, else every one."""
+    if not _in_chunks(span):
+        return span
+    return min(span, -(-longest // PREFILL_CHUNK) * PREFILL_CHUNK)
+
+
+def _over_chunks(fn, operands, chunks, out):
+    """``fn`` over chunks ``0 .. chunks - 1`` (a traced count) of the
+    position axis, in ONE compiled loop body: ``operands`` is a pytree
+    of ``[B, T, ...]`` arrays, of which ``fn`` is handed ``[B,
+    PREFILL_CHUNK, ...]`` slices and returns a pytree of such slices;
+    ``out`` is that pytree as the ``[B, T, ...]`` shapes of the whole
+    span (given, not traced for: ``fn`` is traced once, as the one pass
+    it replaces was).  Returns the outputs, ZEROS where no chunk ran."""
+    def body(i, out):
+        return jax.tree.map(
+            lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
+                whole, part, i * PREFILL_CHUNK, axis=1),
+            out, fn(jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(
+                    a, i * PREFILL_CHUNK, PREFILL_CHUNK, axis=1),
+                operands)))
+    return jax.lax.fori_loop(
+        0, chunks, body,
+        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), out))
 
 
 class Block(nn.Module):
@@ -700,13 +830,22 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
-                 moe_stacked=None, pool=None, layer=None):
+                 moe_stacked=None, lengths=None, pool=None, layer=None,
+                 part=None):
         """With a paged KV ``pool`` (see Attention) returns ``(x, pool)``:
         the shape ``stack_layers`` carries it through the stack in.
         ``moe_stacked``: the layer stack's whole dropless expert leaves
         (GPT hands them down in decode; see ``DroplessMoE.__call__``).
         ``layer`` counts every layer (the pool's index); the stacked
-        experts' index starts after the dense prefix."""
+        experts' index starts after the dense prefix.
+
+        ``lengths`` [B] (a prompt wave into the pool; None: every
+        position is real): the rows' real lengths.  A wave longer than
+        one ``PREFILL_CHUNK`` then does its token-wise work, everything
+        but the attention itself, on the chunks its longest real prompt
+        reaches and on no other (``_chunked``); any other call is one
+        pass over the whole span.  ``part`` is ``_chunked``'s own: the
+        section ``"before"`` or ``"after"`` the attention alone."""
         cfg = self.cfg
         experts = cfg.moe_experts > 0 and not self.dense_ffn
         # a row whose table starts at the scratch page holds no request
@@ -716,8 +855,7 @@ class Block(nn.Module):
         # cfg.post_norm: each sub-layer reads x and its OUTPUT is normed
         attn_norm = RMSNorm(cfg.norm_eps, name="attn_norm")
         mlp_norm = RMSNorm(cfg.norm_eps, name="mlp_norm")
-        y = x if cfg.post_norm else attn_norm(x)
-        moe = router_logits = None
+        moe = None
         if experts and cfg.moe_dropless:
             from ray_tpu.ops.moe import DroplessMoE
             moe = DroplessMoE(cfg.d_model, cfg.moe_experts, cfg.moe_d_ff,
@@ -727,45 +865,113 @@ class Block(nn.Module):
                               route_scale=cfg.moe_route_scale,
                               held=cfg.moe_experts_held,
                               held_first=cfg.moe_held_first, name="moe")
-            if cfg.moe_router_pre_attn:
-                router_logits = moe.router_logits(y)
         attn_cls = LatentAttention if cfg.kv_lora_rank else Attention
-        y = attn_cls(cfg, self.mesh, self.rules, self.decode,
-                     self.prefix_attend, name="attn")(
-            y, cos, sin, positions, block_tables, pool, layer, live)
+        attn = attn_cls(cfg, self.mesh, self.rules, self.decode,
+                        self.prefix_attend, name="attn")
+        routes_before = moe is not None and cfg.moe_router_pre_attn
+
+        def before(x, positions, project: bool):
+            """-> (the attention's input, or with ``project`` what its
+            ``"project"`` part makes of it; the router's logits where
+            it reads that input)."""
+            y = x if cfg.post_norm else attn_norm(x)
+            router_logits = moe.router_logits(y) if routes_before else None
+            if project:
+                y = attn(y, cos, sin, positions, layer=layer, part="project")
+            return y, router_logits
+
+        def after(x, y, router_logits, output: bool):
+            """The block's output from its input ``x`` and the
+            attention's output ``y`` (with ``output`` the heads', which
+            the attention's ``"output"`` part maps first)."""
+            if output:
+                y = attn(y, cos, sin, part="output")
+            if cfg.post_norm:
+                y = attn_norm(y)
+            y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
+            x = x + y
+            y = x if cfg.post_norm else mlp_norm(x)
+            if moe is not None:
+                routed = moe(y, router_logits, live, moe_stacked,
+                             None if layer is None
+                             else layer - cfg.first_dense_layers)
+                if cfg.moe_shared_experts:    # every token, beside the sum
+                    routed = routed + MLP(
+                        cfg, cfg.moe_shared_experts * cfg.moe_d_ff,
+                        name="shared_mlp")(y)
+                y = routed
+            elif experts:
+                from ray_tpu.ops.moe import MoEMLP
+                y = MoEMLP(cfg.moe_experts, cfg.moe_d_ff,
+                           top_k=cfg.moe_top_k,
+                           capacity_factor=cfg.moe_capacity_factor,
+                           aux_loss_coef=cfg.moe_aux_coef,
+                           dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                           name="moe")(y)
+            else:
+                y = MLP(cfg, name="mlp")(y)
+            if cfg.post_norm:
+                y = mlp_norm(y)
+            y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
+            return x + y
+
+        if part == "before":
+            return before(x, positions, True)
+        if part == "after":
+            return after(*x, True)
+        if (pool is not None and lengths is not None
+                and _in_chunks(x.shape[1])
+                and not self.is_initializing()):
+            return self._chunked(attn, routes_before, x, cos, sin, positions,
+                                 block_tables, lengths, pool, layer, live)
+        y, router_logits = before(x, positions, False)
+        y = attn(y, cos, sin, positions, block_tables, pool, layer, live,
+                 lengths)
         if pool is not None:
             y, pool = y
-        if cfg.post_norm:
-            y = attn_norm(y)
-        y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
-        x = x + y
-        y = x if cfg.post_norm else mlp_norm(x)
-        if moe is not None:
-            routed = moe(y, router_logits, live, moe_stacked,
-                         None if layer is None
-                         else layer - cfg.first_dense_layers)
-            if cfg.moe_shared_experts:        # every token, beside the sum
-                routed = routed + MLP(
-                    cfg, cfg.moe_shared_experts * cfg.moe_d_ff,
-                    name="shared_mlp")(y)
-            y = routed
-        elif experts:
-            from ray_tpu.ops.moe import MoEMLP
-            y = MoEMLP(cfg.moe_experts, cfg.moe_d_ff, top_k=cfg.moe_top_k,
-                       capacity_factor=cfg.moe_capacity_factor,
-                       aux_loss_coef=cfg.moe_aux_coef,
-                       dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                       name="moe")(y)
-        else:
-            y = MLP(cfg, name="mlp")(y)
-        if cfg.post_norm:
-            y = mlp_norm(y)
-        y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
-        x = x + y
+        x = after(x, y, router_logits, False)
         if self.mesh is not None and not self.decode:
             x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
                               self.rules)
         return x if pool is None else (x, pool)
+
+    def _chunked(self, attn, routes_before: bool, x, cos, sin, positions,
+                 block_tables, lengths, pool, layer, live):
+        """A prompt wave of more than one chunk a row, told its real
+        ``lengths``: the sections before and after the attention run a
+        chunk of positions at a time, all rows at once, over ``ceil(max(
+        lengths) / PREFILL_CHUNK)`` chunks, each section ONE loop body
+        (a pure function of this layer's parameters: this block, unbound,
+        applied to a chunk).  Between them the page write and the
+        attention see the whole span, the pool outside both loops.  What
+        no chunk computed is ZEROS: the projections of a skipped
+        position (so the rows the pages get for it, finite), the heads'
+        outputs there where the flash kernel skipped them too, and the
+        block's output, which is the next block's input there.  A real
+        position gets what one pass over the span gives it: the work is
+        per token, experts included (a chunk's sort and grouped products
+        hand a token what the wave's would)."""
+        cfg = self.cfg
+        chunks = -(-jnp.max(lengths) // PREFILL_CHUNK)
+        params = {"params": self.variables["params"]}
+        block = Block(cfg, self.mesh, self.rules, self.decode,
+                      self.prefix_attend, self.dense_ffn, parent=None)
+        projected, router_logits = _over_chunks(
+            lambda cut: block.apply(params, cut[0], cos, sin, cut[1],
+                                    layer=layer, part="before"),
+            (x, positions), chunks,
+            (attn.projected(*x.shape[:2]),
+             jax.ShapeDtypeStruct(x.shape[:2] + (cfg.moe_experts,),
+                                  jnp.float32) if routes_before else None))
+        y, pool = attn(projected, cos, sin, positions, block_tables, pool,
+                       layer, live, lengths, part="attend")
+        x = _over_chunks(
+            lambda cut: block.apply(params, cut, cos, sin,
+                                    block_tables=block_tables, layer=layer,
+                                    part="after"),
+            (x, y, router_logits), chunks,
+            jax.ShapeDtypeStruct(x.shape, x.dtype))
+        return x, pool
 
 
 def _a_log_init(key, shape, dtype):
@@ -955,9 +1161,12 @@ class Period(nn.Module):
                 period * kinds.count(kind) + seen[kind])
             seen[kind] += 1
             if kind == "full_attention":
+                # not told the lengths: a period's prompt waves stay
+                # one pass (its linear layers compute every position)
                 x = Block(self.cfg, self.mesh, self.rules, self.decode,
                           self.prefix_attend, name=f"layer_{j}")(
-                    x, cos, sin, positions, block_tables, None, pool, layer)
+                    x, cos, sin, positions, block_tables, None, None, pool,
+                    layer)
                 if pool is not None:
                     x, pool = x
             else:
@@ -1093,11 +1302,15 @@ class GPT(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False,
                  block_tables=None, lengths=None, state_rows=None):
-        """``lengths`` [B] and ``state_rows`` [B] matter to a model with
-        linear_attention layers only: each row's REAL length in a ``T >
-        1`` call (right-pad that attention never sees would be absorbed
-        by a recurrence; None: every position is real) and each row's
-        entry of the recurrent leaves (None: row i uses entry i)."""
+        """``lengths`` [B]: each row's REAL length in a ``T > 1`` call
+        (None: every position is real).  A linear_attention layer needs
+        it (right-pad that attention never sees would be absorbed by a
+        recurrence); a ``Block`` writing a prompt wave into the pool
+        leaves out the work past the longest of them (``Block``,
+        ``_prefill_attend``), and what a row holds past its own is then
+        finite and otherwise unspecified.  ``state_rows`` [B] (a model
+        with linear_attention layers): each row's entry of the recurrent
+        leaves (None: row i uses entry i)."""
         cfg = self.cfg
         embed = self.param(
             "embed",
@@ -1137,7 +1350,7 @@ class GPT(nn.Module):
                             decode=self.decode,
                             prefix_attend=self.prefix_attend)
         call_args = (cos, sin, positions, block_tables,
-                     self._moe_stacked())
+                     self._moe_stacked(), lengths)
         if cfg.period:
             x = self._stack_periods(x, block_kwargs, call_args[:4], lengths,
                                     state_rows, do_remat)

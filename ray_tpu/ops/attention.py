@@ -95,8 +95,13 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, sm_scale: Optional[float] = None,
               impl: str = "auto",
-              mask: Optional[jax.Array] = None) -> jax.Array:
-    """Public fused attention entry point (see module docstring)."""
+              mask: Optional[jax.Array] = None,
+              q_lens: Optional[jax.Array] = None) -> jax.Array:
+    """Public fused attention entry point (see module docstring).
+    ``q_lens`` [B] (causal): each row's real length.  The flash kernel
+    skips the query spans past it and returns zeros there
+    (``flash_attention``); the other impls compute every position, which
+    is as good to a caller that reads the real ones."""
     impl = resolve_impl(impl)
     if impl in ("flash", "splash") and mask is not None:
         impl = "xla"       # the Pallas kernels have no padding-mask path
@@ -108,7 +113,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if impl == "flash":
             from ray_tpu.ops.flash_attention import flash_attention
             return flash_attention(q, k, v, causal=causal,
-                                   sm_scale=sm_scale)
+                                   sm_scale=sm_scale, q_lens=q_lens)
         # JAX's tuned public TPU kernel, kept as a comparison impl
         from ray_tpu.ops.splash import splash_attention
         return splash_attention(q, k, v, causal=causal, sm_scale=sm_scale)

@@ -255,9 +255,11 @@ def _last_span(causal: bool, qi):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                 sm_scale: float, causal: bool, spans: int,
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, span_pair=None):
+    """``span_pair``: the step's ``(qi, ki)`` where the caller read them
+    (the interpreter has no ``program_id`` inside a ``pl.when``)."""
     span_q, span_k = q_ref.shape[1], k_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, ki = span_pair or (pl.program_id(1), pl.program_id(2))
     fold = _scale_is_exact(sm_scale)
 
     @pl.when(ki == 0)
@@ -301,6 +303,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         lse_ref[0] = m_ref[...] + jnp.log(l_safe)
 
 
+def _fwd_kernel_to_lens(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                        **static):
+    """``_fwd_kernel`` under ``q_lens``: a query span that starts at or
+    past its row's length (``lens_ref``, one a ``bh`` row in SMEM) does
+    no arithmetic, and its output block and lse are written as ZEROS,
+    once, never left as the buffers held them: whoever caches or reads a
+    position past a prompt's end finds a finite value there."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    real = qi * q_ref.shape[1] < lens_ref[pl.program_id(0)]
+
+    @pl.when(real)
+    def _():
+        _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, **static,
+                    span_pair=(qi, ki))
+
+    @pl.when(jnp.logical_not(real) & (ki == 0))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        lse_ref[...] = jnp.zeros(lse_ref.shape, lse_ref.dtype)
+
+
 def _specs(t: Tiling, d: int, causal: bool):
     """Block specs under grid (bh, q span, kv span): q-side operands,
     kv-side operands, [1, span_q] rows.  A kv span above the diagonal
@@ -312,34 +335,68 @@ def _specs(t: Tiling, d: int, causal: bool):
             pl.BlockSpec((1, 1, t.span_q), lambda b, i, j: (b, 0, i)))
 
 
+def _specs_to_lens(t: Tiling, d: int):
+    """``_specs`` of a causal forward under ``q_lens`` (the lengths are
+    the index maps' last argument): q in, k/v in, o out, lse out.  A
+    query span past its row's length names what its row's last real
+    span left resident, that span's q and the diagonal's keys and
+    values, so nothing is fetched for it."""
+    def last(b, lens):
+        return jnp.maximum(lens[b] - 1, 0) // t.span_q
+
+    def q_span(b, i, j, lens):
+        return b, jnp.minimum(i, last(b, lens)), 0
+
+    def kv_span(b, i, j, lens):
+        return b, jnp.where(i > last(b, lens), last(b, lens),
+                            jnp.minimum(j, i)), 0
+    return (pl.BlockSpec((1, t.span_q, d), q_span),
+            pl.BlockSpec((1, t.span_k, d), kv_span),
+            pl.BlockSpec((1, t.span_q, d), lambda b, i, j, lens: (b, i, 0)),
+            pl.BlockSpec((1, 1, t.span_q), lambda b, i, j, lens: (b, 0, i)))
+
+
 _SEMANTICS = _CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _fwd(q3, k3, v3, causal: bool, sm_scale: float, t: Tiling,
-         interpret: bool):
-    """-> o [bh, q_len, d], lse [bh, 1, q_len] float32."""
+         interpret: bool, lens=None):
+    """-> o [bh, q_len, d], lse [bh, 1, q_len] float32.  ``lens`` [bh]
+    int32 (causal only; None: the call as it always was, operand for
+    operand): each row's real length, a scalar-prefetch operand of
+    ``_fwd_kernel_to_lens``."""
     bh, q_len, d = q3.shape
     kv_len = k3.shape[1]
-    qspec, kspec, row = _specs(t, d, causal)
+    static = dict(sm_scale=sm_scale, causal=causal, spans=q_len // t.span_q,
+                  block_q=t.block_q, block_k=t.block_k)
+    grid = (bh, q_len // t.span_q, kv_len // t.span_k)
+    scratch_shapes = [
+        pltpu.VMEM((1, t.span_q), jnp.float32),    # m
+        pltpu.VMEM((1, t.span_q), jnp.float32),    # l
+        pltpu.VMEM((d, t.span_q), jnp.float32),    # o^T
+    ]
+    if lens is None:
+        qspec, kspec, row = _specs(t, d, causal)
+        kernel, operands = _fwd_kernel, (q3, k3, v3)
+        call = dict(grid=grid, in_specs=[qspec, kspec, kspec],
+                    out_specs=[qspec, row], scratch_shapes=scratch_shapes)
+    else:
+        qspec, kspec, ospec, row = _specs_to_lens(t, d)
+        kernel, operands = _fwd_kernel_to_lens, (lens, q3, k3, v3)
+        call = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[qspec, kspec, kspec], out_specs=[ospec, row],
+            scratch_shapes=scratch_shapes))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          spans=q_len // t.span_q,
-                          block_q=t.block_q, block_k=t.block_k),
-        grid=(bh, q_len // t.span_q, kv_len // t.span_k),
-        in_specs=[qspec, kspec, kspec],
-        out_specs=[qspec, row],
+        functools.partial(kernel, **static),
         out_shape=[jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
                    jax.ShapeDtypeStruct((bh, 1, q_len), jnp.float32)],
-        scratch_shapes=[
-            pltpu.VMEM((1, t.span_q), jnp.float32),    # m
-            pltpu.VMEM((1, t.span_q), jnp.float32),    # l
-            pltpu.VMEM((d, t.span_q), jnp.float32),    # o^T
-        ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
         name="flash_fwd",
-    )(q3, k3, v3)
+        **call,
+    )(*operands)
 
 
 # --------------------------------------------------------------------------- #
@@ -514,15 +571,38 @@ def _flash_bwd(causal, sm_scale, plan, interpret, res, do3):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_to_lens(q3, k3, v3, lens, sm_scale, plan, interpret):
+    """The causal forward alone, query spans past ``lens`` skipped."""
+    o, _ = _fwd(q3, k3, v3, True, sm_scale, plan.fwd, interpret, lens)
+    return o
+
+
+def _no_gradient(*_):
+    raise NotImplementedError(
+        "flash_attention with q_lens is forward only (prefill): the "
+        "backward kernels know nothing of the skipped query spans")
+
+
+_flash_to_lens.defvjp(_no_gradient, _no_gradient)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    q_lens: Optional[jax.Array] = None) -> jax.Array:
     """Flash attention on [B, S, H, D] / [B, Sk, H, D] inputs (heads equal;
     GQA expansion happens in ops.attention).  ``block_q`` / ``block_k``
     override ``plan_blocks`` (tests); compiled for the chip they must be
-    multiples of 128 or the whole sequence."""
+    multiples of 128 or the whole sequence.
+
+    ``q_lens`` [B] int32 (causal, forward only): each row's REAL length.
+    A query span (``plan_blocks``' ``span_q``) that starts at or past it
+    is not computed and comes out as zeros; every position below the
+    length comes out as without ``q_lens`` (its keys lie below it too).
+    None is the call as it always was."""
     b, q_len, h, d = q.shape
     kv_len = k.shape[1]
     if causal and q_len != kv_len:
@@ -530,6 +610,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             "causal flash attention requires q_len == kv_len (got "
             f"{q_len} vs {kv_len}); use ops.attention with q_offset for "
             "decode-style queries")
+    if q_lens is not None and not causal:
+        raise ValueError("q_lens skips query spans only: without the "
+                         "causal mask a real query would still see the "
+                         "keys past its row's length")
     scale = sm_scale if sm_scale is not None else d ** -0.5
     plan = plan_blocks(q_len, kv_len, causal, block_q, block_k)
     if interpret is None:
@@ -538,6 +622,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
-    o3 = _flash(to3(q), to3(k), to3(v), causal, float(scale), plan,
-                bool(interpret))
+    if q_lens is None:
+        o3 = _flash(to3(q), to3(k), to3(v), causal, float(scale), plan,
+                    bool(interpret))
+    else:
+        o3 = _flash_to_lens(to3(q), to3(k), to3(v),
+                            jnp.repeat(q_lens.astype(jnp.int32), h),
+                            float(scale), plan, bool(interpret))
     return o3.reshape(b, h, q_len, d).transpose(0, 2, 1, 3)

@@ -39,9 +39,21 @@ iteration's prefills):
     (one compile per (bucket, wave-size) pair) that writes their K/V
     straight into freshly allocated pages and samples each first token
     inside the same jit — *before* any decode slot frees
-    (prefill-ahead).  Right-pad garbage beyond a real prompt length is
-    always overwritten by a decode write before a row's length makes it
-    visible, so padding needs no extra masking.
+    (prefill-ahead).  In a bucket some prompt of which can leave a
+    chunk of positions out (models/gpt.py ``PREFILL_CHUNK``; buckets
+    double, so that is one of more than two chunks: ``_skips_pad``;
+    shorter buckets are one pass, as ever) the program is told each
+    prompt's real length and does the work of the wave's LONGEST
+    prompt, not of its bucket: each layer's token-wise work runs on
+    the chunks that prompt reaches, in one loop body whose trip count
+    is data, and the flash kernel leaves out each row's query spans
+    past its own length.  What a page then holds
+    past a real prompt's end is the right-pad's rows where a chunk
+    computed them and zeros where none did: finite (the decode kernel
+    multiplies a masked probability of 0 by it), and overwritten by a
+    decode write before a row's length makes it visible, so padding
+    needs no masking.  ``prefill_padded_tokens`` counts what was
+    computed.
   - Install with the prefill.  A request admitted while a slot is
     free takes it at once: the block dispatched right behind its
     prefill wave installs it, its first token handed from the wave's
@@ -116,7 +128,7 @@ import numpy as np
 
 from ray_tpu._private.profiler import span
 from ray_tpu.models.configs import TransformerConfig
-from ray_tpu.models.gpt import GPT, output_logits
+from ray_tpu.models.gpt import GPT, output_logits, prefill_positions
 from ray_tpu.serve.frontdoor.prefix import page_digests
 
 # admission waves are padded to the next of these sizes (bounded jit
@@ -368,7 +380,10 @@ class EngineStats:
         self.prefix_evictions = 0        # retained runs evicted (LRU/space)
         self.prefill_waves = 0           # prefill programs dispatched
         self.prefill_prompt_tokens = 0   # real prompt tokens in them
-        self.prefill_padded_tokens = 0   # wave x bucket: what they computed
+        # what they computed: wave x bucket, or where the program left
+        # out the chunks past the wave's longest prompt (models/gpt.py
+        # prefill_positions), wave x what it ran
+        self.prefill_padded_tokens = 0
         # dropless expert layers (ops/moe.py), counted on the device over
         # the rows that hold a request and fetched with each block's
         # tokens: a layer step is one expert layer in one decode step
@@ -642,8 +657,9 @@ class LLMEngine:
         # (a slot's ``stall_base`` is a mark on it); and what it has
         # measured to reckon by where it cannot see: the seconds each
         # prefill program took when last it ran alone in an iteration,
-        # by (bucket, wave size, suffix), and the last block's own
-        # seconds that were measured and not reckoned
+        # by (bucket, wave size, suffix, positions a row it computed),
+        # and the last block's own seconds that were measured and not
+        # reckoned
         self._fetched_at = 0.0
         self._block_s = 0.0
         self._stall_s = 0.0
@@ -741,20 +757,24 @@ class LLMEngine:
                              top_k=self.top_k, top_p=self.top_p)
 
     def _last_logits(self, model, params, cache, tokens, positions,
-                     s_reals, tables, entries=None):
+                     s_reals, tables, entries=None, skip_pad=True):
         """``(logits [wave, vocab] of each row's last REAL position, the
         updated cache)``.  The head runs on those rows alone: float32
         logits of every position are ``wave x bucket x vocab`` (1.2 GB
         for one 2048-token prompt at a 152k vocabulary), of which one
-        row a prompt is read.  ``entries`` [wave] (a model with
-        recurrent layers): where each prompt's final state is written;
-        such a model is also told the real lengths."""
-        recurrent = {} if entries is None else {
-            "lengths": s_reals, "state_rows": entries}
+        row a prompt is read.  ``skip_pad``: the model is told the real
+        lengths ``s_reals``, and leaves out what it can of the work past
+        them (models/gpt.py ``Block``, ``_prefill_attend``).  ``entries``
+        [wave] (a model with recurrent layers, which is always told the
+        lengths): where each prompt's final state is written."""
+        told = {"lengths": s_reals} if (
+            skip_pad or entries is not None) else {}
+        if entries is not None:
+            told["state_rows"] = entries
         hidden, mut = model.apply(
             {"params": params, "cache": cache}, tokens, positions,
             return_hidden=True, mutable=["cache"], block_tables=tables,
-            **recurrent)
+            **told)
         last = jnp.take_along_axis(
             hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0]
         return output_logits(self.cfg, params, last), mut["cache"]
@@ -777,7 +797,7 @@ class LLMEngine:
                 last, cache = self._last_logits(
                     self.model, params, cache, tokens, positions, s_reals,
                     tables, packed[:, bucket + 2] if self._state_layers
-                    else None)
+                    else None, skip_pad=self._skips_pad(bucket))
                 first = self._sample_fn(rng, last, temps)
                 return first, cache
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
@@ -802,9 +822,11 @@ class LLMEngine:
                 b, s = tokens.shape
                 positions = offs[:, None] + jnp.broadcast_to(
                     jnp.arange(s), (b, s))
+                # windows at an offset, attending through the pool:
+                # every position computed, as counted
                 last, cache = self._last_logits(
                     self.model_prefix, params, cache, tokens, positions,
-                    s_reals, tables)
+                    s_reals, tables, skip_pad=False)
                 first = self._sample_fn(rng, last, temps)
                 return first, cache
             fn = self._suffix_jit[(bucket, wave)] = jax.jit(
@@ -1330,6 +1352,17 @@ class LLMEngine:
         while b < n:
             b *= 2
         return min(b, self.cfg.max_seq_len)
+
+    def _skips_pad(self, bucket: int) -> bool:
+        """Whether some prompt of ``bucket`` leaves a chunk of it
+        uncomputed once the model is told the lengths.  Buckets double,
+        so a bucket's prompts are longer than half of it: one of two
+        chunks always runs both, and its program stays the one pass.
+        A model with recurrent layers computes every position (its
+        ``Period`` does not hand the lengths on to its blocks)."""
+        shortest = 1 if bucket <= self._min_bucket else bucket // 2 + 1
+        return (not self._state_layers
+                and prefill_positions(bucket, shortest) < bucket)
 
     def _widest_wave(self, bucket: int) -> int:
         """The largest wave size whose prompts of ``bucket`` tokens fit
@@ -2007,7 +2040,8 @@ class LLMEngine:
         """Batch queued prompts into (bucket, wave) prefill calls that
         write straight into their reserved pages.  Device dispatch only —
         first tokens are fetched later in the iteration.  Returns a
-        (firsts, metas, key) a wave, ``key`` naming its program."""
+        (firsts, metas, key) a wave, ``key`` naming its program and, as
+        its seconds follow them, the positions a row it computes."""
         out = []
         for bucket, chunk, wave in self._wave_chunks(todo):
             packed = np.zeros((wave, self.packed_width(bucket)), np.int32)
@@ -2028,10 +2062,13 @@ class LLMEngine:
                 bucket, wave)(self.params, self._cache,
                               jnp.asarray(packed),
                               jnp.asarray(tables), self._next_key())
+            computed = prefill_positions(
+                bucket, max(len(req.prompt) for req, _ in chunk)
+            ) if self._skips_pad(bucket) else bucket
             self._count_prefill_wave(
                 len(chunk), sum(len(req.prompt) for req, _ in chunk),
-                wave * bucket)
-            out.append((firsts, metas, (bucket, wave, False)))
+                wave * computed)
+            out.append((firsts, metas, (bucket, wave, False, computed)))
         return out
 
     def _dispatch_suffix_waves(self, todo: list) -> list:
@@ -2075,7 +2112,7 @@ class LLMEngine:
                 self._count_prefill_wave(
                     len(chunk), int(packed[:len(chunk), bucket].sum()),
                     wave * bucket)
-                out.append((firsts, metas, (bucket, wave, True)))
+                out.append((firsts, metas, (bucket, wave, True, bucket)))
         return out
 
     def _process_prefill_waves(self, waves: list, ahead: _Ahead,

@@ -170,7 +170,8 @@ def _stage_module(cfg: TransformerConfig, spec: StageSpec):
             if spec.n_layers:
                 cos, sin = rope_frequencies(c.head_dim, c.max_seq_len,
                                             c.rope_theta)
-                x = stack_layers(Block, c, {}, x, (cos, sin, None, None, None),
+                x = stack_layers(Block, c, {}, x,
+                                 (cos, sin, None, None, None, None),
                                  remat=False, n_layers=spec.n_layers)
             if not spec.last:
                 return x

@@ -122,3 +122,102 @@ def debug_sanitizers_enabled():
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
+
+
+@pytest.fixture
+def prefill_chunk(monkeypatch):
+    """Set ``models/gpt.py PREFILL_CHUNK`` for one test: it is a module
+    constant, not an option, so a test of a wave several chunks long at
+    test size patches it down."""
+    import importlib
+    gpt = importlib.import_module("ray_tpu.models.gpt")
+    return lambda positions: monkeypatch.setattr(gpt, "PREFILL_CHUNK",
+                                                 positions)
+
+
+def assert_chunked_wave_is_the_whole_wave(eng, lengths, bucket, chunk,
+                                          set_chunk):
+    """One prefill wave of ``len(lengths)`` prompts at ``bucket`` through
+    ``eng``'s paged model, told the real lengths, once as ONE chunk (the
+    whole span in one pass) and once in chunks of ``chunk``: the hidden
+    states and the pool's rows at every real position and the first
+    tokens agree; the pool is finite everywhere; and what no chunk
+    computed (hidden states, pool rows) is exactly zero."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    wave, ps = len(lengths), eng.page_size
+    rng = np.random.default_rng(sum(lengths))
+    packed = np.zeros((wave, eng.packed_width(bucket)), np.int32)
+    tables = np.zeros((wave, eng.max_pages), np.int32)
+    for r, n in enumerate(lengths):
+        packed[r, :n] = rng.integers(1, 256, n)
+        packed[r, bucket] = n
+        tables[r, :bucket // ps] = 1 + r * (bucket // ps) + np.arange(
+            bucket // ps)
+    packed, tables = jnp.asarray(packed), jnp.asarray(tables)
+    positions = jnp.broadcast_to(jnp.arange(bucket), (wave, bucket))
+
+    def run(positions_a_chunk):
+        set_chunk(positions_a_chunk)
+        eng._prefill_jit.clear()
+        fresh = lambda: jax.tree.map(jnp.copy, eng._cache)  # noqa: E731
+        hidden, mut = eng.model.apply(
+            {"params": eng.params, "cache": fresh()}, packed[:, :bucket],
+            positions, return_hidden=True, mutable=["cache"],
+            block_tables=tables, lengths=packed[:, bucket])
+        firsts, _ = eng._get_prefill_paged(bucket, wave)(
+            eng.params, fresh(), packed, tables, jax.random.PRNGKey(0))
+        (pool,) = [leaf for leaf in jax.tree.leaves(mut["cache"])
+                   if eng._is_pool_leaf(leaf)]
+        # [layers, pages, kv_heads, page, row] -> a row's positions in order
+        rows = [np.asarray(pool[:, tables[r, :bucket // ps]]
+                           .transpose(0, 1, 3, 2, 4)
+                           .reshape(pool.shape[0], bucket, -1), np.float32)
+                for r in range(wave)]
+        return (np.asarray(hidden, np.float32), rows, np.asarray(firsts),
+                np.asarray(pool, np.float32))
+
+    whole, got = run(bucket), run(chunk)
+    eng._prefill_jit.clear()
+    assert np.isfinite(got[3]).all()
+    np.testing.assert_array_equal(got[2], whole[2])
+    computed = -(-max(lengths) // chunk) * chunk
+    for r, n in enumerate(lengths):
+        np.testing.assert_allclose(got[0][r, :n], whole[0][r, :n],
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(got[1][r][:, :n], whole[1][r][:, :n],
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(got[0][r, computed:], 0)
+        np.testing.assert_array_equal(got[1][r][:, computed:], 0)
+    if computed < bucket:       # the one pass computed the right-pad too
+        assert np.abs(whole[0][:, computed:]).max() > 0
+
+
+def prefill_jaxpr(eng, bucket, wave, skip_pad):
+    """The text of ``eng``'s prefill wave's forward at ``[wave,
+    bucket]``, the model told the rows' real lengths (``skip_pad``, as
+    ``engine_prefill`` tells it) or not (the one pass over the span that
+    every prefill was)."""
+    import jax
+    import jax.numpy as jnp
+    return str(jax.make_jaxpr(
+        lambda params, cache, tokens, lens, tables: eng._last_logits(
+            eng.model, params, cache, tokens,
+            jnp.broadcast_to(jnp.arange(bucket), (wave, bucket)), lens,
+            tables, skip_pad=skip_pad))(
+        eng.params, eng._cache, jnp.zeros((wave, bucket), jnp.int32),
+        jnp.ones((wave,), jnp.int32),
+        jnp.zeros((wave, eng.max_pages), jnp.int32)))
+
+
+def assert_only_several_chunks_loop(eng, set_chunk):
+    """At ``[2, 32]``: with a chunk of 32 the forward told the lengths
+    is the one pass, equation for equation; with a chunk of 8 it is
+    another program, one that loops."""
+    set_chunk(32)
+    assert prefill_jaxpr(eng, 32, 2, True) == prefill_jaxpr(eng, 32, 2, False)
+    set_chunk(8)
+    told = prefill_jaxpr(eng, 32, 2, True)
+    assert told != prefill_jaxpr(eng, 32, 2, False)
+    assert "while" in told

@@ -404,6 +404,69 @@ def test_on_the_tpu_such_a_row_runs_the_flash_kernel(monkeypatch):
     np.testing.assert_allclose(got, whole, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("lengths", [(23,), (13, 40), (64, 5)],
+                         ids=["one-row", "two-rows", "fills-its-bucket"])
+def test_a_wave_of_several_chunks_is_the_one_pass_on_real_positions(
+        lengths, prefill_chunk):
+    """Latent attention with 3 of 8 experts held, a dense layer first: a
+    wave at bucket 64 in chunks of 16 (``Block._chunked``: the sections
+    before and after the attention a chunk at a time, as many as the
+    longest prompt needs) against the same wave in one pass."""
+    from conftest import assert_chunked_wave_is_the_whole_wave
+    cfg, params, _, _ = _parts(moe_experts_held=3, moe_held_first=2)
+    eng = _engine(cfg, params, max_seq_len=128, max_prompt_len=64)
+    try:
+        assert_chunked_wave_is_the_whole_wave(eng, lengths, 64, 16,
+                                              prefill_chunk)
+    finally:
+        eng.close()
+
+
+def test_a_wave_of_one_chunk_is_the_program_it_was(parts, prefill_chunk):
+    """Told the real lengths or not, a wave of one chunk or less traces
+    to the same program, equation for equation (the cells whose prompts
+    fit a chunk run what they ran); a wave of several chunks does not:
+    it loops over them."""
+    from conftest import assert_only_several_chunks_loop
+    cfg, params, _, _ = parts
+    eng = _engine(cfg, params)
+    try:
+        assert_only_several_chunks_loop(eng, prefill_chunk)
+    finally:
+        eng.close()
+
+
+def test_the_engine_counts_the_chunks_a_wave_ran(parts, prefill_chunk):
+    """Through ``submit`` with chunks of 8: a prompt of 17 runs at bucket
+    32 and computes 24 positions, one of 30 all 32, one of 7 its bucket
+    of 8 (one chunk: as ever); ``prefill_padded_tokens`` counts what was
+    computed, never less than the real tokens, and the greedy tokens
+    are lone generation's: decode reads pages whose rows past the
+    prompt were never computed."""
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.models.generate import Generator
+    cfg, params, _, _ = parts
+    prefill_chunk(8)
+    eng = _engine(cfg, params)
+    lone = Generator(cfg, params)
+    rng = np.random.default_rng(5)
+    try:
+        padded = prompt = 0
+        for n, computed in ((17, 24), (30, 32), (7, 8)):
+            p = [int(t) for t in rng.integers(1, 256, n)]
+            want = lone.generate(jnp.asarray([p], jnp.int32),
+                                 max_new_tokens=6, temperature=0.0)[0]
+            assert eng.submit(p, max_new_tokens=6).tokens == [
+                int(t) for t in want]
+            padded, prompt = padded + computed, prompt + n
+            st = eng.stats.snapshot(2)
+            assert st["prefill_padded_tokens"] == padded
+            assert st["prefill_prompt_tokens"] == prompt
+    finally:
+        eng.close()
+
+
 def test_the_engine_s_greedy_tokens_are_lone_generation_s(parts):
     """Through ``submit``: admission, prefill wave, install, decode
     blocks; four requests on two slots against ``Generator`` (a dense

@@ -104,6 +104,24 @@ def _engine(cfg, params, **kw):
                      min_prefill_bucket=4, **kw)
 
 
+@pytest.mark.parametrize("lengths", [(21,), (30, 9)],
+                         ids=["one-row", "two-rows"])
+def test_a_wave_of_several_chunks_is_the_one_pass_on_real_positions(
+        parts, lengths, prefill_chunk):
+    """A wave at bucket 32 in chunks of 8 (``Block._chunked``), where the
+    router reads the ATTENTION's input (its logits cross the attention
+    beside the projections) and the layers differ in rotation and
+    window (a span of 32 is four windows long): against one pass."""
+    from conftest import assert_chunked_wave_is_the_whole_wave
+    cfg, params = parts[:2]
+    eng = _engine(cfg, params)
+    try:
+        assert_chunked_wave_is_the_whole_wave(eng, lengths, 32, 8,
+                                              prefill_chunk)
+    finally:
+        eng.close()
+
+
 def test_paged_prefill_and_decode_match_the_reference(parts):
     """(b) through the engine's own model, cache and page tables: a
     prompt of 13 tokens (longer than the window, not a whole number of

@@ -137,6 +137,88 @@ def test_flash_causal_skipped_blocks_are_not_read(span, flash_span):
     np.testing.assert_array_equal(dv_n[:, cut:], dv[:, cut:])
 
 
+# (dtype, head width of q and k, of v): the training cell's heads, and
+# the latent prefill's (keys 192 wide, values 128, which ride
+# zero-padded to the keys' width as ``_prefill_attend`` sends them)
+_Q_LENS_HEADS = {"d64": (jnp.float32, 64, 64),
+                 "latent-192-128": (jnp.bfloat16, 192, 128)}
+_Q_LENS_SPAN, _Q_LENS_SEQ = 128, 512
+
+
+@pytest.mark.parametrize("heads", list(_Q_LENS_HEADS))
+@pytest.mark.parametrize(
+    "length", [1, _Q_LENS_SPAN - 1, _Q_LENS_SPAN, _Q_LENS_SPAN + 1,
+               _Q_LENS_SEQ],
+    ids=["one", "span-1", "span", "span+1", "whole"])
+def test_flash_q_lens_skips_the_query_spans_past_a_row(length, heads,
+                                                       flash_span):
+    """``q_lens``: a row's real prefix comes out as XLA attention on that
+    prefix alone gives it; every query span that starts at or past the
+    length comes out as exactly zero, whatever the operands hold there
+    (NaN here: a skipped span reads nothing and writes zeros, it does
+    not multiply by zero); the rest of the span the length ends in is
+    computed and finite.  Row 0 has the length under test, row 1 the
+    whole sequence: a wave's rows differ."""
+    flash_span(_Q_LENS_SPAN)
+    dtype, dk, dv = _Q_LENS_HEADS[heads]
+    S, span = _Q_LENS_SEQ, _Q_LENS_SPAN
+    keys = jax.random.split(jax.random.PRNGKey(13), 3)
+    q, k = (jax.random.normal(kk, (2, S, 2, dk), jnp.float32).astype(dtype)
+            for kk in keys[:2])
+    v = jax.random.normal(keys[2], (2, S, 2, dv), jnp.float32).astype(dtype)
+    edge = -(-length // span) * span        # where the skipped spans start
+    nan_past = lambda x: x.at[0, edge:].set(jnp.nan)       # noqa: E731
+    wide = jnp.pad(v, [(0, 0)] * 3 + [(0, dk - dv)])
+    got = flash_attention(nan_past(q), nan_past(k), nan_past(wide),
+                          causal=True, block_q=64, block_k=128,
+                          q_lens=jnp.asarray([length, S], jnp.int32)
+                          )[..., :dv]
+    assert got.dtype == dtype and bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(np.asarray(got[0, edge:], np.float32), 0)
+    f32 = lambda x: x.astype(jnp.float32)                  # noqa: E731
+    atol = 2e-5 if dtype == jnp.float32 else 2.0 ** -6
+    for row, n in ((0, length), (1, S)):
+        want = xla_attention(f32(q[row:row + 1, :n]), f32(k[row:row + 1, :n]),
+                             f32(v[row:row + 1, :n]), causal=True)
+        np.testing.assert_allclose(np.asarray(got[row, :n], np.float32),
+                                   want[0], atol=atol, rtol=0)
+
+
+def test_flash_without_q_lens_is_the_call_it_was():
+    """``q_lens=None`` (training, every call before there was one): the
+    forward's ``pallas_call`` has q, k, v for operands and no scalar
+    prefetch; with ``q_lens`` the lengths ride in front as one.  And a
+    gradient through a call with ``q_lens`` is refused, not wrong."""
+    q = k = v = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    lens = jnp.asarray([100], jnp.int32)
+
+    def fwd_call(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: flash_attention(
+            *a, causal=True, **kw))(q, k, v)
+        (eqn,) = [e for e in _all_eqns(jaxpr.jaxpr)
+                  if e.primitive.name == "pallas_call"]
+        return eqn
+
+    plain = fwd_call()
+    assert [v.aval.shape for v in plain.invars] == [(2, 256, 64)] * 3
+    assert plain.params["grid_mapping"].num_index_operands == 0
+    told = fwd_call(q_lens=lens)
+    assert [v.aval.shape for v in told.invars] == [(2,)] + [(2, 256, 64)] * 3
+    assert told.params["grid_mapping"].num_index_operands == 1
+    with pytest.raises(NotImplementedError, match="forward only"):
+        jax.grad(lambda q: flash_attention(q, k, v, q_lens=lens).sum())(q)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, q_lens=lens)
+
+
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
 def _pairs_by_brute_force(q_len, kv_len, block_q, block_k, causal):
     mask = (np.tril(np.ones((q_len, kv_len), bool)) if causal
             else np.ones((q_len, kv_len), bool))

@@ -123,6 +123,110 @@ def test_paged_model_matches_dense_at_mixed_offsets(tiny_parts_either):
     assert out == expect
 
 
+def _gqa_engine(scan_layers, **kw):
+    """An engine of the tiny model with 4 query heads on 2 KV heads."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models.configs import get_config
+    from ray_tpu.models.gpt import GPT
+    from ray_tpu.serve.llm_engine import LLMEngine
+    cfg = get_config("tiny", n_kv_heads=2, scan_layers=scan_layers)
+    params = GPT(cfg, decode=True).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))["params"]
+    return cfg, params, LLMEngine(
+        cfg, params, **{"num_slots": 2, "page_size": 4, "max_seq_len": 128,
+                        "max_prompt_len": 64, "block_size": 4,
+                        "min_prefill_bucket": 8, **kw})
+
+
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["scanned", "unrolled"])
+@pytest.mark.parametrize("lengths", [(23,), (13, 40), (64, 5)],
+                         ids=["one-row", "two-rows", "fills-its-bucket"])
+def test_a_wave_of_several_chunks_is_the_one_pass_on_real_positions(
+        lengths, scan_layers, prefill_chunk):
+    """Dense GQA: a wave at bucket 64 in chunks of 16 (``Block._chunked``)
+    against the same wave in one pass: real positions' hidden states and
+    K|V rows, first tokens; the pool finite, and zero where no chunk
+    ran."""
+    from conftest import assert_chunked_wave_is_the_whole_wave
+    _, _, eng = _gqa_engine(scan_layers)
+    try:
+        assert_chunked_wave_is_the_whole_wave(eng, lengths, 64, 16,
+                                              prefill_chunk)
+    finally:
+        eng.close()
+
+
+def test_a_wave_of_one_chunk_is_the_program_it_was(prefill_chunk):
+    """As tests/test_latent_attention.py's, on K|V pages: one chunk or
+    less traces to what it always did, several chunks to a loop."""
+    from conftest import assert_only_several_chunks_loop
+    _, _, eng = _gqa_engine(True)
+    try:
+        assert_only_several_chunks_loop(eng, prefill_chunk)
+    finally:
+        eng.close()
+
+
+def test_the_engine_counts_the_chunks_a_wave_ran(prefill_chunk):
+    """Two prompts of 17 and 20 tokens in ONE wave at bucket 32, chunks
+    of 8: the wave runs the longer row's 3 chunks for both rows, so
+    ``prefill_padded_tokens`` grows by 2 x 24 (what was computed, not
+    less than the 37 real tokens), and the wave's key, by which the
+    engine remembers what such a program took, names the 24."""
+    from ray_tpu.models import gpt
+    from ray_tpu.serve.llm_engine import _Request
+    _, _, eng = _gqa_engine(True)
+    prefill_chunk(8)
+    assert [gpt.prefill_positions(32, n) for n in (1, 8, 9, 17, 32)] == [
+        8, 8, 16, 24, 32]
+    assert gpt.prefill_positions(8, 3) == 8         # one chunk: all of it
+    assert gpt.prefill_positions(20, 3) == 20       # not whole chunks
+    # buckets double: the prompts of one of two chunks always run both,
+    # so its program is not told the lengths and stays the one pass
+    assert [eng._skips_pad(b) for b in (8, 16, 32, 64)] == [
+        False, False, True, True]
+    try:
+        todo = [(_Request(list(range(1, n + 1)), 4, 0.0, None,
+                          lambda ok, value: None, None),
+                 list(range(1 + 8 * r, 9 + 8 * r)))
+                for r, n in enumerate((17, 20))]
+        ((firsts, metas, key),) = eng._dispatch_prefill_waves(todo)
+        assert firsts.shape == (2,) and len(metas) == 2
+        assert key == (32, 2, False, 24)
+        st = eng.stats.snapshot(2)
+        assert (st["prefill_waves"], st["prefill_prompt_tokens"],
+                st["prefill_padded_tokens"]) == (1, 37, 48)
+    finally:
+        eng.close()
+
+
+def test_a_hybrid_model_s_prompt_waves_stay_one_pass(prefill_chunk):
+    """A model with recurrent layers is always told the real lengths
+    (its recurrence needs them); its full-attention blocks are not, so
+    its prefill program is the same whatever a chunk is, and the engine
+    counts the whole bucket as computed."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.serve.llm_engine import LLMEngine
+    cfg, params, kw = _hybrid_parts()
+    eng = LLMEngine(cfg, params, **kw)
+
+    def program():
+        eng._prefill_jit.clear()
+        return str(jax.make_jaxpr(eng._get_prefill_paged(32, 2))(
+            eng.params, eng._cache,
+            jnp.zeros((2, eng.packed_width(32)), jnp.int32),
+            jnp.zeros((2, eng.max_pages), jnp.int32), jax.random.PRNGKey(0)))
+    try:
+        whole = program()
+        prefill_chunk(8)
+        assert program() == whole and not eng._skips_pad(32)
+    finally:
+        eng.close()
+
+
 @pytest.mark.parametrize("front", ["LLMEngine", "LLMServer"])
 def test_paged_false_is_refused(front):
     """The dense engine is gone: the keyword has one legal value (it
