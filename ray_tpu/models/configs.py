@@ -13,6 +13,14 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+# block classes of ``TransformerConfig.layer_types`` by what a layer
+# keeps of a sequence: KV pages in the paged pool, or a fixed-size
+# recurrent state entry a request; and those that are one mixer a layer
+POOL_KINDS = ("full_attention", "attention_only")
+STATE_KINDS = ("linear_attention", "mamba2")
+MIXER_KINDS = ("mamba2", "latent_moe", "attention_only")
+
+
 @dataclasses.dataclass
 class TransformerConfig:
     vocab_size: int = 32000
@@ -65,11 +73,12 @@ class TransformerConfig:
     sliding_window: Optional[int] = None
     rope_layout: Optional[tuple] = None
     window_layout: Optional[tuple] = None
-    # Per-layer BLOCK classes ("full_attention" | "linear_attention";
-    # layer l is entry l, the tuple may be longer than n_layers).  Where
+    # Per-layer BLOCK classes ("full_attention" | "linear_attention",
+    # and the single-mixer classes further down; layer l is entry l,
+    # the tuple may be longer than n_layers).  Where
     # it names more than one class the layer stack scans one PERIOD of
-    # it (``period``; models/gpt.py Period) and n_layers is whole
-    # periods.  A linear_attention layer is a Gated DeltaNet mixer
+    # it (``period``; models/gpt.py Period).  A linear_attention layer is
+    # a Gated DeltaNet mixer
     # (ops/gated_delta.py): linear_key_heads x linear_key_head_dim
     # queries and keys, linear_value_heads x linear_value_head_dim
     # values and gates, a causal depthwise convolution of
@@ -120,6 +129,29 @@ class TransformerConfig:
     # every expert is held
     moe_experts_held: Optional[int] = None
     moe_held_first: int = 0
+    # Layers that are ONE mixer each, x + Mixer(Norm(x)) (models/gpt.py
+    # MixerBlock), three more entries of layer_types:
+    #   "mamba2": a Mamba-2 mixer (ops/mamba2.py): mamba_heads x
+    #     mamba_head_dim channels, B and C of ssm_state_size shared by
+    #     the heads of each of mamba_groups groups, a causal depthwise
+    #     convolution of mamba_conv_kernel taps with a bias over [x; B;
+    #     C], prompts in chunks of mamba_chunk.  A fixed-size state a
+    #     request, as a linear_attention layer's;
+    #   "latent_moe": routed experts in a latent of moe_latent_size
+    #     around the moe_* router, with one shared expert of
+    #     moe_shared_d_ff on the full width (ops/moe.py LatentMoE);
+    #   "attention_only": the Attention of a full_attention layer and
+    #     nothing else; it holds KV pages.
+    # A latent_moe layer's experts are NOT gated, down(act(up x)), two
+    # matrices each; moe_act "relu2" is relu(.)^2.
+    mamba_heads: Optional[int] = None
+    mamba_head_dim: Optional[int] = None
+    ssm_state_size: Optional[int] = None
+    mamba_groups: int = 1
+    mamba_conv_kernel: int = 4
+    mamba_chunk: int = 128
+    moe_latent_size: Optional[int] = None
+    moe_shared_d_ff: Optional[int] = None
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -132,7 +164,7 @@ class TransformerConfig:
         if self.moe_d_ff is None:
             self.moe_d_ff = self.d_ff
         assert self.n_heads % self.n_kv_heads == 0
-        assert self.moe_act in ("silu", "relu")
+        assert self.moe_act in ("silu", "relu", "relu2")
         assert self.moe_scoring in ("softmax", "sigmoid")
         if self.kv_lora_rank:
             assert self.head_dim == (self.qk_nope_head_dim
@@ -153,31 +185,40 @@ class TransformerConfig:
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             assert len(self.layer_types) >= self.n_layers
-            assert set(self.layer_types) <= {"full_attention",
-                                             "linear_attention"}
-            assert not self.layers_differ and not self.moe_experts
-            assert not self.kv_lora_rank
-            if self.period:
-                assert self.n_layers % len(self.period) == 0, (
-                    "n_layers must be whole periods of layer_types")
+            assert set(self.layer_types) <= set(POOL_KINDS + STATE_KINDS
+                                                + ("latent_moe",))
+            assert not self.layers_differ and not self.kv_lora_rank
+            # experts live in latent_moe layers or in none of a period
+            assert bool(self.moe_experts) == (
+                "latent_moe" in self.layer_types[:self.n_layers])
+            if self.moe_experts:
+                assert self.moe_dropless and self.moe_latent_size \
+                    and self.moe_shared_d_ff
+            if "mamba2" in self.layer_types:
+                # one recurrent class a model: its leaves have one shape
+                assert "linear_attention" not in self.layer_types
+                assert self.mamba_heads % self.mamba_groups == 0
 
     @property
     def period(self) -> Optional[tuple]:
-        """The shortest run of block classes that ``layer_types``
-        repeats over the first ``n_layers``, where it names more than one
-        class (the layer stack then scans that run); else None."""
+        """The shortest run of block classes that the first ``n_layers``
+        of ``layer_types`` are whole repeats of, where they name more
+        than one class (the layer stack then scans that run; a pattern
+        that is not periodic is one run of all its layers); else None."""
         kinds = (self.layer_types or ())[:self.n_layers]
         if len(set(kinds)) < 2:
             return None
         return next(kinds[:p] for p in range(1, len(kinds) + 1)
-                    if all(k == kinds[i % p] for i, k in enumerate(kinds)))
+                    if len(kinds) % p == 0
+                    and all(k == kinds[i % p] for i, k in enumerate(kinds)))
 
-    def layers_of(self, kind: str) -> int:
-        """How many of the ``n_layers`` are of block class ``kind``
-        (every layer is full_attention without ``layer_types``)."""
+    def layers_of(self, *kinds: str) -> int:
+        """How many of the ``n_layers`` are of one of the block classes
+        ``kinds`` (every layer is full_attention without
+        ``layer_types``)."""
         if self.layer_types is None:
-            return self.n_layers if kind == "full_attention" else 0
-        return self.layer_types[:self.n_layers].count(kind)
+            return self.n_layers if "full_attention" in kinds else 0
+        return sum(k in kinds for k in self.layer_types[:self.n_layers])
 
     @property
     def layers_differ(self) -> bool:
@@ -236,9 +277,35 @@ class TransformerConfig:
                 + self.linear_conv_kernel * (2 * qk + vv)
                 + 2 * self.linear_value_heads + self.linear_value_head_dim)
 
+    def _mixer_params(self, kind: str) -> int:
+        """One single-mixer layer of class ``kind`` with its norm.
+        mamba2: the input projection to ``[z | x B C | dt]``, the output
+        projection, the convolution's taps and bias, ``A_log``,
+        ``dt_bias`` and ``D`` a head, the gated norm's weight.
+        latent_moe: router and its selection bias, the latent's two
+        projections, the shared expert, and the ``experts_here`` routed
+        experts, two matrices each (none of them is gated)."""
+        d = self.d_model
+        if kind == "attention_only":
+            return self._attn_params() + d
+        if kind == "mamba2":
+            inner = self.mamba_heads * self.mamba_head_dim
+            conv = inner + 2 * self.mamba_groups * self.ssm_state_size
+            return (d * (inner + conv + self.mamba_heads) + inner * d
+                    + (self.mamba_conv_kernel + 1) * conv
+                    + 3 * self.mamba_heads + inner + d)
+        return (d * self.moe_experts
+                + (self.moe_experts if self.moe_scoring == "sigmoid" else 0)
+                + 2 * d * self.moe_latent_size
+                + 2 * d * self.moe_shared_d_ff
+                + self.experts_here * 2 * self.moe_latent_size
+                * self.moe_d_ff + d)
+
     def layer_params(self, kind: str = "full_attention") -> int:
-        """One dense block of class ``kind``: mixer, SwiGLU, two
-        norms."""
+        """One layer of class ``kind``: a dense block (mixer, SwiGLU,
+        two norms) or a single mixer with its norm."""
+        if kind in MIXER_KINDS:
+            return self._mixer_params(kind)
         mixer = (self._attn_params() if kind == "full_attention"
                  else self._linear_attn_params())
         return mixer + 3 * self.d_model * self.d_ff + 2 * self.d_model
@@ -247,6 +314,10 @@ class TransformerConfig:
         """Parameters this program holds (``experts_here`` routed experts
         a layer, not all the router scores)."""
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
+        if self.layers_of(*MIXER_KINDS):
+            return emb + self.d_model + sum(
+                self.layer_params(k)
+                for k in self.layer_types[:self.n_layers])
         dense = 3 * self.d_model * self.d_ff
         if self.moe_experts:
             mlp = (self.experts_here + self.moe_shared_experts) * 3 \
@@ -293,6 +364,13 @@ class TransformerConfig:
             * self.n_layers
         return 6.0 * (self.n_layers * attn + first * dense
                       + (self.n_layers - first) * mlp + head) + attention
+
+
+def pattern_layer_types(pattern: str) -> tuple:
+    """``layer_types`` of a ``hybrid_override_pattern`` (the nemotron_h
+    family publishes its layers as a string, a letter a layer)."""
+    return tuple({"M": "mamba2", "E": "latent_moe",
+                  "*": "attention_only"}[letter] for letter in pattern)
 
 
 PRESETS = {
@@ -402,6 +480,36 @@ PRESETS = {
         moe_experts=8, moe_top_k=3, moe_d_ff=32, moe_dropless=True,
         moe_scoring="sigmoid", moe_route_scale=2.448,
         moe_shared_experts=2, first_dense_layers=1),
+    # NVIDIA-Nemotron-3-Super-120B-A12B (model_type nemotron_h) as
+    # published: 88 layers of ONE mixer each by hybrid_override_pattern
+    # (M Mamba-2: 128 heads of 64, state 128, 8 groups, conv 4; E
+    # LatentMoE: 512 sigmoid-routed squared-ReLU experts of 2688 in a
+    # latent of 1024, top-22, x 5, one shared expert of 5376; *
+    # attention with 32 query and 2 KV heads of 128, no rotation)
+    "nemotron-3-super-120b-a12b": TransformerConfig(
+        vocab_size=131072, d_model=4096, n_layers=88, n_heads=32,
+        n_kv_heads=2, head_dim=128, d_ff=2688, max_seq_len=262144,
+        rope_theta=None, norm_eps=1e-5,
+        layer_types=pattern_layer_types(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        mamba_heads=128, mamba_head_dim=64, ssm_state_size=128,
+        mamba_groups=8, mamba_conv_kernel=4, mamba_chunk=128,
+        moe_experts=512, moe_top_k=22, moe_d_ff=2688, moe_act="relu2",
+        moe_dropless=True, moe_scoring="sigmoid",
+        moe_route_scale=5.0, moe_latent_size=1024, moe_shared_d_ff=5376),
+    # the same layers at test size: the first 11-layer segment's
+    # pattern (5 : 5 : 1), tests/test_nemotron_h.py
+    "tiny-nemotron-h": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=11, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=32, max_seq_len=256, dtype=jnp.float32,
+        remat=False, rope_theta=None, norm_eps=1e-5,
+        layer_types=pattern_layer_types("MEMEMEMEM*E"),
+        mamba_heads=8, mamba_head_dim=16, ssm_state_size=16,
+        mamba_groups=2, mamba_conv_kernel=4, mamba_chunk=8,
+        moe_experts=16, moe_top_k=5, moe_d_ff=32, moe_act="relu2",
+        moe_dropless=True, moe_scoring="sigmoid",
+        moe_route_scale=5.0, moe_latent_size=32, moe_shared_d_ff=48),
 }
 
 

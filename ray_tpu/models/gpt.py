@@ -24,7 +24,8 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ray_tpu.models.configs import TransformerConfig
+from ray_tpu.models.configs import (POOL_KINDS, STATE_KINDS,
+                                    TransformerConfig)
 from ray_tpu.ops.attention import repeat_kv, xla_attention
 from ray_tpu.ops.layers import apply_rope, rope_frequencies
 from ray_tpu.parallel.sharding import LOGICAL_RULES, ShardingRules, with_sharding
@@ -1133,18 +1134,124 @@ class LinearBlock(nn.Module):
         return x if rec is None else (x, rec)
 
 
-class Period(nn.Module):
-    """One period of ``cfg.layer_types``: what ``stack_layers`` scans
-    where the layers are of more than one block CLASS (their parameter
-    trees differ, so one stacked ``Block`` cannot hold them).  The
-    parameters are stacked over periods, one subtree ``layer_<j>`` a
-    position in the period.  The carry is ``(pool, rec)``: the KV pool
-    stacked over the full-attention layers ONLY and the recurrent
-    leaves stacked over the linear ones; ``period`` (the scanned index)
-    times the layers of a class a period, plus the position's rank in
-    its class, is the layer's index into its leaf."""
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 mixer (ops/mamba2.py): one input projection to ``[z | x B
+    C | dt]``, a causal depthwise convolution with a bias and SiLU over
+    ``[x; B; C]``, the selective state-space recurrence with a scalar
+    decay a head (``B``, ``C`` shared by the heads of a group), ``+ D
+    u``, the output gated by ``SiLU(z)`` and THEN normalised over each
+    group's channels, the output projection.
+
+    What it remembers of a sequence is ``rec = (state, conv)`` as
+    ``LinearAttention``'s is: the model's two stacked leaves ``[ssm
+    layers, entries, N, heads * P]`` float32 and the convolution's last
+    ``taps - 1`` inputs, flat in rows of 128 lanes, addressed ``[layer,
+    entry]`` and passed through whole.  Without ``rec`` a call is a
+    whole sequence from an empty state; with it ``T > 1`` is a prompt
+    from an empty state whose final state and tail, as of each row's
+    last REAL token (``lengths``), are written to the rows'
+    ``entries``, and ``T == 1`` one decode step on the rows' entries,
+    dead rows untouched."""
 
     cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, lengths=None, entries=None, rec=None, layer=None,
+                 live=None):
+        from ray_tpu.ops import gated_delta as gd
+        from ray_tpu.ops import mamba2
+        cfg = self.cfg
+        h, p = cfg.mamba_heads, cfg.mamba_head_dim
+        n, g, taps = cfg.ssm_state_size, cfg.mamba_groups, cfg.mamba_conv_kernel
+        inner, bc = h * p, g * n
+        b, t, _ = x.shape
+        f32 = jnp.float32
+        zxd = _dense(2 * inner + 2 * bc + h, ("embed", "mlp"), "in_proj",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
+        z, u, dt = jnp.split(zxd, [inner, 2 * inner + 2 * bc], axis=-1)
+        vec = lambda init: nn.with_logical_partitioning(    # noqa: E731
+            init, ("norm",))
+        conv_w = self.param(
+            "conv", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), (None, "norm")),
+            (taps, inner + 2 * bc), cfg.param_dtype).astype(f32)
+        conv_b = self.param("conv_bias", vec(nn.initializers.normal(0.1)),
+                            (inner + 2 * bc,), f32)
+        a_log = self.param("A_log", vec(_a_log_init), (h,), f32)
+        dt_bias = self.param("dt_bias", vec(_dt_bias_init), (h,), f32)
+        d_skip = self.param("D", vec(nn.initializers.ones_init()), (h,), f32)
+        o_scale = self.param("norm", vec(nn.initializers.ones_init()),
+                             (inner,), f32)
+
+        decode_step = rec is not None and t == 1 \
+            and not self.is_initializing()
+        if decode_step:
+            state, conv = rec
+            flat, at = gd.flat_rows(conv, layer, entries)
+            tail = flat[at].reshape(b, taps - 1, -1)
+            window = jnp.concatenate([tail, u.astype(conv.dtype)], 1)
+            if live is not None:      # a dead row keeps its tail
+                tail = jnp.where(live[:, None, None], window[:, 1:], tail)
+            else:
+                tail = window[:, 1:]
+            rec = (state, flat.at[at].set(
+                tail.reshape((b,) + conv.shape[2:])).reshape(conv.shape))
+        else:       # zeros before the sequence's start
+            window = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+        c = sum(window[:, j:j + t].astype(f32) * conv_w[j]
+                for j in range(taps)) + conv_b
+        c = nn.silu(c).astype(cfg.dtype)
+        xs, bm, cm = jnp.split(c, [inner, inner + bc], axis=-1)
+        xs = xs.reshape(b, t, h, p)
+        bm, cm = bm.reshape(b, t, g, n), cm.reshape(b, t, g, n)
+        delta = jax.nn.softplus(dt.astype(f32) + dt_bias)
+        a_neg = -jnp.exp(a_log)
+
+        if decode_step:
+            y, state = mamba2.ssm_decode(
+                delta[:, 0, :, None] * xs[:, 0].astype(f32),
+                jnp.exp(delta[:, 0] * a_neg), bm[:, 0], cm[:, 0], rec[0],
+                entries, live, layer=layer)
+            y, rec = y[:, None], (state, rec[1])
+        else:
+            with jax.named_scope("ssm_prefill"):
+                y, final = mamba2.ssm_chunked(xs, delta, a_neg, bm, cm,
+                                              lengths, chunk=cfg.mamba_chunk)
+            if rec is not None and not self.is_initializing():
+                state, conv = rec
+                if lengths is None:
+                    lengths = jnp.full((b,), t, jnp.int32)
+                # the last taps - 1 REAL inputs (zeros before the start),
+                # picked by a 0/1 product: exact, and no gather
+                pick = (jnp.arange(window.shape[1])[None, None, :]
+                        == lengths[:, None, None]
+                        + jnp.arange(taps - 1)[None, :, None])
+                tail = jnp.einsum("bjt,btc->bjc", pick.astype(window.dtype),
+                                  window)
+                rec = (gd.write_rows(state, gd.pack_state(final), layer,
+                                     entries),
+                       gd.write_rows(conv, tail.reshape(
+                           (b,) + conv.shape[2:]), layer, entries))
+        y = y + d_skip[:, None] * xs.astype(f32)
+        # gated, then an RMSNorm over each group's channels
+        y = (y.reshape(b, t, inner) * nn.silu(z.astype(f32))).reshape(
+            b, t, g, inner // g)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                              + cfg.norm_eps)
+        y = (y.reshape(b, t, inner) * o_scale).astype(cfg.dtype)
+        out = _dense(cfg.d_model, ("mlp", "embed"), "out_proj",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)(y)
+        return out if rec is None else (out, rec)
+
+
+class MixerBlock(nn.Module):
+    """A layer that is ONE mixer: ``x + Mixer(Norm(x))``, the mixer by
+    ``kind``: ``"mamba2"`` (``Mamba2Mixer``; its carry the recurrent
+    leaves), ``"latent_moe"`` (ops/moe.py ``LatentMoE``; no carry) or
+    ``"attention_only"`` (``Attention``; its carry the KV pool)."""
+
+    cfg: TransformerConfig
+    kind: str = "mamba2"
     mesh: Optional[Mesh] = None
     rules: ShardingRules = LOGICAL_RULES
     decode: bool = False
@@ -1152,30 +1259,102 @@ class Period(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
-                 lengths=None, entries=None, carry=None, period=None):
+                 lengths=None, entries=None, carry=None, layer=None,
+                 moe_stacked=None):
+        """``moe_stacked`` (a latent_moe layer in decode): the routed
+        experts' leaves stacked over periods, ``layer`` then the
+        period's index (``DroplessMoE.__call__``)."""
+        cfg = self.cfg
+        live = None if block_tables is None else block_tables[:, 0] != 0
+        y = RMSNorm(cfg.norm_eps, name="norm")(x)
+        if self.kind == "mamba2":
+            y = Mamba2Mixer(cfg, name="mixer")(y, lengths, entries, carry,
+                                               layer, live)
+        elif self.kind == "latent_moe":
+            from ray_tpu.ops.moe import LatentMoE
+            y = LatentMoE(
+                cfg.d_model, cfg.moe_latent_size, cfg.moe_experts,
+                cfg.moe_d_ff, cfg.moe_shared_d_ff, top_k=cfg.moe_top_k,
+                act=cfg.moe_act, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, scoring=cfg.moe_scoring,
+                route_scale=cfg.moe_route_scale, held=cfg.moe_experts_held,
+                held_first=cfg.moe_held_first, name="mixer")(
+                y, live, moe_stacked, layer)
+        else:
+            y = Attention(cfg, self.mesh, self.rules, self.decode,
+                          self.prefix_attend, name="mixer")(
+                y, cos, sin, positions, block_tables, carry, layer, live)
+        if carry is not None:
+            y, carry = y
+        y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
+        x = x + y
+        if self.mesh is not None and not self.decode:
+            x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
+                              self.rules)
+        return x if carry is None else (x, carry)
+
+
+class Period(nn.Module):
+    """One period of ``cfg.layer_types``: what ``stack_layers`` scans
+    where the layers are of more than one block CLASS (their parameter
+    trees differ, so one stacked ``Block`` cannot hold them).  The
+    parameters are stacked over periods, one subtree ``layer_<j>`` a
+    position in the period.  The carry is ``(pool, rec)``: the KV pool
+    stacked over the layers that hold pages ONLY (``POOL_KINDS``) and
+    the recurrent leaves stacked over the layers that hold a state
+    entry (``STATE_KINDS``).  Each class names its carry (``_carry_of``;
+    an expert layer has none); ``period`` (the scanned index) times the
+    layers of that carry a period, plus the position's rank among them,
+    is the layer's index into its leaf."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Mesh] = None
+    rules: ShardingRules = LOGICAL_RULES
+    decode: bool = False
+    prefix_attend: bool = False
+
+    @staticmethod
+    def _carry_of(kind: str):
+        """Which of the period's two carries a layer of class ``kind``
+        reads and writes: 0 the pool, 1 the recurrent leaves, None."""
+        return 0 if kind in POOL_KINDS else 1 if kind in STATE_KINDS else None
+
+    @nn.compact
+    def __call__(self, x, cos, sin, positions=None, block_tables=None,
+                 lengths=None, entries=None, moe_stacked=None, carry=None,
+                 period=None):
+        """``moe_stacked``: ``{position in the period: the expert leaves
+        stacked over periods}`` (``GPT._moe_stacked``), so that the
+        decode kernel reads a layer's experts in place."""
         kinds = self.cfg.period
-        pool, rec = carry if carry is not None else (None, None)
-        seen = {"full_attention": 0, "linear_attention": 0}
-        for j, kind in enumerate(kinds):
-            layer = None if carry is None else (
-                period * kinds.count(kind) + seen[kind])
-            seen[kind] += 1
+        carries = [None, None] if carry is None else list(carry)
+        held = [self._carry_of(kind) for kind in kinds]
+        seen = [0, 0]
+        blocks = (self.cfg, self.mesh, self.rules, self.decode)
+        for j, (kind, at) in enumerate(zip(kinds, held)):
+            mine = layer = None
+            if at is not None and carry is not None:
+                mine = carries[at]
+                layer = period * held.count(at) + seen[at]
+                seen[at] += 1
             if kind == "full_attention":
                 # not told the lengths: a period's prompt waves stay
                 # one pass (its linear layers compute every position)
-                x = Block(self.cfg, self.mesh, self.rules, self.decode,
-                          self.prefix_attend, name=f"layer_{j}")(
-                    x, cos, sin, positions, block_tables, None, None, pool,
+                x = Block(*blocks, self.prefix_attend, name=f"layer_{j}")(
+                    x, cos, sin, positions, block_tables, None, None, mine,
                     layer)
-                if pool is not None:
-                    x, pool = x
+            elif kind == "linear_attention":
+                x = LinearBlock(*blocks, name=f"layer_{j}")(
+                    x, block_tables, lengths, entries, mine, layer)
             else:
-                x = LinearBlock(self.cfg, self.mesh, self.rules,
-                                self.decode, name=f"layer_{j}")(
-                    x, block_tables, lengths, entries, rec, layer)
-                if rec is not None:
-                    x, rec = x
-        return x if carry is None else (x, (pool, rec))
+                stacked = (moe_stacked or {}).get(j)
+                x = MixerBlock(self.cfg, kind, *blocks[1:],
+                               self.prefix_attend, name=f"layer_{j}")(
+                    x, cos, sin, positions, block_tables, lengths, entries,
+                    mine, period if stacked else layer, stacked)
+            if mine is not None:
+                x, carries[at] = x
+        return x if carry is None else (x, tuple(carries))
 
 
 def output_logits(cfg: TransformerConfig, params, hidden) -> jax.Array:
@@ -1206,8 +1385,8 @@ class GPT(nn.Module):
     paged_pages: int = 0                   # >0: paged KV decode (see Attention)
     page_size: int = 64
     prefix_attend: bool = False            # suffix prefill over cached pages
-    # entries of the recurrent leaves (see LinearAttention) of a paged
-    # model with linear_attention layers; entry 0 is scratch
+    # entries of the recurrent leaves (see LinearAttention,
+    # Mamba2Mixer) of a paged model with such layers; entry 0 is scratch
     state_entries: int = 0
 
     def _moe_stacked(self):
@@ -1216,13 +1395,20 @@ class GPT(nn.Module):
         kernel that reads them): they ride ``call_args`` into the scan
         body beside the layer index, as the KV pool rides the carry, so
         that no layer's experts are sliced out.  None where there is no
-        such stack (no dropless experts, unrolled layers, initialising)."""
+        such stack (no dropless experts, unrolled layers, initialising).
+        A stack of periods: one triple a latent_moe position."""
         cfg = self.cfg
         if not (self.decode and cfg.moe_dropless and cfg.moe_experts
                 and cfg.scan_layers) or self.is_initializing():
             return None
-        moe = nn.meta.unbox(self.variables["params"]["blocks"]["moe"])
-        return moe["w_gate"], moe["w_up"], moe["w_down"]
+        leaves = lambda moe: (moe.get("w_gate"), moe["w_up"],  # noqa: E731
+                              moe["w_down"])
+        blocks = nn.meta.unbox(self.variables["params"]["blocks"])
+        if cfg.period:       # by position in the period, stacked over them
+            return {j: leaves(blocks[f"layer_{j}"]["mixer"]["moe"])
+                    for j, kind in enumerate(cfg.period)
+                    if kind == "latent_moe"}
+        return leaves(blocks["moe"])
 
     def _stack_blocks(self, x, block_kwargs, call_args, **stack):
         """``stack_layers`` of ``Block`` over every layer.  Where the
@@ -1248,12 +1434,13 @@ class GPT(nn.Module):
                             first_layer=first, carry=carry, **stack)
 
     def _stack_periods(self, x, block_kwargs, call_args, lengths, entries,
-                       remat):
+                       moe_stacked, remat):
         """The layer stack of a model whose ``layer_types`` name more
         than one block class: ``Period`` is what is scanned (4 layers a
         step at Olmo-Hybrid's 3 + 1), and a paged decode model carries
-        ``(pool, (state, conv))`` through it: the KV pool has the
-        full-attention layers only, the recurrent leaves the others."""
+        ``(pool, (state, conv))`` through it: the KV pool has the layers
+        that hold pages only, the recurrent leaves (their shapes the
+        recurrent class's own) those that hold a state entry."""
         cfg = self.cfg
         if cfg.remat_layers is not None:
             raise ValueError("remat_layers counts layers of one class; "
@@ -1263,37 +1450,48 @@ class GPT(nn.Module):
         if not (self.decode and self.paged_pages):
             if self.decode and not self.is_initializing():
                 raise ValueError(
-                    "a linear_attention layer has no dense-cache decode: "
-                    "its state lives in the paged engine's entries "
-                    "(serve/llm_engine.py); Generator cannot run it")
+                    "a layer with a recurrent state has no dense-cache "
+                    "decode: its state lives in the paged engine's "
+                    "entries (serve/llm_engine.py); Generator cannot run "
+                    "it")
             return stack_layers(Period, cfg, block_kwargs, x,
-                                call_args + (lengths, None), remat=remat,
-                                cache=True, n_layers=n_periods)
-        n_full = cfg.layers_of("full_attention")
-        n_lin = cfg.n_layers - n_full
-        # the convolution's last inputs, every channel of [q; k; v]
-        tail = (cfg.linear_conv_kernel - 1) * (
-            2 * cfg.linear_key_heads * cfg.linear_key_head_dim
-            + cfg.linear_value_heads * cfg.linear_value_head_dim)
+                                call_args + (lengths, None, None),
+                                remat=remat, cache=True, n_layers=n_periods)
+        n_pool = cfg.layers_of(*POOL_KINDS)
+        n_state = cfg.layers_of(*STATE_KINDS)
+        # a request's state in one recurrent layer, and its
+        # convolution's last inputs over every channel convolved
+        if cfg.layers_of("mamba2"):
+            names = "ssm_state", "ssm_conv"
+            entry = (cfg.ssm_state_size,
+                     cfg.mamba_heads * cfg.mamba_head_dim)
+            tail = (cfg.mamba_conv_kernel - 1) * (
+                entry[1] + 2 * cfg.mamba_groups * cfg.ssm_state_size)
+        else:
+            names = "gdn_state", "gdn_conv"
+            entry = (cfg.linear_key_head_dim,
+                     cfg.linear_value_heads * cfg.linear_value_head_dim)
+            tail = (cfg.linear_conv_kernel - 1) * (
+                2 * cfg.linear_key_heads * cfg.linear_key_head_dim
+                + cfg.linear_value_heads * cfg.linear_value_head_dim)
         ckv = self.variable(
             "cache", "kv_pages", jnp.zeros,
-            (n_full, self.paged_pages, cfg.n_kv_heads, self.page_size,
+            (n_pool, self.paged_pages, cfg.n_kv_heads, self.page_size,
              2 * cfg.head_dim), cfg.dtype)
         cst = self.variable(
-            "cache", "gdn_state", jnp.zeros,
-            (n_lin, self.state_entries, cfg.linear_key_head_dim,
-             cfg.linear_value_heads * cfg.linear_value_head_dim),
-            jnp.float32)
+            "cache", names[0], jnp.zeros,
+            (n_state, self.state_entries) + entry, jnp.float32)
         ccv = self.variable(
-            "cache", "gdn_conv", jnp.zeros,
-            (n_lin, self.state_entries) + (
+            "cache", names[1], jnp.zeros,
+            (n_state, self.state_entries) + (
                 (tail // 128, 128) if tail % 128 == 0 else (1, tail)),
             cfg.dtype)
         if entries is None:
             entries = jnp.arange(x.shape[0], dtype=jnp.int32)
         x, (pool, (state, conv)) = stack_layers(
-            Period, cfg, block_kwargs, x, call_args + (lengths, entries),
-            remat=False, n_layers=n_periods,
+            Period, cfg, block_kwargs, x,
+            call_args + (lengths, entries, moe_stacked), remat=False,
+            n_layers=n_periods,
             carry=(ckv.value, (cst.value, ccv.value)))
         if not self.is_initializing():
             ckv.value, cst.value, ccv.value = pool, state, conv
@@ -1353,7 +1551,7 @@ class GPT(nn.Module):
                      self._moe_stacked(), lengths)
         if cfg.period:
             x = self._stack_periods(x, block_kwargs, call_args[:4], lengths,
-                                    state_rows, do_remat)
+                                    state_rows, call_args[4], do_remat)
         elif self.decode and self.paged_pages:
             # the paged pool: ONE stacked leaf for the whole model, its
             # row what the model's attention caches of a token (K in

@@ -134,6 +134,14 @@ class MoEMLP(nn.Module):
 # ``moe_experts_decode`` kernel, which reads only the experts that live
 # rows chose (PERF.md, PR 27); elsewhere every expert is multiplied
 DENSE_PAIRS_MAX = 512
+# the top-k that threshold was measured at.  What the small-batch
+# formulation costs follows the ROWS (each touched expert is read once
+# for all of them), so a batch of no more rows than that threshold is at
+# top-6 takes it whatever its top-k: 65 decode rows at top-22 are 1,430
+# pairs, and XLA's TPU lowering of their ``ragged_dot`` is an
+# all-experts product over all 1,430 pair rows, 1 TFLOP a matrix a layer
+# (tests/test_chip_compile.py, ISSUE 44)
+DENSE_PAIRS_TOP_K = 6
 
 # pairs above which the grouped formulation runs a chunk of tokens at a
 # time: its sorted copies of the pairs' rows are ``[pairs, d]`` each (a
@@ -145,6 +153,11 @@ RAGGED_PAIRS_MAX = 1 << 17
 # much VMEM before the expert width is tiled (a v5e core has 128 MiB;
 # SmallThinker's expert is 11.8 MB, 23.6 MB double-buffered)
 _KERNEL_WEIGHTS_VMEM = 40 << 20
+
+
+# an expert's activation by name ("relu2": Nemotron's squared ReLU)
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+        "relu2": lambda h: jnp.square(jax.nn.relu(h))}
 
 
 def route_top_k(logits: jax.Array, k: int, *, scoring: str = "softmax",
@@ -187,12 +200,21 @@ def touched_experts(experts: jax.Array, live, n_experts: int):
     return jnp.where(slot[:, 0] < count, ids, ids.max()), count
 
 
-def expert_kernel_applies(pairs: int, d: int, f: int) -> bool:
-    """Whether ``pairs`` (rows x top_k) on STACKED experts of widths
-    ``d``, ``f`` run the Pallas kernel: the small-pair formulation, on
+def small_batch(pairs: int, rows: int = None) -> bool:
+    """Whether ``pairs`` (rows x top_k) take the formulation in which
+    each touched expert is read once for all rows: few pairs, or few
+    ``rows`` at any top-k (``DENSE_PAIRS_TOP_K``)."""
+    return pairs <= DENSE_PAIRS_MAX or (
+        rows is not None and rows <= DENSE_PAIRS_MAX // DENSE_PAIRS_TOP_K)
+
+
+def expert_kernel_applies(pairs: int, d: int, f: int,
+                          rows: int = None) -> bool:
+    """Whether ``pairs`` (``rows`` x top_k) on STACKED experts of widths
+    ``d``, ``f`` run the Pallas kernel: the small-batch formulation, on
     the TPU, at lane-aligned widths (a choice by shape and platform, as
     ``paged_attention`` makes it)."""
-    return (pairs <= DENSE_PAIRS_MAX and d % 128 == 0 and f % 128 == 0
+    return (small_batch(pairs, rows) and d % 128 == 0 and f % 128 == 0
             and backend_platform() == "tpu")
 
 
@@ -218,18 +240,22 @@ def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
     the down product, summed over experts in float32, rounded once.
 
     x [N, d] (N a multiple of 16); combine [N, E] float32; ids [S],
-    count [1], layer [1] int32 (scalar-prefetch operands)."""
+    count [1], layer [1] int32 (scalar-prefetch operands).  ``w_gate``
+    None: an expert that is not gated, ``down(fn(up x))``, two matrices
+    an expert."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, d = x.shape
-    e, f = w_gate.shape[1], w_gate.shape[3]
+    e, f = w_up.shape[1], w_up.shape[3]
     slots = ids.shape[0]
     tf = _width_tile(d, f, x.dtype.itemsize)
     nf = f // tf
+    gated = w_gate is not None
 
-    def kernel(ids_ref, count_ref, layer_ref, x_ref, c_ref, wg_ref, wu_ref,
-               wd_ref, o_ref, acc_ref):
+    def kernel(ids_ref, count_ref, layer_ref, x_ref, c_ref, *refs):
+        wg_ref = refs[0] if gated else None
+        wu_ref, wd_ref, o_ref, acc_ref = refs[-4:]
         s, j = pl.program_id(0), pl.program_id(1)
 
         @pl.when((s == 0) & (j == 0))
@@ -239,16 +265,18 @@ def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
         @pl.when(s < count_ref[0])
         def _():
             xv = x_ref[...]
-            gate_h = jnp.dot(xv, wg_ref[...],
-                             preferred_element_type=jnp.float32)
+            if gated:
+                gate_h = jnp.dot(xv, wg_ref[...],
+                                 preferred_element_type=jnp.float32)
             up_h = jnp.dot(xv, wu_ref[...],
                            preferred_element_type=jnp.float32)
             # this expert's column of the gates, picked by a lane mask
             lane = jax.lax.broadcasted_iota(jnp.int32, (n, e), 1)
             gate = jnp.sum(jnp.where(lane == ids_ref[s], c_ref[...], 0.0),
                            axis=1, keepdims=True)               # [N, 1]
-            h = (fn(gate_h.astype(xv.dtype).astype(jnp.float32))
-                 * up_h.astype(xv.dtype).astype(jnp.float32))
+            h = up_h.astype(xv.dtype).astype(jnp.float32)
+            h = (fn(gate_h.astype(xv.dtype).astype(jnp.float32)) * h
+                 if gated else fn(h))
             h = h.astype(xv.dtype).astype(jnp.float32) * gate
             acc_ref[...] += jnp.dot(h.astype(xv.dtype), wd_ref[...],
                                     preferred_element_type=jnp.float32)
@@ -273,7 +301,7 @@ def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
             grid=(slots, nf),
             in_specs=[pl.BlockSpec((n, d), whole),
                       pl.BlockSpec((n, e), whole),
-                      weights(False), weights(False), weights(True)],
+                      *[weights(False)] * (1 + gated), weights(True)],
             out_specs=pl.BlockSpec((n, d), whole),
             scratch_shapes=[pltpu.VMEM((n, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
@@ -281,7 +309,8 @@ def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_KERNEL_WEIGHTS_VMEM + (16 << 20)),
         name="moe_experts_decode",
-    )(ids, count, layer, x, combine, w_gate, w_up, w_down)
+    )(ids, count, layer, x, combine, *([w_gate] if gated else []), w_up,
+      w_down)
 
 
 def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
@@ -289,25 +318,31 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
                      partial: bool = False) -> jax.Array:
     """``sum_j gates[t, j] * down_e(act(gate_e x_t) * up_e x_t)`` with
     ``e = experts[t, j]``, for EVERY pair ``(t, j)``: no capacity, nothing
-    dropped whatever the imbalance.
+    dropped whatever the imbalance.  ``w_gate`` None: an expert that is
+    not gated, ``down_e(act(up_e x_t))``, in every formulation; ``act``
+    ``"relu2"`` is ``relu(.)^2``.
 
     x [N, d]; gates, experts [N, k]; w_gate, w_up [E, d, f]; w_down
     [E, f, d] (already in the compute dtype), or with ``layer`` (an int32
     scalar, traced or not) a layer stack's whole leaves ``[L, E, ...]``,
     of which layer ``layer`` is read.  Two formulations of the same sum,
-    chosen by the static number of pairs (``DENSE_PAIRS_MAX``).  Up to
-    it, the gates pick among experts that are each read once for all
-    rows (``live [N]`` bool: only those rows count and the others come
-    out zero; None: every row): of stacked weights, on the TPU at
+    chosen by the static number of pairs and rows (``small_batch``).
+    In a small batch the gates pick among experts that are each read
+    once for all rows (``live [N]`` bool: only those rows count and the
+    others come out zero; None: every row): of stacked weights, on the
+    TPU at
     lane-aligned widths, the ``moe_experts_decode`` kernel reads the
     ``touched_experts`` in place; otherwise, and as the kernel's oracle,
     all experts are multiplied (a kernel fed a scan's slice of the
     stack would have XLA copy the layer's experts out first, and no
-    gradient is defined through it: it is the decode path).  Above it,
-    a sort by expert and three ``jax.lax.ragged_dot`` (on the TPU a
+    gradient is defined through it: it is the decode path).  In a
+    larger one, a sort by expert and three ``jax.lax.ragged_dot`` (on
+    the TPU a
     grouped-matmul kernel that reads each touched expert once and
-    computes only the pairs' rows; ``live`` is not looked at; above
-    ``RAGGED_PAIRS_MAX`` pairs, a chunk of tokens after the other).
+    computes only the pairs' rows; a row ``live`` leaves out sends its
+    pairs to the id ``E`` below, so nothing is read or multiplied for
+    it and it comes out zero; above ``RAGGED_PAIRS_MAX`` pairs, a chunk
+    of tokens after the other).
 
     ``experts`` index the ``E`` experts of the weights GIVEN.  With
     ``partial`` an id may be ``E``: a pair whose expert is not among
@@ -315,19 +350,23 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     anything is read or multiplied for it (a scatter drops an index out
     of range; the sort puts such pairs behind every group)."""
     n, d = x.shape
-    e, f = w_gate.shape[-3], w_gate.shape[-1]
+    e, f = w_up.shape[-3], w_up.shape[-1]
     k = experts.shape[1]
-    fn = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    fn = ACTS[act]
+    gated = w_gate is not None
     if n * k > RAGGED_PAIRS_MAX and n % 2 == 0:
         halves = lambda a: a.reshape(2, n // 2, *a.shape[1:])  # noqa: E731
         return jax.lax.map(
-            lambda xs: dropless_experts(*xs, w_gate, w_up, w_down, act=act,
-                                        layer=layer, partial=partial),
-            (halves(x), halves(gates), halves(experts))).reshape(n, d)
-    kernel = layer is not None and expert_kernel_applies(n * k, d, f)
+            lambda xs: dropless_experts(*xs[:3], w_gate, w_up, w_down,
+                                        act=act, live=xs[3], layer=layer,
+                                        partial=partial),
+            (halves(x), halves(gates), halves(experts),
+             None if live is None else halves(live))).reshape(n, d)
+    kernel = layer is not None and expert_kernel_applies(n * k, d, f, n)
     if layer is not None and not kernel:
-        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
-    if n * k <= DENSE_PAIRS_MAX:
+        w_gate, w_up, w_down = (w if w is None else w[layer]
+                                for w in (w_gate, w_up, w_down))
+    if small_batch(n * k, n):
         if live is not None:
             gates = jnp.where(live[:, None], gates, 0.0)
         # combine[n, e]: the token's gate for expert e, 0 if not chosen
@@ -340,17 +379,23 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
                 jnp.pad(x, pad), jnp.pad(combine, pad), ids,
                 count.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
                 w_gate, w_up, w_down, fn)[:n]
-        gate_h = jnp.einsum("nd,edf->enf", x, w_gate)
         up_h = jnp.einsum("nd,edf->enf", x, w_up)
-        h = (fn(gate_h) * up_h).astype(jnp.float32) * combine.T[:, :, None]
+        h = (fn(jnp.einsum("nd,edf->enf", x, w_gate)) * up_h if gated
+             else fn(up_h))
+        h = h.astype(jnp.float32) * combine.T[:, :, None]
         return jnp.einsum("enf,efd->nd", h.astype(x.dtype), w_down)
+    if live is not None:
+        # a row that holds no request sends its pairs where ``partial``
+        # sends another chip's: behind every group, nothing read for them
+        experts, partial = jnp.where(live[:, None], experts, e), True
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True)              # pairs by expert
     sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
     xs = jnp.take(x, order // k, axis=0)                # [N*k, d]
-    gate_h = jax.lax.ragged_dot(xs, w_gate, sizes)
     up_h = jax.lax.ragged_dot(xs, w_up, sizes)
-    out = jax.lax.ragged_dot(fn(gate_h) * up_h, w_down, sizes)
+    h = (fn(jax.lax.ragged_dot(xs, w_gate, sizes)) * up_h if gated
+         else fn(up_h))
+    out = jax.lax.ragged_dot(h, w_down, sizes)
     out = out * jnp.take(gates.reshape(-1), order)[:, None].astype(
         out.dtype)
     if partial:          # rows behind the last group belong to no expert
@@ -386,6 +431,8 @@ class DroplessMoE(nn.Module):
     route_scale: float = 1.0
     held: Optional[int] = None
     held_first: int = 0
+    # False: an expert is down(act(up x)), two matrices and no w_gate
+    gated: bool = True
 
     def setup(self):
         d, f = self.d_model, self.d_ff
@@ -409,7 +456,7 @@ class DroplessMoE(nn.Module):
         self.w_gate = self.param(
             "w_gate", nn.with_logical_partitioning(
                 init, ("expert", "expert_in", "expert_mlp")),
-            (e, d, f), self.param_dtype)
+            (e, d, f), self.param_dtype) if self.gated else None
         self.w_up = self.param(
             "w_up", nn.with_logical_partitioning(
                 init, ("expert", "expert_in", "expert_mlp")),
@@ -450,8 +497,9 @@ class DroplessMoE(nn.Module):
         dt = self.dtype
         weights = self.w_gate, self.w_up, self.w_down
         if (stacked is not None and layer is not None
-                and stacked[0].dtype == dt
-                and expert_kernel_applies(b * s * self.top_k, d, self.d_ff)):
+                and stacked[1].dtype == dt
+                and expert_kernel_applies(b * s * self.top_k, d, self.d_ff,
+                                          b * s)):
             weights = stacked
         else:           # this layer's own slice, as the scan hands it over
             layer = None
@@ -459,6 +507,73 @@ class DroplessMoE(nn.Module):
             live = jnp.repeat(live, s)
         y = dropless_experts(
             x.reshape(b * s, d).astype(dt), gates, experts,
-            *(w.astype(dt) for w in weights), act=self.act, live=live,
+            *(w if w is None else w.astype(dt) for w in weights),
+            act=self.act, live=live,
             layer=layer, partial=self.held is not None)
         return y.reshape(b, s, d).astype(x.dtype)
+
+
+class LatentMoE(nn.Module):
+    """Routed experts that work in a LATENT narrower than the model
+    (Nemotron 3's LatentMoE), around ``DroplessMoE``::
+
+        out = W_out sum_e gate_e down_e(act(up_e (W_in x))) + shared(x)
+
+    The router and the shared expert (not gated either, ``shared_d_ff``
+    wide) read the full ``d_model``; only the routed experts see the
+    ``latent`` width, so an expert's two matrices are ``latent x d_ff``.
+    ``held`` / ``held_first`` are ``DroplessMoE``'s: this layer holds
+    that share of the routed experts, and the projections, the router
+    and the shared expert whole."""
+
+    d_model: int
+    latent: int
+    n_experts: int
+    d_ff: int
+    shared_d_ff: int
+    top_k: int = 2
+    act: str = "relu2"
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+    scoring: str = "sigmoid"
+    route_scale: float = 1.0
+    held: Optional[int] = None
+    held_first: int = 0
+
+    @nn.compact
+    def __call__(self, x: jax.Array, live=None, stacked=None,
+                 layer=None) -> jax.Array:
+        """``stacked``, ``layer``: ``DroplessMoE.__call__``'s (the routed
+        experts' whole leaves and this layer's index among them)."""
+        def dense(features, axes, name):
+            return nn.DenseGeneral(
+                features, axis=-1, use_bias=False, name=name,
+                dtype=self.dtype, param_dtype=self.param_dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), axes))
+        fn = ACTS[self.act]
+        moe = DroplessMoE(self.latent, self.n_experts, self.d_ff,
+                          top_k=self.top_k, act=self.act, dtype=self.dtype,
+                          param_dtype=self.param_dtype, scoring=self.scoring,
+                          route_scale=self.route_scale, held=self.held,
+                          held_first=self.held_first, gated=False,
+                          name="moe")
+        with jax.named_scope("latent_moe"):
+            # one buffer of the layer's input for its three readers
+            # (router, latent projection, shared expert).  Without the
+            # barrier the TPU compiler recomputes the norm inside each
+            # reader's fusion, here from a residual sum that took the
+            # previous mixer's float32 accumulator and there from its
+            # rounded copy, so the router read activations 2.4e-3 of
+            # its logits away from any array of the program (PERF.md
+            # section 6, PR 44); sown, for a check of the router's
+            # arithmetic against what it read
+            x = jax.lax.optimization_barrier(x)
+            self.sow("intermediates", "router_in", x)
+            logits = moe.router_logits(x)
+            routed = moe(dense(self.latent, ("embed", "mlp"), "w_in")(x),
+                         logits, live, stacked, layer)
+            shared = dense(self.d_model, ("mlp", "embed"), "shared_down")(
+                fn(dense(self.shared_d_ff, ("embed", "mlp"), "shared_up")(x)))
+            return dense(self.d_model, ("mlp", "embed"), "w_out")(
+                routed) + shared

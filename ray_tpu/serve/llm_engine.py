@@ -73,12 +73,15 @@ iteration's prefills):
     its (token, position, table) row into the block step's device
     state.  Time-to-first-token is bounded by prefill throughput and
     pool capacity, not by slot turnover.
-  - State that is not pages.  A model with linear_attention layers
-    (models/gpt.py LinearAttention) keeps, beside its KV pages in the
-    full-attention layers, a FIXED-SIZE recurrent state a request: two
-    more stacked cache leaves ``gdn_state`` [linear layers, entries,
-    dk, heads*dv] float32 and ``gdn_conv`` [linear layers, entries,
-    (taps-1)*channels/128, 128], chained and donated with the pool.  A request
+  - State that is not pages.  A model with recurrent layers
+    (models/gpt.py LinearAttention, Mamba2Mixer) keeps, beside its KV
+    pages in the attention layers, a FIXED-SIZE recurrent state a
+    request: two more stacked cache leaves ``gdn_state`` [linear layers,
+    entries, dk, heads*dv] float32 and ``gdn_conv`` [linear layers,
+    entries, (taps-1)*channels/128, 128] (``ssm_state`` [mamba2 layers,
+    entries, N, heads*P] and ``ssm_conv`` for Mamba-2 layers: the model
+    declares them, the engine never names their shapes), chained and
+    donated with the pool.  A request
     holds one ENTRY of them from admission to finish, allocated and
     freed with its pages (``_free_states`` beside ``_free_pages``;
     admission waits for either); entry 0 is scratch, as page 0 is.  The
@@ -127,7 +130,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private.profiler import span
-from ray_tpu.models.configs import TransformerConfig
+from ray_tpu.models.configs import (POOL_KINDS, STATE_KINDS,
+                                    TransformerConfig)
 from ray_tpu.models.gpt import GPT, output_logits, prefill_positions
 from ray_tpu.serve.frontdoor.prefix import page_digests
 
@@ -398,7 +402,8 @@ class EngineStats:
         self.decode_pages_read = 0
         self.window_pages_read = 0
         self.window_pages_skipped = 0
-        # recurrent (gated delta) layers: a layer step is one such layer
+        # recurrent layers of either class (gated delta, Mamba-2; the
+        # names are the first class's): a layer step is one such layer
         # in one decode step; gdn_state_rows sums over them the rows
         # whose token was delivered, each of which had its state entry
         # read and written once by that layer step.  Host arithmetic,
@@ -582,13 +587,13 @@ class LLMEngine:
                               else 1 + (num_slots + 1) * self.max_pages)
         # layers that hold KV pages, and layers that hold a recurrent
         # state entry instead (module docstring)
-        self._pool_layers = cfg.layers_of("full_attention")
-        self._state_layers = cfg.n_layers - self._pool_layers
+        self._pool_layers = cfg.layers_of(*POOL_KINDS)
+        self._state_layers = cfg.layers_of(*STATE_KINDS)
         self.state_entries = (num_slots + 1 + _STATE_AHEAD
                               if self._state_layers else 0)
         if self._state_layers and prefix_cache_pages:
             raise ValueError(
-                "prefix_cache_pages > 0 on a model with linear_attention "
+                "prefix_cache_pages > 0 on a model with recurrent "
                 "layers: a cached page run would need a snapshot of the "
                 "recurrent state at its page boundary to resume from, "
                 "which the prefix cache does not keep")
@@ -1067,7 +1072,7 @@ class LLMEngine:
     def _refuse_handoff(self) -> None:
         if self._state_layers:
             raise ValueError(
-                "prefill handoff on a model with linear_attention "
+                "prefill handoff on a model with recurrent "
                 "layers: a PrefillHandoff carries KV pages only, not the "
                 "request's recurrent state and convolution tail, so the "
                 "importer would decode from an empty state")

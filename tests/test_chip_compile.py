@@ -56,7 +56,7 @@ def _answer_tpu(patch):
     # would put that back for every later test of the process
     mods = [importlib.import_module(f"ray_tpu.ops.{name}") for name in
             ("attention", "flash_attention", "paged_attention", "moe",
-             "gated_delta")]
+             "gated_delta", "mamba2")]
     for mod in mods:
         patch.setattr(mod, "backend_platform", lambda: "tpu")
 
@@ -654,6 +654,133 @@ def test_kanana_cell_fits_the_chip(kanana_programs):
     assert 11.3e9 < block.argument_size_in_bytes < 11.6e9
     assert block.temp_size_in_bytes < 0.3e9
     for bucket in (8192, 4096):
+        prefill = p[f"engine_prefill_{bucket}"].memory_analysis()
+        assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
+                + block.temp_size_in_bytes) < 16.0e9, (
+            bucket, prefill.temp_size_in_bytes / 1e9)
+
+
+# ---- layers that are one mixer each: Mamba-2, LatentMoE, attention
+# ---- (ISSUE 44)
+
+@pytest.fixture(scope="module")
+def nemotron_programs(topo, one_chip):
+    """``engine_decode_block`` and the two widest prefill waves the
+    serve-workers cell may form under ``prefill_wave_tokens`` 4096 (4 x
+    1024 and 16 x 256) of Nemotron-3-Super at the cell's cut and server:
+    the 11-layer period ``MEMEMEMEM*E`` at the published widths, 128 of
+    512 experts a layer held, a quarter of the vocabulary, 64 slots, 73
+    state entries.  Compiled once for the tests below."""
+    from ray_tpu.models.configs import get_config, pattern_layer_types
+
+    cfg = get_config(
+        "nemotron-3-super-120b-a12b", n_layers=11, vocab_size=32768,
+        layer_types=pattern_layer_types("MEMEMEMEM*E"), max_seq_len=4096,
+        moe_experts_held=128, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    patch = pytest.MonkeyPatch()
+    _answer_tpu(patch)
+    try:
+        eng = _described_engine(cfg, patch, num_slots=64, max_seq_len=4096,
+                                max_prompt_len=1024, kv_pool_pages=4673,
+                                prefill_wave_tokens=4096)
+        block = eng._block_jit.lower(
+            *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                     one_chip))
+        out = {"eng": eng, "block_text": block.as_text(),
+               "engine_decode_block": block.compile()}
+        for bucket, wave in ((1024, 4), (256, 16)):
+            prefill = eng._get_prefill_paged(bucket, wave).lower(
+                *_shapes((eng.params, eng._cache,
+                          jnp.zeros((wave, eng.packed_width(bucket)),
+                                    jnp.int32),
+                          jnp.zeros((wave, eng.max_pages), jnp.int32),
+                          jax.random.PRNGKey(0)), one_chip))
+            out[f"prefill_text_{bucket}"] = prefill.as_text()
+            out[f"engine_prefill_{bucket}"] = prefill.compile()
+        return out
+    finally:
+        patch.undo()
+
+
+def test_nemotron_engine_programs_compile_with_their_kernels(
+        nemotron_programs):
+    """The decode block holds ``ssm_decode`` once a Mamba-2 layer of the
+    period, the paged kernel once (32 query heads on 2 KV heads) and
+    ``moe_experts_decode`` once an expert layer, on the period's stacked
+    HELD experts of two matrices each: its 1,430 pairs a step are past
+    ``DENSE_PAIRS_MAX`` but its 65 rows are a small batch, and the
+    sorted ``ragged_dot`` of so few rows lowers to an all-experts
+    product over all 1,430 pair rows (1 TFLOP a matrix a layer); the
+    state leaves have the shapes the model declares."""
+    p = nemotron_programs
+    assert {k: v.shape for k, v in p["eng"]._cache.items()} == {
+        "kv_pages": (1, 4673, 2, 64, 256),
+        "ssm_state": (5, 73, 128, 8192),
+        "ssm_conv": (5, 73, 240, 128)}
+    assert p["eng"]._cache["ssm_state"].dtype == jnp.float32
+    text = p["block_text"]
+    assert text.count('kernel_name = "ssm_decode"') == 5
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
+    assert text.count('kernel_name = "moe_experts_decode"') == 5
+    assert "@jit_engine_decode_block" in text
+    import re
+    hlo = p["engine_decode_block"].as_text()
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*%?moe_experts_decode\S* = ", line)]
+    assert len(calls) == 5
+    for call in calls:
+        assert call.count("bf16[1,128,1024,2688]") == 1      # w_up
+        assert call.count("bf16[1,128,2688,1024]") == 1      # w_down
+    assert "convolution-base-dilated" not in hlo
+    for bucket in (1024, 256):
+        assert "@jit_engine_prefill" in p[f"prefill_text_{bucket}"]
+        # the chunked (SSD) prefill form is plain XLA, and a wave's
+        # 90,112 pairs are the grouped ragged products
+        assert "ssm_decode" not in p[f"prefill_text_{bucket}"]
+        assert "moe_experts_decode" not in p[f"prefill_text_{bucket}"]
+        assert "ragged-dot" in p[f"engine_prefill_{bucket}"].as_text()
+
+
+@pytest.mark.parametrize("name", ["engine_decode_block",
+                                  "engine_prefill_1024",
+                                  "engine_prefill_256"])
+def test_nemotron_programs_address_pool_state_and_experts_in_place(
+        nemotron_programs, name):
+    """Nothing but parameters and in-place updates produces a result of
+    the size of the KV pool, of the state leaf or of the tail leaf, whole
+    or one layer of it; and no operation of the decode block makes a
+    copy of a layer's held experts (one matrix of them is 704 MB) ahead
+    of the kernel that reads them.  The tail leaf is not looked at in
+    the decode block: there the compiler itself moves the whole 22 MB of
+    it through fast memory a step (``S(1)``: slices in, a
+    ``ConcatBitcast``, one copy back, in forms that change with its
+    schedule), 45 MB beside the ~9 GB a step reads."""
+    hlo = nemotron_programs[name].as_text()
+    pool = 4673 * 2 * 64 * 256
+    state, tail = 73 * 128 * 8192, 73 * 240 * 128
+    for what, sizes, dtype in (("pool", (pool,), "bf16"),
+                               ("state", (state, 5 * state), "f32"),
+                               ("tail", (tail, 5 * tail), "bf16")):
+        if what == "tail" and name == "engine_decode_block":
+            continue
+        made = _pool_result_producers(hlo, sizes, dtype)
+        assert set(made) <= _IN_PLACE, (
+            f"{name}: {what}-sized results from {dict(made)}")
+    if name == "engine_decode_block":
+        made = _pool_result_producers(hlo, (128 * 1024 * 2688,))
+        assert set(made) <= _IN_PLACE, (
+            f"{name}: a layer's experts from {dict(made)}")
+
+
+def test_nemotron_cell_fits_the_chip(nemotron_programs):
+    """11.2 GB resident (weights 9.30, state entries 1.55, pool 0.31),
+    arguments and temporaries of the decode block and of each of the
+    widest prefill waves under 16 GB."""
+    p = nemotron_programs
+    block = p["engine_decode_block"].memory_analysis()
+    assert 11.0e9 < block.argument_size_in_bytes < 11.4e9
+    assert (block.argument_size_in_bytes + block.temp_size_in_bytes) < 16e9
+    for bucket in (1024, 256):
         prefill = p[f"engine_prefill_{bucket}"].memory_analysis()
         assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
                 + block.temp_size_in_bytes) < 16.0e9, (
