@@ -534,8 +534,14 @@ class Attention(nn.Module):
         reads only the pages that hold the last ``window`` positions,
         and a T > 1 span longer than the window is masked by it.
         ``live`` [rows] bool (None: every row): decode reads no page of
-        a row it leaves out, whose output is zeros; its K/V is still
-        written (to the scratch page its table names).
+        a row it leaves out, whose output is zeros.
+
+        Who writes: a prompt's rows (T > 1) ``write_kv_pages``; a decode
+        step's (T == 1) ``paged_attention`` itself, handed the new rows:
+        on the chip the kernel puts each row it keeps into its own tail
+        page and a row it does not keep writes NOTHING; the XLA form
+        scatters every row first, a dead one into the scratch page its
+        table names, which nothing reads.
         """
         cfg = self.cfg
         if self.is_initializing():
@@ -546,14 +552,15 @@ class Attention(nn.Module):
         from ray_tpu.ops.paged_attention import (gather_kv_pages,
                                                  paged_attention,
                                                  write_kv_pages)
-        pool = write_kv_pages(pool, jnp.concatenate([k, v], axis=-1),
-                              block_tables, positions, layer=layer)
+        kv = jnp.concatenate([k, v], axis=-1)
         if q.shape[1] == 1:
-            out = paged_attention(
+            out, pool = paged_attention(
                 q[:, 0], pool, block_tables, positions[:, 0] + 1,
-                layer=layer, live=live, window=self._window_over(
+                new_rows=kv[:, 0], layer=layer, live=live,
+                window=self._window_over(
                     window, block_tables.shape[1] * pool.shape[3]))
             return out[:, None], pool
+        pool = write_kv_pages(pool, kv, block_tables, positions, layer=layer)
         if not self.prefix_attend:
             window = self._window_over(window, q.shape[1])
             if window is not None:
@@ -590,21 +597,28 @@ def _widen(a, width: int):
 
 
 def absorbed_attention(cfg: TransformerConfig, q, wkv_b, pool, block_tables,
-                       lengths, *, layer=0, live=None):
+                       lengths, *, layer=0, live=None, new_rows=None):
     """One query a row over the latent rows its pages hold, ABSORBED
     (``LatentAttention``): ``q [B, heads, dn + dr]`` rotated already,
     ``wkv_b [r, heads, dn + dv]``, ``pool`` the stacked latent pool ->
     ``[B, heads, dv]``.  ``q_lat = q_nope Wuk^T``; the paged kernel takes
     ``[q_lat | q_rope | 0]`` against whole rows and returns ``sum_j p_j
-    c_j``; ``Wuv`` maps that to the head's value."""
+    c_j``; ``Wuv`` maps that to the head's value.  With ``new_rows [B,
+    r + dr]`` (the step's latent rows, which ``lengths`` counts) they
+    are written first, ``[row | 0]``, and the result is ``(out, pool)``
+    (``paged_attention``)."""
     from ray_tpu.ops.paged_attention import paged_attention
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkv_b[..., :dn])
-    o_lat = paged_attention(
+    got = paged_attention(
         _widen(jnp.concatenate([q_lat, q[..., dn:]], -1), pool.shape[-1]),
         pool, block_tables, lengths, layer=layer, live=live,
-        sm_scale=cfg.head_dim ** -0.5, v_width=r)
-    return jnp.einsum("bhr,rhd->bhd", o_lat, wkv_b[..., dn:])
+        sm_scale=cfg.head_dim ** -0.5, v_width=r,
+        new_rows=(None if new_rows is None
+                  else _widen(new_rows, pool.shape[-1])[:, None]))
+    if new_rows is None:
+        return jnp.einsum("bhr,rhd->bhd", got, wkv_b[..., dn:])
+    return jnp.einsum("bhr,rhd->bhd", got[0], wkv_b[..., dn:]), got[1]
 
 
 class LatentAttention(nn.Module):
@@ -739,9 +753,8 @@ class LatentAttention(nn.Module):
             q, k, v, causal=False,
             mask=window_mask(positions, jnp.arange(cfg.max_seq_len)))
 
-    def _write_rows(self, row, positions, block_tables, pool, layer):
-        """``pool`` with the latent rows ``row [B, T, r + dr]`` written,
-        ``[row | 0]``, to the rows' pages of layer ``layer``."""
+    def _check_paged(self, positions, block_tables):
+        """What either writer of latent rows needs of a paged call."""
         if positions is None or block_tables is None:
             raise ValueError("paged decode requires positions and "
                              "block_tables")
@@ -750,6 +763,11 @@ class LatentAttention(nn.Module):
                 "suffix prefill over a latent pool: LatentAttention has "
                 "no path that gathers a cached prefix's latent rows and "
                 "expands them (kv_b) beside the window's own")
+
+    def _write_rows(self, row, positions, block_tables, pool, layer):
+        """``pool`` with the latent rows ``row [B, T, r + dr]`` written,
+        ``[row | 0]``, to the rows' pages of layer ``layer``."""
+        self._check_paged(positions, block_tables)
         from ray_tpu.ops.paged_attention import write_kv_pages
         return write_kv_pages(
             pool, _widen(row, pool.shape[-1])[:, :, None], block_tables,
@@ -757,22 +775,26 @@ class LatentAttention(nn.Module):
 
     def _decode_attend_paged(self, q, c, k_rope, wkv_b, positions,
                              block_tables, pool, layer, live, lengths=None):
-        """``Attention._decode_attend_paged`` for latent rows: write this
-        call's rows ``[c | k_rope | 0]`` into the rows' pages of layer
-        ``layer``; a prompt (``T > 1``) then attends EXPANDED over its own
-        keys (no pool read), a decode step ABSORBED over the occupied
-        pages.  Returns ``(out [B, T, heads, dv], pool)``."""
+        """``Attention._decode_attend_paged`` for latent rows: this
+        call's rows ``[c | k_rope | 0]`` go into the rows' pages of layer
+        ``layer``; a prompt (``T > 1``) is written by ``_write_rows`` and
+        attends EXPANDED over its own keys (no pool read), a decode step
+        ABSORBED over the occupied pages, its row written by the paged
+        call itself (a row ``live`` leaves out: as in ``Attention``).
+        Returns ``(out [B, T, heads, dv], pool)``."""
         cfg = self.cfg
         if self.is_initializing():
             return xla_attention(q, *self._expand(c, k_rope, wkv_b),
                                  causal=True), pool
-        pool = self._write_rows(jnp.concatenate([c, k_rope], -1), positions,
-                                block_tables, pool, layer)
+        row = jnp.concatenate([c, k_rope], -1)
         if q.shape[1] > 1:
+            pool = self._write_rows(row, positions, block_tables, pool, layer)
             return _prefill_attend(q, *self._expand(c, k_rope, wkv_b),
                                    lengths=lengths), pool
-        out = absorbed_attention(cfg, q[:, 0], wkv_b, pool, block_tables,
-                                 positions[:, 0] + 1, layer=layer, live=live)
+        self._check_paged(positions, block_tables)
+        out, pool = absorbed_attention(
+            cfg, q[:, 0], wkv_b, pool, block_tables, positions[:, 0] + 1,
+            layer=layer, live=live, new_rows=row[:, 0])
         return out[:, None], pool
 
 

@@ -43,15 +43,29 @@ one was kept on).
 
 ADDRESSING.  The pool is addressed, never sliced: a reader names
 ``[layer, page]`` (the kernel's DMA source, the oracle's gather index),
-the writer ``[layer, page, :, offset]`` (``write_kv_pages`` below,
-called by models/gpt.py ``_decode_attend_paged``), on the whole stacked
-array.  ``pool[layer]`` as a value is a copy of one layer's pool (108 MB
-at SmolLM2-360M's default pool) — under the layer scan one such copy,
-its relayout and its write-back a layer, which was 85% of the serving
-cell's device time (PERF.md, PR 25).  So the pool rides the model's
-layer scan and the engine's step scan as loop-carried, donated state,
-and everything here takes it whole plus a ``layer`` index (an int, or a
+a writer ``[layer, page, :, offset]``, on the whole stacked array.
+``pool[layer]`` as a value is a copy of one layer's pool (108 MB at
+SmolLM2-360M's default pool) — under the layer scan one such copy, its
+relayout and its write-back a layer, which was 85% of the serving cell's
+device time (PERF.md, PR 25).  So the pool rides the model's layer scan
+and the engine's step scan as loop-carried, donated state, and
+everything here takes it whole plus a ``layer`` index (an int, or a
 traced scalar under the layer scan).
+
+WHO WRITES (models/gpt.py ``_decode_attend_paged`` is the call site of
+both).  A prompt's rows (``T > 1``): ``write_kv_pages`` below, a loop of
+``dynamic_update_slice``.  A decode step's one row (``T == 1``):
+``paged_attention`` itself, handed ``new_rows``.  On the chip the Pallas
+kernel puts each row it keeps into the row's own tail page, which it has
+in VMEM with the row's last chunk anyway, and sends the tile-aligned
+group that holds it back to the pool, the pool aliased through the call;
+a row that holds no request writes NOTHING.  Off the chip ``new_rows``
+go through ``write_kv_pages``' ``T == 1`` form (one XLA row scatter,
+every row of the batch, a dead row into the scratch page its table
+names, which nothing reads) in front of the gather; that pair is also
+the kernel's oracle.  The scatter cost 13-14 us a layer at 33 rows x 4-5
+KV heads and 158 us at 65 x 30, the kernel's write 0.03-0.35 us a live
+row (PERF.md, PR 45).
 
 Two implementations:
 
@@ -65,7 +79,9 @@ Two implementations:
     bf16 pages straight to the MXU, flash-style online softmax in
     float32.  A row that holds no request issues nothing.  HBM traffic
     and time per decode step scale with the context the live rows
-    actually hold — the property the dense row layout can't have.
+    actually hold — the property the dense row layout can't have.  With
+    ``new_rows`` the same kernel also writes them (above); without, the
+    pool is only read.
 
 ``paged_attention`` dispatches by backend.  Both take ``live`` [rows]
 (optional): rows it leaves out are not read and return zeros.
@@ -102,10 +118,11 @@ def write_kv_pages(pool: jax.Array, kv: jax.Array,
     out to that layout and back, a step.  Times on a v5e, 32 layers of
     SmolLM2-360M's pool (PERF.md, PR 25):
 
-      - T == 1 (decode): ONE row scatter on the pool viewed as
-        ``[layers*pages*kv_heads*page_size, 2*head_dim]`` (a bitcast):
-        0.46 ms a step for 33 rows, against 3.2 ms for a
-        dynamic_update_slice a row.
+      - T == 1 (a decode step where the kernel does not run; on the
+        chip the kernel writes: module docstring): ONE row scatter on
+        the pool viewed as ``[layers*pages*kv_heads*page_size,
+        2*head_dim]`` (a bitcast): 0.46 ms a step for 33 rows, against
+        3.2 ms for a dynamic_update_slice a row.
       - T > 1 (prefill): a loop of one dynamic_update_slice per chunk of
         ``gcd(T, page_size)`` positions, ``[kv_heads, chunk,
         2*head_dim]`` each: 8.1 ms for 4 x 2048 tokens, against 95 ms
@@ -153,7 +170,8 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
                         block_tables: jax.Array, lengths: jax.Array, *,
                         layer=0, window=None, live=None,
                         sm_scale: Optional[float] = None,
-                        v_width: Optional[int] = None) -> jax.Array:
+                        v_width: Optional[int] = None,
+                        new_rows: Optional[jax.Array] = None):
     """Gather-based paged decode attention (one query token per row).
 
     q:            [rows, heads, head_dim]  (latent: [rows, heads, row])
@@ -168,8 +186,18 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
     v_width:      None: the row is ``[k | v]`` halves.  An int: a latent
                   row (module docstring): keys the whole row, values its
                   first ``v_width`` columns
+    new_rows:     None, or [rows, kv_heads, row]: the token ``lengths``
+                  counts last, scattered into the pool first
+                  (``write_kv_pages``, every row); the result is then
+                  ``(out, pool)``
     returns       [rows, heads, head_dim]  (latent: [rows, heads, v_width])
     """
+    if new_rows is not None:
+        kv_pages = write_kv_pages(kv_pages, new_rows[:, None], block_tables,
+                                  lengths[:, None] - 1, layer=layer)
+        return paged_attention_xla(
+            q, kv_pages, block_tables, lengths, layer=layer, window=window,
+            live=live, sm_scale=sm_scale, v_width=v_width), kv_pages
     hd = q.shape[-1]
     kv = gather_kv_pages(kv_pages, block_tables, layer=layer)
     pos = jnp.arange(kv.shape[1])[None, :]
@@ -205,7 +233,8 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                 layer: jax.Array, sm_scale: float,
                 window: Optional[jax.Array] = None,
                 live: Optional[jax.Array] = None,
-                v_width: Optional[int] = None) -> jax.Array:
+                v_width: Optional[int] = None,
+                new_rows: Optional[jax.Array] = None):
     """Pallas TPU decode kernel: ONE invocation walks the flat list of
     (live row, chunk of occupied pages) work with a page pipeline that
     never drains between rows.
@@ -250,6 +279,23 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
     against its first ``v_width`` columns (whole lane tiles), the output
     ``[rows, heads, v_width]``: ``kvh`` is 1 and all ``heads`` queries
     of a row ride one product.
+
+    ``new_rows`` [rows, kv_heads, row] (the pool's dtype, whole in
+    VMEM): the kernel also WRITES the step's token, and returns ``(out,
+    pool)``, the pool aliased to its second output.  ``lengths`` counts
+    the new token, so it belongs at offset ``(length - 1) % page_size``
+    of the row's last page, which arrives in ``kvbuf`` with the row's
+    last chunk.  When that chunk has landed the row is put into the
+    buffered page (a select over the tile-aligned group of ``grp``
+    positions that holds the offset: no single-row store), the group
+    starts back to ``pool[layer, page]``, and the chunk's two products
+    run on the patched buffer while it flies: the new token's score and
+    value come out of them like every other position's, the row exactly
+    as the pool's dtype holds it.  The write-back is waited for when
+    the row's products are done, before the next fetch into its slot.  A
+    row's tail page is its own (the engine lends whole read-only pages
+    only), so no other row reads or writes it inside a call; a row the
+    kernel does not keep writes nothing.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -262,7 +308,12 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
     chunk_tokens = _CHUNK_TOKENS if v_width is None else _LATENT_CHUNK_TOKENS
     cp = max(1, min(chunk_tokens // ps, _CHUNK_BYTES // page_bytes))
     windowed, masked = window is not None, live is not None
+    writes = new_rows is not None
     n_prefetch = 3 + windowed + masked
+    # positions of a page that one write-back moves: the dtype's sublane
+    # tile (bf16 packs 16 positions a tile), or the whole page
+    tile = 32 // kv_pages.dtype.itemsize
+    grp = tile if ps % tile == 0 else ps
     # the page's K and V parts as the two products see them
     if v_width is not None:
         k_cols, v_cols = slice(None), slice(0, v_width)
@@ -276,8 +327,13 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
         tables_ref, len_ref, layer_ref = refs[:3]
         window_ref = refs[3] if windowed else None
         live_ref = refs[3 + windowed] if masked else None
+        rest = list(refs[n_prefetch:])
+        # with new rows: their operand, the pool as an output and the
+        # write-back's semaphore besides
+        new_ref, pool_ref, wsem = ((rest.pop(2), rest.pop(3), rest.pop())
+                                   if writes else (None, None, None))
         (q_ref, kv_ref, out_ref, kvbuf, row_out, ids, firsts, ends,
-         sems) = refs[n_prefetch:]
+         sems) = rest
 
         # branch-free and unrolled (0.3-0.7 us a call less than a loop
         # of conditional stores): every row is written at the running
@@ -328,6 +384,14 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
         for slot in range(depth - 1):
             cursor = fetch(cursor, slot)
 
+        def tail_dma(slot, k, at, page):
+            """The write-back of the group at ``at`` of page ``k`` of
+            ``slot`` to ``pool[layer, page]``."""
+            return pltpu.make_async_copy(
+                kvbuf.at[slot, k, :, pl.ds(at, grp)],
+                pool_ref.at[layer_ref[0], page, :, pl.ds(at, grp)],
+                wsem.at[0])
+
         def row(j, carry):
             step, cursor = carry
             r, first, end = ids[j], firsts[j], ends[j]
@@ -341,12 +405,37 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                            jnp.zeros((g, 1), jnp.float32),
                            jnp.zeros((g, vw), jnp.float32))
                           for _ in range(kvh))
+            n_chunks = pl.cdiv(end - first, cp)
+            if writes:
+                # where the new token goes: page ``tail_k`` of the last
+                # chunk, the group at ``tail_at``, position ``off`` of it
+                tail_k = end - 1 - (first + (n_chunks - 1) * cp)
+                off = (length - 1) % ps
+                tail_at = (0 if grp == ps
+                           else pl.multiple_of(off // grp * grp, grp))
+                off = off - tail_at
+                tail_page = tables_ref[r, end - 1]
 
             def chunk(t, carry):
                 step, cursor, stats = carry
                 cursor = fetch(cursor, (step + depth - 1) % depth)
                 slot, at = step % depth, first + t * cp
                 chunk_dmas(slot, at, end)
+                if writes:
+                    @pl.when(t == n_chunks - 1)
+                    def _():
+                        # float32 and back is exact, and slices a packed
+                        # dtype nowhere but on whole tiles
+                        rows_new = new_ref[r].astype(jnp.float32)
+                        hit = jax.lax.broadcasted_iota(
+                            jnp.int32, (grp, hd2), 0) == off
+                        for h in range(kvh):
+                            at_h = (slot, tail_k, h, pl.ds(tail_at, grp))
+                            kvbuf[at_h] = jnp.where(
+                                hit, rows_new[h:h + 1],
+                                kvbuf[at_h].astype(jnp.float32)
+                            ).astype(kvbuf.dtype)
+                        tail_dma(slot, tail_k, tail_at, tail_page).start()
                 pos = at * ps + jax.lax.broadcasted_iota(
                     jnp.int32, (g, cp * ps), 1)
                 valid = pos < length
@@ -375,7 +464,10 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                 return step + 1, cursor, tuple(new)
 
             step, cursor, stats = jax.lax.fori_loop(
-                0, pl.cdiv(end - first, cp), chunk, (step, cursor, stats))
+                0, n_chunks, chunk, (step, cursor, stats))
+            if writes:
+                tail_dma((step - 1) % depth, tail_k, tail_at,
+                         tail_page).wait()
             for h, (_, l, acc) in enumerate(stats):
                 row_out[h * g:(h + 1) * g] = acc / jnp.maximum(l, 1e-30)
             out_ref[r] = row_out[...].astype(out_ref.dtype)
@@ -383,6 +475,8 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
 
         jax.lax.fori_loop(0, n, row, (jnp.int32(0), cursor))
 
+    out_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out_shape = jax.ShapeDtypeStruct((rows, heads, vw), q.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # block_tables, lengths, layer (, window) (, live)
         num_scalar_prefetch=n_prefetch,
@@ -390,8 +484,9 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),        # q, whole
             pl.BlockSpec(memory_space=pl.ANY),   # stacked kv_pages (HBM)
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        ] + [pl.BlockSpec(memory_space=pltpu.VMEM)] * writes,  # new rows
+        out_specs=([out_spec, pl.BlockSpec(memory_space=pl.ANY)] if writes
+                   else out_spec),
         scratch_shapes=[
             pltpu.VMEM((depth, cp, kvh, ps, hd2), kv_pages.dtype),
             pltpu.VMEM((heads, vw), jnp.float32),   # one row's output
@@ -399,21 +494,27 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
             pltpu.SMEM((rows,), jnp.int32),         # their first page
             pltpu.SMEM((rows,), jnp.int32),         # the page they end at
             pltpu.SemaphoreType.DMA((depth,)),
-        ],
+        ] + [pltpu.SemaphoreType.DMA((1,))] * writes,   # the write-back
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, heads, vw), q.dtype),
+        out_shape=([out_shape, jax.ShapeDtypeStruct(
+            kv_pages.shape, kv_pages.dtype)] if writes else out_shape),
+        # the pool (the operand after the scalars and q) IS the second
+        # output: in place when it is loop-carried and donated
+        input_output_aliases={n_prefetch + 1: 1} if writes else {},
         name="paged_attention_decode",
     )(block_tables, lengths, layer, *([window] if windowed else []),
-      *([live] if masked else []), q, kv_pages)
+      *([live] if masked else []), q, kv_pages,
+      *([new_rows] if writes else []))
 
 
 def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
                         window=None, live=None,
                         sm_scale: Optional[float] = None,
-                        v_width: Optional[int] = None) -> jax.Array:
+                        v_width: Optional[int] = None,
+                        new_rows: Optional[jax.Array] = None):
     hd = q.shape[-1]
     scale = sm_scale if sm_scale is not None else hd ** -0.5
     # a half of the page is whole lane tiles, or the query is padded
@@ -426,10 +527,15 @@ def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
         window = jnp.asarray(window, jnp.int32).reshape(1)
     if live is not None:
         live = live.astype(jnp.int32)
-    out = _tpu_kernel(q, kv_pages, block_tables, lengths.astype(jnp.int32),
+    if new_rows is not None:
+        new_rows = new_rows.astype(kv_pages.dtype)
+    got = _tpu_kernel(q, kv_pages, block_tables, lengths.astype(jnp.int32),
                       jnp.asarray(layer, jnp.int32).reshape(1), scale,
-                      window, live, v_width)
-    return out if split else out[..., hd:]
+                      window, live, v_width, new_rows)
+    if new_rows is None:
+        return got if split else got[..., hd:]
+    out, pool = got
+    return (out if split else out[..., hd:]), pool
 
 
 def resolve_paged_impl(kv_minor: int, impl: str = "auto",
@@ -469,7 +575,8 @@ def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
                     window=None, live=None,
                     sm_scale: Optional[float] = None,
                     v_width: Optional[int] = None,
-                    impl: str = "auto") -> jax.Array:
+                    new_rows: Optional[jax.Array] = None,
+                    impl: str = "auto"):
     """Backend-dispatched paged decode attention over layer ``layer`` of
     the stacked pool (see module docstring and
     :func:`resolve_paged_impl`); under a ``window`` (scalar, traced or
@@ -481,8 +588,17 @@ def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
     (page 0 is scratch; models/gpt.py ``Block`` derives the mask once
     for this kernel and the expert kernel).  ``None``: every row is
     read.  ``v_width``: the pool holds latent rows (module docstring);
-    the kernel then wants ``v_width`` in whole lane tiles too."""
+    the kernel then wants ``v_width`` in whole lane tiles too.
+
+    ``new_rows`` [rows, kv_heads, row] (None: the pool is only read, and
+    the result is the output alone): the step's token of every row,
+    which ``lengths`` already counts.  It is written to layer ``layer``
+    at position ``lengths - 1`` before anything is read, and the result
+    is ``(out, pool)``: by the kernel itself, for the rows it keeps, or
+    by ``write_kv_pages`` in front of the gather (module docstring, WHO
+    WRITES)."""
     impl = resolve_paged_impl(kv_pages.shape[-1], impl, v_width)
     fn = paged_attention_tpu if impl == "tpu" else paged_attention_xla
     return fn(q, kv_pages, block_tables, lengths, layer=layer,
-              window=window, live=live, sm_scale=sm_scale, v_width=v_width)
+              window=window, live=live, sm_scale=sm_scale, v_width=v_width,
+              new_rows=new_rows)

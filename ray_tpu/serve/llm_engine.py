@@ -24,7 +24,8 @@ iteration's prefills):
     engine reads the shape off the leaf and is otherwise indifferent.  It rides the model's layer
     scan and the block's step scan as loop-carried state; a layer
     writes its rows at ``[layer, page, :, offset]`` and the kernel reads
-    ``[layer, page]`` (``write_kv_pages`` / the DMA source).  Nobody
+    ``[layer, page]`` (``write_kv_pages`` for a prompt, the decode
+    kernel itself for a step's row / the DMA source).  Nobody
     slices a layer out of it: a decode step or a prefill wave moves the
     rows it writes and the pages it reads, whatever ``kv_pool_pages``
     is (tests/test_chip_compile.py holds that in the compiled
@@ -402,6 +403,13 @@ class EngineStats:
         self.decode_pages_read = 0
         self.window_pages_read = 0
         self.window_pages_skipped = 0
+        # rows the decode kernel wrote into the pool (the step's token,
+        # into the row's tail page) over delivered tokens, every pool
+        # layer; a pool layer step is one pool layer in one decode step.
+        # Their ratio is the rows a layer step writes: the rows that
+        # hold a request, not the batch.  Host arithmetic like the pages
+        self.decode_rows_written = 0
+        self.pool_layer_steps = 0
         # recurrent layers of either class (gated delta, Mamba-2; the
         # names are the first class's): a layer step is one such layer
         # in one decode step; gdn_state_rows sums over them the rows
@@ -483,6 +491,8 @@ class EngineStats:
             "decode_pages_read": self.decode_pages_read,
             "window_pages_read": self.window_pages_read,
             "window_pages_skipped": self.window_pages_skipped,
+            "decode_rows_written": self.decode_rows_written,
+            "pool_layer_steps": self.pool_layer_steps,
             "gdn_layer_steps": self.gdn_layer_steps,
             "gdn_state_rows": self.gdn_state_rows,
             "mla_layer_steps": self.mla_layer_steps,
@@ -940,6 +950,7 @@ class LLMEngine:
         # Block derives the same mask from the tables and its decode
         # kernels read nothing for such a row (PERF.md, PR 27, PR 32).
         # It keeps stepping junk, but AT POSITION 0, so its K/V write
+        # (the XLA form's: the decode kernel writes nothing for it)
         # stays on the scratch page's first row and a reader without
         # the mask reads one page of it, not ceil((position+1) /
         # page_size): 28 idle rows left to walk to max_seq_len did twice
@@ -1519,6 +1530,7 @@ class LLMEngine:
             st = self.stats
             st.steps += self.block_size
             st.quanta += 1
+            st.pool_layer_steps += self.block_size * self._pool_layers
             st.gdn_layer_steps += self.block_size * self._state_layers
             st.mla_layer_steps += self.block_size * self._latent_layers
             tokens0, done0 = st.step_tokens, st.requests_completed
@@ -1545,6 +1557,7 @@ class LLMEngine:
                     # stall between its tokens
                     sl.stall_base = self._stall_s
                 self._count_decode_pages(pos0 + 1, sl.pos)
+                st.decode_rows_written += (sl.pos - pos0) * self._pool_layers
                 st.gdn_state_rows += (sl.pos - pos0) * self._state_layers
                 # steps at positions pos0 .. pos - 1 read pos0 + 1 .. pos
                 st.mla_context_tokens += self._latent_layers * (

@@ -159,6 +159,61 @@ def test_paged_decode_gpt_small_widths(topo, one_chip, shape):
     lowered.compile()
 
 
+# Kanana-2's decode shape (test_paged_decode_on_latent_pages): 32 query
+# heads on one KV head, rows of 640 whose values are the first 512
+_LATENT_SHAPE = (33, 32, 1, None, 160, False, True)
+
+
+@pytest.mark.parametrize("shape", list(_PAGED_SHAPES) + ["latent"])
+def test_paged_decode_writes_the_pool_it_is_given(topo, one_chip, shape):
+    """Handed the step's rows (ISSUE 45) the call is still ONE custom
+    call ``paged_attention_decode``; the pool, a donated argument, goes
+    into it as it came and IS its second result: aliased, with no copy
+    and no scatter of the pool's shape beside it."""
+    import re
+
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    rows, heads, kvh, hd, mp, windowed, masked = (
+        _LATENT_SHAPE if shape == "latent" else _PAGED_SHAPES[shape])
+    ps, pages, layers = 64, 129, 3
+    row, v_width = (2 * hd, None) if hd else (640, 512)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(
+        lambda q, kv, bt, ln, layer, window, live, new: paged_attention(
+            q, kv, bt, ln, layer=layer, impl="tpu", new_rows=new,
+            v_width=v_width, window=window if windowed else None,
+            live=live if masked else None), donate_argnums=(1,)
+    ).lower(sds((rows, heads, hd or row), jnp.bfloat16),
+            sds((layers, pages, kvh, ps, row), jnp.bfloat16),
+            sds((rows, mp), jnp.int32), sds((rows,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32),
+            sds((rows,), jnp.bool_), sds((rows, kvh, row), jnp.bfloat16))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
+    hlo = lowered.compile().as_text()
+    (call,) = [line for line in hlo.splitlines()
+               if re.match(r"\s*(ROOT )?%?paged_attention_decode\S* = ", line)]
+    pool = f"bf16[{layers},{pages},{kvh},{ps},{row}]"
+    operands = re.search(r"custom-call\((.*?)\), custom_call_target",
+                         call).group(1)
+    operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
+    (out, at), = re.findall(
+        r"output_to_operand_aliasing=\{\{(\d+)\}: \((\d+), \{\}\)\}", call)
+    assert out == "1"
+    # the aliased operand is the program's own (donated) argument
+    assert any(re.match(
+        rf"\s*{re.escape(operands[int(at)])} = {re.escape(pool)}\S* "
+        r"parameter\(1\)", line) for line in hlo.splitlines())
+    assert "input_output_alias={ {1}: (1, {}, may-alias) }" in hlo
+    made = _pool_result_producers(hlo, (layers * pages * kvh * ps * row,))
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
+
+
 def _train_step_programs(devices, sharding, *, batch, overrides):
     """grad_fn/apply_fn of the gang loop's own step (executor.build_step)
     for gpt-small cut to 2 layers, plus its state and batch shapes."""
@@ -377,7 +432,12 @@ def test_engine_programs_address_the_pool_in_place(topo, one_chip, on_tpu,
     in-place updates produces a pool-sized result, and a pool four times
     as large changes neither temporaries nor bytes accessed.  (The tree
     before ISSUE 25: decode block temporaries 17x one layer's pool,
-    ``copy`` and ``dynamic-slice`` fusions of the pool in both.)"""
+    ``copy`` and ``dynamic-slice`` fusions of the pool in both.)  The
+    prefill's update is XLA's (``write_kv_pages``); the decode block
+    holds NO scatter or update of the pool's shape (ISSUE 45): its one
+    writer is ``paged_attention_decode``, the pool aliased through it."""
+    import re
+
     pages = 2048
     layer_elems = pages * 5 * 64 * 128
     small = _compiled_engine_programs(pages, one_chip, monkeypatch)
@@ -391,7 +451,12 @@ def test_engine_programs_address_the_pool_in_place(topo, one_chip, on_tpu,
                                       (layer_elems, 4 * layer_elems))
         assert set(made) <= _IN_PLACE, (
             f"{name}: pool-sized results from {dict(made)}")
-        assert any("scatter" in op or "update-slice" in op for op in made)
+        updated = any("scatter" in op or "update-slice" in op for op in made)
+        assert updated == (name == "engine_prefill"), dict(made)
+        if not updated:
+            (call,) = [line for line in compiled.as_text().splitlines()
+                       if re.match(r"\s*%?paged_attention_decode\S* = ", line)]
+            assert "output_to_operand_aliasing={{1}: (" in call
         temp4 = large[name].memory_analysis().temp_size_in_bytes
         assert abs(temp4 - temp) < 0.1 * temp, (
             f"{name}: temporaries {temp / 1e6:.1f} MB at {pages} pages, "

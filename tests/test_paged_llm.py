@@ -979,6 +979,109 @@ def test_pallas_decode_kernel_never_loads_what_it_must_not_read(
                  live), clean)
 
 
+# ---- the kernel writes the step's row itself (ISSUE 45) ----
+
+_WPS = 32         # two bf16 sublane tiles: the write-back is HALF a page
+
+# name: (head_dim, or None for a latent row 640 / 512), each row's
+# length WITH the new token, window, live.  Chunks are two pages.
+_WRITE_CASES = {
+    "halves-split": (128, [37, 70, 5], None, None),
+    "padded-query": (64, [37, 70, 5], None, None),
+    "latent-row": (None, [37, 70, 5], None, None),
+    "window": (128, [37, 150, 100], 40, None),
+    # row 1 is dead on its table's scratch page, row 3 dead with pages
+    # of its own, which the kernel must leave as they were
+    "dead-rows": (64, [37, 70, 5, 90], None, [1, 0, 1, 0]),
+    "offset-0": (128, [33, 65, 129], None, None),       # a fresh page
+    "offset-last": (128, [32, 64, 96], None, None),
+    "odd-offset": (64, [36, 70, 8], None, None),  # packed second halves
+    "one-position": (128, [1, 1, 1], None, None),
+    # 4, 2 and 6 pages: the tail page is the SECOND of its chunk
+    "tail-not-first-in-chunk": (128, [100, 60, 168], None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_WRITE_CASES))
+def test_pallas_decode_kernel_writes_the_new_row_in_tpu_interpreter(
+        tpu_interpreter, monkeypatch, case):
+    """The kernel handed the step's rows against its oracle,
+    ``write_kv_pages`` then ``paged_attention_xla``: the output within
+    the kernel's tolerance; the returned pool BIT for bit the oracle's
+    on every kept row's tail page and, everywhere else (other layers,
+    other pages, a dead row's pages; scratch page 0 left out), the pool
+    that went in.  Every page holds finite junk, a fresh one too."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_CHUNK_TOKENS", 2 * _WPS)
+    monkeypatch.setattr(pa, "_LATENT_CHUNK_TOKENS", 2 * _WPS)
+    hd, lengths, window, live = _WRITE_CASES[case]
+    rows, mp, layer = len(lengths), 6, 1
+    kvh, heads, row, v_width = ((2, 4, 2 * hd, None) if hd
+                                else (1, 8, 640, 512))
+    rs = np.random.RandomState(len(case))
+    pool = jnp.asarray(rs.randn(3, 1 + rows * mp, kvh, _WPS, row),
+                       jnp.bfloat16)
+    q = jnp.asarray(rs.randn(rows, heads, hd or row), jnp.bfloat16)
+    new = jnp.asarray(rs.randn(rows, kvh, row), jnp.bfloat16)
+    tables = (rs.permutation(rows * mp).reshape(rows, mp) + 1).astype(
+        "int32")
+    if live is not None:
+        live = np.asarray(live, bool)
+        tables[1] = 0 if not live[1] else tables[1]
+    lengths = jnp.asarray(lengths, jnp.int32)
+    kw = dict(layer=layer, window=window, v_width=v_width,
+              live=None if live is None else jnp.asarray(live))
+    got, got_pool = pa.paged_attention_tpu(
+        q, pool, jnp.asarray(tables), lengths, new_rows=new, **kw)
+    want_pool = pa.write_kv_pages(pool, new[:, None], jnp.asarray(tables),
+                                  lengths[:, None] - 1, layer=layer)
+    want = pa.paged_attention_xla(q, want_pool, jnp.asarray(tables),
+                                  lengths, **kw)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+    def bits(x):
+        return np.array(jax.lax.bitcast_convert_type(x, jnp.uint16))
+
+    got_bits, want_bits, expect = bits(got_pool), bits(want_pool), bits(pool)
+    kept = np.flatnonzero(np.ones(rows, bool) if live is None else live)
+    for r in kept:
+        page = tables[r, (int(lengths[r]) - 1) // _WPS]
+        assert (want_bits[layer, page] != expect[layer, page]).any()
+        expect[layer, page] = want_bits[layer, page]
+    np.testing.assert_array_equal(got_bits[:, 1:], expect[:, 1:])
+
+
+def test_paged_attention_with_new_rows_off_the_chip_is_write_then_read():
+    """``paged_attention(new_rows=)`` where the kernel does not run (the
+    CPU, test-size heads): ``write_kv_pages`` in front of the gather,
+    ``(out, pool)``; without new rows the result is the output alone."""
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops import paged_attention as pa
+
+    rs = np.random.RandomState(3)
+    pool = _random_pool(rs, 2, 7, 2, 4, 8)
+    q = jnp.asarray(rs.randn(2, 4, 8), jnp.float32)
+    new = jnp.asarray(rs.randn(2, 2, 16), jnp.float32)
+    tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+    lengths = jnp.asarray([5, 12], jnp.int32)
+    got, got_pool = pa.paged_attention(q, pool, tables, lengths,
+                                       new_rows=new, layer=1)
+    want_pool = pa.write_kv_pages(pool, new[:, None], tables,
+                                  lengths[:, None] - 1, layer=1)
+    np.testing.assert_array_equal(np.asarray(got_pool),
+                                  np.asarray(want_pool))
+    assert (np.asarray(got_pool) != np.asarray(pool)).any()
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(pa.paged_attention(
+            q, want_pool, tables, lengths, layer=1)))
+
+
 @pytest.mark.parametrize("window", [1, 8, 16, 48],
                          ids=["decode", "part-page", "page", "3-pages"])
 @pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
@@ -1184,3 +1287,15 @@ def test_decode_pages_read_counts_what_the_kernel_reads(preset):
     assert plain > 0 and bool(windowed) == bool(window_layers)
     assert st["decode_pages_read"] == plain + windowed
     assert st["window_pages_read"] == windowed
+    # the rows the decode kernel wrote: one a delivered step a pool layer,
+    # and chipbench's reader gives them over the pool layer steps
+    from chipbench.metrics import kv_rows_written_mean
+    steps = sum(new - 1 for _, new in requests)
+    assert st["decode_rows_written"] == steps * cfg.n_layers
+    assert st["pool_layer_steps"] == st["steps"] * cfg.n_layers
+    assert kv_rows_written_mean.read(
+        {"serve": {"stats0": {"decode_rows_written": 0,
+                              "pool_layer_steps": 0}, "stats1": st}}
+    ) == steps / st["steps"]
+    assert kv_rows_written_mean.read({"serve": {"stats0": {},
+                                                "stats1": {}}}) is None
