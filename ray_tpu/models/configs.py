@@ -92,8 +92,12 @@ class TransformerConfig:
     linear_value_head_dim: Optional[int] = None
     linear_conv_kernel: int = 4
     linear_allow_neg_eigval: bool = False
-    # RMSNorm over the whole q and k projections before the heads split
+    # RMSNorm of q and k before the rotation, in one of two forms: over
+    # the WHOLE projection, heads unsplit (one weight of heads * head_dim:
+    # OLMo 2/3), or with qk_norm_per_head over each head's head_dim alone
+    # (one weight of head_dim, shared by the heads: EXAONE 4.0, K-EXAONE)
     qk_norm: bool = False
+    qk_norm_per_head: bool = False
     # a full_attention block normalises each sub-layer's OUTPUT (x +
     # Norm(f(x)), the OLMo 2/3 block), not its input
     post_norm: bool = False
@@ -152,6 +156,14 @@ class TransformerConfig:
     mamba_chunk: int = 128
     moe_latent_size: Optional[int] = None
     moe_shared_d_ff: Optional[int] = None
+    # multi-token-prediction modules behind the stack (DeepSeek-V3's MTP;
+    # models/gpt.py MTPModule), 0 or 1: one more block (global attention
+    # without rotation, experts where the stack has them) that reads the
+    # stack's last hidden state at i beside the embedding of token i + 1
+    # and predicts token i + 2 through the model's own head.  It holds KV
+    # pages as a layer of the stack does (the pool's last layer), and the
+    # serving engine drafts with it (serve/llm_engine.py)
+    mtp_layers: int = 0
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -175,6 +187,10 @@ class TransformerConfig:
             assert self.moe_dropless and 0 <= self.moe_held_first \
                 <= self.moe_experts - self.moe_experts_held
         assert 0 <= self.first_dense_layers <= self.n_layers
+        assert self.qk_norm or not self.qk_norm_per_head
+        assert self.mtp_layers in (0, 1)
+        if self.mtp_layers:     # a block of the stack's own class
+            assert self.layer_types is None and not self.kv_lora_rank
         for layout in (self.rope_layout, self.window_layout):
             assert layout is None or len(layout) >= self.n_layers
         if self.rope_layout is not None:
@@ -260,8 +276,9 @@ class TransformerConfig:
                     + r * self.n_heads * (self.qk_nope_head_dim
                                           + self.v_head_dim)
                     + self.n_heads * self.v_head_dim * self.d_model)
-        qk = ((self.n_heads + self.n_kv_heads) * self.head_dim
-              if self.qk_norm else 0)
+        qk = (0 if not self.qk_norm else 2 * self.head_dim
+              if self.qk_norm_per_head
+              else (self.n_heads + self.n_kv_heads) * self.head_dim)
         return self.d_model * self.head_dim * (
             self.n_heads * 2 + self.n_kv_heads * 2) + qk
 
@@ -332,8 +349,12 @@ class TransformerConfig:
         mixers = (self.n_layers - linear) * self._attn_params() + (
             linear and linear * self._linear_attn_params())
         first = self.first_dense_layers
+        # the module: two input norms, their projection, a block, a norm
+        mtp = self.mtp_layers * (
+            2 * self.d_model * self.d_model + 3 * self.d_model
+            + self._attn_params() + mlp + norms)
         return (emb + mixers + first * dense + (self.n_layers - first) * mlp
-                + self.n_layers * norms + self.d_model)
+                + self.n_layers * norms + self.d_model + mtp)
 
     def train_flops_per_token(self, seq_len: int) -> float:
         """Operations a training step REQUIRES per token — the count
@@ -510,6 +531,33 @@ PRESETS = {
         moe_experts=16, moe_top_k=5, moe_d_ff=32, moe_act="relu2",
         moe_dropless=True, moe_scoring="sigmoid",
         moe_route_scale=5.0, moe_latent_size=32, moe_shared_d_ff=48),
+    # K-EXAONE-236B-A23B (LGAI-EXAONE, model_type exaone_moe) as
+    # published: 64 query / 8 KV heads of 128 with a per-head QK norm, a
+    # 4-layer period of three rotating layers with a window of 128 and
+    # one global layer without positions, layer 0 a dense SwiGLU of
+    # 18432, then 47 layers of 128 sigmoid-routed experts of 2048 (top-8
+    # by score + bias, renormalised, x 2.5) and one shared, and one
+    # multi-token-prediction module
+    "k-exaone-236b-a23b": TransformerConfig(
+        vocab_size=153600, d_model=6144, n_layers=48, n_heads=64,
+        n_kv_heads=8, head_dim=128, d_ff=18432, max_seq_len=262144,
+        rope_theta=1e6, norm_eps=1e-5, qk_norm=True, qk_norm_per_head=True,
+        sliding_window=128, rope_layout=(1, 1, 1, 0) * 12,
+        window_layout=(1, 1, 1, 0) * 12, moe_experts=128, moe_top_k=8,
+        moe_d_ff=2048, moe_dropless=True, moe_scoring="sigmoid",
+        moe_route_scale=2.5, moe_shared_experts=1, first_dense_layers=1,
+        mtp_layers=1),
+    # the same blocks at test size (tests/test_k_exaone.py): a window of
+    # 8, one dense layer then five of 8 experts, the module
+    "tiny-k-exaone": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=6, n_heads=8, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=256, dtype=jnp.float32,
+        remat=False, rope_theta=1e6, norm_eps=1e-5, qk_norm=True,
+        qk_norm_per_head=True, sliding_window=8,
+        rope_layout=(1, 1, 1, 0) * 2, window_layout=(1, 1, 1, 0) * 2,
+        moe_experts=8, moe_top_k=3, moe_d_ff=32, moe_dropless=True,
+        moe_scoring="sigmoid", moe_route_scale=2.5, moe_shared_experts=1,
+        first_dense_layers=1, mtp_layers=1),
 }
 
 

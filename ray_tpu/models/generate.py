@@ -35,6 +35,14 @@ def _trim_logits(logits: jax.Array, top_k: int, top_p: float) -> jax.Array:
     return logits
 
 
+def _tempered(logits: jax.Array, temps: jax.Array, top_k: int,
+              top_p: float) -> jax.Array:
+    """Each row's ``logits / temperature``, trimmed: what a sampled
+    row's token is drawn from (a greedy row's counts as 1 here)."""
+    safe_t = jnp.where(temps > 0, temps, 1.0)
+    return _trim_logits(logits / safe_t[:, None], top_k, top_p)
+
+
 def sample_logits(rng: jax.Array, logits: jax.Array, *,
                   temperature=1.0, top_k: int = 0,
                   top_p: float = 1.0) -> jax.Array:
@@ -51,10 +59,67 @@ def sample_logits(rng: jax.Array, logits: jax.Array, *,
             rng, _trim_logits(logits / temperature, top_k, top_p), axis=-1)
     temps = jnp.asarray(temperature)
     greedy = jnp.argmax(logits, axis=-1)
-    safe_t = jnp.where(temps > 0, temps, 1.0)
-    trimmed = _trim_logits(logits / safe_t[:, None], top_k, top_p)
-    sampled = jax.random.categorical(rng, trimmed, axis=-1)
+    sampled = jax.random.categorical(
+        rng, _tempered(logits, temps, top_k, top_p), axis=-1)
     return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+
+def sampling_probs(logits: jax.Array, temperature, *, top_k: int = 0,
+                   top_p: float = 1.0) -> jax.Array:
+    """float32 ``[B, V]``: the distribution ``sample_logits`` draws a
+    row's token from under a per-row ``temperature`` [B]: the softmax of
+    the trimmed ``logits / temperature``; one-hot at the argmax for a
+    greedy row (temperature 0)."""
+    temps = jnp.asarray(temperature)
+    logits = logits.astype(jnp.float32)
+    probs = jax.nn.softmax(_tempered(logits, temps, top_k, top_p), axis=-1)
+    greedy = jax.nn.one_hot(jnp.argmax(logits, axis=-1), logits.shape[-1],
+                            dtype=jnp.float32)
+    return jnp.where(temps[:, None] > 0, probs, greedy)
+
+
+def accept_draft(p1: jax.Array, q: jax.Array, draft: jax.Array,
+                 u: jax.Array):
+    """The acceptance rule of speculative sampling (Leviathan et al.,
+    arXiv:2211.17192) for ONE draft a row: ``draft`` [B] was drawn from
+    ``q`` [B, V]; the model's own distribution of that token is ``p1``;
+    ``u`` [B] is uniform on [0, 1).  Returns ``(accepted [B] bool,
+    residual [B, V])``: the draft stands with probability ``min(1,
+    p1(d) / q(d))`` (``u q(d) < p1(d)``: no division), and a rejected
+    row draws its token from ``residual`` normalised, ``max(p1 - q,
+    0)``.  Whatever ``q`` is, the token that comes out is distributed as
+    ``p1``.  All float32; one-hot ``p1`` and ``q`` (greedy rows) make it
+    "equal or not", the residual one-hot at ``p1``'s token."""
+    at = draft[:, None]
+    accepted = (u * jnp.take_along_axis(q, at, axis=1)[:, 0]
+                < jnp.take_along_axis(p1, at, axis=1)[:, 0])
+    return accepted, jnp.maximum(p1 - q, 0.0)
+
+
+def verify_draft(rng: jax.Array, logits1: jax.Array, logits2: jax.Array,
+                 q_logits: jax.Array, draft: jax.Array, *, temperature,
+                 top_k: int = 0, top_p: float = 1.0):
+    """One row-wise step of self-speculative decoding.  ``draft`` [B] is
+    the token proposed for the position after the row's last confirmed
+    one, drawn from ``q_logits`` [B, V] by ``sample_logits`` at the
+    row's ``temperature`` [B]; ``logits1`` / ``logits2`` are the model's
+    logits of that position and (given the draft) of the next.  Returns
+    ``(n [B] int32, first [B], second [B])``: the row emits ``n`` tokens,
+    ``first`` (the draft where ``accept_draft`` lets it stand, else a
+    draw from the residual) and, where the draft stood (``n`` 2),
+    ``second``, a draw from the model's distribution behind it."""
+    k_u, k_r, k_2 = jax.random.split(rng, 3)
+    p1, p2, q = (sampling_probs(lg, temperature, top_k=top_k, top_p=top_p)
+                 for lg in (logits1, logits2, q_logits))
+    accepted, residual = accept_draft(
+        p1, q, draft, jax.random.uniform(k_u, draft.shape))
+    # p1 == q leaves no residual, and no rejection either but by rounding
+    residual = jnp.where(residual.sum(-1, keepdims=True) > 0, residual, p1)
+    first = jnp.where(accepted, draft,
+                      jax.random.categorical(k_r, jnp.log(residual)))
+    second = jax.random.categorical(k_2, jnp.log(p2))
+    return (1 + accepted.astype(jnp.int32), first.astype(jnp.int32),
+            second.astype(jnp.int32))
 
 
 def init_decode_cache(model, batch_size: int):

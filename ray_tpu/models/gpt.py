@@ -278,6 +278,14 @@ class Attention(nn.Module):
     # prompt prefix, so attention must read back through the pool
     # instead of being causal over its own window only
     prefix_attend: bool = False
+    # a paged call of T > 1 positions a row is a decode step that
+    # verifies a draft (serve/llm_engine.py), not a prompt: written and
+    # read by the paged decode call, each query over the pool up to its
+    # own position
+    verify: bool = False
+    # a block outside the stack (``MTPModule``'s): global attention
+    # without rotation, whatever the layouts say of its pool layer
+    lone: bool = False
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
@@ -326,14 +334,17 @@ class Attention(nn.Module):
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
         v = _dense((kvh, hd), ("embed", "kv", "head_dim"), "wv",
                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(x)
-        if cfg.qk_norm:       # over the whole projection, heads unsplit
+        if cfg.qk_norm_per_head:    # over each head's head_dim alone
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
+        elif cfg.qk_norm:     # over the whole projection, heads unsplit
             q = RMSNorm(cfg.norm_eps, name="q_norm")(
                 q.reshape(*q.shape[:2], h * hd)).reshape(q.shape)
             k = RMSNorm(cfg.norm_eps, name="k_norm")(
                 k.reshape(*k.shape[:2], kvh * hd)).reshape(k.shape)
         rotate, _ = self._layer_kind(layer)
-        if cfg.rope_theta is None:
-            pass                            # no layer rotates
+        if cfg.rope_theta is None or self.lone:
+            pass                            # no layer rotates, or not this
         elif rotate is None:
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
@@ -370,6 +381,8 @@ class Attention(nn.Module):
         ``layer``."""
         cfg = self.cfg
         rotate = window = None
+        if self.lone:
+            return rotate, window
         if cfg.rope_layout is not None:
             rotate = jnp.asarray(cfg.rope_layout, jnp.bool_)[layer]
         if cfg.window_layout is not None:
@@ -537,7 +550,9 @@ class Attention(nn.Module):
         a row it leaves out, whose output is zeros.
 
         Who writes: a prompt's rows (T > 1) ``write_kv_pages``; a decode
-        step's (T == 1) ``paged_attention`` itself, handed the new rows:
+        step's (T == 1, or under ``verify`` the T positions of a step
+        that verifies a draft) ``paged_attention`` itself, handed the
+        new rows:
         on the chip the kernel puts each row it keeps into its own tail
         page and a row it does not keep writes NOTHING; the XLA form
         scatters every row first, a dead one into the scratch page its
@@ -560,6 +575,14 @@ class Attention(nn.Module):
                 window=self._window_over(
                     window, block_tables.shape[1] * pool.shape[3]))
             return out[:, None], pool
+        if self.verify:
+            # T positions a row in one decode call: the same kernel at T
+            # queries, every row of them written by it, each query
+            # masked (and windowed) from its own position
+            return paged_attention(
+                q, pool, block_tables, positions[:, -1] + 1, new_rows=kv,
+                layer=layer, live=live, window=self._window_over(
+                    window, block_tables.shape[1] * pool.shape[3]))
         pool = write_kv_pages(pool, kv, block_tables, positions, layer=layer)
         if not self.prefix_attend:
             window = self._window_over(window, q.shape[1])
@@ -850,6 +873,11 @@ class Block(nn.Module):
     # a layer of the dense prefix of a model whose other layers have
     # experts (cfg.first_dense_layers): its feed-forward is SwiGLU(d_ff)
     dense_ffn: bool = False
+    verify: bool = False                   # see Attention
+    # the block of ``MTPModule``, outside the stack: its attention is
+    # global and does not rotate, and ``moe_stacked`` is a stack of its
+    # own experts alone (index 0, whatever its pool ``layer``)
+    lone: bool = False
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
@@ -888,9 +916,13 @@ class Block(nn.Module):
                               route_scale=cfg.moe_route_scale,
                               held=cfg.moe_experts_held,
                               held_first=cfg.moe_held_first, name="moe")
-        attn_cls = LatentAttention if cfg.kv_lora_rank else Attention
-        attn = attn_cls(cfg, self.mesh, self.rules, self.decode,
-                        self.prefix_attend, name="attn")
+        if cfg.kv_lora_rank:
+            attn = LatentAttention(cfg, self.mesh, self.rules, self.decode,
+                                   self.prefix_attend, name="attn")
+        else:
+            attn = Attention(cfg, self.mesh, self.rules, self.decode,
+                             self.prefix_attend, self.verify, self.lone,
+                             name="attn")
         routes_before = moe is not None and cfg.moe_router_pre_attn
 
         def before(x, positions, project: bool):
@@ -916,7 +948,7 @@ class Block(nn.Module):
             y = x if cfg.post_norm else mlp_norm(x)
             if moe is not None:
                 routed = moe(y, router_logits, live, moe_stacked,
-                             None if layer is None
+                             None if layer is None else 0 if self.lone
                              else layer - cfg.first_dense_layers)
                 if cfg.moe_shared_experts:    # every token, beside the sum
                     routed = routed + MLP(
@@ -978,7 +1010,8 @@ class Block(nn.Module):
         chunks = -(-jnp.max(lengths) // PREFILL_CHUNK)
         params = {"params": self.variables["params"]}
         block = Block(cfg, self.mesh, self.rules, self.decode,
-                      self.prefix_attend, self.dense_ffn, parent=None)
+                      self.prefix_attend, self.dense_ffn, self.verify,
+                      self.lone, parent=None)
         projected, router_logits = _over_chunks(
             lambda cut: block.apply(params, cut[0], cos, sin, cut[1],
                                     layer=layer, part="before"),
@@ -1379,6 +1412,55 @@ class Period(nn.Module):
         return x if carry is None else (x, tuple(carries))
 
 
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+    section 2.2), behind the stack::
+
+        x_i = M [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)]
+        g_i = RMSNorm(Block(x)_i)
+
+    ``h_i`` the stack's last hidden state at position i BEFORE its final
+    norm, ``M`` 2 d_model -> d_model, the block one of the stack's kind
+    (``Block.lone``: global attention without rotation, experts where
+    the stack has them), the embedding and the head the model's own
+    (``GPT`` looks the tokens up and ``output_logits`` reads ``g``): the
+    logits at i are of token i + 2.  With a paged ``pool`` the block's
+    K/V rows live in the pool's last layer, at position i, under the
+    rows' own page tables, and the result is ``(g, pool)``."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Mesh] = None
+    rules: ShardingRules = LOGICAL_RULES
+    decode: bool = False
+    verify: bool = False
+
+    @nn.compact
+    def __call__(self, emb, hidden, cos, sin, positions=None,
+                 block_tables=None, lengths=None, pool=None):
+        cfg = self.cfg
+        x = _dense(cfg.d_model, ("mlp", "embed"), "eh_proj",
+                   dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
+            jnp.concatenate([RMSNorm(cfg.norm_eps, name="enorm")(emb),
+                             RMSNorm(cfg.norm_eps, name="hnorm")(hidden)],
+                            axis=-1))
+        block = Block(cfg, self.mesh, self.rules, self.decode, False, False,
+                      self.verify, True, name="block")
+        stacked = None
+        if (pool is not None and cfg.moe_experts and cfg.moe_dropless
+                and not self.is_initializing()):
+            # its experts as a stack of one, which the decode kernel
+            # reads in place (``DroplessMoE.__call__``)
+            moe = nn.meta.unbox(block.variables["params"]["moe"])
+            stacked = tuple(w[None] for w in (moe["w_gate"], moe["w_up"],
+                                              moe["w_down"]))
+        x = block(x, cos, sin, positions, block_tables, stacked, lengths,
+                  pool, None if pool is None else cfg.n_layers)
+        if pool is not None:
+            x, pool = x
+        x = RMSNorm(cfg.norm_eps, name="norm")(x)
+        return x if pool is None else (x, pool)
+
+
 def output_logits(cfg: TransformerConfig, params, hidden) -> jax.Array:
     """float32 logits of post-final-norm hidden states ``[..., d_model]``
     (what ``GPT.__call__(return_hidden=True)`` returns) under ``params``
@@ -1410,6 +1492,9 @@ class GPT(nn.Module):
     # entries of the recurrent leaves (see LinearAttention,
     # Mamba2Mixer) of a paged model with such layers; entry 0 is scratch
     state_entries: int = 0
+    # a paged call of T > 1 positions a row is a decode step over a
+    # draft, not a prompt (see Attention)
+    verify: bool = False
 
     def _moe_stacked(self):
         """The scanned layer stack's dropless expert leaves ``[L, E, ...]``
@@ -1521,8 +1606,18 @@ class GPT(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden: bool = False,
-                 block_tables=None, lengths=None, state_rows=None):
-        """``lengths`` [B]: each row's REAL length in a ``T > 1`` call
+                 block_tables=None, lengths=None, state_rows=None,
+                 mtp_hidden=None, return_prenorm: bool = False):
+        """``mtp_hidden`` [B, T, d_model] (a model with ``cfg.mtp_layers``):
+        run the multi-token-prediction module INSTEAD of the stack, on the
+        stack's last hidden states before the final norm (what
+        ``return_prenorm`` returns beside the normed ones, ``(hidden,
+        prenorm)``, with ``return_hidden``) at ``positions``, ``tokens``
+        then being the tokens one position on; the result is the module's
+        normed output (``return_hidden``) or its logits through the
+        model's head, of the tokens two positions on.
+
+        ``lengths`` [B]: each row's REAL length in a ``T > 1`` call
         (None: every position is real).  A linear_attention layer needs
         it (right-pad that attention never sees would be absorbed by a
         recurrence); a ``Block`` writing a prompt wave into the pool
@@ -1569,24 +1664,34 @@ class GPT(nn.Module):
         block_kwargs = dict(mesh=self.mesh, rules=self.rules,
                             decode=self.decode,
                             prefix_attend=self.prefix_attend)
+        if self.verify:
+            block_kwargs["verify"] = True
+        # the paged pool: ONE stacked leaf for the whole model (below)
+        ckv = self._pool() if (self.decode and self.paged_pages
+                               and not cfg.period) else None
+        if cfg.mtp_layers and (mtp_hidden is not None
+                               or self.is_initializing()):
+            mtp = MTPModule(cfg, self.mesh, self.rules, self.decode,
+                            self.verify, name="mtp")
+        if mtp_hidden is not None:
+            x = mtp(x, mtp_hidden, cos, sin, positions, block_tables,
+                    lengths, None if ckv is None else ckv.value)
+            if ckv is not None:
+                x, ckv.value = x
+            return x if return_hidden else self._head(x, embed)
         call_args = (cos, sin, positions, block_tables,
                      self._moe_stacked(), lengths)
         if cfg.period:
             x = self._stack_periods(x, block_kwargs, call_args[:4], lengths,
                                     state_rows, call_args[4], do_remat)
-        elif self.decode and self.paged_pages:
-            # the paged pool: ONE stacked leaf for the whole model, its
-            # row what the model's attention caches of a token (K in
-            # [..., :hd], V in [..., hd:]; or one latent row for all
-            # heads: cfg.cache_row_width, ops/paged_attention.py layout
-            # note).  It rides the layer stack as loop-carried state
+        elif ckv is not None:
+            # the pool's row is what the model's attention caches of a
+            # token (K in [..., :hd], V in [..., hd:]; or one latent row
+            # for all heads: cfg.cache_row_width, ops/paged_attention.py
+            # layout note).  It rides the layer stack as loop-carried state
             # with a layer index, so each block writes its rows in place
             # and no layer's pool is ever sliced out, relaid or written
             # back.
-            ckv = self.variable(
-                "cache", "kv_pages", jnp.zeros,
-                (cfg.n_layers, self.paged_pages, cfg.cache_kv_heads,
-                 self.page_size, cfg.cache_row_width), cfg.dtype)
             x, pool = self._stack_blocks(x, block_kwargs, call_args,
                                          remat=False, carry=ckv.value)
             if not self.is_initializing():
@@ -1611,12 +1716,34 @@ class GPT(nn.Module):
             x = self._stack_blocks(x, block_kwargs, call_args,
                                    remat=do_remat, cache=True)
 
+        prenorm = x
+        if cfg.mtp_layers and self.is_initializing():
+            # shape-only: the module's parameters (no cache is written)
+            mtp(jnp.take(lookup, tokens, axis=0).astype(cfg.dtype), x, cos,
+                sin, positions, block_tables, None,
+                None if ckv is None else ckv.value)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         if return_hidden:
             # memory-lean loss path: the caller projects per sequence
             # chunk (ops/losses.py chunked_lm_loss) so [B, S, vocab]
             # logits never materialize
-            return x
+            return (x, prenorm) if return_prenorm else x
+        return self._head(x, embed)
+
+    def _pool(self):
+        """The paged pool's cache variable (``__call__``): the stack's
+        layers and, behind them, the prediction module's."""
+        cfg = self.cfg
+        return self.variable(
+            "cache", "kv_pages", jnp.zeros,
+            (cfg.n_layers + cfg.mtp_layers, self.paged_pages,
+             cfg.cache_kv_heads, self.page_size, cfg.cache_row_width),
+            cfg.dtype)
+
+    def _head(self, x, embed):
+        """float32 logits of normed hidden states (``__call__``'s own
+        compact scope: the head's parameters are the model's)."""
+        cfg = self.cfg
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
         else:

@@ -54,9 +54,12 @@ traced scalar under the layer scan).
 
 WHO WRITES (models/gpt.py ``_decode_attend_paged`` is the call site of
 both).  A prompt's rows (``T > 1``): ``write_kv_pages`` below, a loop of
-``dynamic_update_slice``.  A decode step's one row (``T == 1``):
-``paged_attention`` itself, handed ``new_rows``.  On the chip the Pallas
-kernel puts each row it keeps into the row's own tail page, which it has
+``dynamic_update_slice``.  A decode step's one row (``T == 1``), or the
+two rows of a step that verifies a draft (``q`` ``[rows, T, heads,
+head_dim]``: ``T`` query positions a row, causal between them, each
+counting its window from its own position; serve/llm_engine.py
+drafting): ``paged_attention`` itself, handed ``new_rows``.  On the chip
+the Pallas kernel puts each row it keeps into the row's own tail page, which it has
 in VMEM with the row's last chunk anyway, and sends the tile-aligned
 group that holds it back to the pool, the pool aliased through the call;
 a row that holds no request writes NOTHING.  Off the chip ``new_rows``
@@ -192,6 +195,9 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
                   ``(out, pool)``
     returns       [rows, heads, head_dim]  (latent: [rows, heads, v_width])
     """
+    if q.ndim == 4:
+        return _multi_xla(q, kv_pages, block_tables, lengths, layer, window,
+                          live, sm_scale, new_rows)
     if new_rows is not None:
         kv_pages = write_kv_pages(kv_pages, new_rows[:, None], block_tables,
                                   lengths[:, None] - 1, layer=layer)
@@ -211,6 +217,31 @@ def paged_attention_xla(q: jax.Array, kv_pages: jax.Array,
     if live is not None:
         out = jnp.where(live[:, None, None], out, jnp.zeros_like(out))
     return out
+
+
+def _multi_xla(q, kv_pages, block_tables, lengths, layer, window, live,
+               sm_scale, new_rows):
+    """``paged_attention_xla`` at ``T`` query positions a row (``q``
+    [rows, T, heads, head_dim], ``new_rows`` [rows, T, kv_heads, row]):
+    the T rows scattered one position after the other (a row's T
+    positions may straddle a page), then each query over the positions
+    up to its own, ``lengths - T + t + 1`` of them.  ``(out, pool)``."""
+    t, hd = q.shape[1], q.shape[-1]
+    ends = lengths[:, None] - (t - 1) + jnp.arange(t)      # [rows, T]
+    for i in range(t):
+        kv_pages = write_kv_pages(kv_pages, new_rows[:, i:i + 1],
+                                  block_tables, ends[:, i:i + 1] - 1,
+                                  layer=layer)
+    kv = gather_kv_pages(kv_pages, block_tables, layer=layer)
+    pos = jnp.arange(kv.shape[1])[None, None, :]
+    mask = pos < ends[:, :, None]
+    if window is not None:
+        mask = mask & (pos >= ends[:, :, None] - window)
+    out = xla_attention(q, kv[..., :hd], kv[..., hd:], causal=False,
+                        mask=mask[:, None], sm_scale=sm_scale)
+    if live is not None:
+        out = jnp.where(live[:, None, None, None], out, jnp.zeros_like(out))
+    return out, kv_pages
 
 
 # a work item of the kernel is a chunk of one row's pages: this many
@@ -234,7 +265,7 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                 window: Optional[jax.Array] = None,
                 live: Optional[jax.Array] = None,
                 v_width: Optional[int] = None,
-                new_rows: Optional[jax.Array] = None):
+                new_rows: Optional[jax.Array] = None, tq: int = 1):
     """Pallas TPU decode kernel: ONE invocation walks the flat list of
     (live row, chunk of occupied pages) work with a page pipeline that
     never drains between rows.
@@ -296,13 +327,29 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
     row's tail page is its own (the engine lends whole read-only pages
     only), so no other row reads or writes it inside a call; a row the
     kernel does not keep writes nothing.
+
+    ``tq`` > 1 (a step that verifies a draft): ``tq`` query positions a
+    row, the last at ``length - 1``.  ``q`` and the output are then
+    ``[rows, kv_heads * tq * g, qw]``, a KV head's ``tq * g`` queries
+    side by side, position-major (``paged_attention_tpu`` lays them out
+    so), and ride one product a KV head: ``tq * g`` rows of the MXU tile
+    where one position fills ``g``.  The query of position ``length - tq
+    + i`` sees the keys up to its own, so the positions of a step are
+    causal among themselves, and under a window those after ``its own
+    position - window``; the row's page range starts where its FIRST
+    query's does.  ``new_rows`` is ``[rows, tq * kv_heads, row]``,
+    position-major, and every one is written: each into the buffered page
+    that holds its position, in the chunk that page arrives with (an
+    earlier position may lie a page, and so a chunk, ahead of the last),
+    the group of each going back once (one group holds both: one
+    write-back), an earlier chunk's waited for before that chunk ends.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rows, heads, qw = q.shape
+    rows, heads, qw = q.shape           # heads: tq positions' worth
     _, _, kvh, ps, hd2 = kv_pages.shape
-    g = heads // kvh
+    g = heads // kvh                    # a KV head's queries, tq * its group
     depth = _PIPELINE_DEPTH
     page_bytes = kvh * ps * hd2 * kv_pages.dtype.itemsize
     chunk_tokens = _CHUNK_TOKENS if v_width is None else _LATENT_CHUNK_TOKENS
@@ -342,8 +389,10 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
         for r in range(rows):
             length = len_ref[r]
             end = pl.cdiv(length, ps)
-            first = (jnp.maximum(length - window_ref[0], 0) // ps
-                     if windowed else 0)
+            first = 0
+            if windowed:        # of the row's FIRST query
+                reach = window_ref[0] + (tq - 1) if tq > 1 else window_ref[0]
+                first = jnp.maximum(length - reach, 0) // ps
             keep = end > first
             if masked:
                 keep = keep & (live_ref[r] != 0)
@@ -384,13 +433,27 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
         for slot in range(depth - 1):
             cursor = fetch(cursor, slot)
 
-        def tail_dma(slot, k, at, page):
+        def tail_dma(slot, k, at, page, sem=0):
             """The write-back of the group at ``at`` of page ``k`` of
             ``slot`` to ``pool[layer, page]``."""
             return pltpu.make_async_copy(
                 kvbuf.at[slot, k, :, pl.ds(at, grp)],
                 pool_ref.at[layer_ref[0], page, :, pl.ds(at, grp)],
-                wsem.at[0])
+                wsem.at[sem])
+
+        def patch(slot, k, at, off, r, i):
+            """New row ``i`` of row ``r`` into position ``at + off`` of
+            page ``k`` of ``slot``: a select over the group at ``at``
+            (float32 and back is exact, and slices a packed dtype
+            nowhere but on whole tiles)."""
+            rows_new = new_ref[r].astype(jnp.float32)
+            hit = jax.lax.broadcasted_iota(jnp.int32, (grp, hd2), 0) == off
+            for h in range(kvh):
+                at_h = (slot, k, h, pl.ds(at, grp))
+                new = rows_new[i * kvh + h:i * kvh + h + 1]
+                kvbuf[at_h] = jnp.where(
+                    hit, new, kvbuf[at_h].astype(jnp.float32)
+                ).astype(kvbuf.dtype)
 
         def row(j, carry):
             step, cursor = carry
@@ -406,7 +469,27 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                            jnp.zeros((g, vw), jnp.float32))
                           for _ in range(kvh))
             n_chunks = pl.cdiv(end - first, cp)
-            if writes:
+            if tq > 1:
+                # query ``i`` of the product's rows (position-major in a
+                # KV head) stands ``back`` positions before the last
+                back = (tq - 1) - jax.lax.broadcasted_iota(
+                    jnp.int32, (g, cp * ps), 0) // (g // tq)
+            if writes and tq > 1:
+                # new row i: its chunk, its page there, its group in the
+                # page, its place in the group, its physical page; and
+                # whether its group goes back on its own (the last one's
+                # always; an earlier one's where the next lies elsewhere)
+                w_pos = [length - tq + i for i in range(tq)]
+                w_page = [p // ps - first for p in w_pos]
+                w_chunk = [pg // cp for pg in w_page]
+                w_k = [pg - c * cp for pg, c in zip(w_page, w_chunk)]
+                w_at = [0 if grp == ps else pl.multiple_of(
+                    p % ps // grp * grp, grp) for p in w_pos]
+                w_off = [p % ps - at for p, at in zip(w_pos, w_at)]
+                w_phys = [tables_ref[r, p // ps] for p in w_pos]
+                w_own = [w_pos[i] // grp != w_pos[i + 1] // grp
+                         for i in range(tq - 1)] + [True]
+            elif writes:
                 # where the new token goes: page ``tail_k`` of the last
                 # chunk, the group at ``tail_at``, position ``off`` of it
                 tail_k = end - 1 - (first + (n_chunks - 1) * cp)
@@ -421,26 +504,32 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                 cursor = fetch(cursor, (step + depth - 1) % depth)
                 slot, at = step % depth, first + t * cp
                 chunk_dmas(slot, at, end)
-                if writes:
+                if writes and tq > 1:
+                    for i in range(tq):
+                        @pl.when(t == w_chunk[i])
+                        def _():
+                            patch(slot, w_k[i], w_at[i], w_off[i], r, i)
+                    for i in range(tq):
+                        @pl.when((t == w_chunk[i]) & w_own[i])
+                        def _():
+                            tail_dma(slot, w_k[i], w_at[i], w_phys[i],
+                                     i).start()
+                elif writes:
                     @pl.when(t == n_chunks - 1)
                     def _():
-                        # float32 and back is exact, and slices a packed
-                        # dtype nowhere but on whole tiles
-                        rows_new = new_ref[r].astype(jnp.float32)
-                        hit = jax.lax.broadcasted_iota(
-                            jnp.int32, (grp, hd2), 0) == off
-                        for h in range(kvh):
-                            at_h = (slot, tail_k, h, pl.ds(tail_at, grp))
-                            kvbuf[at_h] = jnp.where(
-                                hit, rows_new[h:h + 1],
-                                kvbuf[at_h].astype(jnp.float32)
-                            ).astype(kvbuf.dtype)
+                        patch(slot, tail_k, tail_at, off, r, 0)
                         tail_dma(slot, tail_k, tail_at, tail_page).start()
                 pos = at * ps + jax.lax.broadcasted_iota(
                     jnp.int32, (g, cp * ps), 1)
-                valid = pos < length
-                if windowed:
-                    valid = valid & (pos >= first_pos)
+                if tq > 1:
+                    valid = pos < length - back
+                    if windowed:
+                        valid = valid & (pos >= length - back
+                                         - window_ref[0])
+                else:
+                    valid = pos < length
+                    if windowed:
+                        valid = valid & (pos >= first_pos)
                 new = []
                 for h in range(kvh):             # static per-head 2-D ops
                     m_prev, l_prev, acc = stats[h]
@@ -461,11 +550,22 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
                                 l_prev * alpha
                                 + jnp.sum(p, axis=1, keepdims=True),
                                 acc * alpha + pv))
+                if writes and tq > 1:
+                    # an earlier row's write-back leaves this slot before
+                    # a later chunk's fetch lands in it
+                    for i in range(tq - 1):
+                        @pl.when((t == w_chunk[i]) & w_own[i])
+                        def _():
+                            tail_dma(slot, w_k[i], w_at[i], w_phys[i],
+                                     i).wait()
                 return step + 1, cursor, tuple(new)
 
             step, cursor, stats = jax.lax.fori_loop(
                 0, n_chunks, chunk, (step, cursor, stats))
-            if writes:
+            if writes and tq > 1:
+                tail_dma((step - 1) % depth, w_k[-1], w_at[-1], w_phys[-1],
+                         tq - 1).wait()
+            elif writes:
                 tail_dma((step - 1) % depth, tail_k, tail_at,
                          tail_page).wait()
             for h, (_, l, acc) in enumerate(stats):
@@ -494,7 +594,7 @@ def _tpu_kernel(q: jax.Array, kv_pages: jax.Array,
             pltpu.SMEM((rows,), jnp.int32),         # their first page
             pltpu.SMEM((rows,), jnp.int32),         # the page they end at
             pltpu.SemaphoreType.DMA((depth,)),
-        ] + [pltpu.SemaphoreType.DMA((1,))] * writes,   # the write-back
+        ] + [pltpu.SemaphoreType.DMA((tq,))] * writes,  # the write-backs
     )
     return pl.pallas_call(
         kernel,
@@ -517,6 +617,18 @@ def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
                         new_rows: Optional[jax.Array] = None):
     hd = q.shape[-1]
     scale = sm_scale if sm_scale is not None else hd ** -0.5
+    tq = 1
+    if q.ndim == 4:
+        # T query positions a row: a KV head's queries side by side,
+        # position-major, and the new rows position-major (_tpu_kernel);
+        # the output comes back in the same order
+        rows, tq, heads, _ = q.shape
+        kvh = kv_pages.shape[2]
+        by_kv = lambda a, *axes: a.reshape(                 # noqa: E731
+            rows, *axes, a.shape[-1]).swapaxes(1, 2).reshape(
+                rows, tq * heads, a.shape[-1])
+        q = by_kv(q, tq, kvh, heads // kvh)
+        new_rows = new_rows.reshape(rows, tq * kvh, -1)
     # a half of the page is whole lane tiles, or the query is padded
     # with zeros to the page's width (see _tpu_kernel); a latent row's
     # query comes at the page's width
@@ -531,11 +643,14 @@ def paged_attention_tpu(q, kv_pages, block_tables, lengths, *, layer=0,
         new_rows = new_rows.astype(kv_pages.dtype)
     got = _tpu_kernel(q, kv_pages, block_tables, lengths.astype(jnp.int32),
                       jnp.asarray(layer, jnp.int32).reshape(1), scale,
-                      window, live, v_width, new_rows)
+                      window, live, v_width, new_rows, tq)
     if new_rows is None:
         return got if split else got[..., hd:]
     out, pool = got
-    return (out if split else out[..., hd:]), pool
+    out = out if split else out[..., hd:]
+    if tq > 1:
+        out = by_kv(out, kvh, tq, heads // kvh).reshape(rows, tq, heads, -1)
+    return out, pool
 
 
 def resolve_paged_impl(kv_minor: int, impl: str = "auto",
@@ -589,6 +704,14 @@ def paged_attention(q, kv_pages, block_tables, lengths, *, layer=0,
     for this kernel and the expert kernel).  ``None``: every row is
     read.  ``v_width``: the pool holds latent rows (module docstring);
     the kernel then wants ``v_width`` in whole lane tiles too.
+
+    ``q`` may be ``[rows, T, heads, head_dim]``, ``T`` query positions a
+    row of which the LAST is at ``lengths - 1`` (a step that verifies a
+    draft), with ``new_rows`` ``[rows, T, kv_heads, row]`` (required
+    then): the query of position ``lengths - T + t`` sees the positions
+    up to its own, under a window the last ``window`` of them, and all
+    ``T`` rows are written first; the output is ``[rows, T, heads,
+    head_dim]``.
 
     ``new_rows`` [rows, kv_heads, row] (None: the pool is only read, and
     the result is the output alone): the step's token of every row,

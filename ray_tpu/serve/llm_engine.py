@@ -102,6 +102,26 @@ iteration's prefills):
     rng stay on the device between blocks.  Installs from the ready
     queue upload their current last token (host-known since their
     prefill), so the block's tokens come back in a single fetch.
+  - Drafting.  A model with a multi-token-prediction module
+    (``cfg.mtp_layers``; models/gpt.py MTPModule) is served with it as
+    its own drafter, and a step then yields ONE OR TWO tokens a row
+    (``_spec_block_fn``).  A row holds its last confirmed token ``t`` at
+    position p, a draft ``d`` for p + 1 and the logits ``d`` was drawn
+    from.  A step runs the stack on both positions (the paged kernel at
+    two queries a row, both K/V rows written by it), accepts or rejects
+    the draft (models/generate.py ``verify_draft``, the row's own
+    temperature: the emitted tokens are distributed as the model's own,
+    whatever the drafter), advances the row by 1 or 2, runs the module
+    on the confirmed positions and draws the next draft.  A rejected
+    draft's K/V row at p + 1 is overwritten by the next step; nothing
+    is rolled back.  The module's block holds pages in the pool's last
+    layer under the same tables; a prefill wave fills them over the
+    prompt and yields the first token, the first draft and its logits,
+    which stay on the device until the install takes them from there.
+    The block's one fetch carries both tokens of every step and how
+    many each row emitted.  Refused with it: the prefix cache, the
+    prefill handoff (neither carries a draft), recurrent layers (a
+    rejected draft would need the state rolled back).
   - No eos logic on device: rows that finish mid-block keep generating
     junk the host truncates.  A freed slot keeps stepping junk until
     its redirect row (table -> scratch page 0, position 0; see
@@ -318,11 +338,15 @@ class _Prefilled:
     the device alone, and a request installed that early (``slot``)
     takes it from there (``source``)."""
 
-    __slots__ = ("slot_state", "table", "source", "slot")
+    __slots__ = ("slot_state", "table", "source", "slot", "drafted")
 
     def __init__(self, slot_state: _Slot, table):
         self.slot_state = slot_state     # reused verbatim at install
         self.table = table               # np.int32 [max_pages]
+        # a drafting engine: (the wave's (firsts, drafts, draft logits)
+        # on the device, this request's row of them), kept until the
+        # install takes all three from there
+        self.drafted: Optional[tuple] = None
         # (the wave's ``firsts`` on the device, this request's row of
         # it) while the host has not fetched the token
         self.source: Optional[tuple] = None
@@ -368,7 +392,9 @@ class EngineStats:
     def __init__(self):
         self.steps = 0                   # decode steps executed (N-wide)
         self.quanta = 0                  # decode blocks fetched
-        self.step_tokens = 0             # tokens delivered from steps
+        # tokens delivered from steps: one a live row a step, or under
+        # drafting one or two (so batch_occupancy may pass 1 there)
+        self.step_tokens = 0
         self.tokens_generated = 0        # + prefill first tokens
         self.prefills = 0
         # of them, requests stepped by the block dispatched right behind
@@ -410,6 +436,13 @@ class EngineStats:
         # hold a request, not the batch.  Host arithmetic like the pages
         self.decode_rows_written = 0
         self.pool_layer_steps = 0
+        # a drafting engine (module docstring): drafts verified by steps
+        # whose row was delivered, and of them those that stood.  Such a
+        # step reads its pages with two queries and writes two rows a
+        # pool layer (decode_pages_read, decode_rows_written), and
+        # step_tokens / drafts_proposed is the tokens a live row a step
+        self.drafts_proposed = 0
+        self.drafts_accepted = 0
         # recurrent layers of either class (gated delta, Mamba-2; the
         # names are the first class's): a layer step is one such layer
         # in one decode step; gdn_state_rows sums over them the rows
@@ -470,6 +503,9 @@ class EngineStats:
                        else min(under_way, like))
         return {
             "steps": self.steps,
+            "step_tokens": self.step_tokens,
+            "drafts_proposed": self.drafts_proposed,
+            "drafts_accepted": self.drafts_accepted,
             "tokens_generated": self.tokens_generated,
             "prefills": self.prefills,
             "installs_with_prefill": self.installs_with_prefill,
@@ -597,8 +633,17 @@ class LLMEngine:
                               else 1 + (num_slots + 1) * self.max_pages)
         # layers that hold KV pages, and layers that hold a recurrent
         # state entry instead (module docstring)
-        self._pool_layers = cfg.layers_of(*POOL_KINDS)
+        self._pool_layers = cfg.layers_of(*POOL_KINDS) + cfg.mtp_layers
         self._state_layers = cfg.layers_of(*STATE_KINDS)
+        # the model drafts for itself (module docstring)
+        self._drafts = bool(cfg.mtp_layers)
+        if self._drafts and (self._state_layers or prefix_cache_pages):
+            raise ValueError(
+                "a model with a multi-token-prediction module is served "
+                "drafting with it: a rejected draft would need a "
+                "recurrent layer's state rolled back, and a prefix-cache "
+                "hit's suffix prefill has no path that fills the "
+                "module's pages over the cached prefix")
         self.state_entries = (num_slots + 1 + _STATE_AHEAD
                               if self._state_layers else 0)
         if self._state_layers and prefix_cache_pages:
@@ -619,6 +664,12 @@ class LLMEngine:
         self.model = GPT(cfg, decode=True, paged_pages=self.kv_pool_pages,
                          page_size=page_size,
                          state_entries=self.state_entries)
+        if self._drafts:
+            # same params/cache structure, different (static) attention
+            # path: a call of two positions a row is a decode step
+            self.model_verify = GPT(cfg, decode=True,
+                                    paged_pages=self.kv_pool_pages,
+                                    page_size=page_size, verify=True)
         self.stats = EngineStats()
         # the block program also returns the dropless expert layers'
         # load (EngineStats.moe_*); a model without them compiles the
@@ -655,7 +706,7 @@ class LLMEngine:
         no_meta = np.zeros((self._meta_rows, num_slots), np.int32)
         no_meta[0, :] = num_slots                           # -> scratch
         self._no_admit = (jnp.asarray(no_meta),
-                          jnp.zeros((num_slots,), jnp.int32),
+                          self._wave_outs(num_slots),
                           jnp.zeros((num_slots, self.max_pages), jnp.int32))
         self._prefill_jit: dict = {}      # (bucket, wave) -> jitted fn
         self._suffix_jit: dict = {}       # (bucket, wave) -> jitted fn
@@ -735,7 +786,8 @@ class LLMEngine:
         # a profiler trace shows its program as jit_<name> on the
         # device's ``XLA Modules`` line (docs/observability.md)
         def engine_decode_block(*args):
-            return self._block_fn(*args)
+            return (self._spec_block_fn if self._drafts
+                    else self._block_fn)(*args)
         self._block_jit = jax.jit(engine_decode_block,
                                   donate_argnums=(1, 2))
 
@@ -743,8 +795,12 @@ class LLMEngine:
             # the block's admit_lasts [num_slots] with the first tokens
             # of ONE prefill wave put in, device to device: rows[n] is
             # the wave's row that install n takes its token from, -1
-            # where it takes none.  One program a wave size
-            return jnp.where(rows < 0, lasts, firsts[jnp.maximum(rows, 0)])
+            # where it takes none.  One program a wave size.  A drafting
+            # engine's are triples (token, draft, the draft's logits)
+            return jax.tree.map(
+                lambda last, first: jnp.where(
+                    (rows < 0).reshape((-1,) + (1,) * (last.ndim - 1)),
+                    last, first[jnp.maximum(rows, 0)]), lasts, firsts)
         self._install_firsts_jit = jax.jit(engine_install_firsts)
 
     # ------------------------------------------------------------ jit fns
@@ -762,7 +818,19 @@ class LLMEngine:
                  jax.random.PRNGKey(seed))                # device rng
         if self._state_layers:      # per-row state entries (0: scratch)
             state += (jnp.zeros((self._rows,), jnp.int32),)
+        if self._drafts:            # per-row draft and its logits
+            state += self._wave_outs(self._rows)[1:]
         return state
+
+    def _wave_outs(self, n: int):
+        """Zeros of what a prefill wave of ``n`` prompts yields and an
+        install takes: the first tokens [n]; a drafting engine's
+        ``(first tokens, drafts, the drafts' logits [n, vocab])``."""
+        firsts = jnp.zeros((n,), jnp.int32)
+        if not self._drafts:
+            return firsts
+        return (firsts, firsts,
+                jnp.zeros((n, self.cfg.vocab_size), jnp.float32))
 
     def _sample_fn(self, rng, logits, temps):
         """[B, V] logits + per-row temperature -> [B] token ids
@@ -774,7 +842,9 @@ class LLMEngine:
     def _last_logits(self, model, params, cache, tokens, positions,
                      s_reals, tables, entries=None, skip_pad=True):
         """``(logits [wave, vocab] of each row's last REAL position, the
-        updated cache)``.  The head runs on those rows alone: float32
+        updated cache)``; a drafting engine's: ``(logits, cache, the
+        stack's hidden states before the final norm)``, for
+        ``_first_draft``.  The head runs on those rows alone: float32
         logits of every position are ``wave x bucket x vocab`` (1.2 GB
         for one 2048-token prompt at a 152k vocabulary), of which one
         row a prompt is read.  ``skip_pad``: the model is told the real
@@ -789,10 +859,32 @@ class LLMEngine:
         hidden, mut = model.apply(
             {"params": params, "cache": cache}, tokens, positions,
             return_hidden=True, mutable=["cache"], block_tables=tables,
-            **told)
+            return_prenorm=self._drafts, **told)
+        hidden, *prenorm = hidden if self._drafts else (hidden,)
         last = jnp.take_along_axis(
             hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0]
-        return output_logits(self.cfg, params, last), mut["cache"]
+        return (output_logits(self.cfg, params, last), mut["cache"],
+                *prenorm)
+
+    def _first_draft(self, params, cache, prenorm, tokens, positions,
+                     s_reals, tables, first, temps, rng):
+        """A drafting engine's prefill, behind the first token's
+        sampling: the prediction module over the prompt, which fills its
+        pages (entry i from the stack's hidden state at i, ``prenorm`` as
+        ``_last_logits`` returns it, and token i + 1: the prompt's next,
+        ``first`` at the last real position), and the first draft, drawn
+        from its logits at that position.  ``((first, draft, logits),
+        cache)``."""
+        rows = jnp.arange(tokens.shape[0])
+        nxt = jnp.roll(tokens, -1, axis=1).at[rows, s_reals - 1].set(first)
+        hidden, mut = self.model.apply(
+            {"params": params, "cache": cache}, nxt, positions,
+            return_hidden=True, mutable=["cache"], block_tables=tables,
+            mtp_hidden=prenorm, lengths=s_reals)
+        logits = output_logits(self.cfg, params, jnp.take_along_axis(
+            hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0])
+        return ((first, self._sample_fn(rng, logits, temps), logits),
+                mut["cache"])
 
     def _get_prefill_paged(self, bucket: int, wave: int):
         """Slotless prefill: prompts write straight into pool pages via
@@ -809,10 +901,16 @@ class LLMEngine:
                 temps = packed[:, bucket + 1].astype(jnp.float32) / 1e6
                 b, s = tokens.shape
                 positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-                last, cache = self._last_logits(
+                last, cache, *prenorm = self._last_logits(
                     self.model, params, cache, tokens, positions, s_reals,
                     tables, packed[:, bucket + 2] if self._state_layers
                     else None, skip_pad=self._skips_pad(bucket))
+                if self._drafts:
+                    rng, sub = jax.random.split(rng)
+                    return self._first_draft(
+                        params, cache, *prenorm, tokens, positions, s_reals,
+                        tables, self._sample_fn(rng, last, temps), temps,
+                        sub)
                 first = self._sample_fn(rng, last, temps)
                 return first, cache
             fn = self._prefill_jit[(bucket, wave)] = jax.jit(
@@ -986,10 +1084,88 @@ class LLMEngine:
         return combined, (tokens, positions, temps, tables, rng,
                           *entries), cache
 
+    def _spec_block_fn(self, params, cache, state, admit_meta, admit_lasts,
+                       admit_tables):
+        """``_block_fn`` of a drafting engine (module docstring): a scan
+        of block_size steps of ONE OR TWO tokens a row.  The state holds
+        each row's draft and the logits it was drawn from besides;
+        ``admit_lasts`` is the installs' ``(last token, draft, logits)``.
+        A step: the stack over (last token at p, draft at p + 1), one
+        pass (``model_verify``: the paged kernel at two queries a row
+        writes both K/V rows); ``verify_draft``; the module over both
+        positions with the tokens now known to follow them (its second
+        entry is junk where the draft fell, and overwritten by the next
+        step's first, as the stack's row at p + 1 is); the next draft
+        from the module's logits at the row's new last position.  The
+        block's ONE fetch is ``[first | second | count]``, ``rows *
+        block_size`` each (then the expert load's two numbers): ``count``
+        1 or 2, ``second`` junk where it is 1."""
+        from ray_tpu.models.generate import verify_draft
+        tokens, positions, temps, tables, rng, drafts, q_logits = state
+        a_slots = admit_meta[0]
+        tokens, drafts, q_logits = (
+            old.at[a_slots].set(new) for old, new in zip(
+                (tokens, drafts, q_logits), admit_lasts))
+        positions = positions.at[a_slots].set(admit_meta[1])
+        temps = temps.at[a_slots].set(
+            admit_meta[2].astype(jnp.float32) / 1e6)
+        tables = tables.at[a_slots].set(admit_tables)
+        rng, sub = jax.random.split(rng)
+        keys = jax.random.split(sub, self.block_size)
+        live = tables[:, 0] != 0      # as in _block_fn
+        load = self._counts_expert_load
+        mutable = ["cache", "intermediates"] if load else ["cache"]
+        # the draft's position must exist: a row the host has not yet
+        # redirected stops one short of max_seq_len - 1
+        last_pos = self.cfg.max_seq_len - 2
+
+        def one(carry, key):
+            tokens, positions, drafts, q_logits, cache = carry
+            k_verify, k_draft = jax.random.split(key)
+            at = jnp.stack([positions, positions + 1], axis=1)
+            with jax.named_scope("spec_verify"):
+                (hidden, prenorm), mut = self.model_verify.apply(
+                    {"params": params, "cache": cache},
+                    jnp.stack([tokens, drafts], axis=1), at,
+                    block_tables=tables, return_hidden=True,
+                    return_prenorm=True, mutable=mutable)
+                logits = output_logits(self.cfg, params, hidden)
+            with jax.named_scope("spec_accept"):
+                n, first, second = verify_draft(
+                    k_verify, logits[:, 0], logits[:, 1], q_logits, drafts,
+                    temperature=temps, top_k=self.top_k, top_p=self.top_p)
+            with jax.named_scope("mtp_draft"):
+                drafted, mut2 = self.model_verify.apply(
+                    {"params": params, "cache": mut["cache"]},
+                    jnp.stack([first, second], axis=1), at,
+                    block_tables=tables, return_hidden=True,
+                    mtp_hidden=prenorm, mutable=mutable)
+                q_logits = output_logits(self.cfg, params, jnp.where(
+                    (n == 2)[:, None], drafted[:, 1], drafted[:, 0]))
+                drafts = self._sample_fn(k_draft, q_logits, temps)
+            tokens = jnp.where(n == 2, second, first)
+            positions = jnp.where(
+                live, jnp.minimum(positions + n, last_pos), 0)
+            out = (first, second, n)
+            if load:
+                out += (self._expert_load(
+                    (mut["intermediates"], mut2["intermediates"]), live),)
+            return (tokens, positions, drafts, q_logits,
+                    mut2["cache"]), out
+
+        (tokens, positions, drafts, q_logits, cache), out = jax.lax.scan(
+            one, (tokens, positions, drafts, q_logits, cache), keys)
+        combined = [a.T.reshape(-1) for a in out[:3]]
+        if load:
+            combined.append(jnp.stack([a.sum() for a in out[3]]))
+        return jnp.concatenate(combined), (
+            tokens, positions, temps, tables, rng, drafts, q_logits), cache
+
     def _expert_load(self, intermediates, live):
         """One decode step's expert load over the rows that hold a
         request, from the ``expert_idx`` every dropless expert layer
-        sows (``[.., rows, 1, k]``, stacked by the layer scan): int32
+        sows (``[.., rows, T, k]``, ``T`` 1 or a drafting step's 2,
+        stacked by the layer scan): int32
         ``(layer steps with a live row, experts touched summed over
         them)``.  On the v5e 2.3 us of a decode step of 11.4 ms
         (PERF.md, PR 26)."""
@@ -997,10 +1173,10 @@ class LLMEngine:
         # the id past them, which one_hot leaves out
         e = self.cfg.experts_here
         idx = jnp.concatenate([
-            leaf.reshape(-1, self._rows, leaf.shape[-1])
+            leaf.reshape(-1, self._rows, leaf.shape[-2] * leaf.shape[-1])
             for path, leaf in jax.tree_util.tree_leaves_with_path(
                 intermediates)
-            if "expert_idx" in jax.tree_util.keystr(path)])   # [L, rows, k]
+            if "expert_idx" in jax.tree_util.keystr(path)])  # [L, rows, Tk]
         counts = (jax.nn.one_hot(idx, e, dtype=jnp.int32)
                   * live[None, :, None, None].astype(jnp.int32)
                   ).sum(axis=(1, 2))                          # [L, E]
@@ -1042,7 +1218,7 @@ class LLMEngine:
         none = jnp.full((self.num_slots,), -1, jnp.int32)
         for wave in _WAVE_SIZES:
             self._install_firsts_jit(
-                self._no_admit[1], jnp.zeros((wave,), jnp.int32), none)
+                self._no_admit[1], self._wave_outs(wave), none)
         if burst:
             plen = max(prompt_lens)
 
@@ -1081,6 +1257,12 @@ class LLMEngine:
         return bucket + (3 if self._state_layers else 2)
 
     def _refuse_handoff(self) -> None:
+        if self._drafts:
+            raise ValueError(
+                "prefill handoff on a model that drafts with its own "
+                "prediction module: a PrefillHandoff carries the stack's "
+                "pages and the first token, not the module's pages, the "
+                "first draft and the logits it was drawn from")
         if self._state_layers:
             raise ValueError(
                 "prefill handoff on a model with recurrent "
@@ -1522,10 +1704,18 @@ class LLMEngine:
                 pool_row=self._pool_tail[0] * self._pool_tail[2])
 
     def _deliver_block(self, block, rows: list, ahead: _Ahead) -> None:
-        """Hand one fetched decode block's tokens to their requests,
-        truncating junk past each row's finish.  ``ahead`` is what the
-        device runs meanwhile, in front of the NEXT block: a look at it
-        after each row brackets the end of its waves."""
+        """Hand one fetched decode block's tokens to their requests, in
+        order, truncating junk past each row's finish.  ``block`` is
+        ``[rows, block_size]``, row i's k-th token the one step k gave
+        it; a drafting engine's ``[3, rows, block_size]``: each step's
+        first token, its second, and how many of the two the row emitted
+        (a request may end at the first of a pair; the second is then
+        junk like any token past the end).  A step is a step either way
+        (``steps``, the layer steps, the place of a request's last step
+        in the block's seconds); the tokens, rows written and pages read
+        are counted by what the delivered steps did.  ``ahead`` is what
+        the device runs meanwhile, in front of the NEXT block: a look at
+        it after each row brackets the end of its waves."""
         with self._phase("deliver_block", "deliver_s") as sp:
             st = self.stats
             st.steps += self.block_size
@@ -1534,13 +1724,21 @@ class LLMEngine:
             st.gdn_layer_steps += self.block_size * self._state_layers
             st.mla_layer_steps += self.block_size * self._latent_layers
             tokens0, done0 = st.step_tokens, st.requests_completed
+            drafts0 = st.drafts_proposed, st.drafts_accepted
             for i, req in rows:
                 sl = self._slots[i]
                 if sl is None or sl.request is not req:
                     continue      # evicted earlier (or reused): junk row
                 pos0, reason = sl.pos, None
-                for k in range(self.block_size):
-                    tok = int(block[i, k])
+                if self._drafts:
+                    # the row's tokens in order, and the step of each
+                    count = block[2, i]
+                    emitted = np.arange(2)[None, :] < count[:, None]
+                    toks = block[:2, i].T[emitted].tolist()
+                    step_of = np.repeat(np.arange(self.block_size), count)
+                else:
+                    toks = block[i].tolist()
+                for j, tok in enumerate(toks):
                     sl.out.append(tok)
                     sl.last_token = tok
                     sl.pos += 1
@@ -1551,13 +1749,26 @@ class LLMEngine:
                     reason = self._finish_reason(sl, self.cfg.max_seq_len)
                     if reason is not None:
                         break     # rest of the row is junk past eos
+                # the step that gave the last delivered token
+                k = int(step_of[j]) if self._drafts else j
                 if sl.stall_base is None:
                     # its first block: the waves ahead of it (its own,
                     # or those it waited behind for a slot) are not a
                     # stall between its tokens
                     sl.stall_base = self._stall_s
-                self._count_decode_pages(pos0 + 1, sl.pos)
-                st.decode_rows_written += (sl.pos - pos0) * self._pool_layers
+                if self._drafts:
+                    # steps 0 .. k, each over its row's position then
+                    # and the one after: two queries, two rows written
+                    stood = int(count[:k + 1].sum()) - (k + 1)
+                    st.drafts_proposed += k + 1
+                    st.drafts_accepted += stood
+                    self._count_verify_pages(
+                        pos0 + 2 + np.cumsum(count[:k + 1]) - count[:k + 1])
+                    st.decode_rows_written += 2 * (k + 1) * self._pool_layers
+                else:
+                    self._count_decode_pages(pos0 + 1, sl.pos)
+                    st.decode_rows_written += (
+                        sl.pos - pos0) * self._pool_layers
                 st.gdn_state_rows += (sl.pos - pos0) * self._state_layers
                 # steps at positions pos0 .. pos - 1 read pos0 + 1 .. pos
                 st.mla_context_tokens += self._latent_layers * (
@@ -1574,7 +1785,10 @@ class LLMEngine:
                     self._look(ahead)
             sp.set_metadata(block=st.quanta,
                             tokens=st.step_tokens - tokens0,
-                            finished=st.requests_completed - done0)
+                            finished=st.requests_completed - done0,
+                            **({"drafts": st.drafts_proposed - drafts0[0],
+                                "accepted": st.drafts_accepted - drafts0[1]}
+                               if self._drafts else {}))
 
     # ------------------------------------- where a block's interval went
     #
@@ -1643,10 +1857,12 @@ class LLMEngine:
 
     def _count_decode_pages(self, first: int, last: int) -> None:
         """``EngineStats.decode_pages_read`` and ``window_pages_*`` for
-        one row's delivered steps, which read ``first .. last``
-        positions: the page arithmetic of ``ops/paged_attention.py
-        _tpu_kernel`` (a step over ``n`` positions reads pages ``max(0,
-        n - window) // page_size`` to ``ceil(n / page_size)``, from page
+        one row's delivered steps of ONE query position each
+        (``_count_verify_pages`` counts a drafting engine's steps of
+        two), which read ``first .. last`` positions: the page
+        arithmetic of ``ops/paged_attention.py _tpu_kernel`` (a step
+        over ``n`` positions reads pages ``max(0, n - window) //
+        page_size`` to ``ceil(n / page_size)``, from page
         0 in a layer without a window) summed in closed form, once a row
         a block.  Host arithmetic on positions the loop already holds:
         it says what the kernel's loop bounds name, not that the kernel
@@ -1659,14 +1875,33 @@ class LLMEngine:
             return ps * q * (q - 1) // 2 + q * (r + 1) if n > 0 else 0
 
         by_length = floors(last + ps - 1) - floors(first + ps - 2)
+        self._add_pages(by_length,
+                        floors(last - w) - floors(max(first, w) - w - 1)
+                        if self._window_layers else 0)
+
+    def _add_pages(self, by_length: int, skipped: int) -> None:
+        """Pages one row's delivered steps read, to ``EngineStats``:
+        ``by_length`` a pool layer by the row's lengths, of which a
+        layer with a window left ``skipped`` unread."""
         windowed = self._window_layers
-        skipped = (floors(last - w) - floors(max(first, w) - w - 1)
-                   if windowed else 0)
         st = self.stats
         st.window_pages_skipped += skipped * windowed
         st.window_pages_read += (by_length - skipped) * windowed
         st.decode_pages_read += (by_length * self._pool_layers
                                  - skipped * windowed)
+
+    def _count_verify_pages(self, lengths) -> None:
+        """``_count_decode_pages`` for a drafting engine's delivered
+        steps of one row: ``lengths`` [steps] are the positions each
+        step's LAST query saw (its row's position then, + 2).  The
+        kernel at two queries a row reads pages ``max(0, n - 1 - window)
+        // page_size`` to ``ceil(n / page_size)``: the window counts
+        from the FIRST query's position."""
+        ps, w = self.page_size, self.cfg.sliding_window
+        self._add_pages(
+            int((-(-lengths // ps)).sum()),
+            int((np.maximum(lengths - 1 - w, 0) // ps).sum())
+            if self._window_layers else 0)
 
     # ------------------------------------------------- prompt-prefix cache
     #
@@ -2080,6 +2315,10 @@ class LLMEngine:
                 bucket, wave)(self.params, self._cache,
                               jnp.asarray(packed),
                               jnp.asarray(tables), self._next_key())
+            if self._drafts:
+                outs, firsts = firsts, firsts[0]    # + drafts, their logits
+                for r, pf in enumerate(metas):
+                    pf.drafted = (outs, r)
             computed = prefill_positions(
                 bucket, max(len(req.prompt) for req, _ in chunk)
             ) if self._skips_pad(bucket) else bucket
@@ -2339,10 +2578,12 @@ class LLMEngine:
             meta[2, n] = int(sl.request.temperature * 1e6)
             if self._state_layers:
                 meta[3, n] = sl.request.entry
-            if pf.source is None:
+            if pf.source is None and pf.drafted is None:
                 lasts[n] = sl.last_token
             else:
-                firsts, row = pf.source
+                # a drafting engine's install takes all it needs from
+                # the wave's outputs, fetched or not
+                firsts, row = pf.drafted or pf.source
                 _, rows = from_waves.setdefault(
                     id(firsts), (firsts, np.full((A,), -1, np.int32)))
                 rows[n] = row
@@ -2357,6 +2598,8 @@ class LLMEngine:
                 self._stale_slots.discard(slot)
         if n:
             lasts = jnp.asarray(lasts)
+            if self._drafts:    # a redirect row's draft and logits: zeros
+                lasts = (lasts,) + self._no_admit[1][1:]
             for firsts, rows in from_waves.values():
                 lasts = self._install_firsts_jit(lasts, firsts,
                                                  jnp.asarray(rows))
@@ -2389,5 +2632,8 @@ class LLMEngine:
             host, (steps, touched) = host[:-2], host[-2:]
             st.moe_layer_steps += int(steps)
             st.moe_experts_touched += int(touched)
-        self._deliver_block(host.reshape(self._rows, self.block_size),
+        # a drafting engine's block: [first | second | count]
+        self._deliver_block(host.reshape(-1, self._rows, self.block_size)
+                            if self._drafts
+                            else host.reshape(self._rows, self.block_size),
                             rows, nxt_ahead)

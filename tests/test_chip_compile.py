@@ -850,3 +850,124 @@ def test_nemotron_cell_fits_the_chip(nemotron_programs):
         assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
                 + block.temp_size_in_bytes) < 16.0e9, (
             bucket, prefill.temp_size_in_bytes / 1e9)
+
+
+# ---- a step that verifies a draft: two query positions a row, and the
+# ---- model that drafts for itself (ISSUE 46)
+
+@pytest.mark.parametrize("windowed", [True, False], ids=["window", "global"])
+def test_paged_decode_at_two_queries_a_row(topo, one_chip, windowed):
+    """K-EXAONE's verify shape (64 query heads on 8 KV heads of 128, two
+    positions a row: 16 rows of the MXU tile a KV head) is still ONE
+    custom call ``paged_attention_decode`` that writes both rows into
+    the pool it is given, aliased."""
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    rows, heads, kvh, hd, mp = 33, 64, 8, 128, 64
+    ps, pages, layers = 64, 129, 7
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(
+        lambda q, kv, bt, ln, layer, window, live, new: paged_attention(
+            q, kv, bt, ln, layer=layer, impl="tpu", new_rows=new, live=live,
+            window=window if windowed else None), donate_argnums=(1,)
+    ).lower(sds((rows, 2, heads, hd), jnp.bfloat16),
+            sds((layers, pages, kvh, ps, 2 * hd), jnp.bfloat16),
+            sds((rows, mp), jnp.int32), sds((rows,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32), sds((rows,), jnp.bool_),
+            sds((rows, 2, kvh, 2 * hd), jnp.bfloat16))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
+    hlo = lowered.compile().as_text()
+    assert "input_output_alias={ {1}: (1, {}, may-alias) }" in hlo
+    made = _pool_result_producers(hlo, (layers * pages * kvh * ps * 2 * hd,))
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast"}, made
+
+
+@pytest.fixture(scope="module")
+def k_exaone_programs(topo, one_chip):
+    """``engine_decode_block`` (a drafting engine's: verify, accept,
+    draft) and the widest prefill wave the serve-think cell may form
+    under ``prefill_wave_tokens`` 4096 (4 x 1024) of K-EXAONE-236B-A23B
+    at the cell's cut and server: the dense layer and five expert layers
+    at the published widths, 16 of 128 experts a layer held, an eighth
+    of the vocabulary, the module, 32 slots, 1,400 pages in 7 pool
+    layers.  Compiled once for the tests below."""
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("k-exaone-236b-a23b", n_layers=6, vocab_size=19200,
+                     moe_experts_held=16, dtype=jnp.bfloat16,
+                     param_dtype=jnp.bfloat16)
+    patch = pytest.MonkeyPatch()
+    _answer_tpu(patch)
+    try:
+        eng = _described_engine(cfg, patch, max_seq_len=4096,
+                                max_prompt_len=1024, kv_pool_pages=1400,
+                                prefill_wave_tokens=4096)
+        block = eng._block_jit.lower(
+            *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                     one_chip))
+        prefill = eng._get_prefill_paged(1024, 4).lower(
+            *_shapes((eng.params, eng._cache,
+                      jnp.zeros((4, eng.packed_width(1024)), jnp.int32),
+                      jnp.zeros((4, eng.max_pages), jnp.int32),
+                      jax.random.PRNGKey(0)), one_chip))
+        return {"eng": eng, "block_text": block.as_text(),
+                "engine_decode_block": block.compile(),
+                "engine_prefill": prefill.compile()}
+    finally:
+        patch.undo()
+
+
+def test_k_exaone_engine_programs_compile_with_their_kernels(
+        k_exaone_programs):
+    """The decode block holds the paged kernel three times (the dense
+    layer, the scanned expert layers' ONE traced body, the module) and
+    ``moe_experts_decode`` twice (the scanned stack's ``[5, 16, ...]``
+    held experts, the module's stack of one): 66 step rows at top-8 are
+    528 pairs, past ``DENSE_PAIRS_MAX``, but a small batch by rows.  The
+    state carries each row's draft and its logits."""
+    import re
+    p = k_exaone_programs
+    eng = p["eng"]
+    assert {k: v.shape for k, v in eng._cache.items()} == {
+        "kv_pages": (7, 1400, 8, 64, 256)}
+    assert [a.shape for a in eng._state[-2:]] == [(33,), (33, 19200)]
+    text = p["block_text"]
+    assert text.count('kernel_name = "paged_attention_decode"') == 3
+    assert text.count('kernel_name = "moe_experts_decode"') == 2
+    assert "@jit_engine_decode_block" in text
+    hlo = p["engine_decode_block"].as_text()
+    for scope in ("spec_verify", "spec_accept", "mtp_draft"):
+        assert f"/{scope}/" in hlo, scope   # in the operations' metadata
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*%?moe_experts_decode\S* = ", line)]
+    assert sorted(call.count("bf16[5,16,6144,2048]") for call in calls) \
+        == [0, 2]                                   # w_gate, w_up
+    assert sorted(call.count("bf16[1,16,6144,2048]") for call in calls) \
+        == [0, 2]
+    for name in ("engine_decode_block", "engine_prefill"):
+        made = _pool_result_producers(p[name].as_text(),
+                                      (1400 * 8 * 64 * 256,
+                                       7 * 1400 * 8 * 64 * 256))
+        assert set(made) <= _IN_PLACE, f"{name}: pool-sized {dict(made)}"
+    # (the module's own experts are that size: parameters, bitcast to
+    # their stack of one)
+    made = _pool_result_producers(hlo, (16 * 6144 * 2048,))
+    assert set(made) <= _IN_PLACE, f"a layer's experts from {dict(made)}"
+
+
+def test_k_exaone_cell_fits_the_chip(k_exaone_programs):
+    """13.2 GB resident (weights 10.60, pool 2.57), arguments and
+    temporaries of the decode block and of the widest prefill wave under
+    15.5 GB: the check's float32 reference runs beside them."""
+    p = k_exaone_programs
+    block = p["engine_decode_block"].memory_analysis()
+    assert 13.0e9 < block.argument_size_in_bytes < 13.3e9
+    assert (block.argument_size_in_bytes + block.temp_size_in_bytes) < 15e9
+    prefill = p["engine_prefill"].memory_analysis()
+    assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
+            ) < 15.5e9, prefill.temp_size_in_bytes / 1e9

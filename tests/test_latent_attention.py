@@ -132,13 +132,18 @@ def test_the_router_scores_choose_with_the_bias_and_gate_without_it():
 
 
 @pytest.mark.parametrize("tokens", [5, 200], ids=["small-pairs", "grouped"])
-def test_the_eight_shares_add_up_to_the_uncut_layer(tokens):
+@pytest.mark.parametrize("preset, layer", [(PRESET, None),
+                                           ("tiny-k-exaone", 1)],
+                         ids=["kanana", "k-exaone"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(tokens, preset, layer):
     """Expert parallelism as the chips see it: 8 chips hold one expert
     each of a layer's 8.  Each routes over all 8 and computes its own
     pairs; the routed parts of the 8 shares, plus the attention and the
     shared expert counted ONCE, are the uncut layer's output.  (The
     attention and the shared expert are in every share's block; ``base``
-    is the block with the routed sum's down-projections zeroed.)"""
+    is the block with the routed sum's down-projections zeroed.)  For
+    Kanana's block (latent attention, two shared experts) and K-EXAONE's
+    (GQA under a window, one shared; its blocks are told their layer)."""
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -146,16 +151,17 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(tokens):
     from ray_tpu.models import get_config
     from ray_tpu.models.gpt import Block
     from ray_tpu.ops.layers import rope_frequencies
-    cfg = get_config(PRESET, scan_layers=False)
+    cfg = get_config(preset, scan_layers=False)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, tokens // 2 + 1, 64))
     cos, sin = rope_frequencies(cfg.rope_dim, cfg.max_seq_len, cfg.rope_theta)
-    params = nn.unbox(Block(cfg).init(jax.random.PRNGKey(1), x, cos, sin)
-                      ["params"])
-    uncut = Block(cfg).apply({"params": params}, x, cos, sin)
+    at = {"layer": layer}
+    params = nn.unbox(Block(cfg).init(jax.random.PRNGKey(1), x, cos, sin,
+                                      **at)["params"])
+    uncut = Block(cfg).apply({"params": params}, x, cos, sin, **at)
     zeroed = jax.tree_util.tree_map_with_path(
         lambda path, a: a * 0 if "['moe']['w_down']" in
         jax.tree_util.keystr(path) else a, params)
-    base = Block(cfg).apply({"params": zeroed}, x, cos, sin)
+    base = Block(cfg).apply({"params": zeroed}, x, cos, sin, **at)
     routed, counted = 0.0, 0
     for chip in range(8):
         share = dataclasses.replace(cfg, moe_experts_held=1,
@@ -164,7 +170,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(tokens):
             lambda path, a: a[chip:chip + 1] if "['moe']['w_" in
             jax.tree_util.keystr(path) else a, params)
         out, mut = Block(share).apply({"params": mine}, x, cos, sin,
-                                      mutable=["intermediates"])
+                                      mutable=["intermediates"], **at)
         routed = routed + (out - base)
         idx = np.asarray(mut["intermediates"]["moe"]["expert_idx"][0])
         counted += int((idx == 0).sum())       # pairs computed here
