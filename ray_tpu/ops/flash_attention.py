@@ -1,8 +1,8 @@
 """Flash attention as a Pallas TPU kernel (forward + backward).
 
-Blockwise online-softmax attention in three kernels, ``flash_fwd``,
-``flash_bwd_dq`` and ``flash_bwd_dkv``, that pay for the triangle a
-causal call needs.  Two levels of blocks:
+Blockwise online-softmax attention in two kernels, ``flash_fwd`` and
+``flash_bwd_dqkv``, that pay for the triangle a causal call needs and
+visit each tile pair once.  Two levels of blocks:
 
   - The GRID walks spans of the two sequences (up to 1024 positions
     each): the reduction axis is innermost and ``"arbitrary"``, operands
@@ -17,35 +17,49 @@ causal call needs.  Two levels of blocks:
     pair the tiles the mask covers whole are left out by plain
     arithmetic, the ``iota``/compare/select mask is built only on the
     tiles the diagonal crosses, and a span pair below the diagonal
-    builds none.  (One pair a grid step was tried first: a step's fixed
-    cost and its statistics outweigh a 256 x 256 tile's work; PERF.md
-    section 6, PR 34.)
+    builds none.  Adjacent tiles of a kind go to the MXU as one strip.
+    (One pair a grid step was tried first: a step's fixed cost and its
+    statistics outweigh a 256 x 256 tile's work; PERF.md section 6,
+    PR 34.)
 
 Every tile is computed TRANSPOSED, ``k @ q^T``: keys on sublanes,
 queries on lanes.  The softmax statistics, lse and delta are then
 lane-dense ``[1, block_q]`` rows, reductions over keys run down
 sublanes, and none of the second products needs a score tile
-transposed: the forward accumulates ``o^T += v^T @ p^T`` and dq
-``dq^T += k^T @ ds^T`` (``v^T`` / ``k^T`` made once a span, the
-``[head_dim, span]`` accumulator turned once at the end), dkv
-``dv += p^T @ dO`` and ``dk += ds^T @ q`` as they stand.  lse and delta
+transposed: the forward accumulates ``o^T += v^T @ p^T`` (``v^T`` made
+once a span, the ``[head_dim, span]`` accumulator turned once at the
+end), and the backward makes ``s^T``, ``p^T``, ``dp^T = v @ dO^T`` and
+``ds^T`` ONCE a tile and feeds all three gradients from them:
+``dv += p^T @ dO`` and ``dk += ds^T @ q`` as they stand,
+``dq^T += k^T @ ds^T`` with ``k^T`` made once a kv strip.  lse and delta
 travel as ``[batch*heads, 1, q_len]``: a trailing axis of 1 pads to 128
 lanes in HBM as in VMEM.
+
+What the backward keeps in scratch: under grid (bh, kv span, q span) the
+kv side is resident, dk and dv ``[span_k, head_dim]`` float32 sum over a
+kv span's q spans and are written at the last; dq^T of the head's WHOLE
+q sequence, ``[q spans, head_dim, span_q]`` float32, sums across the kv
+spans, and every visit writes its q span's dq block from the sum so far,
+the last visit (the diagonal span under ``causal``) leaving the whole
+sum.  That accumulator is what bounds the backward: ``plan_blocks``
+holds it to ``DQ_ACC_BYTES`` (8,192 positions at heads of 128), and a
+gradient asked for beyond it is refused by the kernel's name.
 
 Operands reach the MXU in the dtype they arrive in (bf16 stays bf16),
 products accumulate in float32; the probabilities and ``ds`` are cast to
 the operands' dtype before the second products.  ``m``, ``l``, lse,
 delta, the scores and the accumulators are float32.  The softmax scale
-is folded into the tile's resident operand (q; k in ``flash_bwd_dkv``)
-where that is exact — ``head_dim ** -0.5`` a power of two — and applied
-to the float32 scores otherwise.
+is folded into the tile's resident operand (q in ``flash_fwd``, k in
+``flash_bwd_dqkv``, where dq then comes out scaled) where that is exact
+— ``head_dim ** -0.5`` a power of two — and applied to the float32
+scores otherwise.
 
 Spans and tiles come from ``plan_blocks``, a function of the sequence
-lengths (the probe that chose its targets read the same ones fastest at
+lengths (the probes that chose its targets read the same ones fastest at
 both head sizes and operand dtypes), which also returns the share of
-tile pairs a causal call visits.  A sequence that no
-lane-aligned block divides (ViT's 197) runs as one tile, the whole
-sequence.
+tile pairs a causal call visits and whether the backward's accumulator
+fits.  A sequence that no lane-aligned block divides (ViT's 197) runs as
+one tile, the whole sequence.
 
 On the CPU backend the same kernels run under ``interpret=True`` so unit
 tests exercise the identical code path (SURVEY.md §4 device-simulation
@@ -108,9 +122,11 @@ class Tiling(NamedTuple):
 
 
 class FlashPlan(NamedTuple):
+    """``fused``: the backward's dq^T accumulator fits ``DQ_ACC_BYTES``;
+    a call beyond it has a forward and no backward."""
     fwd: Tiling
-    dq: Tiling
-    dkv: Tiling
+    bwd: Tiling
+    fused: bool
 
 
 def _aligned_block(seq: int, target: int, step: int = LANES) -> int:
@@ -143,37 +159,44 @@ def _tiling(q_len: int, kv_len: int, block_q: int, block_k: int,
 
 def plan_blocks(q_len: int, kv_len: int, causal: bool,
                 block_q: Optional[int] = None,
-                block_k: Optional[int] = None) -> FlashPlan:
-    """Spans and score tiles of the three kernels from what the call can
-    observe, and the share of tile pairs it visits.
+                block_k: Optional[int] = None, *,
+                head_dim: int) -> FlashPlan:
+    """Spans and score tiles of the two kernels from what the call can
+    observe, the share of tile pairs it visits, and whether its backward
+    is the fused kernel's to run.
 
     The tiles are the largest lane-aligned divisors of the sequences up
-    to the targets below, the spans likewise up to ``SPAN``.  A probe of
-    the kernels alone on a v5e (PERF.md section 6, PR 34) read the same
-    targets fastest at ``head_dim`` 64 and 128, bf16 and float32,
-    sequences of 1,024 to 4,096, causal and not, so the lengths are all
-    the plan reads.  ``block_q`` / ``block_k`` given explicitly (tests)
-    are the score tile of all three kernels, cut to a divisor of the
-    sequence as before.
+    to the targets below, the spans likewise up to ``SPAN``.  Probes of
+    the kernels alone on a v5e (PERF.md section 6, PRs 34 and 47) read
+    the same targets fastest at ``head_dim`` 64 and 128, bf16 and
+    float32, sequences of 1,024 to 4,096, causal and not, so the lengths
+    are all the tiles read.  ``block_q`` / ``block_k`` given explicitly
+    (tests) are the score tile of both kernels, cut to a divisor of the
+    sequence as before.  ``fused`` reads ``q_len`` and ``head_dim``: the
+    backward holds dq^T of a head's whole q sequence in float32.
     """
+    fused = 4 * q_len * head_dim <= DQ_ACC_BYTES
     if block_q is not None or block_k is not None:
         t = _tiling(q_len, kv_len, _pick_block(q_len, block_q or q_len),
                     _pick_block(kv_len, block_k or kv_len), causal)
-        return FlashPlan(t, t, t)
+        return FlashPlan(t, t, fused)
     return FlashPlan(*(
         _tiling(q_len, kv_len, _aligned_block(q_len, tq),
                 _aligned_block(kv_len, tk), causal)
-        for tq, tk in _TARGETS))
+        for tq, tk in _TARGETS), fused)
 
 
 # a span's operands are whole in VMEM
 SPAN = 1024
-# (block_q, block_k) targets of fwd, dq, dkv
-_TARGETS = ((512, 512), (512, 512), (128, 128))
+# (block_q, block_k) targets of fwd, bwd
+_TARGETS = ((512, 512), (256, 256))
+# what the backward may keep in VMEM as dq^T [head_dim, q_len] float32
+# across a head's kv spans: 8,192 positions at heads of 128
+DQ_ACC_BYTES = 4 * 2 ** 20
 
 
 # --------------------------------------------------------------------------- #
-# Shared pieces of the three bodies                                           #
+# Shared pieces of the two bodies                                             #
 # --------------------------------------------------------------------------- #
 
 def _scale_is_exact(sm_scale: float) -> bool:
@@ -194,23 +217,21 @@ def _tile_pairs(span_q: int, span_k: int, block_q: int, block_k: int,
             if not diagonal or b * block_k <= a * block_q + block_q - 1]
 
 
-def _strips(pairs, axis: int, sizes, join: bool):
+def _strips(pairs, axis: int, sizes):
     """``_tile_pairs`` grouped by their q tile (``axis`` 0) or kv tile
-    (1): [(outer slice, [(inner slice, masked)])].  ``join`` makes one
-    strip of adjacent inner tiles of a kind, so an outer tile meets at
-    most one strip the diagonal crosses and one it does not."""
+    (1): [(outer slice, [(inner slice, masked)])], adjacent inner tiles
+    of a kind as one strip, so an outer tile meets at most one strip the
+    diagonal crosses and one it does not."""
     size, inner = sizes[axis], sizes[1 - axis]
     out = []
     for i, group in itertools.groupby(
             sorted(pairs, key=lambda pair: pair[axis]),
             key=lambda pair: pair[axis]):
         strips = []
-        for _, run in itertools.groupby(
-                group, key=lambda pair: pair[2] if join else pair):
+        for masked, run in itertools.groupby(group, key=lambda pair: pair[2]):
             run = list(run)
             strips.append((slice(run[0][1 - axis] * inner,
-                                 (run[-1][1 - axis] + 1) * inner),
-                           run[0][2]))
+                                 (run[-1][1 - axis] + 1) * inner), masked))
         out.append((slice(i * size, (i + 1) * size), strips))
     return out
 
@@ -271,7 +292,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     def body(diagonal: bool):
         pairs = _tile_pairs(span_q, span_k, block_q, block_k, diagonal)
         vt = v_ref[0].T                                     # [d, span_k]
-        for rows, strips in _strips(pairs, 0, (block_q, block_k), True):
+        for rows, strips in _strips(pairs, 0, (block_q, block_k)):
             q = q_ref[0, rows, :]
             if fold:
                 q = q * sm_scale
@@ -403,52 +424,17 @@ def _fwd(q3, k3, v3, causal: bool, sm_scale: float, t: Tiling,
 # Backward                                                                    #
 # --------------------------------------------------------------------------- #
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, sm_scale: float, causal: bool, spans: int,
-                   block_q: int, block_k: int):
-    span_q, span_k = q_ref.shape[1], k_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    fold = _scale_is_exact(sm_scale)
-
-    @pl.when(ki == 0)
-    def _():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    def body(diagonal: bool):
-        pairs = _tile_pairs(span_q, span_k, block_q, block_k, diagonal)
-        kt = k_ref[0].T                                     # [d, span_k]
-        for rows, strips in _strips(pairs, 0, (block_q, block_k), True):
-            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
-            if fold:
-                q = q * sm_scale
-            lse, delta = lse_ref[0, :, rows], delta_ref[0, :, rows]
-            dqt = acc_ref[:, rows]
-            for cols, masked in strips:
-                st = _scores_t(k_ref[0, cols, :], q, rows, cols, masked,
-                               None if fold else sm_scale)
-                pt = jnp.exp(st - lse)
-                dpt = jax.lax.dot_general(v_ref[0, cols, :], do, _NT,
-                                          preferred_element_type=jnp.float32)
-                dst = pt * (dpt - delta)
-                dqt = dqt + jax.lax.dot(kt[:, cols], dst.astype(kt.dtype),
-                                        preferred_element_type=jnp.float32)
-            acc_ref[:, rows] = dqt
-
-    _on_spans(causal, spans, qi, ki, body)
-
-    @pl.when(ki == _last_span(causal, qi))
-    def _():
-        # ds was left unscaled and k is as it came: dq = scale * ds @ k
-        dq_ref[0] = (acc_ref[...] * sm_scale).T.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    sm_scale: float, causal: bool, spans: int,
-                    block_q: int, block_k: int):
-    """Grid (bh, kv span, q span): the kv side is resident."""
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                sm_scale: float, causal: bool, spans: int,
+                block_q: int, block_k: int):
+    """Grid (bh, kv span, q span): the kv side is resident, dk and dv
+    accumulate over a kv span's q spans; ``dq_acc`` [q spans, d, span_q]
+    holds dq^T of the head's WHOLE q sequence across the kv spans."""
     span_k, span_q = k_ref.shape[1], q_ref.shape[1]
     ki, qi = pl.program_id(1), pl.program_id(2)
+    # one q span (the training cell): a static index, no address arithmetic
+    q_at = qi if dq_acc.shape[0] > 1 else 0
     fold = _scale_is_exact(sm_scale)
 
     @pl.when(qi == (ki if causal else 0))
@@ -456,12 +442,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[q_at] = jnp.zeros(dq_acc.shape[1:], jnp.float32)
+
     def body(diagonal: bool):
         pairs = _tile_pairs(span_q, span_k, block_q, block_k, diagonal)
-        for cols, tiles in _strips(pairs, 1, (block_q, block_k), False):
+        for cols, tiles in _strips(pairs, 1, (block_q, block_k)):
             k, v = k_ref[0, cols, :], v_ref[0, cols, :]
             if fold:
                 k = k * sm_scale
+            kt = k.T                                        # [d, block_k]
             dk, dv = dk_acc[cols, :], dv_acc[cols, :]
             for rows, masked in tiles:
                 q, do = q_ref[0, rows, :], do_ref[0, rows, :]
@@ -472,12 +463,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                       preferred_element_type=jnp.float32)
                 dpt = jax.lax.dot_general(v, do, _NT,
                                           preferred_element_type=jnp.float32)
-                dst = pt * (dpt - delta_ref[0, :, rows])
-                dk = dk + jax.lax.dot(dst.astype(q.dtype), q,
+                dst = (pt * (dpt - delta_ref[0, :, rows])).astype(q.dtype)
+                dk = dk + jax.lax.dot(dst, q,
                                       preferred_element_type=jnp.float32)
+                dq_acc[q_at, :, rows] += jax.lax.dot(
+                    kt, dst, preferred_element_type=jnp.float32)
             dk_acc[cols, :], dv_acc[cols, :] = dk, dv
 
     _on_spans(causal, spans, qi, ki, body)
+
+    # every visit writes the q span's block from the sum so far: the last
+    # one (the diagonal's under ``causal``, the last kv span's otherwise)
+    # leaves the whole sum.  ds was left unscaled: dq = scale * ds @ k,
+    # the scale in k already where it folds
+    @pl.when(ki <= qi if causal else True)
+    def _():
+        dqt = dq_acc[q_at]
+        if not fold:
+            dqt = dqt * sm_scale
+        dq_ref[0] = dqt.T.astype(dq_ref.dtype)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _():
@@ -486,32 +490,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_dq(q3, k3, v3, do3, lse, delta, causal: bool, sm_scale: float,
-            t: Tiling, interpret: bool):
+def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
+         plan: FlashPlan, interpret: bool):
     bh, q_len, d = q3.shape
     kv_len = k3.shape[1]
-    qspec, kspec, row = _specs(t, d, causal)
-    return pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          spans=q_len // t.span_q,
-                          block_q=t.block_q, block_k=t.block_k),
-        grid=(bh, q_len // t.span_q, kv_len // t.span_k),
-        in_specs=[qspec, kspec, kspec, qspec, row, row],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, q_len, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((d, t.span_q), jnp.float32)],   # dq^T
-        compiler_params=_SEMANTICS,
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(q3, k3, v3, do3, lse, delta)
-
-
-def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal: bool, sm_scale: float,
-             t: Tiling, interpret: bool):
-    bh, q_len, d = q3.shape
-    kv_len = k3.shape[1]
+    if not plan.fused:
+        raise NotImplementedError(
+            f"flash_bwd_dqkv keeps dq^T of a head's whole q sequence in "
+            f"VMEM: {q_len} x {d} float32 is {4 * q_len * d} bytes, over "
+            f"DQ_ACC_BYTES = {DQ_ACC_BYTES}")
+    t = plan.bwd
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1)[:, None, :]                    # as lse
     # under grid (bh, kv span, q span) a pair above the diagonal names
-    # the diagonal's q span, so nothing is fetched for it
+    # the diagonal's q span, so nothing is fetched for it (nor written:
+    # the diagonal's visit fills the dq block it names)
     q_span = (lambda i, j: jnp.maximum(i, j)) if causal else (lambda i, j: i)
     qspec = pl.BlockSpec((1, t.span_q, d),
                          lambda b, j, i: (b, q_span(i, j), 0))
@@ -519,33 +512,26 @@ def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal: bool, sm_scale: float,
                        lambda b, j, i: (b, 0, q_span(i, j)))
     kspec = pl.BlockSpec((1, t.span_k, d), lambda b, j, i: (b, j, 0))
     return pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
                           spans=q_len // t.span_q,
                           block_q=t.block_q, block_k=t.block_k),
         grid=(bh, kv_len // t.span_k, q_len // t.span_q),
         in_specs=[qspec, kspec, kspec, qspec, row, row],
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((bh, kv_len, d), k3.dtype),
-                   jax.ShapeDtypeStruct((bh, kv_len, d), v3.dtype)],
+        out_specs=[qspec, kspec, kspec],
+        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                   jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
         scratch_shapes=[
+            pltpu.VMEM((q_len // t.span_q, d, t.span_q), jnp.float32),  # dq^T
             pltpu.VMEM((t.span_k, d), jnp.float32),    # dk
             pltpu.VMEM((t.span_k, d), jnp.float32),    # dv
         ],
-        compiler_params=_SEMANTICS,
+        # dq^T sums over the kv spans, dk and dv over the q spans
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dqkv",
     )(q3, k3, v3, do3, lse, delta)
-
-
-def _bwd(q3, k3, v3, o3, lse, do3, causal: bool, sm_scale: float,
-         plan: FlashPlan, interpret: bool):
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)[:, None, :]                    # as lse
-    dq = _bwd_dq(q3, k3, v3, do3, lse, delta, causal, sm_scale, plan.dq,
-                 interpret)
-    dk, dv = _bwd_dkv(q3, k3, v3, do3, lse, delta, causal, sm_scale,
-                      plan.dkv, interpret)
-    return dq, dk, dv
 
 
 # --------------------------------------------------------------------------- #
@@ -615,7 +601,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          "causal mask a real query would still see the "
                          "keys past its row's length")
     scale = sm_scale if sm_scale is not None else d ** -0.5
-    plan = plan_blocks(q_len, kv_len, causal, block_q, block_k)
+    plan = plan_blocks(q_len, kv_len, causal, block_q, block_k, head_dim=d)
     if interpret is None:
         interpret = _interpret()
 

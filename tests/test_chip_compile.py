@@ -86,32 +86,41 @@ def test_flash_fwd_bwd_gpt_small_widths(topo, one_chip):
                                sharding=one_chip)
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         qkv, qkv, qkv)
-    # forward + dq + dkv kernels
-    assert lowered.as_text().count("tpu_custom_call") >= 3
+    # the forward and the one backward kernel
+    assert lowered.as_text().count("tpu_custom_call") >= 2
     lowered.compile()
 
 
 # the training cell's call (SmolLM2-360M, 16 x 1024), then what the
 # whole-sequence operands of the old backward refused from sequence 2048
-# (RESOURCE_EXHAUSTED): 4 x 4096 at heads of 64, 2 x 2048 at heads of 128
-@pytest.mark.parametrize("shape", [(16, 1024, 15, 64), (4, 4096, 15, 64),
-                                   (2, 2048, 28, 128)],
-                         ids=lambda s: "x".join(map(str, s)))
-def test_flash_fwd_bwd_compiles_in_blocks(topo, one_chip, shape):
+# (RESOURCE_EXHAUSTED): 4 x 4096 at heads of 64, 2 x 2048 at heads of
+# 128; then the backward's budget's edge (dq^T of 8192 x 128 in scratch
+# across eight kv spans) and the float32 backward at the cell's lengths
+@pytest.mark.parametrize("shape,dtype", [
+    ((16, 1024, 15, 64), jnp.bfloat16), ((4, 4096, 15, 64), jnp.bfloat16),
+    ((2, 2048, 28, 128), jnp.bfloat16), ((1, 8192, 4, 128), jnp.bfloat16),
+    ((16, 1024, 15, 64), jnp.float32)],
+    ids=lambda s: "x".join(map(str, s)) if isinstance(s, tuple)
+    else jnp.dtype(s).name)
+def test_flash_fwd_bwd_compiles_in_blocks(topo, one_chip, shape, dtype):
     from ray_tpu.ops.flash_attention import flash_attention, plan_blocks
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True,
                                interpret=False).astype(jnp.float32).sum()
 
-    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         qkv, qkv, qkv)
     hlo = lowered.as_text()
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert name in hlo, name
+    # one visit a tile pair: the fused backward and no second one
+    for name in ("flash_fwd", "flash_bwd_dqkv"):
+        assert f'kernel_name = "{name}"' in hlo, name
+    assert "flash_bwd_dkv" not in hlo
+    plan = plan_blocks(shape[1], shape[1], True, head_dim=shape[3])
+    assert plan.fused
     # several blocks a sequence, so causal skipping has pairs to skip
-    for t in plan_blocks(shape[1], shape[1], True):
+    for t in (plan.fwd, plan.bwd):
         assert t.visited < t.total
     lowered.compile()
 
@@ -253,8 +262,10 @@ def test_gpt_small_train_step_one_chip(topo, on_tpu):
 
 def test_train_grad_names_its_program_and_flash_kernels(topo, on_tpu):
     """What a profiler trace finds the step by (ISSUE 24): the program
-    is ``train_grad`` and each of the three Pallas calls carries its own
-    name — a reader matches ``flash_bwd_dkv``, not a result type."""
+    is ``train_grad`` and each of the two Pallas calls carries its own
+    name — a reader matches ``flash_bwd_dq``, which ``flash_bwd_dqkv``
+    holds, not a result type.  One visit a tile pair: no second
+    backward kernel is in the program."""
     from ray_tpu.train.sharded.layout import ShardingConfig
 
     _, grad_fn, apply_fn, state, tokens = _train_step_programs(
@@ -262,8 +273,9 @@ def test_train_grad_names_its_program_and_flash_kernels(topo, on_tpu):
         overrides={"attention_impl": "flash"})
     text = grad_fn.lower(state, tokens).as_text()
     assert "@jit_train_grad" in text
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for name in ("flash_fwd", "flash_bwd_dqkv"):
         assert f'kernel_name = "{name}"' in text
+    assert "flash_bwd_dkv" not in text
     grads = jax.eval_shape(grad_fn, state, tokens)[0]
     assert "@jit_train_apply" in apply_fn.lower(state, grads).as_text()
 

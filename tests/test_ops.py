@@ -52,10 +52,12 @@ def _flash_and_xla_with_grads(q, k, v, do, causal, **blocks):
 # output's cast), each of a value no larger than the result's largest:
 # 2**-6 of the reference's largest magnitude is four such roundings.
 _F32_TOL = dict(o=2e-5, grad=2e-4)
-# (dtype, sequence, head_dim, causal, block keywords, span): ``span``
-# None is the module's own (1024: one span holds these sequences, tiles
-# are skipped and masked inside it); 128 makes the GRID walk several
-# spans, so whole span pairs are skipped and their index maps clamped
+# (dtype, sequence or (q_len, kv_len), head_dim, causal, block keywords,
+# span): ``span`` None is the module's own (1024: one span holds these
+# sequences, tiles are skipped and masked inside it); 128 makes the GRID
+# walk several spans, so whole span pairs are skipped and their index
+# maps clamped, and the backward's dq sums over several kv spans while
+# its dk and dv sum over several q spans
 _FLASH_CASES = {
     # several tiles, block_q != block_k, both orders, causal and not
     "f32-q64-k128-causal": (jnp.float32, 256, 32, True, dict(block_q=64, block_k=128), None),
@@ -71,6 +73,14 @@ _FLASH_CASES = {
     "bf16-d128-causal": (jnp.bfloat16, 512, 128, True, {}, None),
     "bf16-d64-full": (jnp.bfloat16, 512, 64, False, {}, None),
     "bf16-d64-causal-spans": (jnp.bfloat16, 512, 64, True, {}, 256),
+    # four spans a sequence: a q span's dq is the sum of up to four kv
+    # spans' visits, written at each and whole after the diagonal's
+    "f32-d64-causal-4spans": (jnp.float32, 512, 64, True, {}, 128),
+    "bf16-d64-causal-4spans": (jnp.bfloat16, 512, 64, True, {}, 128),
+    # an encoder's rectangular call over several spans, both ways
+    "f32-384x640-full-spans": (jnp.float32, (384, 640), 32, False, {}, 128),
+    "f32-640x384-full-spans": (jnp.float32, (640, 384), 32, False, {}, 128),
+    "bf16-384x640-full-spans": (jnp.bfloat16, (384, 640), 64, False, {}, 128),
     # no lane-aligned block divides these: one tile, the whole sequence
     "f32-197-full": (jnp.float32, 197, 32, False, {}, None),
     "f32-200-causal": (jnp.float32, 200, 32, True, {}, None),
@@ -90,9 +100,11 @@ def test_flash_blocks_match_xla_fwd_bwd(case, flash_span):
     dtype, S, D, causal, blocks, span = _FLASH_CASES[case]
     if span:
         flash_span(span)
+    q_len, kv_len = S if isinstance(S, tuple) else (S, S)
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
-    q, k, v, do = [jax.random.normal(kk, (1, S, 2, D), jnp.float32)
-                   .astype(dtype) for kk in keys]
+    q, k, v, do = [jax.random.normal(kk, (1, n, 2, D), jnp.float32)
+                   .astype(dtype)
+                   for kk, n in zip(keys, (q_len, kv_len, kv_len, q_len))]
     got, want = _flash_and_xla_with_grads(q, k, v, do, causal, **blocks)
     for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
         assert a.dtype == dtype
@@ -108,9 +120,9 @@ def test_flash_blocks_match_xla_fwd_bwd(case, flash_span):
 def test_flash_causal_skipped_blocks_are_not_read(span, flash_span):
     """What lies above the diagonal's blocks must not reach the result,
     not even multiplied by a zero probability (0 * NaN is NaN).  Forward
-    and dq skip keys past a q block's last row; dkv skips queries before
-    a kv block's first column.  ``tiles``: the skipping inside one span;
-    ``spans``: the grid's, whole span pairs."""
+    and backward skip keys past a q block's last row, which is queries
+    before a kv block's first column.  ``tiles``: the skipping inside
+    one span; ``spans``: the grid's, whole span pairs."""
     flash_span(span)
     S, D, blk, cut = 512, 32, 128, 256
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
@@ -245,8 +257,9 @@ _PLAN_SHAPES = [
 def test_flash_plan_blocks_and_visited_share(shape):
     from ray_tpu.ops.flash_attention import plan_blocks
     q_len, kv_len, causal = shape
-    plan = plan_blocks(q_len, kv_len, causal)
-    for t in plan:
+    plan = plan_blocks(q_len, kv_len, causal, head_dim=64)
+    assert plan.fused
+    for t in (plan.fwd, plan.bwd):
         for seq, blocks in ((q_len, (t.block_q, t.span_q)),
                             (kv_len, (t.block_k, t.span_k))):
             for b in blocks:
@@ -255,18 +268,57 @@ def test_flash_plan_blocks_and_visited_share(shape):
         assert (t.visited, t.total) == _pairs_by_brute_force(
             q_len, kv_len, t.block_q, t.block_k, causal)
     if q_len % 128:
-        assert all(t.total == 1 for t in plan)      # the whole sequence
+        assert plan.fwd.total == plan.bwd.total == 1    # the whole sequence
 
 
 @pytest.mark.parametrize("block,pairs", [(1024, (1, 1)), (512, (3, 4)),
                                          (256, (10, 16)), (128, (36, 64))])
 def test_flash_visited_share_of_the_training_cell(block, pairs):
     """The counter of how often causal skipping engages is static: the
-    pairs of a [1024, 1024] causal call by block size."""
+    pairs of a [1024, 1024] causal call by block size.  So is the one of
+    how often the fused backward engages: ``fused``, and each pair is
+    visited once for dq, dk and dv together."""
     from ray_tpu.ops.flash_attention import plan_blocks
-    t = plan_blocks(1024, 1024, True, block, block).fwd
-    assert (t.visited, t.total) == pairs
+    plan = plan_blocks(1024, 1024, True, block, block, head_dim=64)
+    assert plan.fused and plan.bwd == plan.fwd
+    assert (plan.fwd.visited, plan.fwd.total) == pairs
     assert pairs == _pairs_by_brute_force(1024, 1024, block, block, True)
+
+
+@pytest.mark.parametrize("length,fused", [(256, True), (384, False)],
+                         ids=["at-the-budget", "past-it"])
+def test_flash_backward_is_fused_up_to_its_budget(length, fused, flash_span,
+                                                  monkeypatch):
+    """The backward holds dq^T of a head's whole q sequence in VMEM
+    (``DQ_ACC_BYTES``, read against ``q_len`` and ``head_dim`` alone).
+    Up to the budget it is the one kernel ``flash_bwd_dqkv``, here over
+    two spans; past it the forward still runs and the gradient is
+    refused by the kernel's name: there is no second backward."""
+    import ray_tpu.ops.flash_attention as mod
+    flash_span(128)
+    D = 32
+    monkeypatch.setattr(mod, "DQ_ACC_BYTES", 4 * 256 * D)
+    assert mod.plan_blocks(length, length, True, head_dim=D).fused == fused
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    q, k, v, do = [jax.random.normal(kk, (1, length, 2, D), jnp.float32)
+                   for kk in keys]
+    if not fused:
+        o_ref = xla_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(flash_attention(q, k, v, causal=True),
+                                   o_ref, atol=_F32_TOL["o"])
+        with pytest.raises(NotImplementedError, match="flash_bwd_dqkv"):
+            jax.grad(lambda q: flash_attention(q, k, v).sum())(q)
+        return
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: flash_attention(*a).sum(), (0, 1, 2)))(q, k, v)
+    names = [e.params["name"] for e in _all_eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert names == ["flash_fwd", "flash_bwd_dqkv"]
+    got, want = _flash_and_xla_with_grads(q, k, v, do, True)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            a, b, atol=_F32_TOL["o" if name == "o" else "grad"], rtol=0,
+            err_msg=name)
 
 
 def test_gqa_repeat_kv():
