@@ -96,12 +96,13 @@ iteration's prefills):
     1 + _STATE_AHEAD`` entries: every slot, scratch, and that many
     requests prefilled ahead of a slot.  The prefix cache and the
     prefill handoff carry pages only and are refused for such a model.
-  - The block step.  One jitted program advances ALL slots
-    ``block_size`` tokens via lax.scan: [N] tokens in, [N, K] tokens
-    out, donated pool; tokens, positions, temperatures, tables and the
-    rng stay on the device between blocks.  Installs from the ready
-    queue upload their current last token (host-known since their
-    prefill), so the block's tokens come back in a single fetch.
+  - The block step.  One jitted program advances ALL slots UP TO
+    ``block_size`` tokens in one loop (``_run_steps``): [N] tokens in,
+    [N, K] tokens out, donated pool; tokens, positions, temperatures,
+    tables, budgets, eos ids and the rng stay on the device between
+    blocks.  Installs from the ready queue upload their current last
+    token (host-known since their prefill), so the block's tokens come
+    back in a single fetch, with the number of steps it ran.
   - Drafting.  A model with a multi-token-prediction module
     (``cfg.mtp_layers``; models/gpt.py MTPModule) is served with it as
     its own drafter, and a step then yields ONE OR TWO tokens a row
@@ -122,12 +123,23 @@ iteration's prefills):
     many each row emitted.  Refused with it: the prefix cache, the
     prefill handoff (neither carries a draft), recurrent layers (a
     rejected draft would need the state rolled back).
-  - No eos logic on device: rows that finish mid-block keep generating
-    junk the host truncates.  A freed slot keeps stepping junk until
-    its redirect row (table -> scratch page 0, position 0; see
-    ``_block_fn``) rides the next block dispatch; pages are recycled
-    only through dispatches ordered after the last junk write (device
-    stream order), so reuse can never corrupt a live request.
+  - A row ends itself.  An install carries the request's budget (the
+    tokens it may still emit before ``max_new_tokens`` or
+    ``max_seq_len`` ends it) and its eos id.  The step that emits a
+    row's last token redirects the row on the device (table -> scratch
+    page 0, token 0, position 0, state entry 0; ``_end_step``): no
+    later step reads a page, writes a row, moves a state entry or
+    routes to an expert for it, and a block ends at the step after
+    which no row is live (one step later where the last row ends by
+    its eos or on a drafted pair's second token: ``_run_steps``), which
+    may be its first (a block dispatched before the host had seen its
+    rows' last tokens runs no step).  What
+    a block holds past a row's end is zeros or other rows' steps; the
+    host truncates as it always did, by the same three rules, and its
+    own redirect row for the freed slot still rides the next dispatch
+    (idempotent).  Pages are recycled only through dispatches ordered
+    after a row's last write (device stream order), so reuse can never
+    corrupt a live request.
   - Per-request temperature rides as an [N] array (greedy rows select
     argmax under the same jit); top_k/top_p are engine-static.
 
@@ -170,6 +182,9 @@ _WAVE_SIZES = (1, 2, 4, 8, 16, 32)
 # waits in the queue as it waits for pages
 _STATE_AHEAD = 8
 
+# a row's budget where its install names none (``LLMEngine._install``)
+_NO_BUDGET = 1 << 30
+
 
 @dataclasses.dataclass
 class GenerationResult:
@@ -195,8 +210,9 @@ class GenerationResult:
     # decode blocks this one rode, after the block that first stepped
     # it; block_tail_s: from the instant the device produced its last
     # token (step k of its last block, placed in the block's own seconds
-    # by k / block_size) to this result's stamp: the junk steps behind
-    # it and the host's delivery up to this row; stepping_s: the rest
+    # by k / the steps the block ran) to this result's stamp: the steps
+    # other rows took behind it (the block ends with its last live row)
+    # and the host's delivery up to this row; stepping_s: the rest
     # (the decode blocks themselves, the first block's wait behind the
     # request's own wave, what the host adds between blocks)
     stepping_s: float = 0.0
@@ -392,6 +408,10 @@ class EngineStats:
     def __init__(self):
         self.steps = 0                   # decode steps executed (N-wide)
         self.quanta = 0                  # decode blocks fetched
+        # block_size a block fetched: the steps they would have run had
+        # none ended with its last live row (block_steps_run / this is
+        # how often that engages)
+        self.block_steps_offered = 0
         # tokens delivered from steps: one a live row a step, or under
         # drafting one or two (so batch_occupancy may pass 1 there)
         self.step_tokens = 0
@@ -486,9 +506,15 @@ class EngineStats:
     def prefill_wave_s(self) -> float:
         return self._wave[0]
 
+    @property
+    def block_steps_run(self) -> int:
+        """``steps``, under the name that pairs with
+        ``block_steps_offered``."""
+        return self.steps
+
     def occupancy(self, num_slots: int) -> float:
-        """Fraction of step-slots that produced a delivered token (junk
-        decoded past eos / on freed slots counts against it)."""
+        """Fraction of step-slots that produced a delivered token (a
+        slot nobody holds, or whose row has ended, counts against it)."""
         return (self.step_tokens / (self.steps * num_slots)
                 if self.steps else 0.0)
 
@@ -519,6 +545,8 @@ class EngineStats:
             "prefix_tokens_saved": self.prefix_tokens_saved,
             "prefix_evictions": self.prefix_evictions,
             "quanta": self.quanta,
+            "block_steps_run": self.block_steps_run,
+            "block_steps_offered": self.block_steps_offered,
             "prefill_waves": self.prefill_waves,
             "prefill_prompt_tokens": self.prefill_prompt_tokens,
             "prefill_padded_tokens": self.prefill_padded_tokens,
@@ -694,15 +722,18 @@ class LLMEngine:
 
         # +1 scratch row: the target of padded install rows
         self._rows = num_slots + 1
-        # install metadata rows: slots, positions, temps (, entries)
-        self._meta_rows = 4 if self._state_layers else 3
+        # install metadata rows: slots, positions, temps (, entries),
+        # then the last two: budget and eos + 1 (``_install``)
+        self._meta_rows = 6 if self._state_layers else 5
         self._cache = self._init_cache(self._rows)
         # decode state lives ON DEVICE between blocks (tokens, positions,
-        # temps, tables, rng): the host uploads only the small install
-        # arrays, and only when something was installed or redirected
+        # temps, tables, rng, budgets, eos ids): the host uploads only
+        # the small install arrays, and only when something was
+        # installed or redirected
         self._state = self._init_state(seed)
-        # packed install metadata [3, num_slots]: slots row, positions
-        # row, temps*1e6 row — one upload per block, cached when empty
+        # packed install metadata [_meta_rows, num_slots]: slots row,
+        # positions row, temps*1e6 row, ... — one upload per block,
+        # cached when empty
         no_meta = np.zeros((self._meta_rows, num_slots), np.int32)
         no_meta[0, :] = num_slots                           # -> scratch
         self._no_admit = (jnp.asarray(no_meta),
@@ -724,13 +755,13 @@ class LLMEngine:
         # measured to reckon by where it cannot see: the seconds each
         # prefill program took when last it ran alone in an iteration,
         # by (bucket, wave size, suffix, positions a row it computed),
-        # and the last block's own seconds that were measured and not
-        # reckoned
+        # and a step's share of the last block's own seconds that were
+        # measured and not reckoned (blocks differ in length)
         self._fetched_at = 0.0
         self._block_s = 0.0
         self._stall_s = 0.0
         self._wave_like: dict = {}
-        self._block_like: Optional[float] = None
+        self._step_like: Optional[float] = None
         self._imports: collections.deque = collections.deque()
         # admitted-handoff wait-queue bound: beyond it import_prefill
         # rejects SYNCHRONOUSLY (KVPoolFullError) so the caller can
@@ -815,7 +846,11 @@ class LLMEngine:
                  jnp.zeros((self._rows,), jnp.float32),   # temps
                  # per-row block tables (zeros -> every page is scratch)
                  jnp.zeros((self._rows, self.max_pages), jnp.int32),
-                 jax.random.PRNGKey(seed))                # device rng
+                 jax.random.PRNGKey(seed),                # device rng
+                 # tokens each row may still emit, and the token that
+                 # ends it (-1: none)
+                 jnp.zeros((self._rows,), jnp.int32),
+                 jnp.full((self._rows,), -1, jnp.int32))
         if self._state_layers:      # per-row state entries (0: scratch)
             state += (jnp.zeros((self._rows,), jnp.int32),)
         if self._drafts:            # per-row draft and its logits
@@ -1016,78 +1051,170 @@ class LLMEngine:
                 engine_kv_import, donate_argnums=(0,))
         return fn
 
-    def _block_fn(self, params, cache, state, admit_meta, admit_lasts,
-                  admit_tables):
-        """lax.scan of block_size decode steps: one dispatch, ONE fetch
-        of the [rows * K] token block, and all decode state (per-row
-        block tables included) chained on device.  Installed rows'
-        tokens/positions/temps/tables are scattered in here; admit_meta
-        is one packed [3, num_slots] i32 upload (slots, positions,
-        temps*1e6), padded so every block reuses one compiled program
-        (pad slots point at the scratch row).  admit_lasts holds each
-        install's CURRENT last token: uploaded where the host knows it,
+    def _install(self, state, admit_meta, admit_lasts, admit_tables):
+        """What a block program does first: the installs, scattered into
+        the device state.  admit_meta is one packed [_meta_rows,
+        num_slots] i32 upload (slots, positions, temps*1e6, a recurrent
+        model's state entries; then each install's budget, the tokens it
+        may still emit, 0: no bound, and its eos id + 1, 0: none: a
+        caller that fills in neither steps its rows until it redirects
+        them), padded so every block reuses one compiled program (pad
+        slots point at the scratch row).  admit_lasts holds each
+        install's CURRENT last token (a drafting engine's: with its
+        draft and the draft's logits): uploaded where the host knows it,
         put there on the device where its prefill wave runs just ahead
         of this block (_dispatch_block), so nothing extra is fetched;
         redirect rows (evicted slots) are just installs of (token 0,
-        position 0, zero table -> scratch page)."""
-        tokens, positions, temps, tables, rng, *entries = state
+        position 0, zero table -> scratch page).  A row installed ON its
+        eos (a first token the host has not seen yet) ends here.
+        Returns ``(rows, temps, eos, rng, keys)``: ``rows`` the dict of
+        what a step changes (``_end_step``), ``keys`` a sampling key a
+        step."""
+        tokens, positions, temps, tables, rng, remaining, eos, *rest = state
         a_slots = admit_meta[0]
-        recurrent = {}
-        if entries:           # a redirect row's entry is scratch (0)
-            entries = (entries[0].at[a_slots].set(admit_meta[3]),)
-            recurrent["state_rows"] = entries[0]
-        tokens = tokens.at[a_slots].set(admit_lasts)
-        positions = positions.at[a_slots].set(admit_meta[1])
-        temps = temps.at[a_slots].set(
-            admit_meta[2].astype(jnp.float32) / 1e6)
-        tables = tables.at[a_slots].set(admit_tables)
+
+        def put(old, new):
+            return old.at[a_slots].set(new)
+        rows = {}
+        if self._drafts:
+            tokens, rows["drafts"], rows["q_logits"] = (
+                put(old, new) for old, new in zip((tokens, *rest),
+                                                  admit_lasts))
+        else:
+            tokens = put(tokens, admit_lasts)
+            if rest:          # a redirect row's entry is scratch (0)
+                rows["entries"] = put(rest[0], admit_meta[3])
+        temps = put(temps, admit_meta[2].astype(jnp.float32) / 1e6)
+        eos = put(eos, admit_meta[-1] - 1)
+        rows.update(
+            positions=put(positions, admit_meta[1]),
+            tables=put(tables, admit_tables),
+            remaining=put(remaining, jnp.where(
+                admit_meta[-2] > 0, admit_meta[-2], _NO_BUDGET)))
+        rows = self._end_step(rows, tokens, 0, tokens == eos)
         rng, sub = jax.random.split(rng)
-        keys = jax.random.split(sub, self.block_size)
-        # a row whose table starts at scratch page 0 holds no request
-        # (never installed, or redirected after eviction).  The model's
-        # Block derives the same mask from the tables and its decode
-        # kernels read nothing for such a row (PERF.md, PR 27, PR 32).
-        # It keeps stepping junk, but AT POSITION 0, so its K/V write
-        # (the XLA form's: the decode kernel writes nothing for it)
-        # stays on the scratch page's first row and a reader without
-        # the mask reads one page of it, not ceil((position+1) /
-        # page_size): 28 idle rows left to walk to max_seq_len did twice
-        # the work of the whole model (PERF.md, PR 25)
-        live = tables[:, 0] != 0
+        return (rows, temps, eos, rng,
+                jax.random.split(sub, self.block_size))
+
+    @staticmethod
+    def _pack_state(rows, temps, eos, rng):
+        """The device state in ``_init_state``'s order, from what
+        ``_install`` took it apart into."""
+        return (rows["tokens"], rows["positions"], temps, rows["tables"],
+                rng, rows["remaining"], eos,
+                *(rows[k] for k in ("entries", "drafts", "q_logits")
+                  if k in rows))
+
+    def _end_step(self, rows, nxt, moved, hit_eos):
+        """The end of a decode step, every row at once.  A row whose
+        table starts at scratch page 0 holds no request (never
+        installed, redirected after eviction, or ended by this very
+        function).  The model's Block derives the same mask from the
+        tables and its decode kernels read nothing, write nothing, move
+        no state entry and route to no expert for such a row (PERF.md,
+        PR 27, PR 32, PR 45).  It is stepped AT POSITION 0, so that a
+        reader without the mask reads one page of it, not
+        ceil((position+1) / page_size): 28 idle rows left to walk to
+        max_seq_len did twice the work of the whole model (PERF.md, PR
+        25).  A live row has emitted ``moved`` tokens (1, or a drafting
+        step's 1 or 2: an overshoot of its budget by one is the host's
+        to truncate), the last of them ``nxt``, one of them its eos
+        where ``hit_eos``: with its budget spent or its eos out it does
+        for itself what the host's redirect install does a block later
+        (table to scratch, token 0, position 0, state entry 0), and no
+        later step of this block or the next serves it."""
+        live = rows["tables"][:, 0] != 0
+        remaining = rows["remaining"] - jnp.where(live, moved, 0)
+        ended = live & (hit_eos | (remaining <= 0))
+        stays = live & ~ended
+        # where a row whose install named no budget stops walking (a
+        # drafting row's draft position must exist too)
+        top = self.cfg.max_seq_len - (2 if self._drafts else 1)
+        out = dict(
+            rows, remaining=remaining, tokens=jnp.where(stays, nxt, 0),
+            positions=jnp.where(
+                stays, jnp.minimum(rows["positions"] + moved, top), 0),
+            tables=jnp.where(ended[:, None], 0, rows["tables"]))
+        if "entries" in rows:
+            out["entries"] = jnp.where(ended, 0, rows["entries"])
+        return out
+
+    def _run_steps(self, one, rows, cache, keys, outs: int):
+        """The block's loop, its trip count the device's to decide:
+        ``one(rows, cache, key) -> (rows, cache, out, load)`` while a row
+        is live, ``block_size`` times at most.  Whether to go on is
+        settled from what the rows held BEFORE the step just run: some
+        live row had more than that step's token left of its budget.  A
+        condition on the step's own outcome (any table still off
+        scratch) makes the loop wait for the step at its every turn,
+        16-30 us a step on the v5e (PERF.md, PR 48); this one is as free
+        as a scan's counter, and exact wherever budgets end the rows.
+        Where the last row ends by its eos, or by the second token of a
+        drafted pair, one more step runs for nobody and the loop ends
+        behind it.  ``out`` is ``outs`` int32 [rows] arrays a step,
+        written at the step's index into [block_size, rows] buffers
+        (zeros past the last step run); ``load`` the step's expert load
+        (``_expert_load``; () without).  The cache and the rows ride the
+        loop's carry, donated and in place.  Returns the block's ONE
+        fetch (each buffer as [rows * block_size], then the steps run,
+        then the expert load's two numbers), the rows and the cache."""
+        zero = jnp.zeros((), jnp.int32)
+
+        def going(carry):
+            return (carry[0] < self.block_size) & carry[-1]
+
+        def body(carry):
+            step, rows, cache, bufs, loads, _ = carry
+            more = jnp.any((rows["tables"][:, 0] != 0)
+                           & (rows["remaining"] > 1))
+            rows, cache, out, load = one(rows, cache, keys[step])
+            return (step + 1, rows, cache,
+                    tuple(b.at[step].set(o) for b, o in zip(bufs, out)),
+                    tuple(a + b for a, b in zip(loads, load)), more)
+
+        steps, rows, cache, bufs, loads, _ = jax.lax.while_loop(
+            going, body, (
+                zero, rows, cache,
+                (jnp.zeros((self.block_size, self._rows), jnp.int32),) * outs,
+                (zero, zero) if self._counts_expert_load else (),
+                jnp.any(rows["tables"][:, 0] != 0)))
+        return jnp.concatenate(
+            [b.T.reshape(-1) for b in bufs]
+            + [jnp.stack([steps, *loads])]), rows, cache
+
+    def _block_fn(self, params, cache, state, admit_meta, admit_lasts,
+                  admit_tables):
+        """At most block_size decode steps (``_run_steps``): one
+        dispatch, ONE fetch of the [rows * K] token block and the steps
+        run, and all decode state (per-row block tables, budgets and eos
+        ids included) chained on device.  The installs are scattered in
+        first (``_install``); each step then ends the rows that emitted
+        their last token (``_end_step``), and the block ends with its
+        last live row."""
+        rows, temps, eos, rng, keys = self._install(
+            state, admit_meta, admit_lasts, admit_tables)
         load = self._counts_expert_load
 
-        def one(carry, key):
-            tokens, positions, cache = carry
+        def one(rows, cache, key):
+            live = rows["tables"][:, 0] != 0
             logits, mut = self.model.apply(
-                {"params": params, "cache": cache}, tokens[:, None],
-                positions[:, None], block_tables=tables,
+                {"params": params, "cache": cache}, rows["tokens"][:, None],
+                rows["positions"][:, None], block_tables=rows["tables"],
                 mutable=["cache", "intermediates"] if load else ["cache"],
-                **recurrent)
+                **({"state_rows": rows["entries"]} if "entries" in rows
+                   else {}))
             nxt = self._sample_fn(key, logits[:, -1], temps)
-            positions = jnp.where(
-                live, jnp.minimum(positions + 1,
-                                  self.cfg.max_seq_len - 1), 0)
-            out = (nxt, self._expert_load(mut["intermediates"], live)
-                   ) if load else nxt
-            return (nxt, positions, mut["cache"]), out
+            return (self._end_step(rows, nxt, 1, nxt == eos), mut["cache"],
+                    (nxt,), self._expert_load(mut["intermediates"], live)
+                    if load else ())
 
-        (tokens, positions, cache), block = jax.lax.scan(
-            one, (tokens, positions, cache), keys)
-        if load:
-            # two numbers ride the block's ONE fetch
-            block, (steps, touched) = block
-            combined = jnp.concatenate([
-                block.T.reshape(-1), jnp.stack(
-                    [steps.sum(), touched.sum()])])
-        else:
-            combined = block.T.reshape(-1)
-        return combined, (tokens, positions, temps, tables, rng,
-                          *entries), cache
+        combined, rows, cache = self._run_steps(one, rows, cache, keys, 1)
+        return combined, self._pack_state(rows, temps, eos, rng), cache
 
     def _spec_block_fn(self, params, cache, state, admit_meta, admit_lasts,
                        admit_tables):
-        """``_block_fn`` of a drafting engine (module docstring): a scan
-        of block_size steps of ONE OR TWO tokens a row.  The state holds
+        """``_block_fn`` of a drafting engine (module docstring): its
+        steps yield ONE OR TWO tokens a row.  The state holds
         each row's draft and the logits it was drawn from besides;
         ``admit_lasts`` is the installs' ``(last token, draft, logits)``.
         A step: the stack over (last token at p, draft at p + 1), one
@@ -1098,29 +1225,18 @@ class LLMEngine:
         step's first, as the stack's row at p + 1 is); the next draft
         from the module's logits at the row's new last position.  The
         block's ONE fetch is ``[first | second | count]``, ``rows *
-        block_size`` each (then the expert load's two numbers): ``count``
-        1 or 2, ``second`` junk where it is 1."""
+        block_size`` each (then the steps run and the expert load's two
+        numbers): ``count`` 1 or 2, ``second`` junk where it is 1."""
         from ray_tpu.models.generate import verify_draft
-        tokens, positions, temps, tables, rng, drafts, q_logits = state
-        a_slots = admit_meta[0]
-        tokens, drafts, q_logits = (
-            old.at[a_slots].set(new) for old, new in zip(
-                (tokens, drafts, q_logits), admit_lasts))
-        positions = positions.at[a_slots].set(admit_meta[1])
-        temps = temps.at[a_slots].set(
-            admit_meta[2].astype(jnp.float32) / 1e6)
-        tables = tables.at[a_slots].set(admit_tables)
-        rng, sub = jax.random.split(rng)
-        keys = jax.random.split(sub, self.block_size)
-        live = tables[:, 0] != 0      # as in _block_fn
+        rows, temps, eos, rng, keys = self._install(
+            state, admit_meta, admit_lasts, admit_tables)
         load = self._counts_expert_load
         mutable = ["cache", "intermediates"] if load else ["cache"]
-        # the draft's position must exist: a row the host has not yet
-        # redirected stops one short of max_seq_len - 1
-        last_pos = self.cfg.max_seq_len - 2
 
-        def one(carry, key):
-            tokens, positions, drafts, q_logits, cache = carry
+        def one(rows, cache, key):
+            tokens, positions, tables, drafts = (
+                rows[k] for k in ("tokens", "positions", "tables", "drafts"))
+            live = tables[:, 0] != 0
             k_verify, k_draft = jax.random.split(key)
             at = jnp.stack([positions, positions + 1], axis=1)
             with jax.named_scope("spec_verify"):
@@ -1132,8 +1248,9 @@ class LLMEngine:
                 logits = output_logits(self.cfg, params, hidden)
             with jax.named_scope("spec_accept"):
                 n, first, second = verify_draft(
-                    k_verify, logits[:, 0], logits[:, 1], q_logits, drafts,
-                    temperature=temps, top_k=self.top_k, top_p=self.top_p)
+                    k_verify, logits[:, 0], logits[:, 1], rows["q_logits"],
+                    drafts, temperature=temps, top_k=self.top_k,
+                    top_p=self.top_p)
             with jax.named_scope("mtp_draft"):
                 drafted, mut2 = self.model_verify.apply(
                     {"params": params, "cache": mut["cache"]},
@@ -1143,23 +1260,16 @@ class LLMEngine:
                 q_logits = output_logits(self.cfg, params, jnp.where(
                     (n == 2)[:, None], drafted[:, 1], drafted[:, 0]))
                 drafts = self._sample_fn(k_draft, q_logits, temps)
-            tokens = jnp.where(n == 2, second, first)
-            positions = jnp.where(
-                live, jnp.minimum(positions + n, last_pos), 0)
-            out = (first, second, n)
-            if load:
-                out += (self._expert_load(
-                    (mut["intermediates"], mut2["intermediates"]), live),)
-            return (tokens, positions, drafts, q_logits,
-                    mut2["cache"]), out
+            rows = self._end_step(
+                dict(rows, drafts=drafts, q_logits=q_logits),
+                jnp.where(n == 2, second, first), n,
+                (first == eos) | ((n == 2) & (second == eos)))
+            return rows, mut2["cache"], (first, second, n), self._expert_load(
+                (mut["intermediates"], mut2["intermediates"]), live
+            ) if load else ()
 
-        (tokens, positions, drafts, q_logits, cache), out = jax.lax.scan(
-            one, (tokens, positions, drafts, q_logits, cache), keys)
-        combined = [a.T.reshape(-1) for a in out[:3]]
-        if load:
-            combined.append(jnp.stack([a.sum() for a in out[3]]))
-        return jnp.concatenate(combined), (
-            tokens, positions, temps, tables, rng, drafts, q_logits), cache
+        combined, rows, cache = self._run_steps(one, rows, cache, keys, 3)
+        return combined, self._pack_state(rows, temps, eos, rng), cache
 
     def _expert_load(self, intermediates, live):
         """One decode step's expert load over the rows that hold a
@@ -1677,12 +1787,13 @@ class LLMEngine:
         sl = self._slots[i]
         self._slots[i] = None
         self._free.append(i)
-        # the freed slot junk-steps its old table until its redirect
-        # row rides a block dispatch; pages recycle only through later
-        # dispatches, so immediate free is stream-safe (see module
-        # docstring).  Junk writes only ever advance PAST the prompt
-        # span, so leading pages retained by the prefix cache are never
-        # touched by the straggling steps.
+        # the row ended itself on the device at its last token's step
+        # (``_end_step``), and a block in flight finds it dead; the
+        # redirect row that rides the next dispatch is the host's own
+        # record of it.  Pages recycle only through later dispatches, so
+        # immediate free is stream-safe (see module docstring), and a
+        # row never wrote past its last token: leading pages retained by
+        # the prefix cache are its prompt's.
         self._stale_slots.add(i)
         self._prefix_release(sl)
         self._deliver_result(sl, reason)
@@ -1703,11 +1814,14 @@ class LLMEngine:
                 # what a wave writes to the pool a token a layer
                 pool_row=self._pool_tail[0] * self._pool_tail[2])
 
-    def _deliver_block(self, block, rows: list, ahead: _Ahead) -> None:
+    def _deliver_block(self, block, rows: list, ahead: _Ahead,
+                       steps_run: int) -> None:
         """Hand one fetched decode block's tokens to their requests, in
         order, truncating junk past each row's finish.  ``block`` is
         ``[rows, block_size]``, row i's k-th token the one step k gave
-        it; a drafting engine's ``[3, rows, block_size]``: each step's
+        it, of the ``steps_run`` steps the device ran before the block's
+        last live row ended (0: it found none, and nothing is delivered);
+        a drafting engine's ``[3, rows, block_size]``: each step's
         first token, its second, and how many of the two the row emitted
         (a request may end at the first of a pair; the second is then
         junk like any token past the end).  A step is a step either way
@@ -1718,26 +1832,27 @@ class LLMEngine:
         it after each row brackets the end of its waves."""
         with self._phase("deliver_block", "deliver_s") as sp:
             st = self.stats
-            st.steps += self.block_size
+            st.steps += steps_run
             st.quanta += 1
-            st.pool_layer_steps += self.block_size * self._pool_layers
-            st.gdn_layer_steps += self.block_size * self._state_layers
-            st.mla_layer_steps += self.block_size * self._latent_layers
+            st.block_steps_offered += self.block_size
+            st.pool_layer_steps += steps_run * self._pool_layers
+            st.gdn_layer_steps += steps_run * self._state_layers
+            st.mla_layer_steps += steps_run * self._latent_layers
             tokens0, done0 = st.step_tokens, st.requests_completed
             drafts0 = st.drafts_proposed, st.drafts_accepted
-            for i, req in rows:
+            for i, req in rows if steps_run else ():
                 sl = self._slots[i]
                 if sl is None or sl.request is not req:
                     continue      # evicted earlier (or reused): junk row
                 pos0, reason = sl.pos, None
                 if self._drafts:
                     # the row's tokens in order, and the step of each
-                    count = block[2, i]
+                    count = block[2, i, :steps_run]
                     emitted = np.arange(2)[None, :] < count[:, None]
-                    toks = block[:2, i].T[emitted].tolist()
-                    step_of = np.repeat(np.arange(self.block_size), count)
+                    toks = block[:2, i, :steps_run].T[emitted].tolist()
+                    step_of = np.repeat(np.arange(steps_run), count)
                 else:
-                    toks = block[i].tolist()
+                    toks = block[i, :steps_run].tolist()
                 for j, tok in enumerate(toks):
                     sl.out.append(tok)
                     sl.last_token = tok
@@ -1775,9 +1890,9 @@ class LLMEngine:
                     (sl.pos - pos0) * (sl.pos + pos0 + 1) // 2)
                 if reason is not None:
                     # step k + 1 of the block produced its last token;
-                    # the steps behind it are junk
+                    # the steps behind it are other rows'
                     sl.last_step_at = self._fetched_at - self._block_s * (
-                        self.block_size - 1 - k) / self.block_size
+                        steps_run - 1 - k) / steps_run
                     # counted first: whoever holds the result may read
                     # the counters
                     self._evict(i, reason)
@@ -1805,7 +1920,7 @@ class LLMEngine:
     # waves, whose tokens are joined BEHIND the block) it reckons: each
     # wave as that program went when last it ran alone, or, where one
     # never did (or imports were scattered), the interval's excess over
-    # the last block that was measured.
+    # this block's steps at the pace of the last block that was measured.
 
     def _begin(self, ahead: _Ahead, at: float) -> None:
         """The device turns to what is ahead of the next block."""
@@ -1830,17 +1945,18 @@ class LLMEngine:
         self.stats._wave = (self.stats._wave[0] + ahead.wave_s, None, None,
                             0.0)
 
-    def _account_block(self, ahead: _Ahead, done: float):
-        """A block's fetch came back at ``done``: split the interval
-        since the device turned to it into the waves ahead of it and its
-        own seconds.  Returns (interval, wave seconds)."""
+    def _account_block(self, ahead: _Ahead, done: float, steps_run: int):
+        """A block's fetch came back at ``done``, ``steps_run`` steps
+        long: split the interval since the device turned to it into the
+        waves ahead of it and its own seconds.  Returns (interval, wave
+        seconds)."""
         interval = max(0.0, done - ahead.start)
         measured = not ahead.waves or ahead.wave_s is not None
         if not measured:
             if ahead.like is not None:
                 reckoned = ahead.like
-            elif self._block_like is not None:
-                reckoned = interval - self._block_like
+            elif self._step_like is not None:
+                reckoned = interval - self._step_like * steps_run
             else:
                 reckoned = 0.0
             self._waves_done(
@@ -1851,8 +1967,8 @@ class LLMEngine:
         self._fetched_at = done
         self._block_s = interval - wave_s
         self._stall_s += wave_s
-        if measured:
-            self._block_like = self._block_s
+        if measured and steps_run:
+            self._step_like = self._block_s / steps_run
         return interval, wave_s
 
     def _count_decode_pages(self, first: int, last: int) -> None:
@@ -2085,11 +2201,14 @@ class LLMEngine:
         first-tokens (the device finished them before the
         just-dispatched block).  Block k+1 is dispatched before block
         k's tokens are fetched, so the device never idles on the host's
-        fetch round-trip or bookkeeping.  The price is a one-block
-        EVICTION lag: a row that finished in block k (or at its first
-        token, an install with the prefill) steps junk through block
-        k+1 and its slot is free for the block after, which the
-        request-identity check in _deliver_block makes safe.  There is
+        fetch round-trip or bookkeeping.  The price is a one-block lag
+        of the SLOT, not of the device: a row that finished in block k
+        (or at its first token, an install with the prefill) ended
+        itself on the device at that step and costs block k+1 nothing
+        (``_end_step``; a block k+1 with no other row runs no step),
+        but the host learns of it a block later and its slot is free
+        for the block after, which the request-identity check in
+        _deliver_block makes safe.  There is
         no install lag while a slot is free: a request is stepped by
         the block behind its prefill.  Only a request that found every
         slot taken waits in _ready, for the eviction that frees one.
@@ -2259,7 +2378,7 @@ class LLMEngine:
                     self._stale_slots.clear()
                 self._prefix_reset()
                 inflight = None
-                self._block_like = None
+                self._step_like = None
                 self.stats._wave = (self.stats._wave[0], None, None, 0.0)
                 self._cache = self._init_cache(self._rows)
                 self._state = self._init_state(0)
@@ -2432,7 +2551,7 @@ class LLMEngine:
                 if reason is not None:
                     self._evict(pf.slot, reason)
             elif reason is not None:
-                # never installed -> nothing junk-steps these pages:
+                # never installed -> no row steps on these pages:
                 # free immediately, no redirect needed
                 self._prefix_release(sl)
                 self._deliver_result(sl, reason)
@@ -2554,8 +2673,9 @@ class LLMEngine:
     def _dispatch_block(self, installs: list):
         """Install requests into free slots, attach redirect rows for
         stale slots, and dispatch one decode block.  A request's
-        position, temperature, table and state entry are host-known from
-        its admission on; its last token is too (nothing is fetched),
+        position, temperature, table, state entry, budget and eos id are
+        host-known from its admission on (``_install`` says how each
+        rides ``meta``); its last token is too (nothing is fetched),
         except where its prefill wave was dispatched this iteration
         (``pf.source``): that token goes from the wave's output into the
         block's ``admit_lasts`` on the device.  Returns
@@ -2578,6 +2698,12 @@ class LLMEngine:
             meta[2, n] = int(sl.request.temperature * 1e6)
             if self._state_layers:
                 meta[3, n] = sl.request.entry
+            # what _length_reached will say on the host, one token out
+            # (its first, fetched or not)
+            meta[-2, n] = min(sl.request.max_new_tokens - 1,
+                              self.cfg.max_seq_len - 1 - sl.pos)
+            if sl.request.eos_id is not None:
+                meta[-1, n] = sl.request.eos_id + 1
             if pf.source is None and pf.drafted is None:
                 lasts[n] = sl.last_token
             else:
@@ -2621,19 +2747,21 @@ class LLMEngine:
         with self._phase("fetch_block", "fetch_wait_s") as sp:
             host = np.asarray(combined)    # the ONE fetch this quantum
             done = time.monotonic()
-            interval, wave_s = self._account_block(ahead, done)
+            # behind the tokens: the steps the block ran (``_run_steps``)
+            host, (steps_run, *load) = np.split(
+                host, [-3 if self._counts_expert_load else -1])
+            steps_run = int(steps_run)
+            interval, wave_s = self._account_block(ahead, done, steps_run)
             self._begin(nxt_ahead, done)
             sp.set_metadata(block=self.stats.quanta + 1, rows=len(rows),
-                            waves=ahead.waves,
+                            steps=steps_run, waves=ahead.waves,
                             interval_ms=round(1e3 * interval, 3),
                             wave_ms=round(1e3 * wave_s, 3))
-        if self._counts_expert_load:
-            st = self.stats
-            host, (steps, touched) = host[:-2], host[-2:]
-            st.moe_layer_steps += int(steps)
-            st.moe_experts_touched += int(touched)
+        if load:
+            self.stats.moe_layer_steps += int(load[0])
+            self.stats.moe_experts_touched += int(load[1])
         # a drafting engine's block: [first | second | count]
         self._deliver_block(host.reshape(-1, self._rows, self.block_size)
                             if self._drafts
                             else host.reshape(self._rows, self.block_size),
-                            rows, nxt_ahead)
+                            rows, nxt_ahead, steps_run)

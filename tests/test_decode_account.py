@@ -224,8 +224,8 @@ A, B, NEW = (8, 1, False), (16, 2, False), (32, 1, True)
 def test_where_a_blocks_interval_went(case, keys, wave_s):
     """``_account_block`` on hand-made stamps: an end the loop saw is
     taken as seen and remembered for its program; unseen waves are
-    reckoned from what their programs took alone, else from the last
-    measured block, else not at all; and a snapshot counts waves under
+    reckoned from what their programs took alone, else from this
+    block's steps at the last measured block's pace, else not at all; and a snapshot counts waves under
     way up to now while they run, and no more than such waves took
     before once they have ended unseen."""
     import time
@@ -236,7 +236,8 @@ def test_where_a_blocks_interval_went(case, keys, wave_s):
     eng.stats = EngineStats()
     eng._stall_s = 1.0
     eng._wave_like = {A: 0.03, B: 0.04}
-    eng._block_like = None if case.startswith("nothing") else 0.10
+    # a step's pace, from a measured block of 4 steps in 0.10 s
+    eng._step_like = None if case.startswith("nothing") else 0.025
     class Tokens:                      # a wave's first tokens, faked
         ready = False
 
@@ -258,7 +259,7 @@ def test_where_a_blocks_interval_went(case, keys, wave_s):
     else:
         # ended nobody saw when: no more than such waves took before
         assert under_way == (0.07 if keys == [A, B] else 0.0)
-    interval, got = eng._account_block(ahead, start + 0.13)
+    interval, got = eng._account_block(ahead, start + 0.13, 4)
     assert interval == pytest.approx(0.13)
     assert got == pytest.approx(wave_s)
     assert eng._block_s == pytest.approx(0.13 - wave_s)
@@ -269,9 +270,9 @@ def test_where_a_blocks_interval_went(case, keys, wave_s):
     assert eng._wave_like[A] == pytest.approx(
         0.05 if len(keys) == 1 else 0.03)
     assert NEW not in eng._wave_like
-    assert eng._block_like == (pytest.approx(0.08) if len(keys) == 1
-                               else None if case.startswith("nothing")
-                               else 0.10)
+    assert eng._step_like == (pytest.approx(0.02) if len(keys) == 1
+                              else None if case.startswith("nothing")
+                              else 0.025)
 
 
 def test_decode_pool_summary_has_the_parts():

@@ -351,7 +351,7 @@ def _delivered(cfg, params, block, *, max_new_tokens, eos_id=None,
     eng._slots[0] = sl = le._Slot(req, prompt_len, 100, [1, 2, 3])
     full = np.zeros((3, eng._rows, 4), np.int64)
     full[:, 0] = block
-    eng._deliver_block(full, [(0, req)], le._Ahead(0, [], {}))
+    eng._deliver_block(full, [(0, req)], le._Ahead(0, [], {}), 4)
     result = done.get("result")
     return (sl.out, result and result.finish_reason, eng.stats)
 

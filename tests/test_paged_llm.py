@@ -55,18 +55,26 @@ def _lone_expect(cfg, params, prompts, n=8):
     ]
 
 
-def _submit_all(eng, prompts, n=8, timeout=240):
-    results = [None] * len(prompts)
-    threads = []
-    for i, p in enumerate(prompts):
-        def go(i=i, p=p):
-            results[i] = eng.submit(p, max_new_tokens=n, temperature=0.0)
-        t = threading.Thread(target=go)
+def _serve_each(eng, requests, timeout=240):
+    """Every request (kwargs of ``submit``) at once, a thread each; the
+    results in order."""
+    out = [None] * len(requests)
+
+    def go(i):
+        out[i] = eng.submit(**requests[i])
+    threads = [threading.Thread(target=go, args=(i,))
+               for i in range(len(requests))]
+    for t in threads:
         t.start()
-        threads.append(t)
     for t in threads:
         t.join(timeout=timeout)
-    return results
+    return out
+
+
+def _submit_all(eng, prompts, n=8, timeout=240):
+    return _serve_each(eng, [dict(prompt=p, max_new_tokens=n,
+                                  temperature=0.0) for p in prompts],
+                       timeout)
 
 
 def test_paged_model_matches_dense_at_mixed_offsets(tiny_parts_either):
@@ -1181,7 +1189,7 @@ def _step_blocks(eng, live_slots, blocks=2):
     meta = np.asarray(eng._no_admit[0]).copy()
     tables = np.zeros((meta.shape[1], eng.max_pages), np.int32)
     for i, (slot, page) in enumerate(live_slots.items()):
-        meta[:, i] = (slot, 5, 0)
+        meta[:3, i] = (slot, 5, 0)
         tables[i, 0] = page
     out = {slot: [] for slot in live_slots}
     for _ in range(blocks):
@@ -1299,3 +1307,346 @@ def test_decode_pages_read_counts_what_the_kernel_reads(preset):
     ) == steps / st["steps"]
     assert kv_rows_written_mean.read({"serve": {"stats0": {},
                                                 "stats1": {}}}) is None
+
+
+# ---- a block knows each row's budget (ISSUE 48) ----
+
+
+def _blocks_run(eng):
+    """The steps each block ran, as ``_deliver_block`` was told them,
+    in order: a list that grows while the engine serves."""
+    ran, deliver = [], eng._deliver_block
+
+    def spy(block, rows, ahead, steps_run):
+        deliver(block, rows, ahead, steps_run)
+        ran.append(steps_run)
+    eng._deliver_block = spy
+    return ran
+
+
+def _until(what, timeout=60.0):
+    end = time.monotonic() + timeout
+    while not what():
+        assert time.monotonic() < end, "timed out"
+        time.sleep(0.01)
+
+
+def _decode_account_holds(r):
+    parts = (r.stepping_s, r.prefill_stall_s, r.block_tail_s)
+    assert all(p >= 0.0 for p in parts), parts
+    assert sum(parts) == pytest.approx(
+        r.latency_s - r.time_to_first_token_s, abs=1e-9, rel=1e-12)
+
+
+# a request of n tokens installed at a block's step 0 (its first token is
+# the prefill's) emits its last at step (n - 2) % block_size
+_ENDS_AT = {"step-0": 2, "step-1": 3, "step-17": 19, "step-31": 33,
+            "second-block": 41}
+
+
+@pytest.fixture(scope="module")
+def mixed_batch(tiny_parts):
+    """One engine of 32-step blocks serving, all at once, requests that
+    end at steps 0, 1, 17 and 31 of a block and in a second block, one
+    that meets its eos in the middle of a block and one that max_seq_len
+    ends: ``(names, requests, replies, the lone streams, stats, the steps
+    each block ran)``."""
+    import numpy as np
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts
+    names = list(_ENDS_AT) + ["eos", "max_seq_len"]
+    prompts = [[1 + i, 2 + i, 3 + i][:1 + i % 3] + [40 + i]
+               for i in range(len(names))]
+    prompts[-1] = list(range(7, 7 + 50))
+    lone = _lone_expect(cfg, params, prompts, n=48)
+    asked = list(_ENDS_AT.values()) + [48, 48]
+    # the last token of its stream's first twenty that none before it
+    # equals, as the eos: the request stops there, mid-block
+    stream = lone[-2]
+    at = max(j for j in range(3, 20) if stream[j] not in stream[:j])
+    requests = [dict(prompt=p, max_new_tokens=n, temperature=0.0)
+                for p, n in zip(prompts, asked)]
+    requests[-2]["eos_id"] = stream[at]
+    want = [s[:n] for s, n in zip(lone, asked)]
+    want[-2] = stream[:at + 1]
+    want[-1] = lone[-1][:64 - 50]
+    eng = LLMEngine(cfg, params, num_slots=8, max_seq_len=64,
+                    max_prompt_len=56, page_size=16)
+    try:
+        ran = _blocks_run(eng)
+        replies = _serve_each(eng, requests)
+        _until(lambda: eng.load_snapshot()["busy_slots"] == 0
+               and sum(ran) == eng.stats.steps and len(ran) >= 2
+               and ran[-1] == 0)
+        tokens, positions, _, tables = eng._state[:4]
+        state = [np.asarray(a).tolist()
+                 for a in (tokens, positions, tables[:, 0])]
+        return (names, requests, replies, want, eng.stats.snapshot(8),
+                list(ran), state, cfg)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("who", list(_ENDS_AT) + ["eos", "max_seq_len"])
+def test_a_mixed_batch_streams_what_lone_generation_does(mixed_batch, who):
+    """Token for token the plain reference's stream, wherever in a block
+    a request ends and whatever ends it; and the decode-time account
+    holds on every reply, with blocks of unequal length."""
+    names, requests, replies, want, *_ = mixed_batch
+    i = names.index(who)
+    assert replies[i] is not None
+    assert replies[i].tokens == want[i]
+    assert replies[i].finish_reason == ("eos" if who == "eos" else "length")
+    if who == "max_seq_len":
+        assert replies[i].prompt_len + len(replies[i].tokens) == 64
+    _decode_account_holds(replies[i])
+
+
+def test_a_mixed_batch_runs_the_steps_its_longest_row_needs(mixed_batch):
+    """The device ends each block with its last live row: no block runs
+    past the longest answer's last step, the block behind the last runs
+    none, the counters say so, and what was written is what was
+    delivered; the rows all ended themselves (table on scratch, token 0,
+    position 0) with no redirect from the host."""
+    _, _, replies, _, st, ran, state, cfg = mixed_batch
+    delivered = sum(len(r.tokens) - 1 for r in replies)
+    longest = max(len(r.tokens) - 1 for r in replies)
+    assert st["steps"] == st["block_steps_run"] == sum(ran)
+    assert st["block_steps_offered"] == 32 * len(ran) == 32 * st["quanta"]
+    # every request was installed behind its prefill wave, in one block or
+    # in neighbouring ones: far fewer steps than a scan of 32 would run
+    assert longest <= st["steps"] < st["block_steps_offered"] - 32
+    assert ran[-1] == 0 and max(ran) <= 32
+    assert st["step_tokens"] == delivered
+    assert st["decode_rows_written"] == delivered * cfg.n_layers
+    assert st["pool_layer_steps"] == st["steps"] * cfg.n_layers
+    tokens, positions, first_pages = state
+    assert not any(tokens) and not any(positions) and not any(first_pages)
+
+
+@pytest.mark.parametrize("n, blocks", [(8, [7, 0]), (33, [32, 0]),
+                                       (40, [32, 7, 0])],
+                         ids=["inside-a-block", "a-whole-block",
+                              "into-a-second"])
+def test_a_lone_request_costs_the_steps_of_its_tokens(tiny_parts, n, blocks):
+    """``n`` tokens are the prefill's and ``n - 1`` decode steps, not a
+    multiple of the block; the block that was dispatched before the host
+    had seen the last token finds no live row and runs no step."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts
+    (want,) = _lone_expect(cfg, params, [[3, 4, 5]], n=n)
+    eng = LLMEngine(cfg, params, num_slots=2)
+    try:
+        ran = _blocks_run(eng)
+        r = eng.submit([3, 4, 5], max_new_tokens=n, temperature=0.0)
+        assert r.tokens == want
+        _decode_account_holds(r)
+        _until(lambda: len(ran) == len(blocks))
+        st = eng.stats
+        assert ran == blocks
+        assert st.steps == n - 1
+        assert st.block_steps_run == n - 1 < st.block_steps_offered == (
+            32 * len(blocks))
+        snap = st.snapshot(2)
+        assert (snap["block_steps_run"], snap["block_steps_offered"]) == (
+            n - 1, 32 * len(blocks))
+    finally:
+        eng.close()
+
+
+def test_a_lone_request_s_eos_ends_its_block_one_step_on(tiny_parts):
+    """The loop goes on by what the rows held BEFORE a step (their
+    budgets), so an eos, which only the step's own token shows, ends the
+    block one step later: the request's steps, one for nobody, and the
+    block behind runs none."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts
+    (stream,) = _lone_expect(cfg, params, [[3, 4, 5]], n=24)
+    at = max(j for j in range(3, 20) if stream[j] not in stream[:j])
+    eng = LLMEngine(cfg, params, num_slots=2)
+    try:
+        ran = _blocks_run(eng)
+        r = eng.submit([3, 4, 5], max_new_tokens=64, temperature=0.0,
+                       eos_id=stream[at])
+        assert (r.tokens, r.finish_reason) == (stream[:at + 1], "eos")
+        _decode_account_holds(r)
+        _until(lambda: len(ran) == 2)
+        assert ran == [at + 1, 0]
+        assert eng.stats.step_tokens == at
+    finally:
+        eng.close()
+
+
+def test_an_ended_row_is_neither_read_nor_written(tiny_parts):
+    """The block program by hand: row 0 with a budget of 3 tokens, row 1
+    ended by its eos, row 2 with no budget named, each on a page of its
+    own from position 5.  A row writes the K/V rows of the steps it took
+    and not one more, in this block or the next; the block ends when
+    nobody is live and reports the steps it ran."""
+    import numpy as np
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg, params = tiny_parts
+    eng = LLMEngine(cfg, params, num_slots=3, block_size=4, page_size=16,
+                    kv_pool_pages=1 + 8)
+    try:
+        rows = eng.num_slots + 1
+
+        def block(meta, tables):
+            out, eng._state, eng._cache = eng._block_jit(
+                eng.params, eng._cache, eng._state, meta,
+                np.zeros((3,), np.int32), tables)
+            out = np.asarray(out)
+            return (out[:-1].reshape(rows, 4), int(out[-1]),
+                    np.asarray(eng._cache["kv_pages"]))
+
+        def install(eos_of_row_1):
+            meta = np.asarray(eng._no_admit[0]).copy()
+            tables = np.zeros((3, eng.max_pages), np.int32)
+            for i in range(3):
+                meta[:3, i] = (i, 5, 0)
+                tables[i, 0] = 3 + i
+            meta[-2, 0] = 3
+            meta[-1, 1] = eos_of_row_1 + 1
+            return meta, tables
+        # what row 1 emits at its second step, unbounded, is its eos next
+        tokens, steps, _ = block(*install(-1))
+        assert steps == 4
+        eos = int(tokens[1, 1])
+        assert eos != int(tokens[1, 0])
+        eng._state, eng._cache = eng._init_state(0), eng._init_cache(rows)
+
+        tokens, steps, pool = block(*install(eos))
+        assert steps == 4                          # row 2 ran them all
+        written = np.abs(pool).sum(axis=(0, 2, 4))  # [page, offset]
+        for page, took in ((3, 3), (4, 2), (5, 4)):
+            assert (written[page, 5:5 + took] > 0).all()
+            assert not written[page, 5 + took:].any(), page
+            assert not written[page, :5].any()
+        state = [np.asarray(a) for a in eng._state]
+        assert state[3][:, 0].tolist() == [0, 0, 5, 0]       # tables
+        assert state[1].tolist() == [0, 0, 9, 0]             # positions
+        assert state[0][:2].tolist() == [0, 0]               # tokens
+        # the next block: rows 0 and 1 stay as they ended, their pages
+        # bit for bit; row 2 alone is stepped
+        idle = (np.asarray(eng._no_admit[0]), np.zeros_like(install(0)[1]))
+        _, steps, after = block(*idle)
+        assert steps == 4
+        assert (after[:, 3:5] == pool[:, 3:5]).all()
+        assert (after[:, 5] != pool[:, 5]).any()
+        # and with row 2 redirected, as the host does, nobody is live
+        meta = idle[0].copy()
+        meta[0, 0] = 2
+        _, steps, last = block(meta, idle[1])
+        assert steps == 0
+        assert (last == after).all()
+    finally:
+        eng.close()
+
+
+@pytest.fixture(scope="module")
+def drafting_parts():
+    """The tiny preset that drafts with its own prediction module."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import GPT, get_config
+
+    cfg = get_config("tiny-k-exaone")
+    params = GPT(cfg).init(jax.random.PRNGKey(1),
+                           jnp.zeros((1, 8), jnp.int32))["params"]
+    return cfg, params
+
+
+def _drafting_engine(cfg, params, **kw):
+    from ray_tpu.serve.llm_engine import LLMEngine
+    return LLMEngine(cfg, params, **{
+        "num_slots": 6, "page_size": 4, "max_seq_len": 96,
+        "max_prompt_len": 32, "min_prefill_bucket": 8, **kw})
+
+
+def test_a_drafting_engine_s_mixed_batch_is_greedy_without_the_module(
+        drafting_parts):
+    """``_spec_block_fn`` under budgets: greedy requests that end at
+    steps 0, 1, 17, 31 of a block and in a second one, by an eos in the
+    middle of a block and by max_seq_len, get the tokens the plain
+    reference gives the same model without its module."""
+    import dataclasses
+
+    import flax.linen as nn
+
+    cfg, params = drafting_parts
+    plain = (dataclasses.replace(cfg, mtp_layers=0),
+             {k: v for k, v in nn.unbox(params).items() if k != "mtp"})
+    prompts = [[1 + i, 2 + i, 3 + i][:1 + i % 3] + [40 + i]
+               for i in range(len(_ENDS_AT) + 1)] + [list(range(7, 7 + 30))]
+    lone = _lone_expect(*plain, prompts, n=70)
+    asked = list(_ENDS_AT.values()) + [70, 70]
+    stream = lone[-2]
+    at = max(j for j in range(3, 20) if stream[j] not in stream[:j])
+    requests = [dict(prompt=p, max_new_tokens=n, temperature=0.0)
+                for p, n in zip(prompts, asked)]
+    requests[-2]["eos_id"] = stream[at]
+    want = [s[:n] for s, n in zip(lone, asked)]
+    want[-2] = stream[:at + 1]
+    want[-1] = lone[-1][:96 - 30]
+    eng = _drafting_engine(cfg, params, num_slots=8)
+    try:
+        ran = _blocks_run(eng)
+        replies = _serve_each(eng, requests)
+        _until(lambda: ran and ran[-1] == 0
+               and eng.load_snapshot()["busy_slots"] == 0)
+        st = eng.stats
+        for r, tokens in zip(replies, want):
+            assert r.tokens == tokens
+            _decode_account_holds(r)
+        assert replies[-2].finish_reason == "eos"
+        assert st.steps == sum(ran) < st.block_steps_offered - 32
+        assert st.drafts_proposed > 0
+        assert st.step_tokens == st.drafts_proposed + st.drafts_accepted
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("n", [9, 10, 2, 3], ids=[
+    "ends-at-a-second", "ends-at-the-first-of-a-pair", "two", "three"])
+def test_a_lone_drafted_request_costs_the_steps_of_its_pairs(
+        drafting_parts, monkeypatch, n):
+    """Every draft made to stand: a step is a pair, so ``n`` tokens are
+    the prefill's and ``ceil((n - 1) / 2)`` steps, the last of which may
+    overshoot the budget by the pair's second token (the host's to
+    drop).  The loop goes on by what the rows held before a step, one
+    token counted a step: a row that ends ON a pair's second token had
+    two left, so one step for nobody runs behind it; the block behind
+    runs no step."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    generate = importlib.import_module("ray_tpu.models.generate")
+    real = generate.verify_draft
+
+    def every_draft_stands(rng, logits1, logits2, q_logits, draft, **kw):
+        n_, first, second = real(rng, logits1, logits2, q_logits, draft, **kw)
+        return jnp.full_like(n_, 2), draft.astype(first.dtype), second
+    monkeypatch.setattr(generate, "verify_draft", every_draft_stands)
+    cfg, params = drafting_parts
+    eng = _drafting_engine(cfg, params, num_slots=2)
+    try:
+        ran = _blocks_run(eng)
+        r = eng.submit(list(range(1, 10)), max_new_tokens=n,
+                       temperature=1.0)
+        assert len(r.tokens) == n and r.finish_reason == "length"
+        _decode_account_holds(r)
+        _until(lambda: len(ran) == 2)
+        steps = -(-(n - 1) // 2)
+        st = eng.stats
+        assert ran == [steps + ((n - 1) % 2 == 0), 0]
+        assert st.steps == st.block_steps_run == sum(ran)
+        assert st.block_steps_offered == 64
+        assert (st.drafts_proposed, st.drafts_accepted) == (steps, steps)
+        assert st.step_tokens == n - 1
+    finally:
+        eng.close()

@@ -111,7 +111,7 @@ def test_reply_splits_time_after_first_token(server, entry, n_tokens):
 def test_engine_time_accounts_and_prefill_counters(server):
     """The loop thread's accounts only grow, the parts never exceed the
     whole, padded prefill tokens are at least the real ones, and one
-    quantum is block_size steps."""
+    quantum is block_size steps offered and no more than that run."""
     eng = server.engine
     snaps = [eng.stats.snapshot(eng.num_slots)]
     for n in (1, 6, 9):
@@ -129,7 +129,11 @@ def test_engine_time_accounts_and_prefill_counters(server):
         assert s["loop_s"] >= (s["idle_wait_s"] + s["fetch_wait_s"]
                                + s["deliver_s"])
         assert s["prefill_padded_tokens"] >= s["prefill_prompt_tokens"]
-        assert s["steps"] == s["quanta"] * eng.block_size
+        assert s["block_steps_offered"] == s["quanta"] * eng.block_size
+        assert s["steps"] == s["block_steps_run"] <= s["block_steps_offered"]
+    # a reply of n tokens is n - 1 decode steps, with nobody beside it
+    assert [b["steps"] - a["steps"] for a, b in zip(snaps, snaps[1:])] == [
+        0, 5, 8]
     last = snaps[-1]
     assert last["fetch_wait_s"] > 0 and last["deliver_s"] > 0
     assert last["idle_wait_s"] > 0      # and its sleeps are on the account
