@@ -195,12 +195,6 @@ SECTIONS = {
     "vision": dict(cmd=[sys.executable,
                         os.path.join(REPO, "benchmarks", "vision_perf.py")],
                    timeout=1800),
-    # the composed-kernel MFU ceiling for the flagship model (VERDICT r4
-    # task #5's "committed roofline note"); ~20 min of chip compiles
-    "roofline": dict(cmd=[sys.executable,
-                          os.path.join(REPO, "benchmarks",
-                                       "roofline_gpt.py")],
-                     timeout=3600),
 }
 
 

@@ -3,8 +3,8 @@
 Its own program, run by hand: the simulated multi-device mesh needs
 ``JAX_PLATFORMS=cpu`` + ``XLA_FLAGS=--xla_force_host_platform_device_
 count=N`` pinned before the first backend touch.  Its rows are counts and
-host timings on forced host devices — never device metrics — so
-``bench.py`` (the chip's JSON line) does not carry them.
+host timings on forced host devices — never device metrics: the chip's
+numbers are ``chipbench/``'s (``PERF_LEDGER.jsonl``).
 
 Two legs:
 
@@ -79,11 +79,17 @@ def _model_overrides(args) -> dict:
     return ov
 
 
-def _flops_per_token(cfg, seq: int) -> float:
-    # PaLM-style: 6N per token fwd+bwd + attention 12*L*d*S (the same
-    # arithmetic bench.py and sharded_train_loop use)
-    return (6 * cfg.num_params()
-            + 12 * cfg.n_layers * cfg.d_model * seq)
+def _mfu(model_cfg, seq: int, tokens: int, productive_ms: float,
+         peak_flops: float) -> float:
+    """Model FLOPs of ``tokens`` over the productive time, against
+    ``peak_flops``: the trainer's ledger's and the benchmark's count
+    (``TransformerConfig.train_flops_per_token``).  6 decimals: against
+    a TPU peak the simulated-CPU MFU is ~1e-6 — visible precision keeps
+    the column a consistency check instead of a constant 0.0."""
+    if productive_ms <= 0 or peak_flops <= 0:
+        return 0.0
+    return round(model_cfg.train_flops_per_token(seq) * tokens
+                 / (productive_ms / 1000.0) / peak_flops, 6)
 
 
 # ---------------------------------------------------------------------------
@@ -218,15 +224,8 @@ def run_elastic(args) -> dict:
             productive_ms / (wall_s * 1000.0 * world), 4) \
             if wall_s > 0 else 0.0
         tokens_total = world * steps * args.batch * args.seq
-        # 6 decimals: against a TPU peak the simulated-CPU MFU is
-        # ~1e-6 — visible precision keeps the column a consistency
-        # check instead of a constant 0.0
-        mfu_overall = 0.0
-        if productive_ms > 0 and args.peak > 0:
-            mfu_overall = round(
-                _flops_per_token(model_cfg, args.seq) * tokens_total
-                / (productive_ms / 1000.0)
-                / (args.peak * args.devices), 6)
+        mfu_overall = _mfu(model_cfg, args.seq, tokens_total,
+                           productive_ms, args.peak * args.devices)
 
         return {
             "config": args.model,

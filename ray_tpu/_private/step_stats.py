@@ -10,8 +10,8 @@ through the gradient allreduce, and "why is MFU 0.51 and not 0.55" is
 unanswerable without a per-step phase breakdown.  Four pieces:
 
 * **StepClock** — a per-rank, per-step phase timer the train loop drives
-  (``bench.py`` timing discipline: phases are cut by explicit fences,
-  ``jax.block_until_ready`` for device compute).  Every step decomposes
+  (phases are cut by explicit fences, ``jax.block_until_ready`` for
+  device compute).  Every step decomposes
   into ``data_wait / host_dispatch / device_compute / grad_allreduce /
   optimizer / checkpoint`` slices, published three ways: runtime-metrics
   histogram families (``ray_tpu_train_step_ms`` / ``_phase_ms``,
@@ -33,9 +33,7 @@ unanswerable without a per-step phase breakdown.  Four pieces:
   step time, checkpoint time, idle/restart gaps, tokens, model FLOPs ->
   MFU and goodput fraction), pushed to the GCS at run end and exposed
   via ``experimental.state.training_summary()`` / ``ray-tpu summary
-  training`` / the dashboard Training tab.  ``bench.py`` consumes the
-  same ledger so BENCH rows carry ``goodput`` and a phase breakdown
-  instead of recomputing MFU by hand.
+  training`` / the dashboard Training tab.
 
 * **merged_profile_trace** — folds per-rank ``profile`` RPC captures
   (``ray-tpu profile --group``) into one Perfetto-compatible trace
@@ -346,7 +344,7 @@ class _RunContext:
 
 def _events_buffer():
     """The connected process's task-event buffer (timeline sink), or
-    (None, ...) standalone — bench.py runs without a cluster."""
+    (None, ...) standalone — a loop may run without a cluster."""
     try:
         from ray_tpu.runtime import core_worker as cw
         worker = cw.get_global_worker()
@@ -388,7 +386,7 @@ class StepClock:
     ``end(tokens=...)`` closes the step and publishes metrics, the
     timeline slice and the GCS report.  Phase timing relies on the
     caller fencing device work (``jax.block_until_ready`` inside the
-    ``device_compute`` phase — the bench.py discipline); an unfenced
+    ``device_compute`` phase); an unfenced
     dispatch attributes device time to whichever phase next blocks on
     the device queue.
 
@@ -492,7 +490,8 @@ def start_run(run_id: Optional[str] = None, *, group: str = "",
               sink: Optional[Callable[[List[dict]], Any]] = None,
               meta: Optional[dict] = None) -> Optional[_RunContext]:
     """Open a training-run context on this thread (TrainWorker installs
-    one around the user loop; bench.py opens its own).  ``sink`` takes
+    one around the user loop; a standalone loop opens its own).  ``sink``
+    takes
     report batches (``report_step_stats`` payload); None = local-only
     (the ledger still accumulates).  Returns None when disabled."""
     # raylint: disable=kill-switch -- once per training RUN, not per step; disabled runs get the shared no-op clock
@@ -586,7 +585,8 @@ def instrument_step(step_fn: Callable, *,
     ``begin`` -> dispatch as ``host_dispatch`` -> ``block_until_ready``
     fence as ``device_compute`` -> ``end``.  The fence serializes the
     device pipeline — use the explicit :func:`step_clock` API in
-    throughput-critical loops and fence only where bench.py does."""
+    throughput-critical loops and fence only where a result is read
+    (``sharded_train_loop``: the loss fetch)."""
     def timed(*args, **kwargs):
         clock = step_clock()
         clock.begin()

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 POOL_KINDS = ("full_attention", "attention_only")
 STATE_KINDS = ("linear_attention", "mamba2")
 MIXER_KINDS = ("mamba2", "latent_moe", "attention_only")
+# ``TransformerConfig.attention_impl`` (ops/attention.py says what each is)
+ATTENTION_IMPLS = ("auto", "xla", "flash", "ring", "ulysses")
 
 
 @dataclasses.dataclass
@@ -34,12 +36,8 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     dtype: jnp.dtype = jnp.bfloat16        # activation dtype
     param_dtype: jnp.dtype = jnp.float32
-    attention_impl: str = "auto"           # auto | xla | flash | splash | ring | ulysses
+    attention_impl: str = "auto"           # one of ATTENTION_IMPLS
     remat: bool = True                     # checkpoint each block (HBM <-> FLOPs)
-    remat_layers: Optional[int] = None     # None -> all; K -> only the first
-    # K layers rematerialize, the rest store activations (partial remat:
-    # spends HBM headroom to cut the backward recompute, the knob between
-    # "nothing" and no-remat that per-policy selection can't reach)
     remat_policy: str = "dots"             # "dots": save no-batch-dim dots
     # (cheap recompute, more HBM); "nothing": full per-block recompute —
     # the memory-lean setting that fits ~1B params on one 16 GiB chip
@@ -176,6 +174,10 @@ class TransformerConfig:
         if self.moe_d_ff is None:
             self.moe_d_ff = self.d_ff
         assert self.n_heads % self.n_kv_heads == 0
+        if self.attention_impl not in ATTENTION_IMPLS:
+            raise ValueError(
+                f"attention_impl={self.attention_impl!r}: must be one of "
+                + " | ".join(ATTENTION_IMPLS))
         assert self.moe_act in ("silu", "relu", "relu2")
         assert self.moe_scoring in ("softmax", "sigmoid")
         if self.kv_lora_rank:

@@ -107,10 +107,6 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
     depth). Must be called from a parent's ``@nn.compact`` __call__.
     Blocks are invoked ``mdl(x, *call_args)``.
 
-    cfg.remat_layers splits the stack at the CALLER (two stack_layers
-    calls, one rematted, one plain) — partial remat for configs with
-    HBM headroom between "recompute everything" and "store everything".
-
     ``carry`` is state that rides the stack beside ``x`` as LOOP-CARRIED
     state, never as a scanned (sliced-in, stacked-out) variable: the
     paged KV pool ``[n_layers, pages, ...]``, which every block updates
@@ -437,12 +433,12 @@ class Attention(nn.Module):
             return ulysses_attention(q, k, v, mesh=self.mesh, causal=True)
         from ray_tpu.ops.attention import attention, resolve_impl
         impl = resolve_impl(impl)
-        if impl in ("flash", "splash") and self.mesh is not None \
+        if impl == "flash" and self.mesh is not None \
                 and self.mesh.size > 1:
-            return self._kernel_attend_sharded(q, k, v, impl)
+            return self._kernel_attend_sharded(q, k, v)
         return attention(q, k, v, causal=True, impl=impl)
 
-    def _kernel_attend_sharded(self, q, k, v, impl: str):
+    def _kernel_attend_sharded(self, q, k, v):
         """A Pallas (Mosaic) kernel is one opaque custom call: GSPMD
         cannot partition it, and lowering it with sharded operands on a
         multi-chip TPU mesh is refused outright.  So the kernel runs per
@@ -458,7 +454,7 @@ class Attention(nn.Module):
         cfg, mesh = self.cfg, self.mesh
         if mesh.shape.get("context", 1) > 1:
             raise ValueError(
-                f"attention_impl={impl!r} needs the whole sequence on "
+                "attention_impl='flash' needs the whole sequence on "
                 "each shard; with context parallelism use 'ring' or "
                 "'ulysses'")
         if cfg.n_kv_heads != cfg.n_heads:
@@ -467,7 +463,7 @@ class Attention(nn.Module):
             v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
         spec = logical_spec(("batch", None, "heads", None), mesh,
                             self.rules)
-        fn = functools.partial(attention, causal=True, impl=impl)
+        fn = functools.partial(attention, causal=True, impl="flash")
         return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
@@ -1520,11 +1516,11 @@ class GPT(nn.Module):
     def _stack_blocks(self, x, block_kwargs, call_args, **stack):
         """``stack_layers`` of ``Block`` over every layer.  Where the
         first ``cfg.first_dense_layers`` have a dense feed-forward and
-        the others experts, two stacks, as the ``remat_layers`` split
-        is two: ``dense_blocks`` (a parameter tree of its own: the
-        kinds differ in leaves, not in two scalars), then ``blocks``,
-        the scanned expert stack, whose layer indices go on from the
-        prefix's; a ``carry`` (the pool) rides through both."""
+        the others experts, two stacks: ``dense_blocks`` (a parameter
+        tree of its own: the kinds differ in leaves, not in two
+        scalars), then ``blocks``, the scanned expert stack, whose layer
+        indices go on from the prefix's; a ``carry`` (the pool) rides
+        through both."""
         cfg = self.cfg
         first = cfg.first_dense_layers
         if not first:
@@ -1549,10 +1545,6 @@ class GPT(nn.Module):
         that hold pages only, the recurrent leaves (their shapes the
         recurrent class's own) those that hold a state entry."""
         cfg = self.cfg
-        if cfg.remat_layers is not None:
-            raise ValueError("remat_layers counts layers of one class; "
-                             "a model that scans periods takes remat "
-                             "on or off")
         n_periods = cfg.n_layers // len(cfg.period)
         if not (self.decode and self.paged_pages):
             if self.decode and not self.is_initializing():
@@ -1659,8 +1651,6 @@ class GPT(nn.Module):
             sin = with_sharding(self.mesh, sin, (None, None), self.rules)
 
         do_remat = cfg.remat and not self.decode
-        n_remat = (cfg.n_layers if cfg.remat_layers is None
-                   else max(0, min(cfg.remat_layers, cfg.n_layers)))
         block_kwargs = dict(mesh=self.mesh, rules=self.rules,
                             decode=self.decode,
                             prefix_attend=self.prefix_attend)
@@ -1696,22 +1686,6 @@ class GPT(nn.Module):
                                          remat=False, carry=ckv.value)
             if not self.is_initializing():
                 ckv.value = pool
-        elif do_remat and 0 < n_remat < cfg.n_layers:
-            if cfg.first_dense_layers:
-                raise ValueError("remat_layers splits ONE stack; a model "
-                                 "with a dense prefix takes remat on or "
-                                 "off")
-            # partial remat: the first n_remat layers recompute in the
-            # backward pass, the tail stores activations (uses the HBM
-            # headroom "policy" selection can't reach)
-            x = stack_layers(Block, cfg, block_kwargs, x,
-                             call_args, remat=True,
-                             cache=True, n_layers=n_remat)
-            x = stack_layers(Block, cfg, block_kwargs, x,
-                             call_args, remat=False,
-                             cache=True, name="blocks_tail",
-                             n_layers=cfg.n_layers - n_remat,
-                             first_layer=n_remat)
         else:
             x = self._stack_blocks(x, block_kwargs, call_args,
                                    remat=do_remat, cache=True)

@@ -4,8 +4,6 @@
   - ``"xla"``    — einsum attention; runs everywhere, materializes [Sq, Sk].
   - ``"flash"``  — Pallas TPU flash kernel (ray_tpu/ops/flash_attention.py);
                    O(S) memory, fused online softmax on the MXU.
-  - ``"splash"`` — JAX's public tuned TPU kernel (comparison impl; not
-    timed by any benchmark cell).
   - ``"auto"``   — flash on TPU backends, xla elsewhere.
 
 Layout convention throughout the framework: ``q``: [batch, q_len, heads,
@@ -103,20 +101,16 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (``flash_attention``); the other impls compute every position, which
     is as good to a caller that reads the real ones."""
     impl = resolve_impl(impl)
-    if impl in ("flash", "splash") and mask is not None:
-        impl = "xla"       # the Pallas kernels have no padding-mask path
-    if impl in ("flash", "splash"):
+    if impl == "flash" and mask is not None:
+        impl = "xla"       # the Pallas kernel has no padding-mask path
+    if impl == "flash":
         heads, kv_heads = q.shape[-2], k.shape[-2]
         if kv_heads != heads:
             k = repeat_kv(k, heads // kv_heads)
             v = repeat_kv(v, heads // kv_heads)
-        if impl == "flash":
-            from ray_tpu.ops.flash_attention import flash_attention
-            return flash_attention(q, k, v, causal=causal,
-                                   sm_scale=sm_scale, q_lens=q_lens)
-        # JAX's tuned public TPU kernel, kept as a comparison impl
-        from ray_tpu.ops.splash import splash_attention
-        return splash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        from ray_tpu.ops.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=causal,
+                               sm_scale=sm_scale, q_lens=q_lens)
     if impl == "xla":
         return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                              mask=mask)

@@ -33,75 +33,9 @@ import pickle
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu.train.sharded.layout import ShardingConfig
+from ray_tpu.train.step import make_grad_apply_step
 
 _KV_PREFIX = "shardckpt"
-
-
-# ---------------------------------------------------------------------------
-# split grad/apply step
-# ---------------------------------------------------------------------------
-
-def make_grad_apply_step(model, mesh, optimizer=None, rules=None,
-                         loss_fn=None, example_batch=None, z_loss=None):
-    """Split variant of :func:`ray_tpu.train.step.make_sharded_train`.
-
-    Returns ``(init_fn, grad_fn, apply_fn, state_shardings,
-    batch_sharding)``:
-
-      - ``grad_fn(state, batch) -> (grads, metrics)`` — jitted forward +
-        backward, grads land in the params' shardings,
-      - ``apply_fn(state, grads) -> state`` — jitted optimizer update
-        with donated state.
-
-    The split exists so a *host-plane* reduction can run between the
-    two: ``sync_gradients`` sees materialized per-rank gradients, and
-    with ``async_op=True`` the ring overlaps the host-side work between
-    issue and fence.  The fused single-jit step stays the right call
-    when the reduction is compiled into the graph instead.
-    """
-    import jax
-
-    from ray_tpu.parallel.sharding import LOGICAL_RULES
-    from ray_tpu.train.step import (OptimizerConfig, TrainState, lm_loss_fn,
-                                    trace_state_shardings)
-    optimizer = optimizer or OptimizerConfig()
-    rules = rules or LOGICAL_RULES
-    loss_fn = loss_fn or lm_loss_fn
-    tx = optimizer.make()
-    if z_loss is None:
-        z_loss = getattr(getattr(model, "cfg", None), "z_loss", 0.0)
-
-    # the three functions' names are the programs' names in a profiler
-    # trace (``jit_train_grad`` on the device's ``XLA Modules`` line)
-    def train_init(rng, batch) -> TrainState:
-        variables = model.init(rng, batch["tokens"][:, :-1])
-        return TrainState.create(apply_fn=model.apply,
-                                 params=variables["params"], tx=tx)
-
-    from jax.sharding import NamedSharding, PartitionSpec
-    state_shardings, batch_sharding = trace_state_shardings(
-        train_init, example_batch, mesh, rules, batch_axes=("batch", None))
-    param_shardings = state_shardings.params
-    repl = NamedSharding(mesh, PartitionSpec())
-
-    def train_grad(state, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            lambda p: loss_fn(state.apply_fn, p, batch, z_loss),
-            has_aux=True)(state.params)
-        return grads, dict(metrics)
-
-    def train_apply(state, grads):
-        return state.apply_gradients(grads=grads)
-
-    init_fn = jax.jit(train_init, out_shardings=state_shardings)
-    grad_fn = jax.jit(train_grad,
-                      in_shardings=(state_shardings, batch_sharding),
-                      out_shardings=(param_shardings, repl))
-    apply_fn = jax.jit(train_apply,
-                       in_shardings=(state_shardings, param_shardings),
-                       out_shardings=state_shardings,
-                       donate_argnums=(0,))
-    return init_fn, grad_fn, apply_fn, state_shardings, batch_sharding
 
 
 # ---------------------------------------------------------------------------

@@ -120,35 +120,10 @@ def _lm_loss_body(batch: Dict[str, jax.Array],
     return loss, metrics
 
 
-def cast_params_once(params: Any, dtype=jnp.bfloat16) -> Any:
-    """Cast f32 matrix/embedding params to the activation dtype OUTSIDE
-    the rematted blocks.
-
-    flax promotes param dtype inside each Dense call — under full remat
-    that cast sits inside the checkpointed region and re-reads the f32
-    master weights on every backward recompute (~6.5 GB of extra HBM
-    traffic per recompute at 1B params).  Hoisting it here makes the
-    bf16 copy a saved residual: one cast per step, measured +1.4pp MFU
-    on gpt-large with remat_policy="nothing" (benchmarks/mfu_sweep.py).
-    1-D leaves (norm scales) stay f32 — their kernels want f32 anyway.
-    Gradients are unchanged: autodiff through the cast accumulates f32.
-    """
-    return jax.tree.map(
-        lambda p: p.astype(dtype)
-        if (hasattr(p, "dtype") and p.dtype == jnp.float32
-            and getattr(p, "ndim", 0) >= 2) else p, params)
-
-
 def lm_loss_fn(apply_fn: Callable, params: Any, batch: Dict[str, jax.Array],
-               z_loss: float = 0.0,
-               param_cast=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token LM loss. batch: {"tokens": [B, S+1] or [B, S], "mask"?}.
-
-    ``param_cast``: optional dtype for :func:`cast_params_once` (models
-    computing in bf16 with f32 masters under remat)."""
-    if param_cast is not None:
-        params = cast_params_once(params, param_cast)
-
+               z_loss: float = 0.0
+               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Next-token LM loss. batch: {"tokens": [B, S+1] or [B, S], "mask"?}."""
     def head(inputs, mask, targets):
         logits, mutated = apply_fn({"params": params}, inputs,
                                    mutable=["intermediates"])
@@ -162,8 +137,7 @@ def lm_loss_chunked_fn(apply_fn: Callable, params: Any,
                        batch: Dict[str, jax.Array],
                        z_loss: float = 0.0,
                        chunk_size: int = 256,
-                       head_weight: Optional[Callable] = None,
-                       param_cast=None
+                       head_weight: Optional[Callable] = None
                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Next-token LM loss with the chunked projection head
     (ops/losses.py chunked_lm_loss): the logits tensor's peak HBM drops
@@ -176,12 +150,7 @@ def lm_loss_chunked_fn(apply_fn: Callable, params: Any,
     ``lm_head`` Dense, else the tied ``embed`` table — and raises for
     models that match neither; pass an explicit selector (e.g. via
     functools.partial) for other architectures.
-
-    ``param_cast``: optional dtype for :func:`cast_params_once`.
     """
-    if param_cast is not None:
-        params = cast_params_once(params, param_cast)
-
     def head(inputs, mask, targets):
         hidden, mutated = apply_fn({"params": params}, inputs,
                                    mutable=["intermediates"],
@@ -209,8 +178,7 @@ def trace_state_shardings(build_state, example_batch, mesh: Mesh,
                           rules: ShardingRules, batch_axes=("batch",)):
     """Trace the state abstractly and map its logical PartitionSpecs to
     mesh shardings.  Returns (state_shardings, batch_sharding) — the
-    contract both the fused step below and the sharded executor's split
-    grad/apply step (train/sharded/executor.py) build on."""
+    contract both the fused and the split step below build on."""
     if example_batch is None:
         raise ValueError("example_batch is required to trace shapes")
     abstract = jax.eval_shape(build_state, jax.random.PRNGKey(0),
@@ -256,23 +224,14 @@ def _born_sharded(build_state, step, example_batch, mesh: Mesh,
     return init_fn, step_fn, state_shardings, batch_sharding
 
 
-def make_sharded_train(model: nn.Module,
-                       mesh: Mesh,
-                       optimizer: Optional[OptimizerConfig] = None,
-                       rules: ShardingRules = LOGICAL_RULES,
-                       loss_fn: Callable = lm_loss_fn,
-                       example_batch: Optional[Dict[str, jax.Array]] = None,
-                       z_loss: Optional[float] = None,
-                       init_inputs: Optional[Callable] = None):
-    """Returns (init_fn, step_fn, state_shardings, batch_sharding).
-
-    ``init_fn(rng, batch) -> TrainState`` born sharded over ``mesh``;
-    ``step_fn(state, batch) -> (state, metrics)`` jitted with donated state.
-    ``init_inputs(batch) -> args tuple`` overrides how model.init is called
-    (default: next-token LM convention, ``batch["tokens"][:, :-1]``).
-    """
-    optimizer = optimizer or OptimizerConfig()
-    tx = optimizer.make()
+def _lm_train_parts(model: nn.Module, optimizer: Optional[OptimizerConfig],
+                    loss_fn: Callable, z_loss: Optional[float],
+                    init_inputs: Optional[Callable]):
+    """What the fused and the split step are both built on:
+    ``train_init(rng, batch) -> TrainState`` and
+    ``loss_and_grads(state, batch) -> ((loss, metrics), grads)``.
+    ``z_loss=None`` takes the model config's."""
+    tx = (optimizer or OptimizerConfig()).make()
     if z_loss is None:
         z_loss = getattr(getattr(model, "cfg", None), "z_loss", 0.0)
 
@@ -284,11 +243,36 @@ def make_sharded_train(model: nn.Module,
         return TrainState.create(apply_fn=model.apply,
                                  params=variables["params"], tx=tx)
 
+    def loss_and_grads(state: TrainState, batch):
+        return jax.value_and_grad(
+            lambda p: loss_fn(state.apply_fn, p, batch, z_loss),
+            has_aux=True)(state.params)
+
+    return train_init, loss_and_grads
+
+
+def make_sharded_train(model: nn.Module,
+                       mesh: Mesh,
+                       optimizer: Optional[OptimizerConfig] = None,
+                       rules: ShardingRules = LOGICAL_RULES,
+                       loss_fn: Callable = lm_loss_fn,
+                       example_batch: Optional[Dict[str, jax.Array]] = None,
+                       z_loss: Optional[float] = None,
+                       init_inputs: Optional[Callable] = None):
+    """The fused step: one jit.  Returns (init_fn, step_fn,
+    state_shardings, batch_sharding).
+
+    ``init_fn(rng, batch) -> TrainState`` born sharded over ``mesh``;
+    ``step_fn(state, batch) -> (state, metrics)`` jitted with donated state.
+    ``init_inputs(batch) -> args tuple`` overrides how model.init is called
+    (default: next-token LM convention, ``batch["tokens"][:, :-1]``).
+    """
+    train_init, loss_and_grads = _lm_train_parts(
+        model, optimizer, loss_fn, z_loss, init_inputs)
+
     def train_step(state: TrainState, batch
                    ) -> Tuple[TrainState, Dict[str, Any]]:
-        grad_fn = jax.value_and_grad(
-            lambda p: loss_fn(state.apply_fn, p, batch, z_loss), has_aux=True)
-        (loss, metrics), grads = grad_fn(state.params)
+        (loss, metrics), grads = loss_and_grads(state, batch)
         new_state = state.apply_gradients(grads=grads)
         metrics = dict(metrics)
         metrics["grad_norm"] = optax.global_norm(grads)
@@ -296,6 +280,57 @@ def make_sharded_train(model: nn.Module,
 
     return _born_sharded(train_init, train_step, example_batch, mesh, rules,
                          batch_axes=("batch", None))
+
+
+def make_grad_apply_step(model: nn.Module,
+                         mesh: Mesh,
+                         optimizer: Optional[OptimizerConfig] = None,
+                         rules: ShardingRules = LOGICAL_RULES,
+                         loss_fn: Callable = lm_loss_fn,
+                         example_batch: Optional[Dict[str, jax.Array]] = None,
+                         z_loss: Optional[float] = None,
+                         init_inputs: Optional[Callable] = None):
+    """The split step: :func:`make_sharded_train`'s arguments, two jits.
+    Returns ``(init_fn, grad_fn, apply_fn, state_shardings,
+    batch_sharding)``:
+
+      - ``grad_fn(state, batch) -> (grads, metrics)`` — jitted forward +
+        backward, grads land in the params' shardings,
+      - ``apply_fn(state, grads) -> state`` — jitted optimizer update
+        with donated state.
+
+    The split exists so a *host-plane* reduction can run between the
+    two (the gang loop, train/sharded/executor.py): ``sync_gradients``
+    sees materialized per-rank gradients, and with ``async_op=True`` the
+    ring overlaps the host-side work between issue and fence.  The fused
+    step stays the right call when the reduction is compiled into the
+    graph instead.
+    """
+    train_init, loss_and_grads = _lm_train_parts(
+        model, optimizer, loss_fn, z_loss, init_inputs)
+    state_shardings, batch_sharding = trace_state_shardings(
+        train_init, example_batch, mesh, rules, batch_axes=("batch", None))
+    param_shardings = state_shardings.params
+    repl = NamedSharding(mesh, PartitionSpec())
+
+    # like ``train_init``, the two functions' names are the programs'
+    # names in a profiler trace (``jit_train_grad``, ``jit_train_apply``)
+    def train_grad(state, batch):
+        (loss, metrics), grads = loss_and_grads(state, batch)
+        return grads, dict(metrics)
+
+    def train_apply(state, grads):
+        return state.apply_gradients(grads=grads)
+
+    init_fn = jax.jit(train_init, out_shardings=state_shardings)
+    grad_fn = jax.jit(train_grad,
+                      in_shardings=(state_shardings, batch_sharding),
+                      out_shardings=(param_shardings, repl))
+    apply_fn = jax.jit(train_apply,
+                       in_shardings=(state_shardings, param_shardings),
+                       out_shardings=state_shardings,
+                       donate_argnums=(0,))
+    return init_fn, grad_fn, apply_fn, state_shardings, batch_sharding
 
 
 def classification_loss_fn(logits: jax.Array, labels: jax.Array

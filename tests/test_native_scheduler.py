@@ -274,19 +274,14 @@ def test_sharded_trainer_lease(monkeypatch, nodes, workers, want):
         assert tpu_lease_per_worker(workers) == want
 
 
-def test_bench_refuses_unknown_devices():
-    import os
-    import sys
-    import types
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
-    assert bench.peak_flops(v5e) == 197e12
-    for dev in (types.SimpleNamespace(platform="cpu", device_kind="cpu"),
-                types.SimpleNamespace(platform="tpu", device_kind="TPU v9")):
+def test_peaks_table_refuses_unknown_devices():
+    """The one table of peaks (``chipbench/lib/peaks.py``): the v5e's
+    published bf16 peak, and no default for a kind it does not hold."""
+    from chipbench.lib.peaks import peaks_for
+    assert peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
+    for kind in ("cpu", "TPU v9"):
         with pytest.raises(SystemExit):
-            bench.peak_flops(dev)
+            peaks_for(kind)
 
 
 def test_gcs_health_check_credits_its_own_pause():

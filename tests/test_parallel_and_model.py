@@ -194,59 +194,138 @@ def test_generation_samplers_and_eos():
                                   np.asarray(jnp.argmax(logits, -1)))
 
 
-def test_partial_remat_split_stack():
-    """cfg.remat_layers splits the stack into a rematted head and a
-    plain tail (two scan scopes); the forward math is unchanged vs the
-    single-stack model and a train step runs."""
-    import jax
-    import jax.numpy as jnp
+def _tiny_lm_batch(cfg, rows):
+    rng = np.random.default_rng(0)
+    return {"tokens": jnp.asarray(
+        rng.integers(0, cfg.vocab_size, (rows, 33)), jnp.int32)}
 
-    from ray_tpu.models import GPT, get_config
-    from ray_tpu.train.step import OptimizerConfig, make_sharded_train
-    from ray_tpu.parallel import MeshConfig, build_mesh
 
-    cfg = get_config("tiny", max_seq_len=64, remat=True,
-                     remat_policy="nothing", remat_layers=1)
-    model = GPT(cfg)
-    tokens = jnp.arange(2 * 32, dtype=jnp.int32).reshape(2, 32) % 256
-    variables = model.init(jax.random.PRNGKey(0), tokens)
-    assert "blocks_tail" in variables["params"], \
-        "partial remat must create the plain tail scope"
-    logits = model.apply(variables, tokens)
-    assert jnp.isfinite(logits).all()
+def test_fused_and_split_steps_agree():
+    """``make_sharded_train`` (one jit) and ``make_grad_apply_step``
+    (``train_grad`` + ``train_apply``) are one construction: from the
+    same seed and batch they give the same loss and the same
+    parameters, up to float32 reassociation (two steps each)."""
+    from ray_tpu.train.sharded import make_grad_apply_step
+    from ray_tpu.train.step import \
+        make_grad_apply_step as defined_in_step
 
+    assert make_grad_apply_step is defined_in_step
     mesh = build_mesh(MeshConfig(data=-1))
-    m_model = GPT(cfg, mesh=mesh)
-    n_dev = len(jax.devices())
-    batch = {"tokens": jnp.arange(n_dev * 33, dtype=jnp.int32
-                                  ).reshape(n_dev, 33) % 256}
-    init_fn, step_fn, _, _ = make_sharded_train(
-        m_model, mesh, OptimizerConfig(warmup_steps=1, decay_steps=10),
-        example_batch=batch)
+    cfg = get_config("tiny", max_seq_len=64, attention_impl="xla")
+    model = GPT(cfg, mesh=mesh)
+    batch = _tiny_lm_batch(cfg, len(jax.devices()))
+    opt = OptimizerConfig(learning_rate=1e-2, warmup_steps=1,
+                          decay_steps=10)
+    init_fn, step_fn, fused_sh, _ = make_sharded_train(
+        model, mesh, opt, example_batch=batch)
+    init2, grad_fn, apply_fn, split_sh, _ = make_grad_apply_step(
+        model, mesh, opt, example_batch=batch)
+    assert jax.tree.leaves(fused_sh) == jax.tree.leaves(split_sh)
+    key = jax.random.PRNGKey(0)
+    before = jax.device_get(init2(key, batch).params)
+    fused, split = init_fn(key, batch), init2(key, batch)
+    for _ in range(2):      # the schedule's first step has rate 0
+        fused, fused_metrics = step_fn(fused, batch)
+        grads, metrics = grad_fn(split, batch)
+        split = apply_fn(split, grads)
+        assert float(metrics["loss"]) == pytest.approx(
+            float(fused_metrics["loss"]), rel=1e-6)
+    moved = 0.0
+    for was, a, b in zip(jax.tree.leaves(before),
+                         jax.tree.leaves(fused.params),
+                         jax.tree.leaves(split.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+        moved = max(moved, float(np.abs(np.asarray(a) - was).max()))
+    assert moved > 1e-4, "the steps moved no parameter"
+    assert int(split.step) == int(fused.step) == 2
+
+
+def test_split_step_takes_init_inputs():
+    """The split step initialises a model whose ``init`` does not take
+    ``tokens[:, :-1]`` (T5: encoder and decoder tokens), as the fused
+    one does: they share ``train_init``."""
+    from ray_tpu.models.t5 import T5, seq2seq_loss_fn, t5_init_inputs
+    from ray_tpu.train.step import make_grad_apply_step
+
+    cfg = get_config("tiny", max_seq_len=32)
+    mesh = build_mesh(MeshConfig(data=-1))
+    rng = np.random.default_rng(0)
+    batch = {"enc_tokens": jnp.asarray(rng.integers(1, 256, (8, 12)),
+                                       jnp.int32),
+             "dec_tokens": jnp.asarray(rng.integers(1, 256, (8, 9)),
+                                       jnp.int32)}
+    init_fn, grad_fn, apply_fn, _, _ = make_grad_apply_step(
+        T5(cfg, mesh=mesh), mesh,
+        OptimizerConfig(warmup_steps=1, decay_steps=20),
+        loss_fn=seq2seq_loss_fn, example_batch=batch,
+        init_inputs=t5_init_inputs)
     state = init_fn(jax.random.PRNGKey(0), batch)
-    state, metrics = step_fn(state, batch)
-    assert bool(jnp.isfinite(metrics["loss"]))
+    losses = []
+    for _ in range(4):
+        grads, metrics = grad_fn(state, batch)
+        state = apply_fn(state, grads)
+        losses.append(float(metrics["loss"]))
+    assert losses[-1] < losses[0]
 
 
-def test_cast_params_once_identical_loss():
-    """The hoisted f32->bf16 cast changes scheduling, not numerics: the
-    loss equals the uncast path bit-for-bit (flax promotes to the same
-    bf16 values inside each Dense)."""
-    import functools
+@pytest.mark.parametrize("remat,policy", [
+    (False, "dots"), (True, "nothing"), (True, "block_outs"),
+    (True, "dots"), (True, "dots_all")])
+def test_memory_options_do_not_rename_weights(remat, policy):
+    """A model's parameter tree does not depend on a memory option:
+    without remat and under each of ``stack_layers``' policies ``init``
+    yields one tree (a checkpoint saved under one loads under another),
+    and logits and gradients agree."""
+    import dataclasses
 
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import GPT, get_config
     from ray_tpu.train.step import lm_loss_fn
 
-    cfg = get_config("tiny", max_seq_len=64, dtype=jnp.bfloat16)
-    model = GPT(cfg)
-    tokens = (jnp.arange(2 * 33, dtype=jnp.int32).reshape(2, 33) * 7) % 256
-    params = model.init(jax.random.PRNGKey(0),
-                        tokens[:, :-1])["params"]
-    batch = {"tokens": tokens}
-    base, _ = lm_loss_fn(model.apply, params, batch)
-    cast, _ = lm_loss_fn(model.apply, params, batch,
-                         param_cast=jnp.bfloat16)
-    assert float(base) == float(cast)
+    base = get_config("tiny", max_seq_len=64, remat=False)
+    cfg = get_config("tiny", max_seq_len=64, remat=remat,
+                     remat_policy=policy)
+    # no field is left that splits the stack in two
+    assert {f.name for f in dataclasses.fields(cfg)
+            if f.name.startswith("remat")} == {"remat", "remat_policy"}
+    batch = _tiny_lm_batch(cfg, 2)
+    tokens = batch["tokens"][:, :-1]
+    want = GPT(base).init(jax.random.PRNGKey(0), tokens)["params"]
+    got = GPT(cfg).init(jax.random.PRNGKey(0), tokens)["params"]
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert [k for k in got if "blocks" in k] == ["blocks"]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(
+        np.asarray(GPT(cfg).apply({"params": want}, tokens)),
+        np.asarray(GPT(base).apply({"params": want}, tokens)),
+        rtol=1e-5, atol=1e-5)
+
+    def grads(model):
+        return jax.grad(lambda p: lm_loss_fn(model.apply, p, batch)[0])(want)
+
+    for a, b in zip(jax.tree.leaves(grads(GPT(cfg))),
+                    jax.tree.leaves(grads(GPT(base)))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["auto", "xla", "flash", "ring", "ulysses"])
+def test_attention_impl_legal_values_construct(impl):
+    from ray_tpu.models.configs import ATTENTION_IMPLS
+
+    assert impl in ATTENTION_IMPLS and len(ATTENTION_IMPLS) == 5
+    assert get_config("tiny", attention_impl=impl).attention_impl == impl
+
+
+@pytest.mark.parametrize("impl", ["splash", "flahs"])
+def test_attention_impl_is_checked_where_the_config_is_built(impl):
+    """A stale or misspelt ``attention_impl`` fails in ``get_config``,
+    by name and with the legal values, not at trace time inside
+    ``attention()``."""
+    with pytest.raises(ValueError) as err:
+        get_config("tiny", attention_impl=impl)
+    assert repr(impl) in str(err.value)
+    for legal in ("auto", "xla", "flash", "ring", "ulysses"):
+        assert legal in str(err.value)
+
+

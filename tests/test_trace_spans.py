@@ -297,6 +297,24 @@ def test_loop_and_benchmark_count_the_same_operations():
     ours = cfg.train_flops_per_token(1024)
     theirs = flops.train_flops_per_token(published, 1024)
     assert ours == pytest.approx(theirs, rel=0.01)
-    # and it is not the old count (embedding counted, attention twice)
-    old = 6 * cfg.num_params() + 12 * cfg.n_layers * cfg.d_model * 1024
-    assert abs(old - theirs) / theirs > 0.01
+
+
+def test_sandbox_train_bench_counts_the_trainer_s_operations():
+    """``benchmarks/sharded_train_bench.py``'s MFU column counts
+    ``TransformerConfig.train_flops_per_token`` and nothing of its own:
+    one FLOP count in the tree."""
+    import importlib.util
+    import os
+
+    from ray_tpu.models.configs import get_config
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "sharded_train_bench.py")
+    spec = importlib.util.spec_from_file_location("sharded_train_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    cfg = get_config("gpt-small")
+    seq, tokens, ms, peak = 1024, 4 * 16 * 1024, 2000.0, 197e12
+    want = cfg.train_flops_per_token(seq) * tokens / (ms / 1e3) / peak
+    assert bench._mfu(cfg, seq, tokens, ms, peak) == round(want, 6)
+    assert bench._mfu(cfg, seq, tokens, ms, 0.0) == 0.0
