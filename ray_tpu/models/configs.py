@@ -17,7 +17,10 @@ import jax.numpy as jnp
 # keeps of a sequence: KV pages in the paged pool, or a fixed-size
 # recurrent state entry a request; and those that are one mixer a layer
 POOL_KINDS = ("full_attention", "attention_only")
-STATE_KINDS = ("linear_attention", "mamba2")
+STATE_KINDS = ("linear_attention", "mamba2", "mamba2_mlp")
+# of those, the classes whose recurrence is Mamba-2's: their state leaves
+# are ``ssm_state`` / ``ssm_conv`` (models/gpt.py GPT._stack_periods)
+MAMBA_KINDS = ("mamba2", "mamba2_mlp")
 MIXER_KINDS = ("mamba2", "latent_moe", "attention_only")
 # ``TransformerConfig.attention_impl`` (ops/attention.py says what each is)
 ATTENTION_IMPLS = ("auto", "xla", "flash", "ring", "ulysses")
@@ -71,9 +74,9 @@ class TransformerConfig:
     sliding_window: Optional[int] = None
     rope_layout: Optional[tuple] = None
     window_layout: Optional[tuple] = None
-    # Per-layer BLOCK classes ("full_attention" | "linear_attention",
-    # and the single-mixer classes further down; layer l is entry l,
-    # the tuple may be longer than n_layers).  Where
+    # Per-layer BLOCK classes ("full_attention" | "linear_attention" |
+    # "mamba2_mlp", and the single-mixer classes further down; layer l
+    # is entry l, the tuple may be longer than n_layers).  Where
     # it names more than one class the layer stack scans one PERIOD of
     # it (``period``; models/gpt.py Period).  A linear_attention layer is
     # a Gated DeltaNet mixer
@@ -83,6 +86,9 @@ class TransformerConfig:
     # linear_conv_kernel taps in front; linear_allow_neg_eigval: the
     # write strength ranges over (0, 2), not (0, 1).  It holds no KV
     # pages but a fixed-size state a request (serve/llm_engine.py).
+    # A mamba2_mlp layer (models/gpt.py MambaBlock) is the same pre-norm
+    # block around a Mamba-2 mixer (the mamba_* sizes further down) and
+    # a dense SwiGLU of d_ff: the granitemoehybrid family's layer.
     layer_types: Optional[tuple] = None
     linear_key_heads: Optional[int] = None
     linear_value_heads: Optional[int] = None
@@ -162,6 +168,18 @@ class TransformerConfig:
     # pages as a layer of the stack does (the pool's last layer), and the
     # serving engine drafts with it (serve/llm_engine.py)
     mtp_layers: int = 0
+    # Four published scalars of a model trained under muP (the granite
+    # family's), none folded into a weight: the embedding's output times
+    # embedding_multiplier; each residual branch's output (mixer and
+    # feed-forward, in full_attention and mamba2_mlp layers) times
+    # residual_multiplier; the softmax scale attention_multiplier where
+    # it is stated (None: head_dim^-1/2); the logits divided by
+    # logits_scaling (a tied head reads the UNmultiplied table).  At
+    # their defaults the program is what it was without them
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -212,10 +230,12 @@ class TransformerConfig:
             if self.moe_experts:
                 assert self.moe_dropless and self.moe_latent_size \
                     and self.moe_shared_d_ff
-            if "mamba2" in self.layer_types:
+            if set(MAMBA_KINDS) & set(self.layer_types):
                 # one recurrent class a model: its leaves have one shape
-                assert "linear_attention" not in self.layer_types
+                assert len(set(STATE_KINDS) & set(self.layer_types)) == 1
                 assert self.mamba_heads % self.mamba_groups == 0
+        if self.attention_multiplier is not None:
+            assert not self.kv_lora_rank    # LatentAttention states its own
 
     @property
     def period(self) -> Optional[tuple]:
@@ -326,14 +346,16 @@ class TransformerConfig:
         if kind in MIXER_KINDS:
             return self._mixer_params(kind)
         mixer = (self._attn_params() if kind == "full_attention"
-                 else self._linear_attn_params())
+                 else self._linear_attn_params() if kind == "linear_attention"
+                 # the single-mixer layer's count, less its one norm
+                 else self._mixer_params("mamba2") - self.d_model)
         return mixer + 3 * self.d_model * self.d_ff + 2 * self.d_model
 
     def num_params(self) -> int:
         """Parameters this program holds (``experts_here`` routed experts
         a layer, not all the router scores)."""
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        if self.layers_of(*MIXER_KINDS):
+        if self.layers_of(*MIXER_KINDS, "mamba2_mlp"):
             return emb + self.d_model + sum(
                 self.layer_params(k)
                 for k in self.layer_types[:self.n_layers])
@@ -533,6 +555,36 @@ PRESETS = {
         moe_experts=16, moe_top_k=5, moe_d_ff=32, moe_act="relu2",
         moe_dropless=True, moe_scoring="sigmoid",
         moe_route_scale=5.0, moe_latent_size=32, moe_shared_d_ff=48),
+    # granite-4.0-h-micro (ibm-granite, model_type granitemoehybrid) as
+    # published, whole: a 10-layer period of nine mamba2_mlp layers (64
+    # heads of 64, state 128, ONE group, conv 4, prompts in chunks of
+    # 256) and one full_attention layer at place 5 (32 query heads on 8
+    # KV heads of 64, no rotation), a dense SwiGLU of 8192 in every
+    # layer (num_local_experts 0: the family's shared_mlp alone), a tied
+    # head, and the four muP multipliers
+    "granite-4.0-h-micro": TransformerConfig(
+        vocab_size=100352, d_model=2048, n_layers=40, n_heads=32,
+        n_kv_heads=8, head_dim=64, d_ff=8192, max_seq_len=131072,
+        rope_theta=None, norm_eps=1e-5, tie_embeddings=True,
+        layer_types=(("mamba2_mlp",) * 5 + ("full_attention",)
+                     + ("mamba2_mlp",) * 4) * 4,
+        mamba_heads=64, mamba_head_dim=64, ssm_state_size=128,
+        mamba_groups=1, mamba_conv_kernel=4, mamba_chunk=256,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.015625, logits_scaling=8.0),
+    # the same blocks at test size (tests/test_granite_h.py): two
+    # periods of four layers, the attention layer at place 2, every
+    # multiplier another number than 1 (and the softmax scale not 16^-1/2)
+    "tiny-granite-h": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=96, max_seq_len=256, dtype=jnp.float32,
+        remat=False, rope_theta=None, norm_eps=1e-5, tie_embeddings=True,
+        layer_types=("mamba2_mlp", "mamba2_mlp", "full_attention",
+                     "mamba2_mlp") * 2,
+        mamba_heads=8, mamba_head_dim=16, ssm_state_size=16,
+        mamba_groups=1, mamba_conv_kernel=4, mamba_chunk=8,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.125, logits_scaling=8.0),
     # K-EXAONE-236B-A23B (LGAI-EXAONE, model_type exaone_moe) as
     # published: 64 query / 8 KV heads of 128 with a per-head QK norm, a
     # 4-layer period of three rotating layers with a window of 128 and

@@ -22,9 +22,10 @@ import flax.linen as nn
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh
 
-from ray_tpu.models.configs import (POOL_KINDS, STATE_KINDS,
+from ray_tpu.models.configs import (MAMBA_KINDS, POOL_KINDS, STATE_KINDS,
                                     TransformerConfig)
 from ray_tpu.ops.attention import repeat_kv, xla_attention
 from ray_tpu.ops.layers import apply_rope, rope_frequencies
@@ -69,6 +70,13 @@ class RMSNorm(nn.Module):
                            (x.shape[-1],), jnp.float32)
         from ray_tpu.ops.layers import rms_norm
         return rms_norm(x, scale, self.eps)
+
+
+def _branch(cfg: TransformerConfig, y):
+    """A residual branch's output as the stream takes it: times the
+    model's ``residual_multiplier`` where it states one."""
+    r = cfg.residual_multiplier
+    return y if r == 1.0 else y * jnp.asarray(r, y.dtype)
 
 
 class MLP(nn.Module):
@@ -396,12 +404,12 @@ class Attention(nn.Module):
         w = self.cfg.sliding_window
         return window if w and span > w else None
 
-    @staticmethod
-    def _window_attend(q, k, v, window):
+    def _window_attend(self, q, k, v, window):
         """Causal attention of a span over itself under a window; no
         kernel here takes one (ROADMAP B2): masked einsum."""
         pos = jnp.arange(q.shape[1])
         return xla_attention(q, k, v, causal=False,
+                             sm_scale=self.cfg.attention_multiplier,
                              mask=window_mask(pos[None, :], pos, window))
 
     def _train_attend(self, q, k, v, window=None):
@@ -422,7 +430,8 @@ class Attention(nn.Module):
                     k = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
                     v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
                 from ray_tpu.ops.ring_attention import ring_attention
-                return ring_attention(q, k, v, mesh=self.mesh, causal=True)
+                return ring_attention(q, k, v, mesh=self.mesh, causal=True,
+                                      sm_scale=cfg.attention_multiplier)
             # ulysses handles GQA natively (KV all-to-all stays at kv_heads);
             # only expand when kv_heads doesn't divide the context axis
             ctx = self.mesh.shape.get("context", 1)
@@ -430,13 +439,15 @@ class Attention(nn.Module):
                 k = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
                 v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
             from ray_tpu.ops.ulysses import ulysses_attention
-            return ulysses_attention(q, k, v, mesh=self.mesh, causal=True)
+            return ulysses_attention(q, k, v, mesh=self.mesh, causal=True,
+                                     sm_scale=cfg.attention_multiplier)
         from ray_tpu.ops.attention import attention, resolve_impl
         impl = resolve_impl(impl)
         if impl == "flash" and self.mesh is not None \
                 and self.mesh.size > 1:
             return self._kernel_attend_sharded(q, k, v)
-        return attention(q, k, v, causal=True, impl=impl)
+        return attention(q, k, v, causal=True, impl=impl,
+                         sm_scale=cfg.attention_multiplier)
 
     def _kernel_attend_sharded(self, q, k, v):
         """A Pallas (Mosaic) kernel is one opaque custom call: GSPMD
@@ -463,7 +474,8 @@ class Attention(nn.Module):
             v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
         spec = logical_spec(("batch", None, "heads", None), mesh,
                             self.rules)
-        fn = functools.partial(attention, causal=True, impl="flash")
+        fn = functools.partial(attention, causal=True, impl="flash",
+                               sm_scale=cfg.attention_multiplier)
         return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
@@ -510,7 +522,8 @@ class Attention(nn.Module):
         # and, under a window, j > p - window
         mask = window_mask(positions, jnp.arange(cfg.max_seq_len),
                            self._window_over(window, cfg.max_seq_len))
-        return xla_attention(q, ck.value, cv.value, causal=False, mask=mask)
+        return xla_attention(q, ck.value, cv.value, causal=False, mask=mask,
+                             sm_scale=cfg.attention_multiplier)
 
     def _decode_attend_paged(self, q, k, v, positions, block_tables,
                              pool, layer, window=None, live=None,
@@ -564,10 +577,11 @@ class Attention(nn.Module):
                                                  paged_attention,
                                                  write_kv_pages)
         kv = jnp.concatenate([k, v], axis=-1)
+        scale = cfg.attention_multiplier      # None: head_dim^-1/2
         if q.shape[1] == 1:
             out, pool = paged_attention(
                 q[:, 0], pool, block_tables, positions[:, 0] + 1,
-                new_rows=kv[:, 0], layer=layer, live=live,
+                new_rows=kv[:, 0], layer=layer, live=live, sm_scale=scale,
                 window=self._window_over(
                     window, block_tables.shape[1] * pool.shape[3]))
             return out[:, None], pool
@@ -577,14 +591,15 @@ class Attention(nn.Module):
             # masked (and windowed) from its own position
             return paged_attention(
                 q, pool, block_tables, positions[:, -1] + 1, new_rows=kv,
-                layer=layer, live=live, window=self._window_over(
+                layer=layer, live=live, sm_scale=scale,
+                window=self._window_over(
                     window, block_tables.shape[1] * pool.shape[3]))
         pool = write_kv_pages(pool, kv, block_tables, positions, layer=layer)
         if not self.prefix_attend:
             window = self._window_over(window, q.shape[1])
             if window is not None:
                 return self._window_attend(q, k, v, window), pool
-            return _prefill_attend(q, k, v, lengths=lengths), pool
+            return _prefill_attend(q, k, v, scale, lengths), pool
         # suffix prefill: the window's keys are NOT the whole story —
         # leading block-table entries hold a cached prompt prefix, so
         # gather the row's full logical span back out of the pool and
@@ -597,8 +612,8 @@ class Attention(nn.Module):
         mask = window_mask(positions, jnp.arange(kvfull.shape[1]),
                            self._window_over(window, kvfull.shape[1]))
         return xla_attention(q, kvfull[..., :cfg.head_dim],
-                             kvfull[..., cfg.head_dim:],
-                             causal=False, mask=mask), pool
+                             kvfull[..., cfg.head_dim:], causal=False,
+                             sm_scale=scale, mask=mask), pool
 
 
 def _rope_interleaved(x, cos, sin, positions=None):
@@ -940,7 +955,7 @@ class Block(nn.Module):
             if cfg.post_norm:
                 y = attn_norm(y)
             y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
-            x = x + y
+            x = x + _branch(cfg, y)
             y = x if cfg.post_norm else mlp_norm(x)
             if moe is not None:
                 routed = moe(y, router_logits, live, moe_stacked,
@@ -964,7 +979,7 @@ class Block(nn.Module):
             if cfg.post_norm:
                 y = mlp_norm(y)
             y = jax.ad_checkpoint.checkpoint_name(y, "mlp_out")
-            return x + y
+            return x + _branch(cfg, y)
 
         if part == "before":
             return before(x, positions, True)
@@ -1238,15 +1253,22 @@ class Mamba2Mixer(nn.Module):
             and not self.is_initializing()
         if decode_step:
             state, conv = rec
-            flat, at = gd.flat_rows(conv, layer, entries)
-            tail = flat[at].reshape(b, taps - 1, -1)
+            # the rows' tails out of the stacked leaf and back ONE LAYER'S
+            # SLAB at a time: over the leaf viewed flat
+            # (``gated_delta.flat_rows``) the TPU compiler holds the whole
+            # leaf in fast memory around every layer's scatter and copies
+            # it there and back (69 MB at 36 layers of 73 entries: a third
+            # of a decode step; PERF.md section 6, PR 50)
+            slab = jax.lax.dynamic_index_in_dim(conv, layer, 0, False)
+            tail = slab[entries].reshape(b, taps - 1, -1)
             window = jnp.concatenate([tail, u.astype(conv.dtype)], 1)
             if live is not None:      # a dead row keeps its tail
                 tail = jnp.where(live[:, None, None], window[:, 1:], tail)
             else:
                 tail = window[:, 1:]
-            rec = (state, flat.at[at].set(
-                tail.reshape((b,) + conv.shape[2:])).reshape(conv.shape))
+            slab = slab.at[entries].set(tail.reshape((b,) + conv.shape[2:]))
+            rec = (state,
+                   jax.lax.dynamic_update_index_in_dim(conv, slab, layer, 0))
         else:       # zeros before the sequence's start
             window = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
         c = sum(window[:, j:j + t].astype(f32) * conv_w[j]
@@ -1279,8 +1301,15 @@ class Mamba2Mixer(nn.Module):
                         + jnp.arange(taps - 1)[None, :, None])
                 tail = jnp.einsum("bjt,btc->bjc", pick.astype(window.dtype),
                                   window)
-                rec = (gd.write_rows(state, gd.pack_state(final), layer,
-                                     entries),
+                # the update pinned row-major, as the leaf is: a one-row
+                # ``write_rows`` is ONE update, no loop, and XLA's layout
+                # assignment may then take the update's layout (N minor,
+                # as the chunked form's product leaves it) for the whole
+                # carried leaf: a copy of it in and out of the wave, 5.1
+                # GB at 36 layers of 73 entries (PERF.md section 6, PR 50)
+                final = with_layout_constraint(
+                    gd.pack_state(final), Layout(major_to_minor=(0, 1, 2)))
+                rec = (gd.write_rows(state, final, layer, entries),
                        gd.write_rows(conv, tail.reshape(
                            (b,) + conv.shape[2:]), layer, entries))
         y = y + d_skip[:, None] * xs.astype(f32)
@@ -1293,6 +1322,40 @@ class Mamba2Mixer(nn.Module):
         out = _dense(cfg.d_model, ("mlp", "embed"), "out_proj",
                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(y)
         return out if rec is None else (out, rec)
+
+
+class MambaBlock(nn.Module):
+    """A pre-norm block around ``Mamba2Mixer``, as ``LinearBlock`` is
+    around ``LinearAttention``: ``h = x + r Mamba2(Norm(x))``, ``out = h
+    + r MLP(Norm(h))``, ``r`` the model's ``residual_multiplier`` (the
+    granitemoehybrid family's layer; its feed-forward is the dense
+    SwiGLU of ``d_ff``)."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Mesh] = None
+    rules: ShardingRules = LOGICAL_RULES
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, block_tables=None, lengths=None, entries=None,
+                 rec=None, layer=None):
+        cfg = self.cfg
+        live = None if block_tables is None else block_tables[:, 0] != 0
+        with jax.named_scope("mamba_mixer"):
+            y = Mamba2Mixer(cfg, name="mixer")(
+                RMSNorm(cfg.norm_eps, name="mixer_norm")(x), lengths,
+                entries, rec, layer, live)
+        if rec is not None:
+            y, rec = y
+        x = x + _branch(cfg, jax.ad_checkpoint.checkpoint_name(y, "attn_out"))
+        with jax.named_scope("mamba_mlp"):
+            y = MLP(cfg, name="mlp")(
+                RMSNorm(cfg.norm_eps, name="mlp_norm")(x))
+        x = x + _branch(cfg, jax.ad_checkpoint.checkpoint_name(y, "mlp_out"))
+        if self.mesh is not None and not self.decode:
+            x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
+                              self.rules)
+        return x if rec is None else (x, rec)
 
 
 class MixerBlock(nn.Module):
@@ -1394,8 +1457,10 @@ class Period(nn.Module):
                 x = Block(*blocks, self.prefix_attend, name=f"layer_{j}")(
                     x, cos, sin, positions, block_tables, None, None, mine,
                     layer)
-            elif kind == "linear_attention":
-                x = LinearBlock(*blocks, name=f"layer_{j}")(
+            elif kind in ("linear_attention", "mamba2_mlp"):
+                block = LinearBlock if kind == "linear_attention" \
+                    else MambaBlock
+                x = block(*blocks, name=f"layer_{j}")(
                     x, block_tables, lengths, entries, mine, layer)
             else:
                 stacked = (moe_stacked or {}).get(j)
@@ -1470,7 +1535,15 @@ def output_logits(cfg: TransformerConfig, params, hidden) -> jax.Array:
     else:
         logits = jnp.einsum("...d,dv->...v", hidden,
                             p["lm_head"]["kernel"].astype(cfg.dtype))
-    return logits.astype(jnp.float32)
+    return _scaled_logits(cfg, logits)
+
+
+def _scaled_logits(cfg: TransformerConfig, logits) -> jax.Array:
+    """The head's products as float32 logits, divided by the model's
+    ``logits_scaling`` where it states one."""
+    logits = logits.astype(jnp.float32)
+    return logits if cfg.logits_scaling == 1.0 \
+        else logits / cfg.logits_scaling
 
 
 class GPT(nn.Module):
@@ -1560,7 +1633,7 @@ class GPT(nn.Module):
         n_state = cfg.layers_of(*STATE_KINDS)
         # a request's state in one recurrent layer, and its
         # convolution's last inputs over every channel convolved
-        if cfg.layers_of("mamba2"):
+        if cfg.layers_of(*MAMBA_KINDS):
             names = "ssm_state", "ssm_conv"
             entry = (cfg.ssm_state_size,
                      cfg.mamba_heads * cfg.mamba_head_dim)
@@ -1635,6 +1708,10 @@ class GPT(nn.Module):
             lookup = with_sharding(self.mesh, embed, (None, None),
                                    self.rules)
         x = jnp.take(lookup, tokens, axis=0).astype(cfg.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            # of the looked-up rows, not of the table: a tied head reads
+            # the table as it is stored
+            x = x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
         if self.mesh is not None and not self.decode:
             x = with_sharding(self.mesh, x, ("batch", "seq", "act_embed"),
                               self.rules)
@@ -1726,4 +1803,4 @@ class GPT(nn.Module):
         if self.mesh is not None and not self.decode:
             logits = with_sharding(self.mesh, logits,
                                    ("batch", "seq", "act_vocab"), self.rules)
-        return logits.astype(jnp.float32)
+        return _scaled_logits(cfg, logits)

@@ -79,8 +79,9 @@ iteration's prefills):
     pages in the attention layers, a FIXED-SIZE recurrent state a
     request: two more stacked cache leaves ``gdn_state`` [linear layers,
     entries, dk, heads*dv] float32 and ``gdn_conv`` [linear layers,
-    entries, (taps-1)*channels/128, 128] (``ssm_state`` [mamba2 layers,
-    entries, N, heads*P] and ``ssm_conv`` for Mamba-2 layers: the model
+    entries, (taps-1)*channels/128, 128] (``ssm_state`` [Mamba-2 layers,
+    entries, N, heads*P] and ``ssm_conv`` for Mamba-2 layers of either
+    block class, a mixer alone or a mixer and a SwiGLU: the model
     declares them, the engine never names their shapes), chained and
     donated with the pool.  A request
     holds one ENTRY of them from admission to finish, allocated and
@@ -471,6 +472,16 @@ class EngineStats:
         # once a row a block, as the page counts are
         self.gdn_layer_steps = 0
         self.gdn_state_rows = 0
+        # two high-water marks (not cumulative: the larger of two
+        # snapshots is the later one's; ``reset_peaks`` starts them
+        # again): the most rows whose token ONE decode step delivered
+        # (a block's first step: rows are installed between blocks and
+        # only end inside one), which is what a burst does to the
+        # recurrent layers' state traffic, and the most state entries
+        # held at once, slots and requests prefilled ahead of one, to
+        # set against ``state_entries - 1``
+        self.live_rows_max = 0
+        self.state_entries_max = 0
         # latent-attention layers (a latent pool): a layer step is one
         # such layer in one decode step; mla_context_tokens sums over
         # them the cached positions the absorbed kernel read for rows
@@ -505,6 +516,11 @@ class EngineStats:
     @property
     def prefill_wave_s(self) -> float:
         return self._wave[0]
+
+    def reset_peaks(self) -> None:
+        """Start the two high-water marks again (a benchmark does so
+        before its window)."""
+        self.live_rows_max = self.state_entries_max = 0
 
     @property
     def block_steps_run(self) -> int:
@@ -559,6 +575,8 @@ class EngineStats:
             "pool_layer_steps": self.pool_layer_steps,
             "gdn_layer_steps": self.gdn_layer_steps,
             "gdn_state_rows": self.gdn_state_rows,
+            "live_rows_max": self.live_rows_max,
+            "state_entries_max": self.state_entries_max,
             "mla_layer_steps": self.mla_layer_steps,
             "mla_context_tokens": self.mla_context_tokens,
             "loop_s": self.loop_s,
@@ -1840,6 +1858,13 @@ class LLMEngine:
             st.mla_layer_steps += steps_run * self._latent_layers
             tokens0, done0 = st.step_tokens, st.requests_completed
             drafts0 = st.drafts_proposed, st.drafts_accepted
+            if steps_run:
+                # rows that still hold their request: each delivers the
+                # token of the block's first step.  Counted before any
+                # result goes out: whoever holds one may read the counters
+                st.live_rows_max = max(st.live_rows_max, sum(
+                    self._slots[i] is not None
+                    and self._slots[i].request is req for i, req in rows))
             for i, req in rows if steps_run else ():
                 sl = self._slots[i]
                 if sl is None or sl.request is not req:
@@ -2285,6 +2310,10 @@ class LLMEngine:
                         req.admitted_at = now
                         if self._state_layers:
                             req.entry = self._free_states.pop()
+                            self.stats.state_entries_max = max(
+                                self.stats.state_entries_max,
+                                self.state_entries - 1
+                                - len(self._free_states))
                         pages = [self._free_pages.pop()
                                  for _ in range(fresh)]
                         if hit is not None:

@@ -827,11 +827,10 @@ def test_nemotron_programs_address_pool_state_and_experts_in_place(
     the size of the KV pool, of the state leaf or of the tail leaf, whole
     or one layer of it; and no operation of the decode block makes a
     copy of a layer's held experts (one matrix of them is 704 MB) ahead
-    of the kernel that reads them.  The tail leaf is not looked at in
-    the decode block: there the compiler itself moves the whole 22 MB of
-    it through fast memory a step (``S(1)``: slices in, a
-    ``ConcatBitcast``, one copy back, in forms that change with its
-    schedule), 45 MB beside the ~9 GB a step reads."""
+    of the kernel that reads them.  Since PR 50 the decode block
+    addresses the tail leaf one layer's slab at a time too (``models/gpt.py
+    Mamba2Mixer``): over the leaf viewed flat the compiler moved the
+    whole 22 MB of it through fast memory a step (``S(1)``)."""
     hlo = nemotron_programs[name].as_text()
     pool = 4673 * 2 * 64 * 256
     state, tail = 73 * 128 * 8192, 73 * 240 * 128
@@ -839,11 +838,14 @@ def test_nemotron_programs_address_pool_state_and_experts_in_place(
                                ("state", (state, 5 * state), "f32"),
                                ("tail", (tail, 5 * tail), "bf16")):
         if what == "tail" and name == "engine_decode_block":
-            continue
+            sizes = sizes[1:]       # a step slices a layer's slab out
         made = _pool_result_producers(hlo, sizes, dtype)
         assert set(made) <= _IN_PLACE, (
             f"{name}: {what}-sized results from {dict(made)}")
     if name == "engine_decode_block":
+        assert "S(1)" not in "".join(
+            line for line in hlo.splitlines()
+            if "bf16[5,73,240,128]" in line.split(" = ")[-1][:40])
         made = _pool_result_producers(hlo, (128 * 1024 * 2688,))
         assert set(made) <= _IN_PLACE, (
             f"{name}: a layer's experts from {dict(made)}")
@@ -983,3 +985,109 @@ def test_k_exaone_cell_fits_the_chip(k_exaone_programs):
     prefill = p["engine_prefill"].memory_analysis()
     assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
             ) < 15.5e9, prefill.temp_size_in_bytes / 1e9
+
+
+# ---- a model whose Mamba-2 layers carry a SwiGLU each, whole on one
+# ---- chip (ISSUE 50)
+
+@pytest.fixture(scope="module")
+def granite_programs(topo, one_chip):
+    """``engine_decode_block`` and the two widest prefill waves the
+    serve-burst cell may form under ``prefill_wave_tokens`` 2048 (4 x 512
+    and 32 x 64) of granite-4.0-h-micro WHOLE at the cell's server: 40
+    layers (four 10-layer periods), the full 100,352-row tied table, 64
+    slots, 73 state entries, 2,081 pages.  Compiled once for the tests
+    below."""
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("granite-4.0-h-micro", max_seq_len=2048,
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    patch = pytest.MonkeyPatch()
+    _answer_tpu(patch)
+    try:
+        eng = _described_engine(cfg, patch, num_slots=64, max_seq_len=2048,
+                                max_prompt_len=512, kv_pool_pages=2081,
+                                prefill_wave_tokens=2048)
+        block = eng._block_jit.lower(
+            *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                     one_chip))
+        out = {"eng": eng, "block_text": block.as_text(),
+               "engine_decode_block": block.compile()}
+        for bucket, wave in ((512, 4), (64, 32)):
+            prefill = eng._get_prefill_paged(bucket, wave).lower(
+                *_shapes((eng.params, eng._cache,
+                          jnp.zeros((wave, eng.packed_width(bucket)),
+                                    jnp.int32),
+                          jnp.zeros((wave, eng.max_pages), jnp.int32),
+                          jax.random.PRNGKey(0)), one_chip))
+            out[f"prefill_text_{bucket}"] = prefill.as_text()
+            out[f"engine_prefill_{bucket}"] = prefill.compile()
+        return out
+    finally:
+        patch.undo()
+
+
+def test_granite_engine_programs_compile_with_their_kernels(
+        granite_programs):
+    """The decode block's scanned period holds ``ssm_decode`` once a
+    Mamba-2 layer of it (nine: ONE group of 64 heads x 64 = 4096 lanes)
+    and the paged kernel once (32 query heads on 8 KV heads of 64; its
+    scale is a constant of the kernel's body, held by numbers in
+    tests/test_granite_h.py and on the chip); the state leaf is the
+    36-layer one the model declares; the prefill waves are plain XLA."""
+    p = granite_programs
+    assert {k: v.shape for k, v in p["eng"]._cache.items()} == {
+        "kv_pages": (4, 2081, 8, 64, 128),
+        "ssm_state": (36, 73, 128, 4096),
+        "ssm_conv": (36, 73, 102, 128)}
+    assert p["eng"]._cache["ssm_state"].dtype == jnp.float32
+    text = p["block_text"]
+    assert text.count('kernel_name = "ssm_decode"') == 9
+    assert text.count('kernel_name = "paged_attention_decode"') == 1
+    assert "@jit_engine_decode_block" in text
+    for bucket in (512, 64):
+        assert "@jit_engine_prefill" in p[f"prefill_text_{bucket}"]
+        assert "ssm_decode" not in p[f"prefill_text_{bucket}"]
+
+
+@pytest.mark.parametrize("name", ["engine_decode_block",
+                                  "engine_prefill_512",
+                                  "engine_prefill_64"])
+def test_granite_programs_address_pool_and_state_in_place(
+        granite_programs, name):
+    """Nothing but parameters and in-place updates produces a result of
+    the size of the KV pool or of the 5.5 GB state leaf, whole or one
+    layer of it (a copy of the leaf would not fit the chip beside it),
+    nor one of the size of the 69 MB tail leaf, in the decode block
+    either: a step addresses it one layer's 1.9 MB slab at a time
+    (``models/gpt.py Mamba2Mixer``), where over the leaf viewed flat the
+    compiler moved the whole leaf through fast memory a layer (a third
+    of a step: PERF.md section 6, PR 50)."""
+    hlo = granite_programs[name].as_text()
+    pool = 2081 * 8 * 64 * 128
+    state, tail = 73 * 128 * 4096, 73 * 102 * 128
+    for what, sizes, dtype in (("pool", (pool, 4 * pool), "bf16"),
+                               ("state", (state, 36 * state), "f32"),
+                               ("tail", (36 * tail,), "bf16")):
+        made = _pool_result_producers(hlo, sizes, dtype)
+        assert set(made) <= _IN_PLACE, (
+            f"{name}: {what}-sized results from {dict(made)}")
+    if name == "engine_decode_block":
+        assert "S(1)" not in "".join(
+            line for line in hlo.splitlines()
+            if "bf16[36,73,102,128]" in line.split(" = ")[-1][:40])
+
+
+def test_granite_cell_fits_the_chip(granite_programs):
+    """13.1 GB resident (weights 6.38, state entries 5.58 and their tails
+    0.07, pool 1.09), arguments and temporaries of the decode block and
+    of each of the widest prefill waves under 16 GB."""
+    p = granite_programs
+    block = p["engine_decode_block"].memory_analysis()
+    assert 13.0e9 < block.argument_size_in_bytes < 13.3e9
+    assert (block.argument_size_in_bytes + block.temp_size_in_bytes) < 16e9
+    for bucket in (512, 64):
+        prefill = p[f"engine_prefill_{bucket}"].memory_analysis()
+        assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
+                + block.temp_size_in_bytes) < 16.0e9, (
+            bucket, prefill.temp_size_in_bytes / 1e9)

@@ -62,7 +62,46 @@ def test_a_prompt_s_state_is_that_of_its_last_real_token():
     assert float(jnp.abs(absorbed[1] - state[1]).max()) > 1e-2
 
 
-@pytest.mark.parametrize("heads,p,groups,n", [(8, 32, 2, 16), (4, 64, 2, 8)])
+@pytest.mark.parametrize("length", [64, 256, 300])
+def test_three_forms_agree_at_one_group_and_the_published_chunk(length):
+    """``groups = 1`` with ``heads x P`` a multiple of 128 (4 heads of
+    64: every head reads the one ``B`` and ``C``) at a chunk of 256
+    (ISSUE 50's shape, fewer heads): a prompt shorter than the chunk, one
+    chunk whole, a ragged second chunk.  The chunked form against the
+    scan, then one decode step from the prompt's state, as the XLA form
+    and as the Pallas kernel in the interpreter, against one more step of
+    the scan."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.ops import mamba2
+    from ray_tpu.ops.gated_delta import pack_state
+    h, p, g, n = 4, 64, 1, 16
+    u, delta, a_neg, b, c = _inputs(jax.random.PRNGKey(length), 2,
+                                    length + 1, h, p, g, n)
+    head = lambda x: x[:, :length]                            # noqa: E731
+    y1, s1 = mamba2.ssm_chunked(head(u), head(delta), a_neg, head(b),
+                                head(c), chunk=256)
+    y2, s2 = mamba2.ssm_scan(head(u), head(delta), a_neg, head(b), head(c))
+    # 256 terms a sum in another order: float32 noise, not a form's
+    np.testing.assert_allclose(y1, y2, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(s1, s2, atol=1e-4, rtol=1e-4)
+    want, _ = mamba2.ssm_scan(u[:, length:], delta[:, length:], a_neg,
+                              b[:, length:], c[:, length:], s2)
+    # the prompt's states in entries 2 and 1 of layer 1 of a stacked leaf
+    state = jnp.zeros((2, 4, n, h * p)).at[1, jnp.asarray([2, 1])].set(
+        pack_state(s1))
+    x = (delta[..., None] * u)[:, length]
+    a = jnp.exp(delta * a_neg)[:, length]
+    for fn, kw in ((mamba2.ssm_decode_xla, {}),
+                   (mamba2.ssm_decode_tpu, {"interpret": True})):
+        y, _ = fn(x, a, b[:, length], c[:, length], state,
+                  jnp.asarray([2, 1]), jnp.ones((2,), bool), layer=1, **kw)
+        np.testing.assert_allclose(y, want[:, 0], atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("heads,p,groups,n", [(8, 32, 2, 16), (4, 64, 2, 8),
+                                              (2, 64, 1, 16)])
 def test_decode_kernel_in_the_interpreter_leaves_dead_rows_alone(
         heads, p, groups, n):
     """``ssm_decode`` (the Pallas kernel, interpreted) against the jnp
@@ -116,5 +155,6 @@ def test_the_kernel_is_chosen_by_platform_and_lane_alignment(monkeypatch):
     monkeypatch.setattr(mamba2, "backend_platform", lambda: "tpu")
     assert mamba2.resolve_ssm_impl(128, 64, 8) == "tpu"
     assert mamba2.resolve_ssm_impl(4, 16, 2) == "xla"   # 32 lanes a group
+    assert mamba2.resolve_ssm_impl(64, 64, 1) == "tpu"  # one group of 4096
     with pytest.raises(ValueError, match="unknown ssm_decode impl"):
         mamba2.resolve_ssm_impl(128, 64, 8, "cuda")
