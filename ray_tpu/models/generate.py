@@ -43,6 +43,12 @@ def _tempered(logits: jax.Array, temps: jax.Array, top_k: int,
     return _trim_logits(logits / safe_t[:, None], top_k, top_p)
 
 
+def any_sampled(temps: jax.Array) -> jax.Array:
+    """Whether some row of a per-row ``temperature`` [B] is sampled:
+    the predicate ``sample_logits`` draws under."""
+    return jnp.any(temps > 0)
+
+
 def sample_logits(rng: jax.Array, logits: jax.Array, *,
                   temperature=1.0, top_k: int = 0,
                   top_p: float = 1.0) -> jax.Array:
@@ -51,17 +57,26 @@ def sample_logits(rng: jax.Array, logits: jax.Array, *,
     ``temperature`` may be a scalar or a per-row [B] array — the
     continuous-batching engine mixes greedy and sampled requests in one
     batch, so greedy rows (temperature 0) select argmax under the same
-    trace."""
+    trace.  The draw (tempering, trimming, ``rows x vocabulary`` Gumbel
+    noise and its argmax) sits in a ``lax.cond`` on ``any_sampled``: a
+    batch whose rows are all greedy computes the argmax alone, and one
+    with a sampled row draws for every row on ``rng`` as it always did,
+    so the tokens are the same either way.  The logits enter the
+    drawing branch in their own dtype and are cast there."""
     if isinstance(temperature, (int, float)):
         if temperature == 0.0:
             return jnp.argmax(logits, axis=-1)
         return jax.random.categorical(
             rng, _trim_logits(logits / temperature, top_k, top_p), axis=-1)
     temps = jnp.asarray(temperature)
-    greedy = jnp.argmax(logits, axis=-1)
-    sampled = jax.random.categorical(
-        rng, _tempered(logits, temps, top_k, top_p), axis=-1)
-    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw(logits):
+        sampled = jax.random.categorical(
+            rng, _tempered(logits, temps, top_k, top_p), axis=-1)
+        return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+    return jax.lax.cond(any_sampled(temps), draw, lambda _: greedy, logits)
 
 
 def sampling_probs(logits: jax.Array, temperature, *, top_k: int = 0,

@@ -16,6 +16,7 @@ TPU-first design notes:
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import flax.linen as nn
@@ -1544,6 +1545,19 @@ def _scaled_logits(cfg: TransformerConfig, logits) -> jax.Array:
     logits = logits.astype(jnp.float32)
     return logits if cfg.logits_scaling == 1.0 \
         else logits / cfg.logits_scaling
+
+
+def narrowed_logits(cfg: TransformerConfig, logits) -> jax.Array:
+    """``_scaled_logits``' float32 logits back in ``cfg.dtype``, where
+    that is the same numbers: they are ``cfg.dtype`` products widened,
+    and a ``logits_scaling`` that is a power of two moves the exponent
+    alone.  A reader behind a boundary the compiler cannot fuse the
+    widening through (the sampler's conditional: serve/llm_engine.py
+    ``_sample_fn``) takes them so, and the head then writes them at
+    that width: as float32 they are twice the bytes.  Any other scaling
+    leaves them float32."""
+    exact = math.frexp(cfg.logits_scaling)[0] == 0.5
+    return logits.astype(cfg.dtype) if exact else logits
 
 
 class GPT(nn.Module):

@@ -141,8 +141,14 @@ iteration's prefills):
     (idempotent).  Pages are recycled only through dispatches ordered
     after a row's last write (device stream order), so reuse can never
     corrupt a live request.
-  - Per-request temperature rides as an [N] array (greedy rows select
-    argmax under the same jit); top_k/top_p are engine-static.
+  - Per-request temperature rides as an [N] array; top_k/top_p are
+    engine-static.  The sampler (models/generate.py ``sample_logits``)
+    draws only where some row has a temperature: a decode step is told
+    the temperatures of its LIVE rows (``_run_steps``: an ended row's
+    stays in its slot until the next install), so a step of greedy rows
+    computes an argmax and no noise, under the same jit, and a step
+    with a sampled row draws for every row as before
+    (``stats.block_steps_drawn`` counts those, on the device).
 
 The host loop owns admission/eviction and runs on a plain thread;
 ``submit`` is loop-aware like serve's ``_BatchQueue.submit`` (awaitable
@@ -166,7 +172,8 @@ import numpy as np
 from ray_tpu._private.profiler import span
 from ray_tpu.models.configs import (POOL_KINDS, STATE_KINDS,
                                     TransformerConfig)
-from ray_tpu.models.gpt import GPT, output_logits, prefill_positions
+from ray_tpu.models.gpt import (GPT, narrowed_logits, output_logits,
+                                prefill_positions)
 from ray_tpu.serve.frontdoor.prefix import page_digests
 
 # admission waves are padded to the next of these sizes (bounded jit
@@ -413,6 +420,10 @@ class EngineStats:
         # none ended with its last live row (block_steps_run / this is
         # how often that engages)
         self.block_steps_offered = 0
+        # of ``steps``, those whose sampler drew (some live row had a
+        # temperature: models/generate.py sample_logits); counted by
+        # the device and fetched with each block's tokens
+        self.block_steps_drawn = 0
         # tokens delivered from steps: one a live row a step, or under
         # drafting one or two (so batch_occupancy may pass 1 there)
         self.step_tokens = 0
@@ -563,6 +574,7 @@ class EngineStats:
             "quanta": self.quanta,
             "block_steps_run": self.block_steps_run,
             "block_steps_offered": self.block_steps_offered,
+            "block_steps_drawn": self.block_steps_drawn,
             "prefill_waves": self.prefill_waves,
             "prefill_prompt_tokens": self.prefill_prompt_tokens,
             "prefill_padded_tokens": self.prefill_padded_tokens,
@@ -887,10 +899,12 @@ class LLMEngine:
 
     def _sample_fn(self, rng, logits, temps):
         """[B, V] logits + per-row temperature -> [B] token ids
-        (models/generate.py sample_logits, array-temperature form)."""
+        (models/generate.py sample_logits, array-temperature form, at
+        the width the head computed them in)."""
         from ray_tpu.models.generate import sample_logits
-        return sample_logits(rng, logits, temperature=temps,
-                             top_k=self.top_k, top_p=self.top_p)
+        return sample_logits(rng, narrowed_logits(self.cfg, logits),
+                             temperature=temps, top_k=self.top_k,
+                             top_p=self.top_p)
 
     def _last_logits(self, model, params, cache, tokens, positions,
                      s_reals, tables, entries=None, skip_pad=True):
@@ -1157,48 +1171,57 @@ class LLMEngine:
             out["entries"] = jnp.where(ended, 0, rows["entries"])
         return out
 
-    def _run_steps(self, one, rows, cache, keys, outs: int):
+    def _run_steps(self, one, rows, temps, cache, keys, outs: int):
         """The block's loop, its trip count the device's to decide:
-        ``one(rows, cache, key) -> (rows, cache, out, load)`` while a row
-        is live, ``block_size`` times at most.  Whether to go on is
-        settled from what the rows held BEFORE the step just run: some
-        live row had more than that step's token left of its budget.  A
-        condition on the step's own outcome (any table still off
-        scratch) makes the loop wait for the step at its every turn,
+        ``one(rows, cache, key, live_temps) -> (rows, cache, out, load)``
+        while a row is live, ``block_size`` times at most.  Whether to go
+        on is settled from what the rows held BEFORE the step just run:
+        some live row had more than that step's token left of its
+        budget.  A condition on the step's own outcome (any table still
+        off scratch) makes the loop wait for the step at its every turn,
         16-30 us a step on the v5e (PERF.md, PR 48); this one is as free
         as a scan's counter, and exact wherever budgets end the rows.
         Where the last row ends by its eos, or by the second token of a
         drafted pair, one more step runs for nobody and the loop ends
-        behind it.  ``out`` is ``outs`` int32 [rows] arrays a step,
-        written at the step's index into [block_size, rows] buffers
+        behind it.  ``live_temps`` is settled before the step too:
+        ``temps`` with 0 for the rows that hold no request at its start
+        (an ended row's temperature stays in its slot until the next
+        install), which is what the step's sampler is told, so that it
+        draws for live rows' sake alone (models/generate.py
+        ``sample_logits``).  ``out`` is ``outs`` int32 [rows] arrays a
+        step, written at the step's index into [block_size, rows] buffers
         (zeros past the last step run); ``load`` the step's expert load
         (``_expert_load``; () without).  The cache and the rows ride the
         loop's carry, donated and in place.  Returns the block's ONE
         fetch (each buffer as [rows * block_size], then the steps run,
-        then the expert load's two numbers), the rows and the cache."""
+        how many of them drew, then the expert load's two numbers), the
+        rows and the cache."""
+        from ray_tpu.models.generate import any_sampled
         zero = jnp.zeros((), jnp.int32)
 
         def going(carry):
             return (carry[0] < self.block_size) & carry[-1]
 
         def body(carry):
-            step, rows, cache, bufs, loads, _ = carry
-            more = jnp.any((rows["tables"][:, 0] != 0)
-                           & (rows["remaining"] > 1))
-            rows, cache, out, load = one(rows, cache, keys[step])
+            step, rows, cache, bufs, drawn, loads, _ = carry
+            live = rows["tables"][:, 0] != 0
+            more = jnp.any(live & (rows["remaining"] > 1))
+            live_temps = jnp.where(live, temps, 0.0)
+            rows, cache, out, load = one(rows, cache, keys[step], live_temps)
             return (step + 1, rows, cache,
                     tuple(b.at[step].set(o) for b, o in zip(bufs, out)),
+                    drawn + any_sampled(live_temps).astype(jnp.int32),
                     tuple(a + b for a, b in zip(loads, load)), more)
 
-        steps, rows, cache, bufs, loads, _ = jax.lax.while_loop(
+        steps, rows, cache, bufs, drawn, loads, _ = jax.lax.while_loop(
             going, body, (
                 zero, rows, cache,
                 (jnp.zeros((self.block_size, self._rows), jnp.int32),) * outs,
-                (zero, zero) if self._counts_expert_load else (),
+                zero, (zero, zero) if self._counts_expert_load else (),
                 jnp.any(rows["tables"][:, 0] != 0)))
         return jnp.concatenate(
             [b.T.reshape(-1) for b in bufs]
-            + [jnp.stack([steps, *loads])]), rows, cache
+            + [jnp.stack([steps, drawn, *loads])]), rows, cache
 
     def _block_fn(self, params, cache, state, admit_meta, admit_lasts,
                   admit_tables):
@@ -1213,7 +1236,7 @@ class LLMEngine:
             state, admit_meta, admit_lasts, admit_tables)
         load = self._counts_expert_load
 
-        def one(rows, cache, key):
+        def one(rows, cache, key, live_temps):
             live = rows["tables"][:, 0] != 0
             logits, mut = self.model.apply(
                 {"params": params, "cache": cache}, rows["tokens"][:, None],
@@ -1221,12 +1244,13 @@ class LLMEngine:
                 mutable=["cache", "intermediates"] if load else ["cache"],
                 **({"state_rows": rows["entries"]} if "entries" in rows
                    else {}))
-            nxt = self._sample_fn(key, logits[:, -1], temps)
+            nxt = self._sample_fn(key, logits[:, -1], live_temps)
             return (self._end_step(rows, nxt, 1, nxt == eos), mut["cache"],
                     (nxt,), self._expert_load(mut["intermediates"], live)
                     if load else ())
 
-        combined, rows, cache = self._run_steps(one, rows, cache, keys, 1)
+        combined, rows, cache = self._run_steps(
+            one, rows, temps, cache, keys, 1)
         return combined, self._pack_state(rows, temps, eos, rng), cache
 
     def _spec_block_fn(self, params, cache, state, admit_meta, admit_lasts,
@@ -1243,15 +1267,15 @@ class LLMEngine:
         step's first, as the stack's row at p + 1 is); the next draft
         from the module's logits at the row's new last position.  The
         block's ONE fetch is ``[first | second | count]``, ``rows *
-        block_size`` each (then the steps run and the expert load's two
-        numbers): ``count`` 1 or 2, ``second`` junk where it is 1."""
+        block_size`` each (then ``_run_steps``' counters): ``count`` 1
+        or 2, ``second`` junk where it is 1."""
         from ray_tpu.models.generate import verify_draft
         rows, temps, eos, rng, keys = self._install(
             state, admit_meta, admit_lasts, admit_tables)
         load = self._counts_expert_load
         mutable = ["cache", "intermediates"] if load else ["cache"]
 
-        def one(rows, cache, key):
+        def one(rows, cache, key, live_temps):
             tokens, positions, tables, drafts = (
                 rows[k] for k in ("tokens", "positions", "tables", "drafts"))
             live = tables[:, 0] != 0
@@ -1277,7 +1301,7 @@ class LLMEngine:
                     mtp_hidden=prenorm, mutable=mutable)
                 q_logits = output_logits(self.cfg, params, jnp.where(
                     (n == 2)[:, None], drafted[:, 1], drafted[:, 0]))
-                drafts = self._sample_fn(k_draft, q_logits, temps)
+                drafts = self._sample_fn(k_draft, q_logits, live_temps)
             rows = self._end_step(
                 dict(rows, drafts=drafts, q_logits=q_logits),
                 jnp.where(n == 2, second, first), n,
@@ -1286,7 +1310,8 @@ class LLMEngine:
                 (mut["intermediates"], mut2["intermediates"]), live
             ) if load else ()
 
-        combined, rows, cache = self._run_steps(one, rows, cache, keys, 3)
+        combined, rows, cache = self._run_steps(
+            one, rows, temps, cache, keys, 3)
         return combined, self._pack_state(rows, temps, eos, rng), cache
 
     def _expert_load(self, intermediates, live):
@@ -2776,9 +2801,10 @@ class LLMEngine:
         with self._phase("fetch_block", "fetch_wait_s") as sp:
             host = np.asarray(combined)    # the ONE fetch this quantum
             done = time.monotonic()
-            # behind the tokens: the steps the block ran (``_run_steps``)
-            host, (steps_run, *load) = np.split(
-                host, [-3 if self._counts_expert_load else -1])
+            # behind the tokens: the steps the block ran, and how many
+            # of them drew (``_run_steps``)
+            host, (steps_run, drawn, *load) = np.split(
+                host, [-4 if self._counts_expert_load else -2])
             steps_run = int(steps_run)
             interval, wave_s = self._account_block(ahead, done, steps_run)
             self._begin(nxt_ahead, done)
@@ -2786,6 +2812,7 @@ class LLMEngine:
                             steps=steps_run, waves=ahead.waves,
                             interval_ms=round(1e3 * interval, 3),
                             wave_ms=round(1e3 * wave_s, 3))
+        self.stats.block_steps_drawn += int(drawn)
         if load:
             self.stats.moe_layer_steps += int(load[0])
             self.stats.moe_experts_touched += int(load[1])

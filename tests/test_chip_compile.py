@@ -339,6 +339,20 @@ _IN_PLACE = {"parameter", "get-tuple-element", "bitcast", "scatter",
              "fusion:dynamic-update-slice"}
 
 
+def _computations(hlo: str):
+    """The optimised HLO's computations: ``{name: [its instructions]}``."""
+    import re
+
+    comps, lines = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            lines = comps[head.group(2)] = []
+        elif lines is not None and " = " in line:
+            lines.append(line)
+    return comps
+
+
 def _pool_result_producers(hlo: str, sizes, dtype: str = "bf16"):
     """Opcodes of the optimised HLO's instructions whose (array) result
     holds one of ``sizes`` values of ``dtype``, whatever its rank — the pool,
@@ -351,21 +365,17 @@ def _pool_result_producers(hlo: str, sizes, dtype: str = "bf16"):
     inst = re.compile(
         rf"^\s*(ROOT )?%?([\w.\-]+) = {dtype}\[([\d,]+)\]\S* ([\w\-]+)\((.*)$")
     roots, found = {}, []
-    comp = None
-    for line in hlo.splitlines():
-        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
-        if head:
-            comp = head.group(2)
-            continue
-        m = inst.match(line)
-        if not m:
-            continue
-        is_root, name, dims, op, rest = m.groups()
-        if is_root:
-            roots[comp] = op
-        if np.prod([int(d) for d in dims.split(",")]) in sizes:
-            calls = re.search(r"calls=%?([\w.\-]+)", rest)
-            found.append((op, calls.group(1) if calls else None))
+    for comp, lines in _computations(hlo).items():
+        for line in lines:
+            m = inst.match(line)
+            if not m:
+                continue
+            is_root, name, dims, op, rest = m.groups()
+            if is_root:
+                roots[comp] = op
+            if np.prod([int(d) for d in dims.split(",")]) in sizes:
+                calls = re.search(r"calls=%?([\w.\-]+)", rest)
+                found.append((op, calls.group(1) if calls else None))
     return collections.Counter(
         f"fusion:{roots.get(c, '?')}" if op == "fusion" else op
         for op, c in found)
@@ -593,6 +603,72 @@ def test_olmo_hybrid_programs_address_pool_and_state_in_place(
         made = _pool_result_producers(hlo, sizes, dtype)
         assert set(made) <= _IN_PLACE, (
             f"{name}: {what}-sized results from {dict(made)}")
+    if name == "engine_decode_block":
+        # nor is the tail leaf moved through fast memory a layer, as the
+        # Mamba-2 cells' was before PR 50 (looked for in ISSUE 52)
+        assert "S(1)" not in "".join(
+            line for line in hlo.splitlines()
+            if any(leaf in line.split(" = ")[-1][:40]
+                   for leaf in ("bf16[9,73,270,128]", "bf16[657,270,128]")))
+
+
+def _reachable(comps, root: str):
+    """``root`` and every computation it calls, at any depth."""
+    import re
+
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += [n for n in re.findall(
+                r"%([\w.\-]+)", line.split("metadata=")[0]) if n in comps]
+    return seen
+
+
+def test_decode_block_draws_inside_its_one_conditional(olmo_hybrid_programs):
+    """ISSUE 52.  The serve-assist decode block's loop body holds ONE
+    ``conditional``, behind the head: one branch passes the greedy
+    argmax through, the other holds every operation of the Gumbel draw
+    (``jit(_gumbel)`` / ``jit(_uniform)``: a threefry hash a logit), and
+    none lies outside it.  The head still writes ``bf16[rows, vocab]``
+    and hands the conditional that (as float32 it is twice the bytes,
+    the greedy argmax reading them too: ``LLMEngine._sample_fn``
+    narrows), and nothing of that size is float32 but inside the
+    drawing branch."""
+    import re
+
+    hlo = olmo_hybrid_programs["engine_decode_block"].as_text()
+    comps = _computations(hlo)
+    ((home, cond),) = [(name, line) for name, lines in comps.items()
+                       for line in lines if " conditional(" in line]
+    assert re.search(rf"body=%?{re.escape(home)}\b", hlo), (
+        f"{home} is no loop's body")
+    branches = re.search(r"branch_computations=\{([^}]*)\}", cond).group(
+        1).replace("%", "").split(", ")
+    assert len(branches) == 2
+    noisy = {name for name, lines in comps.items()
+             if any("_gumbel" in line or "_uniform" in line
+                    for line in lines)}
+    assert noisy
+    inside = {b: _reachable(comps, b) for b in branches}
+    (drawing,) = [b for b in branches if inside[b] & noisy]
+    (greedy,) = [b for b in branches if b != drawing]
+    assert noisy <= inside[drawing]
+    (passed,) = comps[greedy]
+    assert " parameter(0)" in passed
+    logits = "[65,100352]"
+    (head,) = [line for line in comps[home]
+               if "GPT._head" in line and f" = bf16{logits}" in line]
+    head = head.split(" = ")[0].split()[-1]
+    (fed,) = [line.split(" = ")[0].split()[-1] for line in comps[home]
+              if " tuple(" in line and f"{head}," in line]
+    assert f"{fed})" in cond or f"{fed}," in cond
+    wide = {name for name, lines in comps.items()
+            if any(f" = f32{logits}" in line for line in lines)}
+    assert wide <= inside[drawing]
 
 
 def test_olmo_hybrid_cell_fits_the_chip(olmo_hybrid_programs):

@@ -1500,7 +1500,7 @@ def test_an_ended_row_is_neither_read_nor_written(tiny_parts):
                 eng.params, eng._cache, eng._state, meta,
                 np.zeros((3,), np.int32), tables)
             out = np.asarray(out)
-            return (out[:-1].reshape(rows, 4), int(out[-1]),
+            return (out[:-2].reshape(rows, 4), int(out[-2]),
                     np.asarray(eng._cache["kv_pages"]))
 
         def install(eos_of_row_1):
