@@ -17,7 +17,7 @@ import jax.numpy as jnp
 # keeps of a sequence: KV pages in the paged pool, or a fixed-size
 # recurrent state entry a request; and those that are one mixer a layer
 POOL_KINDS = ("full_attention", "attention_only")
-STATE_KINDS = ("linear_attention", "mamba2", "mamba2_mlp")
+STATE_KINDS = ("linear_attention", "kda", "mamba2", "mamba2_mlp")
 # of those, the classes whose recurrence is Mamba-2's: their state leaves
 # are ``ssm_state`` / ``ssm_conv`` (models/gpt.py GPT._stack_periods)
 MAMBA_KINDS = ("mamba2", "mamba2_mlp")
@@ -75,7 +75,7 @@ class TransformerConfig:
     rope_layout: Optional[tuple] = None
     window_layout: Optional[tuple] = None
     # Per-layer BLOCK classes ("full_attention" | "linear_attention" |
-    # "mamba2_mlp", and the single-mixer classes further down; layer l
+    # "kda" | "mamba2_mlp", and the single-mixer classes further down; layer l
     # is entry l, the tuple may be longer than n_layers).  Where
     # it names more than one class the layer stack scans one PERIOD of
     # it (``period``; models/gpt.py Period).  A linear_attention layer is
@@ -86,16 +86,30 @@ class TransformerConfig:
     # linear_conv_kernel taps in front; linear_allow_neg_eigval: the
     # write strength ranges over (0, 2), not (0, 1).  It holds no KV
     # pages but a fixed-size state a request (serve/llm_engine.py).
+    # A kda layer (models/gpt.py KimiDeltaAttention, the kimi_linear
+    # family's) is the same rule with a decay a KEY CHANNEL, from a
+    # low-rank pair of linear_gate_rank, a sigmoid output gate from
+    # another such pair, and a write strength in (0, 1); its
+    # feed-forward is what a full_attention layer's is at that depth
+    # (first_dense_layers, the moe_* experts), and the full_attention
+    # layers beside it may be latent (kv_lora_rank).
     # A mamba2_mlp layer (models/gpt.py MambaBlock) is the same pre-norm
     # block around a Mamba-2 mixer (the mamba_* sizes further down) and
     # a dense SwiGLU of d_ff: the granitemoehybrid family's layer.
+    # layer_period (stated, not found: a pattern cut short reads as
+    # aperiodic): the stack is the whole periods of that many layers
+    # that a leading dense layer breaks, unrolled (``runs``: the head
+    # run), then a SCAN of the whole periods behind them, then the
+    # partial period that is left, unrolled (the tail run).
     layer_types: Optional[tuple] = None
+    layer_period: Optional[int] = None
     linear_key_heads: Optional[int] = None
     linear_value_heads: Optional[int] = None
     linear_key_head_dim: Optional[int] = None
     linear_value_head_dim: Optional[int] = None
     linear_conv_kernel: int = 4
     linear_allow_neg_eigval: bool = False
+    linear_gate_rank: Optional[int] = None
     # RMSNorm of q and k before the rotation, in one of two forms: over
     # the WHOLE projection, heads unsplit (one weight of heads * head_dim:
     # OLMo 2/3), or with qk_norm_per_head over each head's head_dim alone
@@ -203,6 +217,8 @@ class TransformerConfig:
                                      + self.qk_rope_head_dim)
             assert self.v_head_dim and not self.qk_norm
             assert not self.layers_differ and not self.sliding_window
+            # beside recurrent layers the latent layers are kda's
+            assert set(self.layer_types or ()) <= {"full_attention", "kda"}
         if self.moe_experts_held is not None:
             assert self.moe_dropless and 0 <= self.moe_held_first \
                 <= self.moe_experts - self.moe_experts_held
@@ -223,13 +239,26 @@ class TransformerConfig:
             assert len(self.layer_types) >= self.n_layers
             assert set(self.layer_types) <= set(POOL_KINDS + STATE_KINDS
                                                 + ("latent_moe",))
-            assert not self.layers_differ and not self.kv_lora_rank
-            # experts live in latent_moe layers or in none of a period
-            assert bool(self.moe_experts) == (
-                "latent_moe" in self.layer_types[:self.n_layers])
-            if self.moe_experts:
-                assert self.moe_dropless and self.moe_latent_size \
-                    and self.moe_shared_d_ff
+            assert not self.layers_differ
+            kinds = set(self.layer_types[:self.n_layers])
+            if "kda" in kinds:
+                # its feed-forward is Block's: dense, or dropless experts
+                assert kinds <= {"kda", "full_attention"}
+                assert self.linear_gate_rank and not self.post_norm
+                assert self.linear_key_heads == self.linear_value_heads
+                assert not self.moe_experts or self.moe_dropless
+            else:
+                # experts live in latent_moe layers or in none of a period
+                assert bool(self.moe_experts) == ("latent_moe" in kinds)
+                assert not self.first_dense_layers
+                if self.moe_experts:
+                    assert self.moe_dropless and self.moe_latent_size \
+                        and self.moe_shared_d_ff
+            if self.layer_period:
+                head, periods, _ = self.runs
+                scanned = self.layer_types[head:head
+                                           + periods * self.layer_period]
+                assert periods and scanned == self.period * periods
             if set(MAMBA_KINDS) & set(self.layer_types):
                 # one recurrent class a model: its leaves have one shape
                 assert len(set(STATE_KINDS) & set(self.layer_types)) == 1
@@ -246,9 +275,25 @@ class TransformerConfig:
         kinds = (self.layer_types or ())[:self.n_layers]
         if len(set(kinds)) < 2:
             return None
+        if self.layer_period:       # the first whole one behind the head
+            head = self.runs[0]
+            return kinds[head:head + self.layer_period]
         return next(kinds[:p] for p in range(1, len(kinds) + 1)
                     if len(kinds) % p == 0
                     and all(k == kinds[i % p] for i, k in enumerate(kinds)))
+
+    @property
+    def runs(self) -> tuple:
+        """``(head, periods, tail)``: the layers of the unrolled head
+        run, the scanned whole periods behind it, and the layers of the
+        unrolled tail run (``layer_period``; without it the stack is
+        whole periods and nothing else)."""
+        p = self.layer_period
+        if not p:
+            return 0, self.n_layers // len(self.period or (None,)), 0
+        head = -(-self.first_dense_layers // p) * p
+        periods = (self.n_layers - head) // p
+        return head, periods, self.n_layers - head - periods * p
 
     def layers_of(self, *kinds: str) -> int:
         """How many of the ``n_layers`` are of one of the block classes
@@ -316,6 +361,20 @@ class TransformerConfig:
                 + self.linear_conv_kernel * (2 * qk + vv)
                 + 2 * self.linear_value_heads + self.linear_value_head_dim)
 
+    def _kda_params(self) -> int:
+        """A Kimi Delta Attention mixer: q, k, v and output projections,
+        the decay's and the output gate's low-rank pairs, the write
+        strength's projection, the three convolutions' taps, ``A_log`` a
+        head, ``dt_bias`` a key channel, the output norm's one weight
+        of ``linear_value_head_dim``."""
+        d, rank = self.d_model, self.linear_gate_rank
+        qk = self.linear_key_heads * self.linear_key_head_dim
+        vv = self.linear_value_heads * self.linear_value_head_dim
+        return (d * (2 * qk + vv) + vv * d
+                + rank * (2 * d + qk + vv) + d * self.linear_value_heads
+                + self.linear_conv_kernel * (2 * qk + vv)
+                + self.linear_value_heads + qk + self.linear_value_head_dim)
+
     def _mixer_params(self, kind: str) -> int:
         """One single-mixer layer of class ``kind`` with its norm.
         mamba2: the input projection to ``[z | x B C | dt]``, the output
@@ -347,6 +406,7 @@ class TransformerConfig:
             return self._mixer_params(kind)
         mixer = (self._attn_params() if kind == "full_attention"
                  else self._linear_attn_params() if kind == "linear_attention"
+                 else self._kda_params() if kind == "kda"
                  # the single-mixer layer's count, less its one norm
                  else self._mixer_params("mamba2") - self.d_model)
         return mixer + 3 * self.d_model * self.d_ff + 2 * self.d_model
@@ -369,9 +429,10 @@ class TransformerConfig:
         else:
             mlp = dense
         norms = 2 * self.d_model
-        linear = self.layers_of("linear_attention")
-        mixers = (self.n_layers - linear) * self._attn_params() + (
-            linear and linear * self._linear_attn_params())
+        linear, kda = self.layers_of("linear_attention"), self.layers_of("kda")
+        mixers = (self.n_layers - linear - kda) * self._attn_params() + (
+            linear and linear * self._linear_attn_params()) + (
+            kda and kda * self._kda_params())
         first = self.first_dense_layers
         # the module: two input norms, their projection, a block, a norm
         mtp = self.mtp_layers * (
@@ -601,6 +662,43 @@ PRESETS = {
         moe_d_ff=2048, moe_dropless=True, moe_scoring="sigmoid",
         moe_route_scale=2.5, moe_shared_experts=1, first_dense_layers=1,
         mtp_layers=1),
+    # Kimi-Linear-48B-A3B-Instruct (moonshotai, model_type kimi_linear) as
+    # published: 27 layers, 20 of Kimi Delta Attention (32 heads, keys
+    # and values of 128, convolutions of 4 taps, gates through a rank of
+    # 128) and 7 of latent attention that does not rotate
+    # (mla_use_nope; latent 512 + 64, queries 128 + 64, values 128) at
+    # places 3, 7, 11, 15, 19, 23 and 26: six periods of 3 + 1 and a
+    # partial one of 2 + 1; layer 0 a dense SwiGLU of 9216, then 26
+    # layers of 256 sigmoid-routed experts of 1024 (top-8 by score +
+    # bias, renormalised, x 2.446, no group limit) and one shared
+    "kimi-linear-48b-a3b": TransformerConfig(
+        vocab_size=163840, d_model=2304, n_layers=27, n_heads=32,
+        n_kv_heads=32, head_dim=192, d_ff=9216, max_seq_len=1048576,
+        rope_theta=None, norm_eps=1e-5, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        layer_types=(("kda",) * 3 + ("full_attention",)) * 6
+        + ("kda", "kda", "full_attention"), layer_period=4,
+        linear_key_heads=32, linear_value_heads=32,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel=4, linear_gate_rank=128, moe_experts=256,
+        moe_top_k=8, moe_d_ff=1024, moe_dropless=True,
+        moe_scoring="sigmoid", moe_route_scale=2.446,
+        moe_shared_experts=1, first_dense_layers=1),
+    # the same blocks at test size (tests/test_kimi_linear.py), the same
+    # shape of pattern: a dense first layer in a head run of one period,
+    # two scanned periods, a partial one; keys of 8 and values of 32
+    "tiny-kimi-linear": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=15, n_heads=4, n_kv_heads=4,
+        head_dim=24, d_ff=128, max_seq_len=256, dtype=jnp.float32,
+        remat=False, rope_theta=None, norm_eps=1e-5, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        layer_types=(("kda",) * 3 + ("full_attention",)) * 3
+        + ("kda", "kda", "full_attention"), layer_period=4,
+        linear_key_heads=4, linear_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=32, linear_conv_kernel=4, linear_gate_rank=8,
+        moe_experts=16, moe_top_k=3, moe_d_ff=32, moe_dropless=True,
+        moe_scoring="sigmoid", moe_route_scale=2.446,
+        moe_shared_experts=1, first_dense_layers=1),
     # the same blocks at test size (tests/test_k_exaone.py): a window of
     # 8, one dense layer then five of 8 experts, the module
     "tiny-k-exaone": TransformerConfig(

@@ -105,6 +105,32 @@ class MLP(nn.Module):
             nn.silu(gate) * up)
 
 
+def _rematted(block_cls, cfg: TransformerConfig):
+    """``block_cls`` checkpointed under ``cfg.remat_policy``."""
+    # the remat ladder, least to most memory (scaling-book recipe:
+    # pick the most-saving policy that still fits HBM):
+    #   nothing    — full recompute (fits 1B on one 16 GiB chip)
+    #   block_outs — save each block's attn/mlp outputs (named
+    #                checkpoints below): residual stream reconstructs
+    #                without re-running attention, ~1.5 GiB at 1B/b8
+    #   dots       — save only no-batch-dim dot outputs (tiny)
+    #   dots_all   — save every dot output (max memory, min recompute)
+    policies = {
+        "nothing": None,
+        "block_outs": jax.checkpoint_policies.save_only_these_names(
+            "attn_out", "mlp_out"),
+        "dots": jax.checkpoint_policies
+        .dots_with_no_batch_dims_saveable,
+        "dots_all": jax.checkpoint_policies.dots_saveable,
+    }
+    if cfg.remat_policy not in policies:
+        raise ValueError(
+            f"unknown remat_policy {cfg.remat_policy!r}; "
+            f"choose one of {sorted(policies)}")
+    return nn.remat(block_cls, prevent_cse=False,
+                    policy=policies[cfg.remat_policy])
+
+
 def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
                  call_args, *, remat: Optional[bool] = None,
                  cache: bool = False, name: str = "blocks",
@@ -139,28 +165,7 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
     if remat is None:
         remat = cfg.remat
     if remat:
-        # the remat ladder, least to most memory (scaling-book recipe:
-        # pick the most-saving policy that still fits HBM):
-        #   nothing    — full recompute (fits 1B on one 16 GiB chip)
-        #   block_outs — save each block's attn/mlp outputs (named
-        #                checkpoints below): residual stream reconstructs
-        #                without re-running attention, ~1.5 GiB at 1B/b8
-        #   dots       — save only no-batch-dim dot outputs (tiny)
-        #   dots_all   — save every dot output (max memory, min recompute)
-        policies = {
-            "nothing": None,
-            "block_outs": jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "mlp_out"),
-            "dots": jax.checkpoint_policies
-            .dots_with_no_batch_dims_saveable,
-            "dots_all": jax.checkpoint_policies.dots_saveable,
-        }
-        if cfg.remat_policy not in policies:
-            raise ValueError(
-                f"unknown remat_policy {cfg.remat_policy!r}; "
-                f"choose one of {sorted(policies)}")
-        policy = policies[cfg.remat_policy]
-        block_cls = nn.remat(block_cls, prevent_cse=False, policy=policy)
+        block_cls = _rematted(block_cls, cfg)
     if cfg.scan_layers:
         variable_axes = {"params": 0, "intermediates": 0}
         if cache:
@@ -663,6 +668,8 @@ class LatentAttention(nn.Module):
         q   = y Wq            heads x [q_nope dn | q_rope dr]
         ckv = y Wkva          [c' r | k_rope' dr]
         c   = RMSNorm(c');  k_rope = rope(k_rope'), ONE for all heads
+                            (no rotation of either where the model has
+                            none, ``rope_theta`` None: Kimi Linear's)
         [k_nope dn | v dv] = c Wkvb   a head
         s = (q_nope . k_nope + rope(q_rope) . k_rope) / sqrt(dn + dr)
 
@@ -717,9 +724,12 @@ class LatentAttention(nn.Module):
             (r, h, dn + dv), cfg.param_dtype).astype(cfg.dtype)
         rope = _rope_interleaved if cfg.rope_interleave else apply_rope
         c = RMSNorm(cfg.norm_eps, name="kv_norm")(ckv[..., :r])
-        k_rope = rope(ckv[..., None, r:], cos, sin, positions)[:, :, 0]
-        q = jnp.concatenate(
-            [q[..., :dn], rope(q[..., dn:], cos, sin, positions)], -1)
+        if cos is None:     # no layer rotates (cfg.rope_theta None): the
+            k_rope = ckv[..., r:]       # row's last dr are plain key dims
+        else:
+            k_rope = rope(ckv[..., None, r:], cos, sin, positions)[:, :, 0]
+            q = jnp.concatenate(
+                [q[..., :dn], rope(q[..., dn:], cos, sin, positions)], -1)
         if part == "project":
             return (q, *self._expand(c, k_rope, wkv_b),
                     jnp.concatenate([c, k_rope], -1))
@@ -890,17 +900,22 @@ class Block(nn.Module):
     # global and does not rotate, and ``moe_stacked`` is a stack of its
     # own experts alone (index 0, whatever its pool ``layer``)
     lone: bool = False
+    # a kda layer: the mixer is ``KimiDeltaAttention``, what rides as
+    # ``pool`` the recurrent leaves ``(state, conv)`` and ``layer`` its
+    # index into them, ``entries`` the rows' state entries
+    kda: bool = False
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, block_tables=None,
                  moe_stacked=None, lengths=None, pool=None, layer=None,
-                 part=None):
+                 part=None, entries=None, moe_index=None):
         """With a paged KV ``pool`` (see Attention) returns ``(x, pool)``:
         the shape ``stack_layers`` carries it through the stack in.
         ``moe_stacked``: the layer stack's whole dropless expert leaves
         (GPT hands them down in decode; see ``DroplessMoE.__call__``).
         ``layer`` counts every layer (the pool's index); the stacked
-        experts' index starts after the dense prefix.
+        experts' index starts after the dense prefix, or is
+        ``moe_index`` where the caller states it (a period's).
 
         ``lengths`` [B] (a prompt wave into the pool; None: every
         position is real): the rows' real lengths.  A wave longer than
@@ -928,7 +943,9 @@ class Block(nn.Module):
                               route_scale=cfg.moe_route_scale,
                               held=cfg.moe_experts_held,
                               held_first=cfg.moe_held_first, name="moe")
-        if cfg.kv_lora_rank:
+        if self.kda:
+            attn = KimiDeltaAttention(cfg, name="attn")
+        elif cfg.kv_lora_rank:
             attn = LatentAttention(cfg, self.mesh, self.rules, self.decode,
                                    self.prefix_attend, name="attn")
         else:
@@ -959,8 +976,19 @@ class Block(nn.Module):
             x = x + _branch(cfg, y)
             y = x if cfg.post_norm else mlp_norm(x)
             if moe is not None:
+                if cfg.layers_of("kda"):
+                    # one buffer of the experts' input for its readers
+                    # (router, experts, shared expert), as ``LatentMoE``
+                    # keeps one: in this model's programs the TPU
+                    # compiler otherwise recomputes the norm inside the
+                    # router's fusion in float32, and the router reads
+                    # activations 1.2e-3 of its logits away from the
+                    # bfloat16 ones the experts read (PERF.md section 6,
+                    # PR 55)
+                    y = jax.lax.optimization_barrier(y)
                 routed = moe(y, router_logits, live, moe_stacked,
-                             None if layer is None else 0 if self.lone
+                             moe_index if moe_index is not None
+                             else None if layer is None else 0 if self.lone
                              else layer - cfg.first_dense_layers)
                 if cfg.moe_shared_experts:    # every token, beside the sum
                     routed = routed + MLP(
@@ -987,13 +1015,16 @@ class Block(nn.Module):
         if part == "after":
             return after(*x, True)
         if (pool is not None and lengths is not None
-                and _in_chunks(x.shape[1])
+                and _in_chunks(x.shape[1]) and not self.kda
                 and not self.is_initializing()):
             return self._chunked(attn, routes_before, x, cos, sin, positions,
                                  block_tables, lengths, pool, layer, live)
         y, router_logits = before(x, positions, False)
-        y = attn(y, cos, sin, positions, block_tables, pool, layer, live,
-                 lengths)
+        if self.kda:
+            y = attn(y, lengths, entries, pool, layer, live)
+        else:
+            y = attn(y, cos, sin, positions, block_tables, pool, layer, live,
+                     lengths)
         if pool is not None:
             y, pool = y
         x = after(x, y, router_logits, False)
@@ -1077,6 +1108,8 @@ class LinearAttention(nn.Module):
     1`` one decode step on the rows' entries, dead rows untouched."""
 
     cfg: TransformerConfig
+    # ``KimiDeltaAttention``'s gates (see there)
+    channelwise: bool = False
 
     @nn.compact
     def __call__(self, x, lengths=None, entries=None, rec=None, layer=None,
@@ -1096,8 +1129,20 @@ class LinearAttention(nn.Module):
             proj((hk, dk), "wq").reshape(b, t, hk * dk),
             proj((hk, dk), "wk").reshape(b, t, hk * dk),
             proj((hv, dv), "wv").reshape(b, t, hv * dv)], axis=-1)
-        z = proj((hv, dv), "wg")
-        a, bw = proj((hv,), "wa"), proj((hv,), "wb")
+        if self.channelwise:
+            def low_rank(features, name):
+                """``x`` through ``d_model -> linear_gate_rank ->
+                features``, no bias and nothing between the two."""
+                return _dense(
+                    features, ("head_dim", "heads", "head_dim"), name + "_b",
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
+                    proj((cfg.linear_gate_rank,), name + "_a",
+                         ("embed", "head_dim")))
+            z, a = low_rank((hv, dv), "wg"), low_rank((hk, dk), "wf")
+            bw = proj((hv,), "wb")
+        else:
+            z = proj((hv, dv), "wg")
+            a, bw = proj((hv,), "wa"), proj((hv,), "wb")
         vec = lambda init: nn.with_logical_partitioning(    # noqa: E731
             init, ("norm",))
         conv_w = self.param(
@@ -1105,7 +1150,11 @@ class LinearAttention(nn.Module):
                 nn.initializers.lecun_normal(), (None, "norm")),
             (taps, u.shape[-1]), cfg.param_dtype).astype(f32)
         a_log = self.param("A_log", vec(_a_log_init), (hv,), f32)
-        dt_bias = self.param("dt_bias", vec(_dt_bias_init), (hv,), f32)
+        dt_bias = self.param(
+            "dt_bias", vec(_dt_bias_init) if not self.channelwise
+            else nn.with_logical_partitioning(_dt_bias_init,
+                                              ("heads", "norm")),
+            (hk, dk) if self.channelwise else (hv,), f32)
         o_scale = self.param("o_norm", vec(nn.initializers.ones_init()),
                              (dv,), f32)
 
@@ -1139,7 +1188,11 @@ class LinearAttention(nn.Module):
         v = v.reshape(b, t, hv, dv)
         beta = jax.nn.sigmoid(bw.astype(f32)) * (
             2.0 if cfg.linear_allow_neg_eigval else 1.0)
-        g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(f32) + dt_bias)
+        if self.channelwise:          # [b, t, heads, dk]: a channel its own
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                a.astype(f32) + dt_bias)
+        else:
+            g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(f32) + dt_bias)
 
         if decode_step:
             o, state = gd.gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -1166,11 +1219,32 @@ class LinearAttention(nn.Module):
         # per-head RMSNorm (one weight of dv), gated
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + cfg.norm_eps) * o_scale
-        o = (o * nn.silu(z.astype(f32))).astype(cfg.dtype)
+        gate = jax.nn.sigmoid if self.channelwise else nn.silu
+        o = (o * gate(z.astype(f32))).astype(cfg.dtype)
         out = _dense(cfg.d_model, ("heads_embed", "embed"), "wo",
                      dtype=cfg.dtype, param_dtype=cfg.param_dtype)(
             o.reshape(b, t, hv * dv))
         return out if rec is None else (out, rec)
+
+
+class KimiDeltaAttention(LinearAttention):
+    """Kimi Delta Attention (arXiv:2510.26692; the kimi_linear family's
+    linear layer): ``LinearAttention``'s mixer (the three convolutions
+    are depthwise, so one over ``[q; k; v]``; ``rec`` the same leaves)
+    with other gates, ``y`` the block's normed input::
+
+        g    = -exp(A_log) softplus(f_b(f_a(y)) + dt_bias)   [heads, dk]
+        beta = sigmoid(y Wb)                                 in (0, 1)
+        o    = RMSNorm_dv(o) * sigmoid(g_b(g_a(y)))
+
+    ``f`` and ``g`` are low-rank pairs through ``linear_gate_rank``,
+    ``A_log`` one a head, ``dt_bias`` one a key channel: the decay is a
+    VECTOR over the head's key channels (``S' = Diag(exp(g)) S``:
+    ops/gated_delta.py takes it by ``g``'s rank), where Gated DeltaNet
+    has one scalar a head, and the output gate a sigmoid, where it has
+    SiLU.  As many value heads as key heads."""
+
+    channelwise: bool = True
 
 
 class LinearBlock(nn.Module):
@@ -1409,6 +1483,15 @@ class MixerBlock(nn.Module):
         return x if carry is None else (x, carry)
 
 
+def _own_experts_stacked(block):
+    """A bound ``Block``'s own dropless experts as a stack of one,
+    which the decode kernel reads in place (``DroplessMoE.__call__``:
+    index 0)."""
+    moe = nn.meta.unbox(block.variables["params"]["moe"])
+    return tuple(w[None] for w in (moe["w_gate"], moe["w_up"],
+                                   moe["w_down"]))
+
+
 class Period(nn.Module):
     """One period of ``cfg.layer_types``: what ``stack_layers`` scans
     where the layers are of more than one block CLASS (their parameter
@@ -1420,13 +1503,23 @@ class Period(nn.Module):
     entry (``STATE_KINDS``).  Each class names its carry (``_carry_of``;
     an expert layer has none); ``period`` (the scanned index) times the
     layers of that carry a period, plus the position's rank among them,
-    is the layer's index into its leaf."""
+    is the layer's index into its leaf.
+
+    Also a RUN of layers that no scan holds (``cfg.runs``: the whole
+    periods a leading dense layer breaks, the partial period at the
+    end), called once: ``kinds`` its classes, ``first`` the depth of
+    its first layer (a layer before depth ``cfg.first_dense_layers`` has
+    the dense feed-forward) and ``ahead`` the layers of each carry in front
+    of it, which its indices go on from."""
 
     cfg: TransformerConfig
     mesh: Optional[Mesh] = None
     rules: ShardingRules = LOGICAL_RULES
     decode: bool = False
     prefix_attend: bool = False
+    kinds: Optional[tuple] = None          # None: cfg.period
+    first: int = 0
+    ahead: tuple = (0, 0)
 
     @staticmethod
     def _carry_of(kind: str):
@@ -1441,31 +1534,46 @@ class Period(nn.Module):
         """``moe_stacked``: ``{position in the period: the expert leaves
         stacked over periods}`` (``GPT._moe_stacked``), so that the
         decode kernel reads a layer's experts in place."""
-        kinds = self.cfg.period
+        cfg = self.cfg
+        kinds = self.kinds or cfg.period
         carries = [None, None] if carry is None else list(carry)
         held = [self._carry_of(kind) for kind in kinds]
         seen = [0, 0]
-        blocks = (self.cfg, self.mesh, self.rules, self.decode)
+        blocks = (cfg, self.mesh, self.rules, self.decode)
         for j, (kind, at) in enumerate(zip(kinds, held)):
             mine = layer = None
             if at is not None and carry is not None:
                 mine = carries[at]
                 layer = period * held.count(at) + seen[at]
+                if self.ahead[at]:
+                    layer = layer + self.ahead[at]
                 seen[at] += 1
-            if kind == "full_attention":
-                # not told the lengths: a period's prompt waves stay
-                # one pass (its linear layers compute every position)
-                x = Block(*blocks, self.prefix_attend, name=f"layer_{j}")(
-                    x, cos, sin, positions, block_tables, None, None, mine,
-                    layer)
+            stacked = (moe_stacked or {}).get(j)
+            if kind in ("full_attention", "kda"):
+                # an attention layer is not told the lengths: a period's
+                # prompt waves stay one pass (its linear layers compute
+                # every position).  Experts, where the model has them,
+                # behind either mixer, their stacked leaves' index the
+                # period's
+                kda = kind == "kda"
+                dense = self.first + j < cfg.first_dense_layers
+                block = Block(*blocks, self.prefix_attend, dense, kda=kda,
+                              name=f"layer_{j}")
+                index = period if stacked else None
+                if (self.kinds and mine is not None and cfg.moe_experts
+                        and not dense and not self.is_initializing()):
+                    # a layer of an unrolled run (as ``MTPModule``'s block)
+                    stacked, index = _own_experts_stacked(block), 0
+                x = block(x, cos, sin, positions, block_tables, stacked,
+                          lengths if kda else None, mine, layer,
+                          entries=entries, moe_index=index)
             elif kind in ("linear_attention", "mamba2_mlp"):
                 block = LinearBlock if kind == "linear_attention" \
                     else MambaBlock
                 x = block(*blocks, name=f"layer_{j}")(
                     x, block_tables, lengths, entries, mine, layer)
             else:
-                stacked = (moe_stacked or {}).get(j)
-                x = MixerBlock(self.cfg, kind, *blocks[1:],
+                x = MixerBlock(cfg, kind, *blocks[1:],
                                self.prefix_attend, name=f"layer_{j}")(
                     x, cos, sin, positions, block_tables, lengths, entries,
                     mine, period if stacked else layer, stacked)
@@ -1510,11 +1618,7 @@ class MTPModule(nn.Module):
         stacked = None
         if (pool is not None and cfg.moe_experts and cfg.moe_dropless
                 and not self.is_initializing()):
-            # its experts as a stack of one, which the decode kernel
-            # reads in place (``DroplessMoE.__call__``)
-            moe = nn.meta.unbox(block.variables["params"]["moe"])
-            stacked = tuple(w[None] for w in (moe["w_gate"], moe["w_up"],
-                                              moe["w_down"]))
+            stacked = _own_experts_stacked(block)
         x = block(x, cos, sin, positions, block_tables, stacked, lengths,
                   pool, None if pool is None else cfg.n_layers)
         if pool is not None:
@@ -1595,9 +1699,11 @@ class GPT(nn.Module):
                               moe["w_down"])
         blocks = nn.meta.unbox(self.variables["params"]["blocks"])
         if cfg.period:       # by position in the period, stacked over them
-            return {j: leaves(blocks[f"layer_{j}"]["mixer"]["moe"])
+            return {j: leaves(blocks[f"layer_{j}"]["mixer"]["moe"]
+                              if kind == "latent_moe"
+                              else blocks[f"layer_{j}"]["moe"])
                     for j, kind in enumerate(cfg.period)
-                    if kind == "latent_moe"}
+                    if kind in ("latent_moe", "full_attention", "kda")}
         return leaves(blocks["moe"])
 
     def _stack_blocks(self, x, block_kwargs, call_args, **stack):
@@ -1630,19 +1736,56 @@ class GPT(nn.Module):
         step at Olmo-Hybrid's 3 + 1), and a paged decode model carries
         ``(pool, (state, conv))`` through it: the KV pool has the layers
         that hold pages only, the recurrent leaves (their shapes the
-        recurrent class's own) those that hold a state entry."""
+        recurrent class's own) those that hold a state entry.  Under
+        ``cfg.layer_period`` the scan has an unrolled run of layers in
+        front and another behind (``cfg.runs``; parameter subtrees
+        ``head`` and ``tail``), and the carry and its indices go on
+        through all three."""
         cfg = self.cfg
-        n_periods = cfg.n_layers // len(cfg.period)
-        if not (self.decode and self.paged_pages):
+        head, n_periods, tail = cfg.runs
+        p = len(cfg.period)
+        kinds = cfg.layer_types[:cfg.n_layers]
+        paged = bool(self.decode and self.paged_pages)
+
+        def placed(first):
+            """What tells a ``Period`` that starts at depth ``first``
+            where it is: the layers of each carry in front of it."""
+            return {} if not first else dict(first=first, ahead=tuple(
+                sum(Period._carry_of(k) == at for k in kinds[:first])
+                for at in (0, 1)))
+
+        def run(name, first, count, x, carry):
+            """The ``count`` layers from ``first``, unrolled."""
+            if not count:
+                return x if carry is None else (x, carry)
+            block = _rematted(Period, cfg) if remat and not paged else Period
+            return block(cfg, **block_kwargs, **placed(first),
+                         kinds=kinds[first:first + count], name=name)(
+                x, *call_args, lengths, entries if paged else None, None,
+                carry, 0)
+
+        def stack(x, carry):
+            x = run("head", 0, head, x, carry)
+            if carry is not None:
+                x, carry = x
+            x = stack_layers(
+                Period, cfg, dict(block_kwargs, **placed(head)), x,
+                call_args + ((lengths, entries, moe_stacked) if paged
+                             else (lengths, None, None)),
+                remat=remat and not paged, n_layers=n_periods,
+                **({"carry": carry} if paged else {"cache": True}))
+            if carry is not None:
+                x, carry = x
+            return run("tail", head + n_periods * p, tail, x, carry)
+
+        if not paged:
             if self.decode and not self.is_initializing():
                 raise ValueError(
                     "a layer with a recurrent state has no dense-cache "
                     "decode: its state lives in the paged engine's "
                     "entries (serve/llm_engine.py); Generator cannot run "
                     "it")
-            return stack_layers(Period, cfg, block_kwargs, x,
-                                call_args + (lengths, None, None),
-                                remat=remat, cache=True, n_layers=n_periods)
+            return stack(x, None)
         n_pool = cfg.layers_of(*POOL_KINDS)
         n_state = cfg.layers_of(*STATE_KINDS)
         # a request's state in one recurrent layer, and its
@@ -1651,34 +1794,32 @@ class GPT(nn.Module):
             names = "ssm_state", "ssm_conv"
             entry = (cfg.ssm_state_size,
                      cfg.mamba_heads * cfg.mamba_head_dim)
-            tail = (cfg.mamba_conv_kernel - 1) * (
+            tail_width = (cfg.mamba_conv_kernel - 1) * (
                 entry[1] + 2 * cfg.mamba_groups * cfg.ssm_state_size)
         else:
             names = "gdn_state", "gdn_conv"
             entry = (cfg.linear_key_head_dim,
                      cfg.linear_value_heads * cfg.linear_value_head_dim)
-            tail = (cfg.linear_conv_kernel - 1) * (
+            tail_width = (cfg.linear_conv_kernel - 1) * (
                 2 * cfg.linear_key_heads * cfg.linear_key_head_dim
                 + cfg.linear_value_heads * cfg.linear_value_head_dim)
         ckv = self.variable(
             "cache", "kv_pages", jnp.zeros,
-            (n_pool, self.paged_pages, cfg.n_kv_heads, self.page_size,
-             2 * cfg.head_dim), cfg.dtype)
+            (n_pool, self.paged_pages, cfg.cache_kv_heads, self.page_size,
+             cfg.cache_row_width), cfg.dtype)
         cst = self.variable(
             "cache", names[0], jnp.zeros,
             (n_state, self.state_entries) + entry, jnp.float32)
         ccv = self.variable(
             "cache", names[1], jnp.zeros,
             (n_state, self.state_entries) + (
-                (tail // 128, 128) if tail % 128 == 0 else (1, tail)),
+                (tail_width // 128, 128) if tail_width % 128 == 0
+                else (1, tail_width)),
             cfg.dtype)
         if entries is None:
             entries = jnp.arange(x.shape[0], dtype=jnp.int32)
-        x, (pool, (state, conv)) = stack_layers(
-            Period, cfg, block_kwargs, x,
-            call_args + (lengths, entries, moe_stacked), remat=False,
-            n_layers=n_periods,
-            carry=(ckv.value, (cst.value, ccv.value)))
+        x, (pool, (state, conv)) = stack(
+            x, (ckv.value, (cst.value, ccv.value)))
         if not self.is_initializing():
             ckv.value, cst.value, ccv.value = pool, state, conv
         return x

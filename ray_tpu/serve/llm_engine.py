@@ -75,9 +75,12 @@ iteration's prefills):
     state.  Time-to-first-token is bounded by prefill throughput and
     pool capacity, not by slot turnover.
   - State that is not pages.  A model with recurrent layers
-    (models/gpt.py LinearAttention, Mamba2Mixer) keeps, beside its KV
-    pages in the attention layers, a FIXED-SIZE recurrent state a
-    request: two more stacked cache leaves ``gdn_state`` [linear layers,
+    (models/gpt.py LinearAttention, KimiDeltaAttention, Mamba2Mixer)
+    keeps, beside its KV pages in the attention layers (latent rows
+    where those are latent: the pool and the entries then live in one
+    engine, each counted by the layers that hold it), a FIXED-SIZE
+    recurrent state a request: two more stacked cache leaves
+    ``gdn_state`` [linear layers,
     entries, dk, heads*dv] float32 and ``gdn_conv`` [linear layers,
     entries, (taps-1)*channels/128, 128] (``ssm_state`` [Mamba-2 layers,
     entries, N, heads*P] and ``ssm_conv`` for Mamba-2 layers of either

@@ -1167,3 +1167,101 @@ def test_granite_cell_fits_the_chip(granite_programs):
         assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
                 + block.temp_size_in_bytes) < 16.0e9, (
             bucket, prefill.temp_size_in_bytes / 1e9)
+
+
+# ---- all 27 layers of Kimi Linear beside 12.9 GB resident on ONE
+# ---- chip (ISSUE 55)
+
+@pytest.fixture(scope="module")
+def kimi_programs(topo, one_chip):
+    """``engine_decode_block`` and the widest warmed prefill waves of the
+    serve-longgen cell (one prompt of 8,192 tokens, and 8 x 1,024 under
+    ``prefill_wave_tokens`` 8192) of kimi-linear-48b-a3b at the cell's
+    server: all 27 layers (head run of 4, five scanned periods, tail run
+    of 3), 16 of 256 experts held, the full 163,840-row head, 32 slots,
+    41 state entries, 2,048 pages.  Compiled once for the tests below."""
+    from ray_tpu.models.configs import get_config
+
+    cfg = get_config("kimi-linear-48b-a3b", moe_experts_held=16,
+                     max_seq_len=12288, dtype=jnp.bfloat16,
+                     param_dtype=jnp.bfloat16)
+    patch = pytest.MonkeyPatch()
+    _answer_tpu(patch)
+    try:
+        eng = _described_engine(cfg, patch, num_slots=32, max_seq_len=12288,
+                                max_prompt_len=8192, kv_pool_pages=2048,
+                                prefill_wave_tokens=8192)
+        block = eng._block_jit.lower(
+            *_shapes((eng.params, eng._cache, eng._state) + eng._no_admit,
+                     one_chip))
+        out = {"eng": eng, "block_text": block.as_text(),
+               "engine_decode_block": block.compile()}
+        for bucket, wave in ((8192, 1), (1024, 8)):
+            prefill = eng._get_prefill_paged(bucket, wave).lower(
+                *_shapes((eng.params, eng._cache,
+                          jnp.zeros((wave, eng.packed_width(bucket)),
+                                    jnp.int32),
+                          jnp.zeros((wave, eng.max_pages), jnp.int32),
+                          jax.random.PRNGKey(0)), one_chip))
+            out[f"prefill_text_{bucket}"] = prefill.as_text()
+            out[f"engine_prefill_{bucket}"] = prefill.compile()
+        return out
+    finally:
+        patch.undo()
+
+
+def test_kimi_engine_programs_compile_with_their_kernels(kimi_programs):
+    """The decode block holds ``kda_decode`` once a KDA layer of the head
+    run, of the scanned period and of the tail run (3 + 3 + 2), the
+    absorbed latent kernel once a latent layer of each (1 + 1 + 1) and
+    the expert kernel once an expert layer (3 + 4 + 3); no ``gdn_decode``
+    (the scalar rule's name); a latent pool of the 7 pool layers alone
+    and state leaves of the 20 KDA layers; the prefill waves are plain
+    XLA."""
+    p = kimi_programs
+    assert {k: v.shape for k, v in p["eng"]._cache.items()} == {
+        "kv_pages": (7, 2048, 1, 64, 640),
+        "gdn_state": (20, 41, 128, 4096),
+        "gdn_conv": (20, 41, 288, 128)}
+    assert p["eng"]._cache["gdn_state"].dtype == jnp.float32
+    text = p["block_text"]
+    assert text.count('kernel_name = "kda_decode"') == 8
+    assert text.count('kernel_name = "gdn_decode"') == 0
+    assert text.count('kernel_name = "paged_attention_decode"') == 3
+    assert text.count('kernel_name = "moe_experts_decode"') == 10
+    assert "@jit_engine_decode_block" in text
+    for bucket in (8192, 1024):
+        assert "@jit_engine_prefill" in p[f"prefill_text_{bucket}"]
+        assert "kda_decode" not in p[f"prefill_text_{bucket}"]
+
+
+@pytest.mark.parametrize("name", ["engine_decode_block",
+                                  "engine_prefill_8192",
+                                  "engine_prefill_1024"])
+def test_kimi_programs_address_pool_and_state_in_place(kimi_programs, name):
+    """Nothing but parameters and in-place updates produces a result of
+    the size of the latent pool or of the 1.76 GB state leaf, whole or
+    one layer of it."""
+    hlo = kimi_programs[name].as_text()
+    pool = 2048 * 64 * 640
+    state = 41 * 128 * 4096
+    for what, sizes, dtype in (("pool", (pool, 7 * pool), "bf16"),
+                               ("state", (state, 20 * state), "f32")):
+        made = _pool_result_producers(hlo, sizes, dtype)
+        assert set(made) <= _IN_PLACE, (
+            f"{name}: {what}-sized results from {dict(made)}")
+
+
+def test_kimi_cell_fits_the_chip(kimi_programs):
+    """12.9 GB resident (weights 9.91, state entries 1.76 and their tails
+    0.06, pool 1.17), arguments and temporaries of the decode block and
+    of each of the widest prefill waves under 16 GB."""
+    p = kimi_programs
+    block = p["engine_decode_block"].memory_analysis()
+    assert 12.7e9 < block.argument_size_in_bytes < 13.1e9
+    assert (block.argument_size_in_bytes + block.temp_size_in_bytes) < 16e9
+    for bucket in (8192, 1024):
+        prefill = p[f"engine_prefill_{bucket}"].memory_analysis()
+        assert (prefill.argument_size_in_bytes + prefill.temp_size_in_bytes
+                + block.temp_size_in_bytes) < 16.0e9, (
+            bucket, prefill.temp_size_in_bytes / 1e9)
