@@ -30,6 +30,7 @@ from ray_tpu.models.configs import (MAMBA_KINDS, POOL_KINDS, STATE_KINDS,
                                     TransformerConfig)
 from ray_tpu.ops.attention import repeat_kv, xla_attention
 from ray_tpu.ops.layers import apply_rope, rope_frequencies
+from ray_tpu.ops.moe import PAIR_ROWS
 from ray_tpu.parallel.sharding import LOGICAL_RULES, ShardingRules, with_sharding
 
 
@@ -167,7 +168,7 @@ def stack_layers(block_cls, cfg: TransformerConfig, ctor_kwargs, x,
     if remat:
         block_cls = _rematted(block_cls, cfg)
     if cfg.scan_layers:
-        variable_axes = {"params": 0, "intermediates": 0}
+        variable_axes = {"params": 0, "intermediates": 0, PAIR_ROWS: 0}
         if cache:
             variable_axes["cache"] = 0
         layers = jnp.arange(first_layer, first_layer + n_layers,
@@ -865,25 +866,35 @@ def prefill_positions(span: int, longest: int) -> int:
     return min(span, -(-longest // PREFILL_CHUNK) * PREFILL_CHUNK)
 
 
-def _over_chunks(fn, operands, chunks, out):
+def _over_chunks(fn, operands, chunks, out, summed=None):
     """``fn`` over chunks ``0 .. chunks - 1`` (a traced count) of the
     position axis, in ONE compiled loop body: ``operands`` is a pytree
     of ``[B, T, ...]`` arrays, of which ``fn`` is handed ``[B,
     PREFILL_CHUNK, ...]`` slices and returns a pytree of such slices;
     ``out`` is that pytree as the ``[B, T, ...]`` shapes of the whole
     span (given, not traced for: ``fn`` is traced once, as the one pass
-    it replaces was).  Returns the outputs, ZEROS where no chunk ran."""
-    def body(i, out):
+    it replaces was).  Returns the outputs, ZEROS where no chunk ran.
+    With ``summed`` (a shape) ``fn`` returns ``(slices, an array of that
+    shape)`` and the loop carries the arrays' sum over the chunks run
+    beside the outputs: ``(outputs, sum)``."""
+    def body(i, carry):
+        out, total = carry
+        part = fn(jax.tree.map(
+            lambda a: jax.lax.dynamic_slice_in_dim(
+                a, i * PREFILL_CHUNK, PREFILL_CHUNK, axis=1),
+            operands))
+        if summed is not None:
+            part, count = part
+            total = total + count
         return jax.tree.map(
             lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
                 whole, part, i * PREFILL_CHUNK, axis=1),
-            out, fn(jax.tree.map(
-                lambda a: jax.lax.dynamic_slice_in_dim(
-                    a, i * PREFILL_CHUNK, PREFILL_CHUNK, axis=1),
-                operands)))
-    return jax.lax.fori_loop(
+            out, part), total
+    out, total = jax.lax.fori_loop(
         0, chunks, body,
-        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), out))
+        (jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), out),
+         None if summed is None else jnp.zeros(summed.shape, summed.dtype)))
+    return out if summed is None else (out, total)
 
 
 class Block(nn.Module):
@@ -1017,8 +1028,10 @@ class Block(nn.Module):
         if (pool is not None and lengths is not None
                 and _in_chunks(x.shape[1]) and not self.kda
                 and not self.is_initializing()):
-            return self._chunked(attn, routes_before, x, cos, sin, positions,
-                                 block_tables, lengths, pool, layer, live)
+            return self._chunked(
+                attn, routes_before, x, cos, sin, positions, block_tables,
+                lengths, pool, layer, live,
+                moe is not None and self.is_mutable_collection(PAIR_ROWS))
         y, router_logits = before(x, positions, False)
         if self.kda:
             y = attn(y, lengths, entries, pool, layer, live)
@@ -1034,7 +1047,7 @@ class Block(nn.Module):
         return x if pool is None else (x, pool)
 
     def _chunked(self, attn, routes_before: bool, x, cos, sin, positions,
-                 block_tables, lengths, pool, layer, live):
+                 block_tables, lengths, pool, layer, live, counted: bool):
         """A prompt wave of more than one chunk a row, told its real
         ``lengths``: the sections before and after the attention run a
         chunk of positions at a time, all rows at once, over ``ceil(max(
@@ -1048,7 +1061,10 @@ class Block(nn.Module):
         block's output, which is the next block's input there.  A real
         position gets what one pass over the span gives it: the work is
         per token, experts included (a chunk's sort and grouped products
-        hand a token what the wave's would)."""
+        hand a token what the wave's would).  ``counted``: the expert
+        layer's count of pair rows (``DroplessMoE``, for a caller that
+        asked for ``PAIR_ROWS``) rides the second loop and is sown from
+        here."""
         cfg = self.cfg
         chunks = -(-jnp.max(lengths) // PREFILL_CHUNK)
         params = {"params": self.variables["params"]}
@@ -1064,12 +1080,20 @@ class Block(nn.Module):
                                   jnp.float32) if routes_before else None))
         y, pool = attn(projected, cos, sin, positions, block_tables, pool,
                        layer, live, lengths, part="attend")
+
+        def after(cut):
+            out = block.apply(params, cut, cos, sin,
+                              block_tables=block_tables, layer=layer,
+                              part="after",
+                              mutable=[PAIR_ROWS] if counted else False)
+            return (out[0], sum(jax.tree.leaves(out[1]))) if counted else out
         x = _over_chunks(
-            lambda cut: block.apply(params, cut, cos, sin,
-                                    block_tables=block_tables, layer=layer,
-                                    part="after"),
-            (x, y, router_logits), chunks,
-            jax.ShapeDtypeStruct(x.shape, x.dtype))
+            after, (x, y, router_logits), chunks,
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((2,), jnp.int32) if counted else None)
+        if counted:
+            x, rows = x
+            self.sow(PAIR_ROWS, "rows", rows)
         return x, pool
 
 

@@ -17,11 +17,13 @@ step collects and adds it (ray_tpu/train/step.py lm_loss_fn).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops.attention import backend_platform
 
@@ -149,11 +151,28 @@ DENSE_PAIRS_TOP_K = 6
 # at d 2048)
 RAGGED_PAIRS_MAX = 1 << 17
 
+# a layer that holds a SHARE of the router's experts runs its sorted
+# pairs a slab of this many times the share's own pairs at a time
+# (``held_slab``): room for a wave that sends this chip more than its
+# share before a second slab has to run, in an ODD number of tiles of
+# pair rows: XLA's TPU ``ragged_dot`` over a slab of 512, 1,024 or 1,536
+# rows against 16 small groups took 20-30% longer than over 640-1,280 or
+# 1,792 (jaxlib 0.9.0, libtpu 0.0.34 on a v5e; PERF.md section 6, PR 56:
+# what room and tile were chosen from; the oddness is that compiler's,
+# to be read again when it changes)
+SLAB_HEADROOM = 1.25
+SLAB_TILE = 256
+
 # the kernel's double-buffered blocks of the three matrices may take this
 # much VMEM before the expert width is tiled (a v5e core has 128 MiB;
 # SmallThinker's expert is 11.8 MB, 23.6 MB double-buffered)
 _KERNEL_WEIGHTS_VMEM = 40 << 20
 
+
+# the collection an expert layer sows ``dropless_experts``' count of
+# pair rows into, for a caller that makes it mutable (the engine's
+# prefill programs where the model holds a share of its experts)
+PAIR_ROWS = "moe_pair_rows"
 
 # an expert's activation by name ("relu2": Nemotron's squared ReLU)
 ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
@@ -313,11 +332,121 @@ def _experts_kernel(x, combine, ids, count, layer, w_gate, w_up, w_down,
       w_down)
 
 
+def held_slab(pairs: int, share: float) -> int:
+    """The pair rows the grouped products run over at a time where a
+    layer holds ``share`` of the router's experts: a function of the
+    ``pairs`` (tokens x top_k) and the share alone.  The share's pairs
+    with ``SLAB_HEADROOM``, in an odd number of ``SLAB_TILE``s, not
+    below the rows from which XLA's ``ragged_dot`` is the grouped
+    product (``DENSE_PAIRS_MAX``), never more than the pairs; all of
+    them where the layer holds every expert."""
+    if share >= 1.0:
+        return pairs
+    tiles = max(-(-int(pairs * share * SLAB_HEADROOM) // SLAB_TILE),
+                -(-DENSE_PAIRS_MAX // SLAB_TILE)) | 1
+    return min(pairs, tiles * SLAB_TILE)
+
+
+def runs_in_slabs(pairs: int, rows: int, share: float) -> bool:
+    """Whether ``pairs`` (``rows`` x top_k) of a layer that holds
+    ``share`` of its experts take the grouped formulation a slab at a
+    time: not a small batch, and a slab smaller than the pairs."""
+    return not small_batch(pairs, rows) and held_slab(pairs, share) < pairs
+
+
+def _pair_rows(x, gates, rows, k, sizes, w_gate, w_up, w_down, fn):
+    """The sorted pairs ``rows`` (ids ``token * k + choice``, by expert,
+    ``sizes [E]`` of them an expert) through their experts, scaled by
+    their gates: gather, the grouped products, the scaling.  ``[len(
+    rows), d]``; a row behind the last group is undefined."""
+    xs = jnp.take(x, rows // k, axis=0)
+    up_h = jax.lax.ragged_dot(xs, w_up, sizes)
+    h = (fn(jax.lax.ragged_dot(xs, w_gate, sizes)) * up_h
+         if w_gate is not None else fn(up_h))
+    out = jax.lax.ragged_dot(h, w_down, sizes)
+    return out * jnp.take(gates.reshape(-1), rows)[:, None].astype(out.dtype)
+
+
+def _add_rows(y, tokens, rows):
+    """``y [N, d]`` float32 with ``rows [S, d]`` added to its rows
+    ``tokens [S]`` (a token may occur more than once), as a 0/1
+    selection product: exact products, float32 sums.  Its work grows
+    with ``N * S``; on a v5e it still costs no more than a float32
+    scatter-add of the same rows at the widest call a cell makes (8,192
+    tokens, a slab of 5,376: 3,551 against 3,648 us for the expert
+    section) and 3-20% less below that (PERF.md section 6, PR 56, the
+    review round); a call of more tokens would want it tiled."""
+    mine = jnp.arange(y.shape[0], dtype=tokens.dtype)[:, None] == tokens
+    return y + jnp.dot(mine.astype(rows.dtype), rows,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _no_gradient(operands):
+    """``operands`` as they are, for a computation that has no
+    reverse-mode gradient: asked for one, it says why (JAX's own error
+    names a ``while_loop``, not the layer that emitted it)."""
+    return operands
+
+
+def _no_gradient_fwd(operands):
+    raise NotImplementedError(
+        "dropless_experts(share < 1): a layer that holds a share of its "
+        "experts (DroplessMoE.held) runs its pairs a slab at a time under "
+        "a traced trip count, which has no reverse-mode gradient; a layer "
+        "that is trained holds every expert (held=None: one pass, no loop)")
+
+
+_no_gradient.defvjp(_no_gradient_fwd, lambda *_: None)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "slab", "act"))
+def _in_slabs(x, gates, w_gate, w_up, w_down, order, sizes, layer, *,
+              k: int, slab: int, act: str):
+    """``(sum [N, d], slabs run)``: the sorted pairs ``order`` (``sizes
+    [E]`` held ones an expert, the others behind them) through their
+    experts a slab of ``slab`` rows at a time, the ``ceil(held pairs /
+    slab)`` slabs the held pairs fill in ONE loop body under that traced
+    count; each slab's rows added to their tokens in float32, rounded
+    once.  ``layer`` (or None): the weights are a stack's whole leaves,
+    and the body picks the layer.  Jitted on its own so that a program
+    of several expert layers of one shape traces and lowers this body
+    once: traced an unrolled layer, it cost a replica ~0.2 s a layer a
+    prefill program of set-up (PERF.md section 6, PR 56)."""
+    n, d = x.shape
+    pairs = order.shape[0]
+    ends = jnp.cumsum(sizes)
+    held_pairs = ends[-1]
+    slabs = -(-held_pairs // slab)
+    # whole slabs: the pad's rows lie behind every held pair
+    order = jnp.pad(order, (0, -pairs % slab))
+
+    def run_slab(i, y):
+        lo = i * slab
+        rows = jax.lax.dynamic_slice_in_dim(order, lo, slab)
+        within = lambda a: jnp.clip(a, lo, lo + slab)   # noqa: E731
+        ws = w_gate, w_up, w_down
+        if layer is not None:
+            ws = (w if w is None else w[layer] for w in ws)
+        out = _pair_rows(x, gates, rows, k,
+                         within(ends) - within(ends - sizes), *ws,
+                         ACTS[act])
+        # (a row behind the last held pair is undefined, not zero)
+        out = jnp.where((lo + jnp.arange(slab) < held_pairs)[:, None],
+                        out, 0)
+        return _add_rows(y, rows // k, out)
+
+    y = jax.lax.fori_loop(0, slabs, run_slab,
+                          jnp.zeros((n, d), jnp.float32))
+    return y.astype(x.dtype), slabs
+
+
 def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
                      act: str = "silu", live=None, layer=None,
-                     partial: bool = False) -> jax.Array:
-    """``sum_j gates[t, j] * down_e(act(gate_e x_t) * up_e x_t)`` with
-    ``e = experts[t, j]``, for EVERY pair ``(t, j)``: no capacity, nothing
+                     partial: bool = False, share: float = 1.0):
+    """``(sum, rows)``: ``sum_j gates[t, j] * down_e(act(gate_e x_t) *
+    up_e x_t)`` with ``e = experts[t, j]``, for EVERY pair ``(t, j)``: no capacity, nothing
     dropped whatever the imbalance.  ``w_gate`` None: an expert that is
     not gated, ``down_e(act(up_e x_t))``, in every formulation; ``act``
     ``"relu2"`` is ``relu(.)^2``.
@@ -325,7 +454,9 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     x [N, d]; gates, experts [N, k]; w_gate, w_up [E, d, f]; w_down
     [E, f, d] (already in the compute dtype), or with ``layer`` (an int32
     scalar, traced or not) a layer stack's whole leaves ``[L, E, ...]``,
-    of which layer ``layer`` is read.  Two formulations of the same sum,
+    of which layer ``layer`` is read (by the decode kernel in place; by
+    the slab loop inside its body, so that a scanned stack's layer is
+    not copied out to be carried into the loop).  Two formulations of the same sum,
     chosen by the static number of pairs and rows (``small_batch``).
     In a small batch the gates pick among experts that are each read
     once for all rows (``live [N]`` bool: only those rows count and the
@@ -348,7 +479,27 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     ``partial`` an id may be ``E``: a pair whose expert is not among
     them (``DroplessMoE.held``: it is some other chip's), dropped before
     anything is read or multiplied for it (a scatter drops an index out
-    of range; the sort puts such pairs behind every group)."""
+    of range; the sort puts such pairs behind every group).
+
+    ``share`` (static; ``DroplessMoE`` states ``held / n_experts``): the
+    part of the router's experts that the weights given are.  The
+    grouped formulation runs the sorted pairs a slab of ``held_slab``
+    rows at a time.  At share 1 that is one slab of all the pairs: one
+    pass, no loop, differentiable.  Below it the pairs of experts held
+    elsewhere sort behind every group, and only the ``ceil(held pairs /
+    slab)`` slabs that held pairs fill are run, in one loop body under
+    that traced count (no reverse-mode gradient: ``jax.grad`` raises and
+    says so): the slab's rows are
+    gathered, multiplied with each group's size clipped to the slab,
+    scaled, and added to their tokens in float32, rounded once at the
+    end (the one pass sums a token's rounded rows in the output dtype);
+    nothing ``[N * k, d]`` wide exists.  No pair held: no slab runs and
+    the sum is exactly zero.
+
+    ``rows``: ``[pairs, pair rows run]`` int32, what the grouped
+    products were given and what they ran over (slabs run x slab; the
+    pairs themselves in one pass, zeros in a small batch: constants
+    there, no equation of the program)."""
     n, d = x.shape
     e, f = w_up.shape[-3], w_up.shape[-1]
     k = experts.shape[1]
@@ -356,14 +507,19 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     gated = w_gate is not None
     if n * k > RAGGED_PAIRS_MAX and n % 2 == 0:
         halves = lambda a: a.reshape(2, n // 2, *a.shape[1:])  # noqa: E731
-        return jax.lax.map(
+        y, rows = jax.lax.map(
             lambda xs: dropless_experts(*xs[:3], w_gate, w_up, w_down,
                                         act=act, live=xs[3], layer=layer,
-                                        partial=partial),
+                                        partial=partial, share=share),
             (halves(x), halves(gates), halves(experts),
-             None if live is None else halves(live))).reshape(n, d)
+             None if live is None else halves(live)))
+        return y.reshape(n, d), rows.sum(axis=0)
     kernel = layer is not None and expert_kernel_applies(n * k, d, f, n)
-    if layer is not None and not kernel:
+    # a stack's layer is picked where it is read: by the kernel, inside
+    # the slab loop's body, else here
+    in_loop = (layer is not None and not kernel
+               and runs_in_slabs(n * k, n, share))
+    if layer is not None and not kernel and not in_loop:
         w_gate, w_up, w_down = (w if w is None else w[layer]
                                 for w in (w_gate, w_up, w_down))
     if small_batch(n * k, n):
@@ -375,15 +531,17 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
         if kernel:
             ids, count = touched_experts(experts, live, e)
             pad = (0, -n % 16), (0, 0)             # whole bf16 sublane tiles
-            return _experts_kernel(
+            y = _experts_kernel(
                 jnp.pad(x, pad), jnp.pad(combine, pad), ids,
                 count.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
                 w_gate, w_up, w_down, fn)[:n]
-        up_h = jnp.einsum("nd,edf->enf", x, w_up)
-        h = (fn(jnp.einsum("nd,edf->enf", x, w_gate)) * up_h if gated
-             else fn(up_h))
-        h = h.astype(jnp.float32) * combine.T[:, :, None]
-        return jnp.einsum("enf,efd->nd", h.astype(x.dtype), w_down)
+        else:
+            up_h = jnp.einsum("nd,edf->enf", x, w_up)
+            h = (fn(jnp.einsum("nd,edf->enf", x, w_gate)) * up_h if gated
+                 else fn(up_h))
+            h = h.astype(jnp.float32) * combine.T[:, :, None]
+            y = jnp.einsum("enf,efd->nd", h.astype(x.dtype), w_down)
+        return y, np.zeros((2,), np.int32)
     if live is not None:
         # a row that holds no request sends its pairs where ``partial``
         # sends another chip's: behind every group, nothing read for them
@@ -391,17 +549,20 @@ def dropless_experts(x, gates, experts, w_gate, w_up, w_down, *,
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True)              # pairs by expert
     sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-    xs = jnp.take(x, order // k, axis=0)                # [N*k, d]
-    up_h = jax.lax.ragged_dot(xs, w_up, sizes)
-    h = (fn(jax.lax.ragged_dot(xs, w_gate, sizes)) * up_h if gated
-         else fn(up_h))
-    out = jax.lax.ragged_dot(h, w_down, sizes)
-    out = out * jnp.take(gates.reshape(-1), order)[:, None].astype(
-        out.dtype)
-    if partial:          # rows behind the last group belong to no expert
-        out = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None], out, 0)
-    back = jnp.argsort(order)                           # pair -> row
-    return jnp.take(out, back, axis=0).reshape(n, k, d).sum(axis=1)
+    pairs, slab = n * k, held_slab(n * k, share)
+    if slab == pairs:                                   # one pass
+        out = _pair_rows(x, gates, order, k, sizes, w_gate, w_up, w_down,
+                         fn)                            # [N*k, d]
+        if partial:      # rows behind the last group belong to no expert
+            out = jnp.where((jnp.arange(pairs) < sizes.sum())[:, None],
+                            out, 0)
+        back = jnp.argsort(order)                       # pair -> row
+        y = jnp.take(out, back, axis=0).reshape(n, k, d).sum(axis=1)
+        return y, np.full((2,), pairs, np.int32)
+    y, slabs = _in_slabs(*_no_gradient((x, gates, w_gate, w_up, w_down)),
+                         order, sizes, layer if in_loop else None,
+                         k=k, slab=slab, act=act)
+    return y, jnp.stack([jnp.int32(pairs), slabs * slab])
 
 
 class DroplessMoE(nn.Module):
@@ -418,7 +579,10 @@ class DroplessMoE(nn.Module):
     group does.  It routes over all ``n_experts`` and computes the pairs
     that chose an expert it holds; the others' terms are left out of its
     sum.  ``expert_idx`` then counts in the held experts' own numbering,
-    ``held`` for a pair that went elsewhere."""
+    ``held`` for a pair that went elsewhere.  The share ``held /
+    n_experts`` is what ``dropless_experts`` sizes its slabs by; for a
+    caller that makes the ``PAIR_ROWS`` collection mutable the layer
+    sows ``rows`` there: ``[pairs, pair rows run]`` of the call."""
 
     d_model: int
     n_experts: int
@@ -479,7 +643,8 @@ class DroplessMoE(nn.Module):
         ...]`` of the layer stack this layer is scanned in, and ``layer``
         its index there.  The decode kernel reads them in place; handed
         this layer's slice of the scan, a custom call would make XLA
-        copy the layer's experts out first (PERF.md, PR 26)."""
+        copy the layer's experts out first (PERF.md, PR 26), and so
+        would the slab loop of a layer that holds a share (PR 56)."""
         b, s, d = x.shape
         if logits is None:
             logits = self.router_logits(x)
@@ -496,20 +661,24 @@ class DroplessMoE(nn.Module):
                  experts.reshape(b, s, self.top_k))
         dt = self.dtype
         weights = self.w_gate, self.w_up, self.w_down
+        pairs = b * s * self.top_k
+        share = 1.0 if self.held is None else self.held / self.n_experts
         if (stacked is not None and layer is not None
                 and stacked[1].dtype == dt
-                and expert_kernel_applies(b * s * self.top_k, d, self.d_ff,
-                                          b * s)):
+                and (expert_kernel_applies(pairs, d, self.d_ff, b * s)
+                     or runs_in_slabs(pairs, b * s, share))):
             weights = stacked
         else:           # this layer's own slice, as the scan hands it over
             layer = None
         if live is not None:
             live = jnp.repeat(live, s)
-        y = dropless_experts(
+        y, rows = dropless_experts(
             x.reshape(b * s, d).astype(dt), gates, experts,
             *(w if w is None else w.astype(dt) for w in weights),
             act=self.act, live=live,
-            layer=layer, partial=self.held is not None)
+            layer=layer, partial=self.held is not None, share=share)
+        if self.is_mutable_collection(PAIR_ROWS):
+            self.sow(PAIR_ROWS, "rows", jnp.asarray(rows))
         return y.reshape(b, s, d).astype(x.dtype)
 
 

@@ -177,6 +177,7 @@ from ray_tpu.models.configs import (POOL_KINDS, STATE_KINDS,
                                     TransformerConfig)
 from ray_tpu.models.gpt import (GPT, narrowed_logits, output_logits,
                                 prefill_positions)
+from ray_tpu.ops.moe import PAIR_ROWS
 from ray_tpu.serve.frontdoor.prefix import page_digests
 
 # admission waves are padded to the next of these sizes (bounded jit
@@ -455,6 +456,13 @@ class EngineStats:
         # tokens: a layer step is one expert layer in one decode step
         self.moe_layer_steps = 0
         self.moe_experts_touched = 0     # experts with >= 1 pair, summed
+        # a model that holds a SHARE of its experts (cfg.moe_experts_held):
+        # the (token, choice) pairs its prefill programs' grouped expert
+        # products were given, every expert layer, and the pair rows
+        # they ran over (slabs run x slab: ops/moe.py dropless_experts);
+        # each program counts its own, fetched behind its first tokens
+        self.moe_prefill_pairs = 0
+        self.moe_prefill_pairs_run = 0
         # pages the decode kernel read over delivered tokens, every
         # layer (decode_pages_read: times a page's bytes in one layer it
         # is the kernel's HBM traffic); of them the window layers' part
@@ -583,6 +591,8 @@ class EngineStats:
             "prefill_padded_tokens": self.prefill_padded_tokens,
             "moe_layer_steps": self.moe_layer_steps,
             "moe_experts_touched": self.moe_experts_touched,
+            "moe_prefill_pairs": self.moe_prefill_pairs,
+            "moe_prefill_pairs_run": self.moe_prefill_pairs_run,
             "decode_pages_read": self.decode_pages_read,
             "window_pages_read": self.window_pages_read,
             "window_pages_skipped": self.window_pages_skipped,
@@ -737,6 +747,14 @@ class LLMEngine:
         # program it always did
         self._counts_expert_load = bool(
             cfg.moe_experts and cfg.moe_dropless)
+        # a model that holds a share of its experts: a prefill program
+        # also returns its expert layers' pair rows
+        # (EngineStats.moe_prefill_*), which ``_get_prefill_paged``'s
+        # callable keeps here until ``_process_prefill_waves`` reads
+        # them; any other model's programs and callers are as they were
+        self._counts_pair_rows = bool(
+            self._counts_expert_load and cfg.moe_experts_held)
+        self._pair_rows: list = []
         # layers whose decode reads stop at the window
         self._window_layers = (
             0 if not cfg.sliding_window else cfg.n_layers
@@ -914,7 +932,8 @@ class LLMEngine:
         """``(logits [wave, vocab] of each row's last REAL position, the
         updated cache)``; a drafting engine's: ``(logits, cache, the
         stack's hidden states before the final norm)``, for
-        ``_first_draft``.  The head runs on those rows alone: float32
+        ``_first_draft``; where the engine counts them, then the expert
+        layers' pair rows (``_sown_pair_rows``).  The head runs on those rows alone: float32
         logits of every position are ``wave x bucket x vocab`` (1.2 GB
         for one 2048-token prompt at a 152k vocabulary), of which one
         row a prompt is read.  ``skip_pad``: the model is told the real
@@ -928,13 +947,13 @@ class LLMEngine:
             told["state_rows"] = entries
         hidden, mut = model.apply(
             {"params": params, "cache": cache}, tokens, positions,
-            return_hidden=True, mutable=["cache"], block_tables=tables,
-            return_prenorm=self._drafts, **told)
+            return_hidden=True, mutable=self._prefill_mutable,
+            block_tables=tables, return_prenorm=self._drafts, **told)
         hidden, *prenorm = hidden if self._drafts else (hidden,)
         last = jnp.take_along_axis(
             hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0]
         return (output_logits(self.cfg, params, last), mut["cache"],
-                *prenorm)
+                *prenorm, *self._sown_pair_rows(mut))
 
     def _first_draft(self, params, cache, prenorm, tokens, positions,
                      s_reals, tables, first, temps, rng):
@@ -944,17 +963,55 @@ class LLMEngine:
         ``_last_logits`` returns it, and token i + 1: the prompt's next,
         ``first`` at the last real position), and the first draft, drawn
         from its logits at that position.  ``((first, draft, logits),
-        cache)``."""
+        cache)``, then the pair rows where ``_last_logits`` has them."""
         rows = jnp.arange(tokens.shape[0])
         nxt = jnp.roll(tokens, -1, axis=1).at[rows, s_reals - 1].set(first)
         hidden, mut = self.model.apply(
             {"params": params, "cache": cache}, nxt, positions,
-            return_hidden=True, mutable=["cache"], block_tables=tables,
-            mtp_hidden=prenorm, lengths=s_reals)
+            return_hidden=True, mutable=self._prefill_mutable,
+            block_tables=tables, mtp_hidden=prenorm, lengths=s_reals)
         logits = output_logits(self.cfg, params, jnp.take_along_axis(
             hidden, (s_reals - 1)[:, None, None], axis=1)[:, 0])
         return ((first, self._sample_fn(rng, logits, temps), logits),
-                mut["cache"])
+                mut["cache"], *self._sown_pair_rows(mut))
+
+    @property
+    def _prefill_mutable(self) -> list:
+        """The collections a prefill program lets the model write."""
+        return ["cache", PAIR_ROWS] if self._counts_pair_rows else ["cache"]
+
+    def _sown_pair_rows(self, written) -> tuple:
+        """Nothing, or where the engine counts them one array: ``[pairs,
+        pair rows run]`` int32 summed over the expert layers that sowed
+        into the collections ``written`` (one leaf a layer, or one a
+        scanned stack, ``[layers, 2]``)."""
+        if not self._counts_pair_rows:
+            return ()
+        return (sum((leaf.reshape(-1, 2).sum(axis=0) for leaf in
+                     jax.tree.leaves(written.get(PAIR_ROWS, ()))),
+                    jnp.zeros((2,), jnp.int32)),)
+
+    def _keeps_pair_rows(self, program):
+        """``program`` as its callers know it, ``-> (first tokens,
+        cache)``: where it also returns its pair rows they are kept for
+        ``_count_pair_rows``, on the device."""
+        if not self._counts_pair_rows:
+            return program
+
+        def run(*operands):
+            first, cache, rows = program(*operands)
+            self._pair_rows.append(rows)
+            return first, cache
+        run.lower = program.lower
+        return run
+
+    def _count_pair_rows(self) -> None:
+        """Fold the pair rows of the prefill programs run so far into
+        ``stats`` (a fetch of a few ready integers a program)."""
+        kept, self._pair_rows = self._pair_rows, []
+        for pairs, run in jax.device_get(kept):
+            self.stats.moe_prefill_pairs += int(pairs)
+            self.stats.moe_prefill_pairs_run += int(run)
 
     def _get_prefill_paged(self, bucket: int, wave: int):
         """Slotless prefill: prompts write straight into pool pages via
@@ -971,20 +1028,22 @@ class LLMEngine:
                 temps = packed[:, bucket + 1].astype(jnp.float32) / 1e6
                 b, s = tokens.shape
                 positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-                last, cache, *prenorm = self._last_logits(
+                last, cache, *more = self._last_logits(
                     self.model, params, cache, tokens, positions, s_reals,
                     tables, packed[:, bucket + 2] if self._state_layers
                     else None, skip_pad=self._skips_pad(bucket))
                 if self._drafts:
                     rng, sub = jax.random.split(rng)
-                    return self._first_draft(
-                        params, cache, *prenorm, tokens, positions, s_reals,
+                    first, cache, *rows = self._first_draft(
+                        params, cache, more[0], tokens, positions, s_reals,
                         tables, self._sample_fn(rng, last, temps), temps,
                         sub)
-                first = self._sample_fn(rng, last, temps)
-                return first, cache
-            fn = self._prefill_jit[(bucket, wave)] = jax.jit(
-                engine_prefill, donate_argnums=(1,))
+                    if rows:    # the stack's expert layers, the module's
+                        rows = [more[1] + rows[0]]
+                    return first, cache, *rows
+                return self._sample_fn(rng, last, temps), cache, *more
+            fn = self._prefill_jit[(bucket, wave)] = self._keeps_pair_rows(
+                jax.jit(engine_prefill, donate_argnums=(1,)))
         return fn
 
     def _get_prefill_suffix(self, bucket: int, wave: int):
@@ -1007,13 +1066,12 @@ class LLMEngine:
                     jnp.arange(s), (b, s))
                 # windows at an offset, attending through the pool:
                 # every position computed, as counted
-                last, cache = self._last_logits(
+                last, cache, *rows = self._last_logits(
                     self.model_prefix, params, cache, tokens, positions,
                     s_reals, tables, skip_pad=False)
-                first = self._sample_fn(rng, last, temps)
-                return first, cache
-            fn = self._suffix_jit[(bucket, wave)] = jax.jit(
-                engine_prefill_suffix, donate_argnums=(1,))
+                return self._sample_fn(rng, last, temps), cache, *rows
+            fn = self._suffix_jit[(bucket, wave)] = self._keeps_pair_rows(
+                jax.jit(engine_prefill_suffix, donate_argnums=(1,)))
         return fn
 
     def _is_pool_leaf(self, leaf) -> bool:
@@ -1375,6 +1433,9 @@ class LLMEngine:
         for wave in _WAVE_SIZES:
             self._install_firsts_jit(
                 self._no_admit[1], self._wave_outs(wave), none)
+        # the programs run above (and by a benchmark that warms its own
+        # pairs before it calls this) held no request: not counted
+        self._pair_rows.clear()
         if burst:
             plen = max(prompt_lens)
 
@@ -2570,6 +2631,8 @@ class LLMEngine:
             # the fetch waited for the waves' end and for nothing else
             # (several waves' tokens are joined BEHIND the block)
             self._waves_done(ahead, time.monotonic())
+        if self._pair_rows:
+            self._count_pair_rows()
         off = 0
         exports = []
         with self._phase("deliver_prefill", "deliver_s") as sp:

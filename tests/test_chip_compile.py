@@ -716,6 +716,18 @@ def test_paged_decode_on_latent_pages(topo, one_chip):
     lowered.compile()
 
 
+def _grouped_products_and_custom_calls(lowered: str):
+    """``(chlo.ragged_dot operations, stablehlo.custom_call
+    operations)`` of a lowered program: a layer that holds a share of
+    its experts runs its sorted pairs in slabs (ISSUE 56) under the
+    grouped products the program had, no more of them than it had (the
+    slab loop is a function of its own: layers of one shape share ONE
+    lowered body), and no custom call beside those it had."""
+    lines = lowered.splitlines()
+    return (sum('"chlo.ragged_dot"(' in line for line in lines),
+            sum("stablehlo.custom_call" in line for line in lines))
+
+
 @pytest.fixture(scope="module")
 def kanana_programs(topo, one_chip):
     """``engine_decode_block`` and the two widest prefill waves the
@@ -778,6 +790,9 @@ def test_kanana_engine_programs_compile_with_their_kernels(kanana_programs):
         text = p[f"prefill_text_{bucket}"]
         assert text.count('kernel_name = "flash_fwd"') == 2
         assert not re.search(r"tensor<\d+x32x\d{3,}x\d{3,}xf32>", text)
+        # the scanned expert stack's three grouped products, in the slab
+        # loop now; the two flash kernels are the only custom calls
+        assert _grouped_products_and_custom_calls(text) == (3, 2)
 
 
 @pytest.mark.parametrize("name", ["engine_decode_block",
@@ -892,6 +907,10 @@ def test_nemotron_engine_programs_compile_with_their_kernels(
         assert "ssm_decode" not in p[f"prefill_text_{bucket}"]
         assert "moe_experts_decode" not in p[f"prefill_text_{bucket}"]
         assert "ragged-dot" in p[f"engine_prefill_{bucket}"].as_text()
+        # two products an expert (not gated), in the ONE body the five
+        # unrolled layers call (ten at the parent); the custom calls it had
+        assert _grouped_products_and_custom_calls(
+            p[f"prefill_text_{bucket}"]) == (2, 5)
 
 
 @pytest.mark.parametrize("name", ["engine_decode_block",
@@ -1007,6 +1026,7 @@ def k_exaone_programs(topo, one_chip):
                       jax.random.PRNGKey(0)), one_chip))
         return {"eng": eng, "block_text": block.as_text(),
                 "engine_decode_block": block.compile(),
+                "prefill_text": prefill.as_text(),
                 "engine_prefill": prefill.compile()}
     finally:
         patch.undo()
@@ -1048,6 +1068,10 @@ def test_k_exaone_engine_programs_compile_with_their_kernels(
     # their stack of one)
     made = _pool_result_producers(hlo, (16 * 6144 * 2048,))
     assert set(made) <= _IN_PLACE, f"a layer's experts from {dict(made)}"
+    # the prefill wave's grouped products: three in the scanned stack's
+    # one body and three in the module, and no custom call
+    assert "ragged-dot" in p["engine_prefill"].as_text()
+    assert _grouped_products_and_custom_calls(p["prefill_text"]) == (6, 0)
 
 
 def test_k_exaone_cell_fits_the_chip(k_exaone_programs):
@@ -1233,6 +1257,14 @@ def test_kimi_engine_programs_compile_with_their_kernels(kimi_programs):
     for bucket in (8192, 1024):
         assert "@jit_engine_prefill" in p[f"prefill_text_{bucket}"]
         assert "kda_decode" not in p[f"prefill_text_{bucket}"]
+        # three grouped products in each of the TWO bodies its ten
+        # traced expert layers call (the runs' stacks of one, the
+        # period's stack; thirty at the parent); no custom call but the
+        # latent layers' flash kernels
+        assert "ragged-dot" in p[f"engine_prefill_{bucket}"].as_text()
+        text = p[f"prefill_text_{bucket}"]
+        assert _grouped_products_and_custom_calls(text) == (
+            6, text.count('kernel_name = "flash_fwd"'))
 
 
 @pytest.mark.parametrize("name", ["engine_decode_block",

@@ -357,10 +357,12 @@ def test_paged_prefill_and_decode_match_the_reference(parts, reference,
         tokens[r, :n] = seq[:n]
         tables[r, :8] = 1 + 8 * r + np.arange(8)
     entries = jnp.asarray([2, 5], jnp.int32)
-    logits, cache = eng._last_logits(
+    # (a share of the experts is held: the expert layers' pair rows too)
+    logits, cache, pair_rows = eng._last_logits(
         eng.model, eng.params, eng._cache, jnp.asarray(tokens),
         jnp.broadcast_to(jnp.arange(bucket), (wave, bucket)),
         jnp.asarray(n_prompt, jnp.int32), jnp.asarray(tables), entries)
+    assert pair_rows.shape == (2,)
     want = [reference.logits(weights, seq, published) for seq in seqs]
     noise = [jnp.abs(reference.logits(weights, seq, published, bits=7)
                      - w).max(-1)

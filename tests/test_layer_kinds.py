@@ -358,14 +358,14 @@ def test_expert_kernel_reads_what_live_rows_chose(monkeypatch, case,
     layer = jnp.int32(layers - 1)
     args = (x, gates, jnp.asarray(experts, jnp.int32), *w)
     run = jax.jit(lambda *a: moe.dropless_experts(
-        *a, act="relu", live=mask, layer=layer))
+        *a, act="relu", live=mask, layer=layer)[0])
     want = np.asarray(run(*args), np.float32)        # every expert read
     built = _interpreted_expert_kernel(monkeypatch)
     if vmem:
         monkeypatch.setattr(moe, "_KERNEL_WEIGHTS_VMEM", vmem)
         assert moe._width_tile(d, f, 2) == 128
     run = jax.jit(lambda *a: moe.dropless_experts(
-        *a, act="relu", live=mask, layer=layer))
+        *a, act="relu", live=mask, layer=layer)[0])
     got = np.asarray(run(*args), np.float32)
     assert built == [(layers, e, d, f)]
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
@@ -403,7 +403,7 @@ def test_no_live_row_reads_no_expert(monkeypatch):
     dead = jnp.zeros((5,), bool)
     _, count = moe.touched_experts(experts, dead, 4)
     assert int(count) == 0
-    out = moe.dropless_experts(
+    out, _ = moe.dropless_experts(
         jnp.asarray(rs.randn(5, 128), jnp.bfloat16),
         jnp.full((5, 2), 0.5), experts, *w, live=dead, layer=0)
     assert (np.asarray(out, np.float32) == 0).all()

@@ -339,7 +339,7 @@ def test_gated_and_ungated_experts_in_both_formulations(monkeypatch, gated,
     fn = (lambda h: h / (1 + np.exp(-h))) if gated else (
         lambda h: np.maximum(h, 0) ** 2)
     for rows in (None, live):
-        got = moe.dropless_experts(
+        got, _ = moe.dropless_experts(
             x, gates, experts, w_gate, w_up, w_down, act=act, partial=True,
             live=None if rows is None else jnp.asarray(rows))
         want = _pairs_loop(x, gates, experts, w_gate, w_up, w_down, fn, rows)

@@ -13,6 +13,7 @@ are the benchmark's (chipbench/) and benchmarks/serve_llm.py's.
 import threading
 import time
 
+import jax
 import pytest
 
 
@@ -1648,5 +1649,98 @@ def test_a_lone_drafted_request_costs_the_steps_of_its_pairs(
         assert st.block_steps_offered == 64
         assert (st.drafts_proposed, st.drafts_accepted) == (steps, steps)
         assert st.step_tokens == n - 1
+    finally:
+        eng.close()
+
+
+def _preset_engine(preset, **over):
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import GPT, get_config
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    cfg = get_config(preset, **over)
+    params = GPT(cfg).init(jax.random.PRNGKey(1),
+                           jnp.zeros((1, 8), jnp.int32))["params"]
+    return LLMEngine(cfg, params, num_slots=3, page_size=4, max_seq_len=96,
+                     max_prompt_len=32, block_size=4, min_prefill_bucket=8)
+
+
+def _prefill_program_outs(eng, bucket=32, wave=2):
+    """The output tree of the engine's prefill program, described."""
+    import jax
+    import jax.numpy as jnp
+    return jax.eval_shape(
+        eng._get_prefill_paged(bucket, wave), eng.params, eng._cache,
+        jnp.zeros((wave, eng.packed_width(bucket)), jnp.int32),
+        jnp.zeros((wave, eng.max_pages), jnp.int32), jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("preset,over,chunk", [
+    ("tiny-kanana", {"moe_experts_held": 2, "moe_held_first": 3}, None),
+    ("tiny-kanana", {"moe_experts_held": 2, "moe_held_first": 3}, 8),
+    ("tiny-k-exaone", {"moe_experts_held": 2, "moe_held_first": 1}, None),
+    ("tiny-nemotron-h", {"moe_experts_held": 2}, None)],
+    ids=["wave", "chunked-wave", "drafting", "period-latent-moe"])
+def test_a_held_engine_counts_the_pair_rows_its_prefill_ran(
+        monkeypatch, prefill_chunk, preset, over, chunk):
+    """A model that holds a share of its experts: after prompt waves
+    (one pass; chunks of 8 under ``_skips_pad``; a drafting engine's
+    two model calls; a period's ``LatentMoE``) the grouped products ran
+    over some pair rows and over no more than they were given (the
+    warm-up's all-pad programs left out), and a caller of the program still gets ``(first tokens,
+    cache)``."""
+    from ray_tpu.ops import moe
+    if chunk:
+        prefill_chunk(chunk)
+    monkeypatch.setattr(moe, "DENSE_PAIRS_MAX", 0)      # grouped at any size
+    monkeypatch.setattr(moe, "SLAB_TILE", 8)            # and in slabs
+    eng = _preset_engine(preset, **over)
+    try:
+        assert eng._counts_pair_rows
+        assert bool(chunk) == eng._skips_pad(32)
+        # a warm-up's programs hold no request: run, and not counted
+        eng._get_prefill_paged(32, 1)
+        eng.warmup(prompt_lens=(30,))
+        assert not eng._pair_rows
+        assert eng.stats.snapshot(2)["moe_prefill_pairs"] == 0
+        for n in (30, 17):
+            assert len(eng.submit(list(range(3, 3 + n)),
+                                  max_new_tokens=3).tokens) == 3
+        st = eng.stats.snapshot(2)
+        given, ran = st["moe_prefill_pairs"], st["moe_prefill_pairs_run"]
+        assert 0 < ran < given and not eng._pair_rows
+        assert given % (eng.cfg.moe_top_k * 8) == 0
+        firsts, cache = _prefill_program_outs(eng)
+        assert jax.tree.structure(cache) == jax.tree.structure(eng._cache)
+        assert jax.tree.leaves(firsts)[0].shape == (2,)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("preset", [
+    "tiny", "tiny-smallthinker", "tiny-olmo-hybrid", "tiny-granite-h"],
+    ids=["dense", "every-expert-held", "period", "mamba2"])
+def test_an_engine_that_holds_no_share_runs_the_programs_it_ran(
+        monkeypatch, preset):
+    """No share held: nothing is counted, nothing is kept, and the
+    prefill program is the jitted function itself, returning ``(first
+    tokens, cache)`` and no third output."""
+    from ray_tpu.ops import moe
+    monkeypatch.setattr(moe, "DENSE_PAIRS_MAX", 0)
+    eng = _preset_engine(preset)
+    try:
+        assert not eng._counts_pair_rows
+        assert eng._prefill_mutable == ["cache"]
+        eng.submit(list(range(3, 33)), max_new_tokens=2)
+        st = eng.stats.snapshot(2)
+        assert (st["moe_prefill_pairs"], st["moe_prefill_pairs_run"]) == (
+            0, 0)
+        assert not eng._pair_rows
+        program = eng._get_prefill_paged(32, 2)
+        assert hasattr(program, "lower") and hasattr(program, "trace")
+        firsts, cache = _prefill_program_outs(eng)
+        assert firsts.shape == (2,)
+        assert jax.tree.structure(cache) == jax.tree.structure(eng._cache)
     finally:
         eng.close()
